@@ -5,10 +5,8 @@ native core's fused allreduce; rank 0 reports images/sec.
 
 Run: tpurun -np 4 python examples/jax_synthetic_benchmark.py
 
-The in-jit gradient allreduce lowers to a host callback; on a
-remote-compile relay backend (see docs/running.md) it raises at trace
-time with guidance — use examples/jax_mesh_train.py (pure-XLA in-mesh
-path) on such platforms.
+The in-jit gradient allreduce lowers to a host callback (``io_callback``);
+examples/jax_mesh_train.py is the pure-XLA in-mesh path.
 """
 import os
 import time

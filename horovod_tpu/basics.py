@@ -61,7 +61,8 @@ def _maybe_build():
                         or os.path.getmtime(_LIB_PATH) < newest):
                     if locked:
                         subprocess.run(
-                            ["make", "-s"], cwd=_CSRC_DIR, check=True,
+                            ["make", "-s", f"-j{os.cpu_count() or 1}"],
+                            cwd=_CSRC_DIR, check=True,
                             stdout=subprocess.DEVNULL,
                         )
                     elif not os.path.exists(_LIB_PATH):

@@ -56,28 +56,13 @@ MANIFEST = "MANIFEST.json"
 _RANK_MANIFEST = "shards.json"
 
 
-def _dist_initialized():
-    """jax.distributed.is_initialized with a fallback for jax releases
-    that don't expose it (0.4.x): probe the distributed client state."""
-    import jax
-
-    if hasattr(jax.distributed, "is_initialized"):
-        return jax.distributed.is_initialized()
-    try:
-        from jax._src import distributed as _d
-
-        return _d.global_state.client is not None
-    except Exception:
-        return False
-
-
 def _ckptr():
     """Orbax Checkpointer confined to this process — kept ONLY for the
     legacy read path (checkpoints written before the sharded format)."""
     import jax
     import orbax.checkpoint as ocp
 
-    me = jax.process_index() if _dist_initialized() else 0
+    me = jax.process_index() if jax.distributed.is_initialized() else 0
     return ocp.Checkpointer(
         ocp.StandardCheckpointHandler(),
         multiprocessing_options=ocp.options.MultiprocessingOptions(

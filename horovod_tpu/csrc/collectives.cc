@@ -443,7 +443,10 @@ void DataPlane::UringDuplex(
           uring_full_sends_ = false;  // kernel handed the op back unfinished
           continue;                   // resubmit next round
         }
-        if (res < 0) throw std::runtime_error("data-plane send failed");
+        if (res < 0)
+          throw std::runtime_error(
+              std::string("data-plane send failed (io_uring): ") +
+              strerror(-res));
         if ((size_t)res < sleft) uring_full_sends_ = false;
         IovAdvance(sv, &si, (size_t)res);
         sleft -= (size_t)res;
@@ -463,7 +466,10 @@ void DataPlane::UringDuplex(
         if (res == -ECANCELED) continue;
         if (res == -EINTR || res == -EAGAIN) continue;
         if (res == 0) throw std::runtime_error("data-plane peer closed");
-        if (res < 0) throw std::runtime_error("data-plane recv failed");
+        if (res < 0)
+          throw std::runtime_error(
+              std::string("data-plane recv failed (io_uring): ") +
+              strerror(-res));
         if (abuf != nullptr) {
           if (shift > 0) memmove(abuf - shift, abuf, (size_t)res);
           if ((size_t)res < alen) shift += alen - (size_t)res;
@@ -547,7 +553,8 @@ void DataPlane::FullDuplex(Socket& to, const void* sbuf, size_t sn,
         if ((fds[i].revents & POLLOUT) && sent < sn) {
           ssize_t k = WireSend(to, sp + sent, sn - sent, &zc_pending);
           if (k < 0 && errno != EAGAIN && errno != EWOULDBLOCK && errno != EINTR)
-            throw std::runtime_error("data-plane send failed");
+            throw std::runtime_error(
+                std::string("data-plane send failed: ") + strerror(errno));
           if (k > 0) {
             sent += (size_t)k;
             to.note_tx((size_t)k);
@@ -632,7 +639,8 @@ void DataPlane::FullDuplexV(Socket& to, std::vector<iovec>& sv, Socket& from,
           ssize_t k = WireSendMsg(to, &mh, sleft, &zc_pending);
           if (k < 0 && errno != EAGAIN && errno != EWOULDBLOCK &&
               errno != EINTR)
-            throw std::runtime_error("data-plane send failed");
+            throw std::runtime_error(
+                std::string("data-plane send failed: ") + strerror(errno));
           if (k > 0) {
             IovAdvance(sv, &si, (size_t)k);
             sleft -= (size_t)k;
@@ -738,7 +746,8 @@ void DataPlane::FullDuplexStream(
         if ((fds[i].revents & POLLOUT) && sent < sn) {
           ssize_t k = WireSend(to, sp + sent, sn - sent, &zc_pending);
           if (k < 0 && errno != EAGAIN && errno != EWOULDBLOCK && errno != EINTR)
-            throw std::runtime_error("data-plane send failed");
+            throw std::runtime_error(
+                std::string("data-plane send failed: ") + strerror(errno));
           if (k > 0) {
             sent += (size_t)k;
             to.note_tx((size_t)k);
@@ -834,7 +843,8 @@ void DataPlane::FullDuplexVStream(
           ssize_t k = WireSendMsg(to, &mh, sleft, &zc_pending);
           if (k < 0 && errno != EAGAIN && errno != EWOULDBLOCK &&
               errno != EINTR)
-            throw std::runtime_error("data-plane send failed");
+            throw std::runtime_error(
+                std::string("data-plane send failed: ") + strerror(errno));
           if (k > 0) {
             IovAdvance(sv, &si, (size_t)k);
             sleft -= (size_t)k;

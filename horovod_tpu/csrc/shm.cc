@@ -233,6 +233,21 @@ bool ShmPlane::Init(int rank, const std::vector<int>& host_ranks,
       }
       spins = Backoff(spins);
     }
+    // The name exists from the owner's shm_open, its length only from the
+    // ftruncate after it: touching a mapping of the still-empty file is
+    // SIGBUS. Wait for the full length before mapping.
+    struct stat st{};
+    spins = 0;
+    while (fstat(pfd, &st) == 0 && (size_t)st.st_size < seg_len &&
+           MonoUs() <= deadline)
+      spins = Backoff(spins);
+    if ((size_t)st.st_size < seg_len) {
+      LogF(LogLevel::kWarn, "shm: peer %d's segment %s never reached %zu bytes",
+           host_ranks_[i], name.c_str(), seg_len);
+      close(pfd);
+      Shutdown();
+      return false;
+    }
     void* pbase =
         mmap(nullptr, seg_len, PROT_READ | PROT_WRITE, MAP_SHARED, pfd, 0);
     close(pfd);

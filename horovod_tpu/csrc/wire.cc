@@ -1,6 +1,9 @@
 #include "wire.h"
 
 #include <errno.h>
+#include <linux/errqueue.h>
+#include <netinet/in.h>
+#include <poll.h>
 #include <pthread.h>
 #include <sched.h>
 #include <stdio.h>
@@ -11,6 +14,7 @@
 #include <unistd.h>
 
 #include <atomic>
+#include <vector>
 
 #include "debug_lock.h"
 #include "tcp.h"  // fault::Check
@@ -31,6 +35,12 @@
 
 #ifndef SO_ZEROCOPY
 #define SO_ZEROCOPY 60
+#endif
+#ifndef MSG_ZEROCOPY
+#define MSG_ZEROCOPY 0x4000000
+#endif
+#ifndef SO_EE_ORIGIN_ZEROCOPY
+#define SO_EE_ORIGIN_ZEROCOPY 5
 #endif
 
 namespace hvd {
@@ -55,6 +65,66 @@ int TierFromName(const char* name) {
   return -1;  // "auto" and anything unrecognized
 }
 
+namespace {
+
+// One MSG_ZEROCOPY send over a loopback pair, its completion reaped. A
+// kernel can accept SO_ZEROCOPY and still fail the send (EINVAL) or never
+// post the completion — the sandboxed kernel of the v5e chip machines does
+// both — and the data plane then fails or stalls on its first large
+// message. So the tier is offered only where it has been seen to work.
+bool ZeroCopyRoundTrip() {
+  int ls = ::socket(AF_INET, SOCK_STREAM, 0), c = -1, a = -1;
+  bool ok = false;
+  sockaddr_in addr{};
+  addr.sin_family = AF_INET;
+  addr.sin_addr.s_addr = htonl(INADDR_LOOPBACK);
+  socklen_t len = sizeof(addr);
+  int one = 1;
+  std::vector<char> buf(1 << 16);  // above the tier's default threshold
+  do {
+    if (ls < 0 || ::bind(ls, (sockaddr*)&addr, sizeof(addr)) != 0 ||
+        ::listen(ls, 1) != 0 ||
+        ::getsockname(ls, (sockaddr*)&addr, &len) != 0)
+      break;
+    c = ::socket(AF_INET, SOCK_STREAM, 0);
+    fault::Check("connect");
+    lockdep::OnBlockingSyscall("connect");
+    if (c < 0 || ::connect(c, (sockaddr*)&addr, sizeof(addr)) != 0) break;
+    a = ::accept(ls, nullptr, nullptr);
+    if (a < 0 ||
+        setsockopt(c, SOL_SOCKET, SO_ZEROCOPY, &one, sizeof(one)) != 0)
+      break;
+    if (::send(c, buf.data(), buf.size(), MSG_NOSIGNAL | MSG_ZEROCOPY) <= 0)
+      break;
+    // The completion is posted once the peer holds the bytes: drain them,
+    // then wait (half a second at most) for the error queue.
+    for (int tries = 0; tries < 50 && !ok; tries++) {
+      while (::recv(a, buf.data(), buf.size(), MSG_DONTWAIT) > 0) {
+      }
+      pollfd pfd{c, 0, 0};  // error-queue readiness reports as POLLERR
+      fault::Check("poll");
+      lockdep::OnBlockingSyscall("poll");
+      if (::poll(&pfd, 1, 10) <= 0) continue;
+      uint8_t ctrl[128];
+      msghdr mh{};
+      mh.msg_control = ctrl;
+      mh.msg_controllen = sizeof(ctrl);
+      if (::recvmsg(c, &mh, MSG_ERRQUEUE | MSG_DONTWAIT) < 0) continue;
+      for (cmsghdr* cm = CMSG_FIRSTHDR(&mh); cm; cm = CMSG_NXTHDR(&mh, cm)) {
+        sock_extended_err ee;
+        memcpy(&ee, CMSG_DATA(cm), sizeof(ee));
+        if (ee.ee_errno == 0 && ee.ee_origin == SO_EE_ORIGIN_ZEROCOPY)
+          ok = true;
+      }
+    }
+  } while (false);
+  for (int fd : {ls, c, a})
+    if (fd >= 0) ::close(fd);
+  return ok;
+}
+
+}  // namespace
+
 int Probe(int want, int deny_mask, int64_t* probe_failures) {
   int got = kBasic;
   if (want >= kUring) {
@@ -70,14 +140,7 @@ int Probe(int want, int deny_mask, int64_t* probe_failures) {
   }
   if (got < kZeroCopy && want >= kZeroCopy) {
     bool ok = false;
-    if (!(deny_mask & (1 << kZeroCopy))) {
-      int fd = ::socket(AF_INET, SOCK_STREAM, 0);
-      if (fd >= 0) {
-        int one = 1;
-        ok = setsockopt(fd, SOL_SOCKET, SO_ZEROCOPY, &one, sizeof(one)) == 0;
-        ::close(fd);
-      }
-    }
+    if (!(deny_mask & (1 << kZeroCopy))) ok = ZeroCopyRoundTrip();
     if (ok)
       got = kZeroCopy;
     else if (probe_failures)

@@ -32,26 +32,17 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU-only hosts too; guard for safety.
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
 
 def _vspec(block, index_map=None):
-    return pl.BlockSpec(block, index_map, memory_space=_VMEM)
+    return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
 
 
 def _scratch(shape):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, jnp.float32)
-    return pltpu  # pragma: no cover
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _diag_keep(diag, mode, bq, bk):
@@ -218,8 +209,6 @@ def _unfold(x, B, H):
 
 
 def _compiler_params():
-    if pltpu is None:  # pragma: no cover
-        return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 

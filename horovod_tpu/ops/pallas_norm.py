@@ -26,14 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _block_rows(R, C):
@@ -90,27 +83,23 @@ def paired_reduce(a, b, *, interpret=False):
     b2 = b.reshape(-1, C)
     R = a2.shape[0]
     br = _block_rows(R, C)
-    compiler_params = None
-    if pltpu is not None:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",))
     s, p = pl.pallas_call(
         _paired_kernel,
         grid=(R // br,),
         in_specs=[pl.BlockSpec((br, C), lambda i: (i, 0),
-                               memory_space=_VMEM),
+                               memory_space=pltpu.VMEM),
                   pl.BlockSpec((br, C), lambda i: (i, 0),
-                               memory_space=_VMEM)],
+                               memory_space=pltpu.VMEM)],
         out_specs=[pl.BlockSpec((1, C), lambda i: (0, 0),
-                                memory_space=_VMEM),
+                                memory_space=pltpu.VMEM),
                    pl.BlockSpec((1, C), lambda i: (0, 0),
-                                memory_space=_VMEM)],
+                                memory_space=pltpu.VMEM)],
         out_shape=[jax.ShapeDtypeStruct((1, C), jnp.float32),
                    jax.ShapeDtypeStruct((1, C), jnp.float32)],
-        scratch_shapes=[] if pltpu is None else [
-            pltpu.VMEM((1, C), jnp.float32),
-            pltpu.VMEM((1, C), jnp.float32)],
-        compiler_params=compiler_params,
+        scratch_shapes=[pltpu.VMEM((1, C), jnp.float32),
+                        pltpu.VMEM((1, C), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(a2, b2)
     return s[0], p[0]

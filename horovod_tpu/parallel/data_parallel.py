@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from jax import shard_map  # requires jax >= 0.8
+from jax import shard_map
 
 
 def make_train_step(loss_fn, tx, mesh, data_axis="data", extra_reduce=None,

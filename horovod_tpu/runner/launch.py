@@ -460,13 +460,14 @@ def _run_static(args):
                            controller_addr=ctrl, jax_coord_addr=jax_coord,
                            extra_env=extra)
             # Pin the chip BEFORE libtpu initializes; harmless off-TPU.
-            maybe_bind_tpu_chip(env, s.local_rank)
+            maybe_bind_tpu_chip(env, s.local_rank, s.local_size)
             if hosts_mod.is_local(s.hostname):
                 procs.append(safe_exec(list(args.command), env=env))
             else:
                 cmd = get_remote_command(s, list(args.command), {
                     k: v for k, v in env.items()
-                    if k.startswith(("HVD_", "PYTHONPATH", "PATH", "TPU_"))
+                    if k.startswith(("HVD_", "PYTHONPATH", "PATH", "TPU_",
+                                     "CLOUD_TPU_"))
                 }, args.ssh_port, stdin_env=("HVD_RENDEZVOUS_SECRET",),
                     remote_shell=args.remote_shell)
                 procs.append(spawn_remote(

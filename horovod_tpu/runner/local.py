@@ -17,14 +17,43 @@ import sys
 import time
 
 
-def maybe_bind_tpu_chip(env, index):
+# How libtpu lays `local_size` one-chip processes over one host's chips
+# (x,y,z process grid): the layouts that have run on a v5e host, as the
+# launcher JAX ships for its own multi-process TPU tests sets them
+# (jax/_src/test_multiprocess.py).
+_TPU_PROCESS_BOUNDS = {1: "1,1,1", 4: "2,2,1"}
+# libtpu's default slice-builder port; rank i of a host listens on base + i.
+_TPU_PROCESS_PORT = 8476
+
+
+def maybe_bind_tpu_chip(env, index, local_size=None):
     """One process = one chip (reference: local_rank pins a GPU): set
     ``TPU_VISIBLE_CHIPS=<index>``, OVERWRITING any inherited value — a
     launcher-level pin applied to every rank would bind all ranks to the
     same chip. ``HVD_BIND_TPU_CHIPS=0`` opts out. The ONE implementation
-    every launch path (static, elastic, local) uses."""
-    if os.environ.get("HVD_BIND_TPU_CHIPS", "1") != "0":
-        env["TPU_VISIBLE_CHIPS"] = str(index)
+    every launch path (static, elastic, local) uses.
+
+    A visible chip alone gives N unrelated one-chip runtimes. For the
+    ranks of a host to form ONE slice over ICI (what ``hvd.global_mesh()``
+    needs), libtpu must also be told each process's share of the host
+    (``TPU_CHIPS_PER_PROCESS_BOUNDS``), the process grid
+    (``TPU_PROCESS_BOUNDS``), where its peers' slice builders listen
+    (``TPU_PROCESS_ADDRESSES`` / ``TPU_PROCESS_PORT``) and which of them it
+    is (``CLOUD_TPU_TASK_ID``). These are set when ``local_size`` is a
+    layout in ``_TPU_PROCESS_BOUNDS``; the elastic driver, whose world size
+    changes after libtpu has started, passes none and pins the chip only.
+    All of it is inert off-TPU."""
+    if os.environ.get("HVD_BIND_TPU_CHIPS", "1") == "0":
+        return env
+    env["TPU_VISIBLE_CHIPS"] = str(index)
+    bounds = _TPU_PROCESS_BOUNDS.get(local_size)
+    if bounds is not None:
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+        env["TPU_PROCESS_BOUNDS"] = bounds
+        env["TPU_PROCESS_ADDRESSES"] = ",".join(
+            f"localhost:{_TPU_PROCESS_PORT + i}" for i in range(local_size))
+        env["TPU_PROCESS_PORT"] = str(_TPU_PROCESS_PORT + index)
+        env["CLOUD_TPU_TASK_ID"] = str(index)
     return env
 
 
@@ -75,7 +104,7 @@ def run_local(np_, command, env=None, timeout=None, stdout=None,
         for r in range(np_):
             extra = dict(env or {})
             if bind_tpu_chips:
-                maybe_bind_tpu_chip(extra, r)
+                maybe_bind_tpu_chip(extra, r, np_)
             e = slot_env(r, np_, controller_addr=addr,
                          jax_coord_addr=jax_addr, extra_env=extra)
             procs.append(

@@ -16,6 +16,20 @@ import time
 
 GRACEFUL_TERMINATION_TIME_S = 5.0
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def compile_cache_dir():
+    """Where the repo's own drivers (chip_smoke.py, bench.py) tell the
+    processes they start to keep JAX's persistent compilation cache: the
+    directory ``JAX_COMPILATION_CACHE_DIR`` names when the machine sets
+    one, otherwise ``.jax_cache`` at the root of the checkout. The path is
+    part of JAX's cache key, so it is fixed: never the temp dir, a uid, a
+    pid or a time."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_CHECKOUT, ".jax_cache"))
+
 
 def make_secret_key() -> bytes:
     return _secrets.token_bytes(32)

@@ -103,8 +103,7 @@ def test_alltoall_int8_compress(np_):
 def test_env_capacity_factor(monkeypatch):
     """HVD_EP_CAPACITY_FACTOR: default 1.25, numeric override honored,
     garbage falls back to the default instead of raising mid-layer."""
-    ep = pytest.importorskip("horovod_tpu.parallel.expert_parallel",
-                             reason="mesh package needs jax >= 0.8")
+    from horovod_tpu.parallel import expert_parallel as ep
     monkeypatch.delenv("HVD_EP_CAPACITY_FACTOR", raising=False)
     assert ep.env_capacity_factor() == 1.25
     monkeypatch.setenv("HVD_EP_CAPACITY_FACTOR", "2.0")
@@ -117,8 +116,7 @@ def test_report_dispatch_without_core_is_noop():
     """The pure-XLA path has no gauge plane: report_dispatch returns
     False instead of raising when the core is uninitialized."""
     import horovod_tpu as hvd
-    ep = pytest.importorskip("horovod_tpu.parallel.expert_parallel",
-                             reason="mesh package needs jax >= 0.8")
+    from horovod_tpu.parallel import expert_parallel as ep
     if hvd.is_initialized():
         pytest.skip("core initialized in-process by another module")
     assert ep.report_dispatch(0.1, 32) is False

@@ -1,25 +1,17 @@
-"""The wedge-proof bench harness itself (VERDICT r4 #1 — the round-4
-record was lost to a TPU hang that outlived the driver's timeout, so the
-harness's survival properties need direct coverage):
+"""The bench harness itself: a config that hangs is SIGKILLed at its
+sub-deadline and becomes an explicit error line while every other config
+still measures, the final cumulative line lands last — and the run then
+exits non-zero, because a run in which a config failed is a failed run.
 
-- a config that hangs is SIGKILLed at its sub-deadline and becomes an
-  explicit error line while every other config still measures and the
-  final cumulative line lands last;
-- a wedged relay probe produces the explicit error + the cached numbers
-  from bench_cache.json instead of consuming the driver budget.
-
-Both use bench.py's _BENCH_TEST_HANG injection hooks; configs run on the
-CPU smoke path so the whole file is device-independent.
+Uses bench.py's _BENCH_TEST_HANG injection hook; configs run on the CPU
+smoke path so the whole file is device-independent.
 """
 import json
 import os
 import subprocess
 import sys
 
-import pytest
-
 from .util import _REPO
-from .util import have_shard_map
 
 BENCH = os.path.join(_REPO, "bench.py")
 
@@ -28,7 +20,7 @@ def _run_bench(extra_env, timeout):
     from .util import tpu_isolated_env
 
     env = dict(os.environ)
-    env.update(tpu_isolated_env())  # the one children-off-the-TPU policy
+    env.update(tpu_isolated_env())  # the one children-on-the-CPU policy
     env.update({k: str(v) for k, v in extra_env.items()})
     p = subprocess.run([sys.executable, BENCH], env=env,
                        capture_output=True, text=True, timeout=timeout)
@@ -37,11 +29,11 @@ def _run_bench(extra_env, timeout):
     return p, lines
 
 
-@pytest.mark.skipif(not have_shard_map(), reason="jax.shard_map unavailable (jax < 0.8): the graded moe bench config cannot import horovod_tpu.parallel here")
 def test_hung_config_is_killed_and_rest_still_measure():
     """transformer hangs forever; the parent must kill it at the (tiny)
-    sub-deadline, emit its error line in sequence, and still deliver
-    resnet50 + the remaining configs + the final cumulative line."""
+    sub-deadline, emit its error line in sequence, still deliver
+    resnet50 + the remaining configs + the final cumulative line, and
+    exit non-zero."""
     # Outer timeout must EXCEED the bench's own deadline — on a slow box
     # the graceful skip path needs its full budget before we'd SIGKILL.
     p, lines = _run_bench(
@@ -57,7 +49,7 @@ def test_hung_config_is_killed_and_rest_still_measure():
          # keep the CPU smoke run quick
          "HVD_BENCH_BATCH": "8"},
         timeout=850)
-    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.returncode == 1, p.stderr[-2000:]
     by_metric = {d["metric"]: d for d in lines}
     tr = by_metric["bert_large_scale_train_throughput"]
     assert "sub-deadline" in tr.get("error", ""), tr
@@ -73,28 +65,5 @@ def test_hung_config_is_killed_and_rest_still_measure():
     # MoE dispatch throughput and measured elastic recovery.
     assert final["extra"]["moe"]["value"] > 0, final["extra"]
     assert final["extra"]["elastic"]["value"] > 0, final["extra"]
-
-
-def test_wedged_probe_emits_cached_fallback(tmp_path):
-    """probe hang = the real round-4 failure mode. The bench must print
-    ONE line: explicit error + the last recorded numbers from the cache,
-    well inside the budget. A temp BENCH_CACHE_PATH is seeded so the
-    assertion is deterministic and the repo's real record is untouched."""
-    cache = tmp_path / "cache.json"
-    cache.write_text(json.dumps(
-        {"metric": "resnet50_synthetic_train_throughput", "value": 1234.5,
-         "unit": "images/sec/chip", "vs_baseline": 0.16,
-         "cached_note": "seeded by test"}))
-    p, lines = _run_bench(
-        {"_BENCH_TEST_HANG": "probe",
-         "BENCH_PROBE_TIMEOUT": "6",
-         "BENCH_CACHE_PATH": str(cache),
-         "BENCH_DEADLINE": "120"},
-        timeout=110)
-    assert p.returncode == 0, p.stderr[-2000:]
-    assert len(lines) == 1, lines
-    d = lines[0]
-    assert "relay wedged" in d.get("error", ""), d
-    assert d.get("cached") is True, d
-    assert d["value"] == 1234.5, d
-    assert d["vs_baseline"] == 0.16, d
+    # The headline measured, and nothing was replayed from an earlier run.
+    assert "cached" not in final and "error" not in final, final

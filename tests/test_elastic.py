@@ -12,7 +12,6 @@ import time
 import pytest
 
 from .util import tpu_isolated_env
-from .util import have_shard_map
 
 WORKER = os.path.join(os.path.dirname(__file__), "workers",
                       "elastic_train_worker.py")
@@ -89,7 +88,6 @@ def test_elastic_failure_recovery(tmp_path):
     assert all("iter=10" in line for line in finals), log
 
 
-@pytest.mark.skipif(not have_shard_map(), reason="jax.shard_map unavailable (jax < 0.8): mesh workers cannot import horovod_tpu.parallel")
 def test_elastic_mesh_scale_up(tmp_path):
     """Elastic × ICI composition (VERDICT r2 #1): each epoch trains in-jit
     over a global jax mesh sized to membership. Scale-up 2→3 procs (2
@@ -111,7 +109,6 @@ def test_elastic_mesh_scale_up(tmp_path):
     assert all("iter=12" in line for line in finals), log
 
 
-@pytest.mark.skipif(not have_shard_map(), reason="jax.shard_map unavailable (jax < 0.8): mesh workers cannot import horovod_tpu.parallel")
 def test_elastic_mesh_failure_recovery(tmp_path):
     """A worker dies mid-job: survivors restore committed HOST state, the
     PJRT backend is rebuilt per epoch, and the respawned membership trains
@@ -129,7 +126,6 @@ def test_elastic_mesh_failure_recovery(tmp_path):
     assert all("iter=8" in line and "ndev=4" in line for line in finals), log
 
 
-@pytest.mark.skipif(not have_shard_map(), reason="jax.shard_map unavailable (jax < 0.8): mesh workers cannot import horovod_tpu.parallel")
 def test_elastic_mesh_scale_down(tmp_path):
     """Scale-down 3→2: the excess worker exits on the KV directive,
     survivors tear the 6-device mesh down and finish on a 4-device mesh

@@ -7,7 +7,6 @@ import os
 import pytest
 
 from .util import run_worker_job
-from .util import have_shard_map
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _EXAMPLES = os.path.join(_REPO, "examples")
@@ -85,7 +84,6 @@ def test_bn_sweep_driver_smoke():
     assert "vs baseline" in p.stdout  # summary table printed
 
 
-@pytest.mark.skipif(not have_shard_map(), reason="jax.shard_map unavailable (jax < 0.8): mesh workers cannot import horovod_tpu.parallel")
 def test_pipeline_example():
     """examples/pipeline_train.py: 4 transformer-block GPipe stages x
     2-way dp on the virtual mesh, loss falls."""

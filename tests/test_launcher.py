@@ -316,7 +316,8 @@ def test_tpurun_failure_propagates(tmp_path):
 
 def test_tpu_chip_binding(monkeypatch):
     """tpurun pins TPU_VISIBLE_CHIPS=local_rank per slot (one process =
-    one chip, set before libtpu init); HVD_BIND_TPU_CHIPS=0 opts out."""
+    one chip, set before libtpu init) and describes the host's process
+    grid to libtpu; HVD_BIND_TPU_CHIPS=0 opts out."""
     import horovod_tpu.runner.launch as launch_mod
 
     def capture(np_):
@@ -340,6 +341,18 @@ def test_tpu_chip_binding(monkeypatch):
     envs = capture(2)
     assert [e.get("TPU_VISIBLE_CHIPS") for e in envs] == ["0", "1"]
 
+    # Four ranks on a host are one 2x2 slice: libtpu is told the process
+    # grid, every rank's slice-builder address and which of them it is
+    # (what chip_smoke.py --chips 4 needed on a four-chip v5e host).
+    envs = capture(4)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,2,1"}
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+    addresses, = {e["TPU_PROCESS_ADDRESSES"] for e in envs}
+    assert [f"localhost:{e['TPU_PROCESS_PORT']}" for e in envs] == \
+        addresses.split(",")
+
     # an inherited launcher-level pin must be OVERWRITTEN per rank, not
     # kept (setdefault would bind every rank to the same chip)
     monkeypatch.setenv("TPU_VISIBLE_CHIPS", "3")
@@ -352,6 +365,7 @@ def test_tpu_chip_binding(monkeypatch):
     assert all(e.get("TPU_VISIBLE_CHIPS") != "0" or
                e.get("TPU_VISIBLE_CHIPS") != "1" for e in envs)
     assert all("TPU_VISIBLE_CHIPS" not in e for e in envs)
+    assert all("TPU_PROCESS_BOUNDS" not in e for e in envs)
 
 
 # -- LSF integration (reference: runner/util/lsf.py + js_run.py) ------------

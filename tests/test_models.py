@@ -13,29 +13,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.models import resnet, transformer as tfm
 
-try:  # jax >= 0.8 probe (the PR 13 shard_map gate): the sharded-forward
-    # tests also need modern XLA's sharded-matmul numerics — on 0.4.37
-    # the virtual-CPU-mesh bf16 reduction order drifts past tolerance.
-    from jax import shard_map as _shard_map  # noqa: F401
-    _HAVE_SHARD_MAP = True
-except ImportError:
-    _HAVE_SHARD_MAP = False
-
-try:  # the pallas kernels target jax >= 0.8's pltpu.CompilerParams API
-    from jax.experimental.pallas import tpu as _pltpu
-    _HAVE_PALLAS = hasattr(_pltpu, "CompilerParams")
-except Exception:  # noqa: BLE001
-    _HAVE_PALLAS = False
-
-_needs_modern_jax = pytest.mark.skipif(
-    not _HAVE_SHARD_MAP,
-    reason="jax.shard_map unavailable (jax < 0.8): sharded-mesh "
-           "semantics differ here")
-_needs_pallas = pytest.mark.skipif(
-    not _HAVE_PALLAS,
-    reason="pltpu.CompilerParams unavailable (jax < 0.8): the pallas "
-           "kernels cannot build here")
-
 
 def test_resnet50_forward_shapes():
     model, variables = resnet.create_train_state(
@@ -111,7 +88,6 @@ def test_transformer_moe_matches_dense_expert():
     {"data": 2, "model": 4},
     {"data": 2, "seq": 2, "model": 2},
 ])
-@_needs_modern_jax
 def test_transformer_sharded_matches_single_device(axes):
     """tp/sp/ep-sharded forward == single-device forward (same params)."""
     import dataclasses
@@ -140,13 +116,11 @@ def test_transformer_sharded_matches_single_device(axes):
                                rtol=3e-2, atol=3e-2)
 
 
-@_needs_modern_jax
 def test_graft_entry_dryrun():
     import __graft_entry__ as g
     g.dryrun_multichip(8)
 
 
-@_needs_modern_jax
 def test_transformer_ring_attention_matches_gather():
     """attn_impl='ring' (sequence-parallel K/V rotation) must equal the
     gather implementation on the same sharded mesh."""
@@ -174,7 +148,6 @@ def test_transformer_ring_attention_matches_gather():
                                rtol=3e-2, atol=3e-2)
 
 
-@_needs_pallas
 def test_pallas_norm_matches_reference():
     """ops/pallas_norm paired_reduce + batch_norm_train: forward and all
     three gradients must match the naive XLA batch norm (the kernels are
@@ -285,7 +258,6 @@ def _resnet_norm_trains(norm):
     assert not np.allclose(before, after), "running stats never updated"
 
 
-@_needs_pallas
 def test_resnet_pallas_norm_trains():
     _resnet_norm_trains("pallas")
 

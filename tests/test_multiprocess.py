@@ -13,11 +13,9 @@ import pytest
 pytest.importorskip("jax")
 
 from .util import run_worker_job  # noqa: E402
-from .util import have_shard_map  # noqa: E402
 
 
 @pytest.mark.parametrize("np_", [2, 4])
-@pytest.mark.skipif(not have_shard_map(), reason="jax.shard_map unavailable (jax < 0.8): mesh workers cannot import horovod_tpu.parallel")
 def test_global_mesh_train_step(np_):
     """Mesh formation, in-jit psum across processes, full DP train step
     with on-device gradient pmean, host metadata sync, core control plane
@@ -26,7 +24,6 @@ def test_global_mesh_train_step(np_):
                    jax_coord=True)
 
 
-@pytest.mark.skipif(not have_shard_map(), reason="jax.shard_map unavailable (jax < 0.8): mesh workers cannot import horovod_tpu.parallel")
 def test_mesh_collective_matrix_4proc():
     """All five in-mesh collectives × dtypes through a 4-process × 2-device
     global mesh (the ICI analog of the host path's op matrix)."""
@@ -34,7 +31,6 @@ def test_mesh_collective_matrix_4proc():
                    jax_coord=True)
 
 
-@pytest.mark.skipif(not have_shard_map(), reason="jax.shard_map unavailable (jax < 0.8): mesh workers cannot import horovod_tpu.parallel")
 def test_mixed_in_mesh_and_core_ops():
     """In-mesh XLA collectives and core-bridged (eager + in-jit io_callback)
     collectives interleaved for several rounds in one program."""
@@ -42,7 +38,6 @@ def test_mixed_in_mesh_and_core_ops():
                    jax_coord=True)
 
 
-@pytest.mark.skipif(not have_shard_map(), reason="jax.shard_map unavailable (jax < 0.8): mesh workers cannot import horovod_tpu.parallel")
 def test_worker_death_while_meshed_fails_fast():
     """A rank dying with the mesh live must surface HorovodInternalError on
     survivors via the core plane promptly — not a coordination-service or

@@ -207,7 +207,7 @@ def test_disabled_instrumented_op_skips_metrics(monkeypatch):
 
 def test_observability_import_is_jax_free():
     """`import horovod_tpu.observability` (parent package included) must
-    not pull jax — torch/TF-only workers and the bench's wedge-proof
+    not pull jax — torch/TF-only workers and the bench's jax-free
     parent import it unconditionally."""
     env = {k: v for k, v in os.environ.items()
            if not k.startswith(("HVD_", "JAX_"))}

@@ -9,19 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:  # the kernels target jax >= 0.8's pltpu.CompilerParams API
-    from jax.experimental.pallas import tpu as _pltpu
-    _HAVE_PALLAS = hasattr(_pltpu, "CompilerParams")
-except Exception:  # noqa: BLE001 — any import failure means no pallas
-    _HAVE_PALLAS = False
-
-pytestmark = pytest.mark.skipif(
-    not _HAVE_PALLAS,
-    reason="pltpu.CompilerParams unavailable (jax < 0.8): the pallas "
-           "kernels cannot build here")
-
-if _HAVE_PALLAS:
-    from horovod_tpu.ops.pallas_attention import flash_attention
+from horovod_tpu.ops.pallas_attention import flash_attention
 
 
 def _naive(q, k, v, causal):

@@ -10,17 +10,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-try:  # the whole parallel package needs jax >= 0.8's jax.shard_map
-    from jax import shard_map as _shard_map  # noqa: F401
-    _HAVE_SHARD_MAP = True
-except ImportError:
-    _HAVE_SHARD_MAP = False
-
-pytestmark = pytest.mark.skipif(
-    not _HAVE_SHARD_MAP,
-    reason="jax.shard_map unavailable (jax < 0.8): "
-           "horovod_tpu.parallel cannot import here")
-
 
 def test_pipeline_forward_matches_sequential():
     """parallel/pipeline.py (beyond reference — the reference has no PP

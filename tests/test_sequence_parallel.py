@@ -11,23 +11,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # the whole parallel package needs jax >= 0.8's jax.shard_map
-    from jax import shard_map as _shard_map  # noqa: F401
-    _HAVE_SHARD_MAP = True
-except ImportError:
-    _HAVE_SHARD_MAP = False
-
-pytestmark = pytest.mark.skipif(
-    not _HAVE_SHARD_MAP,
-    reason="jax.shard_map unavailable (jax < 0.8): "
-           "horovod_tpu.parallel cannot import here")
-
-if _HAVE_SHARD_MAP:
-    from horovod_tpu.parallel import (
-        make_moe_layer,
-        make_ring_attention,
-        make_ulysses_attention,
-    )
+from horovod_tpu.parallel import (
+    make_moe_layer,
+    make_ring_attention,
+    make_ulysses_attention,
+)
 
 
 def _ref_attention(q, k, v, causal):

@@ -1,0 +1,151 @@
+"""The kernels of the main path compile for the real chip at real widths.
+
+Ahead-of-time compiles for a DESCRIBED ``v5e:2x2`` (section 2 of the
+on-chip-measurement guide): the TPU's compiler is installed here and refuses
+what the chip's would refuse — a block that breaks the tiling, a kernel that
+wants more VMEM than it may have — which interpret-mode tests on the CPU
+cannot see. Nothing runs, so a pass says nothing about results or times; the
+numbers side is chip_smoke.py's. Skipped only where the topology cannot be
+described (no libtpu).
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from horovod_tpu.ops.pallas_attention import (flash_attention,
+                                              flash_attention_lse)
+from horovod_tpu.ops.pallas_norm import batch_norm_train
+from horovod_tpu.parallel import expert_parallel, make_ring_attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure: no TPU compiler
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    # A compile for a described chip is written to JAX's persistent cache
+    # but cannot be read back without the chip; keep it out of the cache.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _mosaic_calls(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text() \
+        .count("tpu_custom_call")
+
+
+def _on_chip(topo, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=SingleDeviceSharding(topo.devices[0]))
+
+
+def _flash_fwd(q, k, v):
+    return flash_attention(q, k, v, causal=True, block=512)
+
+
+def _flash_loss(q, k, v):
+    return _flash_fwd(q, k, v).astype(jnp.float32).sum()
+
+
+# bert_large() heads at the long-context and the dense training shapes.
+@pytest.mark.parametrize("shape", [(1, 4096, 16, 64), (8, 512, 16, 64)])
+@pytest.mark.parametrize("fn, calls", [
+    (_flash_fwd, 1),
+    (jax.grad(_flash_loss, argnums=(0, 1, 2)), 3),   # fwd, dq, dkv
+], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_compiles(topo, shape, fn, calls):
+    x = _on_chip(topo, shape)
+    assert _mosaic_calls(fn, x, x, x) == calls
+
+
+def test_flash_strict_mask_compiles(topo):
+    """mode="strict" (q > k), which only ring attention's striped layout
+    drives, with the cotangent on lse that the ring's merge feeds back."""
+    def loss(q, k, v):
+        o, lse = flash_attention_lse(q, k, v, mode="strict", block=512)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    x = _on_chip(topo, (1, 2048, 16, 64))
+    assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 3
+
+
+# First and last ResNet-50 stage activations at batch 128.
+@pytest.mark.parametrize("shape", [(128, 56, 56, 64), (128, 7, 7, 2048)])
+def test_batch_norm_train_compiles(topo, shape):
+    x = _on_chip(topo, shape)
+    g = _on_chip(topo, shape[-1:], jnp.float32)
+
+    def fwd(x, gamma, beta):
+        return batch_norm_train(x, gamma, beta, 1e-5, False)
+
+    def loss(x, gamma, beta):
+        return fwd(x, gamma, beta)[0].astype(jnp.float32).sum()
+
+    assert _mosaic_calls(fwd, x, g, g) == 1
+    assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), x, g, g) == 2
+
+
+def test_ring_attention_flash_compiles_on_four_chips(topo):
+    """Striped causal ring over a 4-device ``seq`` mesh: the kernel's
+    "diag" and "strict" modes under ``lax.cond``, K/V rotating on ICI."""
+    mesh = Mesh(np.array(topo.devices), ("seq",))
+    x = jax.ShapeDtypeStruct(
+        (1, 4096, 16, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, "seq", None, None)))
+    ring = make_ring_attention(mesh, axis="seq", causal=True, jit=False,
+                               layout="striped", inner="flash",
+                               inner_interpret=False, inner_block=512)
+
+    def loss(q, k, v):
+        return ring(q, k, v).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x) \
+        .compile().as_text()
+    assert text.count("tpu_custom_call") > 0
+    assert "collective-permute" in text
+
+
+def test_ragged_expert_dispatch_compiles_on_four_chips(topo):
+    """bench.py's MoE shapes (4096 tokens a chip, d=1024, d_ff=4096, 8
+    experts) through the ragged all-to-all on a 4-device ``expert`` mesh."""
+    mesh = Mesh(np.array(topo.devices), ("expert",))
+    T, D, F, E = 4 * 4096, 1024, 4096, 8
+    rows, experts = P("expert", None), P("expert", None, None)
+
+    @jax.jit
+    @jax.shard_map(mesh=mesh, in_specs=(rows, rows, experts, experts),
+                   out_specs=rows, check_vma=False)
+    def layer(x, logits, w_in, w_out):
+        def expert_fn(buf):
+            h = jax.nn.gelu(jnp.einsum("end,edf->enf", buf, w_in))
+            return jnp.einsum("enf,efd->end", h, w_out)
+
+        return expert_parallel.moe_dispatch_combine_ragged(
+            x, logits, expert_fn, "expert")[0]
+
+    def on(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    text = layer.lower(on((T, D), jnp.bfloat16, rows),
+                       on((T, E), jnp.float32, rows),
+                       on((E, D, F), jnp.bfloat16, experts),
+                       on((E, F, D), jnp.bfloat16, experts)) \
+        .compile().as_text()
+    assert "all-to-all" in text

@@ -119,14 +119,9 @@ if mode == "parity":
     # the validation rejects an impossible report.
     r0 = hvd.ep_stats()[0]
     hvd.ep_report(0.125, 64, 8)
-    try:  # the mesh package needs jax >= 0.8; fall back to the raw gauge
-        from horovod_tpu.parallel import report_dispatch
-    except ImportError:
-        report_dispatch = None
-    if report_dispatch is not None:
-        assert report_dispatch(0.25, 16) is True
-    else:
-        hvd.ep_report(0.25, 16, 4)
+    from horovod_tpu.parallel import report_dispatch
+
+    assert report_dispatch(0.25, 16) is True
     reports, tokens, dropped, last = hvd.ep_stats()
     assert reports == r0 + 2, (r0, reports)
     assert tokens >= 64 + 16 and dropped >= 8 + 4, (tokens, dropped)
