@@ -246,5 +246,5 @@ def tpu_built():
 
 from .ops import zerocopy as bridge  # noqa: E402  (hvd.bridge.stats / as_buffer)
 from . import elastic  # noqa: F401,E402  (hvd.elastic.run / State / ObjectState)
-from . import profiler  # noqa: F401,E402  (xplane trace windows + op ranges)
+from . import profiler  # noqa: F401,E402  (xplane trace windows)
 from . import observability  # noqa: F401,E402  (metrics / stall / spans)

@@ -183,11 +183,18 @@ def filter_specs(specs, mesh):
 # ---------------------------------------------------------------------------
 # Forward
 
+# The named scopes below (embed, layer_norm, attention, mlp, loss; and grad,
+# grad_reduce, optimizer in parallel/data_parallel.make_train_step) are
+# metadata only: they reach every HLO operation's op_name, so XProf's and
+# TensorBoard's op views group a step by them (docs/observability.md). They
+# change no instruction and no program name.
+
 def _layer_norm(x, p, eps=1e-5):
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.var(x, -1, keepdims=True)
-    y = (x - mu) * jax.lax.rsqrt(var + eps)
-    return y * p["scale"].astype(x.dtype) + p["bias"].astype(x.dtype)
+    with jax.named_scope("layer_norm"):
+        mu = jnp.mean(x, -1, keepdims=True)
+        var = jnp.var(x, -1, keepdims=True)
+        y = (x - mu) * jax.lax.rsqrt(var + eps)
+        return y * p["scale"].astype(x.dtype) + p["bias"].astype(x.dtype)
 
 
 def _attention_ring(x, layer, cfg, mesh, seq_spec):
@@ -383,18 +390,20 @@ def apply_block(layer, x, cfg: TransformerConfig, mesh=None, impl=None,
         impl = resolve_attn(cfg, x.shape[1], mesh)
 
     h = _layer_norm(x, layer["ln1"])
-    if (impl == "ring" and mesh is not None
-            and cfg.seq_axis in mesh.axis_names):
-        x = x + _attention_ring(h, layer, cfg, mesh, seq_spec)
-    elif impl == "flash":
-        x = x + _attention_flash(h, layer, cfg, mesh, seq_spec)
-    else:
-        x = x + _attention(h, layer, cfg, seq_spec, full_spec)
+    with jax.named_scope("attention"):
+        if (impl == "ring" and mesh is not None
+                and cfg.seq_axis in mesh.axis_names):
+            x = x + _attention_ring(h, layer, cfg, mesh, seq_spec)
+        elif impl == "flash":
+            x = x + _attention_flash(h, layer, cfg, mesh, seq_spec)
+        else:
+            x = x + _attention(h, layer, cfg, seq_spec, full_spec)
     h = _layer_norm(x, layer["ln2"])
-    if cfg.n_experts > 0:
-        x = x + _moe_ffn(h, layer, cfg)
-    else:
-        x = x + _ffn(h, layer, cfg)
+    with jax.named_scope("mlp"):
+        if cfg.n_experts > 0:
+            x = x + _moe_ffn(h, layer, cfg)
+        else:
+            x = x + _ffn(h, layer, cfg)
     return _constrain(x, seq_spec)
 
 
@@ -418,8 +427,9 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None,
         seq_spec = full_spec = None
 
     B, S = tokens.shape
-    x = params["embed"].astype(dt)[tokens]
-    x = x + params["pos_embed"].astype(dt)[:S][None]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]
+        x = x + params["pos_embed"].astype(dt)[:S][None]
     x = _constrain(x, seq_spec)
 
     impl = resolve_attn(cfg, S, mesh)
@@ -441,9 +451,11 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None,
 
 def _nll(hidden, targets, embed):
     """-log p(target) per position from pre-projection hidden states."""
-    logits = jnp.einsum("bsd,vd->bsv", hidden, embed.astype(hidden.dtype))
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-    return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+    with jax.named_scope("loss"):
+        logits = jnp.einsum("bsd,vd->bsv", hidden,
+                            embed.astype(hidden.dtype))
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+        return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
 
 
 def loss_fn(params, batch, cfg: TransformerConfig, mesh=None):

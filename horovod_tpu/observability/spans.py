@@ -9,9 +9,21 @@ anything wrapped in :func:`span`) in the same event schema, and
 Perfetto/chrome://tracing-loadable JSON, so host-plane C++ phases and
 Python framework time line up on a single timeline.
 
-Same off-by-default discipline as the metrics registry: recording is a
-no-op unless ``HVD_METRICS=1`` (or :func:`enable`), and the disabled
-:func:`span` returns a shared nullcontext — no clock read, no lock.
+:func:`span` is the one way the program opens a span, and one ``with``
+feeds two sinks:
+
+- the profiler: once ``jax`` is imported, a span is a
+  ``jax.profiler.TraceAnnotation(name, **args)``, so it lands on the
+  ``/host:CPU`` plane of the ``.xplane.pb`` on the clock of the device
+  planes. The open profiler session (``jax.profiler.start_trace``,
+  ``hvd.profiler.start``) is the switch: with none open the annotation is
+  a no-op inside the runtime. This module itself never imports jax.
+- the Chrome recorder below: off unless ``HVD_METRICS=1`` (or
+  ``metrics.enable()``), same discipline as the metrics registry — while
+  disabled no clock read, no lock, no allocation of its own.
+
+Names are stable and carry no per-call value: a value (rid, fill, step)
+goes in ``args``, never in the name.
 
 Event schema (the subset both Chrome and Perfetto accept):
 ``{"name", "ph": "X", "ts": µs, "dur": µs, "pid", "tid"}`` for spans and
@@ -22,6 +34,7 @@ merged files are homogeneous.
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 
@@ -40,10 +53,11 @@ class SpanRecorder:
         self.pid = os.getpid() if pid is None else pid
 
     @contextlib.contextmanager
-    def _span(self, name, cat, args):
+    def _span(self, name, cat, args, inner=_NOOP):
         t0 = time.perf_counter_ns()
         try:
-            yield
+            with inner:
+                yield
         finally:
             dur_us = (time.perf_counter_ns() - t0) // 1000
             ev = {"name": name, "ph": "X",
@@ -57,11 +71,15 @@ class SpanRecorder:
                 self._events.append(ev)
 
     def span(self, name, cat="python", **args):
-        """Context manager recording one complete event; the shared
-        no-op context while disabled."""
+        """Context manager for one span: a profiler annotation where jax
+        is loaded, one complete Chrome event too while metrics are
+        enabled, the shared no-op context where neither applies."""
+        profiler = sys.modules.get("jax.profiler")
+        annotation = (profiler.TraceAnnotation(name, **args)
+                      if profiler is not None else _NOOP)
         if not _metrics.enabled():
-            return _NOOP
-        return self._span(name, cat, args)
+            return annotation
+        return self._span(name, cat, args, annotation)
 
     def event(self, name, ts_us, dur_us, cat="python", **args):
         """Record one complete event with caller-supplied wall-clock

@@ -515,23 +515,24 @@ def remove_process_set_collective(process_set_id):
 
 
 # ---------------------------------------------------------------------------
-# Profiler ranges + observability instrumentation around the user-facing
-# op calls (reference: horovod/common/nvtx_op_range.h wraps every
-# Enqueue-level API call in an NVTX range for nsys; the TPU mapping is an
-# xplane TraceAnnotation — see horovod_tpu/profiler.py — plus this
-# build's metrics registry and Python-side stall inspector,
+# Spans + observability instrumentation around the user-facing op calls
+# (reference: horovod/common/nvtx_op_range.h wraps every Enqueue-level
+# API call in an NVTX range for nsys; the TPU mapping is the program's
+# span, observability/spans.py — an xplane TraceAnnotation wherever jax
+# is loaded, recorded while a profiler window is open — plus this build's
+# metrics registry and Python-side stall inspector,
 # horovod_tpu/observability/). Applied by rebinding so internal callers
 # (sync wrappers, grouped fan-out, the JAX bridge's callbacks) go through
-# it too. Disabled-path discipline: with HVD_PROFILER and HVD_METRICS
-# both off, a call costs two flag checks — no clock read, no nbytes
-# access, no lock, no jax import (guarded by
-# tests/test_observability.py).
+# it too. Disabled-path discipline: with HVD_METRICS off, a call costs
+# the span (a no-op annotation, or the shared null context without jax)
+# and one flag check — no clock read, no nbytes access, no lock, no jax
+# import (guarded by tests/test_observability.py).
 
 import functools
 import time as _time
 
-from .. import profiler as _profiler
 from ..observability import metrics as _obs_metrics
+from ..observability import spans as _obs_spans
 from ..observability import stall as _obs_stall
 
 # Positional index of `process_set` per instrumented op (grouped fan-out
@@ -551,7 +552,7 @@ def _instrumented(fn, op):
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
         if not _obs_metrics.enabled():
-            with _profiler.op_range(range_name):
+            with _obs_spans.span(range_name, cat="hvd"):
                 return fn(*args, **kwargs)
         nbytes = 0
         if has_tensor and args:
@@ -561,7 +562,7 @@ def _instrumented(fn, op):
             ps = args[ps_index] if len(args) > ps_index else 0
         t0 = _time.perf_counter()
         try:
-            with _profiler.op_range(range_name):
+            with _obs_spans.span(range_name, cat="hvd"):
                 result = fn(*args, **kwargs)
         finally:
             _obs_metrics.record_call(op, _time.perf_counter() - t0,
@@ -579,7 +580,7 @@ def _instrumented_synchronize(fn):
     @functools.wraps(fn)
     def wrapper(handle, *args, **kwargs):
         if not _obs_metrics.enabled():
-            with _profiler.op_range("hvd.synchronize"):
+            with _obs_spans.span("hvd.synchronize", cat="hvd"):
                 return fn(handle, *args, **kwargs)
         # A watcher-detected fatal stall surfaces here, on a thread that
         # can propagate it, instead of the job hanging forever.
@@ -587,7 +588,7 @@ def _instrumented_synchronize(fn):
         kind = getattr(handle, "kind", "group")
         t0 = _time.perf_counter()
         try:
-            with _profiler.op_range("hvd.synchronize"):
+            with _obs_spans.span("hvd.synchronize", cat="hvd"):
                 return fn(handle, *args, **kwargs)
         finally:
             _obs_metrics.record_call(kind + ".wait",

@@ -200,15 +200,20 @@ def make_train_step(loss_fn, tx, mesh, data_axis="data", extra_reduce=None,
         check_vma=False,
     )
     def step(params, opt_state, batch):
-        loss, grads = _shard_grad(params, batch)
-        if bucket_bytes > 0:
-            grads = _bucketed_grad_reduce(grads)
-        else:
-            grads = jax.tree.map(_grad_reduce_all, grads)
-        if extra_reduce is not None:
-            grads = extra_reduce(grads)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        # Named scopes are metadata for the profiler's op views
+        # (docs/observability.md); the compiled step is the same.
+        with jax.named_scope("grad"):
+            loss, grads = _shard_grad(params, batch)
+        with jax.named_scope("grad_reduce"):
+            if bucket_bytes > 0:
+                grads = _bucketed_grad_reduce(grads)
+            else:
+                grads = jax.tree.map(_grad_reduce_all, grads)
+            if extra_reduce is not None:
+                grads = extra_reduce(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, _pmean_all(loss)
 
     if jit:

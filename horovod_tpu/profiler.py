@@ -1,62 +1,30 @@
-"""Profiler ranges around user-facing op calls — the TPU mapping of the
-reference's NVTX integration (``horovod/common/nvtx_op_range.h``: an
-``NvtxOpRange`` around every ``EnqueueTensorAllreduce``-level API call so
-nsys traces show where framework time goes).
+"""Profiler trace windows — the TPU mapping of the reference's NVTX
+integration (``horovod/common/nvtx_op_range.h``: an ``NvtxOpRange`` around
+every ``EnqueueTensorAllreduce``-level API call so nsys traces show where
+framework time goes).
 
-On TPU the system profiler is XLA's xplane trace (``jax.profiler``), so:
-
-- :func:`start` / :func:`stop` open and close a trace window
-  (``jax.profiler.start_trace``/``stop_trace``; view in TensorBoard or
-  Perfetto) — the counterpart of running under nsys.
-- :func:`op_range` wraps the collective entry points in
-  :mod:`horovod_tpu.ops.collective_ops` with
-  ``jax.profiler.TraceAnnotation`` ranges named ``hvd.<op>``.
-
-Annotation is OFF unless ``HVD_PROFILER=1`` is set or :func:`start` has
-been called: the torch/TF bindings must not pay a jax import (nor
-per-call annotation overhead) when nobody is tracing, matching the
-reference's register-once-and-noop NVTX behavior when no collector is
-attached.
+On TPU the system profiler is XLA's xplane trace (``jax.profiler``):
+:func:`start` / :func:`stop` open and close a trace window (view it in
+TensorBoard or Perfetto) — the counterpart of running under nsys. The ranges
+themselves are the program's spans (:func:`horovod_tpu.observability.spans.span`:
+``hvd.<op>`` around every collective entry point, ``serve.*`` in the serve
+loop, ``ckpt.*``, ``elastic.reset``): each is a
+``jax.profiler.TraceAnnotation``, which the runtime records while a window is
+open and drops otherwise. There is no switch besides the window.
 """
-import contextlib
-import os
-
-_enabled = os.environ.get("HVD_PROFILER", "0") == "1"
-_active_logdir = None
-
-_NOOP = contextlib.nullcontext()
-
-
-def enabled():
-    return _enabled
 
 
 def start(logdir):
     """Begin an xplane trace window at ``logdir`` (reference analog: start
-    collecting under nsys). Enables op ranges for the rest of the process."""
-    global _enabled, _active_logdir
+    collecting under nsys)."""
     import jax
 
     jax.profiler.start_trace(str(logdir))
-    _enabled = True
-    _active_logdir = str(logdir)
-    return _active_logdir
+    return str(logdir)
 
 
 def stop():
     """Close the trace window opened by :func:`start`."""
-    global _active_logdir
     import jax
 
     jax.profiler.stop_trace()
-    _active_logdir = None
-
-
-def op_range(name):
-    """Context manager marking one user-facing op call (reference:
-    ``NVTX_OP_RANGE`` macro). A shared no-op when profiling is off."""
-    if not _enabled:
-        return _NOOP
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

@@ -241,7 +241,7 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
     return ck, cv, _layer_norm(x, params["final_ln"])
 
 
-def make_chunk_step(cfg, geo, mesh=None, q_len=None):
+def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
     """Compiled ``(params, cache, tokens, positions, block_tables,
     active) -> (cache, logits)`` — a ``q_len``-token window for every
     slot, the generalization of :func:`make_decode_step` to q_len > 1.
@@ -251,7 +251,10 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None):
     active: [B] bool. Returns logits for EVERY window position
     [B, q_len, vocab] (float32) — the caller picks the rows it trusts.
 
-    Two serving paths compile this one program (with their own shapes):
+    Two serving paths compile this one function, each with its own shapes
+    and under its own program name (``jit_<name>`` in a profiler trace:
+    ``jit_chunk`` for the fill, ``jit_spec`` for speculative scoring, so
+    that a reader of the trace can tell prefill work from decode work):
 
     - **chunked prefill** (B=1, q_len=prefill_chunk): a cache-miss
       suffix fills chunk-by-chunk across decode boundaries instead of
@@ -293,6 +296,7 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None):
         cv = _constrain(cv, mesh, kv_spec)
         return {"k": ck, "v": cv}, logits.astype(jnp.float32)
 
+    chunk.__name__ = chunk.__qualname__ = name
     return jax.jit(chunk, donate_argnums=(1,))
 
 

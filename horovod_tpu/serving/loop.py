@@ -26,11 +26,21 @@ TTFT = first_token_t - arrival_t per request; inter-token latency (ITL)
 = the gaps between a request's consecutive token timestamps. The
 summary reports p50/p99 over all requests' TTFTs and over ALL gaps.
 
-Every request also becomes one ``serve.request`` span (arrival →
-finish, with rid/tokens/ttft_ms args) on the observability timeline, so
-a merged trace shows request lifetimes above the per-step
-``serve.prefill`` / ``serve.chunk_prefill`` / ``serve.decode_step`` /
-``serve.spec_step`` spans.
+Spans (docs/serving.md has the table): every loop iteration is one
+``serve.boundary`` whose children are disjoint leaves that together
+cover it — ``serve.admit``; per engine call ``<p>`` (``prefill``,
+``bprefill``, ``chunk``, ``decode``, ``spec``) ``serve.<p>.pack``,
+``serve.<p>.dispatch`` (the asynchronous jitted call alone) and
+``serve.<p>.fetch`` (ends when the tokens are on the host);
+``serve.emit``; ``serve.report`` (the ``load_reporter`` hook alone);
+``serve.idle_wait``. All open through :meth:`ServeLoop._span`, which
+feeds the profiler (and the Chrome timeline under ``HVD_METRICS=1``)
+and adds each leaf's seconds to ``loop_stats["host_s"]`` by kind, so
+``hvd.serve_stats()["host_s"]`` splits the host's share of every
+boundary with no profiler attached. Every request also becomes one
+``serve.request`` event (arrival → finish, with rid/tokens/ttft_ms
+args) on the Chrome timeline, so a merged trace shows request lifetimes
+above the boundaries.
 
 Kill switches: ``HVD_SERVE_PREFIX_CACHE=0`` (or ``prefix_cache=False``)
 and ``spec_tokens=0`` restore the PR 14 paths exactly — no prefix /
@@ -38,6 +48,7 @@ speculation engine is even built and the new SERVE_* metrics see zero
 activity.
 """
 
+import contextlib
 import time
 
 import numpy as np
@@ -51,6 +62,11 @@ from .scheduler import (DEFAULT_KV_PAGES, DEFAULT_MAX_BATCH,
                         Request, serve_knobs)
 
 
+# The leaf kinds whose seconds ``loop_stats["host_s"]`` accumulates (the
+# last component of a leaf's name; ``serve.boundary`` and
+# ``serve.idle_wait`` are spans only).
+HOST_KINDS = ("admit", "pack", "dispatch", "fetch", "emit", "report")
+
 # Latest ServeLoop snapshot, surfaced as hvd.serve_stats() (same lazy
 # module-registry idiom as hvd.checkpoint_stats()).
 _LAST_STATS = {}
@@ -58,8 +74,9 @@ _LAST_STATS = {}
 
 def serve_stats():
     """Most recent ServeLoop boundary snapshot (empty dict before any
-    loop has run) — queue/fill/occupancy gauges plus the prefix-cache
-    and speculation counters."""
+    loop has run) — queue/fill/occupancy gauges, the prefix-cache and
+    speculation counters, and the host's seconds by leaf kind
+    (``host_s``) over ``boundaries`` loop iterations."""
     return dict(_LAST_STATS)
 
 
@@ -164,7 +181,7 @@ class ServeLoop:
             cfg, geo, mesh, q_len=self.prefill_chunk)
             if use_prefix else None)
         self.spec_fn = (engine.make_chunk_step(
-            cfg, geo, mesh, q_len=self.spec_tokens + 1)
+            cfg, geo, mesh, q_len=self.spec_tokens + 1, name="spec")
             if self.spec_tokens > 0 else None)
         self.drafter = drafter if drafter is not None \
             else speculate.NGramDrafter()
@@ -175,8 +192,24 @@ class ServeLoop:
                                          prefix_cache=self.prefix,
                                          spec_tokens=self.spec_tokens)
         self.loop_stats = {"prefill_single": 0, "prefill_batched": 0,
-                           "prefill_batch_calls": 0, "chunk_fills": 0}
+                           "prefill_batch_calls": 0, "chunk_fills": 0,
+                           "boundaries": 0,
+                           "host_s": dict.fromkeys(HOST_KINDS, 0.0)}
         self._fills = {}   # rid -> (admit_seq, tokens materialized)
+
+    @contextlib.contextmanager
+    def _span(self, name, **args):
+        """The one way the loop opens a span; a leaf of one of
+        ``HOST_KINDS`` also adds its seconds to ``host_s``."""
+        host_s = self.loop_stats["host_s"]
+        kind = name.rpartition(".")[2]
+        t0 = time.perf_counter()
+        try:
+            with _spans.span(name, cat="serve", **args):
+                yield
+        finally:
+            if kind in host_s:
+                host_s[kind] += time.perf_counter() - t0
 
     def warmup(self):
         """Compile every engine jit outside any measured window. Every
@@ -222,90 +255,99 @@ class ServeLoop:
     def _prefill(self, req):
         """Run the request's full (re-)prefill and return its next
         token — the counted singleton fallback path."""
-        ctx = list(req.prompt) + list(req.generated)
-        toks = np.zeros(self.geo.max_kv, np.int32)
-        toks[:len(ctx)] = ctx
-        bt = np.asarray(self.batcher.block_table(req, self.geo.max_blocks),
-                        np.int32)
-        with _spans.span("serve.prefill", cat="serve", rid=req.rid,
-                         context=len(ctx)):
+        with self._span("serve.prefill.pack"):
+            ctx = list(req.prompt) + list(req.generated)
+            toks = np.zeros(self.geo.max_kv, np.int32)
+            toks[:len(ctx)] = ctx
+            bt = np.asarray(
+                self.batcher.block_table(req, self.geo.max_blocks), np.int32)
+        with self._span("serve.prefill.dispatch", rid=req.rid,
+                        context=len(ctx)):
             self.cache, logits = self.prefill_fn(
                 self.params, self.cache, toks, np.int32(len(ctx)), bt)
         self.loop_stats["prefill_single"] += 1
-        return int(engine.greedy(logits))
+        with self._span("serve.prefill.fetch"):
+            return int(engine.greedy(logits))
 
     def _batched_prefill(self, group):
         """All of `group`'s full prefills in ONE padded call; returns
         {slot: first token}. Rows beyond the group are inactive (trash
         writes)."""
-        B, mb, pad = self.max_batch, self.geo.max_blocks, self.geo.max_kv
-        toks = np.zeros((B, pad), np.int32)
-        lengths = np.ones(B, np.int32)
-        tables = np.zeros((B, mb), np.int32)
-        active = np.zeros(B, bool)
-        for row, req in enumerate(group):
-            ctx = list(req.prompt) + list(req.generated)
-            toks[row, :len(ctx)] = ctx
-            lengths[row] = len(ctx)
-            tables[row] = self.batcher.block_table(req, mb)
-            active[row] = True
-        with _spans.span("serve.prefill", cat="serve", batched=len(group),
-                         context=int(lengths[:len(group)].sum())):
+        with self._span("serve.bprefill.pack"):
+            B, mb, pad = (self.max_batch, self.geo.max_blocks,
+                          self.geo.max_kv)
+            toks = np.zeros((B, pad), np.int32)
+            lengths = np.ones(B, np.int32)
+            tables = np.zeros((B, mb), np.int32)
+            active = np.zeros(B, bool)
+            for row, req in enumerate(group):
+                ctx = list(req.prompt) + list(req.generated)
+                toks[row, :len(ctx)] = ctx
+                lengths[row] = len(ctx)
+                tables[row] = self.batcher.block_table(req, mb)
+                active[row] = True
+        with self._span("serve.bprefill.dispatch", batched=len(group),
+                        context=int(lengths[:len(group)].sum())):
             self.cache, logits = self.bprefill_fn(
                 self.params, self.cache, toks, lengths, tables, active)
-        out = np.asarray(engine.greedy(logits))
         self.loop_stats["prefill_batched"] += len(group)
         self.loop_stats["prefill_batch_calls"] += 1
-        return {req.slot: int(out[row]) for row, req in enumerate(group)}
+        with self._span("serve.bprefill.fetch"):
+            out = np.asarray(engine.greedy(logits))
+            return {req.slot: int(out[row]) for row, req in enumerate(group)}
 
     def _chunk_fill(self, req):
         """Advance a prefix-hit request's suffix fill by ONE chunk.
         Returns (done, first_token_or_None); `done` means the whole
         context is materialized and the final chunk's last real
         position produced the request's next token."""
-        ctx = list(req.prompt) + list(req.generated)
-        target = len(ctx)
-        state = self._fills.get(req.rid)
-        filled = (state[1] if state is not None
-                  and state[0] == req.admit_seq else req.cached_tokens)
-        end = min(filled + self.prefill_chunk, target)
-        toks = np.zeros((1, self.prefill_chunk), np.int32)
-        toks[0, :end - filled] = ctx[filled:end]
-        bt = np.asarray(
-            self.batcher.block_table(req, self.geo.max_blocks),
-            np.int32)[None]
-        with _spans.span("serve.chunk_prefill", cat="serve", rid=req.rid,
-                         start=filled, end=end, target=target):
+        with self._span("serve.chunk.pack"):
+            ctx = list(req.prompt) + list(req.generated)
+            target = len(ctx)
+            state = self._fills.get(req.rid)
+            filled = (state[1] if state is not None
+                      and state[0] == req.admit_seq else req.cached_tokens)
+            end = min(filled + self.prefill_chunk, target)
+            toks = np.zeros((1, self.prefill_chunk), np.int32)
+            toks[0, :end - filled] = ctx[filled:end]
+            bt = np.asarray(
+                self.batcher.block_table(req, self.geo.max_blocks),
+                np.int32)[None]
+        with self._span("serve.chunk.dispatch", rid=req.rid, start=filled,
+                        end=end, target=target):
             self.cache, logits = self.chunk_fn(
                 self.params, self.cache, toks,
                 np.asarray([filled], np.int32), bt, np.ones(1, bool))
         self.loop_stats["chunk_fills"] += 1
         if end >= target:
             self._fills.pop(req.rid, None)
-            out = np.asarray(engine.greedy(logits))
-            return True, int(out[0, end - 1 - filled])
+            with self._span("serve.chunk.fetch"):
+                out = np.asarray(engine.greedy(logits))
+                return True, int(out[0, end - 1 - filled])
         self._fills[req.rid] = (req.admit_seq, end)
         return False, None
 
     def _decode(self, ready):
         """One jit'd decode step over the fully-prefilled slots; returns
         {slot: token}."""
-        B, mb = self.max_batch, self.geo.max_blocks
-        tokens = np.zeros(B, np.int32)
-        positions = np.zeros(B, np.int32)
-        tables = np.zeros((B, mb), np.int32)
-        active = np.zeros(B, bool)
-        for slot, req in ready.items():
-            tokens[slot] = req.generated[-1]
-            positions[slot] = req.context_len - 1
-            tables[slot] = self.batcher.block_table(req, mb)
-            active[slot] = True
-        with _spans.span("serve.decode_step", cat="serve",
-                         fill=self.batcher.batch_fill()):
+        with self._span("serve.decode.pack"):
+            B, mb = self.max_batch, self.geo.max_blocks
+            tokens = np.zeros(B, np.int32)
+            positions = np.zeros(B, np.int32)
+            tables = np.zeros((B, mb), np.int32)
+            active = np.zeros(B, bool)
+            for slot, req in ready.items():
+                tokens[slot] = req.generated[-1]
+                positions[slot] = req.context_len - 1
+                tables[slot] = self.batcher.block_table(req, mb)
+                active[slot] = True
+        with self._span("serve.decode.dispatch",
+                        fill=self.batcher.batch_fill()):
             self.cache, logits = self.decode_fn(
                 self.params, self.cache, tokens, positions, tables, active)
-        out = np.asarray(engine.greedy(logits))
-        return {s: int(out[s]) for s in ready}
+        with self._span("serve.decode.fetch"):
+            out = np.asarray(engine.greedy(logits))
+            return {s: int(out[s]) for s in ready}
 
     def _spec_decode(self, ready):
         """One speculative step over the fully-prefilled slots: draft k
@@ -313,41 +355,46 @@ class ServeLoop:
         target pass, resolve accept/reject host-side. Returns
         {slot: [accepted tokens + bonus]} — 1 to k+1 tokens per slot,
         bit-identical to what k+1 plain greedy steps would emit."""
-        B, mb = self.max_batch, self.geo.max_blocks
         k = self.spec_tokens
-        tokens = np.zeros((B, k + 1), np.int32)
-        positions = np.zeros(B, np.int32)
-        tables = np.zeros((B, mb), np.int32)
-        active = np.zeros(B, bool)
-        drafts = {}
-        for slot, req in ready.items():
-            ctx = list(req.prompt) + list(req.generated)
-            d = list(self.drafter.propose(ctx, k))[:k]
-            d += [0] * (k - len(d))   # padded lanes are just cheap guesses
-            drafts[slot] = d
-            tokens[slot] = [ctx[-1]] + d
-            positions[slot] = len(ctx) - 1
-            tables[slot] = self.batcher.block_table(req, mb)
-            active[slot] = True
-        with _spans.span("serve.spec_step", cat="serve", draft_k=k,
-                         fill=self.batcher.batch_fill()):
+        with self._span("serve.spec.pack"):
+            B, mb = self.max_batch, self.geo.max_blocks
+            tokens = np.zeros((B, k + 1), np.int32)
+            positions = np.zeros(B, np.int32)
+            tables = np.zeros((B, mb), np.int32)
+            active = np.zeros(B, bool)
+            drafts = {}
+            for slot, req in ready.items():
+                ctx = list(req.prompt) + list(req.generated)
+                d = list(self.drafter.propose(ctx, k))[:k]
+                d += [0] * (k - len(d))   # padded lanes: cheap guesses
+                drafts[slot] = d
+                tokens[slot] = [ctx[-1]] + d
+                positions[slot] = len(ctx) - 1
+                tables[slot] = self.batcher.block_table(req, mb)
+                active[slot] = True
+        with self._span("serve.spec.dispatch", draft_k=k,
+                        fill=self.batcher.batch_fill()):
             self.cache, logits = self.spec_fn(
                 self.params, self.cache, tokens, positions, tables, active)
-        out = np.asarray(engine.greedy(logits))        # [B, k+1]
-        result = {}
-        st = self.batcher.stats
-        for slot, req in ready.items():
-            emitted, _, rejected = speculate.accept_drafts(
-                drafts[slot], [int(x) for x in out[slot]])
-            # The request's remaining token budget (max_new and cache
-            # room) bounds what the boundary may consume.
-            room = min(req.max_new_tokens - len(req.generated),
-                       self.geo.max_kv - req.context_len)
-            emitted = emitted[:max(1, room)]
-            st["spec_steps"] += 1
-            st["spec_accepted"] += len(emitted) - 1
-            st["spec_rejected"] += rejected
-            result[slot] = emitted
+        with self._span("serve.spec.fetch"):
+            out = np.asarray(engine.greedy(logits))        # [B, k+1]
+        # Which of the scored tokens the boundary emits is the scheduler's
+        # decision, not the engine's: a ``serve.emit`` leaf of its own.
+        with self._span("serve.emit"):
+            result = {}
+            st = self.batcher.stats
+            for slot, req in ready.items():
+                emitted, _, rejected = speculate.accept_drafts(
+                    drafts[slot], [int(x) for x in out[slot]])
+                # The request's remaining token budget (max_new and cache
+                # room) bounds what the boundary may consume.
+                room = min(req.max_new_tokens - len(req.generated),
+                           self.geo.max_kv - req.context_len)
+                emitted = emitted[:max(1, room)]
+                st["spec_steps"] += 1
+                st["spec_accepted"] += len(emitted) - 1
+                st["spec_rejected"] += rejected
+                result[slot] = emitted
         return result
 
     # -- the loop ---------------------------------------------------------
@@ -368,7 +415,7 @@ class ServeLoop:
         finished = []
         prefilled = {}            # rid -> admit_seq at last prefill
         fill_samples, occ_samples = [], []
-        boundaries = 0
+        emits = 0                 # _emit calls: what report_interval counts
         wall_t0_us = time.time_ns() // 1000
         t0 = clock()
         preempt_seen = 0
@@ -379,7 +426,7 @@ class ServeLoop:
             return clock() - t0
 
         def _boundary(done, produced_at):
-            nonlocal preempt_seen, boundaries, pfx_evict_seen, spec_rej_seen
+            nonlocal preempt_seen, pfx_evict_seen, spec_rej_seen
             for req in done:
                 prefilled.pop(req.rid, None)
                 self._fills.pop(req.rid, None)
@@ -426,38 +473,46 @@ class ServeLoop:
                     spec_rej_seen = st["spec_rejected"]
             fill_samples.append(self.batcher.batch_fill())
             occ_samples.append(self.batcher.kv_occupancy())
-            boundaries += 1
             self._publish()
-            if (self.load_reporter is not None
-                    and boundaries % self.report_interval == 0):
-                self.load_reporter(self.batcher.queue_depth(),
-                                   self.batcher.batch_fill(),
-                                   self.batcher.kv_occupancy())
 
-        def _emit(by_slot):
+        def _emit(by_slot, newly_prefilled=()):
             """Feed produced tokens through the scheduler boundary with
-            timestamps for exactly the tokens the boundary will keep."""
-            t = _now()
-            rids = []
-            for s, toks in by_slot.items():
-                req = self.batcher.running[s]
-                rids.append(req.rid)
-                toks = [toks] if isinstance(toks, int) else toks
-                kept, gen = 0, len(req.generated)
-                for tok in toks:
-                    kept += 1
-                    gen += 1
-                    if tok == req.eos_id or gen >= req.max_new_tokens:
-                        break
-                token_times.setdefault(req.rid, []).extend([t] * kept)
-            done = self.batcher.on_tokens(by_slot, t)
-            _boundary(done, rids)
+            timestamps for exactly the tokens the boundary will keep;
+            then the user's hook, as a leaf of its own."""
+            nonlocal emits
+            with self._span("serve.emit"):
+                for req in newly_prefilled:
+                    prefilled[req.rid] = req.admit_seq
+                    self.batcher.register_prefilled(req)
+                t = _now()
+                rids = []
+                for s, toks in by_slot.items():
+                    req = self.batcher.running[s]
+                    rids.append(req.rid)
+                    toks = [toks] if isinstance(toks, int) else toks
+                    kept, gen = 0, len(req.generated)
+                    for tok in toks:
+                        kept += 1
+                        gen += 1
+                        if tok == req.eos_id or gen >= req.max_new_tokens:
+                            break
+                    token_times.setdefault(req.rid, []).extend([t] * kept)
+                done = self.batcher.on_tokens(by_slot, t)
+                _boundary(done, rids)
+            emits += 1
+            if (self.load_reporter is not None
+                    and emits % self.report_interval == 0):
+                with self._span("serve.report"):
+                    self.load_reporter(self.batcher.queue_depth(),
+                                       self.batcher.batch_fill(),
+                                       self.batcher.kv_occupancy())
 
-        while pending or not self.batcher.idle():
-            now = _now()
-            while pending and pending[0].arrival_t <= now:
-                self.batcher.submit(pending.pop(0), now)
-            self.batcher.admit(now)
+        def _one_boundary():
+            with self._span("serve.admit"):
+                now = _now()
+                while pending and pending[0].arrival_t <= now:
+                    self.batcher.submit(pending.pop(0), now)
+                self.batcher.admit(now)
             # Prefill anything (re-)admitted since its last prefill.
             # Cache-miss prompts (cached_tokens == 0) take the full
             # prefill — batched when several admitted at this boundary —
@@ -473,17 +528,10 @@ class ServeLoop:
                                key=lambda r: r.admit_seq)
                 if plain:
                     if self.bprefill_fn is not None and len(plain) > 1:
-                        by_slot = self._batched_prefill(plain)
-                        for r in plain:
-                            prefilled[r.rid] = r.admit_seq
-                            self.batcher.register_prefilled(r)
-                        _emit(by_slot)
+                        _emit(self._batched_prefill(plain), plain)
                     else:
                         req = plain[0]
-                        tok = self._prefill(req)
-                        prefilled[req.rid] = req.admit_seq
-                        self.batcher.register_prefilled(req)
-                        _emit({req.slot: tok})
+                        _emit({req.slot: self._prefill(req)}, [req])
                     continue
                 progressed = False
                 for req in sorted(todo, key=lambda r: r.admit_seq):
@@ -493,9 +541,7 @@ class ServeLoop:
                     progressed = True
                     done_fill, tok = self._chunk_fill(req)
                     if done_fill:
-                        prefilled[req.rid] = req.admit_seq
-                        self.batcher.register_prefilled(req)
-                        _emit({req.slot: tok})
+                        _emit({req.slot: tok}, [req])
                         break   # boundary may have changed the todo set
                 if not progressed:
                     break
@@ -508,8 +554,14 @@ class ServeLoop:
                     _emit(self._decode(ready))
             elif not self.batcher.running and pending:
                 # Idle until the next arrival (open loop: don't spin).
-                time.sleep(min(0.005,
-                               max(0.0, pending[0].arrival_t - _now())))
+                with self._span("serve.idle_wait"):
+                    time.sleep(min(0.005,
+                                   max(0.0, pending[0].arrival_t - _now())))
+
+        while pending or not self.batcher.idle():
+            self.loop_stats["boundaries"] += 1
+            with self._span("serve.boundary"):
+                _one_boundary()
 
         summary = self._summary(finished, token_times, _now(),
                                 fill_samples, occ_samples)
@@ -540,7 +592,7 @@ class ServeLoop:
             if st["spec_steps"] else 0.0,
             "spec_rejected": st["spec_rejected"],
         }
-        snap.update(self.loop_stats)
+        snap.update(self.loop_stats, host_s=dict(self.loop_stats["host_s"]))
         _LAST_STATS.clear()
         _LAST_STATS.update(snap)
 

@@ -175,11 +175,22 @@ def test_disabled_path_touches_no_lock():
     assert c.collect() == []  # nothing recorded
 
 
-def test_disabled_span_is_shared_nullcontext():
+def test_disabled_span_is_shared_nullcontext(monkeypatch):
+    """The one switch is the open profiler session: with none open and
+    HVD_METRICS unset a span is the runtime's own no-op annotation where
+    jax is loaded, the shared null context where it is not, and the
+    Chrome recorder sees nothing and takes no lock either way."""
+    import jax
+
     assert not metrics.enabled()
     real = spans.recorder._lock
     spans.recorder._lock = _PoisonLock()
     try:
+        cm = spans.span("y", step=1)
+        assert isinstance(cm, jax.profiler.TraceAnnotation)
+        with cm:
+            pass
+        monkeypatch.delitem(sys.modules, "jax.profiler")
         cm1 = spans.span("x")
         cm2 = spans.span("y", step=1)
         assert cm1 is cm2 is spans._NOOP  # no per-call allocation

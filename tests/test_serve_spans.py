@@ -1,0 +1,297 @@
+"""The serve loop's spans, read back from the profiler's own trace.
+
+A tiny model on CPU under ``jax.profiler.start_trace`` (no Python tracer):
+the ``.xplane.pb`` must hold every loop iteration as one ``serve.boundary``
+covered by disjoint leaves, on the loop's thread; the same ``with``
+statements feed ``hvd.serve_stats()["host_s"]`` always and the Chrome
+timeline under ``HVD_METRICS=1``. Also pins the names of the jitted programs
+that the benchmark's trace readers find by pattern.
+"""
+
+import glob
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.observability import metrics, spans
+from horovod_tpu.serving import engine, kv_cache
+from horovod_tpu.serving.loop import (HOST_KINDS, ServeLoop, poisson_requests,
+                                      serve_stats, shared_prefix_requests)
+
+LEAF = re.compile(r"^serve\.(admit|emit|report|idle_wait|"
+                  r"(prefill|bprefill|chunk|decode|spec)\."
+                  r"(pack|dispatch|fetch))$")
+CONFIGS = {"plain": {}, "spec": {"spec_tokens": 2}}
+
+
+def _requests(cfg):
+    """Misses admitted together (batched prefill), then hits on their
+    shared prefix (chunk fills), then one stranger alone (single prefill)
+    after a lull (idle wait)."""
+    rng = np.random.default_rng(7)
+    shared = shared_prefix_requests(7, 1e6, rng, prefix_len=24,
+                                    tail_len=(2, 8), max_new=(3, 6),
+                                    vocab=cfg.vocab_size)
+    (late,) = poisson_requests(1, 1e6, rng, prompt_len=(5, 9),
+                               max_new=(3, 4), vocab=cfg.vocab_size)
+    late.rid, late.arrival_t = 99, 0.35
+    return shared + [late]
+
+
+def _make_loop(**kw):
+    cfg = tfm.tiny()
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    reports = []
+    loop = ServeLoop(params, cfg, geo=kv_cache.geometry(64, 8, 64),
+                     max_batch=4, report_interval=1,
+                     load_reporter=lambda *a: reports.append(a), **kw)
+    loop.warmup()
+    return loop, cfg, reports
+
+
+def _host_events(logdir):
+    """-> {thread line: [(name, start_ns, end_ns, stats)]} of /host:CPU."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    (plane,) = [p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU"]
+    return {line.name: [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)) for ev in line.events]
+            for line in plane.lines}
+
+
+@pytest.fixture(scope="module", params=sorted(CONFIGS))
+def traced(request, tmp_path_factory):
+    """One traced run per loop configuration: the loop's thread's
+    ``serve.*`` events, the finished requests, the stats snapshot."""
+    assert not metrics.enabled()
+    spans.recorder.clear()         # another file's test may have left events
+    loop, cfg, reports = _make_loop(**CONFIGS[request.param])
+    logdir = str(tmp_path_factory.mktemp("profile_" + request.param))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    t0 = time.perf_counter()
+    try:
+        _, finished = loop.run(_requests(cfg))
+    finally:
+        wall_s = time.perf_counter() - t0
+        jax.profiler.stop_trace()
+    lines = {name: [e for e in evs if e[0].startswith("serve.")]
+             for name, evs in _host_events(logdir).items()}
+    (events,) = [evs for evs in lines.values() if evs]   # one thread only
+    return {"config": request.param, "events": sorted(events,
+                                                      key=lambda e: e[1]),
+            "finished": finished, "stats": serve_stats(), "wall_s": wall_s,
+            "reports": reports, "chrome": spans.recorder.events()}
+
+
+def _boundaries(events):
+    """-> [(boundary event, [its leaves in order])]."""
+    leaves = [e for e in events if LEAF.match(e[0])]
+    return [(b, [e for e in leaves if b[1] <= e[1] and e[2] <= b[2]])
+            for b in events if b[0] == "serve.boundary"]
+
+
+def test_every_boundary_is_covered_by_disjoint_leaves(traced):
+    events = traced["events"]
+    assert {e[0] for e in events} - {"serve.boundary"} == \
+        {e[0] for e in events if LEAF.match(e[0])}, "a span that is no leaf"
+    groups = _boundaries(events)
+    assert len(groups) == traced["stats"]["boundaries"] >= 8
+    # every leaf lies inside exactly one boundary
+    assert sum(len(leaves) for _, leaves in groups) == \
+        sum(1 for e in events if LEAF.match(e[0]))
+    gap_ns = dur_ns = 0
+    for (_, b0, b1, _), leaves in groups:
+        assert leaves[0][0] == "serve.admit"
+        for prev, nxt in zip(leaves, leaves[1:]):
+            assert prev[2] <= nxt[1], (prev, nxt)        # no two overlap
+        dur_ns += b1 - b0
+        gap_ns += (b1 - b0) - sum(e[2] - e[1] for e in leaves)
+    # what no leaf covers is the interpreter between two `with` blocks
+    assert gap_ns < 0.05 * dur_ns, (gap_ns, dur_ns)
+    seen = {e[0].split(".")[1] for e in events if e[0].count(".") == 2}
+    want = {"plain": {"prefill", "bprefill", "chunk", "decode"},
+            "spec": {"prefill", "bprefill", "chunk", "spec"}}
+    assert seen == want[traced["config"]]
+    assert any(e[0] == "serve.idle_wait" for e in events)
+
+
+def test_each_fetch_follows_its_dispatch_and_report_follows_emit(traced):
+    for _, leaves in _boundaries(traced["events"]):
+        names = [e[0] for e in leaves]
+        for i, name in enumerate(names):
+            call, _, kind = name[len("serve."):].partition(".")
+            if kind == "fetch":
+                # pack, dispatch, fetch of one engine call, in that order
+                assert names[i - 2:i] == [f"serve.{call}.pack",
+                                          f"serve.{call}.dispatch"], names
+                assert leaves[i][1] >= leaves[i - 1][2]
+            if name == "serve.report":
+                assert names[i - 1] == "serve.emit", names
+    # the hook is called once per emit, inside serve.report and nowhere else
+    n_report = sum(e[0] == "serve.report" for e in traced["events"])
+    assert n_report == len(traced["reports"]) > 0
+
+
+def test_span_names_carry_no_value_and_args_become_stats(traced):
+    events = traced["events"]
+    for name in {e[0] for e in events}:
+        assert re.fullmatch(r"[a-z_.]+", name), name     # no digit, # or =
+    stats = {}
+    for name, _, _, st in events:
+        stats.setdefault(name, set()).update(st)
+    step = "decode" if traced["config"] == "plain" else "spec"
+    assert "fill" in stats[f"serve.{step}.dispatch"]
+    assert {"rid", "context"} <= stats["serve.prefill.dispatch"]
+    assert {"batched", "context"} <= stats["serve.bprefill.dispatch"]
+    assert {"rid", "start", "end", "target"} <= stats["serve.chunk.dispatch"]
+    if step == "spec":
+        assert "draft_k" in stats["serve.spec.dispatch"]
+
+
+def test_host_seconds_by_kind_stay_inside_the_wall_time(traced):
+    assert not traced["chrome"], "HVD_METRICS unset: the Chrome sink is off"
+    host_s = traced["stats"]["host_s"]
+    assert tuple(host_s) == HOST_KINDS == (
+        "admit", "pack", "dispatch", "fetch", "emit", "report")
+    assert all(v > 0 for v in host_s.values())
+    assert sum(host_s.values()) <= traced["wall_s"]
+    # the counter and the trace time the same `with` blocks
+    by_kind = dict.fromkeys(HOST_KINDS, 0.0)
+    for name, s, e, _ in traced["events"]:
+        kind = name.rpartition(".")[2]
+        if kind in by_kind:
+            by_kind[kind] += (e - s) * 1e-9
+    for kind in HOST_KINDS:
+        assert host_s[kind] == pytest.approx(by_kind[kind], rel=0.25,
+                                             abs=2e-3), kind
+    assert len(traced["finished"]) == 8
+
+
+def test_fetch_closes_with_the_token_on_the_host(monkeypatch):
+    """``serve.<p>.dispatch`` closes on the enqueue; ``serve.<p>.fetch``
+    opens after it and closes only when the token has been copied to the
+    host. Order of events in the loop's thread, without a profiler."""
+    order = []
+    real_span, real_greedy = spans.span, engine.greedy
+
+    class Tokens:
+        def __init__(self, arr):
+            self.arr = arr
+
+        def _host(self):
+            out = np.asarray(self.arr)      # waits for the device, copies
+            order.append("on_host")
+            return out
+
+        def __array__(self, *a, **k):
+            return self._host()
+
+        def __int__(self):
+            return int(self._host())
+
+    class Recorded:
+        def __init__(self, name, cm):
+            self.name, self.cm = name, cm
+
+        def __enter__(self):
+            order.append("open " + self.name)
+            return self.cm.__enter__()
+
+        def __exit__(self, *exc):
+            order.append("close " + self.name)
+            return self.cm.__exit__(*exc)
+
+    def greedy(logits):
+        order.append("greedy")
+        return Tokens(real_greedy(logits))
+
+    monkeypatch.setattr(spans, "span", lambda name, **kw: Recorded(
+        name, real_span(name, **kw)))
+    monkeypatch.setattr(engine, "greedy", greedy)
+    loop, cfg, _ = _make_loop()
+    order.clear()                                     # drop the warm-up
+    loop.run(_requests(cfg))
+    calls = [i for i, x in enumerate(order) if x == "greedy"]
+    assert len(calls) > 10
+    for i in calls:
+        call = order[i - 1][len("open serve."):-len(".fetch")]
+        assert order[i - 2:i + 3] == [
+            f"close serve.{call}.dispatch", f"open serve.{call}.fetch",
+            "greedy", "on_host", f"close serve.{call}.fetch"], order[i - 2:
+                                                                     i + 3]
+
+
+def test_chrome_sink_gets_the_same_names(traced):
+    """One ``with`` feeds both sinks: under HVD_METRICS=1 the Chrome
+    timeline holds the names the profiler's trace holds, plus one
+    ``serve.request`` per finished request."""
+    metrics.REGISTRY.clear()
+    spans.recorder.clear()
+    metrics.enable()
+    try:
+        loop, cfg, _ = _make_loop(**CONFIGS[traced["config"]])
+        loop.run(_requests(cfg))
+        chrome = spans.recorder.events()
+    finally:
+        metrics.disable()
+        metrics.REGISTRY.clear()
+        spans.recorder.clear()
+    names = {e["name"] for e in chrome}
+    assert names == {e[0] for e in traced["events"]} | {"serve.request"}
+    assert sum(e["name"] == "serve.request" for e in chrome) == 8
+    assert all(e["cat"] == "serve" for e in chrome)
+    dispatch = next(e for e in chrome
+                    if e["name"] == "serve.bprefill.dispatch")
+    assert dispatch["args"]["batched"] > 1
+
+
+def test_program_names_the_trace_readers_depend_on():
+    """``benchmark/layer_metrics`` finds device programs as
+    ``^jit_<name>\\b`` on the trace's ``XLA Modules`` line; the speculative
+    verify step is a program of its own, not ``jit_chunk``."""
+    import optax
+    from jax.sharding import Mesh
+
+    from horovod_tpu.parallel.data_parallel import make_train_step
+
+    def module(fn, *args):
+        return re.search(r"module @(\w+)", fn.lower(*args).as_text()).group(1)
+
+    cfg = tfm.tiny()
+    geo = kv_cache.geometry(16, 8, 32)
+    B, mb, k = 2, geo.max_blocks, 2
+    params = jax.eval_shape(lambda key: tfm.init_params(key, cfg),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: kv_cache.make_cache(cfg, geo, None))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    slots = (i32(B), i32(B, mb), jax.ShapeDtypeStruct((B,), jnp.bool_))
+    assert module(engine.make_prefill(cfg, geo), params, cache,
+                  i32(geo.max_kv), i32(), i32(mb)) == "jit_prefill"
+    assert module(engine.make_batched_prefill(cfg, geo), params, cache,
+                  i32(B, geo.max_kv), *slots) == "jit_bprefill"
+    assert module(engine.make_decode_step(cfg, geo, None, B), params, cache,
+                  i32(B), *slots) == "jit_decode"
+    assert module(engine.make_chunk_step(cfg, geo, q_len=8), params, cache,
+                  i32(B, 8), *slots) == "jit_chunk"
+    loop = ServeLoop(params, cfg, geo=geo, max_batch=B, spec_tokens=k)
+    assert module(loop.spec_fn, params, cache, i32(B, k + 1),
+                  *slots) == "jit_spec"
+    assert module(loop.chunk_fn, params, cache, i32(1, loop.prefill_chunk),
+                  i32(1), i32(1, mb),
+                  jax.ShapeDtypeStruct((1,), jnp.bool_)) == "jit_chunk"
+    tx = optax.sgd(0.1)
+    step = make_train_step(lambda p, b: tfm.loss_fn(p, b, cfg), tx,
+                           Mesh(np.array(jax.devices()[:1]), ("data",)))
+    assert module(step, params, jax.eval_shape(tx.init, params),
+                  {"tokens": i32(2, 9)}) == "jit_step"

@@ -1,8 +1,10 @@
-"""Worker: profiler op ranges + trace window (reference:
-nvtx_op_range.h — ranges around user-facing op calls; TPU mapping is the
-xplane trace via jax.profiler). HVD_PROFILER=1 in the env: every
-collective call runs inside a TraceAnnotation, and rank 0 opens a trace
-window around a few steps and asserts the xplane artifact lands."""
+"""Worker: profiler ranges + trace window (reference: nvtx_op_range.h —
+ranges around user-facing op calls; TPU mapping is the xplane trace via
+jax.profiler). The open window is the only switch: rank-local collectives
+before, inside and after a ``hvd.profiler.start/stop`` window, then the
+xplane artifact is read back — the ranges inside are there, under stable
+names, and those outside are not."""
+import collections
 import glob
 import os
 
@@ -12,7 +14,9 @@ import horovod_tpu as hvd
 
 hvd.init()
 r, s = hvd.rank(), hvd.size()
-assert hvd.profiler.enabled()
+
+# No window open: the range is a no-op inside the runtime.
+hvd.broadcast(np.ones(4, np.float32), 0, name="prof.before")
 
 logdir = os.environ["PROFILE_DIR"] + f"/rank{r}"
 hvd.profiler.start(logdir)
@@ -31,6 +35,17 @@ assert traces, f"no xplane trace under {logdir}"
 # relative to correctness).
 out = hvd.allreduce(np.ones(8, np.float32), op=hvd.Sum, name="prof.after")
 assert np.allclose(out, s)
+
+from jax.profiler import ProfileData
+
+seen = collections.Counter(
+    ev.name for plane in ProfileData.from_file(traces[-1]).planes
+    if plane.name == "/host:CPU" for line in plane.lines
+    for ev in line.events if ev.name.startswith("hvd."))
+assert seen["hvd.allreduce"] == 3, seen      # the one after is not there
+assert seen["hvd.allgather"] == 1, seen
+assert seen["hvd.broadcast"] == 0, seen      # nor the one before
+assert seen["hvd.synchronize"] >= 4, seen
 hvd.barrier()
 hvd.shutdown()
 print(f"PROFILER rank={r} traces={len(traces)} OK", flush=True)
