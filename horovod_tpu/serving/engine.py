@@ -22,6 +22,16 @@ KV; there is nothing for flash's q-tiling to eliminate). That contract
 is exactly the heuristic fix this module forced (resolve_attn keyed on
 query length alone would also have misfiled long chunked prefills).
 
+The cache (:mod:`.kv_cache`) is one ``[n_pages, page, H*dh]`` array per
+layer for K and for V, the layout these programs compute in. Every
+program takes it donated, writes layer ``li`` with ``ck[li].at[page_ids,
+slot].set(k.reshape(..., H*dh))`` (prefill: whole pages at
+``block_table``), reads it with ``ck[li][block_tables]`` and reshapes the
+GATHERED pages to ``[B, max_kv, H, dh]`` — never the cache — so the
+compiled program scatters into its argument in place and holds no copy
+or slice of a layer's cache (tests/test_tpu_compile.py compiles all five
+for a described v5e and checks).
+
 The batch-slot ↔ request mapping, page ownership, and admission policy
 live host-side in :mod:`.scheduler`; this module never allocates.
 """
@@ -67,6 +77,28 @@ def _qkv(h, layer, cfg):
     return qkv[0], qkv[1], qkv[2]
 
 
+def _fused(x):
+    """[..., H, dh] -> [..., H*dh]: a token's K or V as the cache holds it."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def _gather_pages(layer_cache, block_tables, cfg):
+    """One layer's pages through the block tables: [n_pages, page, H*dh]
+    -> [B, max_kv, H, dh]. The block table IS the indirection that lets
+    every context length share one program; the reshape is of the
+    gathered pages, never of the cache."""
+    pages = layer_cache[block_tables]          # [B, max_blocks, page, H*dh]
+    return pages.reshape(pages.shape[0], -1, cfg.n_heads, cfg.head_dim)
+
+
+def _cache_out(ck, cv, mesh, cfg):
+    """The per-layer lists back in the cache's form, each array held to
+    its shard of the mesh."""
+    kv_spec = kv_cache.spec(cfg)
+    return {"k": tuple(_constrain(c, mesh, kv_spec) for c in ck),
+            "v": tuple(_constrain(c, mesh, kv_spec) for c in cv)}
+
+
 def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
     """Compiled ``(params, cache, tokens, length, block_table) ->
     (cache, logits)``.
@@ -92,27 +124,24 @@ def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
             f"geometry or raise max_seq_len")
     n_blocks = pad // geo.page_size
     dt = cfg.compute_dtype
-    kv_spec = kv_cache.spec(cfg)
 
     def prefill(params, cache, tokens, length, block_table):
         x = params["embed"].astype(dt)[tokens][None]
         x = x + params["pos_embed"].astype(dt)[:pad][None]
-        ck, cv = cache["k"], cache["v"]
+        ck, cv = list(cache["k"]), list(cache["v"])
         scale = 1.0 / math.sqrt(cfg.head_dim)
         mask = jnp.tril(jnp.ones((pad, pad), bool))
         for li, layer in enumerate(params["layers"]):
             h = _layer_norm(x, layer["ln1"])
             q, k, v = _qkv(h, layer, cfg)
-            # Page write: [1, pad, H, dh] -> [n_blocks, page, H, dh]
+            # Page write: [1, pad, H, dh] -> [n_blocks, page, H*dh]
             # scattered through the block table (garbage past `length`
             # lands in owned-page slots the decode mask hides, or in
             # trash page 0).
-            kp = k[0].reshape(n_blocks, geo.page_size,
-                              cfg.n_heads, cfg.head_dim)
-            vp = v[0].reshape(n_blocks, geo.page_size,
-                              cfg.n_heads, cfg.head_dim)
-            ck = ck.at[li, block_table[:n_blocks]].set(kp)
-            cv = cv.at[li, block_table[:n_blocks]].set(vp)
+            kp = k[0].reshape(n_blocks, geo.page_size, -1)
+            vp = v[0].reshape(n_blocks, geo.page_size, -1)
+            ck[li] = ck[li].at[block_table[:n_blocks]].set(kp)
+            cv[li] = cv[li].at[block_table[:n_blocks]].set(vp)
             # Causal self-attention — the exact _attention math from
             # models/transformer.py (parity is pinned by
             # tests/test_serving.py against forward()).
@@ -127,9 +156,7 @@ def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
         x = _layer_norm(x, params["final_ln"])
         last = jnp.take(x[0], length - 1, axis=0)
         logits = jnp.einsum("d,vd->v", last, params["embed"].astype(dt))
-        ck = _constrain(ck, mesh, kv_spec)
-        cv = _constrain(cv, mesh, kv_spec)
-        return {"k": ck, "v": cv}, logits.astype(jnp.float32)
+        return _cache_out(ck, cv, mesh, cfg), logits.astype(jnp.float32)
 
     return jax.jit(prefill, donate_argnums=(1,))
 
@@ -147,7 +174,6 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
     """
     _check_decode_impl(cfg, geo, mesh)
     dt = cfg.compute_dtype
-    kv_spec = kv_cache.spec(cfg)
     scale = 1.0 / math.sqrt(cfg.head_dim)
     max_kv = geo.max_kv
 
@@ -155,7 +181,7 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
         x = params["embed"].astype(dt)[tokens]
         x = x + params["pos_embed"].astype(dt)[positions]
         x = x[:, None, :]                                  # [B, 1, D]
-        ck, cv = cache["k"], cache["v"]
+        ck, cv = list(cache["k"]), list(cache["v"])
         blk = positions // geo.page_size
         slot = positions % geo.page_size
         page_ids = jnp.take_along_axis(block_tables, blk[:, None],
@@ -167,15 +193,10 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
         for li, layer in enumerate(params["layers"]):
             h = _layer_norm(x, layer["ln1"])
             q, k, v = _qkv(h, layer, cfg)                  # [B, 1, H, dh]
-            ck = ck.at[li, page_ids, slot_w].set(k[:, 0])
-            cv = cv.at[li, page_ids, slot_w].set(v[:, 0])
-            # Gather the slot's pages: [B, max_blocks, page, H, dh] ->
-            # [B, max_kv, H, dh]; the block table IS the indirection
-            # that lets every context length share this one program.
-            kp = ck[li][block_tables].reshape(
-                -1, max_kv, cfg.n_heads, cfg.head_dim)
-            vp = cv[li][block_tables].reshape(
-                -1, max_kv, cfg.n_heads, cfg.head_dim)
+            ck[li] = ck[li].at[page_ids, slot_w].set(_fused(k[:, 0]))
+            cv[li] = cv[li].at[page_ids, slot_w].set(_fused(v[:, 0]))
+            kp = _gather_pages(ck[li], block_tables, cfg)
+            vp = _gather_pages(cv[li], block_tables, cfg)
             logits = jnp.einsum("bshk,bthk->bhst", q, kp) * scale
             logits = jnp.where(kv_mask[:, :, None, :].swapaxes(1, 2),
                                logits, jnp.finfo(dt).min)
@@ -188,9 +209,7 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
         x = _layer_norm(x, params["final_ln"])
         logits = jnp.einsum("bsd,vd->bsv", x,
                             params["embed"].astype(dt))[:, 0]
-        ck = _constrain(ck, mesh, kv_spec)
-        cv = _constrain(cv, mesh, kv_spec)
-        return {"k": ck, "v": cv}, logits.astype(jnp.float32)
+        return _cache_out(ck, cv, mesh, cfg), logits.astype(jnp.float32)
 
     return jax.jit(decode, donate_argnums=(1,))
 
@@ -212,7 +231,7 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
     pe = jnp.clip(pos, 0, cfg.max_seq_len - 1)
     x = (params["embed"].astype(dt)[tokens]
          + params["pos_embed"].astype(dt)[pe])               # [B, Q, D]
-    ck, cv = cache["k"], cache["v"]
+    ck, cv = list(cache["k"]), list(cache["v"])
     blk = jnp.minimum(pos // geo.page_size, geo.max_blocks - 1)
     valid = (pos < max_kv) & active[:, None]
     page_ids = jnp.take_along_axis(block_tables, blk, axis=1)
@@ -223,12 +242,10 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
     for li, layer in enumerate(params["layers"]):
         h = _layer_norm(x, layer["ln1"])
         q, k, v = _qkv(h, layer, cfg)                        # [B, Q, H, dh]
-        ck = ck.at[li, page_ids, slot_w].set(k)
-        cv = cv.at[li, page_ids, slot_w].set(v)
-        kp = ck[li][block_tables].reshape(
-            -1, max_kv, cfg.n_heads, cfg.head_dim)
-        vp = cv[li][block_tables].reshape(
-            -1, max_kv, cfg.n_heads, cfg.head_dim)
+        ck[li] = ck[li].at[page_ids, slot_w].set(_fused(k))
+        cv[li] = cv[li].at[page_ids, slot_w].set(_fused(v))
+        kp = _gather_pages(ck[li], block_tables, cfg)
+        vp = _gather_pages(cv[li], block_tables, cfg)
         logits = jnp.einsum("bshk,bthk->bhst", q, kp) * scale
         logits = jnp.where(kv_mask[:, None, :, :], logits,
                            jnp.finfo(dt).min)
@@ -284,7 +301,6 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
     # Consulted for the same reason decode pins "gather": the chunk's
     # REAL (q_len, kv_len, causal) footprint decides the kernel tier.
     tfm.resolve_attn(cfg, q_len, mesh, kv_len=geo.max_kv, causal=True)
-    kv_spec = kv_cache.spec(cfg)
 
     def chunk(params, cache, tokens, positions, block_tables, active):
         ck, cv, x = _chunk_forward(params, cache, tokens, positions,
@@ -292,9 +308,7 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
                                    cfg=cfg, geo=geo, mesh=mesh)
         logits = jnp.einsum("bsd,vd->bsv", x,
                             params["embed"].astype(cfg.compute_dtype))
-        ck = _constrain(ck, mesh, kv_spec)
-        cv = _constrain(cv, mesh, kv_spec)
-        return {"k": ck, "v": cv}, logits.astype(jnp.float32)
+        return _cache_out(ck, cv, mesh, cfg), logits.astype(jnp.float32)
 
     chunk.__name__ = chunk.__qualname__ = name
     return jax.jit(chunk, donate_argnums=(1,))
@@ -323,7 +337,6 @@ def make_batched_prefill(cfg, geo, mesh=None, prefill_pad=None):
             f"{cfg.max_seq_len} (pos_embed rows); shrink the cache "
             f"geometry or raise max_seq_len")
     _check_decode_impl(cfg, geo, mesh)
-    kv_spec = kv_cache.spec(cfg)
 
     def bprefill(params, cache, tokens, lengths, block_tables, active):
         positions = jnp.zeros(tokens.shape[:1], jnp.int32)
@@ -334,9 +347,8 @@ def make_batched_prefill(cfg, geo, mesh=None, prefill_pad=None):
             x, jnp.clip(lengths - 1, 0, pad - 1)[:, None, None], axis=1)
         logits = jnp.einsum("bsd,vd->bsv", last,
                             params["embed"].astype(cfg.compute_dtype))
-        ck = _constrain(ck, mesh, kv_spec)
-        cv = _constrain(cv, mesh, kv_spec)
-        return {"k": ck, "v": cv}, logits[:, 0].astype(jnp.float32)
+        return (_cache_out(ck, cv, mesh, cfg),
+                logits[:, 0].astype(jnp.float32))
 
     return jax.jit(bprefill, donate_argnums=(1,))
 

@@ -1,29 +1,41 @@
 """Paged KV cache: the device-side half of the serving plane's memory.
 
-Geometry: two arrays per cache, ``[n_layers, n_pages, page_size,
-n_heads, head_dim]`` for K and V. A *page* holds ``page_size`` token
-slots; requests own pages through the numpy-side
+Geometry: for K and for V, one array per layer, ``[n_pages, page_size,
+n_heads * head_dim]``. A *page* holds ``page_size`` token slots; requests
+own pages through the numpy-side
 :class:`~horovod_tpu.serving.scheduler.PageAllocator` and reach them
 through per-request **block tables** (page-id lists), so the jit'd
 decode step (:mod:`.engine`) serves requests of any mix of lengths with
 one compiled program — the indirection, not padding, absorbs the length
 variance.
 
+The shape is the one the serving programs compute in, so that no program
+copies or slices the cache. The minor dimension is ``n_heads * head_dim``
+(heads major): a TPU tiles the two minor dimensions as (8 or 16, 128), and
+a minor dimension of ``head_dim`` = 64 fills half a lane tile, which made
+the compiler store a 5-D ``[layers, pages, page, heads, head_dim]`` cache
+pages-minor and copy all of it to a padded row-major layout and back
+around every program (half of a decode step on a v5e, PERF.md PR 26). One
+array a layer, because ``big[li]`` of one stacked array is a slice the
+compiler materialises; a layer's own array is scattered into in place
+(the programs donate the cache) and gathered from, nothing else.
+
 Page 0 is the **trash page**: the allocator never hands it out, and the
 engine routes every masked write there (inactive batch slots, padding
 positions), so the compiled scatter needs no branches.
 
-Tensor-parallel layout: heads ride the mesh's ``model`` axis — the SAME
-shard the attention weights already live on (models/transformer.py
-``param_specs``: wqkv column-parallel over heads), so a decode step's
-cache reads and writes are local to each TP shard and no K/V ever
-crosses the interconnect. ``spec()`` returns the PartitionSpec;
-:func:`make_cache` applies it when given a mesh.
+Tensor-parallel layout: the fused ``n_heads * head_dim`` dimension rides
+the mesh's ``model`` axis. Heads are its major part, so a shard of it is
+whole heads — the SAME heads the attention weights' shard produces
+(models/transformer.py ``param_specs``: wqkv column-parallel over heads),
+so a decode step's cache reads and writes are local to each TP shard and
+no K/V ever crosses the interconnect. ``spec()`` returns the
+PartitionSpec of one layer's array; :func:`make_cache` applies it when
+given a mesh.
 """
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -51,25 +63,24 @@ def geometry(n_pages, page_size, max_context):
 
 
 def spec(cfg):
-    """PartitionSpec of the K/V arrays: heads on the model axis (mirrors
-    wqkv's column-parallel head shard)."""
-    return P(None, None, None, cfg.model_axis, None)
+    """PartitionSpec of one layer's K or V array: the fused heads * head_dim
+    dimension on the model axis (heads major, so a shard holds whole heads
+    and mirrors wqkv's column-parallel head shard)."""
+    return P(None, None, cfg.model_axis)
 
 
 def make_cache(cfg, geo, mesh=None):
-    """Allocate the zeroed K/V arrays: {"k": [...], "v": [...]}, each
-    [n_layers, n_pages, page_size, n_heads, head_dim] in the model's
+    """Allocate the zeroed cache: {"k": (...), "v": (...)}, each a tuple of
+    n_layers arrays [n_pages, page_size, n_heads * head_dim] in the model's
     compute dtype. With a mesh, the arrays are placed sharded on the
     model axis (when that axis exists in the mesh)."""
-    shape = (cfg.n_layers, geo.n_pages, geo.page_size, cfg.n_heads,
-             cfg.head_dim)
-    k = jnp.zeros(shape, cfg.compute_dtype)
-    v = jnp.zeros(shape, cfg.compute_dtype)
+    shape = (geo.n_pages, geo.page_size, cfg.n_heads * cfg.head_dim)
+    sharding = None
     if mesh is not None and cfg.model_axis in mesh.axis_names:
-        sh = NamedSharding(mesh, spec(cfg))
-        k = jax.device_put(k, sh)
-        v = jax.device_put(v, sh)
-    return {"k": k, "v": v}
+        sharding = NamedSharding(mesh, spec(cfg))
+    return {name: tuple(jnp.zeros(shape, cfg.compute_dtype, device=sharding)
+                        for _ in range(cfg.n_layers))
+            for name in ("k", "v")}
 
 
 def cache_bytes(cfg, geo):

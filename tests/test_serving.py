@@ -164,6 +164,183 @@ def test_prefill_pad_validated():
 
 
 # ---------------------------------------------------------------------------
+# cache layout: per layer, [n_pages, page, H*dh]
+# ---------------------------------------------------------------------------
+
+def _ref_kv(params, cfg, seq):
+    """Every layer's K and V of ``seq`` from the model's own block,
+    [n_layers, S, H, dh]: what the cache must hold, computed without the
+    engine."""
+    x = (params["embed"][np.asarray(seq)] + params["pos_embed"][:len(seq)])
+    x = x[None].astype(cfg.compute_dtype)
+    ks, vs = [], []
+    for layer in params["layers"]:
+        h = tfm._layer_norm(x, layer["ln1"])
+        qkv = jnp.einsum("bsd,dchk->cbshk", h,
+                         layer["wqkv"].astype(cfg.compute_dtype))
+        ks.append(qkv[1, 0])
+        vs.append(qkv[2, 0])
+        x = tfm.apply_block(layer, x, cfg)
+    return np.stack(ks), np.stack(vs)
+
+
+def _as_5d(layers, cfg):
+    """The per-layer fused arrays read through the reference indexing
+    [layer, page, slot, head, dim] (heads are the fused dimension's major
+    part)."""
+    a = np.stack([np.asarray(x) for x in layers])
+    return a.reshape(*a.shape[:3], cfg.n_heads, cfg.head_dim)
+
+
+def test_make_cache_shape_and_bytes():
+    cfg = _cfg(n_layers=3)
+    geo = kv_cache.geometry(n_pages=16, page_size=8, max_context=64)
+    cache = kv_cache.make_cache(cfg, geo)
+    assert sorted(cache) == ["k", "v"]
+    for layers in cache.values():
+        assert len(layers) == cfg.n_layers
+        for a in layers:
+            assert a.shape == (16, 8, cfg.n_heads * cfg.head_dim)
+            assert a.dtype == cfg.compute_dtype
+            assert not np.asarray(a).any()
+    assert sum(a.nbytes for a in jax.tree.leaves(cache)) \
+        == kv_cache.cache_bytes(cfg, geo)
+    assert kv_cache.spec(cfg) == jax.sharding.PartitionSpec(
+        None, None, cfg.model_axis)
+
+
+def test_programs_share_one_cache_layout():
+    """Prefill, then decode, then a chunk window write the SAME pages, and
+    each reads what the others wrote. After every call the cache, read
+    through the 5-D reference indexing, holds the model's K/V at exactly
+    the positions written so far; every other slot of every owned page is
+    bit-identical to what it was before the call (trash page 0 takes the
+    masked writes); and the chunk's logits, which attend over K/V that all
+    three programs wrote, match the full forward."""
+    cfg = _cfg()
+    geo = kv_cache.geometry(n_pages=16, page_size=8, max_context=64)
+    params = tfm.init_params(jax.random.PRNGKey(4), cfg)
+    prefill = engine.make_prefill(cfg, geo)
+    decode = engine.make_decode_step(cfg, geo, max_batch=2)
+    chunk = engine.make_chunk_step(cfg, geo, q_len=4)
+    cache = kv_cache.make_cache(cfg, geo)
+
+    rng = np.random.default_rng(17)
+    seq = [int(x) for x in rng.integers(0, cfg.vocab_size, size=19)]
+    n_prompt, n_decode = 11, 4            # then one chunk window of 4
+    pages = [5, 2, 9]                     # out of order, never page 0
+    bt = np.asarray(pages + [0] * (geo.max_blocks - 3), np.int32)
+    ref_k, ref_v = _ref_kv(params, cfg, seq)
+    ref_logits = np.asarray(
+        tfm.forward(params, np.asarray([seq], np.int32), cfg)[0], np.float32)
+
+    def where(p):
+        return bt[p // geo.page_size], p % geo.page_size
+
+    def check(cache, before, written, new):
+        """``written`` positions hold the reference K/V; owned slots outside
+        ``new`` are bit-identical to ``before``."""
+        for name, ref in (("k", ref_k), ("v", ref_v)):
+            got = _as_5d(cache[name], cfg)
+            for p in written:
+                page, slot = where(p)
+                np.testing.assert_allclose(got[:, page, slot], ref[:, p],
+                                           rtol=1e-5, atol=1e-6)
+            if before is not None:
+                untouched = np.ones(got.shape[1:3], bool)
+                untouched[0] = False                       # trash page
+                for p in new:
+                    untouched[where(p)] = False
+                np.testing.assert_array_equal(
+                    got[:, untouched], _as_5d(before[name], cfg)[:, untouched])
+
+    def snapshot(cache):
+        # the programs donate the cache: keep host copies, not the arrays
+        return jax.tree.map(np.asarray, cache)
+
+    toks = np.zeros(geo.max_kv, np.int32)
+    toks[:n_prompt] = seq[:n_prompt]
+    cache, logits = prefill(params, cache, toks, np.int32(n_prompt), bt)
+    np.testing.assert_allclose(np.asarray(logits), ref_logits[n_prompt - 1],
+                               rtol=1e-4, atol=1e-5)
+    check(cache, None, range(n_prompt), ())
+    # Only the request's three pages and the trash page were written.
+    for layers in cache.values():
+        other = np.delete(_as_5d(layers, cfg), [0] + pages, axis=1)
+        assert not other.any()
+
+    for p in range(n_prompt, n_prompt + n_decode):
+        before = snapshot(cache)
+        cache, logits = decode(
+            params, cache, np.asarray([seq[p], 0], np.int32),
+            np.asarray([p, 0], np.int32),
+            np.stack([bt, np.zeros_like(bt)]), np.asarray([True, False]))
+        np.testing.assert_allclose(np.asarray(logits[0]), ref_logits[p],
+                                   rtol=1e-4, atol=1e-5)
+        check(cache, before, range(p + 1), [p])
+
+    start = n_prompt + n_decode
+    before = snapshot(cache)
+    cache, logits = chunk(
+        params, cache, np.asarray([seq[start:start + 4]], np.int32),
+        np.asarray([start], np.int32), bt[None], np.ones(1, bool))
+    np.testing.assert_allclose(np.asarray(logits[0]),
+                               ref_logits[start:start + 4],
+                               rtol=1e-4, atol=1e-5)
+    check(cache, before, range(len(seq)), range(start, start + 4))
+
+
+def test_cache_shard_holds_its_wqkv_shards_heads():
+    """Tensor parallel over ``model``: shard i of a layer's fused
+    ``H*dh`` dimension is the heads ``wqkv``'s shard i produces, so the
+    cache write and read stay local to the shard."""
+    n_model = 2
+    if jax.device_count() < n_model:
+        pytest.skip("needs 2 devices")
+    mesh = jax.sharding.Mesh(
+        np.asarray(jax.devices()[:n_model]).reshape(1, n_model),
+        ("data", "model"))
+    cfg = _cfg()
+    geo = kv_cache.geometry(n_pages=8, page_size=8, max_context=32)
+    params = tfm.init_params(jax.random.PRNGKey(2), cfg)
+    specs = tfm.filter_specs(tfm.param_specs(cfg), mesh)
+    sharded = jax.tree.map(
+        lambda x, sp: jax.device_put(
+            x, jax.sharding.NamedSharding(mesh, sp)), params, specs)
+    cache = kv_cache.make_cache(cfg, geo, mesh)
+    prefill = engine.make_prefill(cfg, geo, mesh)
+
+    seq = [int(x) for x in
+           np.random.default_rng(23).integers(0, cfg.vocab_size, size=13)]
+    bt = np.asarray([3, 6] + [0] * (geo.max_blocks - 2), np.int32)
+    toks = np.zeros(geo.max_kv, np.int32)
+    toks[:len(seq)] = seq
+    cache, logits = prefill(sharded, cache, toks, np.int32(len(seq)), bt)
+    np.testing.assert_allclose(np.asarray(logits),
+                               _ref_logits(params, cfg, seq),
+                               rtol=1e-4, atol=1e-5)
+
+    ref_k, _ = _ref_kv(params, cfg, seq)           # [L, S, H, dh]
+    heads = cfg.n_heads // n_model
+    width = heads * cfg.head_dim
+    for li, layer in enumerate(cache["k"]):
+        assert layer.sharding.spec == kv_cache.spec(cfg)
+        w_shards = {s.device: s.index[2]
+                    for s in sharded["layers"][li]["wqkv"].addressable_shards}
+        for shard in layer.addressable_shards:
+            own = w_shards[shard.device]            # this device's heads
+            assert own == slice(own.start, own.start + heads)
+            assert shard.index[2] == slice(own.start * cfg.head_dim,
+                                           own.start * cfg.head_dim + width)
+            data = np.asarray(shard.data)           # [pages, page, width]
+            for p in range(len(seq)):
+                got = data[bt[p // geo.page_size], p % geo.page_size]
+                np.testing.assert_allclose(
+                    got.reshape(heads, cfg.head_dim), ref_k[li, p, own],
+                    rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
 # resolve_attn: serving shapes (satellite)
 # ---------------------------------------------------------------------------
 

@@ -7,8 +7,14 @@ wants more VMEM than it may have — which interpret-mode tests on the CPU
 cannot see. Nothing runs, so a pass says nothing about results or times; the
 numbers side is chip_smoke.py's. Skipped only where the topology cannot be
 described (no libtpu).
+
+The serving programs are compiled too, at the benchmark's ``gpt2-large``
+geometry, for what their compiled text shows and no CPU test can: that the
+paged KV cache is stored in the layout the programs compute in, so that none
+of them copies or slices it (PERF.md, PR 26).
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -19,10 +25,12 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops.pallas_attention import (flash_attention,
                                               flash_attention_lse)
 from horovod_tpu.ops.pallas_norm import batch_norm_train
 from horovod_tpu.parallel import expert_parallel, make_ring_attention
+from horovod_tpu.serving import engine, kv_cache
 
 
 @pytest.fixture(scope="module")
@@ -149,3 +157,94 @@ def test_ragged_expert_dispatch_compiles_on_four_chips(topo):
                        on((E, F, D), jnp.bfloat16, experts)) \
         .compile().as_text()
     assert "all-to-all" in text
+
+
+# ---- the serving programs at benchmark/configs/gpt2-large.json's sizes -----
+
+def _gpt2_large():
+    return tfm.TransformerConfig(vocab_size=50257, d_model=1280, n_heads=20,
+                                 n_layers=36, d_ff=5120, max_seq_len=1024,
+                                 dtype="bfloat16")
+
+
+def _serve_program(name, cfg, geo, max_batch):
+    """(jitted program, shapes of its arguments after params and cache) as
+    ServeLoop builds and calls it."""
+    def slots(b, *q):
+        return [((b, *q), jnp.int32), ((b,), jnp.int32),
+                ((b, geo.max_blocks), jnp.int32), ((b,), jnp.bool_)]
+
+    if name == "prefill":
+        return engine.make_prefill(cfg, geo), [
+            ((geo.max_kv,), jnp.int32), ((), jnp.int32),
+            ((geo.max_blocks,), jnp.int32)]
+    if name == "bprefill":
+        return (engine.make_batched_prefill(cfg, geo),
+                slots(max_batch, geo.max_kv))
+    if name == "chunk":
+        q = 2 * geo.page_size
+        return engine.make_chunk_step(cfg, geo, q_len=q), slots(1, q)
+    if name == "spec":
+        return (engine.make_chunk_step(cfg, geo, q_len=4, name="spec"),
+                slots(max_batch, 4))
+    return engine.make_decode_step(cfg, geo, max_batch=max_batch), \
+        slots(max_batch)
+
+
+_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\((.*)$")
+# What may have a whole layer's cache as its result: the argument itself, a
+# free reinterpretation of it, the scatter that updates it in place (XLA:TPU
+# wraps it in a fusion of kind kCustom), and the memory-space assignment's
+# asynchronous move of a few layers into the chip's fast memory and back
+# (copy-start/-done, and ConcatBitcast over slice-done pieces). Anything
+# else -- copy, slice, a loop fusion -- materialises the cache anew.
+_IN_PLACE = {"parameter", "bitcast", "get-tuple-element", "scatter",
+             "copy-done", "custom-call"}
+
+
+def _cache_materialisations(text, cfg, geo):
+    """Instructions of a compiled program whose result is as large as one
+    layer's cache, has the cache's page dimension, and is not in place."""
+    layer = geo.n_pages * geo.page_size * cfg.n_heads * cfg.head_dim
+    page_dims = {geo.n_pages, geo.n_pages * geo.page_size}
+    found = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m or not m.group(1):
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        if int(np.prod(dims)) < layer or not page_dims & set(dims):
+            continue
+        op, rest = m.group(2), m.group(3)
+        if op in _IN_PLACE or (op == "fusion" and "kind=kCustom" in rest):
+            continue
+        found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("name, max_batch", [
+    ("prefill", 8), ("bprefill", 8), ("chunk", 8), ("spec", 8),
+    ("decode", 8), ("decode", 16)])
+def test_serving_program_never_copies_the_cache(topo, name, max_batch):
+    """Each of the five serving programs, at 36 layers x 20 heads x 64 and a
+    page of 16 with every slot at the full context of 1024: the cache comes
+    in, is scattered into in place and gathered from, and goes out. With the
+    5-D ``[layers, pages, page, heads, 64]`` cache each program opened and
+    closed with a copy of all of it to another layout, and sliced a layer
+    out 72 times (5.6e9 bytes of temporaries in decode)."""
+    cfg = _gpt2_large()
+    geo = kv_cache.geometry(max_batch * 64 + 1, 16, 1024)
+    fn, shapes = _serve_program(name, cfg, geo, max_batch)
+    params, cache = jax.tree.map(
+        lambda x: _on_chip(topo, x.shape, x.dtype),
+        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
+                                kv_cache.make_cache(cfg, geo))))
+    compiled = fn.lower(params, cache,
+                        *[_on_chip(topo, *s) for s in shapes]).compile()
+    assert _cache_materialisations(compiled.as_text(), cfg, geo) == []
+    memory = compiled.memory_analysis()
+    # Every layer's array is donated and aliased to its output.
+    assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
+    if name == "decode":
+        assert memory.temp_size_in_bytes < 1e9
