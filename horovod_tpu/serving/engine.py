@@ -14,6 +14,14 @@ Two compiled paths, compiled ONCE each regardless of the request mix:
   ``kv_pos <= position`` causal mask, next-token logits out. Inactive
   slots run the same program with their writes routed to trash page 0.
 
+Every path runs ``transformer.block``, the one block definition the
+trainer runs too: this module holds no copy of the forward pass, only
+what differs in serving, the attention over pages. A model with a learned
+position table adds its rows at each slot's positions; a RoPE model
+rotates Q and K there, and the cache holds the keys after the norm and the
+rotation. A model with experts returns its routing beside the logits
+(:func:`_result`).
+
 Both paths resolve their attention kernel through
 ``transformer.resolve_attn`` with the REAL (q_len, kv_len, causal)
 shape — the decode step is q_len=1 against ``max_kv`` cached tokens,
@@ -37,14 +45,12 @@ live host-side in :mod:`.scheduler`; this module never allocates.
 """
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import transformer as tfm
-from ..models.transformer import _ffn, _layer_norm, _moe_ffn
 from . import kv_cache
 
 
@@ -64,17 +70,14 @@ def _check_decode_impl(cfg, geo, mesh):
             f"resolved to {impl!r}; use attn_impl='auto' or 'gather'")
 
 
-def _ffn_block(x, layer, cfg):
-    h = _layer_norm(x, layer["ln2"])
-    if cfg.n_experts > 0:
-        return x + _moe_ffn(h, layer, cfg)
-    return x + _ffn(h, layer, cfg)
-
-
-def _qkv(h, layer, cfg):
-    qkv = jnp.einsum("bsd,dchk->cbshk", h,
-                     layer["wqkv"].astype(cfg.compute_dtype))
-    return qkv[0], qkv[1], qkv[2]
+def _check_positions(cfg, n, what):
+    """A learned position table has ``max_seq_len`` rows and no more; RoPE
+    has no table to run out of."""
+    if cfg.pos == "learned" and n > cfg.max_seq_len:
+        raise ValueError(
+            f"{what} {n} exceeds the model's max_seq_len "
+            f"{cfg.max_seq_len} (pos_embed rows); shrink the cache "
+            f"geometry or raise max_seq_len")
 
 
 def _fused(x):
@@ -99,6 +102,43 @@ def _cache_out(ck, cv, mesh, cfg):
             "v": tuple(_constrain(c, mesh, kv_spec) for c in cv)}
 
 
+def _layers(params, cache, x, positions, write, mask, valid, *, cfg, mesh):
+    """Every layer of the model over ``x [B, S, D]`` through
+    ``transformer.block``, the one block definition, with the serving
+    attention: layer ``li``'s new K/V (after the Q/K norm and the rotation
+    to ``positions [B, S]``, so the cache holds keys as attention reads
+    them) go into the cache by ``write(layer_cache, fused) ->
+    (layer_cache, k or v to attend over)``, then the window attends under
+    ``mask``. -> (ck, cv, x after the final norm, routing of the MoE
+    layers or None)."""
+    ck, cv = list(cache["k"]), list(cache["v"])
+    routings = []
+    for li, layer in enumerate(params["layers"]):
+        def attend(q, k, v, li=li):
+            ck[li], kk = write(ck[li], k)
+            cv[li], vv = write(cv[li], v)
+            return tfm.causal_attend(q, kk, vv, cfg, mask=mask)
+
+        x, routing = tfm.block(layer, x, cfg, attend, positions=positions,
+                               mesh=mesh, valid=valid)
+        routings.append(routing)
+    moe = None
+    if cfg.n_experts > 0:
+        moe = {name: jnp.stack([r[name] for r in routings])
+               for name in ("counts", "top")}
+    return ck, cv, tfm._norm(x, params["final_ln"], cfg), moe
+
+
+def _result(ck, cv, logits, moe, mesh, cfg):
+    """What every program returns: the cache and float32 logits; for a
+    model with experts also its routing, ``{"counts": [layers, E] pairs
+    each expert received from the live rows, "top": [layers, B, S, k]}``
+    (the loop fetches ``counts`` with the tokens; ``top`` stays on the
+    device unless someone asks)."""
+    out = (_cache_out(ck, cv, mesh, cfg), logits.astype(jnp.float32))
+    return out if moe is None else out + (moe,)
+
+
 def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
     """Compiled ``(params, cache, tokens, length, block_table) ->
     (cache, logits)``.
@@ -117,46 +157,32 @@ def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
     if pad % geo.page_size != 0:
         raise ValueError(f"prefill_pad {pad} must be a multiple of "
                          f"page_size {geo.page_size}")
-    if pad > cfg.max_seq_len:
-        raise ValueError(
-            f"prefill_pad {pad} exceeds the model's max_seq_len "
-            f"{cfg.max_seq_len} (pos_embed rows); shrink the cache "
-            f"geometry or raise max_seq_len")
+    _check_positions(cfg, pad, "prefill_pad")
     n_blocks = pad // geo.page_size
     dt = cfg.compute_dtype
 
     def prefill(params, cache, tokens, length, block_table):
-        x = params["embed"].astype(dt)[tokens][None]
-        x = x + params["pos_embed"].astype(dt)[:pad][None]
-        ck, cv = list(cache["k"]), list(cache["v"])
-        scale = 1.0 / math.sqrt(cfg.head_dim)
+        x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg)[None],
+                              params, cfg)
         mask = jnp.tril(jnp.ones((pad, pad), bool))
-        for li, layer in enumerate(params["layers"]):
-            h = _layer_norm(x, layer["ln1"])
-            q, k, v = _qkv(h, layer, cfg)
+
+        def write(layer_cache, kv):
             # Page write: [1, pad, H, dh] -> [n_blocks, page, H*dh]
             # scattered through the block table (garbage past `length`
             # lands in owned-page slots the decode mask hides, or in
-            # trash page 0).
-            kp = k[0].reshape(n_blocks, geo.page_size, -1)
-            vp = v[0].reshape(n_blocks, geo.page_size, -1)
-            ck[li] = ck[li].at[block_table[:n_blocks]].set(kp)
-            cv[li] = cv[li].at[block_table[:n_blocks]].set(vp)
-            # Causal self-attention — the exact _attention math from
-            # models/transformer.py (parity is pinned by
-            # tests/test_serving.py against forward()).
-            logits = jnp.einsum("bshk,bthk->bhst", q, k) * scale
-            logits = jnp.where(mask, logits, jnp.finfo(dt).min)
-            probs = jax.nn.softmax(logits.astype(jnp.float32),
-                                   -1).astype(dt)
-            ctx = jnp.einsum("bhst,bthk->bshk", probs, v)
-            x = x + jnp.einsum("bshk,hkd->bsd", ctx,
-                               layer["wo"].astype(dt))
-            x = _ffn_block(x, layer, cfg)
-        x = _layer_norm(x, params["final_ln"])
+            # trash page 0). The window attends over itself: causal
+            # self-attention, the exact math of transformer.forward
+            # (parity is pinned by tests/test_serving.py).
+            pages = kv[0].reshape(n_blocks, geo.page_size, -1)
+            return (layer_cache.at[block_table[:n_blocks]].set(pages), kv)
+
+        valid = (jnp.arange(pad) < length)[None]
+        ck, cv, x, moe = _layers(params, cache, x, None, write, mask, valid,
+                                 cfg=cfg, mesh=mesh)
         last = jnp.take(x[0], length - 1, axis=0)
-        logits = jnp.einsum("d,vd->v", last, params["embed"].astype(dt))
-        return _cache_out(ck, cv, mesh, cfg), logits.astype(jnp.float32)
+        logits = jnp.einsum("d,vd->v", last,
+                            tfm.head_weights(params, cfg).astype(dt))
+        return _result(ck, cv, logits, moe, mesh, cfg)
 
     return jax.jit(prefill, donate_argnums=(1,))
 
@@ -174,14 +200,12 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
     """
     _check_decode_impl(cfg, geo, mesh)
     dt = cfg.compute_dtype
-    scale = 1.0 / math.sqrt(cfg.head_dim)
     max_kv = geo.max_kv
 
     def decode(params, cache, tokens, positions, block_tables, active):
-        x = params["embed"].astype(dt)[tokens]
-        x = x + params["pos_embed"].astype(dt)[positions]
+        x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
+                              params, cfg, positions)
         x = x[:, None, :]                                  # [B, 1, D]
-        ck, cv = list(cache["k"]), list(cache["v"])
         blk = positions // geo.page_size
         slot = positions % geo.page_size
         page_ids = jnp.take_along_axis(block_tables, blk[:, None],
@@ -190,26 +214,19 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
         slot_w = jnp.where(active, slot, 0)
         kv_mask = (jnp.arange(max_kv)[None, None, :]
                    <= positions[:, None, None])            # [B, 1, KV]
-        for li, layer in enumerate(params["layers"]):
-            h = _layer_norm(x, layer["ln1"])
-            q, k, v = _qkv(h, layer, cfg)                  # [B, 1, H, dh]
-            ck[li] = ck[li].at[page_ids, slot_w].set(_fused(k[:, 0]))
-            cv[li] = cv[li].at[page_ids, slot_w].set(_fused(v[:, 0]))
-            kp = _gather_pages(ck[li], block_tables, cfg)
-            vp = _gather_pages(cv[li], block_tables, cfg)
-            logits = jnp.einsum("bshk,bthk->bhst", q, kp) * scale
-            logits = jnp.where(kv_mask[:, :, None, :].swapaxes(1, 2),
-                               logits, jnp.finfo(dt).min)
-            probs = jax.nn.softmax(logits.astype(jnp.float32),
-                                   -1).astype(dt)
-            ctx = jnp.einsum("bhst,bthk->bshk", probs, vp)
-            x = x + jnp.einsum("bshk,hkd->bsd", ctx,
-                               layer["wo"].astype(dt))
-            x = _ffn_block(x, layer, cfg)
-        x = _layer_norm(x, params["final_ln"])
+
+        def write(layer_cache, kv):                        # kv [B, 1, H, dh]
+            layer_cache = layer_cache.at[page_ids, slot_w].set(
+                _fused(kv[:, 0]))
+            return layer_cache, _gather_pages(layer_cache, block_tables, cfg)
+
+        ck, cv, x, moe = _layers(
+            params, cache, x, positions[:, None], write,
+            kv_mask[:, :, None, :].swapaxes(1, 2), active[:, None],
+            cfg=cfg, mesh=mesh)
         logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["embed"].astype(dt))[:, 0]
-        return _cache_out(ck, cv, mesh, cfg), logits.astype(jnp.float32)
+                            tfm.head_weights(params, cfg).astype(dt))[:, 0]
+        return _result(ck, cv, logits, moe, mesh, cfg)
 
     return jax.jit(decode, donate_argnums=(1,))
 
@@ -222,16 +239,13 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
     a ``kv_pos <= position`` mask. Within-window causality falls out of
     the same mask because the window's own K/V is written BEFORE the
     gather — position p sees cached history plus window positions
-    <= p. Returns (ck, cv, x[B, Q, D] after final_ln)."""
-    dt = cfg.compute_dtype
+    <= p. Returns (ck, cv, x[B, Q, D] after the final norm, routing)."""
     q_len = tokens.shape[1]
     max_kv = geo.max_kv
-    scale = 1.0 / math.sqrt(cfg.head_dim)
     pos = positions[:, None] + jnp.arange(q_len)[None, :]    # [B, Q]
     pe = jnp.clip(pos, 0, cfg.max_seq_len - 1)
-    x = (params["embed"].astype(dt)[tokens]
-         + params["pos_embed"].astype(dt)[pe])               # [B, Q, D]
-    ck, cv = list(cache["k"]), list(cache["v"])
+    x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
+                          params, cfg, pe)                   # [B, Q, D]
     blk = jnp.minimum(pos // geo.page_size, geo.max_blocks - 1)
     valid = (pos < max_kv) & active[:, None]
     page_ids = jnp.take_along_axis(block_tables, blk, axis=1)
@@ -239,23 +253,13 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
     slot_w = jnp.where(valid, pos % geo.page_size, 0)
     kv_mask = (jnp.arange(max_kv)[None, None, :]
                <= pos[:, :, None])                           # [B, Q, KV]
-    for li, layer in enumerate(params["layers"]):
-        h = _layer_norm(x, layer["ln1"])
-        q, k, v = _qkv(h, layer, cfg)                        # [B, Q, H, dh]
-        ck[li] = ck[li].at[page_ids, slot_w].set(_fused(k))
-        cv[li] = cv[li].at[page_ids, slot_w].set(_fused(v))
-        kp = _gather_pages(ck[li], block_tables, cfg)
-        vp = _gather_pages(cv[li], block_tables, cfg)
-        logits = jnp.einsum("bshk,bthk->bhst", q, kp) * scale
-        logits = jnp.where(kv_mask[:, None, :, :], logits,
-                           jnp.finfo(dt).min)
-        probs = jax.nn.softmax(logits.astype(jnp.float32),
-                               -1).astype(dt)
-        ctx = jnp.einsum("bhst,bthk->bshk", probs, vp)
-        x = x + jnp.einsum("bshk,hkd->bsd", ctx,
-                           layer["wo"].astype(dt))
-        x = _ffn_block(x, layer, cfg)
-    return ck, cv, _layer_norm(x, params["final_ln"])
+
+    def write(layer_cache, kv):                              # [B, Q, H, dh]
+        layer_cache = layer_cache.at[page_ids, slot_w].set(_fused(kv))
+        return layer_cache, _gather_pages(layer_cache, block_tables, cfg)
+
+    return _layers(params, cache, x, pos, write, kv_mask[:, None, :, :],
+                   valid, cfg=cfg, mesh=mesh)
 
 
 def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
@@ -273,15 +277,16 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
     ``jit_chunk`` for the fill, ``jit_spec`` for speculative scoring, so
     that a reader of the trace can tell prefill work from decode work):
 
-    - **chunked prefill** (B=1, q_len=prefill_chunk): a cache-miss
-      suffix fills chunk-by-chunk across decode boundaries instead of
-      monopolizing one with a full-width prefill. The chunk's live
-      score footprint [q_len, max_kv] is exactly what
-      ``transformer.resolve_attn`` tiers on — q_len is the knob that
-      walks this step from gather territory toward the flash
-      crossover, and the inline math below is the gather-tier kernel
-      (the einsum ``_attention`` parity path; on-TPU flash tiling of
-      the same mask is a drop-in behind the same signature).
+    - **chunked prefill** (B=1, q_len=prefill_chunk): a prompt fills
+      chunk-by-chunk across decode boundaries instead of monopolizing one
+      with a full-width prefill: the suffix of a prefix-cache hit, and
+      every prompt where the cache is too wide for the padded prefills
+      (``ServeLoop``). The chunk's live score footprint [q_len, max_kv]
+      is exactly what ``transformer.resolve_attn`` tiers on — q_len is
+      the knob that walks this step from gather territory toward the
+      flash crossover, and the math is the gather-tier kernel
+      (``transformer.causal_attend``; on-TPU flash tiling of the same
+      mask is a drop-in behind the same signature).
     - **speculative scoring** (B=max_batch, q_len=draft_k+1): one
       batched target pass scores ``[last_token, d_1..d_k]`` per slot;
       accept/reject happens host-side (:mod:`.speculate`).
@@ -294,21 +299,19 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
     q_len = geo.page_size if q_len is None else int(q_len)
     if q_len < 1:
         raise ValueError(f"chunk q_len must be >= 1, got {q_len}")
-    if geo.max_kv > cfg.max_seq_len:
-        raise ValueError(
-            f"cache width {geo.max_kv} exceeds the model's max_seq_len "
-            f"{cfg.max_seq_len} (pos_embed rows); shrink the geometry")
+    _check_positions(cfg, geo.max_kv, "cache width")
     # Consulted for the same reason decode pins "gather": the chunk's
     # REAL (q_len, kv_len, causal) footprint decides the kernel tier.
     tfm.resolve_attn(cfg, q_len, mesh, kv_len=geo.max_kv, causal=True)
 
     def chunk(params, cache, tokens, positions, block_tables, active):
-        ck, cv, x = _chunk_forward(params, cache, tokens, positions,
-                                   block_tables, active,
-                                   cfg=cfg, geo=geo, mesh=mesh)
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["embed"].astype(cfg.compute_dtype))
-        return _cache_out(ck, cv, mesh, cfg), logits.astype(jnp.float32)
+        ck, cv, x, moe = _chunk_forward(params, cache, tokens, positions,
+                                        block_tables, active,
+                                        cfg=cfg, geo=geo, mesh=mesh)
+        logits = jnp.einsum(
+            "bsd,vd->bsv", x,
+            tfm.head_weights(params, cfg).astype(cfg.compute_dtype))
+        return _result(ck, cv, logits, moe, mesh, cfg)
 
     chunk.__name__ = chunk.__qualname__ = name
     return jax.jit(chunk, donate_argnums=(1,))
@@ -331,24 +334,20 @@ def make_batched_prefill(cfg, geo, mesh=None, prefill_pad=None):
     if pad % geo.page_size != 0:
         raise ValueError(f"prefill_pad {pad} must be a multiple of "
                          f"page_size {geo.page_size}")
-    if pad > cfg.max_seq_len:
-        raise ValueError(
-            f"prefill_pad {pad} exceeds the model's max_seq_len "
-            f"{cfg.max_seq_len} (pos_embed rows); shrink the cache "
-            f"geometry or raise max_seq_len")
+    _check_positions(cfg, pad, "prefill_pad")
     _check_decode_impl(cfg, geo, mesh)
 
     def bprefill(params, cache, tokens, lengths, block_tables, active):
         positions = jnp.zeros(tokens.shape[:1], jnp.int32)
-        ck, cv, x = _chunk_forward(params, cache, tokens, positions,
-                                   block_tables, active,
-                                   cfg=cfg, geo=geo, mesh=mesh)
+        ck, cv, x, moe = _chunk_forward(params, cache, tokens, positions,
+                                        block_tables, active,
+                                        cfg=cfg, geo=geo, mesh=mesh)
         last = jnp.take_along_axis(
             x, jnp.clip(lengths - 1, 0, pad - 1)[:, None, None], axis=1)
-        logits = jnp.einsum("bsd,vd->bsv", last,
-                            params["embed"].astype(cfg.compute_dtype))
-        return (_cache_out(ck, cv, mesh, cfg),
-                logits[:, 0].astype(jnp.float32))
+        logits = jnp.einsum(
+            "bsd,vd->bsv", last,
+            tfm.head_weights(params, cfg).astype(cfg.compute_dtype))
+        return _result(ck, cv, logits[:, 0], moe, mesh, cfg)
 
     return jax.jit(bprefill, donate_argnums=(1,))
 
@@ -357,3 +356,11 @@ def make_batched_prefill(cfg, geo, mesh=None, prefill_pad=None):
 def greedy(logits):
     """Greedy next token per row (float32 logits [.., vocab])."""
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+@jax.jit
+def greedy_with_counts(logits, counts):
+    """The greedy tokens and a program's expert counts as ONE int32 vector
+    (tokens first), so that the host gets both with one transfer: a second
+    array costs a second round trip a boundary."""
+    return jnp.concatenate([greedy(logits).ravel(), counts.ravel()])

@@ -9,7 +9,11 @@ One iteration = one token boundary:
    prefilled in ONE batched call (``engine.make_batched_prefill``;
    singleton fallback counted); prefix-cache hits fill only their novel
    suffix, one ``prefill_chunk``-token chunk per boundary, so a long
-   cold prompt never monopolizes a decode boundary. Completing a
+   cold prompt never monopolizes a decode boundary. Where the cache is
+   wider than ``PADDED_PREFILL_MAX_KV`` the padded prefills are not even
+   built (their cost and their materialised scores are those of
+   ``max_kv``, whatever the prompt) and EVERY prompt fills by chunks, so
+   that its cost follows its length. Completing a
    prefill emits the request's first token — TTFT is arrival → that
    token, queueing and prefill included — and registers the prompt's
    pages in the prefix cache,
@@ -51,6 +55,7 @@ activity.
 import contextlib
 import time
 
+import jax
 import numpy as np
 
 from ..observability import metrics as _metrics
@@ -66,6 +71,15 @@ from .scheduler import (DEFAULT_KV_PAGES, DEFAULT_MAX_BATCH,
 # last component of a leaf's name; ``serve.boundary`` and
 # ``serve.idle_wait`` are spans only).
 HOST_KINDS = ("admit", "pack", "dispatch", "fetch", "emit", "report")
+
+# The widest cache for which the loop builds the two prefills padded to
+# ``max_kv``: each pays ``max_kv`` tokens and ``[rows, heads, max_kv,
+# max_kv]`` float32 scores for any prompt, 0.7 GB for 8 rows x 20 heads at
+# 1024, sixteen times that at 4096. Beyond it every prompt is chunk-filled.
+PADDED_PREFILL_MAX_KV = 1024
+# Tokens per chunk there (where only prefix-cache suffixes chunk-fill, two
+# pages): some hundreds, so that one pass over the weights serves many.
+LONG_PREFILL_CHUNK = 512
 
 # Latest ServeLoop snapshot, surfaced as hvd.serve_stats() (same lazy
 # module-registry idiom as hvd.checkpoint_stats()).
@@ -144,8 +158,14 @@ class ServeLoop:
       any ``propose(context, k)`` implementation (default
       :class:`~horovod_tpu.serving.speculate.NGramDrafter`).
     - ``prefill_chunk``: tokens per chunked-prefill call (default
-      2 pages); ``batch_prefill=False`` forces the per-request prefill
-      fallback (the counted A/B baseline).
+      2 pages, or ``LONG_PREFILL_CHUNK`` where every prompt is
+      chunk-filled); ``batch_prefill=False`` forces the per-request
+      prefill fallback (the counted A/B baseline).
+
+    Which programs exist follows from the geometry alone: a cache no wider
+    than ``PADDED_PREFILL_MAX_KV`` gets ``prefill_fn`` and ``bprefill_fn``
+    (padded to ``max_kv``) and chunk-fills only prefix-cache suffixes; a
+    wider one gets neither (both are None) and chunk-fills every prompt.
     """
 
     def __init__(self, params, cfg, geo=None, mesh=None,
@@ -169,17 +189,20 @@ class ServeLoop:
         self.mode = mode
         self.load_reporter = load_reporter
         self.report_interval = int(report_interval)
-        self.prefill_chunk = (min(geo.max_kv, 2 * geo.page_size)
-                              if prefill_chunk is None
-                              else int(prefill_chunk))
-        self.prefill_fn = engine.make_prefill(cfg, geo, mesh)
+        padded = geo.max_kv <= PADDED_PREFILL_MAX_KV
+        if prefill_chunk is None:
+            prefill_chunk = (2 * geo.page_size if padded
+                             else LONG_PREFILL_CHUNK)
+        self.prefill_chunk = min(geo.max_kv, int(prefill_chunk))
+        self.prefill_fn = (engine.make_prefill(cfg, geo, mesh)
+                           if padded else None)
         self.decode_fn = engine.make_decode_step(cfg, geo, mesh, max_batch)
         self.bprefill_fn = (engine.make_batched_prefill(cfg, geo, mesh)
-                            if batch_prefill and self.max_batch > 1
-                            else None)
+                            if padded and batch_prefill
+                            and self.max_batch > 1 else None)
         self.chunk_fn = (engine.make_chunk_step(
             cfg, geo, mesh, q_len=self.prefill_chunk)
-            if use_prefix else None)
+            if use_prefix or not padded else None)
         self.spec_fn = (engine.make_chunk_step(
             cfg, geo, mesh, q_len=self.spec_tokens + 1, name="spec")
             if self.spec_tokens > 0 else None)
@@ -196,6 +219,18 @@ class ServeLoop:
                            "boundaries": 0,
                            "host_s": dict.fromkeys(HOST_KINDS, 0.0)}
         self._fills = {}   # rid -> (admit_seq, tokens materialized)
+        # A model with experts: the (token, expert) pairs every program
+        # routed, by program kind and by (layer, expert). A program's
+        # counts wait on the device for the fetch of its tokens
+        # (``_moe_last``; packed with them into one transfer), those of a
+        # chunk whose tokens nobody fetches for the next fetch after it
+        # (``_moe_pending``).
+        self.moe = cfg.n_experts > 0
+        self._moe_last = None
+        self._moe_pending = []
+        self._moe_load = np.zeros((cfg.n_layers, max(cfg.n_experts, 1)),
+                                  np.int64)
+        self.moe_stats = {"pairs": {}, "expert_reads": {}, "calls": {}}
 
     @contextlib.contextmanager
     def _span(self, name, **args):
@@ -211,44 +246,69 @@ class ServeLoop:
             if kind in host_s:
                 host_s[kind] += time.perf_counter() - t0
 
+    def _call(self, kind, fn, *args):
+        """One engine program: the cache back in place, -> logits. A model
+        with experts also returns its routing; its counts are kept on the
+        device until :meth:`_fetch`."""
+        self.cache, logits, *routing = fn(self.params, self.cache, *args)
+        if routing:
+            if self._moe_last is not None:
+                self._moe_pending.append(self._moe_last)
+            self._moe_last = (kind, routing[0]["counts"])
+        return logits
+
+    def _fetch(self, logits):
+        """The greedy tokens of the last program on the host, and in the
+        same transfer its expert counts."""
+        if self._moe_last is None:
+            return np.asarray(engine.greedy(logits))
+        (kind, counts), self._moe_last = self._moe_last, None
+        pending, self._moe_pending = self._moe_pending, []
+        packed, earlier = jax.device_get(
+            (engine.greedy_with_counts(logits, counts),
+             [c for _, c in pending]))
+        n_tokens = packed.size - counts.size
+        mine = packed[n_tokens:].reshape(counts.shape)
+        for kind, c in [*zip((k for k, _ in pending), earlier),
+                        (kind, mine)]:                 # c: [layers, E]
+            for name, n in (("pairs", c.sum()), ("calls", 1),
+                            ("expert_reads", np.count_nonzero(c))):
+                by_kind = self.moe_stats[name]
+                by_kind[kind] = by_kind.get(kind, 0) + int(n)
+            self._moe_load += c
+        return packed[:n_tokens].reshape(logits.shape[:-1])
+
     def warmup(self):
         """Compile every engine jit outside any measured window. Every
         cache write routes to trash page 0 (all-zero block table,
         all-inactive batch), so the cache stays semantically untouched.
         bench.py calls this before starting the A/B clock so compile
         time never pollutes the throughput comparison."""
-        toks = np.zeros(self.geo.max_kv, np.int32)
-        bt = np.zeros(self.geo.max_blocks, np.int32)
-        self.cache, logits = self.prefill_fn(
-            self.params, self.cache, toks, np.int32(1), bt)
-        int(engine.greedy(logits))
         B, mb = self.max_batch, self.geo.max_blocks
-        self.cache, logits = self.decode_fn(
-            self.params, self.cache, np.zeros(B, np.int32),
-            np.zeros(B, np.int32), np.zeros((B, mb), np.int32),
-            np.zeros(B, bool))
-        np.asarray(engine.greedy(logits))
+
+        def slots(b, *q):
+            return (np.zeros((b, *q), np.int32), np.zeros(b, np.int32),
+                    np.zeros((b, mb), np.int32), np.zeros(b, bool))
+
+        if self.prefill_fn is not None:
+            self._fetch(self._call(
+                "prefill", self.prefill_fn,
+                np.zeros(self.geo.max_kv, np.int32), np.int32(1),
+                np.zeros(mb, np.int32)))
+        self._fetch(self._call("decode", self.decode_fn, *slots(B)))
         if self.bprefill_fn is not None:
-            self.cache, logits = self.bprefill_fn(
-                self.params, self.cache,
-                np.zeros((B, self.geo.max_kv), np.int32),
-                np.ones(B, np.int32), np.zeros((B, mb), np.int32),
-                np.zeros(B, bool))
-            np.asarray(engine.greedy(logits))
+            toks, _, tables, active = slots(B, self.geo.max_kv)
+            self._fetch(self._call("bprefill", self.bprefill_fn, toks,
+                                   np.ones(B, np.int32), tables, active))
         if self.chunk_fn is not None:
-            self.cache, logits = self.chunk_fn(
-                self.params, self.cache,
-                np.zeros((1, self.prefill_chunk), np.int32),
-                np.zeros(1, np.int32), np.zeros((1, mb), np.int32),
-                np.zeros(1, bool))
-            np.asarray(engine.greedy(logits))
+            self._fetch(self._call("chunk", self.chunk_fn,
+                                   *slots(1, self.prefill_chunk)))
         if self.spec_fn is not None:
-            self.cache, logits = self.spec_fn(
-                self.params, self.cache,
-                np.zeros((B, self.spec_tokens + 1), np.int32),
-                np.zeros(B, np.int32), np.zeros((B, mb), np.int32),
-                np.zeros(B, bool))
-            np.asarray(engine.greedy(logits))
+            self._fetch(self._call("spec", self.spec_fn,
+                                   *slots(B, self.spec_tokens + 1)))
+        # What the warm-up routed is not traffic.
+        self._moe_load[:] = 0
+        self.moe_stats = {"pairs": {}, "expert_reads": {}, "calls": {}}
 
     # -- per-request engine calls ----------------------------------------
 
@@ -263,11 +323,11 @@ class ServeLoop:
                 self.batcher.block_table(req, self.geo.max_blocks), np.int32)
         with self._span("serve.prefill.dispatch", rid=req.rid,
                         context=len(ctx)):
-            self.cache, logits = self.prefill_fn(
-                self.params, self.cache, toks, np.int32(len(ctx)), bt)
+            logits = self._call("prefill", self.prefill_fn, toks,
+                                np.int32(len(ctx)), bt)
         self.loop_stats["prefill_single"] += 1
         with self._span("serve.prefill.fetch"):
-            return int(engine.greedy(logits))
+            return int(self._fetch(logits))
 
     def _batched_prefill(self, group):
         """All of `group`'s full prefills in ONE padded call; returns
@@ -288,19 +348,21 @@ class ServeLoop:
                 active[row] = True
         with self._span("serve.bprefill.dispatch", batched=len(group),
                         context=int(lengths[:len(group)].sum())):
-            self.cache, logits = self.bprefill_fn(
-                self.params, self.cache, toks, lengths, tables, active)
+            logits = self._call("bprefill", self.bprefill_fn, toks,
+                                lengths, tables, active)
         self.loop_stats["prefill_batched"] += len(group)
         self.loop_stats["prefill_batch_calls"] += 1
         with self._span("serve.bprefill.fetch"):
-            out = np.asarray(engine.greedy(logits))
+            out = self._fetch(logits)
             return {req.slot: int(out[row]) for row, req in enumerate(group)}
 
     def _chunk_fill(self, req):
-        """Advance a prefix-hit request's suffix fill by ONE chunk.
-        Returns (done, first_token_or_None); `done` means the whole
-        context is materialized and the final chunk's last real
-        position produced the request's next token."""
+        """Advance a request's fill by ONE chunk: the suffix of a
+        prefix-cache hit, or (a cache too wide for the padded prefills)
+        any prompt from wherever its cached prefix ends. Returns
+        (done, first_token_or_None); `done` means the whole context is
+        materialized and the final chunk's last real position produced
+        the request's next token."""
         with self._span("serve.chunk.pack"):
             ctx = list(req.prompt) + list(req.generated)
             target = len(ctx)
@@ -315,14 +377,14 @@ class ServeLoop:
                 np.int32)[None]
         with self._span("serve.chunk.dispatch", rid=req.rid, start=filled,
                         end=end, target=target):
-            self.cache, logits = self.chunk_fn(
-                self.params, self.cache, toks,
-                np.asarray([filled], np.int32), bt, np.ones(1, bool))
+            logits = self._call("chunk", self.chunk_fn, toks,
+                                np.asarray([filled], np.int32), bt,
+                                np.ones(1, bool))
         self.loop_stats["chunk_fills"] += 1
         if end >= target:
             self._fills.pop(req.rid, None)
             with self._span("serve.chunk.fetch"):
-                out = np.asarray(engine.greedy(logits))
+                out = self._fetch(logits)
                 return True, int(out[0, end - 1 - filled])
         self._fills[req.rid] = (req.admit_seq, end)
         return False, None
@@ -343,10 +405,10 @@ class ServeLoop:
                 active[slot] = True
         with self._span("serve.decode.dispatch",
                         fill=self.batcher.batch_fill()):
-            self.cache, logits = self.decode_fn(
-                self.params, self.cache, tokens, positions, tables, active)
+            logits = self._call("decode", self.decode_fn, tokens,
+                                positions, tables, active)
         with self._span("serve.decode.fetch"):
-            out = np.asarray(engine.greedy(logits))
+            out = self._fetch(logits)
             return {s: int(out[s]) for s in ready}
 
     def _spec_decode(self, ready):
@@ -374,10 +436,10 @@ class ServeLoop:
                 active[slot] = True
         with self._span("serve.spec.dispatch", draft_k=k,
                         fill=self.batcher.batch_fill()):
-            self.cache, logits = self.spec_fn(
-                self.params, self.cache, tokens, positions, tables, active)
+            logits = self._call("spec", self.spec_fn, tokens, positions,
+                                tables, active)
         with self._span("serve.spec.fetch"):
-            out = np.asarray(engine.greedy(logits))        # [B, k+1]
+            out = self._fetch(logits)                      # [B, k+1]
         # Which of the scored tokens the boundary emits is the scheduler's
         # decision, not the engine's: a ``serve.emit`` leaf of its own.
         with self._span("serve.emit"):
@@ -517,14 +579,16 @@ class ServeLoop:
             # Cache-miss prompts (cached_tokens == 0) take the full
             # prefill — batched when several admitted at this boundary —
             # and each completion's token runs a boundary which may
-            # admit more, so rescan. Prefix hits advance ONE chunk per
-            # outer boundary (the `advanced` set) so a long suffix
+            # admit more, so rescan. Prefix hits (and, where the padded
+            # prefills do not exist, every prompt) advance ONE chunk per
+            # outer boundary (the `advanced` set) so a long fill
             # interleaves with decode steps instead of stalling them.
             advanced = set()
             while True:
                 todo = [r for r in self.batcher.running.values()
                         if prefilled.get(r.rid) != r.admit_seq]
-                plain = sorted((r for r in todo if r.cached_tokens == 0),
+                plain = sorted((r for r in todo if r.cached_tokens == 0
+                                and self.prefill_fn is not None),
                                key=lambda r: r.admit_seq)
                 if plain:
                     if self.bprefill_fn is not None and len(plain) > 1:
@@ -593,6 +657,16 @@ class ServeLoop:
             "spec_rejected": st["spec_rejected"],
         }
         snap.update(self.loop_stats, host_s=dict(self.loop_stats["host_s"]))
+        if self.moe:
+            ms, load = self.moe_stats, self._moe_load
+            steps = ms["calls"].get("decode", 0) * self.cfg.n_layers
+            snap["moe"] = {
+                **{name: dict(by_kind) for name, by_kind in ms.items()},
+                "experts_touched_mean": (
+                    ms["expert_reads"]["decode"] / steps if steps else 0.0),
+                "load_max_over_mean": (float(load.max() / load.mean())
+                                       if load.any() else 0.0),
+            }
         _LAST_STATS.clear()
         _LAST_STATS.update(snap)
 

@@ -40,6 +40,8 @@ Invariants (tests/test_serving_scheduler.py pins these):
 """
 
 
+import heapq
+
 class _Node:
     __slots__ = ("key", "page", "parent", "children", "last_used")
 
@@ -124,25 +126,35 @@ class PrefixCache:
         """Free up to ``n`` pages by dropping least-recently-used leaf
         nodes whose page is referenced ONLY by the cache (refcount 1).
         Freeing a leaf can make its parent evictable, so one call can
-        peel a whole cold branch. Returns the number of pages freed."""
+        peel a whole cold branch. Returns the number of pages freed.
+        One walk of the tree finds every evictable leaf; a heap then
+        hands them out oldest first (a parent joins it when its last
+        child goes), so a call costs the tree once, not once a page."""
+        if n <= 0:
+            return 0
+
+        def evictable(node):
+            return not node.children and self.alloc.refcount(node.page) == 1
+
+        heap, stack = [], list(self._root.children.values())
+        while stack:
+            node = stack.pop()
+            if node.children:
+                stack.extend(node.children.values())
+            elif evictable(node):
+                heap.append((node.last_used, id(node), node))
+        heapq.heapify(heap)
         freed = 0
-        while freed < max(0, n):
-            victim, oldest = None, None
-            stack = list(self._root.children.values())
-            while stack:
-                node = stack.pop()
-                if node.children:
-                    stack.extend(node.children.values())
-                elif self.alloc.refcount(node.page) == 1:
-                    if oldest is None or node.last_used < oldest:
-                        victim, oldest = node, node.last_used
-            if victim is None:
-                break
+        while freed < n and heap:
+            _, _, victim = heapq.heappop(heap)
             self.alloc.free([victim.page])
-            del victim.parent.children[victim.key]
+            parent = victim.parent
+            del parent.children[victim.key]
             self.stats["nodes"] -= 1
             self.stats["evictions"] += 1
             freed += 1
+            if parent is not self._root and evictable(parent):
+                heapq.heappush(heap, (parent.last_used, id(parent), parent))
         return freed
 
     # -- introspection ---------------------------------------------------

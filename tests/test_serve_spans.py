@@ -295,3 +295,59 @@ def test_program_names_the_trace_readers_depend_on():
                            Mesh(np.array(jax.devices()[:1]), ("data",)))
     assert module(step, params, jax.eval_shape(tx.init, params),
                   {"tokens": i32(2, 9)}) == "jit_step"
+
+
+def test_wide_cache_loop_spans_and_expert_product_names(monkeypatch,
+                                                        tmp_path):
+    """A cache wider than ``PADDED_PREFILL_MAX_KV`` with a model with
+    experts: the loop runs ``jit_chunk`` and ``jit_decode`` alone, every
+    boundary is still covered by the same leaves, and the experts' grouped
+    products keep the name the benchmark's reader finds them by
+    (the ``ragged_dot`` primitive as traced; ``ragged-dot-none`` once
+    compiled for a TPU, pinned in tests/test_tpu_compile.py)."""
+    from horovod_tpu.serving import loop as serve_loop
+
+    monkeypatch.setattr(serve_loop, "PADDED_PREFILL_MAX_KV", 32)
+    cfg = tfm.olmoe_1b_7b(vocab_size=128, d_model=32, n_heads=2, n_layers=2,
+                          d_ff=16, d_expert=16, max_seq_len=128, n_experts=4,
+                          top_k=2, dtype="float32", param_dtype="float32")
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    loop = ServeLoop(params, cfg, geo=kv_cache.geometry(33, 8, 64),
+                     max_batch=2, prefill_chunk=16, report_interval=1,
+                     load_reporter=lambda *a: None)
+    assert (loop.prefill_fn, loop.bprefill_fn, loop.spec_fn) == (None,) * 3
+    loop.warmup()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _, finished = loop.run(poisson_requests(
+            3, 1e6, np.random.default_rng(1), prompt_len=(5, 40),
+            max_new=(2, 4), vocab=cfg.vocab_size))
+    finally:
+        jax.profiler.stop_trace()
+    assert len(finished) == 3
+    events = sorted((e for evs in _host_events(str(tmp_path)).values()
+                     for e in evs if e[0].startswith("serve.")),
+                    key=lambda e: e[1])
+    programs = set()
+    for boundary, leaves in _boundaries(events):
+        assert leaves, boundary
+        for a, b in zip(leaves, leaves[1:]):
+            assert a[2] <= b[1]                     # disjoint, in order
+        programs |= {e[0].split(".")[1] for e in leaves
+                     if e[0].count(".") == 2}
+    assert programs == {"chunk", "decode"}
+
+    def traced_fn(fn, b, *q):
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        return fn.trace(params, loop.cache, i32(b, *q), i32(b),
+                        i32(b, loop.geo.max_blocks),
+                        jax.ShapeDtypeStruct((b,), jnp.bool_))
+
+    for fn, name, shape in ((loop.chunk_fn, "jit_chunk", (1, 16)),
+                            (loop.decode_fn, "jit_decode", (2,))):
+        tr = traced_fn(fn, *shape)
+        assert re.search(r"module @(\w+)",
+                         tr.lower().as_text()).group(1) == name
+        assert str(tr.jaxpr).count("ragged_dot") >= 3 * cfg.n_layers

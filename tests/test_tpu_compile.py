@@ -248,3 +248,61 @@ def test_serving_program_never_copies_the_cache(topo, name, max_batch):
     assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
     if name == "decode":
         assert memory.temp_size_in_bytes < 1e9
+
+
+# ---- the serving programs at benchmark/configs/olmoe-1b-7b.json's sizes ----
+
+def test_olmoe_cell_programs_fit_one_chip(topo):
+    """``olmoe-serve-chat-over``'s two programs (the 512-token chunk fill and
+    the decode step; a cache of 4096 gets no padded prefill) at the cell's
+    geometry: 12 layers of 64 experts in bf16, every slot of 8 at the full
+    context. Weights + cache + the program's temporaries stay under the
+    chip's 16.91e9 bytes; the experts are one ``ragged-dot`` custom call a
+    projection (the name the benchmark's reader matches); no program makes a
+    float32 copy of an expert tensor or a copy shaped like the cache."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmoe-1b-7b.json")) as f:
+        config = json.load(f)
+    srv = config["assumed"]["serve"]
+    cfg = tfm.olmoe_1b_7b(n_layers=config["num_hidden_layers"])
+    assert (cfg.d_model, cfg.ffn_width, cfg.n_experts, cfg.top_k) == (
+        config["hidden_size"], config["intermediate_size"],
+        config["num_experts"], config["num_experts_per_tok"])
+    geo = kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"])
+    assert geo.max_kv > 1024          # ServeLoop: chunk fills only
+    params, cache = jax.tree.map(
+        lambda x: _on_chip(topo, x.shape, x.dtype),
+        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
+                                kv_cache.make_cache(cfg, geo))))
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert 13.6e9 < held < 13.8e9
+    B = srv["max_batch"]
+
+    def slots(b, *q):
+        return [_on_chip(topo, s, d) for s, d in (
+            ((b, *q), jnp.int32), ((b,), jnp.int32),
+            ((b, geo.max_blocks), jnp.int32), ((b,), jnp.bool_))]
+
+    expert = cfg.n_experts * cfg.d_model * cfg.ffn_width
+    for fn, args in ((engine.make_chunk_step(cfg, geo, q_len=512),
+                      slots(1, 512)),
+                     (engine.make_decode_step(cfg, geo, max_batch=B),
+                      slots(B))):
+        compiled = fn.lower(params, cache, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
+        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
+        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
+        assert memory.temp_size_in_bytes < 1e9
+        text = compiled.as_text()
+        assert _cache_materialisations(text, cfg, geo) == []
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
+            == 3 * cfg.n_layers
+        # No instruction's result is an expert tensor's worth of float32.
+        for m in re.finditer(r" = f32\[([\d,]+)\]", text):
+            assert int(np.prod([int(d) for d in m.group(1).split(",")])) \
+                < expert, m.group(0)
