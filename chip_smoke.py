@@ -295,8 +295,8 @@ def serve_phase(cfg, *, n_pages, page_size, max_batch, prompt_len, max_new,
     t0 = time.perf_counter()
     loop.warmup()
     out = {"warmup_s": round(time.perf_counter() - t0, 2),
-           "attn_resolved_decode": tfm.resolve_attn(cfg, 1,
-                                                    kv_len=geo.max_kv)}
+           "attn_resolved_decode": "paged" if loop.decode_paged
+           else "gather"}
 
     # One request by hand through the loop's own compiled prefill and
     # decode programs (slot 0 of the batch, pages 1..k), logits compared

@@ -59,18 +59,23 @@ struct State {
   std::atomic<int64_t> edge_count{0};    // distinct order edges observed
   std::atomic<int64_t> acquisitions{0};  // total instrumented acquisitions
 
+  // Never destroyed: the core's own static destructors and its threads
+  // still take tracked locks while the process exits.
   static State& Get() {
-    static State s;
-    return s;
+    static State* s = new State;
+    return *s;
   }
 };
 
 // Stack of lock-class names currently held by this thread, in acquisition
 // order. Unlock erases the *last matching* entry, not necessarily the top:
 // the core occasionally releases out of LIFO order via unique_lock.
+// Leaked with its thread for the same reason: a thread's locals are
+// destroyed before the static destructors that run on it, and those lock.
 inline std::vector<std::string>& Held() {
-  thread_local std::vector<std::string> held;
-  return held;
+  thread_local std::vector<std::string>* held =
+      new std::vector<std::string>;
+  return *held;
 }
 
 // DFS: is `to` reachable from `from` in the recorded order graph?

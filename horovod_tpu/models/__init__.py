@@ -13,5 +13,14 @@ reference's pure-DP design never needed.
   PartitionSpec pytree (dp/tp/sp/ep shardings over a Mesh).
 """
 
-from . import resnet  # noqa: F401
+import importlib
+
 from . import transformer  # noqa: F401
+
+
+def __getattr__(name):
+    # ``resnet`` pulls in flax (0.65 s of import): loaded on first use, so
+    # that a transformer trainer or server does not pay for it at start.
+    if name == "resnet":
+        return importlib.import_module(".resnet", __name__)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -582,6 +582,25 @@ SERVE_SPEC_REJECTED = counter(
     "hvd_serve_spec_rejected",
     "Draft tokens the target model rejected (their K/V is dead until "
     "overwritten — pure block-table truncation, no copy)")
+SERVE_DECODE_CALLS = counter(
+    "hvd_serve_decode_calls",
+    "Decode steps dispatched (one token for every live slot each)")
+SERVE_DECODE_PAGED_CALLS = counter(
+    "hvd_serve_decode_paged_calls",
+    "Decode steps whose attention read the paged cache in place through "
+    "the Pallas kernel (TPU, no mesh); the rest gathered max_kv tokens")
+SERVE_KV_PAGES_READ = counter(
+    "hvd_serve_kv_pages_read",
+    "KV pages holding live context over all decode steps: what a step "
+    "has to read, ceil((position + 1) / page) summed over live slots")
+SERVE_KV_PAGES_GATHERED_BEFORE = counter(
+    "hvd_serve_kv_pages_gathered_before",
+    "KV pages the gather path reads for the same steps: max_batch x "
+    "max_blocks a step whatever is live")
+SERVE_KV_READ_SHARE = gauge(
+    "hvd_serve_kv_read_share",
+    "hvd_serve_kv_pages_read over hvd_serve_kv_pages_gathered_before: the "
+    "share of the gather path's cache traffic that is live context")
 CKPT_SAVES = counter(
     "hvd_ckpt_saves",
     "checkpoint.save() calls entered on this rank")

@@ -1,0 +1,209 @@
+"""Decode attention over the paged KV cache, read in place (Pallas TPU).
+
+One query token a slot against that slot's cached context. The cache is
+taken as :mod:`horovod_tpu.serving.kv_cache` holds it — one layer's K and
+V, ``[n_pages, page, H*dh]``, left in HBM — and each slot's **block
+table** is walked inside the kernel over the pages that hold live tokens
+only: ``ceil(len / page)`` of them, a dynamic trip count, so a slot 900
+tokens into a 4096-token context moves 900 tokens' worth of bytes and an
+inactive slot (length 0) moves none. The gather tier this replaces in the
+decode program (``layer_cache[block_tables]`` → ``[B, max_kv, H, dh]`` →
+``transformer.causal_attend``) gathered, re-laid and multiplied ``max_kv``
+tokens for every slot whatever the live context (PERF.md, PR 28).
+
+Structure: grid over the ``B`` slots; lengths and the flattened block
+tables are scalar-prefetched into SMEM. A slot's pages are copied
+``pages_per_block`` at a time (one asynchronous copy a page, ``[page,
+H*dh]`` contiguous in HBM) into one of two VMEM buffers, the next block
+started before the current one is used; online softmax with float32
+running max, sum and accumulator in VMEM scratch.
+
+The products run in the cache's fused, lane-dense layout, so no head is
+sliced at a half-tile offset and nothing is re-laid. The query is made
+**block-diagonal**, ``Q_bd [H_padded, H*dh]`` with head ``h``'s query in
+columns ``h*dh .. (h+1)*dh`` and zeros elsewhere: scores ``[H, T] = Q_bd ·
+K_blockᵀ`` are exactly each head's own scores, and ``P · V_block [H,
+H*dh]`` holds head ``h``'s output in row ``h``'s own columns; the rest of
+each row (other heads' values under this head's probabilities) is masked
+off at the end and the rows are summed into one fused ``[1, H*dh]``
+output. ``H`` times the useful FLOPs, which at one query a slot is far
+under the time the bytes take. Every size is read from the shapes.
+
+The mathematics is ``causal_attend``'s over positions ``< len``: scores
+from operands in the cache's dtype accumulated in float32, the
+``1/sqrt(dh)`` scale, softmax in float32, probabilities rounded to the
+cache's dtype before the product with V. A slot of length 0 returns
+zeros. A tail block's missing pages are not fetched from past the live
+ones (the last live page is read again), so a page the slot does not own
+is never touched.
+
+``interpret=True`` runs the same kernel on CPU (tests/test_paged_attention.py).
+"""
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NEG_INF = -1e30
+
+# The instruction name in a compiled program and in the chip's trace
+# (benchmark/layer_metrics/paged_attn_dev_ms.over.json matches it).
+KERNEL_NAME = "paged_decode_attention"
+
+# Tokens a block aims for: two buffers each of K and V at this many rows
+# of H*dh lanes stay within a few MB of VMEM at 2048 lanes.
+_BLOCK_TOKENS = 128
+
+
+def supported(page_size, fused_dim, dtype):
+    """Whether the kernel's copies and products tile on a TPU: a page is
+    whole sublane tiles of ``dtype`` and ``H*dh`` whole lane tiles."""
+    sublanes = 8 * (4 // jnp.dtype(dtype).itemsize)
+    return page_size % sublanes == 0 and fused_dim % 128 == 0
+
+
+def _kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, m_s, l_s, acc_s, *,
+            page, ppb, max_blocks, head_dim, sm_scale):
+    b = pl.program_id(0)
+    length = lens_ref[b]
+    n_pages = (length + page - 1) // page
+    bt = ppb * page                       # tokens a block
+    n_blocks = (length + bt - 1) // bt
+    hp, hd = acc_s.shape
+
+    def copies(i, slot):
+        """Block ``i``'s page copies into buffer ``slot`` (built anew to
+        start them and to wait for them: same pages, same semaphores)."""
+        out = []
+        for j in range(ppb):
+            # Past the live pages: the last live one again, never a page
+            # the slot does not own.
+            p = jnp.minimum(i * ppb + j, n_pages - 1)
+            pid = tables_ref[b * max_blocks + p]
+            rows = pl.ds(j * page, page)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[pid], k_buf.at[slot, rows], sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[pid], v_buf.at[slot, rows], sems.at[1, slot]))
+        return out
+
+    m_s[...] = jnp.full_like(m_s, _NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+    # Head h's query in row h, columns h*dh .. (h+1)*dh; zeros elsewhere.
+    row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
+    own = (col >= row * head_dim) & (col < (row + 1) * head_dim)
+    q = q_ref[0]                                            # [1, hd]
+    q_bd = jnp.where(own, q.astype(jnp.float32), 0.0).astype(q.dtype)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def block(i, _):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            for c in copies(i + 1, 1 - slot):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        k = k_buf[slot]                                     # [bt, hd]
+        v = v_buf[slot]
+        s = jax.lax.dot_general(q_bd, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * sm_scale                                    # [hp, bt]
+        tok = i * bt + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(tok < length, s, _NEG_INF)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+        # A block in the loop holds at least one live token, so m_new is a
+        # real score and a masked one (the tail, a page read again) gives
+        # exp(_NEG_INF - m_new) = 0 exactly.
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        m_s[...] = m_new
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, -1, keepdims=True)
+        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    jax.lax.fori_loop(0, n_blocks, block, None)
+
+    l = l_s[...]
+    out = acc_s[...] * (1.0 / jnp.where(l > 0, l, 1.0))     # [hp, hd]
+    out = jnp.where(own, out, 0.0)
+    o_ref[0] = jnp.sum(out, 0, keepdims=True).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
+def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
+                           pages_per_block=None, interpret=False):
+    """``q [B, H, dh]`` (one token a slot) against one layer's cache
+    ``k_pages, v_pages [n_pages, page, H*dh]`` through ``block_tables [B,
+    max_blocks]`` (page ids; anything past a slot's live pages is never
+    read) over each slot's first ``lengths [B]`` tokens -> ``[B, H, dh]``
+    in ``q``'s dtype; zeros where the length is 0.
+
+    Jitted so that a program calling it once a layer traces and lowers the
+    kernel once, not once a layer (0.1 s each on the host, 36 layers of
+    them in ``gpt2-large``'s decode program, paid at every start even when
+    the compiled program comes from the cache)."""
+    B, H, dh = q.shape
+    n_pages, page, hd = k_pages.shape
+    if hd != H * dh or v_pages.shape != k_pages.shape:
+        raise ValueError(f"cache {k_pages.shape} / {v_pages.shape} does "
+                         f"not hold {H} heads of {dh}")
+    max_blocks = block_tables.shape[1]
+    ppb = pages_per_block
+    if ppb is None:
+        ppb = max(1, _BLOCK_TOKENS // page)
+    ppb = min(int(ppb), max_blocks)
+    hp = -(-H // 16) * 16             # whole bf16 sublane tiles of heads
+    bt = ppb * page
+    kernel = functools.partial(
+        _kernel, page=page, ppb=ppb, max_blocks=max_blocks, head_dim=dh,
+        sm_scale=1.0 / math.sqrt(dh))
+    itemsize = k_pages.dtype.itemsize
+    live = B * max_blocks * page      # an upper bound; the real count is
+    out = pl.pallas_call(             # the lengths', known at run time
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, 1, hd), lambda b, *_: (b, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, 1, hd), lambda b, *_: (b, 0, 0),
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, bt, hd), k_pages.dtype),
+                pltpu.VMEM((2, bt, hd), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, hd), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, 1, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * hp * hd * live, transcendentals=hp * live,
+            bytes_accessed=2 * live * hd * itemsize),
+        name=KERNEL_NAME,
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), block_tables.reshape(-1).astype(jnp.int32),
+      q.reshape(B, 1, hd), k_pages, v_pages)
+    return out.reshape(B, H, dh)

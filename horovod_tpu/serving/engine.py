@@ -10,9 +10,9 @@ Two compiled paths, compiled ONCE each regardless of the request mix:
   write or masked by the decode read — never branched on.
 - **decode_step**: ONE token for every batch slot simultaneously —
   embed at the slot's position, append K/V into the page slot the
-  block table names, attend over the gathered pages under a
-  ``kv_pos <= position`` causal mask, next-token logits out. Inactive
-  slots run the same program with their writes routed to trash page 0.
+  block table names, attend over the slot's cached context up to and
+  including that position, next-token logits out. Inactive slots run
+  the same program with their writes routed to trash page 0.
 
 Every path runs ``transformer.block``, the one block definition the
 trainer runs too: this module holds no copy of the forward pass, only
@@ -22,23 +22,36 @@ rotates Q and K there, and the cache holds the keys after the norm and the
 rotation. A model with experts returns its routing beside the logits
 (:func:`_result`).
 
-Both paths resolve their attention kernel through
-``transformer.resolve_attn`` with the REAL (q_len, kv_len, causal)
-shape — the decode step is q_len=1 against ``max_kv`` cached tokens,
-which must resolve to "gather" (a [B,H,1,KV] score tensor is linear in
-KV; there is nothing for flash's q-tiling to eliminate). That contract
-is exactly the heuristic fix this module forced (resolve_attn keyed on
-query length alone would also have misfiled long chunked prefills).
+How the decode step attends is chosen once, in :func:`decode_attn`, from
+what can be seen there. On a TPU backend with no mesh it reads the cache
+**in place**: one Pallas kernel a layer
+(:mod:`horovod_tpu.ops.pallas_paged_attention`) takes the layer's K and V
+arrays as the cache holds them, walks each slot's block table over the
+pages that hold live tokens only, and returns ``[B, 1, H, dh]`` where
+``causal_attend`` did; bytes moved follow the live context, an inactive
+slot costs nothing. Elsewhere (a CPU backend, a mesh: a ``shard_map`` over
+the cache's head shards is not written; ``attn_impl="gather"``) it gathers
+every slot's ``max_kv`` tokens through the block tables and runs
+``transformer.causal_attend`` under a ``kv_pos <= position`` mask: the same
+mathematics, at the cost of ``max_kv`` whatever is live. The multi-token
+programs (prefill, batched prefill, chunk, spec) always gather: a paged
+kernel for ``q_len > 1`` is not written. ``transformer.resolve_attn`` is
+still consulted with the REAL (q_len, kv_len, causal) shape — q_len=1
+against ``max_kv`` cached tokens resolves to "gather" there (nothing for
+flash's q-tiling to eliminate), and an ``attn_impl`` that forces another
+tier is refused.
 
 The cache (:mod:`.kv_cache`) is one ``[n_pages, page, H*dh]`` array per
 layer for K and for V, the layout these programs compute in. Every
 program takes it donated, writes layer ``li`` with ``ck[li].at[page_ids,
 slot].set(k.reshape(..., H*dh))`` (prefill: whole pages at
-``block_table``), reads it with ``ck[li][block_tables]`` and reshapes the
-GATHERED pages to ``[B, max_kv, H, dh]`` — never the cache — so the
-compiled program scatters into its argument in place and holds no copy
-or slice of a layer's cache (tests/test_tpu_compile.py compiles all five
-for a described v5e and checks).
+``block_table``) and reads it either through the kernel or with
+``ck[li][block_tables]``, reshaping the GATHERED pages to ``[B, max_kv,
+H, dh]`` — never the cache — so the compiled program scatters into its
+argument in place and holds no copy or slice of a layer's cache
+(tests/test_tpu_compile.py compiles all five for a described v5e and
+checks; for the decode program also that nothing of the gathered pages'
+size is left in it).
 
 The batch-slot ↔ request mapping, page ownership, and admission policy
 live host-side in :mod:`.scheduler`; this module never allocates.
@@ -51,6 +64,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import transformer as tfm
+from ..ops import pallas_paged_attention as paged_attention
 from . import kv_cache
 
 
@@ -61,13 +75,26 @@ def _constrain(x, mesh, spec):
         x, NamedSharding(mesh, spec))
 
 
-def _check_decode_impl(cfg, geo, mesh):
+def decode_attn(cfg, geo, mesh):
+    """Which attention the decode program runs, chosen from what can be
+    seen here: ``"paged"`` (the Pallas kernel that reads the cache in
+    place, :mod:`horovod_tpu.ops.pallas_paged_attention`) on a TPU backend
+    with no mesh, when ``attn_impl`` leaves the choice open and the cache's
+    shape tiles; else ``"gather"``. ``attn_impl="gather"`` forces the
+    gather path; the other tiers have no q_len=1 paged form and raise."""
     impl = tfm.resolve_attn(cfg, 1, mesh, kv_len=geo.max_kv, causal=True)
     if impl != "gather":
         raise ValueError(
             f"serving decode needs the gather attention path for its "
             f"q_len=1 paged reads, but attn_impl={cfg.attn_impl!r} "
             f"resolved to {impl!r}; use attn_impl='auto' or 'gather'")
+    if (cfg.attn_impl == "auto" and mesh is None
+            and jax.default_backend() == "tpu"
+            and paged_attention.supported(
+                geo.page_size, cfg.n_heads * cfg.head_dim,
+                cfg.compute_dtype)):
+        return "paged"
+    return "gather"
 
 
 def _check_positions(cfg, n, what):
@@ -94,6 +121,12 @@ def _gather_pages(layer_cache, block_tables, cfg):
     return pages.reshape(pages.shape[0], -1, cfg.n_heads, cfg.head_dim)
 
 
+def _masked(cfg, mask):
+    """``attend(q, k, v)`` of the gathering programs: materialised scores
+    over the gathered ``k, v [B, max_kv, H, dh]`` under ``mask``."""
+    return lambda q, k, v: tfm.causal_attend(q, k, v, cfg, mask=mask)
+
+
 def _cache_out(ck, cv, mesh, cfg):
     """The per-layer lists back in the cache's form, each array held to
     its shard of the mesh."""
@@ -102,24 +135,26 @@ def _cache_out(ck, cv, mesh, cfg):
             "v": tuple(_constrain(c, mesh, kv_spec) for c in cv)}
 
 
-def _layers(params, cache, x, positions, write, mask, valid, *, cfg, mesh):
+def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh):
     """Every layer of the model over ``x [B, S, D]`` through
     ``transformer.block``, the one block definition, with the serving
     attention: layer ``li``'s new K/V (after the Q/K norm and the rotation
     to ``positions [B, S]``, so the cache holds keys as attention reads
     them) go into the cache by ``write(layer_cache, fused) ->
-    (layer_cache, k or v to attend over)``, then the window attends under
-    ``mask``. -> (ck, cv, x after the final norm, routing of the MoE
-    layers or None)."""
+    (layer_cache, k or v to attend over)``, then the window attends by
+    ``attend(q, k, v)`` (:func:`_masked` over gathered pages, or the decode
+    program's kernel over the layer's own arrays). -> (ck, cv, x after the
+    final norm, routing of the MoE layers or None)."""
     ck, cv = list(cache["k"]), list(cache["v"])
     routings = []
     for li, layer in enumerate(params["layers"]):
-        def attend(q, k, v, li=li):
+        def write_and_attend(q, k, v, li=li):
             ck[li], kk = write(ck[li], k)
             cv[li], vv = write(cv[li], v)
-            return tfm.causal_attend(q, kk, vv, cfg, mask=mask)
+            return attend(q, kk, vv)
 
-        x, routing = tfm.block(layer, x, cfg, attend, positions=positions,
+        x, routing = tfm.block(layer, x, cfg, write_and_attend,
+                               positions=positions,
                                mesh=mesh, valid=valid)
         routings.append(routing)
     moe = None
@@ -152,7 +187,7 @@ def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
     preempted request can replay prompt + generated prefix through the
     same compiled program; it must cover whole pages.
     """
-    _check_decode_impl(cfg, geo, mesh)
+    decode_attn(cfg, geo, mesh)
     pad = geo.max_kv if prefill_pad is None else int(prefill_pad)
     if pad % geo.page_size != 0:
         raise ValueError(f"prefill_pad {pad} must be a multiple of "
@@ -177,8 +212,9 @@ def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
             return (layer_cache.at[block_table[:n_blocks]].set(pages), kv)
 
         valid = (jnp.arange(pad) < length)[None]
-        ck, cv, x, moe = _layers(params, cache, x, None, write, mask, valid,
-                                 cfg=cfg, mesh=mesh)
+        ck, cv, x, moe = _layers(params, cache, x, None, write,
+                                 _masked(cfg, mask), valid, cfg=cfg,
+                                 mesh=mesh)
         last = jnp.take(x[0], length - 1, axis=0)
         logits = jnp.einsum("d,vd->v", last,
                             tfm.head_weights(params, cfg).astype(dt))
@@ -198,9 +234,8 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
     attends to everything cached plus itself. Inactive slots write to
     trash page 0 and their logits are garbage the scheduler never reads.
     """
-    _check_decode_impl(cfg, geo, mesh)
+    paged = decode_attn(cfg, geo, mesh) == "paged"
     dt = cfg.compute_dtype
-    max_kv = geo.max_kv
 
     def decode(params, cache, tokens, positions, block_tables, active):
         x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
@@ -212,18 +247,34 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
                                        axis=1)[:, 0]
         page_ids = jnp.where(active, page_ids, 0)          # trash route
         slot_w = jnp.where(active, slot, 0)
-        kv_mask = (jnp.arange(max_kv)[None, None, :]
-                   <= positions[:, None, None])            # [B, 1, KV]
 
-        def write(layer_cache, kv):                        # kv [B, 1, H, dh]
-            layer_cache = layer_cache.at[page_ids, slot_w].set(
-                _fused(kv[:, 0]))
-            return layer_cache, _gather_pages(layer_cache, block_tables, cfg)
+        def scatter(layer_cache, kv):                      # kv [B, 1, H, dh]
+            return layer_cache.at[page_ids, slot_w].set(_fused(kv[:, 0]))
 
-        ck, cv, x, moe = _layers(
-            params, cache, x, positions[:, None], write,
-            kv_mask[:, :, None, :].swapaxes(1, 2), active[:, None],
-            cfg=cfg, mesh=mesh)
+        if paged:
+            lengths = jnp.where(active, positions + 1, 0)
+
+            def write(layer_cache, kv):   # the kernel reads the layer's array
+                layer_cache = scatter(layer_cache, kv)
+                return layer_cache, layer_cache
+
+            def attend(q, k_pages, v_pages):               # q [B, 1, H, dh]
+                return paged_attention.paged_decode_attention(
+                    q[:, 0], k_pages, v_pages, block_tables, lengths,
+                    interpret=jax.default_backend() != "tpu")[:, None]
+        else:
+            kv_mask = (jnp.arange(geo.max_kv)[None, None, None, :]
+                       <= positions[:, None, None, None])  # [B, 1, 1, KV]
+
+            def write(layer_cache, kv):
+                layer_cache = scatter(layer_cache, kv)
+                return layer_cache, _gather_pages(layer_cache, block_tables,
+                                                  cfg)
+
+            attend = _masked(cfg, kv_mask)
+
+        ck, cv, x, moe = _layers(params, cache, x, positions[:, None], write,
+                                 attend, active[:, None], cfg=cfg, mesh=mesh)
         logits = jnp.einsum("bsd,vd->bsv", x,
                             tfm.head_weights(params, cfg).astype(dt))[:, 0]
         return _result(ck, cv, logits, moe, mesh, cfg)
@@ -258,8 +309,9 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
         layer_cache = layer_cache.at[page_ids, slot_w].set(_fused(kv))
         return layer_cache, _gather_pages(layer_cache, block_tables, cfg)
 
-    return _layers(params, cache, x, pos, write, kv_mask[:, None, :, :],
-                   valid, cfg=cfg, mesh=mesh)
+    return _layers(params, cache, x, pos, write,
+                   _masked(cfg, kv_mask[:, None, :, :]), valid, cfg=cfg,
+                   mesh=mesh)
 
 
 def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
@@ -295,7 +347,7 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
     trash page 0, so padded draft lanes and short final chunks are
     branch-free.
     """
-    _check_decode_impl(cfg, geo, mesh)
+    decode_attn(cfg, geo, mesh)
     q_len = geo.page_size if q_len is None else int(q_len)
     if q_len < 1:
         raise ValueError(f"chunk q_len must be >= 1, got {q_len}")
@@ -335,7 +387,7 @@ def make_batched_prefill(cfg, geo, mesh=None, prefill_pad=None):
         raise ValueError(f"prefill_pad {pad} must be a multiple of "
                          f"page_size {geo.page_size}")
     _check_positions(cfg, pad, "prefill_pad")
-    _check_decode_impl(cfg, geo, mesh)
+    decode_attn(cfg, geo, mesh)
 
     def bprefill(params, cache, tokens, lengths, block_tables, active):
         positions = jnp.zeros(tokens.shape[:1], jnp.int32)

@@ -18,7 +18,11 @@ pages-minor and copy all of it to a padded row-major layout and back
 around every program (half of a decode step on a v5e, PERF.md PR 26). One
 array a layer, because ``big[li]`` of one stacked array is a slice the
 compiler materialises; a layer's own array is scattered into in place
-(the programs donate the cache) and gathered from, nothing else.
+(the programs donate the cache) and read through the block tables: by the
+decode step's paged-attention kernel page by page where they lie
+(``ops/pallas_paged_attention.py``: a page is ``page_size`` contiguous rows
+of ``n_heads * head_dim`` lanes, one asynchronous copy), by the multi-token
+programs with a gather. Nothing else.
 
 Page 0 is the **trash page**: the allocator never hands it out, and the
 engine routes every masked write there (inactive batch slots, padding
@@ -43,8 +47,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 @dataclasses.dataclass(frozen=True)
 class CacheGeometry:
     """Static shape half of the cache — everything the jit'd paths close
-    over. max_kv (= max_blocks * page_size) is the fixed KV width every
-    decode step gathers; per-request live length is masked, not shaped."""
+    over. max_kv (= max_blocks * page_size) is the fixed KV width of a
+    block table and of every gathering program; per-request live length is
+    a run-time value (the paged kernel's trip count, the gather's mask),
+    never a shape."""
     n_pages: int
     page_size: int
     max_blocks: int      # block-table width = max context pages/request

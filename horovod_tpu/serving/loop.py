@@ -197,6 +197,9 @@ class ServeLoop:
         self.prefill_fn = (engine.make_prefill(cfg, geo, mesh)
                            if padded else None)
         self.decode_fn = engine.make_decode_step(cfg, geo, mesh, max_batch)
+        # Whether that program reads the cache through the paged kernel
+        # (the engine's choice, from backend, mesh and shapes).
+        self.decode_paged = engine.decode_attn(cfg, geo, mesh) == "paged"
         self.bprefill_fn = (engine.make_batched_prefill(cfg, geo, mesh)
                             if padded and batch_prefill
                             and self.max_batch > 1 else None)
@@ -217,6 +220,8 @@ class ServeLoop:
         self.loop_stats = {"prefill_single": 0, "prefill_batched": 0,
                            "prefill_batch_calls": 0, "chunk_fills": 0,
                            "boundaries": 0,
+                           "decode_calls": 0, "decode_paged_calls": 0,
+                           "kv_pages_read": 0, "kv_pages_gathered_before": 0,
                            "host_s": dict.fromkeys(HOST_KINDS, 0.0)}
         self._fills = {}   # rid -> (admit_seq, tokens materialized)
         # A model with experts: the (token, expert) pairs every program
@@ -403,6 +408,21 @@ class ServeLoop:
                 positions[slot] = req.context_len - 1
                 tables[slot] = self.batcher.block_table(req, mb)
                 active[slot] = True
+            # Pages of live context a decode step has to read, against the
+            # B x max_blocks the gather path reads whatever is live.
+            live_pages = int(
+                (positions[active] // self.geo.page_size + 1).sum())
+            st = self.loop_stats
+            st["decode_calls"] += 1
+            st["decode_paged_calls"] += self.decode_paged
+            st["kv_pages_read"] += live_pages
+            st["kv_pages_gathered_before"] += B * mb
+            if _metrics.enabled():
+                _metrics.SERVE_DECODE_CALLS.inc()
+                _metrics.SERVE_DECODE_PAGED_CALLS.inc(int(self.decode_paged))
+                _metrics.SERVE_KV_PAGES_READ.inc(live_pages)
+                _metrics.SERVE_KV_PAGES_GATHERED_BEFORE.inc(B * mb)
+                _metrics.SERVE_KV_READ_SHARE.set(self._kv_read_share())
         with self._span("serve.decode.dispatch",
                         fill=self.batcher.batch_fill()):
             logits = self._call("decode", self.decode_fn, tokens,
@@ -410,6 +430,12 @@ class ServeLoop:
         with self._span("serve.decode.fetch"):
             out = self._fetch(logits)
             return {s: int(out[s]) for s in ready}
+
+    def _kv_read_share(self):
+        """Live pages over the pages the gather path reads, all decode
+        calls so far."""
+        gathered = self.loop_stats["kv_pages_gathered_before"]
+        return self.loop_stats["kv_pages_read"] / gathered if gathered else 0.0
 
     def _spec_decode(self, ready):
         """One speculative step over the fully-prefilled slots: draft k
@@ -657,6 +683,7 @@ class ServeLoop:
             "spec_rejected": st["spec_rejected"],
         }
         snap.update(self.loop_stats, host_s=dict(self.loop_stats["host_s"]))
+        snap["kv_read_share"] = self._kv_read_share()
         if self.moe:
             ms, load = self.moe_stats, self._moe_load
             steps = ms["calls"].get("decode", 0) * self.cfg.n_layers

@@ -11,8 +11,11 @@ described (no libtpu).
 The serving programs are compiled too, at the benchmark's ``gpt2-large``
 geometry, for what their compiled text shows and no CPU test can: that the
 paged KV cache is stored in the layout the programs compute in, so that none
-of them copies or slices it (PERF.md, PR 26).
+of them copies or slices it (PERF.md, PR 26), and that the decode program
+reads it only through the paged-attention kernel, one call a layer, and
+gathers nothing (PR 28).
 """
+import dataclasses
 import os
 import re
 
@@ -167,6 +170,15 @@ def _gpt2_large():
                                  dtype="bfloat16")
 
 
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """The serving engine chooses the decode program's attention from the
+    backend it sees, which here is the CPU whatever the compile is for: let
+    it see the TPU the program is compiled for (the guide's "steer in the
+    test"), so that what is compiled is what the chip runs."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
 def _serve_program(name, cfg, geo, max_batch):
     """(jitted program, shapes of its arguments after params and cache) as
     ServeLoop builds and calls it."""
@@ -187,6 +199,7 @@ def _serve_program(name, cfg, geo, max_batch):
     if name == "spec":
         return (engine.make_chunk_step(cfg, geo, q_len=4, name="spec"),
                 slots(max_batch, 4))
+    assert name in ("decode", "decode_gather")
     return engine.make_decode_step(cfg, geo, max_batch=max_batch), \
         slots(max_batch)
 
@@ -201,6 +214,28 @@ _RESULT = re.compile(
 # else -- copy, slice, a loop fusion -- materialises the cache anew.
 _IN_PLACE = {"parameter", "bitcast", "get-tuple-element", "scatter",
              "copy-done", "custom-call"}
+
+
+def _gathered(text, cfg, geo, max_batch):
+    """Instructions whose result is as large as every slot's ``max_kv``
+    tokens of one layer: the gathered pages ``[B * max_blocks, page, H*dh]``,
+    their reshape to ``[B, max_kv, H, dh]`` or any copy of either."""
+    size = max_batch * geo.max_kv * cfg.n_heads * cfg.head_dim
+    found = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m and m.group(1) and int(np.prod(
+                [int(d) for d in m.group(1).split(",")])) == size:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _paged_kernels(text):
+    """The paged-attention kernel's calls, by the name the benchmark's
+    ``paged_attn_dev_ms.over`` reads in the chip's trace."""
+    return [line for line in text.splitlines()
+            if re.match(r"\s*%paged_decode_attention[.\d]* = ", line)
+            and "tpu_custom_call" in line]
 
 
 def _cache_materialisations(text, cfg, geo):
@@ -225,15 +260,23 @@ def _cache_materialisations(text, cfg, geo):
 
 @pytest.mark.parametrize("name, max_batch", [
     ("prefill", 8), ("bprefill", 8), ("chunk", 8), ("spec", 8),
-    ("decode", 8), ("decode", 16)])
-def test_serving_program_never_copies_the_cache(topo, name, max_batch):
+    ("decode", 8), ("decode", 16), ("decode_gather", 8)])
+def test_serving_program_never_copies_the_cache(topo, as_on_the_chip, name,
+                                                max_batch):
     """Each of the five serving programs, at 36 layers x 20 heads x 64 and a
     page of 16 with every slot at the full context of 1024: the cache comes
     in, is scattered into in place and gathered from, and goes out. With the
     5-D ``[layers, pages, page, heads, 64]`` cache each program opened and
     closed with a copy of all of it to another layout, and sliced a layer
-    out 72 times (5.6e9 bytes of temporaries in decode)."""
+    out 72 times (5.6e9 bytes of temporaries in decode).
+
+    The decode program as the chip runs it gathers nothing either: it reads
+    the cache through one kernel call a layer and no instruction of it has
+    the size of the gathered pages (``decode_gather``: what
+    ``attn_impl="gather"`` still compiles, the program of before)."""
     cfg = _gpt2_large()
+    if name == "decode_gather":
+        cfg = dataclasses.replace(cfg, attn_impl="gather")
     geo = kv_cache.geometry(max_batch * 64 + 1, 16, 1024)
     fn, shapes = _serve_program(name, cfg, geo, max_batch)
     params, cache = jax.tree.map(
@@ -242,24 +285,35 @@ def test_serving_program_never_copies_the_cache(topo, name, max_batch):
                                 kv_cache.make_cache(cfg, geo))))
     compiled = fn.lower(params, cache,
                         *[_on_chip(topo, *s) for s in shapes]).compile()
-    assert _cache_materialisations(compiled.as_text(), cfg, geo) == []
+    text = compiled.as_text()
+    assert _cache_materialisations(text, cfg, geo) == []
     memory = compiled.memory_analysis()
     # Every layer's array is donated and aliased to its output.
     assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
     if name == "decode":
+        assert len(_paged_kernels(text)) == cfg.n_layers
+        assert _gathered(text, cfg, geo, max_batch) == []
+        assert memory.temp_size_in_bytes < 0.2e9   # the weights' bf16 casts
+    else:
+        assert _paged_kernels(text) == []
+    if name == "decode_gather":
+        assert _gathered(text, cfg, geo, max_batch) != []
         assert memory.temp_size_in_bytes < 1e9
 
 
 # ---- the serving programs at benchmark/configs/olmoe-1b-7b.json's sizes ----
 
-def test_olmoe_cell_programs_fit_one_chip(topo):
+def test_olmoe_cell_programs_fit_one_chip(topo, as_on_the_chip):
     """``olmoe-serve-chat-over``'s two programs (the 512-token chunk fill and
     the decode step; a cache of 4096 gets no padded prefill) at the cell's
     geometry: 12 layers of 64 experts in bf16, every slot of 8 at the full
     context. Weights + cache + the program's temporaries stay under the
     chip's 16.91e9 bytes; the experts are one ``ragged-dot`` custom call a
     projection (the name the benchmark's reader matches); no program makes a
-    float32 copy of an expert tensor or a copy shaped like the cache."""
+    float32 copy of an expert tensor or a copy shaped like the cache; the
+    decode step reads the cache through the paged kernel alone (one call a
+    layer, nothing of the gathered pages' size; its temporaries were 0.28e9
+    with the gather)."""
     import json
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -288,10 +342,11 @@ def test_olmoe_cell_programs_fit_one_chip(topo):
             ((b, geo.max_blocks), jnp.int32), ((b,), jnp.bool_))]
 
     expert = cfg.n_experts * cfg.d_model * cfg.ffn_width
-    for fn, args in ((engine.make_chunk_step(cfg, geo, q_len=512),
-                      slots(1, 512)),
-                     (engine.make_decode_step(cfg, geo, max_batch=B),
-                      slots(B))):
+    for name, fn, args in (
+            ("chunk", engine.make_chunk_step(cfg, geo, q_len=512),
+             slots(1, 512)),
+            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
+             slots(B))):
         compiled = fn.lower(params, cache, *args).compile()
         memory = compiled.memory_analysis()
         assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
@@ -300,6 +355,12 @@ def test_olmoe_cell_programs_fit_one_chip(topo):
         assert memory.temp_size_in_bytes < 1e9
         text = compiled.as_text()
         assert _cache_materialisations(text, cfg, geo) == []
+        if name == "decode":
+            assert len(_paged_kernels(text)) == cfg.n_layers
+            assert _gathered(text, cfg, geo, B) == []
+            assert memory.temp_size_in_bytes < 0.1e9
+        else:
+            assert _paged_kernels(text) == []
         assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
             == 3 * cfg.n_layers
         # No instruction's result is an expert tensor's worth of float32.

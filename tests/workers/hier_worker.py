@@ -9,6 +9,7 @@ rank's cross-plane tx bytes so the test can compare hierarchical vs flat
 wire traffic (expected drop: ~1/local_size per rank).
 """
 import os
+import sys
 
 r = int(os.environ["HVD_RANK"])
 _s = int(os.environ["HVD_SIZE"])
@@ -79,4 +80,7 @@ cross_tx = sum(hvd.peer_tx_bytes(q) for q in range(s) if q // L != host)
 local_tx = sum(hvd.peer_tx_bytes(q) for q in range(s) if q // L == host
                and q != r)
 hvd.shutdown()
-print(f"HIERTX rank={r} cross={cross_tx} local={local_tx}", flush=True)
+# One write, newline included: the ranks share the output file, and a
+# print() on an unbuffered stream writes its newline separately.
+sys.stdout.write(f"HIERTX rank={r} cross={cross_tx} local={local_tx}\n")
+sys.stdout.flush()
