@@ -70,22 +70,37 @@ from .process_sets import (  # noqa: F401
     remove_process_set,
 )
 
+_jax_mesh_wanted = None   # decided once, at this process's first hvd.init()
+
+
 def _maybe_init_jax_mesh():
     """Join the job-wide jax.distributed mesh when the launcher provisioned
     one — static jobs (rank 0 hosts the coordination service) AND elastic
     jobs (the driver hosts a per-epoch service; workers join as recoverable
     clients — see horovod_tpu/jax/distributed.py). Gated so non-JAX users
-    (torch/TF workers) never pay a jax import."""
+    (torch/TF workers) never pay a jax import.
+
+    Whether this process is a JAX worker is decided ONCE, at its first
+    ``hvd.init()``, and holds for every later elastic epoch. Joining is a
+    barrier over all ranks of the epoch, so every rank must answer alike:
+    a survivor that imported jax lazily mid-run (``checkpoint.save`` does)
+    would otherwise wait in the barrier for a respawned replacement that
+    runs the same script from the top, has not imported jax at its
+    ``hvd.init()`` and never joins — until the coordination timeout kills
+    every survivor (tests/test_chaos.py::test_chaos_kill_writer_mid_save).
+    """
     import os as _os
     import sys as _sys
 
+    global _jax_mesh_wanted
     # Gate BEFORE importing .jax: the subpackage __init__ imports jax and
     # optax at module level, which a torch/TF worker must never pay (and
     # may not even have installed).
     gate = _os.environ.get("HVD_JAX_DISTRIBUTED")
-    if gate == "0" or not _os.environ.get("HVD_JAX_COORD_ADDR"):
-        return
-    if "jax" not in _sys.modules and gate != "1":
+    if _jax_mesh_wanted is None:
+        _jax_mesh_wanted = gate == "1" or "jax" in _sys.modules
+    if (gate == "0" or not _jax_mesh_wanted
+            or not _os.environ.get("HVD_JAX_COORD_ADDR")):
         return
     from .jax import distributed as _jd
 
@@ -180,8 +195,8 @@ def checkpoint_stats():
     saves / commits / aborted_commits prove the crash-safe commit
     protocol's accounting, ``bytes``/``bytes_read``/``fragments_fetched``
     quantify the sharded write and reshard-on-read paths, and
-    ``snapshot_stall_ms`` vs ``write_ms`` is the async overlap the
-    ``bench.py ckpt`` A/B measures. See docs/checkpoint.md."""
+    ``snapshot_stall_ms`` vs ``write_ms`` is the async overlap.
+    See docs/checkpoint.md."""
     from . import checkpoint as _checkpoint
 
     return _checkpoint.checkpoint_stats()

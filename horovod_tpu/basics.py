@@ -12,69 +12,29 @@ than the binary.
 
 import ctypes
 import os
-import subprocess
 
-_PKG_DIR = os.path.dirname(os.path.abspath(__file__))
+from . import _build_lock
+
 # HVD_LIB overrides the library to load (e.g. the TSAN build
 # libhvd_tpu_tsan.so from `make tsan`; see tests/test_tsan.py).
-_LIB_PATH = os.environ.get(
-    "HVD_LIB", os.path.join(_PKG_DIR, "lib", "libhvd_tpu.so"))
-_CSRC_DIR = os.path.join(_PKG_DIR, "csrc")
+_LIB_PATH = os.environ.get("HVD_LIB", _build_lock.core_lib_path())
 
 
 def _maybe_build():
     if "HVD_LIB" in os.environ:
-        # Explicit override (e.g. the TSAN build): the caller built it via
-        # its own make target — the default `make` heuristic below would
-        # rebuild the WRONG target and then load the override stale.
+        # Explicit override (e.g. the TSAN build): the caller had it built
+        # as its own target — building the default one here would bring
+        # the WRONG library up to date and then load the override stale.
         if not os.path.exists(_LIB_PATH):
             raise ImportError(f"HVD_LIB={_LIB_PATH} does not exist")
         return
-    if os.path.isdir(_CSRC_DIR):
-        srcs = [
-            os.path.join(_CSRC_DIR, f)
-            for f in os.listdir(_CSRC_DIR)
-            if f.endswith((".cc", ".h", "Makefile"))
-            # tf_ops.cc / torch_ops.cc build SEPARATE libraries (lazy,
-            # driven by their binding loaders); counting them here would
-            # make the core look stale forever and spawn make per import.
-            and f not in ("tf_ops.cc", "torch_ops.cc")
-        ]
-        if srcs:
-            # Staleness is decided UNDER an exclusive lock: N ranks import
-            # concurrently, and make links straight onto the .so, so an
-            # unlocked mtime check can see a fresh-but-half-written library
-            # while another rank is still relinking and dlopen it (observed
-            # as missing-symbol AttributeErrors under the multi-process
-            # tests). Holding the lock across check+build means we only fall
-            # through to CDLL once any in-flight rebuild has finished.
-            # The wait is bounded (HVD_BUILD_LOCK_TIMEOUT): an orphaned
-            # holder must not wedge every subsequent import on the machine,
-            # and a holder older than the timeout is wedged, not relinking
-            # — so loading the existing library without the lock is safe.
-            from . import _build_lock
-
-            with open(os.path.join(_CSRC_DIR, ".build.lock"), "w") as lk:
-                locked = _build_lock.acquire(lk, _build_lock.timeout_from_env())
-                newest = max(os.path.getmtime(f) for f in srcs)
-                if (not os.path.exists(_LIB_PATH)
-                        or os.path.getmtime(_LIB_PATH) < newest):
-                    if locked:
-                        subprocess.run(
-                            ["make", "-s", f"-j{os.cpu_count() or 1}"],
-                            cwd=_CSRC_DIR, check=True,
-                            stdout=subprocess.DEVNULL,
-                        )
-                    elif not os.path.exists(_LIB_PATH):
-                        raise ImportError(
-                            f"native core missing at {_LIB_PATH} and the "
-                            f"build lock is stuck held by another process; "
-                            f"remove {_CSRC_DIR}/.build.lock holders and "
-                            f"retry (HVD_BUILD_LOCK_TIMEOUT tunes the wait)")
+    # One function builds every core, default and instrumented tiers alike,
+    # under csrc/.build.lock (see _build_lock.build_core for the why).
+    _build_lock.build_core()
     if not os.path.exists(_LIB_PATH):
         raise ImportError(
-            f"native core not found at {_LIB_PATH}; run `make` in {_CSRC_DIR}"
-        )
+            f"native core not found at {_LIB_PATH}; "
+            f"run `make` in {_build_lock.CSRC_DIR}")
 
 
 _maybe_build()
@@ -468,7 +428,7 @@ class HorovodBasics:
     def reduce_bench(self, dtype, n, iters=5, vector=True):
         """Seconds per Accumulate(kSum) call over `n` elements of DataType
         index `dtype`, with the vectorized tier forced on/off. Pure in-process
-        microbench (no init needed); used by bench.py's `reduce` config."""
+        microbench (no init needed)."""
         v = _lib.hvd_reduce_bench(int(dtype), int(n), int(iters),
                                   1 if vector else 0)
         if v < 0:
@@ -838,8 +798,8 @@ def _check_init(v):
 class AutotuneSim:
     """Drive the REAL in-core bandit search policy on a caller-supplied
     synthetic score surface with a fake clock — no pod, no init() needed.
-    One window == one sample. Used by tests/test_autotune_v2.py and
-    `bench.py autotune` to measure samples-to-within-5%-of-exhaustive-best
+    One window == one sample. Used by tests/test_autotune_v2.py
+    to measure samples-to-within-5%-of-exhaustive-best
     and the profile save/adopt round-trip against an exhaustive 2^d
     enumeration that a live sweep could never afford.
 

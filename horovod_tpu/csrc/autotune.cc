@@ -745,7 +745,7 @@ bool ParameterManager::Record(int64_t bytes, int64_t now_us, int64_t* fusion,
 // ---------------------------------------------------------------------------
 // Deterministic sim harness: drives the REAL search policy above on a
 // synthetic score surface with a fake clock — no job, no pod. Used by
-// tests/test_autotune_v2.py and `bench.py autotune` to measure
+// tests/test_autotune_v2.py to measure
 // samples-to-within-5%-of-exhaustive-best and the profile adoption A/B
 // against an exhaustive 2^d enumeration that would never fit a live sweep.
 

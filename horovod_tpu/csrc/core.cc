@@ -3021,8 +3021,8 @@ int hvd_reduce_pool_stats(int64_t* threads, int64_t* jobs, int64_t* spans) {
 // Standalone reduce-kernel microbench: time `iters` in-place Accumulate
 // sum calls over `n` elements of `dtype`, under the requested tier
 // (vector_on 0/1; the live tier is restored afterwards). Returns seconds
-// per iteration, or -1 on bad dtype. Does NOT require init — bench.py
-// uses it to measure scalar vs vectorized GB/s on a box with no job up.
+// per iteration, or -1 on bad dtype. Does NOT require init: it measures
+// scalar vs vectorized GB/s on a box with no job up.
 double hvd_reduce_bench(int dtype, int64_t n, int iters, int vector_on) {
   if (n <= 0 || iters <= 0) return -1.0;
   DataType dt = (DataType)dtype;

@@ -19,7 +19,7 @@ and by :class:`horovod_tpu.runner.http_server.MetricsServer` in workers
 (auto-started from ``hvd.init()`` when ``HVD_METRICS_PORT`` is set).
 
 No module here imports jax, numpy, or the native core — torch/TF-only
-processes and bench.py's jax-free parent can import it freely.
+processes and the launchers' jax-free parents can import it freely.
 """
 
 import os

@@ -6,7 +6,7 @@ parse (ISSUE 18 satellite):
   * the C++ writer's header literal in ``csrc/autotune.cc`` (checked
     against this table by the hvdlint ``arm-stats`` rule),
   * the ``tests/workers/autotune_worker.py`` log assertions,
-  * ``bench.py autotune`` / operator tooling slicing columns by name.
+  * operator tooling slicing columns by name.
 
 Layout: ``sample`` then the numeric point, then one column per
 categorical dim in arm-bit order (``ARM_COLUMNS``), then the recorded

@@ -310,8 +310,7 @@ def render_text():
 
 
 def snapshot():
-    """JSON-able dump of the registry — what bench.py attaches to each
-    config's recorded line under ``"metrics"``."""
+    """JSON-able dump of the registry."""
     out = {}
     for m in REGISTRY.metrics():
         samples = []

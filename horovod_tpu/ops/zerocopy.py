@@ -17,8 +17,8 @@ When a framework hands back a non-contiguous or wrong-dtype buffer — or
 exports no buffer at all — the bridge falls back to an explicit copy and
 counts WHY (the always-on :func:`stats` dict; mirrored into the
 observability registry when HVD_METRICS=1). ``HVD_BRIDGE_ZEROCOPY=0``
-forces the copy path everywhere — the A/B switch ``bench.py``'s bridge
-config uses to measure the staging bytes this module removes.
+forces the copy path everywhere — the A/B switch that shows the staging
+bytes this module removes.
 
 Lifetime contract: a zero-copy view aliases the source tensor. Callers
 must keep the source alive until the collective completes (the ops layer

@@ -21,7 +21,7 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 
 def compile_cache_dir():
-    """Where the repo's own drivers (chip_smoke.py, bench.py) tell the
+    """Where the repo's own drivers (chip_smoke.py, benchmark/run.py) tell the
     processes they start to keep JAX's persistent compilation cache: the
     directory ``JAX_COMPILATION_CACHE_DIR`` names when the machine sets
     one, otherwise ``.jax_cache`` at the root of the checkout. The path is

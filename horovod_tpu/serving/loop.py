@@ -287,8 +287,8 @@ class ServeLoop:
         """Compile every engine jit outside any measured window. Every
         cache write routes to trash page 0 (all-zero block table,
         all-inactive batch), so the cache stays semantically untouched.
-        bench.py calls this before starting the A/B clock so compile
-        time never pollutes the throughput comparison."""
+        A benchmark calls this before starting its clock so compile
+        time never pollutes the throughput it reads."""
         B, mb = self.max_batch, self.geo.max_blocks
 
         def slots(b, *q):

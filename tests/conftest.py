@@ -9,7 +9,7 @@ import os
 import sys
 
 # Tests run on the CPU whatever the session env selects: chip_smoke.py and
-# bench.py are the chip surfaces, not the test suite.
+# benchmark/run.py are the chip surfaces, not the test suite.
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
           if "xla_force_host_platform_device_count" not in f]

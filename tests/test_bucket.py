@@ -89,7 +89,7 @@ def test_bucket_timeline_events(tmp_path):
     assert "TCP_BUCKET_ASSEMBLE" in phases, set(phases)
     assert "TCP_BUCKET_LAUNCH" in phases, set(phases)
     # Launch spans close after their members' assemble spans open — the
-    # hold window the overlap fraction is derived from (bench.py).
+    # hold window an overlap fraction is derived from.
     t_assemble = min(e["ts"] for e in events
                      if e["name"] == "TCP_BUCKET_ASSEMBLE")
     t_launch = max(e["ts"] + e.get("dur", 0) for e in events

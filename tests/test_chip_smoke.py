@@ -118,10 +118,10 @@ def test_run_child_returns_result_lines():
 
 
 def test_parents_stay_off_jax():
-    """One process per chip: the launcher and the two drivers that start
-    chip children must not have touched JAX themselves."""
-    code = ("import sys; import horovod_tpu.runner.launch, bench, "
-            "chip_smoke; sys.exit('jax' in sys.modules)")
+    """One process per chip: the launcher and the driver that starts chip
+    children must not have touched JAX themselves."""
+    code = ("import sys; import horovod_tpu.runner.launch, chip_smoke; "
+            "sys.exit('jax' in sys.modules)")
     p = subprocess.run([sys.executable, "-c", code], cwd=_REPO,
                        env=dict(os.environ, PYTHONPATH=_REPO),
                        capture_output=True, text=True, timeout=120)

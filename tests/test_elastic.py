@@ -53,17 +53,39 @@ def _run_elastic(tmp_path, hosts_initial, extra_env, min_np, max_np,
     return proc.returncode, log, out
 
 
+def _mid_run(progress, size, hosts):
+    """A discovery change that really comes MID-RUN: it waits until rank 0
+    has reported 2 iterations at ``size`` (the workers' TEST_PROGRESS
+    beacon). A fixed sleep does not: the driver takes 3 s to publish its
+    first epoch (it imports jax for the coordination service) and more on
+    a loaded machine, so a change 2 s in was picked up in the driver's next
+    pass and published 0.1 s after epoch 0; a worker whose poll fell
+    between the two went into the core's init for epoch 0 and waited out
+    HVD_START_TIMEOUT (60 s) for peers that had gone to epoch 1, and they
+    for it (test_elastic_scale_up in 2 of PR 30's first 5 whole runs,
+    test_elastic_scale_down in one)."""
+    def mutate(hosts_file):
+        deadline = time.time() + 90
+        while time.time() < deadline:
+            if progress.exists():
+                lines = progress.read_text().splitlines()
+                if any(int(ln.split()[0]) >= 2 and ln.split()[1] == str(size)
+                       for ln in lines if len(ln.split()) == 2):
+                    break
+            time.sleep(0.2)
+        hosts_file.write_text(hosts + "\n")
+    return mutate
+
+
 def test_elastic_scale_up(tmp_path):
     """Start with 2 slots, discovery adds a third mid-run; all workers
     (including the late joiner) finish at the full iteration count."""
-    def mutate(hosts_file):
-        time.sleep(2.0)
-        hosts_file.write_text("localhost:3\n")
-
+    progress = tmp_path / "progress.log"
     rc, log, out = _run_elastic(
         tmp_path, "localhost:2",
-        {"TEST_ITERS": "14", "TEST_SLEEP": "0.25"},
-        min_np=2, max_np=4, mutate=mutate)
+        {"TEST_ITERS": "14", "TEST_SLEEP": "0.25",
+         "TEST_PROGRESS": str(progress)},
+        min_np=2, max_np=4, mutate=_mid_run(progress, 2, "localhost:3"))
     assert rc == 0, f"job failed rc={rc}\n{out}"
     finals = [line for line in log.splitlines() if line.startswith("final")]
     assert len(finals) == 3, f"expected 3 finishers:\n{log}\n{out}"
@@ -93,14 +115,13 @@ def test_elastic_mesh_scale_up(tmp_path):
     over a global jax mesh sized to membership. Scale-up 2→3 procs (2
     virtual devices each): every epoch's in-mesh psum equals the device
     count, and the final epoch spans 6 devices."""
-    def mutate(hosts_file):
-        time.sleep(2.5)
-        hosts_file.write_text("localhost:3\n")
-
+    progress = tmp_path / "progress.log"
     rc, log, out = _run_elastic(
         tmp_path, "localhost:2",
-        {"TEST_ITERS": "12", "TEST_SLEEP": "0.25"},
-        min_np=2, max_np=4, mutate=mutate, timeout=180, worker=MESH_WORKER)
+        {"TEST_ITERS": "12", "TEST_SLEEP": "0.25",
+         "TEST_PROGRESS": str(progress)},
+        min_np=2, max_np=4, mutate=_mid_run(progress, 2, "localhost:3"),
+        timeout=180, worker=MESH_WORKER)
     assert rc == 0, f"job failed rc={rc}\n{out}"
     finals = [line for line in log.splitlines() if line.startswith("final")]
     assert len(finals) == 3, f"expected 3 finishers:\n{log}\n{out}"
@@ -134,23 +155,12 @@ def test_elastic_mesh_scale_down(tmp_path):
     iterations at size 3, so slow jax startup cannot race the scale-down
     past the size-3 epochs."""
     progress = tmp_path / "progress.log"
-
-    def mutate(hosts_file):
-        deadline = time.time() + 90
-        while time.time() < deadline:
-            if progress.exists():
-                lines = progress.read_text().splitlines()
-                if any(int(ln.split()[0]) >= 2 and ln.split()[1] == "3"
-                       for ln in lines if len(ln.split()) == 2):
-                    break
-            time.sleep(0.2)
-        hosts_file.write_text("localhost:2\n")
-
     rc, log, out = _run_elastic(
         tmp_path, "localhost:3",
         {"TEST_ITERS": "16", "TEST_SLEEP": "0.4",
          "TEST_PROGRESS": str(progress)},
-        min_np=2, max_np=3, mutate=mutate, timeout=180, worker=MESH_WORKER)
+        min_np=2, max_np=3, mutate=_mid_run(progress, 3, "localhost:2"),
+        timeout=180, worker=MESH_WORKER)
     assert rc == 0, f"job failed rc={rc}\n{out}"
     finals = [line for line in log.splitlines() if line.startswith("final")]
     assert len(finals) == 2, f"expected 2 finishers:\n{log}\n{out}"
@@ -278,14 +288,12 @@ def test_incremental_epoch_preserves_survivor_ranks():
 def test_elastic_scale_down(tmp_path):
     """Discovery removes a slot mid-run: the excess worker is told to exit
     via the KV directive, the rest re-rendezvous at size=2 and finish."""
-    def mutate(hosts_file):
-        time.sleep(2.0)
-        hosts_file.write_text("localhost:2\n")
-
+    progress = tmp_path / "progress.log"
     rc, log, out = _run_elastic(
         tmp_path, "localhost:3",
-        {"TEST_ITERS": "14", "TEST_SLEEP": "0.25"},
-        min_np=2, max_np=3, mutate=mutate)
+        {"TEST_ITERS": "14", "TEST_SLEEP": "0.25",
+         "TEST_PROGRESS": str(progress)},
+        min_np=2, max_np=3, mutate=_mid_run(progress, 3, "localhost:2"))
     assert rc == 0, f"job failed rc={rc}\n{out}"
     finals = [line for line in log.splitlines() if line.startswith("final")]
     assert len(finals) == 2, f"expected 2 finishers:\n{log}\n{out}"
@@ -309,7 +317,11 @@ def test_elastic_torch_failure_recovery(tmp_path):
         {"TEST_ITERS": "8", "TEST_SLEEP": "0.1",
          "TEST_FAIL_SLOT": "1", "TEST_MARKER": str(marker),
          "JAX_PLATFORMS": "cpu"},
-        min_np=2, max_np=2, worker=TORCH_WORKER)
+        # The TF twin's allowance: the job itself takes 12 s, but the first
+        # torch test of a fresh machine JIT-builds the native extension
+        # (csrc/torch_ops.cc, a minute and a half beside five busy workers)
+        # and three workers in turn import torch.
+        min_np=2, max_np=2, worker=TORCH_WORKER, timeout=240)
     assert rc == 0, f"job failed rc={rc}\n{out}"
     assert marker.exists(), "failure was never injected"
     finals = [line for line in log.splitlines() if line.startswith("final")]

@@ -54,36 +54,6 @@ def test_estimator_example_torch_and_lightning(tmp_path):
     assert "lightning loss" in p.stdout
 
 
-def test_bn_sweep_driver_smoke():
-    """examples/resnet_bn_sweep.py end-to-end on the CPU smoke path, one
-    variant: guards the sweep's child-env plumbing (a PYTHONPATH clobber
-    there once failed every variant with an opaque rc=1 — round 5) and
-    the summary-table path."""
-    import json
-    import subprocess
-    import sys
-
-    from .util import tpu_isolated_env
-
-    # Drop ambient HVD_* (a developer's exported bench tunables — e.g. a
-    # TPU-only compiler option — would change or break the CPU child).
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("HVD_")}
-    env.update(tpu_isolated_env())
-    env.update({"SWEEP_ONLY": "baseline", "HVD_BENCH_BATCH": "4"})
-    # Timeout must clear the child's own BENCH_DEADLINE=420 (the sweep
-    # itself allows 600 s per variant for the same reason).
-    p = subprocess.run(
-        [sys.executable, os.path.join(_EXAMPLES, "resnet_bn_sweep.py")],
-        env=env, capture_output=True, text=True, timeout=600)
-    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr}"
-    lines = [json.loads(ln) for ln in p.stdout.splitlines()
-             if ln.strip().startswith("{")]
-    base = [d for d in lines if d.get("variant") == "baseline"]
-    assert base and base[0].get("value", 0) > 0, lines
-    assert "vs baseline" in p.stdout  # summary table printed
-
-
 def test_pipeline_example():
     """examples/pipeline_train.py: 4 transformer-block GPipe stages x
     2-way dp on the virtual mesh, loss falls."""

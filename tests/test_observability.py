@@ -123,7 +123,7 @@ def test_snapshot_shape(metrics_on):
     assert fam["type"] == "counter"
     assert fam["samples"] == [
         {"labels": {"op": "allreduce", "process_set": "0"}, "value": 1.0}]
-    json.dumps(snap)  # must be JSON-able (bench.py attaches it)
+    json.dumps(snap)  # must be JSON-able
 
 
 def test_record_call_families(metrics_on):

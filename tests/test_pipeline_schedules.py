@@ -5,8 +5,7 @@ deliberately jax-free: the tables are trace-time numpy arrays the
 compiled scan indexes, so every invariant here — occupancy orderings,
 collision freedom, ZB weight-grad placement, knob parsing — is testable
 without a jax install. The module is loaded standalone (the parallel
-package __init__ imports jax; the tables don't need it), the same way
-bench.py's schedule accounting loads it.
+package __init__ imports jax; the tables don't need it).
 
 Execution parity (every schedule x stage count x dp vs the
 single-device reference, outputs AND gradients) lives in

@@ -76,7 +76,7 @@ def test_scalar_tier_forced_2rank():
 
 def test_reduce_stats_no_init_required():
     """reduce_stats()/reduce_bench() are process-global — usable before
-    init (bench.py's `reduce` config relies on this)."""
+    init."""
     fast0, fe0, scalar0, se0 = hvd.reduce_stats()
     secs = hvd.reduce_bench(5, 4096, iters=1, vector=True)  # kFloat32
     assert secs > 0

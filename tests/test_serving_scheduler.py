@@ -6,8 +6,8 @@ the paged KV cache sound — page conservation, no double-allocation,
 strict-ownership frees, admission/eviction at token boundaries,
 batch-fill monotonicity under backlog — are all testable without an
 accelerator stack. Modules are loaded standalone (the serving package
-lazy-imports, but standalone load keeps parity with how bench.py's
-jax-free parent would read them), the test_pipeline_schedules.py idiom.
+lazy-imports, but a standalone load proves they need no accelerator
+stack), the test_pipeline_schedules.py idiom.
 
 Engine-side coverage (prefill/decode parity against forward(), the
 mixed-length jit'd step, the ServeLoop A/B) lives in

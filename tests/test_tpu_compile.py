@@ -133,7 +133,7 @@ def test_ring_attention_flash_compiles_on_four_chips(topo):
 
 
 def test_ragged_expert_dispatch_compiles_on_four_chips(topo):
-    """bench.py's MoE shapes (4096 tokens a chip, d=1024, d_ff=4096, 8
+    """MoE training shapes (4096 tokens a chip, d=1024, d_ff=4096, 8
     experts) through the ragged all-to-all on a 4-device ``expert`` mesh."""
     mesh = Mesh(np.array(topo.devices), ("expert",))
     T, D, F, E = 4 * 4096, 1024, 4096, 8

@@ -53,10 +53,9 @@ def run_worker_job(np_, worker_file, extra_env=None, timeout=120,
 # it uses interceptors, run an np_-rank job, and parse the per-rank report
 # files down to the reports that name the core.
 
-CSRC = os.path.join(_REPO, "horovod_tpu", "csrc")
-
 SANITIZER_TIERS = {
-    # make target == tier name; lib = the HVD_LIB each tier loads.
+    # The tier name is the build target (horovod_tpu/_build_lock.py maps it
+    # to the library HVD_LIB then names).
     # preload: sanitizer runtimes with malloc/pthread interceptors must be
     # first in the link order, i.e. LD_PRELOADed into (uninstrumented)
     # python. UBSAN has no interceptors and the debug tier no runtime at
@@ -66,25 +65,21 @@ SANITIZER_TIERS = {
     # EstablishMesh's re-dial path) would then trip the interceptor's
     # "real___cxa_throw != 0" CHECK and silently _exit with `exitcode`.
     "tsan": {
-        "lib": "libhvd_tpu_tsan.so",
         "preload": ["libtsan.so", "libstdc++.so.6"],
         "options_var": "TSAN_OPTIONS",
         "options": "exitcode=0",
     },
     "asan": {
-        "lib": "libhvd_tpu_asan.so",
         "preload": ["libasan.so", "libstdc++.so.6"],
         "options_var": "ASAN_OPTIONS",
         "options": "exitcode=0:detect_leaks=1",
     },
     "ubsan": {
-        "lib": "libhvd_tpu_ubsan.so",
         "preload": None,
         "options_var": "UBSAN_OPTIONS",
         "options": "exitcode=0:print_stacktrace=1",
     },
     "debug": {  # -O0 -DHVD_DEBUG: lockdep on by default (debug_lock.h)
-        "lib": "libhvd_tpu_debug.so",
         "preload": None,
         "options_var": None,
         "options": None,
@@ -150,13 +145,18 @@ def run_under_sanitizer(tmp_path, worker, np_, tier="tsan", extra_env=None,
             missing = spec["preload"][libs.index(None)]
             pytest.skip("gcc/%s unavailable" % missing)
         preload = " ".join(libs)
-    subprocess.run(["make", "-s", tier], cwd=CSRC, check=True)
+    # The one builder of every core tier: under the tier's build lock, so
+    # two xdist workers that want the same tier compile it once and neither
+    # loads a library the other is still linking.
+    from horovod_tpu import _build_lock
+
+    lib = _build_lock.build_core(tier)
 
     env = dict(os.environ)
     env.update({
         "PYTHONPATH": _REPO,
         "JAX_PLATFORMS": "cpu",
-        "HVD_LIB": os.path.join(_REPO, "horovod_tpu", "lib", spec["lib"]),
+        "HVD_LIB": lib,
         # LeakSanitizer's exit path (Die -> _exit) skips stdio flush: a
         # worker whose process has ambient python-internal leaks would
         # lose its block-buffered PASS line when stdout is a pipe.

@@ -10,6 +10,7 @@ Env knobs (set by the test):
 - TEST_SLEEP: per-iteration sleep seconds
 - TEST_FAIL_SLOT: slot index that dies once at iteration 3
 - TEST_MARKER: marker file recording that the death already happened
+- TEST_PROGRESS: file rank 0 appends "<iteration> <size>" to after each commit
 """
 
 import os
@@ -66,6 +67,12 @@ def train(state):
         state.total = state.total + out
         state.iteration += 1
         state.commit()
+        # Progress beacon for tests that change the membership only after
+        # real training happened at the current size.
+        pf = os.environ.get("TEST_PROGRESS")
+        if pf and hvd.rank() == 0:
+            with open(pf, "a") as f:
+                f.write(f"{state.iteration} {hvd.size()}\n")
         time.sleep(SLEEP)
     return hvd.rank(), hvd.size()
 

@@ -203,7 +203,7 @@ def check_arm_stats(root):
     out = []
     # The C++ writer's header literal and the shared Python schema table
     # must be the SAME row layout, or every consumer slicing columns by
-    # name (worker asserts, bench.py autotune, operator tooling) reads
+    # name (worker asserts, operator tooling) reads
     # skewed fields.
     schema_cols, schema_path = _schema_columns(root)
     if csv_cols is not None and schema_cols is not None \
