@@ -8,13 +8,26 @@ VMEM (online softmax forward, FlashAttention-2 recomputation backward)
 with float32 accumulators in scratch, so memory is O(S·D) and 16k+
 sequences train on one chip.
 
-Structure: every kernel runs on a grid ``(B*H, blocks, blocks)`` whose
-innermost dimension streams the contraction blocks (K blocks for the
-forward/dq kernels, Q blocks for the dk/dv kernel); accumulators live in
-VMEM scratch, initialized on the first inner step and flushed to the
-output refs on the last. Causal skipping is predicated (``@pl.when``), so
-masked-out block pairs cost a prefetch but no MXU time. ``block_q ==
-block_k`` keeps the causal frontier exactly one diagonal block.
+Structure: every kernel runs on a grid ``(B*H, live block pairs)``: the
+pairs a causal mode keeps (``blocks * (blocks + 1) / 2`` of them; all in
+mode ``"none"``) are enumerated on the host into two int32 tables that
+ride in SMEM as scalar prefetch, and the index maps and the kernels read
+their block coordinates from them. A masked-out pair is therefore never a
+grid step and never a copy. The inner coordinate streams the contraction
+blocks (K blocks for the forward/dq kernels, Q blocks for the dk/dv
+kernel); accumulators live in VMEM scratch, initialized on a row's first
+pair and flushed to the output refs on its last. ``block_q == block_k``
+keeps the causal frontier exactly one diagonal block.
+
+Precision: every product takes its operands in the dtype the arrays have
+and accumulates in float32; scores, softmax statistics, lse, delta and the
+accumulators are float32, and ``p`` / ``ds`` are cast to the input dtype
+only as MXU operands. That is what the chip did before it was written
+down: Mosaic's default float32 product is ONE bfloat16 pass, so widening
+bf16 blocks bought nothing (same bits, same time; PERF.md, PR 32). What
+bounds the kernels is not the MXU but a grid step's fixed cost and the
+per-row softmax bookkeeping, both of which a larger tile amortizes; see
+``_validate``.
 
 No counterpart exists in the reference (its attention lives in user
 scripts / framework libraries); this is the "pallas kernels for the hot
@@ -31,22 +44,31 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
 
-def _vspec(block, index_map=None):
-    return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
-
-
 def _scratch(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
+def _dot(a, b, contract):
+    """a . b over ``contract`` = (dims of a, dims of b): operands as they
+    are, float32 result."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))    # [m, c] . [n, c] -> [m, n]
+_NN = ((1,), (0,))    # [m, c] . [c, n] -> [m, n]
+_TN = ((0,), (0,))    # [c, m] . [c, n] -> [m, n]
+
+
 def _diag_keep(diag, mode, bq, bk):
-    """Visibility mask for the diagonal block; off-diagonal active blocks
+    """Visibility mask for the diagonal block; off-diagonal live blocks
     are fully visible (block_q == block_k). mode: "diag" = q >= k
     (ordinary causal); "strict" = q > k (the half-open masks ring
     attention's striped layout needs for cross-shard blocks).
@@ -60,21 +82,28 @@ def _diag_keep(diag, mode, bq, bk):
     return jnp.logical_not(diag) | keep
 
 
-def _active(mode, qi, kj):
-    """Block-level causal frontier: with any causal mode, key blocks past
-    the diagonal contribute nothing."""
-    if mode == "none":
-        return jnp.bool_(True)
-    return kj <= qi
+def _q_operand(q_ref, sm_scale):
+    """-> (q as the MXU takes it, the factor its products still owe).
+    float32 inputs fold ``sm_scale`` into q, which is the program these
+    kernels always were; narrower inputs go to the MXU as they are, and
+    the scale is applied in float32 (to the scores, and to dk)."""
+    q = q_ref[0]
+    if q.dtype == jnp.float32:
+        return q * sm_scale, None
+    return q, sm_scale
+
+
+def _scaled(x, factor):
+    return x if factor is None else x * factor
 
 
 # ---------------------------------------------------------------------------
-# Forward: grid (B*H, nq, nk) — K/V blocks stream through the inner dim.
+# Forward: grid (B*H, pairs), K/V blocks streaming under each Q block.
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
-                sm_scale, mode):
-    qi, kj = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                m_s, l_s, acc_s, *, sm_scale, mode, n):
+    t = pl.program_id(1)
+    qi, kj = qi_ref[t], kj_ref[t]
 
     @pl.when(kj == 0)
     def _init():
@@ -82,29 +111,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    @pl.when(_active(mode, qi, kj))
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * sm_scale        # [bq, D]
-        k = k_ref[0].astype(jnp.float32)                   # [bk, D]
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if mode != "none":
-            keep = _diag_keep(kj == qi, mode, *s.shape)
-            s = jnp.where(keep, s, _NEG_INF)
-        m_prev, l_prev = m_s[:], l_s[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if mode != "none":
-            p = jnp.where(keep, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        m_s[:] = m_new
-        l_s[:] = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    q, owed = _q_operand(q_ref, sm_scale)                  # [bq, D]
+    v = v_ref[0]                                           # [bk, D]
+    s = _scaled(_dot(q, k_ref[0], _NT), owed)              # [bq, bk] f32
+    if mode != "none":
+        keep = _diag_keep(kj == qi, mode, *s.shape)
+        s = jnp.where(keep, s, _NEG_INF)
+    m_prev, l_prev = m_s[:], l_s[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    if mode != "none":
+        p = jnp.where(keep, p, 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    m_s[:] = m_new
+    l_s[:] = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
+    acc_s[:] = acc_s[:] * alpha + _dot(p.astype(v.dtype), v, _NN)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(kj == (n - 1 if mode == "none" else qi))
     def _flush():
         # Fully-masked rows (row 0 under mode="strict") have l == 0: emit
         # o = 0 and lse = -inf-ish instead of NaN so downstream online
@@ -118,79 +141,62 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
 # ---------------------------------------------------------------------------
 # Backward (FlashAttention-2): recompute P per block pair.
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_s, *, sm_scale, mode):
-    qi, kj = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+def _bwd_dq_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dq_ref, dq_s, *, sm_scale, mode, n):
+    t = pl.program_id(1)
+    qi, kj = qi_ref[t], kj_ref[t]
 
     @pl.when(kj == 0)
     def _init():
         dq_s[:] = jnp.zeros_like(dq_s)
 
-    @pl.when(_active(mode, qi, kj))
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0, 0][:, None]
-        delta = delta_ref[0, 0, 0][:, None]
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        p = jnp.exp(s - lse)
-        if mode != "none":
-            # explicit zero, not just s = -1e30: a fully-masked row's
-            # sentinel lse would cancel the sentinel s in the exp.
-            p = jnp.where(_diag_keep(kj == qi, mode, *s.shape), p, 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_s[:] = dq_s[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    q, owed = _q_operand(q_ref, sm_scale)
+    k = k_ref[0]
+    lse = lse_ref[0, 0, 0][:, None]
+    delta = delta_ref[0, 0, 0][:, None]
+    s = _scaled(_dot(q, k, _NT), owed)
+    p = jnp.exp(s - lse)
+    if mode != "none":
+        # explicit zero, not just s = -1e30: a fully-masked row's
+        # sentinel lse would cancel the sentinel s in the exp.
+        p = jnp.where(_diag_keep(kj == qi, mode, *s.shape), p, 0.0)
+    dp = _dot(do_ref[0], v_ref[0], _NT)
+    ds = p * (dp - delta)
+    dq_s[:] = dq_s[:] + _dot(ds.astype(k.dtype), k, _NN)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(kj == (n - 1 if mode == "none" else qi))
     def _flush():
         dq_ref[0] = (dq_s[:] * sm_scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_s, dv_s, *, sm_scale, mode):
-    # Grid (B*H, nk, nq): Q blocks stream through the inner dim.
-    kj, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+def _bwd_dkv_kernel(kj_ref, qi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dk_ref, dv_ref, dk_s, dv_s, *, sm_scale,
+                    mode, n):
+    # Q blocks (with dO, lse, delta) stream under each K/V block.
+    t = pl.program_id(1)
+    kj, qi = kj_ref[t], qi_ref[t]
 
-    @pl.when(qi == 0)
+    @pl.when(qi == (0 if mode == "none" else kj))
     def _init():
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    @pl.when(_active(mode, qi, kj))
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0, 0][:, None]
-        delta = delta_ref[0, 0, 0][:, None]
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        p = jnp.exp(s - lse)                                  # [bq, bk]
-        if mode != "none":
-            p = jnp.where(_diag_keep(kj == qi, mode, *s.shape), p, 0.0)
-        dv_s[:] = dv_s[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk_s[:] = dk_s[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    q, owed = _q_operand(q_ref, sm_scale)
+    do = do_ref[0]
+    lse = lse_ref[0, 0, 0][:, None]
+    delta = delta_ref[0, 0, 0][:, None]
+    s = _scaled(_dot(q, k_ref[0], _NT), owed)              # [bq, bk]
+    p = jnp.exp(s - lse)
+    if mode != "none":
+        p = jnp.where(_diag_keep(kj == qi, mode, *s.shape), p, 0.0)
+    dv_s[:] = dv_s[:] + _dot(p.astype(do.dtype), do, _TN)
+    dp = _dot(do, v_ref[0], _NT)
+    ds = p * (dp - delta)
+    dk_s[:] = dk_s[:] + _dot(ds.astype(q.dtype), q, _TN)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(qi == n - 1)
     def _flush():
-        dk_ref[0] = dk_s[:].astype(dk_ref.dtype)
+        dk_ref[0] = _scaled(dk_s[:], owed).astype(dk_ref.dtype)
         dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
 
 
@@ -208,82 +214,83 @@ def _unfold(x, B, H):
     return x.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
 
-def _compiler_params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+def _live_pairs(n, mode, q_under_k=False):
+    """The block pairs a kernel visits, in grid order, as two int32 tables
+    (outer, inner), inner fastest. A causal mode keeps the K blocks
+    ``0..outer`` under a Q block, or with ``q_under_k`` the Q blocks
+    ``outer..n-1`` under a K block."""
+    outer, inner = np.divmod(np.arange(n * n, dtype=np.int32), n)
+    if mode != "none":
+        live = inner >= outer if q_under_k else inner <= outer
+        outer, inner = outer[live], inner[live]
+    return jnp.asarray(outer), jnp.asarray(inner)
+
+
+def _spec(block_shape, table):
+    """The block of a ``[BH, S, D]`` operand (``(1, block, D)``) or of an
+    lse-shaped ``[BH, n, 1, block]`` one (``(1, 1, 1, block)``) that table
+    0 (outer) or 1 (inner) names for this grid step."""
+    tail = (0,) * (len(block_shape) - 2)
+    return pl.BlockSpec(block_shape,
+                        lambda bh, t, *tables: (bh, tables[table][t]) + tail,
+                        memory_space=pltpu.VMEM)
+
+
+def _call(kernel, pairs, in_specs, out_specs, out_shape, scratch, interpret,
+          operands, **kw):
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(operands[0].shape[0], pairs[0].shape[0]),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, **kw)(*pairs, *operands)
 
 
 def _call_fwd(q, k, v, sm_scale, mode, block, interpret):
     BH, S, D = q.shape
     n = S // block
-    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, mode=mode)
+    outer, inner = _spec((1, block, D), 0), _spec((1, block, D), 1)
     flops = 4 * BH * S * S * D // (1 if mode == "none" else 2)
-    return pl.pallas_call(
-        kernel,
-        grid=(BH, n, n),
-        in_specs=[
-            _vspec((1, block, D), lambda bh, i, j: (bh, i, 0)),
-            _vspec((1, block, D), lambda bh, i, j: (bh, j, 0)),
-            _vspec((1, block, D), lambda bh, i, j: (bh, j, 0)),
-        ],
-        out_specs=[
-            _vspec((1, block, D), lambda bh, i, j: (bh, i, 0)),
-            _vspec((1, 1, 1, block), lambda bh, i, j: (bh, i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, n, 1, block), jnp.float32),
-        ],
-        scratch_shapes=[_scratch((block, 1)), _scratch((block, 1)),
-                        _scratch((block, D))],
-        compiler_params=_compiler_params(),
+    return _call(
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, mode=mode, n=n),
+        _live_pairs(n, mode),
+        [outer, inner, inner], [outer, _spec((1, 1, 1, block), 0)],
+        [jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+         jax.ShapeDtypeStruct((BH, n, 1, block), jnp.float32)],
+        [_scratch((block, 1)), _scratch((block, 1)), _scratch((block, D))],
+        interpret, (q, k, v),
         cost_estimate=pl.CostEstimate(
             flops=flops, transcendentals=BH * S * S,
-            bytes_accessed=3 * BH * S * D * q.dtype.itemsize),
-        interpret=interpret,
-    )(q, k, v)
+            bytes_accessed=3 * BH * S * D * q.dtype.itemsize))
 
 
 def _call_bwd(q, k, v, do, lse, delta, sm_scale, mode, block, interpret):
     BH, S, D = q.shape
     n = S // block
+    outer, inner = _spec((1, block, D), 0), _spec((1, block, D), 1)
+    row_o, row_i = _spec((1, 1, 1, block), 0), _spec((1, 1, 1, block), 1)
+    operands = (q, k, v, do, lse, delta)
 
-    def q_blk(sel):
-        return _vspec((1, block, D), lambda bh, i, j: (bh, sel(i, j), 0))
+    # Q, dO, lse, delta at the outer (Q) block; K, V stream.
+    dq, = _call(
+        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, mode=mode, n=n),
+        _live_pairs(n, mode),
+        [outer, inner, inner, outer, row_o, row_o], [outer],
+        [jax.ShapeDtypeStruct((BH, S, D), q.dtype)],
+        [_scratch((block, D))], interpret, operands)
 
-    def lse_blk(sel):
-        return _vspec((1, 1, 1, block),
-                      lambda bh, i, j: (bh, sel(i, j), 0, 0))
-
-    i_of = lambda i, j: i  # noqa: E731
-    j_of = lambda i, j: j  # noqa: E731
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, mode=mode),
-        grid=(BH, n, n),
-        in_specs=[q_blk(i_of), q_blk(j_of), q_blk(j_of), q_blk(i_of),
-                  lse_blk(i_of), lse_blk(i_of)],
-        out_specs=q_blk(i_of),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        scratch_shapes=[_scratch((block, D))],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-
-    # Grid (BH, nk, nq): the kernel reads K/V at the middle index and
-    # streams Q/dO/lse/delta along the inner one.
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, mode=mode),
-        grid=(BH, n, n),
-        in_specs=[q_blk(j_of), q_blk(i_of), q_blk(i_of), q_blk(j_of),
-                  lse_blk(j_of), lse_blk(j_of)],
-        out_specs=[q_blk(i_of), q_blk(i_of)],
-        out_shape=[jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, S, D), v.dtype)],
-        scratch_shapes=[_scratch((block, D)), _scratch((block, D))],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    # K, V at the outer (K) block; Q, dO, lse, delta stream.
+    dk, dv = _call(
+        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, mode=mode, n=n),
+        _live_pairs(n, mode, q_under_k=True),
+        [inner, outer, outer, inner, row_i, row_i], [outer, outer],
+        [jax.ShapeDtypeStruct((BH, S, D), k.dtype),
+         jax.ShapeDtypeStruct((BH, S, D), v.dtype)],
+        [_scratch((block, D)), _scratch((block, D))], interpret, operands)
     return dq, dk, dv
 
 
@@ -319,11 +326,26 @@ def _flash_bwd(mode, sm_scale, block, interpret, res, cts):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# What a kernel's time is made of on the chip is a grid step's fixed cost
+# and the forward's per-row softmax bookkeeping, both per block pair, far
+# more than the MXU: at [16, 4096, 64] bf16 on a v5e the three kernels take
+# 154.2 / 72.6 / 58.5 ms a 24-layer step at blocks of 256 / 512 / 1024, the
+# same at head_dim 128, and 1024 is the better of the last two at every S
+# from 1024 to 8192 (PERF.md, PR 32). So a request for a large tile (512
+# or more) gets the largest whose float32 score tiles (4 MB each, several
+# live) fit the kernel's VMEM, wherever the sequence divides by it; a
+# smaller request is a caller testing or debugging the block loop, and
+# stands.
+_BIG_BLOCK = 1024
+
+
 def _validate(q, k, v, block):
     B, S, H, D = q.shape
     if k.shape != q.shape or v.shape != q.shape:
         raise ValueError(f"q/k/v shapes must match, got {q.shape} "
                          f"{k.shape} {v.shape}")
+    if block >= _BIG_BLOCK // 2 and S % _BIG_BLOCK == 0:
+        block = _BIG_BLOCK
     block = min(block, S)
     if S % block != 0 or block % 8 != 0:
         # Largest multiple-of-8 divisor of S that fits: callers shouldn't
@@ -364,7 +386,8 @@ def flash_attention(q, k, v, *, causal=True, sm_scale=None, block=256,
     and k/v). Returns ``[B, S, H, D]`` in the input dtype; softmax and
     accumulation run in float32 on-chip.
 
-    ``block`` is both the query and key block size (S must divide by it);
+    ``block`` asks for the query and key block size: clipped to S, shrunk
+    to a divisor of S, and from 512 up taken as "large" (``_validate``);
     ``interpret=True`` runs the kernels in the Pallas interpreter (CPU).
     """
     o, _ = flash_attention_lse(q, k, v,

@@ -78,6 +78,153 @@ def test_bf16_inputs_and_partial_block():
                         interpret=True)
 
 
+def _out_and_grads(attn, q, k, v):
+    """attn's output and the gradients of a loss over it, as float32."""
+    def loss(q, k, v):
+        o = attn(q, k, v)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32))), o
+
+    (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v)
+    return [np.asarray(a, np.float32) for a in (o,) + tuple(g)]
+
+
+@pytest.mark.parametrize("block", [64, 256])
+def test_bf16_forward_and_gradients_match_gather_and_naive(block):
+    """bf16 operands into every product, f32 everything else: forward and
+    the three gradients agree with the gather attention the S 512 cells
+    run (bf16 logits, so the coarser of the two) on the same bf16 inputs,
+    and with the float32 naive attention to what bf16 inputs allow."""
+    from horovod_tpu.models import transformer as tfm
+
+    rng = np.random.default_rng(7)
+    B, S, H, D = 1, 256, 2, 64
+    cfg = tfm.TransformerConfig(vocab_size=8, d_model=H * D, n_heads=H,
+                                n_layers=1, d_ff=8, max_seq_len=S,
+                                dtype="bfloat16")
+    q, k, v = (jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.bfloat16)
+               for _ in range(3))
+    flash = _out_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, block=block,
+                                        interpret=True), q, k, v)
+    gather = _out_and_grads(
+        lambda q, k, v: tfm.causal_attend(q, k, v, cfg), q, k, v)
+    naive = _out_and_grads(
+        lambda q, k, v: _naive(q.astype(jnp.float32), k.astype(jnp.float32),
+                               v.astype(jnp.float32), True), q, k, v)
+    for f, g, n, name in zip(flash, gather, naive, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(f, g, atol=4e-2, rtol=4e-2,
+                                   err_msg=f"{name} against gather")
+        np.testing.assert_allclose(f, n, atol=2e-2, rtol=2e-2,
+                                   err_msg=f"{name} against f32 naive")
+        # and closer to the float32 answer than the gather path is
+        assert np.abs(f - n).mean() <= np.abs(g - n).mean(), name
+
+
+def _sub_jaxprs(params):
+    for val in params.values():
+        for x in (val if isinstance(val, (tuple, list)) else (val,)):
+            x = getattr(x, "jaxpr", x)         # ClosedJaxpr -> Jaxpr
+            if hasattr(x, "eqns"):
+                yield x
+
+
+def _eqns(jaxpr, name):
+    """Every equation of primitive ``name`` in ``jaxpr``, nested ones too
+    (not looking inside a match)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        else:
+            for sub in _sub_jaxprs(eqn.params):
+                yield from _eqns(sub, name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("mode", ["diag", "strict", "none"])
+def test_every_product_takes_input_dtype_and_gives_f32(dtype, mode):
+    """Forward and both backward kernels: each dot_general's operands have
+    the arrays' dtype (nothing is widened on its way to the MXU) and its
+    result is float32 (nothing is accumulated narrower)."""
+    from horovod_tpu.ops.pallas_attention import flash_attention_lse
+
+    x = jnp.zeros((1, 256, 2, 64), dtype)
+
+    def loss(q, k, v):
+        o, lse = flash_attention_lse(q, k, v, mode=mode, block=128,
+                                     interpret=True)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    kernels = list(_eqns(jaxpr.jaxpr, "pallas_call"))
+    dots = [[d for sub in _sub_jaxprs(kern.params)
+             for d in _eqns(sub, "dot_general")] for kern in kernels]
+    assert sorted(len(d) for d in dots) == [2, 3, 4]   # fwd, dq, dk/dv
+    for d in sum(dots, []):
+        assert [a.aval.dtype for a in d.invars] == [dtype, dtype], d
+        assert d.outvars[0].aval.dtype == jnp.float32, d
+    # scratch (m, l and the accumulators: rank 2) and the lse / delta
+    # blocks (rank 4) are float32 whatever the arrays are
+    for kern in kernels:
+        for sub in _sub_jaxprs(kern.params):
+            refs = [a.aval for a in sub.invars]
+            assert len([r for r in refs if len(r.shape) == 2]) in (1, 2, 3)
+            assert all(r.dtype == jnp.float32 for r in refs
+                       if len(r.shape) in (2, 4)), refs
+
+
+# sha256 (first 16 hex digits) over o, lse, dq, dk, dv of _f32_digest's
+# call, taken at the commit before the kernels' operands and grid changed
+# (64669e3). head_dim 32: a scale that is no power of two.
+_F32_DIGESTS = {
+    ("diag", 32): "67e552e11127834d", ("diag", 64): "4615fbbeac834dab",
+    ("strict", 32): "5923e9dabd78b35b", ("strict", 64): "b24c57d2027f3384",
+    ("none", 32): "a3b20c128ab91c5e", ("none", 64): "fd5fe36600207941",
+}
+
+
+@pytest.mark.parametrize("mode, D", sorted(_F32_DIGESTS))
+def test_float32_inputs_give_the_same_bits_as_before(mode, D):
+    """The kernels adapt to the input dtype: float32 arrays run the
+    program they always ran, bit for bit, forward and gradients."""
+    import hashlib
+
+    from horovod_tpu.ops.pallas_attention import flash_attention_lse
+
+    rng = np.random.default_rng(32)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 256, 2, D)), jnp.float32)
+               for _ in range(3))
+
+    def f(q, k, v):
+        o, lse = flash_attention_lse(q, k, v, mode=mode, block=64,
+                                     interpret=True)
+        loss = jnp.sum(jnp.sin(o)) + jnp.sum(jnp.where(lse > -1e29, lse, 0.0))
+        return loss, (o, lse)
+
+    (_, (o, lse)), g = jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True)(q, k, v)
+    h = hashlib.sha256()
+    for a in (o, lse) + tuple(g):
+        h.update(np.ascontiguousarray(np.asarray(a, np.float32)).tobytes())
+    assert h.hexdigest()[:16] == _F32_DIGESTS[mode, D]
+
+
+def test_large_block_request_takes_the_big_tile():
+    """_validate: 512 or more asks for "large" and gets 1024 where the
+    sequence divides by it; a smaller request stands."""
+    from horovod_tpu.ops.pallas_attention import _validate
+
+    def used(S, block):
+        x = jax.ShapeDtypeStruct((1, S, 2, 64), jnp.bfloat16)
+        return _validate(x, x, x, block)
+
+    assert used(4096, 512) == used(4096, 1024) == used(4096, 2048) == 1024
+    assert used(1024, 512) == 1024
+    assert used(1536, 512) == 512 and used(512, 512) == 512
+    assert used(4096, 256) == 256 and used(4096, 128) == 128
+    assert used(384, 256) == 192          # largest multiple-of-8 divisor
+
+
 def test_transformer_flash_impl_matches_gather():
     """attn_impl='flash' in the transformer produces the same logits as the
     XLA 'gather' path — single device and on a dp x tp mesh (shard_map)."""
@@ -110,14 +257,17 @@ def test_transformer_flash_impl_matches_gather():
                                atol=2e-2, rtol=2e-2)
 
 
-def test_strict_mode_and_masked_rows():
+@pytest.mark.parametrize("n_blocks", [2, 3, 8])
+def test_strict_mode_and_masked_rows(n_blocks):
     """mode="strict" (q > k, ring striped cross-shard mask): row 0 is
     fully masked and must return o = 0, lse = sentinel, and ZERO
-    gradients — the -1e30 sentinel must not cancel in exp(s - m)."""
+    gradients — the -1e30 sentinel must not cancel in exp(s - m). At 2, 3
+    and 8 blocks: the enumeration of live block pairs keeps every row's
+    diagonal block and nothing past it."""
     from horovod_tpu.ops.pallas_attention import flash_attention_lse
 
     rng = np.random.default_rng(6)
-    B, S, H, D = 1, 128, 2, 32
+    B, S, H, D = 1, 64 * n_blocks, 2, 32
     q, k, v = (jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
                for _ in range(3))
     o, lse = flash_attention_lse(q, k, v, mode="strict", block=64,

@@ -74,8 +74,12 @@ def _flash_loss(q, k, v):
     return _flash_fwd(q, k, v).astype(jnp.float32).sum()
 
 
-# bert_large() heads at the long-context and the dense training shapes.
-@pytest.mark.parametrize("shape", [(1, 4096, 16, 64), (8, 512, 16, 64)])
+# bert_large() heads at the long-context shape (`gpt2m-train-s4096`'s
+# [16, 4096, 64] bf16; a request of 512 runs 1024 x 1024 tiles there), at a
+# length only 512 divides (three blocks of the triangular grid) and at the
+# dense training shape (one block).
+@pytest.mark.parametrize("shape", [(1, 4096, 16, 64), (1, 1536, 16, 64),
+                                   (8, 512, 16, 64)])
 @pytest.mark.parametrize("fn, calls", [
     (_flash_fwd, 1),
     (jax.grad(_flash_loss, argnums=(0, 1, 2)), 3),   # fwd, dq, dkv
@@ -85,14 +89,17 @@ def test_flash_attention_compiles(topo, shape, fn, calls):
     assert _mosaic_calls(fn, x, x, x) == calls
 
 
-def test_flash_strict_mask_compiles(topo):
+@pytest.mark.parametrize("seq, block", [(2048, 512), (4096, 512),
+                                        (4096, 256)])
+def test_flash_strict_mask_compiles(topo, seq, block):
     """mode="strict" (q > k), which only ring attention's striped layout
-    drives, with the cotangent on lse that the ring's merge feeds back."""
+    drives, with the cotangent on lse that the ring's merge feeds back.
+    A block of 256 stands as asked (16 blocks, 136 live pairs)."""
     def loss(q, k, v):
-        o, lse = flash_attention_lse(q, k, v, mode="strict", block=512)
+        o, lse = flash_attention_lse(q, k, v, mode="strict", block=block)
         return o.astype(jnp.float32).sum() + lse.sum()
 
-    x = _on_chip(topo, (1, 2048, 16, 64))
+    x = _on_chip(topo, (1, seq, 16, 64))
     assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 3
 
 
