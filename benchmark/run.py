@@ -169,6 +169,13 @@ def main(argv=None):
             if value is not None:
                 line["metrics"][m["name"]] = {"value": value,
                                               "unit": m["unit"]}
+    # Each number that decided ``correct`` beside its limit: last in the line
+    # and the last lines on standard error.
+    line["compared"] = record.get("compared") or {}
+    for name, c in line["compared"].items():
+        sys.stderr.write(f"compared {name}: {c['value']!r} {c['holds']} "
+                         f"{c['limit']!r}\n")
+    sys.stderr.flush()
     sys.stdout.write(json.dumps(line) + "\n")
     sys.stdout.flush()
 

@@ -20,21 +20,15 @@ small device program per array (``transformer.init_params`` outside ``jit``),
 and the scales of every norm are then drawn around 1 (N(1, 0.1)), so that a
 norm left out cannot hide behind a scale of one.
 
-The window, the traffic and the latency fields are ``serve``'s (see
-``runners/serve.py``; the loop is observed and stopped through its
-``load_reporter`` hook). Beyond its record fields this one reports, for a
-model with experts, from ``hvd.serve_stats()["moe"]``:
+The offer, the window and its record fields and checks are
+``runners/_window.py``'s, as for ``serve``. Beyond them this one reports,
+for a model with experts, from ``hvd.serve_stats()["moe"]``:
 ``experts_touched_mean`` (experts a layer reads in a decode step),
 ``expert_load_max_over_mean``, ``moe_pairs_decode`` / ``moe_pairs_chunk``
 (routed (token, expert) pairs, summed over the layers), and over the traced
 stretch alone ``trace_moe`` (``pairs``, ``expert_reads`` and ``calls`` by
-program kind) for the roofline of the grouped products; ``host_s`` (the
-loop's host seconds by leaf kind); and from the check ``logits_rel``,
-``route_flip_share_pct`` and ``logits_rel_int8_weights``; and by segment of
-the window ``segment_tokens_per_s``, ``segment_boundaries`` and
-``segment_backlog_max``, and ``boundary_gap_ms_slowest`` (the eight longest
-stretches between two reports of the loop, ``[when s, how long ms]``):
-where a slow run lost its time.
+program kind) for the roofline of the grouped products; and from the check
+``logits_rel``, ``route_flip_share_pct`` and ``logits_rel_int8_weights``.
 
 ``correct``: for prompts of ``check_requests`` lengths, the prompt filled the
 way the loop fills it (chunk by chunk where the loop has no padded prefill),
@@ -49,7 +43,6 @@ choose another expert set than the reference does; they are counted
 import importlib.util
 import os
 import sys
-import time
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _CHECKOUT = os.path.dirname(os.path.dirname(_HERE))
@@ -61,10 +54,6 @@ def command(spec_path, spec):
     if spec["cell"]["chips"] != 1:
         raise SystemExit("runner serve_lm drives one replica on one chip")
     return [sys.executable, os.path.abspath(__file__), "--spec", spec_path]
-
-
-class _WindowOver(Exception):
-    pass
 
 
 def model_config(config):
@@ -107,147 +96,30 @@ def make_params(cfg, key):
 
 
 def worker(spec):
-    from benchmark import harness, traffic_gen
+    from benchmark import harness
+    from benchmark.runners import _window
 
-    t_cmd = spec["t_command"]
-    jax = harness.setup_jax()
-    import numpy as np
+    harness.setup_jax()
 
     from horovod_tpu.serving import kv_cache
-    from horovod_tpu.serving.loop import ServeLoop, serve_stats
-    from horovod_tpu.serving.scheduler import Request
+    from horovod_tpu.serving.loop import ServeLoop
 
     device = harness.require_device(spec)
-    runtime_init_seconds = time.time() - t_cmd
-    config, traffic = spec["config"], spec["traffic"]
-    seed, seconds = spec["seed"], float(spec["seconds"])
+    config, traffic, seed = spec["config"], spec["traffic"], spec["seed"]
     srv = config["assumed"]["serve"]
     cfg = model_config(config)
+    window = _window.ServeWindow(spec, cfg.vocab_size)
     reference = load_reference(config)
-    counter = harness.CompileCounter()
 
     params = make_params(cfg, harness.seed_key(seed))
     geo = kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"])
-    series = []          # (t, tokens so far, queue depth, fill, occupancy)
-    state = {"t0": None, "full_at": None, "tracer": None, "trace": None,
-             "moe0": None, "moe1": None}
-    want_trace = bool(spec["trace"])
-    trace_s = float(traffic["trace_s"]) if want_trace else 0.0
-
-    def on_boundary(queue_depth, fill, occupancy):
-        t = time.monotonic() - state["t0"]
-        series.append((t, serve_stats()["tokens"], queue_depth, fill,
-                       occupancy))
-        if state["full_at"] is None and fill >= 1.0:
-            state["full_at"] = t
-        if t < seconds:
-            return
-        # The window is over. A traced run now traces a stretch; then the
-        # loop stops.
-        if not want_trace:
-            raise _WindowOver
-        if state["tracer"] is None:
-            state["moe0"] = serve_stats().get("moe")
-            state["tracer"] = harness.Tracer(spec)
-            state["tracer"].start()
-        elif t >= seconds + trace_s:
-            state["moe1"] = serve_stats().get("moe")
-            state["trace"] = state["tracer"].stop()
-            raise _WindowOver
-
     loop = ServeLoop(params, cfg, geo=geo, max_batch=srv["max_batch"],
-                     load_reporter=on_boundary, report_interval=1)
+                     load_reporter=window.on_boundary, report_interval=1)
     loop.warmup()
+    window.run(loop)
+    fields, checks = window.reduce()
 
-    offered = traffic_gen.generate(traffic, seconds + trace_s, seed,
-                                   cfg.vocab_size)
-    requests = [Request(rid=r["rid"], prompt=r["prompt"],
-                        max_new_tokens=r["max_new_tokens"],
-                        arrival_t=r["due_s"], eos_id=traffic.get("eos_id", -1))
-                for r in offered]
-    harness.quiesce()
-
-    # ---- the measured window ------------------------------------------
-    counter.active = True
-    t_window = time.time()
-    state["t0"] = time.monotonic()
-    try:
-        loop.run(list(requests))
-    except _WindowOver:
-        pass
-    if state["tracer"] is not None and state["trace"] is None:
-        state["moe1"] = serve_stats().get("moe")
-        state["trace"] = state["tracer"].stop()     # the loop ran dry first
-    counter.active = False
-    # ---- window over ---------------------------------------------------
-    setup_seconds = t_window - t_cmd
-    peak = harness.memory_peak_bytes()
-    stats = serve_stats()
-
-    log = np.asarray(series, np.float64).reshape(-1, 5)
-    t_arr, depth = log[:, 0], log[:, 2]
-    emitted = np.diff(log[:, 1], prepend=0.0)
-    inside = t_arr < seconds
-    rates = traffic_gen.segment_rates(t_arr, emitted, 0.0, seconds,
-                                      traffic["segments"])
-    first_q = t_arr < seconds / 4
-    last_q = inside & (t_arr >= seconds * 3 / 4)
-    gaps = np.diff(t_arr[inside], prepend=0.0)
-    slowest = np.argsort(-gaps)[:8]
-    fields = {
-        "boundary_gap_ms_p50": float(np.median(gaps) * 1e3),
-        "boundary_gap_ms_slowest": [
-            [round(float(t_arr[i]), 2), round(float(gaps[i]) * 1e3, 1)]
-            for i in sorted(slowest)],
-        "setup_seconds": setup_seconds,
-        "runtime_init_seconds": runtime_init_seconds,
-        "tokens_per_s": float(emitted[inside].sum() / seconds),
-        "tokens_per_s_segment_median": traffic_gen.median(rates),
-        "segment_tokens_per_s": [float(r) for r in rates],
-        "segment_boundaries": np.histogram(
-            t_arr[inside], traffic["segments"], (0.0, seconds))[0].tolist(),
-        "segment_backlog_max": [
-            int(depth[inside & (t_arr >= a) & (t_arr < a + seconds /
-                                               traffic["segments"])]
-                .max(initial=0))
-            for a in np.linspace(0.0, seconds, traffic["segments"],
-                                 endpoint=False)],
-        "slots_full_s": state["full_at"],
-        "boundaries": int(inside.sum()),
-        "batch_fill_mean_pct": 100.0 * float(log[inside, 3].mean()),
-        "kv_occupancy_mean_pct": 100.0 * float(log[inside, 4].mean()),
-        "backlog_end": int(depth[inside][-1]),
-        "backlog_mean_first_quarter": float(depth[first_q].mean()),
-        "backlog_mean_last_quarter": float(depth[last_q].mean()),
-    }
-    checks = {"no_compile_in_window": counter.count == 0,
-              "loop_ran_the_whole_window": bool(t_arr[-1] >= seconds)}
-    due = [r for r in requests if r.arrival_t < seconds]
-    began = [r for r in due if r.admitted_t > 0 or r.first_token_t > 0]
-    first = [r for r in due if r.first_token_t > 0]
-    done = [r for r in due if r.finished_t > 0]
-    ttft = [(r.first_token_t - r.arrival_t) * 1e3 for r in first]
-    tpot = [(r.finished_t - r.first_token_t) / (len(r.generated) - 1) * 1e3
-            for r in done if len(r.generated) > 1]
-    wait = [(r.admitted_t - r.arrival_t) * 1e3 for r in began]
-    bad = [r for r in done if r.finish_reason not in ("max_tokens", "eos")]
-    pct = traffic_gen.percentile
-    fields.update({
-        "ttft_p50_ms": pct(ttft, 50), "ttft_p95_ms": pct(ttft, 95),
-        "tpot_p50_ms": pct(tpot, 50), "tpot_p95_ms": pct(tpot, 95),
-        "queue_wait_ms_p95": pct(wait, 95),
-        "requests_due": len(due), "requests_began": len(began),
-        "requests_first_token": len(first), "requests_finished": len(done),
-        "ttft_samples": len(ttft), "tpot_samples": len(tpot),
-        "prefill_single": stats.get("prefill_single"),
-        "prefill_batched": stats.get("prefill_batched"),
-        "chunk_fills": stats.get("chunk_fills"),
-        "preemptions": stats.get("preemptions"),
-        "prefix_hit_ratio_pct": 100.0 * stats.get("prefix_hit_ratio", 0.0),
-        "compiles_in_window": counter.count,
-        "host_s": stats.get("host_s"),
-    })
-    moe = stats.get("moe")
+    moe = window.stats.get("moe")
     if moe:
         fields.update({
             "experts_touched_mean": moe["experts_touched_mean"],
@@ -255,10 +127,11 @@ def worker(spec):
             "moe_pairs_decode": moe["pairs"].get("decode", 0),
             "moe_pairs_chunk": moe["pairs"].get("chunk", 0),
         })
-    if state["moe0"] and state["moe1"]:
+    moe0, moe1 = ((s or {}).get("moe") for s in window.stats_at_trace)
+    if moe0 and moe1:
         fields["trace_moe"] = {
-            name: {kind: n - state["moe0"][name].get(kind, 0)
-                   for kind, n in state["moe1"][name].items()}
+            name: {kind: n - moe0[name].get(kind, 0)
+                   for kind, n in moe1[name].items()}
             for name in ("pairs", "expert_reads", "calls")}
 
     # ---- correctness, after the window: logits, not tokens -------------
@@ -269,13 +142,7 @@ def worker(spec):
     fields.update(found, logits_tolerance=tol)
     checks["logits_vs_reference"] = bool(found["logits_rel"] <= tol)
 
-    device["memory_peak_bytes"] = peak
-    trace = state["trace"]
-    harness.write_record(spec, {
-        "device": device, "correct": all(checks.values()), "checks": checks,
-        "attempted": len(began), "failed": len(bad),
-        "trace": {"files": [trace["file"]]} if trace else None,
-        "fields": fields})
+    window.write(device, fields, checks)
 
 
 def _fill(loop, params, prompt, table, geo):

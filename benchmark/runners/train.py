@@ -239,7 +239,14 @@ def worker(spec):
             "ref_tolerance": tol,
             "compiles_in_window": sum(g["compiles"] for g in gathered),
             "trace_steps": traffic["trace_segments"] * k if trace else None,
-        }})
+        },
+        "compared": {
+            "first_loss_rel": {"value": ref_rel, "holds": "<=", "limit": tol},
+            "compiles_in_window": {
+                "value": sum(g["compiles"] for g in gathered), "holds": "<=",
+                "limit": 0},
+            "segments": {"value": len(times), "holds": ">=",
+                         "limit": traffic["min_segments"]}}})
 
 
 if __name__ == "__main__":
