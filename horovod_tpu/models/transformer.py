@@ -23,6 +23,19 @@ the defaults; OLMoE-1B-7B is :func:`olmoe_1b_7b`. `forward`, `loss_fn`,
 `apply_block` and every serving program of ``serving/engine.py`` run that
 one function and differ only in the attention they hand it.
 
+A configuration may also describe its layers one by one: ``layer_attn`` names
+each layer's attention, and a name found in ``latent`` makes that layer
+multi-head LATENT attention (:class:`LatentAttention`: low-rank queries, one
+compressed key/value row a token shared by all heads, attended in the
+absorbed form; optionally a window, or a learned selection of the
+``index_topk`` best-scoring keys), ``attn_gate`` a sigmoid gate a head,
+``dense_layers`` leading layers with a plain feed-forward before the expert
+layers, ``shared_experts`` an expert every token visits, ``router="sigmoid"``
+a sigmoid router with a selection bias, and ``experts_held`` the (offset,
+count) of the experts THIS device holds: the router scores all of them and
+the layer computes its own experts' part of the result (expert parallelism's
+one-device half). Nothing names a model.
+
 Written as an explicit parameter pytree + a mirrored PartitionSpec pytree
 (`param_specs`) instead of framework metadata, so the sharding story is
 auditable in one screen. Activations in ``dtype`` (bfloat16), parameters
@@ -43,6 +56,45 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
+def _round_up(n, m):
+    return -(-n // m) * m
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """One size of multi-head latent attention (DeepSeek-V2, arXiv:2405.04434
+    section 2.1). Queries go through a rank ``q_rank`` latent; keys and values
+    are ONE latent row a token, ``kv_rank`` normed dims and ``rope_dim``
+    rotated dims shared by all heads, which is all the cache holds. A head's
+    key is ``nope_dim`` dims made from the latent plus the shared rotated
+    dims, its value ``v_dim`` dims made from the latent.
+
+    ``window`` > 0: a query sees the last ``window`` positions, itself
+    included. ``index_topk`` > 0: a query sees the ``index_topk`` earlier
+    keys that a small scorer rates highest (``index_heads`` heads of
+    ``index_dim``, rotary on the first ``index_rope_dim``; DeepSeek-V3.2's
+    indexer), all of them while fewer precede it."""
+    n_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float = 10000.0
+    window: int = 0
+    index_heads: int = 0
+    index_dim: int = 0
+    index_rope_dim: int = 0
+    index_topk: int = 0
+
+    @property
+    def row_width(self):
+        """Lanes of a cached row: the latent, the rotated dims, and zeros up
+        to whole lane tiles of 128 (what a TPU array's minor dimension is
+        stored at anyway, made explicit so that kernels take whole tiles)."""
+        return _round_up(self.kv_rank + self.rope_dim, 128)
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32768
@@ -57,6 +109,34 @@ class TransformerConfig:
     # Divide a token's top-k router weights by their sum (HF's
     # ``norm_topk_prob``); False keeps the softmax's own values.
     norm_topk: bool = False
+    # "softmax": the top_k largest softmax weights. "sigmoid": sigmoid
+    # scores, the top_k of score + a learned selection bias
+    # (``router_bias``), the weights the chosen scores themselves
+    # (DeepSeek-V3's ``noaux_tc``), times ``routed_scale``.
+    router: str = "softmax"
+    routed_scale: float = 1.0
+    # Experts every token visits beside the routed ones (one feed-forward of
+    # ``shared_experts * d_expert``); 0 = none.
+    shared_experts: int = 0
+    # Leading layers whose feed-forward is dense (width ``d_ff``) in a model
+    # whose other layers hold experts.
+    dense_layers: int = 0
+    # (offset, count): the experts this device holds of ``n_experts``. The
+    # router scores and picks among all; the layer computes the held ones.
+    # () = all of them.
+    experts_held: tuple = ()
+    # Per-layer attention: ``layer_attn[i]`` names layer i's, and a name that
+    # is a key of ``latent`` (name -> LatentAttention or its fields) makes it
+    # latent attention of that size; any other name, or no entry, is the
+    # multi-head attention of ``n_heads``. Entries past ``n_layers`` are
+    # ignored (a published pattern longer than the layers that are run).
+    layer_attn: tuple = ()
+    latent: tuple = ()
+    # The normed query and key/value latents times sqrt(d_model / rank).
+    latent_rescale: bool = False
+    # A sigmoid gate a head on the attention's output, from a d_model ->
+    # heads projection of the layer's normed input.
+    attn_gate: bool = False
     norm: str = "layernorm"     # | "rmsnorm" (scale only, no mean, no bias)
     norm_eps: float = 1e-5
     pos: str = "learned"        # | "rope" (rotate-half, per head)
@@ -97,9 +177,23 @@ class TransformerConfig:
             raise ValueError(
                 f"attn_impl must be 'auto', 'gather', 'ring' or 'flash', "
                 f"got {self.attn_impl!r}")
+        latent = self.latent.items() if isinstance(self.latent, dict) \
+            else self.latent
+        object.__setattr__(self, "latent", tuple(
+            (name, a if isinstance(a, LatentAttention)
+             else LatentAttention(**a)) for name, a in latent))
+        object.__setattr__(self, "layer_attn", tuple(self.layer_attn))
+        object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        if self.experts_held:
+            offset, count = self.experts_held
+            if not (self.n_experts and 0 <= offset
+                    and 0 < count <= self.n_experts - offset):
+                raise ValueError(f"experts_held {self.experts_held} is not "
+                                 f"a range of the {self.n_experts} experts")
         for field, allowed in (("norm", ("layernorm", "rmsnorm")),
                                ("pos", ("learned", "rope")),
-                               ("ffn", ("gelu", "swiglu"))):
+                               ("ffn", ("gelu", "swiglu")),
+                               ("router", ("softmax", "sigmoid"))):
             if getattr(self, field) not in allowed:
                 raise ValueError(f"{field} must be one of {allowed}, got "
                                  f"{getattr(self, field)!r}")
@@ -120,6 +214,25 @@ class TransformerConfig:
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
+
+    def attn_of(self, li):
+        """Layer ``li``'s :class:`LatentAttention`, or None for the
+        multi-head attention of ``n_heads``."""
+        name = self.layer_attn[li] if li < len(self.layer_attn) else None
+        return dict(self.latent).get(name)
+
+    def is_moe(self, li):
+        return self.n_experts > 0 and li >= self.dense_layers
+
+    @property
+    def moe_layers(self):
+        """The layers that hold experts."""
+        return [li for li in range(self.n_layers) if self.is_moe(li)]
+
+    @property
+    def n_held(self):
+        """Experts whose weights this device holds."""
+        return self.experts_held[1] if self.experts_held else self.n_experts
 
 
 def bert_large() -> TransformerConfig:
@@ -169,13 +282,63 @@ def _norm_params(cfg, shape):
     return p
 
 
+def _ffn_params(k, cfg, lead, F):
+    """One feed-forward's matrices of width ``F`` from three keys (``lead``
+    = (experts,) for a stack of them)."""
+    D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
+    p = {"w_in": _dense_init(k[0], lead + (D, F), D, pdt),
+         "w_out": _dense_init(k[1], lead + (F, D), F, pdt)}
+    if cfg.ffn == "swiglu":
+        p["w_gate"] = _dense_init(k[2], lead + (D, F), D, pdt)
+    return p
+
+
+def _latent_params(key, cfg, a: LatentAttention):
+    """A latent-attention layer's matrices: query down/up through
+    ``q_rank``, key/value down to ``kv_rank + rope_dim`` and up to each
+    head's ``nope_dim + v_dim``, the output projection, the head gate, and
+    the selection's scorer."""
+    D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
+    k = jax.random.split(key, 9)
+    H = a.n_heads
+    # Under ``latent_rescale`` a normed latent's entries have magnitude
+    # sqrt(D / rank), so the matrices that read it are drawn as if it had D
+    # entries of magnitude one: queries, keys and values of unit scale, and
+    # attention logits a softmax can tell apart, as a trained model's are
+    # (at 1 / rank the logits' spread is D / sqrt(q_rank kv_rank) = 7 at the
+    # published widths, and rounding decides the output).
+    q_in = D if cfg.latent_rescale else a.q_rank
+    kv_in = D if cfg.latent_rescale else a.kv_rank
+    p = {
+        "wq_a": _dense_init(k[0], (D, a.q_rank), D, pdt),
+        "q_norm": {"scale": jnp.ones((a.q_rank,), pdt)},
+        "wq_b": _dense_init(k[1], (a.q_rank, H, a.nope_dim + a.rope_dim),
+                            q_in, pdt),
+        "wkv_a": _dense_init(k[2], (D, a.kv_rank + a.rope_dim), D, pdt),
+        "kv_norm": {"scale": jnp.ones((a.kv_rank,), pdt)},
+        "wkv_b": _dense_init(k[3], (a.kv_rank, H, a.nope_dim + a.v_dim),
+                             kv_in, pdt),
+        "wo": _dense_init(k[4], (H, a.v_dim, D), H * a.v_dim, pdt),
+    }
+    if cfg.attn_gate:
+        p["w_attn_gate"] = _dense_init(k[5], (D, H), D, pdt)
+    if a.index_topk:
+        p["wi_q"] = _dense_init(k[6], (a.q_rank, a.index_heads, a.index_dim),
+                                q_in, pdt)
+        p["wi_k"] = _dense_init(k[7], (D, a.index_dim), D, pdt)
+        p["i_norm"] = {"scale": jnp.ones((a.index_dim,), pdt),
+                       "bias": jnp.zeros((a.index_dim,), pdt)}
+        p["wi_w"] = _dense_init(k[8], (D, a.index_heads), D, pdt)
+    return p
+
+
 def init_params(key, cfg: TransformerConfig):
     """The parameter pytree, every array made from ``key`` directly in
     ``cfg.param_dtype``. Called outside ``jit`` each array is one small
     device program, so no float32 copy of a bf16 model ever exists (the
     largest temporary is one tensor's random bits)."""
     keys = jax.random.split(key, cfg.n_layers + 2)
-    D, F, H, dh = cfg.d_model, cfg.ffn_width, cfg.n_heads, cfg.head_dim
+    D, H, dh = cfg.d_model, cfg.n_heads, cfg.head_dim
     pdt = jnp.dtype(cfg.param_dtype)
     params = {
         "embed": jax.random.normal(keys[0], (cfg.vocab_size, D), pdt) * 0.02,
@@ -190,23 +353,30 @@ def init_params(key, cfg: TransformerConfig):
                                      (cfg.vocab_size, D), D, pdt)
     for i in range(cfg.n_layers):
         k = jax.random.split(keys[2 + i], 8)
-        layer = {
-            "ln1": _norm_params(cfg, (D,)),
-            "ln2": _norm_params(cfg, (D,)),
+        layer = {"ln1": _norm_params(cfg, (D,)),
+                 "ln2": _norm_params(cfg, (D,))}
+        a = cfg.attn_of(i)
+        if a is None:
             # column-parallel fused QKV [D, 3, H, dh]; row-parallel out
-            "wqkv": _dense_init(k[0], (D, 3, H, dh), D, pdt),
-            "wo": _dense_init(k[1], (H, dh, D), D, pdt),
-        }
-        if cfg.qk_norm:
-            layer["q_norm"] = {"scale": jnp.ones((H, dh), pdt)}
-            layer["k_norm"] = {"scale": jnp.ones((H, dh), pdt)}
-        lead = (cfg.n_experts,) if cfg.n_experts > 0 else ()
-        if cfg.n_experts > 0:
+            layer["wqkv"] = _dense_init(k[0], (D, 3, H, dh), D, pdt)
+            layer["wo"] = _dense_init(k[1], (H, dh, D), D, pdt)
+            if cfg.qk_norm:
+                layer["q_norm"] = {"scale": jnp.ones((H, dh), pdt)}
+                layer["k_norm"] = {"scale": jnp.ones((H, dh), pdt)}
+        else:
+            layer.update(_latent_params(k[0], cfg, a))
+        if cfg.is_moe(i):
+            lead, F = (cfg.n_held,), cfg.ffn_width
             layer["router"] = _dense_init(k[2], (D, cfg.n_experts), D, pdt)
-        layer["w_in"] = _dense_init(k[3], lead + (D, F), D, pdt)
-        layer["w_out"] = _dense_init(k[4], lead + (F, D), F, pdt)
-        if cfg.ffn == "swiglu":
-            layer["w_gate"] = _dense_init(k[5], lead + (D, F), D, pdt)
+            if cfg.router == "sigmoid":
+                layer["router_bias"] = jnp.zeros((cfg.n_experts,), pdt)
+            if cfg.shared_experts:
+                layer["shared"] = _ffn_params(
+                    jax.random.split(jax.random.fold_in(k[2], 1), 3), cfg,
+                    (), cfg.shared_experts * F)
+        else:
+            lead, F = (), cfg.d_ff
+        layer.update(_ffn_params(k[3:6], cfg, lead, F))
         params["layers"].append(layer)
     return params
 
@@ -222,28 +392,43 @@ def param_specs(cfg: TransformerConfig):
     m, e = cfg.model_axis, cfg.expert_axis
     norm = {"scale": P(), "bias": P()} if cfg.norm == "layernorm" \
         else {"scale": P()}
-    layer = {
-        "ln1": dict(norm),
-        "ln2": dict(norm),
-        "wqkv": P(None, None, m, None),   # heads sharded over model axis
-        "wo": P(m, None, None),           # row-parallel
-    }
-    if cfg.qk_norm:
-        layer["q_norm"] = {"scale": P(m, None)}
-        layer["k_norm"] = {"scale": P(m, None)}
-    if cfg.n_experts > 0:
-        layer["router"] = P()
-        layer["w_in"] = P(e, None, m)
-        layer["w_out"] = P(e, m, None)
-    else:
-        layer["w_in"] = P(None, m)
-        layer["w_out"] = P(m, None)
-    if cfg.ffn == "swiglu":
-        layer["w_gate"] = layer["w_in"]
+
+    def ffn(w_in, w_out):
+        p = {"w_in": w_in, "w_out": w_out}
+        if cfg.ffn == "swiglu":
+            p["w_gate"] = w_in
+        return p
+
+    layers = []
+    for i in range(cfg.n_layers):
+        layer = {"ln1": dict(norm), "ln2": dict(norm)}
+        a = cfg.attn_of(i)
+        if a is None:
+            layer["wqkv"] = P(None, None, m, None)   # heads over model axis
+            layer["wo"] = P(m, None, None)           # row-parallel
+            if cfg.qk_norm:
+                layer["q_norm"] = {"scale": P(m, None)}
+                layer["k_norm"] = {"scale": P(m, None)}
+        else:
+            # Latent attention is held whole on every device (data-parallel
+            # attention): no serving program shards it yet.
+            layer.update(jax.tree.map(
+                lambda _: P(), jax.eval_shape(
+                    lambda: _latent_params(jax.random.PRNGKey(0), cfg, a))))
+        if cfg.is_moe(i):
+            layer["router"] = P()
+            if cfg.router == "sigmoid":
+                layer["router_bias"] = P()
+            if cfg.shared_experts:
+                layer["shared"] = ffn(P(None, m), P(m, None))
+            layer.update(ffn(P(e, None, m), P(e, m, None)))
+        else:
+            layer.update(ffn(P(None, m), P(m, None)))
+        layers.append(layer)
     specs = {
         "embed": P(m, None),
         "final_ln": dict(norm),
-        "layers": [dict(layer) for _ in range(cfg.n_layers)],
+        "layers": layers,
     }
     if cfg.pos == "learned":
         specs["pos_embed"] = P()
@@ -297,12 +482,12 @@ def _norm(x, p, cfg):
     return _layer_norm(x, p, cfg.norm_eps)
 
 
-def _rope(x, positions, cfg):
+def _rope(x, positions, theta):
     """Rotary positions on ``x [B, S, H, dh]`` at ``positions [B, S]``: each
     head's first and second half paired (rotate-half), angle
     ``pos * theta^(-2i/dh)``; computed in float32."""
-    half = cfg.head_dim // 2
-    inv_freq = cfg.rope_theta ** (
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (
         -jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None, None] * inv_freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)                 # [B, S, 1, dh/2]
@@ -325,8 +510,153 @@ def _qkv(h, layer, cfg, positions=None):
     if cfg.pos == "rope":
         if positions is None:
             positions = jnp.arange(h.shape[1])[None]
-        q, k = _rope(q, positions, cfg), _rope(k, positions, cfg)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+def _rope_head(x, positions, theta, width):
+    """RoPE on the first ``width`` dims of ``x [B, S, ..., d]``."""
+    flat = x.reshape(*x.shape[:2], -1, x.shape[-1])
+    turned = _rope(flat[..., :width], positions, theta)
+    return jnp.concatenate([turned, flat[..., width:]], -1).reshape(x.shape)
+
+
+def _latent_qkv(h, layer, cfg, a: LatentAttention, positions=None):
+    """A latent layer's attention operands from the normed input ``h [B, S,
+    D]``, in the ABSORBED form: -> ``(q [B, S, H, row_width], row [B, S,
+    row_width], index)``.
+
+    ``row`` is what the cache holds of a token: the key/value latent after
+    its norm (and scale), the shared key dims after the rotation to
+    ``positions``, zeros up to ``row_width``. ``q`` is each head's query
+    against such rows: its ``nope_dim`` part multiplied through the head's
+    key up-projection into the latent's ``kv_rank`` dims (so that no head's
+    keys are ever made), its rotated part, zeros: ``q . row`` is the head's
+    logit before the scale, and the probabilities times ``row[..., :kv_rank]``
+    go through the value up-projection after the attention (:func:`block`).
+    Per (query, key) that is ``H * (row_width + kv_rank)`` multiply-adds
+    against ``H * (nope + rope + v)`` expanded: 4 x the operations at these
+    widths, and no ``[keys, H, nope + v]`` expansion of every attended row,
+    which for a selection that differs query by query would be ``kv_rank *
+    H * (nope + v)`` a pair, 100 x more.
+
+    ``index`` (a layer with a selection): ``{"q": [B, S, J, d] scorer
+    queries, "k": [B, S, d] this token's scorer key (what the cache holds),
+    "w": [B, S, J] float32 head weights}``, else None."""
+    dt = cfg.compute_dtype
+    if positions is None:
+        positions = jnp.arange(h.shape[1])[None]
+    a_q = math.sqrt(cfg.d_model / a.q_rank) if cfg.latent_rescale else 1.0
+    a_kv = math.sqrt(cfg.d_model / a.kv_rank) if cfg.latent_rescale else 1.0
+    c_q = _rms_norm(jnp.einsum("bsd,dr->bsr", h, layer["wq_a"].astype(dt)),
+                    layer["q_norm"], cfg.norm_eps)
+    c_q = (c_q * a_q).astype(dt)
+    q = jnp.einsum("bsr,rhd->bshd", c_q, layer["wq_b"].astype(dt))
+    q_rope = _rope(q[..., a.nope_dim:], positions, a.rope_theta)
+    kv = jnp.einsum("bsd,dr->bsr", h, layer["wkv_a"].astype(dt))
+    c_kv = _rms_norm(kv[..., :a.kv_rank], layer["kv_norm"], cfg.norm_eps)
+    c_kv = (c_kv * a_kv).astype(dt)
+    k_r = _rope(kv[:, :, None, a.kv_rank:], positions, a.rope_theta)[:, :, 0]
+    q_lat = jnp.einsum("bshd,rhd->bshr", q[..., :a.nope_dim],
+                       layer["wkv_b"][..., :a.nope_dim].astype(dt))
+    pad = a.row_width - a.kv_rank - a.rope_dim
+    q_abs = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros((*q.shape[:3], pad), dt)], -1)
+    row = jnp.concatenate(
+        [c_kv, k_r, jnp.zeros((*kv.shape[:2], pad), dt)], -1)
+    index = None
+    if a.index_topk:
+        q_i = jnp.einsum("bsr,rjd->bsjd", c_q, layer["wi_q"].astype(dt))
+        k_i = _layer_norm(
+            jnp.einsum("bsd,dk->bsk", h, layer["wi_k"].astype(dt)),
+            layer["i_norm"], INDEX_NORM_EPS)
+        w = jnp.einsum("bsd,dj->bsj", h, layer["wi_w"].astype(dt)).astype(
+            jnp.float32) / math.sqrt(a.index_heads * a.index_dim)
+        index = {
+            "q": _rope_head(q_i, positions, a.rope_theta, a.index_rope_dim),
+            "k": _rope_head(k_i, positions, a.rope_theta, a.index_rope_dim),
+            "w": w}
+    return q_abs, row, index
+
+
+# The eps of the selection scorer's LayerNorm (DeepSeek-V3.2's indexer).
+INDEX_NORM_EPS = 1e-6
+
+
+def index_scores(q_i, w, k_i, allowed):
+    """The selection's scores ``I [B, S, T] = sum_j w_j relu(q_j . k)``
+    (float32) of scorer queries ``q_i [B, S, J, d]``, head weights ``w [B,
+    S, J]`` and keys ``k_i [B, T, d]``; ``-inf`` where ``allowed [B, S, T]``
+    is false."""
+    per_head = jnp.einsum("bsjd,btd->bsjt", q_i, k_i,
+                          preferred_element_type=jnp.float32)
+    scores = jnp.einsum("bsjt,bsj->bst", jax.nn.relu(per_head), w)
+    return jnp.where(allowed, scores, -jnp.inf)
+
+
+def select_keys(scores, k):
+    """The ``k`` best-scoring keys of every query: ``scores [.., T]``
+    (``-inf`` = not allowed) -> ``[.., min(k, T)]`` int32 key indices, ``-1``
+    where fewer than ``k`` keys are allowed."""
+    val, idx = jax.lax.top_k(scores, min(k, scores.shape[-1]))
+    return jnp.where(val > -jnp.inf, idx, -1)
+
+
+def latent_attend(q, rows, a: LatentAttention, allowed, dt):
+    """Absorbed latent attention with materialised scores: ``q [B, S, H,
+    W]`` against ``rows [B, T, W]`` under ``allowed [B, S, T]`` ->
+    ``[B, S, H, kv_rank]`` (the probabilities times the rows' latent part;
+    zeros for a query that is allowed nothing). The plain tier: the
+    trainer's forward pass, a CPU, a mesh."""
+    scale = 1.0 / math.sqrt(a.nope_dim + a.rope_dim)
+    logits = jnp.einsum("bshw,btw->bhst", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(allowed[:, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, -1)
+    probs = jnp.where(allowed[:, None], probs, 0.0).astype(dt)
+    return jnp.einsum("bhst,btr->bshr", probs, rows[..., :a.kv_rank])
+
+
+def latent_allowed(a: LatentAttention, q_pos, k_pos, live=None):
+    """Which keys a latent layer's queries may see before any selection:
+    ``q_pos [B, S]``, ``k_pos [B, T]`` -> ``[B, S, T]``: not later than the
+    query, inside its window, and ``live [B, T]``."""
+    dist = q_pos[:, :, None] - k_pos[:, None, :]
+    ok = dist >= 0
+    if a.window:
+        ok &= dist < a.window
+    if live is not None:
+        ok &= live[:, None, :]
+    return ok
+
+
+def _attend_latent(a, dt):
+    """``attend(q, row, index)`` of a latent layer over its own window (no
+    cache): the forward pass of the trainer and of the tests. -> (output,
+    the selection or None)."""
+    def attend(q, row, index):
+        pos = jnp.broadcast_to(jnp.arange(row.shape[1])[None], row.shape[:2])
+        allowed = latent_allowed(a, pos, pos)
+        selected = None
+        if index is not None:
+            selected = select_keys(
+                index_scores(index["q"], index["w"], index["k"], allowed),
+                a.index_topk)
+            allowed = selection_mask(selected, row.shape[1])
+        return latent_attend(q, row, a, allowed, dt), selected
+
+    return attend
+
+
+def selection_mask(selected, n_keys):
+    """``selected [B, S, k]`` key indices (``-1`` = none) -> ``[B, S,
+    n_keys]`` bool."""
+    b, s, _ = selected.shape
+    hit = jnp.zeros((b, s, n_keys + 1), bool).at[
+        jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
+        jnp.where(selected >= 0, selected, n_keys)].set(True)
+    return hit[..., :n_keys]
 
 
 def _attend_ring(q, k, v, cfg, mesh):
@@ -431,14 +761,33 @@ def _route(x, layer, cfg):
     softmax weights ``[.., k]`` (float32; as they are, or divided by their
     sum under ``norm_topk``) and their experts ``[.., k]``. Product and
     softmax in float32: the choice is discontinuous, so it is made at the
-    precision of the reference."""
+    precision of the reference. ``router="sigmoid"``: sigmoid scores, the
+    ``top_k`` of score + ``router_bias``, the weights the chosen scores
+    (the bias chooses and does not weigh), times ``routed_scale``."""
     gates = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
                        layer["router"].astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST)
-    w, top = jax.lax.top_k(jax.nn.softmax(gates, -1), cfg.top_k)
+    if cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(gates)
+        _, top = jax.lax.top_k(
+            scores + layer["router_bias"].astype(jnp.float32), cfg.top_k)
+        w = jnp.take_along_axis(scores, top, -1)
+    else:
+        w, top = jax.lax.top_k(jax.nn.softmax(gates, -1), cfg.top_k)
     if cfg.norm_topk:
         w = w / jnp.sum(w, -1, keepdims=True)
+    if cfg.routed_scale != 1.0:
+        w = w * cfg.routed_scale
     return w, top
+
+
+def _held(top, cfg):
+    """``top [.., k]`` experts -> (their places among the experts held
+    here, ``n_held`` for one that is elsewhere; which are held)."""
+    offset, count = cfg.experts_held
+    local = top - offset
+    held = (local >= 0) & (local < count)
+    return jnp.where(held, local, count), held
 
 
 def _moe_dense(x, w, top, layer, cfg):
@@ -449,7 +798,9 @@ def _moe_dense(x, w, top, layer, cfg):
     ``n_experts / top_k`` times the routed work; the bandwidth-optimal
     alltoall dispatch is in horovod_tpu.parallel.expert_parallel."""
     dt = cfg.compute_dtype
-    combine = jnp.sum(jax.nn.one_hot(top, cfg.n_experts, dtype=jnp.float32)
+    if cfg.experts_held:      # an expert elsewhere one-hots to no column
+        top = _held(top, cfg)[0]
+    combine = jnp.sum(jax.nn.one_hot(top, cfg.n_held, dtype=jnp.float32)
                       * w[..., None], -2).astype(dt)             # [b,s,E]
     h = jnp.einsum("bsd,edf->bsef", x, layer["w_in"].astype(dt))
     h = _activation(h, layer, x, cfg, "bsd,edf->bsef")
@@ -468,7 +819,13 @@ def _moe_grouped(x, w, top, layer, cfg):
     (docs/observability.md)."""
     dt = cfg.compute_dtype
     B, S, D = x.shape
-    k, E = cfg.top_k, cfg.n_experts
+    k, E = cfg.top_k, cfg.n_held
+    if cfg.experts_held:
+        # Pairs routed to an expert elsewhere sort behind every group and
+        # belong to none: their rows are multiplied by nothing, and their
+        # weight is zero.
+        top, held = _held(top, cfg)
+        w = jnp.where(held, w, 0.0)
     experts = top.reshape(-1)                                     # [T*k]
     order = jnp.argsort(experts, stable=True)
     rows = x.reshape(-1, D)[order // k]                           # [T*k, D]
@@ -482,6 +839,8 @@ def _moe_grouped(x, w, top, layer, cfg):
         else:
             h = jax.nn.gelu(h)
         y = jax.lax.ragged_dot(h, layer["w_out"].astype(dt), sizes)
+    if cfg.experts_held:      # a row of no group holds whatever was there
+        y = jnp.where((jnp.arange(y.shape[0]) < sizes.sum())[:, None], y, 0)
     y = y[jnp.argsort(order)].reshape(B, S, k, D)
     return jnp.einsum("bskd,bsk->bsd", y, w.astype(dt))
 
@@ -494,15 +853,21 @@ def _moe_ffn(x, layer, cfg, mesh=None, valid=None):
     ``model`` axes and which is right, at ``n_experts / top_k`` times the
     work: a grouped product under an expert-sharded mesh is not written.
 
-    The routing is ``{"top": [b, s, k] experts, "counts": [E]}``, the
-    (token, expert) pairs each expert received from the rows ``valid [b,
-    s]`` marks (all by default): what ``serve_stats()["moe"]`` counts."""
+    The routing is ``{"top": [b, s, k] experts, "counts": [n_held]}``, the
+    (token, expert) pairs each expert held here received from the rows
+    ``valid [b, s]`` marks (all by default): what ``serve_stats()["moe"]``
+    counts. A shared expert (``shared_experts``) is added for every row."""
     w, top = _route(x, layer, cfg)
     if mesh is None:
         y = _moe_grouped(x, w, top, layer, cfg)
     else:
         y = _moe_dense(x, w, top, layer, cfg)
-    hit = jax.nn.one_hot(top, cfg.n_experts, dtype=jnp.int32)   # [b,s,k,E]
+    if cfg.shared_experts:
+        y = y + _ffn(x, layer["shared"], cfg)
+    # Counted: the pairs this device computes (all of them, or those of the
+    # experts it holds: one elsewhere one-hots to no column).
+    mine = _held(top, cfg)[0] if cfg.experts_held else top
+    hit = jax.nn.one_hot(mine, cfg.n_held, dtype=jnp.int32)     # [b,s,k,E]
     if valid is not None:
         hit = hit * valid[..., None, None]
     return y, {"top": top, "counts": hit.sum((0, 1, 2))}
@@ -572,7 +937,7 @@ def _constrain(v, spec):
 
 
 def block(layer, x, cfg: TransformerConfig, attend, positions=None,
-          mesh=None, out_spec=None, valid=None):
+          mesh=None, out_spec=None, valid=None, li=0):
     """THE transformer block, written once: ``x + Wo attend(q, k, v)`` of the
     normed input, then ``+ ffn`` of the normed result -> ``(x, routing)``
     (``routing`` None for a dense feed-forward; see :func:`_moe_ffn`).
@@ -581,21 +946,44 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
     trainer's three kernels (:func:`apply_block`) and the serving programs
     (``serving/engine.py``: write the window's K/V to the paged cache, then
     attend over the gathered pages). ``positions [B, S]`` are the tokens'
-    places in their sequences (None = 0..S-1), read by RoPE."""
+    places in their sequences (None = 0..S-1), read by RoPE.
+
+    ``li`` is the layer's place in the model, for a configuration that
+    describes its layers one by one (``cfg.attn_of``, ``cfg.is_moe``). A
+    latent layer hands ``attend`` its absorbed operands instead,
+    ``attend(q [B, S, H, W], row [B, S, W], index) -> (o [B, S, H,
+    kv_rank], selected keys or None)`` (:func:`_latent_qkv`), takes the
+    result through the value up-projection and the head gate, and returns
+    the selection in its routing (``{"selected": ..}``)."""
     dt = cfg.compute_dtype
+    a = cfg.attn_of(li)
+    selected = None
     h = _norm(x, layer["ln1"], cfg)
     with jax.named_scope("attention"):
-        q, k, v = _qkv(h, layer, cfg, positions)
-        out = jnp.einsum("bshk,hkd->bsd", attend(q, k, v),
-                         layer["wo"].astype(dt))
+        if a is None:
+            q, k, v = _qkv(h, layer, cfg, positions)
+            out = jnp.einsum("bshk,hkd->bsd", attend(q, k, v),
+                             layer["wo"].astype(dt))
+        else:
+            o, selected = attend(*_latent_qkv(h, layer, cfg, a, positions))
+            o = jnp.einsum("bshr,rhd->bshd", o,
+                           layer["wkv_b"][..., a.nope_dim:].astype(dt))
+            if cfg.attn_gate:
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "bsd,dh->bsh", h, layer["w_attn_gate"].astype(dt)
+                ).astype(jnp.float32))
+                o = o * gate[..., None].astype(dt)
+            out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
         x = x + _constrain(out, out_spec)
     h = _norm(x, layer["ln2"], cfg)
     with jax.named_scope("mlp"):
-        if cfg.n_experts > 0:
+        if cfg.is_moe(li):
             y, routing = _moe_ffn(h, layer, cfg, mesh, valid)
         else:
             y, routing = _ffn(h, layer, cfg), None
         x = x + y
+    if selected is not None:
+        routing = dict(routing or {}, selected=selected)
     return x, routing
 
 
@@ -610,9 +998,12 @@ def _block_fn(cfg, mesh, impl, seq_spec, full_spec):
         attend = lambda q, k, v: _attend_gather(  # noqa: E731
             q, k, v, cfg, full_spec)
 
-    def fn(layer, x):
-        x, routing = block(layer, x, cfg, attend, mesh=mesh,
-                           out_spec=seq_spec)
+    def fn(layer, x, li=0):
+        a = cfg.attn_of(li)
+        x, routing = block(
+            layer, x, cfg,
+            attend if a is None else _attend_latent(a, cfg.compute_dtype),
+            mesh=mesh, out_spec=seq_spec, li=li)
         return _constrain(x, seq_spec), routing
 
     return fn
@@ -678,13 +1069,13 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None,
     impl = resolve_attn(cfg, S, mesh)
     fn = _block_fn(cfg, mesh, impl, seq_spec, full_spec)
 
-    def block_(x, layer):
-        return fn(layer, x)[0]
+    def block_(x, layer, li):
+        return fn(layer, x, li)[0]
 
     if cfg.remat:
-        block_ = jax.checkpoint(block_)
-    for layer in params["layers"]:
-        x = block_(x, layer)
+        block_ = jax.checkpoint(block_, static_argnums=(2,))
+    for li, layer in enumerate(params["layers"]):
+        x = block_(x, layer, li)
     x = _norm(x, params["final_ln"], cfg)
     if return_hidden:
         return x

@@ -600,6 +600,23 @@ SERVE_KV_READ_SHARE = gauge(
     "hvd_serve_kv_read_share",
     "hvd_serve_kv_pages_read over hvd_serve_kv_pages_gathered_before: the "
     "share of the gather path's cache traffic that is live context")
+SERVE_KV_SCORED = counter(
+    "hvd_serve_kv_scored",
+    "(query, key) pairs the key selection of the selecting latent layers "
+    "scored: every live key of every query, summed over those layers",
+    ("program",))
+SERVE_KV_SELECTED = counter(
+    "hvd_serve_kv_selected",
+    "(query, key) pairs those layers then attended over: min(live keys, "
+    "index_topk) a query", ("program",))
+SERVE_KV_WINDOW = counter(
+    "hvd_serve_kv_window",
+    "(query, key) pairs the window latent layers attended over: min(live "
+    "keys, window) a query, summed over those layers", ("program",))
+SERVE_KV_SELECT_SHARE = gauge(
+    "hvd_serve_kv_select_share",
+    "hvd_serve_kv_selected over hvd_serve_kv_scored, all programs so far: "
+    "the share of the scored keys that attention reads")
 CKPT_SAVES = counter(
     "hvd_ckpt_saves",
     "checkpoint.save() calls entered on this rank")

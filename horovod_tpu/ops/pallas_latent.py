@@ -1,0 +1,387 @@
+"""The serving kernels of latent attention layers (Pallas TPU): the key
+selection's scores and its top-k, attention over the selected latent rows,
+and windowed latent attention over a slot's ring.
+
+``serving/engine.py`` (``_latent_layer``) runs them inside ``jit_chunk`` and
+``jit_decode`` on a TPU backend with no mesh; each ``pallas_call`` carries a
+``name`` that is the instruction's name in the compiled program and in a
+device trace (``docs/observability.md``; the benchmark's ``*_dev_ms.doc`` and
+``*_roofline.doc`` metrics match them). The same mathematics in plain
+``jax.numpy`` is ``models/transformer.py``'s ``index_scores``,
+``select_keys`` and ``latent_attend``, which the engine runs everywhere else
+and the tests compare these with (``interpret=True`` on the CPU).
+
+All four take operands in the compute dtype, accumulate in float32, take the
+softmax in float32 and round the probabilities to the compute dtype before
+the product with the rows, as ``latent_attend`` does.
+
+``index_scores``   ``I[q, s] = sum_j w[q, j] relu(qI[q, j] . kI[s])`` for a
+    tile of queries against a tile of the slot's scorer keys at a time: the
+    per-head products ``[J * queries, keys]`` float32 exist one tile at a
+    time in VMEM, never ``[Q, J, max_kv]``. Key tiles past
+    every query of the tile (the causal triangle, the unfilled cache) are
+    neither fetched nor multiplied: cost follows the live context.
+``index_select``   a query's ``k`` best keys, exactly, with no sort: the
+    k-th largest score by bisection on the scores' bit patterns (32 counting
+    passes over the row in VMEM), then the kept keys' indices compacted to
+    the front by prefix sums done as matrix products (within blocks of 128
+    keys, over the blocks, and one gather-by-one-hot product), ``-1`` where
+    fewer than ``k`` keys are live. Ties at the k-th score go to the lower
+    key indices, as in a stable top-k.
+``sparse_latent_attention``   one query's heads against that query's own
+    gathered rows ``[k, row_width]``: absorbed logits, softmax over the live
+    ones, times the rows' latent part.
+``window_latent_attention``   a tile of a slot's queries, all heads, against
+    the slot's ring ``[ring_tokens, row_width]`` under ``0 <= q_pos - k_pos <
+    window``.
+"""
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NEG = -1e30
+_LANES = 128
+
+# The instructions' names (benchmark/layer_metrics/*.doc.json match them).
+SCORES_NAME = "index_scores"
+SELECT_NAME = "index_select"
+SPARSE_NAME = "sparse_latent_attention"
+WINDOW_NAME = "window_latent_attention"
+
+_VMEM_LIMIT = 64 * 1024 * 1024     # of a v5e's 128 MiB; the default is 16
+
+
+def supported(a, geo):
+    """Whether layer kind ``a``'s kernels tile at this cache geometry: whole
+    lane tiles of latent, of scorer key and of selected keys; a context of
+    whole 8 x 128 key blocks; a ring of whole lane tiles."""
+    ok = a.kv_rank % _LANES == 0 and a.row_width % _LANES == 0
+    if a.index_topk:
+        ok &= (a.index_dim % _LANES == 0 and a.index_topk % _LANES == 0
+               and geo.max_kv % (8 * _LANES) == 0
+               and a.index_topk <= geo.max_kv)
+    if a.window:
+        ok &= geo.ring_tokens % _LANES == 0
+    return bool(ok)
+
+
+def _interpret():
+    return jax.default_backend() != "tpu"
+
+
+def _divisor(n, want):
+    """The largest divisor of ``n`` that is at most ``want``."""
+    d = min(n, want)
+    while n % d:
+        d -= 1
+    return d
+
+
+# ---- index_scores ---------------------------------------------------------
+
+def _scores_kernel(hi_ref, q_ref, w_ref, pos_ref, k_ref, o_ref, *,
+                   n_heads, tq, ts, n_q_tiles):
+    b, qi, si = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    hi = hi_ref[b * n_q_tiles + qi]       # the tile's highest query position
+
+    @pl.when(si * ts > hi)
+    def _dead():
+        o_ref[0] = jnp.full(o_ref.shape[1:], -jnp.inf, o_ref.dtype)
+
+    @pl.when(si * ts <= hi)
+    def _live():
+        per_head = jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [J * tq, ts]
+        per_head = jnp.maximum(per_head, 0.0) * w_ref[0, 0]
+        if tq == 1:
+            total = jnp.sum(per_head, axis=0, keepdims=True)
+        else:
+            total = per_head[0:tq]
+            for j in range(1, n_heads):
+                total = total + per_head[j * tq:(j + 1) * tq]
+        key = si * ts + jax.lax.broadcasted_iota(jnp.int32, total.shape, 1)
+        o_ref[0] = jnp.where(key <= pos_ref[0], total, -jnp.inf)
+
+
+def index_scores(q_i, w, keys, q_pos, *, interpret=None):
+    """``q_i [B, Q, J, d]``, ``w [B, Q, J]`` float32, ``keys [B, S, d]`` (the
+    slot's scorer keys through its block table), ``q_pos [B, Q]`` ->
+    ``[B, Q, S]`` float32, ``-inf`` at keys later than the query."""
+    B, Q, J, d = q_i.shape
+    S = keys.shape[1]
+    tq = _divisor(Q, 32)
+    if tq != Q and tq % 8:
+        tq = Q
+    ts = _divisor(S, 512)
+    nq, ns = Q // tq, S // ts
+    # Rows of a query tile head-major, (head, query): the sum over heads is
+    # then a sum of J row slabs.
+    q2 = q_i.reshape(B, nq, tq, J, d).transpose(0, 1, 3, 2, 4) \
+        .reshape(B, nq, J * tq, d)
+    w2 = w.astype(jnp.float32).reshape(B, nq, tq, J).transpose(0, 1, 3, 2) \
+        .reshape(B, nq, J * tq, 1)
+    pos = q_pos.astype(jnp.int32)
+    hi = jnp.max(pos.reshape(B, nq, tq), -1).reshape(-1)
+    kernel = functools.partial(_scores_kernel, n_heads=J, tq=tq, ts=ts,
+                               n_q_tiles=nq)
+
+    def key_tile(b, qi, si, hi):
+        # Past the tile's last live key: the last live tile again (no fetch).
+        return b, jnp.minimum(si, jnp.maximum(hi[b * nq + qi], 0) // ts), 0
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, nq, ns),
+            in_specs=[
+                pl.BlockSpec((1, 1, J * tq, d), lambda b, qi, si, hi:
+                             (b, qi, 0, 0)),
+                pl.BlockSpec((1, 1, J * tq, 1), lambda b, qi, si, hi:
+                             (b, qi, 0, 0)),
+                pl.BlockSpec((1, tq, 1), lambda b, qi, si, hi: (b, qi, 0)),
+                pl.BlockSpec((1, ts, d), key_tile),
+            ],
+            out_specs=pl.BlockSpec((1, tq, ts), lambda b, qi, si, hi:
+                                   (b, qi, si))),
+        out_shape=jax.ShapeDtypeStruct((B, Q, S), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * B * Q * J * d * S, transcendentals=0,
+            bytes_accessed=B * (nq * S * d * 2 + Q * S * 4)),
+        name=SCORES_NAME,
+        interpret=_interpret() if interpret is None else interpret,
+    )(hi, q2, w2, pos[..., None], keys)
+
+
+# ---- index_select ---------------------------------------------------------
+
+def _ordered(x):
+    """float32 -> int32 whose signed order is the floats' order."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _count(mask):
+    ones = jnp.where(mask, 1.0, 0.0)
+    return jnp.sum(jnp.sum(ones, axis=1, keepdims=True), axis=0,
+                   keepdims=True)                               # [1, 1]
+
+
+def _select_kernel(s_ref, o_ref, *, k):
+    scores = s_ref[0]                                 # [nb, 128] float32
+    nb = scores.shape[0]
+    key = _ordered(scores)
+    live = scores > -jnp.inf
+
+    # The k-th largest key: the largest T with count(key >= T) >= k, built
+    # from the sign down (two's complement: setting a bit moves T up).
+    kf = jnp.float32(k)
+    t = jnp.where(_count(key >= 0) >= kf, jnp.int32(0),
+                  jnp.int32(-2 ** 31))                          # [1, 1]
+    for bit in range(30, -1, -1):
+        cand = t | jnp.int32(1 << bit)
+        t = jnp.where(_count(key >= cand) >= kf, cand, t)
+
+    def iota(shape, dim):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+    def mm(a, b, dims):
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    upper = jnp.where(iota((_LANES, _LANES), 0) <= iota((_LANES, _LANES), 1),
+                      1.0, 0.0).astype(jnp.bfloat16)
+    before = jnp.where(iota((nb, nb), 1) < iota((nb, nb), 0), 1.0,
+                       0.0).astype(jnp.bfloat16)
+
+    def prefix(mask):
+        """Of the marked keys, in key order: (each one's rank inside its
+        block of 128, from 1; how many a block holds; how many lie in the
+        blocks before it). Prefix sums as products with triangles of ones:
+        small whole numbers, exact in bfloat16 operands and float32 sums."""
+        m = jnp.where(mask, 1.0, 0.0)                           # [nb, 128]
+        rank = mm(m.astype(jnp.bfloat16), upper, ((1,), (0,))) * m
+        per_block = jnp.sum(m, axis=1, keepdims=True)           # [nb, 1]
+        start = mm(before, jnp.broadcast_to(per_block, (nb, _LANES))
+                   .astype(jnp.bfloat16), ((1,), (0,)))[:, 0:1]
+        return rank, per_block, start
+
+    # Every key above the k-th score, and of those AT it (ties: several
+    # heads' ReLUs all shut give exactly 0) the first in key order that fill
+    # the k, as a stable top-k does.
+    above = (key > t) & live
+    tie_rank, _, tie_start = prefix((key == t) & live)
+    kept = above | ((tie_rank > 0)
+                    & (tie_rank + tie_start <= kf - _count(above)))
+    rank, per_block, start = prefix(kept)
+    # Output slot p (on the lanes) takes the (p - start + 1)-th kept key of
+    # the block whose [start, start + per_block) holds p.
+    p = iota((1, k), 1).astype(jnp.float32)
+    mine = (start <= p) & (p < start + per_block)               # [nb, k]
+    block = jnp.sum(jnp.where(mine, iota((nb, k), 0).astype(jnp.float32),
+                              0.0), axis=0, keepdims=True)
+    want = p - jnp.sum(jnp.where(mine, start, 0.0), axis=0,
+                       keepdims=True) + 1.0                     # [1, k]
+    eye = jnp.where(iota((_LANES, _LANES), 0) == iota((_LANES, _LANES), 1),
+                    1.0, 0.0).astype(jnp.bfloat16)
+    rank_t = mm(eye, rank.astype(jnp.bfloat16), ((1,), (1,)))   # [128, nb]
+    ranks = mm(rank_t.astype(jnp.bfloat16),
+               jnp.where(mine, 1.0, 0.0).astype(jnp.bfloat16),
+               ((1,), (0,)))                                    # [128, k]
+    lane = jnp.sum(jnp.where(ranks == want,
+                             iota((_LANES, k), 0).astype(jnp.float32), 0.0),
+                   axis=0, keepdims=True)
+    n_kept = jnp.sum(per_block, axis=0, keepdims=True)          # [1, 1]
+    idx = (block * _LANES + lane).astype(jnp.int32)
+    o_ref[0] = jnp.where(p < n_kept, idx, -1)
+
+
+def index_select(scores, k, *, interpret=None):
+    """``scores [B, Q, S]`` float32 (``-inf`` = not allowed) -> the ``k``
+    best keys of every query ``[B, Q, k]`` int32 in key order, ``-1`` behind
+    them where fewer than ``k`` are allowed."""
+    B, Q, S = scores.shape
+    if S % _LANES or k > S:
+        raise ValueError(f"index_select needs {_LANES} | S and k <= S, got "
+                         f"S {S}, k {k}")
+    nb = S // _LANES
+    out = pl.pallas_call(
+        functools.partial(_select_kernel, k=k),
+        grid=(B * Q,),
+        in_specs=[pl.BlockSpec((1, nb, _LANES), lambda n: (n, 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, k), lambda n: (n, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * Q, 1, k), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=B * Q * (2 * _LANES * nb * k + 40 * S),
+            transcendentals=0, bytes_accessed=B * Q * (S + k) * 4),
+        name=SELECT_NAME,
+        interpret=_interpret() if interpret is None else interpret,
+    )(scores.reshape(B * Q, nb, _LANES))
+    return out.reshape(B, Q, k)
+
+
+# ---- sparse_latent_attention -------------------------------------------------
+
+def _sparse_kernel(n_ref, q_ref, r_ref, o_ref, *, kv_rank, scale):
+    n_valid = n_ref[pl.program_id(0)]
+    rows = r_ref[0]                                             # [k, W]
+    s = jax.lax.dot_general(q_ref[0], rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(col < n_valid, s, _NEG)                       # [H, k]
+    p = jnp.exp(s - jnp.max(s, -1, keepdims=True))
+    p = jnp.where(col < n_valid, p, 0.0)
+    total = jnp.sum(p, -1, keepdims=True)
+    p = (p / jnp.where(total > 0, total, 1.0)).astype(rows.dtype)
+    o_ref[0] = jax.lax.dot_general(
+        p, rows[:, :kv_rank], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def sparse_latent_attention(q, picked, selected, a, *, interpret=None):
+    """``q [B, Q, H, W]`` against each query's own rows ``picked [B, Q, k,
+    W]`` (``selected [B, Q, k]``: ``-1`` entries, all at the back, are not
+    attended) -> ``[B, Q, H, kv_rank]``."""
+    B, Q, H, W = q.shape
+    k = picked.shape[2]
+    n_valid = jnp.sum(selected >= 0, -1).astype(jnp.int32).reshape(-1)
+    out = pl.pallas_call(
+        functools.partial(_sparse_kernel, kv_rank=a.kv_rank,
+                          scale=1.0 / math.sqrt(a.nope_dim + a.rope_dim)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * Q,),
+            in_specs=[
+                pl.BlockSpec((1, H, W), lambda n, nv: (n, 0, 0)),
+                pl.BlockSpec((1, k, W), lambda n, nv: (n, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, H, a.kv_rank),
+                                   lambda n, nv: (n, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((B * Q, H, a.kv_rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * B * Q * H * k * (W + a.kv_rank),
+            transcendentals=B * Q * H * k,
+            bytes_accessed=B * Q * (k * W + H * (W + a.kv_rank))
+            * q.dtype.itemsize),
+        name=SPARSE_NAME,
+        interpret=_interpret() if interpret is None else interpret,
+    )(n_valid, q.reshape(B * Q, H, W), picked.reshape(B * Q, k, W))
+    return out.reshape(B, Q, H, a.kv_rank)
+
+
+# ---- window_latent_attention -------------------------------------------------
+
+def _window_kernel(p0_ref, q_ref, r_ref, kpos_ref, o_ref, *, n_heads, tq,
+                   window, kv_rank, scale):
+    b, qi = pl.program_id(0), pl.program_id(1)
+    ring = r_ref[0]                                             # [R, W]
+    s = jax.lax.dot_general(q_ref[0, 0], ring, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    # Row r of the tile is query r // H of it, at p0 + its place in the call.
+    row = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0)
+    q_pos = p0_ref[b] + qi * tq + row // n_heads
+    k_pos = kpos_ref[0]                                         # [1, R]
+    dist = q_pos - k_pos
+    ok = (dist >= 0) & (dist < window) & (k_pos >= 0)           # [rows, R]
+    s = jnp.where(ok, s, _NEG)
+    p = jnp.exp(s - jnp.max(s, -1, keepdims=True))
+    p = jnp.where(ok, p, 0.0)
+    total = jnp.sum(p, -1, keepdims=True)
+    p = (p / jnp.where(total > 0, total, 1.0)).astype(ring.dtype)
+    o_ref[0, 0] = jax.lax.dot_general(
+        p, ring[:, :kv_rank], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def window_latent_attention(q, ring, q_pos, k_pos, a, *, interpret=None):
+    """``q [B, Q, H, W]`` at consecutive positions ``q_pos [B, Q]`` against
+    the slot's ring ``[B, R, W]`` whose cells hold positions ``k_pos [B, R]``
+    (negative = nothing yet) -> ``[B, Q, H, kv_rank]``; a query sees ``0 <=
+    q_pos - k_pos < a.window``."""
+    B, Q, H, W = q.shape
+    R = ring.shape[1]
+    tq = _divisor(Q, max(1, 512 // H))
+    nq = Q // tq
+    out = pl.pallas_call(
+        functools.partial(_window_kernel, n_heads=H, tq=tq, window=a.window,
+                          kv_rank=a.kv_rank,
+                          scale=1.0 / math.sqrt(a.nope_dim + a.rope_dim)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, nq),
+            in_specs=[
+                pl.BlockSpec((1, 1, tq * H, W), lambda b, qi, p0:
+                             (b, qi, 0, 0)),
+                pl.BlockSpec((1, R, W), lambda b, qi, p0: (b, 0, 0)),
+                pl.BlockSpec((1, 1, R), lambda b, qi, p0: (b, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, tq * H, a.kv_rank),
+                                   lambda b, qi, p0: (b, qi, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((B, nq, tq * H, a.kv_rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * B * Q * H * R * (W + a.kv_rank),
+            transcendentals=B * Q * H * R,
+            bytes_accessed=B * (R * W + Q * H * (W + a.kv_rank))
+            * q.dtype.itemsize),
+        name=WINDOW_NAME,
+        interpret=_interpret() if interpret is None else interpret,
+    )(q_pos[:, 0].astype(jnp.int32), q.reshape(B, nq, tq * H, W), ring,
+      k_pos.astype(jnp.int32)[:, None, :])
+    return out.reshape(B, Q, H, a.kv_rank)

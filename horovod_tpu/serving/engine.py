@@ -53,6 +53,28 @@ argument in place and holds no copy or slice of a layer's cache
 checks; for the decode program also that nothing of the gathered pages'
 size is left in it).
 
+A layer of LATENT attention (``TransformerConfig.latent``;
+``transformer._latent_qkv`` makes its absorbed operands) runs the same way in
+the chunk and the decode program, which for it differ only in the length of
+the query window (:func:`_latent_layer`): the window's latent rows (and
+selection keys) are scattered into the layer's arrays, then
+
+- a layer that **selects** scores every live key of the slot with the small
+  scorer (``index_scores``), keeps each query's ``index_topk`` best
+  (``index_select``), gathers those rows through the block table and attends
+  over them alone (``sparse_latent_attention``); the ``[Q, J, max_kv]``
+  per-head scores exist only a tile at a time inside the first kernel;
+- a **window** layer gathers the slot's ring (``ring_blocks`` pages, from
+  the block table's tail), works out which position each ring cell holds,
+  and attends under the window (``window_latent_attention``).
+
+Those four names are Pallas kernels (:mod:`horovod_tpu.ops.pallas_latent`)
+and the instruction names a device trace shows; they run on a TPU backend
+with no mesh (:func:`latent_kernels`). Elsewhere the same mathematics runs as
+plain ``jax.numpy`` (``transformer.index_scores``, ``select_keys``,
+``latent_attend``). A latent model is filled by chunks only: the padded
+prefills are not built for it.
+
 The batch-slot ↔ request mapping, page ownership, and admission policy
 live host-side in :mod:`.scheduler`; this module never allocates.
 """
@@ -64,6 +86,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import transformer as tfm
+from ..ops import pallas_latent
 from ..ops import pallas_paged_attention as paged_attention
 from . import kv_cache
 
@@ -90,11 +113,21 @@ def decode_attn(cfg, geo, mesh):
             f"resolved to {impl!r}; use attn_impl='auto' or 'gather'")
     if (cfg.attn_impl == "auto" and mesh is None
             and jax.default_backend() == "tpu"
+            and not cfg.latent
             and paged_attention.supported(
                 geo.page_size, cfg.n_heads * cfg.head_dim,
                 cfg.compute_dtype)):
         return "paged"
     return "gather"
+
+
+def latent_kernels(cfg, geo, mesh):
+    """Whether the latent layers run their Pallas kernels: a TPU backend,
+    no mesh (a ``shard_map`` over them is not written), ``attn_impl`` left
+    open, and shapes the kernels tile."""
+    return (bool(cfg.latent) and mesh is None
+            and cfg.attn_impl == "auto" and jax.default_backend() == "tpu"
+            and all(pallas_latent.supported(a, geo) for _, a in cfg.latent))
 
 
 def _check_positions(cfg, n, what):
@@ -105,6 +138,14 @@ def _check_positions(cfg, n, what):
             f"{what} {n} exceeds the model's max_seq_len "
             f"{cfg.max_seq_len} (pos_embed rows); shrink the cache "
             f"geometry or raise max_seq_len")
+
+
+def _context_tables(block_tables, geo):
+    """The context's columns of the block tables (all of them where the
+    model has no rings)."""
+    if not geo.ring_blocks:
+        return block_tables
+    return block_tables[:, :geo.max_blocks]
 
 
 def _fused(x):
@@ -131,11 +172,93 @@ def _cache_out(ck, cv, mesh, cfg):
     """The per-layer lists back in the cache's form, each array held to
     its shard of the mesh."""
     kv_spec = kv_cache.spec(cfg)
-    return {"k": tuple(_constrain(c, mesh, kv_spec) for c in ck),
-            "v": tuple(_constrain(c, mesh, kv_spec) for c in cv)}
+    def out(c):
+        return None if c is None else _constrain(c, mesh, kv_spec)
+
+    return {"k": tuple(map(out, ck)), "v": tuple(map(out, cv))}
 
 
-def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh):
+def _latent_layer(a, q, row, index, rows_c, keys_c, *, q_pos, ok, tables,
+                  geo, dt, kernels):
+    """One latent layer of a chunk or decode program: write the window's
+    ``row [B, Q, W]`` (and selection key) at ``q_pos [B, Q]`` where ``ok [B,
+    Q]``, then attend ``q [B, Q, H, W]`` -> (the layer's arrays, ``o [B, Q,
+    H, kv_rank]``, the selected keys ``[B, Q, k]`` or None). ``tables [B,
+    max_blocks + ring_blocks]``."""
+    page = geo.page_size
+    B, Q = q_pos.shape
+    if a.window:
+        table = tables[:, geo.max_blocks:]
+        blk = (q_pos // page) % geo.ring_blocks
+    else:
+        table = tables[:, :geo.max_blocks]
+        blk = jnp.minimum(q_pos // page, geo.max_blocks - 1)
+    page_ids = jnp.where(ok, jnp.take_along_axis(table, blk, axis=1), 0)
+    slot = jnp.where(ok, q_pos % page, 0)
+    rows_c = rows_c.at[page_ids, slot].set(row)
+    if a.window:
+        # The ring's cells hold the latest position of each residue that has
+        # been written: the highest this call writes, and the R - 1 before.
+        n_cells = geo.ring_tokens
+        ring = rows_c[table].reshape(B, n_cells, -1)
+        p_hi = jnp.max(jnp.where(ok, q_pos, -1), axis=1)          # [B]
+        k_pos = p_hi[:, None] - (p_hi[:, None] - jnp.arange(n_cells)) \
+            % n_cells
+        if kernels:
+            o = pallas_latent.window_latent_attention(q, ring, q_pos, k_pos, a)
+        else:
+            allowed = tfm.latent_allowed(a, q_pos, k_pos, k_pos >= 0)
+            o = tfm.latent_attend(q, ring, a, allowed, dt)
+        return rows_c, keys_c, o, None
+    k_pos = jnp.broadcast_to(jnp.arange(geo.max_kv)[None], (B, geo.max_kv))
+    if not a.index_topk:
+        raise ValueError("a full latent layer with no key selection is not "
+                         "served yet (it would attend over max_kv rows)")
+    keys_c = keys_c.at[page_ids, slot].set(index["k"])
+    keys = keys_c[table].reshape(B, geo.max_kv, -1)
+    if kernels:
+        scores = pallas_latent.index_scores(index["q"], index["w"], keys,
+                                            q_pos)
+        selected = pallas_latent.index_select(scores, a.index_topk)
+    else:
+        scores = tfm.index_scores(index["q"], index["w"], keys,
+                                  tfm.latent_allowed(a, q_pos, k_pos))
+        selected = tfm.select_keys(scores, a.index_topk)
+    # The selected rows. A long query window picks more rows than the slot
+    # has (512 queries x 2048): the slot's pages are gathered once, whole
+    # pages at a time, and the rows picked from that by key index. A decode
+    # step picks fewer than a slot's context holds: each row comes through
+    # the block table from the layer's array taken as rows (pages and
+    # slots merged: no copy), and nothing of ``max_kv`` is touched.
+    sel = jnp.maximum(selected, 0)
+    if Q * sel.shape[-1] >= geo.max_kv:
+        # One gather of rows out of one flat array: the same rows taken with
+        # a batch dimension (``vmap``) or through the page and slot indices
+        # of the layer's own array take 12-17 ms against 4 for 512 x 2048
+        # rows on a v5e (PERF.md, PR 35). The barrier keeps the page gather
+        # a step of its own.
+        rows = jax.lax.optimization_barrier(
+            rows_c[table].reshape(B * geo.max_kv, -1))
+        flat = sel + (jnp.arange(B) * geo.max_kv)[:, None, None]
+        picked = rows.at[flat].get(mode="promise_in_bounds")    # [B, Q, k, W]
+    else:
+        flat = jnp.take_along_axis(
+            table, (sel // page).reshape(B, -1), axis=1
+        ).reshape(sel.shape) * page + sel % page
+        picked = rows_c.reshape(-1, rows_c.shape[-1])[flat]
+    if kernels:
+        o = pallas_latent.sparse_latent_attention(q, picked, selected, a)
+    else:       # every query a batch row of its own, against its own rows
+        o = tfm.latent_attend(
+            q.reshape(B * Q, 1, *q.shape[2:]),
+            picked.reshape(B * Q, *picked.shape[2:]), a,
+            (selected >= 0).reshape(B * Q, 1, -1), dt).reshape(
+                B, Q, q.shape[2], a.kv_rank)
+    return rows_c, keys_c, o, selected
+
+
+def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
+            latent=None):
     """Every layer of the model over ``x [B, S, D]`` through
     ``transformer.block``, the one block definition, with the serving
     attention: layer ``li``'s new K/V (after the Q/K norm and the rotation
@@ -143,35 +266,52 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh):
     them) go into the cache by ``write(layer_cache, fused) ->
     (layer_cache, k or v to attend over)``, then the window attends by
     ``attend(q, k, v)`` (:func:`_masked` over gathered pages, or the decode
-    program's kernel over the layer's own arrays). -> (ck, cv, x after the
-    final norm, routing of the MoE layers or None)."""
+    program's kernel over the layer's own arrays). A latent layer goes
+    through ``latent(a, q, row, index, rows_c, keys_c)``
+    (:func:`_latent_layer` with the program's positions and tables). ->
+    (ck, cv, x after the final norm, what the layers report or None:
+    ``counts`` and ``top`` of the expert layers, ``selected`` of the
+    selecting ones, each stacked over those layers)."""
     ck, cv = list(cache["k"]), list(cache["v"])
-    routings = []
+    reports = []
     for li, layer in enumerate(params["layers"]):
-        def write_and_attend(q, k, v, li=li):
-            ck[li], kk = write(ck[li], k)
-            cv[li], vv = write(cv[li], v)
-            return attend(q, kk, vv)
+        a = cfg.attn_of(li)
+        if a is None:
+            def write_and_attend(q, k, v, li=li):
+                ck[li], kk = write(ck[li], k)
+                cv[li], vv = write(cv[li], v)
+                return attend(q, kk, vv)
+        else:
+            def write_and_attend(q, row, index, li=li, a=a):
+                ck[li], cv[li], o, selected = latent(a, q, row, index,
+                                                     ck[li], cv[li])
+                return o, selected
 
-        x, routing = tfm.block(layer, x, cfg, write_and_attend,
-                               positions=positions,
-                               mesh=mesh, valid=valid)
-        routings.append(routing)
-    moe = None
-    if cfg.n_experts > 0:
-        moe = {name: jnp.stack([r[name] for r in routings])
-               for name in ("counts", "top")}
-    return ck, cv, tfm._norm(x, params["final_ln"], cfg), moe
+        x, report = tfm.block(layer, x, cfg, write_and_attend,
+                              positions=positions,
+                              mesh=mesh, valid=valid, li=li)
+        reports.append(report or {})
+    names = {name for r in reports for name in r}
+    aux = {name: jnp.stack([r[name] for r in reports if name in r])
+           for name in sorted(names)} or None
+    return ck, cv, tfm._norm(x, params["final_ln"], cfg), aux
 
 
 def _result(ck, cv, logits, moe, mesh, cfg):
     """What every program returns: the cache and float32 logits; for a
-    model with experts also its routing, ``{"counts": [layers, E] pairs
-    each expert received from the live rows, "top": [layers, B, S, k]}``
-    (the loop fetches ``counts`` with the tokens; ``top`` stays on the
-    device unless someone asks)."""
+    model with experts also its routing, ``{"counts": [expert layers, E]
+    pairs each expert held here received from the live rows, "top": [expert
+    layers, B, S, k]}`` (the loop fetches ``counts`` with the tokens; ``top``
+    stays on the device unless someone asks), and for one that selects its
+    keys ``"selected": [selecting layers, B, S, k]`` in the same dict."""
     out = (_cache_out(ck, cv, mesh, cfg), logits.astype(jnp.float32))
     return out if moe is None else out + (moe,)
+
+
+def _no_latent(cfg, what):
+    if cfg.latent:
+        raise ValueError(f"{what}: a model with latent attention layers is "
+                         f"filled by chunks (make_chunk_step) only")
 
 
 def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
@@ -188,6 +328,7 @@ def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
     same compiled program; it must cover whole pages.
     """
     decode_attn(cfg, geo, mesh)
+    _no_latent(cfg, "make_prefill")
     pad = geo.max_kv if prefill_pad is None else int(prefill_pad)
     if pad % geo.page_size != 0:
         raise ValueError(f"prefill_pad {pad} must be a multiple of "
@@ -235,12 +376,17 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
     trash page 0 and their logits are garbage the scheduler never reads.
     """
     paged = decode_attn(cfg, geo, mesh) == "paged"
+    kernels = latent_kernels(cfg, geo, mesh)
     dt = cfg.compute_dtype
 
     def decode(params, cache, tokens, positions, block_tables, active):
         x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
                               params, cfg, positions)
         x = x[:, None, :]                                  # [B, 1, D]
+        latent = functools.partial(
+            _latent_layer, q_pos=positions[:, None], ok=active[:, None],
+            tables=block_tables, geo=geo, dt=dt, kernels=kernels)
+        block_tables = _context_tables(block_tables, geo)
         blk = positions // geo.page_size
         slot = positions % geo.page_size
         page_ids = jnp.take_along_axis(block_tables, blk[:, None],
@@ -274,7 +420,8 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
             attend = _masked(cfg, kv_mask)
 
         ck, cv, x, moe = _layers(params, cache, x, positions[:, None], write,
-                                 attend, active[:, None], cfg=cfg, mesh=mesh)
+                                 attend, active[:, None], cfg=cfg, mesh=mesh,
+                                 latent=latent)
         logits = jnp.einsum("bsd,vd->bsv", x,
                             tfm.head_weights(params, cfg).astype(dt))[:, 0]
         return _result(ck, cv, logits, moe, mesh, cfg)
@@ -293,6 +440,7 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
     <= p. Returns (ck, cv, x[B, Q, D] after the final norm, routing)."""
     q_len = tokens.shape[1]
     max_kv = geo.max_kv
+    tables, block_tables = block_tables, _context_tables(block_tables, geo)
     pos = positions[:, None] + jnp.arange(q_len)[None, :]    # [B, Q]
     pe = jnp.clip(pos, 0, cfg.max_seq_len - 1)
     x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
@@ -309,9 +457,12 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
         layer_cache = layer_cache.at[page_ids, slot_w].set(_fused(kv))
         return layer_cache, _gather_pages(layer_cache, block_tables, cfg)
 
+    latent = functools.partial(
+        _latent_layer, q_pos=pos, ok=valid, tables=tables, geo=geo,
+        dt=cfg.compute_dtype, kernels=latent_kernels(cfg, geo, mesh))
     return _layers(params, cache, x, pos, write,
                    _masked(cfg, kv_mask[:, None, :, :]), valid, cfg=cfg,
-                   mesh=mesh)
+                   mesh=mesh, latent=latent)
 
 
 def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
@@ -388,6 +539,7 @@ def make_batched_prefill(cfg, geo, mesh=None, prefill_pad=None):
                          f"page_size {geo.page_size}")
     _check_positions(cfg, pad, "prefill_pad")
     decode_attn(cfg, geo, mesh)
+    _no_latent(cfg, "make_batched_prefill")
 
     def bprefill(params, cache, tokens, lengths, block_tables, active):
         positions = jnp.zeros(tokens.shape[:1], jnp.int32)

@@ -28,6 +28,27 @@ Page 0 is the **trash page**: the allocator never hands it out, and the
 engine routes every masked write there (inactive batch slots, padding
 positions), so the compiled scatter needs no branches.
 
+Cache by layer kind. A layer of multi-head LATENT attention
+(``TransformerConfig.latent``) holds no per-head K and V: its ``"k"`` array is
+one **latent row** a token, ``[n_pages, page, row_width]`` (the normed
+key/value latent, the rotated key dims shared by all heads, zeros up to whole
+lane tiles: 512 + 64 -> 640 lanes, 1024 + 64 -> 1152), and its ``"v"`` array
+is the selection scorer's key, ``[n_pages, page, index_dim]``, where the
+layer selects its keys, else None. A **window** layer's rows are never read
+again once ``window`` positions behind, so its array is a pool of its own,
+``[ring_pages, page, row_width]``, from which every slot owns a fixed **ring**
+of ``ring_blocks`` pages for as long as it runs: position ``p`` lives in the
+ring's block ``(p // page) % ring_blocks``, and ``ring_blocks * page >=
+window - 1 + the longest query window of any program``, so that a program
+may write its whole window first and still find every key its first query
+sees. A ring (and not a block table that frees pages as the window slides)
+because its size never changes: nothing is allocated or freed at a token
+boundary, no request can be starved or preempted for window state, and the
+block table a program takes keeps one fixed width, ``max_blocks +
+ring_blocks`` (the ring's page ids ride behind the context's). Window
+layers sized like full ones would hold ``max_kv`` positions a slot for state
+that is never read again.
+
 Tensor-parallel layout: the fused ``n_heads * head_dim`` dimension rides
 the mesh's ``model`` axis. Heads are its major part, so a shard of it is
 whole heads — the SAME heads the attention weights' shard produces
@@ -39,6 +60,7 @@ given a mesh.
 """
 
 import dataclasses
+import math
 
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -53,11 +75,22 @@ class CacheGeometry:
     never a shape."""
     n_pages: int
     page_size: int
-    max_blocks: int      # block-table width = max context pages/request
+    max_blocks: int      # context pages a request may own
+    ring_blocks: int = 0  # pages of a slot's ring (window layers); 0 = none
+    ring_pages: int = 0   # the window layers' pool, trash page 0 included
 
     @property
     def max_kv(self):
         return self.max_blocks * self.page_size
+
+    @property
+    def table_width(self):
+        """Columns of a block table: the context's pages, then the ring's."""
+        return self.max_blocks + self.ring_blocks
+
+    @property
+    def ring_tokens(self):
+        return self.ring_blocks * self.page_size
 
 
 def geometry(n_pages, page_size, max_context):
@@ -68,6 +101,19 @@ def geometry(n_pages, page_size, max_context):
                          max_blocks=max_blocks)
 
 
+def with_rings(geo, cfg, q_len, max_batch):
+    """``geo`` with a ring for every slot where ``cfg`` has window layers:
+    ``window - 1 + q_len`` positions (``q_len``: the longest query window a
+    program will run) in whole pages, ``max_batch`` of them and the trash
+    page. Unchanged for a model without window layers."""
+    windows = [a.window for _, a in cfg.latent if a.window]
+    if not windows:
+        return geo
+    blocks = -(-(max(windows) - 1 + int(q_len)) // geo.page_size)
+    return dataclasses.replace(geo, ring_blocks=blocks,
+                               ring_pages=int(max_batch) * blocks + 1)
+
+
 def spec(cfg):
     """PartitionSpec of one layer's K or V array: the fused heads * head_dim
     dimension on the model axis (heads major, so a shard holds whole heads
@@ -75,22 +121,39 @@ def spec(cfg):
     return P(None, None, cfg.model_axis)
 
 
+def layer_shapes(cfg, geo, li):
+    """Shapes of layer ``li``'s ``("k", "v")`` arrays; None = no array."""
+    a = cfg.attn_of(li)
+    if a is None:
+        shape = (geo.n_pages, geo.page_size, cfg.n_heads * cfg.head_dim)
+        return shape, shape
+    pages = geo.ring_pages if a.window else geo.n_pages
+    if a.window and not pages:
+        raise ValueError("a window layer needs a geometry with rings "
+                         "(kv_cache.with_rings)")
+    return ((pages, geo.page_size, a.row_width),
+            (pages, geo.page_size, a.index_dim) if a.index_topk else None)
+
+
 def make_cache(cfg, geo, mesh=None):
     """Allocate the zeroed cache: {"k": (...), "v": (...)}, each a tuple of
-    n_layers arrays [n_pages, page_size, n_heads * head_dim] in the model's
-    compute dtype. With a mesh, the arrays are placed sharded on the
+    n_layers arrays in the model's compute dtype, [n_pages, page_size,
+    n_heads * head_dim] for a multi-head layer (:func:`layer_shapes` for a
+    latent one). With a mesh, the arrays are placed sharded on the
     model axis (when that axis exists in the mesh)."""
-    shape = (geo.n_pages, geo.page_size, cfg.n_heads * cfg.head_dim)
     sharding = None
     if mesh is not None and cfg.model_axis in mesh.axis_names:
         sharding = NamedSharding(mesh, spec(cfg))
-    return {name: tuple(jnp.zeros(shape, cfg.compute_dtype, device=sharding)
-                        for _ in range(cfg.n_layers))
-            for name in ("k", "v")}
+    shapes = [layer_shapes(cfg, geo, li) for li in range(cfg.n_layers)]
+    return {name: tuple(
+        None if s[i] is None
+        else jnp.zeros(s[i], cfg.compute_dtype, device=sharding)
+        for s in shapes) for i, name in enumerate(("k", "v"))}
 
 
 def cache_bytes(cfg, geo):
-    """Total cache footprint in bytes (both K and V)."""
-    per = (cfg.n_layers * geo.n_pages * geo.page_size * cfg.n_heads *
-           cfg.head_dim * jnp.dtype(cfg.compute_dtype).itemsize)
-    return 2 * per
+    """Total cache footprint in bytes (every layer's arrays)."""
+    size = jnp.dtype(cfg.compute_dtype).itemsize
+    return sum(size * math.prod(shape)
+               for li in range(cfg.n_layers)
+               for shape in layer_shapes(cfg, geo, li) if shape is not None)

@@ -189,11 +189,17 @@ class ServeLoop:
         self.mode = mode
         self.load_reporter = load_reporter
         self.report_interval = int(report_interval)
-        padded = geo.max_kv <= PADDED_PREFILL_MAX_KV
+        # Latent layers fill by chunks whatever the width; rings of window
+        # state cannot be shared between requests, so no prefix is.
+        padded = geo.max_kv <= PADDED_PREFILL_MAX_KV and not cfg.latent
         if prefill_chunk is None:
             prefill_chunk = (2 * geo.page_size if padded
                              else LONG_PREFILL_CHUNK)
         self.prefill_chunk = min(geo.max_kv, int(prefill_chunk))
+        geo = self.geo = kv_cache.with_rings(
+            geo, cfg, max(self.prefill_chunk, self.spec_tokens + 1),
+            self.max_batch)
+        use_prefix = use_prefix and not geo.ring_blocks
         self.prefill_fn = (engine.make_prefill(cfg, geo, mesh)
                            if padded else None)
         self.decode_fn = engine.make_decode_step(cfg, geo, mesh, max_batch)
@@ -214,9 +220,12 @@ class ServeLoop:
         self.cache = kv_cache.make_cache(cfg, geo, mesh)
         self.alloc = PageAllocator(geo.n_pages, geo.page_size)
         self.prefix = PrefixCache(self.alloc) if use_prefix else None
-        self.batcher = ContinuousBatcher(self.alloc, max_batch, mode,
-                                         prefix_cache=self.prefix,
-                                         spec_tokens=self.spec_tokens)
+        self.batcher = ContinuousBatcher(
+            self.alloc, max_batch, mode, prefix_cache=self.prefix,
+            spec_tokens=self.spec_tokens,
+            ring_allocator=(PageAllocator(geo.ring_pages, geo.page_size)
+                            if geo.ring_blocks else None),
+            ring_blocks=geo.ring_blocks)
         self.loop_stats = {"prefill_single": 0, "prefill_batched": 0,
                            "prefill_batch_calls": 0, "chunk_fills": 0,
                            "boundaries": 0,
@@ -233,9 +242,17 @@ class ServeLoop:
         self.moe = cfg.n_experts > 0
         self._moe_last = None
         self._moe_pending = []
-        self._moe_load = np.zeros((cfg.n_layers, max(cfg.n_experts, 1)),
-                                  np.int64)
+        self._moe_load = np.zeros((max(len(cfg.moe_layers), 1),
+                                   max(cfg.n_held, 1)), np.int64)
         self.moe_stats = {"pairs": {}, "expert_reads": {}, "calls": {}}
+        # Latent layers: the (query, key) pairs scored, selected and
+        # windowed, by program kind, from the positions alone (as
+        # ``kv_pages_read`` is: host arithmetic, nothing fetched).
+        kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
+        self._select = [a.index_topk for a in kinds if a and a.index_topk]
+        self._windows = [a.window for a in kinds if a and a.window]
+        self.attn_stats = {name: {} for name in (
+            "kv_scored", "kv_selected", "kv_window", "queries", "calls")}
 
     @contextlib.contextmanager
     def _span(self, name, **args):
@@ -256,7 +273,7 @@ class ServeLoop:
         with experts also returns its routing; its counts are kept on the
         device until :meth:`_fetch`."""
         self.cache, logits, *routing = fn(self.params, self.cache, *args)
-        if routing:
+        if routing and "counts" in routing[0]:
             if self._moe_last is not None:
                 self._moe_pending.append(self._moe_last)
             self._moe_last = (kind, routing[0]["counts"])
@@ -283,13 +300,42 @@ class ServeLoop:
             self._moe_load += c
         return packed[:n_tokens].reshape(logits.shape[:-1])
 
+    def _count_attn(self, kind, live):
+        """One program call whose queries see ``live`` keys each (their
+        positions + 1): what its latent layers scored, selected and
+        windowed."""
+        if not (self._select or self._windows):
+            return
+        live = np.asarray(live, np.int64)
+        found = {
+            "kv_scored": int(live.sum()) * len(self._select),
+            "kv_selected": sum(int(np.minimum(live, k).sum())
+                               for k in self._select),
+            "kv_window": sum(int(np.minimum(live, w).sum())
+                             for w in self._windows),
+            "queries": live.size, "calls": 1}
+        for name, n in found.items():
+            by_kind = self.attn_stats[name]
+            by_kind[kind] = by_kind.get(kind, 0) + n
+        if _metrics.enabled():
+            for metric, name in ((_metrics.SERVE_KV_SCORED, "kv_scored"),
+                                 (_metrics.SERVE_KV_SELECTED, "kv_selected"),
+                                 (_metrics.SERVE_KV_WINDOW, "kv_window")):
+                metric.labels(program=kind).inc(found[name])
+            _metrics.SERVE_KV_SELECT_SHARE.set(self._kv_select_share())
+
+    def _kv_select_share(self):
+        scored = sum(self.attn_stats["kv_scored"].values())
+        return (sum(self.attn_stats["kv_selected"].values()) / scored
+                if scored else 0.0)
+
     def warmup(self):
         """Compile every engine jit outside any measured window. Every
         cache write routes to trash page 0 (all-zero block table,
         all-inactive batch), so the cache stays semantically untouched.
         A benchmark calls this before starting its clock so compile
         time never pollutes the throughput it reads."""
-        B, mb = self.max_batch, self.geo.max_blocks
+        B, mb = self.max_batch, self.geo.table_width
 
         def slots(b, *q):
             return (np.zeros((b, *q), np.int32), np.zeros(b, np.int32),
@@ -380,6 +426,7 @@ class ServeLoop:
             bt = np.asarray(
                 self.batcher.block_table(req, self.geo.max_blocks),
                 np.int32)[None]
+            self._count_attn("chunk", np.arange(filled, end) + 1)
         with self._span("serve.chunk.dispatch", rid=req.rid, start=filled,
                         end=end, target=target):
             logits = self._call("chunk", self.chunk_fn, toks,
@@ -401,13 +448,14 @@ class ServeLoop:
             B, mb = self.max_batch, self.geo.max_blocks
             tokens = np.zeros(B, np.int32)
             positions = np.zeros(B, np.int32)
-            tables = np.zeros((B, mb), np.int32)
+            tables = np.zeros((B, self.geo.table_width), np.int32)
             active = np.zeros(B, bool)
             for slot, req in ready.items():
                 tokens[slot] = req.generated[-1]
                 positions[slot] = req.context_len - 1
                 tables[slot] = self.batcher.block_table(req, mb)
                 active[slot] = True
+            self._count_attn("decode", positions[active] + 1)
             # Pages of live context a decode step has to read, against the
             # B x max_blocks the gather path reads whatever is live.
             live_pages = int(
@@ -448,7 +496,7 @@ class ServeLoop:
             B, mb = self.max_batch, self.geo.max_blocks
             tokens = np.zeros((B, k + 1), np.int32)
             positions = np.zeros(B, np.int32)
-            tables = np.zeros((B, mb), np.int32)
+            tables = np.zeros((B, self.geo.table_width), np.int32)
             active = np.zeros(B, bool)
             drafts = {}
             for slot, req in ready.items():
@@ -460,6 +508,8 @@ class ServeLoop:
                 positions[slot] = len(ctx) - 1
                 tables[slot] = self.batcher.block_table(req, mb)
                 active[slot] = True
+            self._count_attn("spec", (positions[active][:, None]
+                                      + np.arange(k + 1) + 1).ravel())
         with self._span("serve.spec.dispatch", draft_k=k,
                         fill=self.batcher.batch_fill()):
             logits = self._call("spec", self.spec_fn, tokens, positions,
@@ -684,9 +734,14 @@ class ServeLoop:
         }
         snap.update(self.loop_stats, host_s=dict(self.loop_stats["host_s"]))
         snap["kv_read_share"] = self._kv_read_share()
+        if self._select or self._windows:
+            snap["attn"] = {
+                **{name: dict(by_kind)
+                   for name, by_kind in self.attn_stats.items()},
+                "kv_select_share": self._kv_select_share()}
         if self.moe:
             ms, load = self.moe_stats, self._moe_load
-            steps = ms["calls"].get("decode", 0) * self.cfg.n_layers
+            steps = ms["calls"].get("decode", 0) * len(self.cfg.moe_layers)
             snap["moe"] = {
                 **{name: dict(by_kind) for name, by_kind in ms.items()},
                 "experts_touched_mean": (

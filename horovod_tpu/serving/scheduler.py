@@ -205,6 +205,7 @@ class Request:
     preemptions: int = 0
     admit_seq: int = -1         # admission order (preemption picks max)
     cached_tokens: int = 0      # prompt tokens covered by a prefix hit
+    ring_pages: list = dataclasses.field(default_factory=list)
 
     @property
     def prompt_len(self):
@@ -234,7 +235,8 @@ class ContinuousBatcher:
     """
 
     def __init__(self, allocator, max_batch=DEFAULT_MAX_BATCH,
-                 mode="continuous", prefix_cache=None, spec_tokens=0):
+                 mode="continuous", prefix_cache=None, spec_tokens=0,
+                 ring_allocator=None, ring_blocks=0):
         if mode not in ("continuous", "static"):
             raise ValueError(f"serve mode must be 'continuous' or "
                              f"'static', got {mode!r}")
@@ -246,6 +248,12 @@ class ContinuousBatcher:
         self.mode = mode
         self.prefix = prefix_cache  # PrefixCache or None (reuse off)
         self.spec_tokens = int(spec_tokens)
+        # Window layers (kv_cache.py): a second pool, of which a request
+        # owns one ring of ``ring_blocks`` pages from admission to the day
+        # it leaves its slot. The pool holds ``max_batch`` rings, so a free
+        # slot always finds one.
+        self.ring_alloc = ring_allocator
+        self.ring_blocks = int(ring_blocks) if ring_allocator else 0
         self.waiting = collections.deque()
         self.running = {}          # slot -> Request
         self.done = []
@@ -319,10 +327,17 @@ class ContinuousBatcher:
         self.admit(now)
         return finished
 
-    def _finish(self, req, now):
+    def _release(self, req):
+        """The request leaves its slot: its pages and its ring go back."""
         del self.running[req.slot]
         self.alloc.free(req.pages)
         req.pages = []
+        if req.ring_pages:
+            self.ring_alloc.free(req.ring_pages)
+            req.ring_pages = []
+
+    def _finish(self, req, now):
+        self._release(req)
         req.state = _DONE
         req.finished_t = now
         req.slot = -1
@@ -370,9 +385,7 @@ class ContinuousBatcher:
         (the re-prefill replays prompt + generated so no tokens are
         lost). Preempted requests go to the FRONT of the queue — they
         have priority over never-admitted work."""
-        del self.running[req.slot]
-        self.alloc.free(req.pages)
-        req.pages = []
+        self._release(req)
         req.slot = -1
         req.state = _WAITING
         req.cached_tokens = 0   # re-resolved against the cache at readmit
@@ -409,6 +422,11 @@ class ContinuousBatcher:
                 if shared:
                     self.alloc.free(shared)  # unpin the aborted hit
                 break  # head-of-line: keep arrival order, wait for pages
+            if self.ring_blocks:
+                req.ring_pages = self.ring_alloc.alloc(self.ring_blocks)
+                if req.ring_pages is None:
+                    raise PageError("no ring for a free slot: the window "
+                                    "pool holds fewer than max_batch rings")
             self.waiting.popleft()
             req.pages = shared + pages
             req.cached_tokens = cached
@@ -443,13 +461,15 @@ class ContinuousBatcher:
 
     def block_table(self, req, max_blocks):
         """The request's page list padded with trash page 0 to the
-        engine's fixed block-table width."""
+        engine's fixed block-table width; behind it the pages of the
+        request's ring, where the model has window layers."""
         if len(req.pages) > max_blocks:
             raise ValueError(
                 f"request {req.rid} holds {len(req.pages)} pages > "
                 f"max_blocks {max_blocks} (context "
                 f"{req.context_len} too long for the cache geometry)")
-        return list(req.pages) + [0] * (max_blocks - len(req.pages))
+        return (list(req.pages) + [0] * (max_blocks - len(req.pages))
+                + list(req.ring_pages))
 
     def idle(self):
         return not self.waiting and not self.running

@@ -351,3 +351,62 @@ def test_wide_cache_loop_spans_and_expert_product_names(monkeypatch,
         assert re.search(r"module @(\w+)",
                          tr.lower().as_text()).group(1) == name
         assert str(tr.jaxpr).count("ragged_dot") >= 3 * cfg.n_layers
+
+
+def test_latent_layers_keep_their_kernels_names(monkeypatch):
+    """A model whose layers differ, with the latent kernels steered on as on
+    the chip: ``jit_chunk`` and ``jit_decode`` each call the four kernels by
+    the names the benchmark's readers find them by in a device trace
+    (``index_scores``, ``index_select``, ``sparse_latent_attention``,
+    ``window_latent_attention``; compiled for the chip in
+    tests/test_tpu_compile.py), and the loop's counters of what they scored,
+    selected and windowed are host arithmetic on the positions."""
+    from horovod_tpu.ops import pallas_latent
+    from horovod_tpu.serving import engine
+    from horovod_tpu.serving.loop import serve_stats
+
+    full = tfm.LatentAttention(n_heads=2, q_rank=16, kv_rank=128,
+                               nope_dim=8, rope_dim=8, v_dim=8,
+                               index_heads=2, index_dim=128,
+                               index_rope_dim=8, index_topk=128)
+    window = tfm.LatentAttention(n_heads=2, q_rank=16, kv_rank=128,
+                                 nope_dim=8, rope_dim=8, v_dim=8, window=17)
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=32,
+        max_seq_len=1024, norm="rmsnorm", pos="rope", ffn="swiglu",
+        tie_embeddings=False, dtype="float32",
+        layer_attn=("full", "window"),
+        latent={"full": full, "window": window})
+    monkeypatch.setattr(engine, "latent_kernels", lambda *a: True)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    loop = ServeLoop(params, cfg, geo=kv_cache.geometry(130, 16, 1024),
+                     max_batch=2, prefill_chunk=112)
+    assert loop.geo.ring_tokens == 128 and loop.prefix is None
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    names = (pallas_latent.SCORES_NAME, pallas_latent.SELECT_NAME,
+             pallas_latent.SPARSE_NAME, pallas_latent.WINDOW_NAME)
+    assert names == ("index_scores", "index_select",
+                     "sparse_latent_attention", "window_latent_attention")
+    for fn, shape in ((loop.chunk_fn, (1, 112)), (loop.decode_fn, (2,))):
+        jaxpr = str(fn.trace(
+            params, loop.cache, i32(*shape), i32(shape[0]),
+            i32(shape[0], loop.geo.table_width),
+            jax.ShapeDtypeStruct(shape[:1], jnp.bool_)).jaxpr)
+        for name in names:
+            assert jaxpr.count(f"name={name}") == 1, name
+    _, finished = loop.run(poisson_requests(
+        2, 1e6, np.random.default_rng(1), prompt_len=(130, 150),
+        max_new=(2, 3), vocab=cfg.vocab_size))
+    attn = serve_stats()["attn"]
+    prompts = [r.prompt_len for r in finished]
+    # Every prompt token went through a chunk once: it scored its own
+    # position + 1 keys, kept at most 128, saw at most 17 in its window.
+    assert attn["queries"]["chunk"] == sum(prompts)
+    assert attn["kv_scored"]["chunk"] == sum(n * (n + 1) // 2
+                                             for n in prompts)
+    assert attn["kv_selected"]["chunk"] == sum(
+        min(t + 1, 128) for n in prompts for t in range(n))
+    assert attn["kv_window"]["chunk"] == sum(
+        min(t + 1, 17) for n in prompts for t in range(n))
+    assert attn["calls"]["chunk"] == sum(-(-n // 112) for n in prompts)
+    assert 0 < attn["kv_select_share"] < 1

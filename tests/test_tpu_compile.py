@@ -374,3 +374,85 @@ def test_olmoe_cell_programs_fit_one_chip(topo, as_on_the_chip):
         for m in re.finditer(r" = f32\[([\d,]+)\]", text):
             assert int(np.prod([int(d) for d in m.group(1).split(",")])) \
                 < expert, m.group(0)
+
+
+# ---- the serving programs at benchmark/configs/dots3-note-prev.json's sizes --
+
+def test_layered_cell_programs_fit_one_chip(topo, as_on_the_chip):
+    """``dots3-serve-doc-over``'s two programs (the 512-token chunk fill and
+    the decode step) at the cell's geometry: five layers that differ, 16
+    slots of a 32k context, the window layers on rings. The chip's compiler
+    takes all four latent kernels at the published widths; each is in both
+    programs under the instruction name the benchmark's readers match, once
+    a layer of its kind; the selection costs no ``[512, 64, max_kv]`` float32
+    score block and no top-k sort of the scores (the sorts that remain are
+    the expert dispatch's and the router's, a few thousand elements each);
+    weights + cache + temporaries stay on the chip; the cache is aliased
+    through."""
+    import importlib.util
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "dots3-note-prev.json")) as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "serve_layers", os.path.join(root, "benchmark", "runners",
+                                     "serve_layers.py"))
+    runner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(runner)
+    cfg = runner.model_config(config)
+    srv = config["assumed"]["serve"]
+    B = srv["max_batch"]
+    geo = kv_cache.with_rings(
+        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
+        cfg, 512, B)
+    assert (geo.max_kv, geo.ring_tokens, geo.ring_pages) == (32768, 1024,
+                                                             1025)
+    assert engine.latent_kernels(cfg, geo, None)
+    params, cache = jax.tree.map(
+        lambda x: _on_chip(topo, x.shape, x.dtype),
+        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
+                                kv_cache.make_cache(cfg, geo))))
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert 9.8e9 < held < 10.0e9
+    assert 0.25 * 16.91e9 < held
+
+    def slots(b, *q):
+        return [_on_chip(topo, s, d) for s, d in (
+            ((b, *q), jnp.int32), ((b,), jnp.int32),
+            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
+
+    kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
+    n_full = sum(1 for a in kinds if a.index_topk)
+    n_window = sum(1 for a in kinds if a.window)
+    for name, fn, args in (
+            ("chunk", engine.make_chunk_step(cfg, geo, q_len=512),
+             slots(1, 512)),
+            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
+             slots(B))):
+        compiled = fn.lower(params, cache, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
+        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
+        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
+        text = compiled.as_text()
+        for kernel, n in (("index_scores", n_full), ("index_select", n_full),
+                          ("sparse_latent_attention", n_full),
+                          ("window_latent_attention", n_window)):
+            calls = [line for line in text.splitlines()
+                     if re.match(rf"\s*%{kernel}[.\d]* = ", line)
+                     and "tpu_custom_call" in line]
+            assert len(calls) == n, (name, kernel)
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
+            == 3 * len(cfg.moe_layers)
+        # Nothing float32 of the per-head score block's size, and no sort
+        # as long as a row of scores.
+        rows = args[0].shape[0] * (args[0].shape[1] if name == "chunk" else 1)
+        block = rows * 64 * geo.max_kv
+        for m in re.finditer(r" = f32\[([\d,]+)\]", text):
+            assert int(np.prod([int(d) for d in m.group(1).split(",")])) \
+                < block, m.group(0)
+        for m in re.finditer(r" = \(?\w+\[([\d,]+)\]\S* sort\(", text):
+            assert int(m.group(1).split(",")[-1]) < geo.max_kv, m.group(0)
