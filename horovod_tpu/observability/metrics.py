@@ -584,6 +584,15 @@ SERVE_SPEC_REJECTED = counter(
 SERVE_DECODE_CALLS = counter(
     "hvd_serve_decode_calls",
     "Decode steps dispatched (one token for every live slot each)")
+SERVE_DECODE_AHEAD_CALLS = counter(
+    "hvd_serve_decode_ahead_calls",
+    "Decode steps dispatched while the step before them was still on the "
+    "chip, their input tokens handed over on the device (docs/serving.md "
+    "§One step ahead); the rest waited for the host to read the tokens")
+SERVE_DECODE_AHEAD_DROPPED = counter(
+    "hvd_serve_decode_ahead_dropped",
+    "Tokens such a step computed for a request that had left its slot by "
+    "the time they were read (EOS one step earlier, a preemption): dropped")
 SERVE_DECODE_PAGED_CALLS = counter(
     "hvd_serve_decode_paged_calls",
     "Decode steps whose attention read the paged cache in place through "

@@ -25,6 +25,22 @@ One iteration = one token boundary:
 4. feed the tokens back through the scheduler boundary (evict finished,
    grow pages, admit into the freed slots) and sample the SERVE_* gauges.
 
+The plain decode step runs ONE STEP AHEAD of the host. Step 3 leaves its
+step N on the chip unfetched (``_flight``); the next iteration's step 3
+dispatches N+1 with N's greedy tokens handed over on the device, and only
+then fetches N's tokens and runs step 4 for N, so the chip always has its
+next program queued. N+1's slots, positions and pages do not depend on the
+values of N's tokens (a request ends by ``max_new_tokens`` exactly when
+its count says so; the scheduler already reserves ``context_len + 1``
+positions); an EOS at N is found out one step late, and a token computed
+for a request that has meanwhile left its slot is dropped
+(``_Step.owners``; ``decode_ahead_dropped``). Where the host has to wait
+for a token anyway (a padded prefill, the last chunk of a fill: their
+program is dispatched behind the step in flight, which is read first),
+the decode step after it is packed from tokens on the host. Speculation
+and ``mode="static"`` read every step's tokens before the next dispatch,
+as they always did. docs/serving.md, "One step ahead of the host".
+
 Latency accounting (docs/serving.md has the formal definitions):
 TTFT = first_token_t - arrival_t per request; inter-token latency (ITL)
 = the gaps between a request's consecutive token timestamps. The
@@ -34,8 +50,10 @@ Spans (docs/serving.md has the table): every loop iteration is one
 ``serve.boundary`` whose children are disjoint leaves that together
 cover it — ``serve.admit``; per engine call ``<p>`` (``prefill``,
 ``bprefill``, ``chunk``, ``decode``, ``spec``) ``serve.<p>.pack``,
-``serve.<p>.dispatch`` (the asynchronous jitted call alone) and
-``serve.<p>.fetch`` (ends when the tokens are on the host);
+``serve.<p>.dispatch`` (the asynchronous jitted call and the greedy pick
+of its logits, both enqueued) and ``serve.<p>.fetch`` (ends when the
+tokens are on the host; ``serve.decode.fetch`` reads the step dispatched
+one iteration earlier);
 ``serve.emit``; ``serve.report`` (the ``load_reporter`` hook alone);
 ``serve.idle_wait``. All open through :meth:`ServeLoop._span`, which
 feeds the profiler (and the Chrome timeline under ``HVD_METRICS=1``)
@@ -53,6 +71,7 @@ activity.
 """
 
 import contextlib
+import dataclasses
 import time
 
 import jax
@@ -80,6 +99,39 @@ PADDED_PREFILL_MAX_KV = 1024
 # Tokens per chunk there (where only prefix-cache suffixes chunk-fill, two
 # pages): some hundreds, so that one pass over the weights serves many.
 LONG_PREFILL_CHUNK = 512
+
+
+@dataclasses.dataclass
+class _Step:
+    """One dispatched program whose greedy tokens are still on the device.
+
+    It owns everything its fetch will copy (``out``: the tokens, and behind
+    them the program's expert counts; ``earlier``: the counts of the chunks
+    dispatched before it whose tokens nobody fetches), so that a step
+    dispatched while another is unfetched costs no second transfer, and it
+    remembers whom each token is for: ``owners[slot] = (request, its
+    admit_seq when the program was dispatched, index of its token)``. A
+    token is emitted only if that request still holds the slot under that
+    admission."""
+    kind: str
+    logits: object
+    counts: object
+    out: object
+    earlier: list
+    owners: dict = dataclasses.field(default_factory=dict)
+    ahead: bool = False
+
+    def feed(self):
+        """The tokens as the next decode step's input, never on the host."""
+        return self.out if self.counts is None else engine.greedy(self.logits)
+
+    def ran_for(self, slot, req):
+        """Whether ``req``, as it is admitted now, is the request this step
+        computed ``slot`` for."""
+        mine = self.owners.get(slot)
+        return (req is not None and mine is not None and mine[0] is req
+                and mine[1] == req.admit_seq)
+
 
 # Latest ServeLoop snapshot, surfaced as hvd.serve_stats() (same lazy
 # module-registry idiom as hvd.checkpoint_stats()).
@@ -230,17 +282,21 @@ class ServeLoop:
                            "prefill_batch_calls": 0, "chunk_fills": 0,
                            "boundaries": 0,
                            "decode_calls": 0, "decode_paged_calls": 0,
+                           "decode_ahead_calls": 0, "decode_ahead_dropped": 0,
                            "kv_pages_read": 0, "kv_pages_gathered_before": 0,
                            "host_s": dict.fromkeys(HOST_KINDS, 0.0)}
         self._fills = {}   # rid -> (admit_seq, tokens materialized)
+        # The decode step that is dispatched and not fetched yet: the chip
+        # runs it while the host plans the step after it. Never more than
+        # this one.
+        self._flight = None
         # A model with experts: the (token, expert) pairs every program
         # routed, by program kind and by (layer, expert). A program's
-        # counts wait on the device for the fetch of its tokens
-        # (``_moe_last``; packed with them into one transfer), those of a
-        # chunk whose tokens nobody fetches for the next fetch after it
-        # (``_moe_pending``).
+        # counts wait on the device for the fetch of its tokens (packed
+        # with them into one transfer: ``_Step.out``), those of a chunk
+        # whose tokens nobody fetches for the next program dispatched
+        # after it (``_moe_pending``, then that step's ``earlier``).
         self.moe = cfg.n_experts > 0
-        self._moe_last = None
         self._moe_pending = []
         self._moe_load = np.zeros((max(len(cfg.moe_layers), 1),
                                    max(cfg.n_held, 1)), np.int64)
@@ -268,37 +324,40 @@ class ServeLoop:
             if kind in host_s:
                 host_s[kind] += time.perf_counter() - t0
 
-    def _call(self, kind, fn, *args):
-        """One engine program: the cache back in place, -> logits. A model
-        with experts also returns its routing; its counts are kept on the
-        device until :meth:`_fetch`."""
+    def _call(self, kind, fn, *args, fetch=True):
+        """One engine program: the cache back in place, -> the
+        :class:`_Step` whose tokens :meth:`_fetch` copies, picked on the
+        device at once. ``fetch=False`` (a chunk that ends no fill): nobody
+        reads its tokens, and the counts of a model with experts wait for
+        the next step."""
         self.cache, logits, *routing = fn(self.params, self.cache, *args)
-        if routing and "counts" in routing[0]:
-            if self._moe_last is not None:
-                self._moe_pending.append(self._moe_last)
-            self._moe_last = (kind, routing[0]["counts"])
-        return logits
+        counts = routing[0].get("counts") if routing else None
+        if not fetch:
+            if counts is not None:
+                self._moe_pending.append((kind, counts))
+            return None
+        earlier, self._moe_pending = self._moe_pending, []
+        out = (engine.greedy(logits) if counts is None
+               else engine.greedy_with_counts(logits, counts))
+        return _Step(kind, logits, counts, out, earlier)
 
-    def _fetch(self, logits):
-        """The greedy tokens of the last program on the host, and in the
-        same transfer its expert counts."""
-        if self._moe_last is None:
-            return np.asarray(engine.greedy(logits))
-        (kind, counts), self._moe_last = self._moe_last, None
-        pending, self._moe_pending = self._moe_pending, []
+    def _fetch(self, step):
+        """The step's greedy tokens on the host, and in the same transfer
+        its expert counts and those of the chunks before it."""
+        if step.counts is None:
+            return np.asarray(step.out)
         packed, earlier = jax.device_get(
-            (engine.greedy_with_counts(logits, counts),
-             [c for _, c in pending]))
-        n_tokens = packed.size - counts.size
-        mine = packed[n_tokens:].reshape(counts.shape)
-        for kind, c in [*zip((k for k, _ in pending), earlier),
-                        (kind, mine)]:                 # c: [layers, E]
+            (step.out, [c for _, c in step.earlier]))
+        n_tokens = packed.size - step.counts.size
+        mine = packed[n_tokens:].reshape(step.counts.shape)
+        for kind, c in [*zip((k for k, _ in step.earlier), earlier),
+                        (step.kind, mine)]:            # c: [layers, E]
             for name, n in (("pairs", c.sum()), ("calls", 1),
                             ("expert_reads", np.count_nonzero(c))):
                 by_kind = self.moe_stats[name]
                 by_kind[kind] = by_kind.get(kind, 0) + int(n)
             self._moe_load += c
-        return packed[:n_tokens].reshape(logits.shape[:-1])
+        return packed[:n_tokens].reshape(step.logits.shape[:-1])
 
     def _count_attn(self, kind, live):
         """One program call whose queries see ``live`` keys each (their
@@ -346,7 +405,12 @@ class ServeLoop:
                 "prefill", self.prefill_fn,
                 np.zeros(self.geo.max_kv, np.int32), np.int32(1),
                 np.zeros(mb, np.int32)))
-        self._fetch(self._call("decode", self.decode_fn, *slots(B)))
+        # The decode step in both forms of its ``tokens``: from the host,
+        # and the step before it handing them over on the device.
+        first = self._call("decode", self.decode_fn, *slots(B))
+        self._fetch(self._call("decode", self.decode_fn, first.feed(),
+                               *slots(B)[1:]))
+        self._fetch(first)
         if self.bprefill_fn is not None:
             toks, _, tables, active = slots(B, self.geo.max_kv)
             self._fetch(self._call("bprefill", self.bprefill_fn, toks,
@@ -364,8 +428,8 @@ class ServeLoop:
     # -- per-request engine calls ----------------------------------------
 
     def _prefill(self, req):
-        """Run the request's full (re-)prefill and return its next
-        token — the counted singleton fallback path."""
+        """Dispatch the request's full (re-)prefill, the counted singleton
+        fallback path; -> the step whose token is its next one."""
         with self._span("serve.prefill.pack"):
             ctx = list(req.prompt) + list(req.generated)
             toks = np.zeros(self.geo.max_kv, np.int32)
@@ -374,16 +438,16 @@ class ServeLoop:
                 self.batcher.block_table(req, self.geo.max_blocks), np.int32)
         with self._span("serve.prefill.dispatch", rid=req.rid,
                         context=len(ctx)):
-            logits = self._call("prefill", self.prefill_fn, toks,
-                                np.int32(len(ctx)), bt)
+            step = self._call("prefill", self.prefill_fn, toks,
+                              np.int32(len(ctx)), bt)
         self.loop_stats["prefill_single"] += 1
-        with self._span("serve.prefill.fetch"):
-            return int(self._fetch(logits))
+        step.owners = {req.slot: (req, req.admit_seq, ())}
+        return step
 
     def _batched_prefill(self, group):
-        """All of `group`'s full prefills in ONE padded call; returns
-        {slot: first token}. Rows beyond the group are inactive (trash
-        writes)."""
+        """All of `group`'s full prefills in ONE padded call; -> the step
+        whose row ``r`` is the first token of the group's request ``r``.
+        Rows beyond the group are inactive (trash writes)."""
         with self._span("serve.bprefill.pack"):
             B, mb, pad = (self.max_batch, self.geo.max_blocks,
                           self.geo.max_kv)
@@ -399,21 +463,20 @@ class ServeLoop:
                 active[row] = True
         with self._span("serve.bprefill.dispatch", batched=len(group),
                         context=int(lengths[:len(group)].sum())):
-            logits = self._call("bprefill", self.bprefill_fn, toks,
-                                lengths, tables, active)
+            step = self._call("bprefill", self.bprefill_fn, toks,
+                              lengths, tables, active)
         self.loop_stats["prefill_batched"] += len(group)
         self.loop_stats["prefill_batch_calls"] += 1
-        with self._span("serve.bprefill.fetch"):
-            out = self._fetch(logits)
-            return {req.slot: int(out[row]) for row, req in enumerate(group)}
+        step.owners = {req.slot: (req, req.admit_seq, row)
+                       for row, req in enumerate(group)}
+        return step
 
     def _chunk_fill(self, req):
         """Advance a request's fill by ONE chunk: the suffix of a
         prefix-cache hit, or (a cache too wide for the padded prefills)
-        any prompt from wherever its cached prefix ends. Returns
-        (done, first_token_or_None); `done` means the whole context is
-        materialized and the final chunk's last real position produced
-        the request's next token."""
+        any prompt from wherever its cached prefix ends. -> None, or, once
+        the whole context is materialized, the step whose final chunk's
+        last real position produced the request's next token."""
         with self._span("serve.chunk.pack"):
             ctx = list(req.prompt) + list(req.generated)
             target = len(ctx)
@@ -429,21 +492,24 @@ class ServeLoop:
             self._count_attn("chunk", np.arange(filled, end) + 1)
         with self._span("serve.chunk.dispatch", rid=req.rid, start=filled,
                         end=end, target=target):
-            logits = self._call("chunk", self.chunk_fn, toks,
-                                np.asarray([filled], np.int32), bt,
-                                np.ones(1, bool))
+            step = self._call("chunk", self.chunk_fn, toks,
+                              np.asarray([filled], np.int32), bt,
+                              np.ones(1, bool), fetch=end >= target)
         self.loop_stats["chunk_fills"] += 1
-        if end >= target:
-            self._fills.pop(req.rid, None)
-            with self._span("serve.chunk.fetch"):
-                out = self._fetch(logits)
-                return True, int(out[0, end - 1 - filled])
-        self._fills[req.rid] = (req.admit_seq, end)
-        return False, None
+        if step is None:
+            self._fills[req.rid] = (req.admit_seq, end)
+            return None
+        self._fills.pop(req.rid, None)
+        step.owners = {req.slot: (req, req.admit_seq, (0, end - 1 - filled))}
+        return step
 
-    def _decode(self, ready):
-        """One jit'd decode step over the fully-prefilled slots; returns
-        {slot: token}."""
+    def _decode(self, ready, after=None):
+        """Dispatch one jit'd decode step over the slots of ``ready``; ->
+        the step, unfetched. ``after`` is the decode step before it while
+        its tokens are still on the device: this one then runs AHEAD of
+        the host, each slot one position past where the host sees it, its
+        input tokens handed over on the device."""
+        ahead = after is not None
         with self._span("serve.decode.pack"):
             B, mb = self.max_batch, self.geo.max_blocks
             tokens = np.zeros(B, np.int32)
@@ -451,10 +517,13 @@ class ServeLoop:
             tables = np.zeros((B, self.geo.table_width), np.int32)
             active = np.zeros(B, bool)
             for slot, req in ready.items():
-                tokens[slot] = req.generated[-1]
-                positions[slot] = req.context_len - 1
+                if not ahead:
+                    tokens[slot] = req.generated[-1]
+                positions[slot] = req.context_len - 1 + ahead
                 tables[slot] = self.batcher.block_table(req, mb)
                 active[slot] = True
+            if ahead:
+                tokens = after.feed()
             self._count_attn("decode", positions[active] + 1)
             # Pages of live context a decode step has to read, against the
             # B x max_blocks the gather path reads whatever is live.
@@ -462,22 +531,25 @@ class ServeLoop:
                 (positions[active] // self.geo.page_size + 1).sum())
             st = self.loop_stats
             st["decode_calls"] += 1
+            st["decode_ahead_calls"] += ahead
             st["decode_paged_calls"] += self.decode_paged
             st["kv_pages_read"] += live_pages
             st["kv_pages_gathered_before"] += B * mb
             if _metrics.enabled():
                 _metrics.SERVE_DECODE_CALLS.inc()
+                _metrics.SERVE_DECODE_AHEAD_CALLS.inc(int(ahead))
                 _metrics.SERVE_DECODE_PAGED_CALLS.inc(int(self.decode_paged))
                 _metrics.SERVE_KV_PAGES_READ.inc(live_pages)
                 _metrics.SERVE_KV_PAGES_GATHERED_BEFORE.inc(B * mb)
                 _metrics.SERVE_KV_READ_SHARE.set(self._kv_read_share())
         with self._span("serve.decode.dispatch",
                         fill=self.batcher.batch_fill()):
-            logits = self._call("decode", self.decode_fn, tokens,
-                                positions, tables, active)
-        with self._span("serve.decode.fetch"):
-            out = self._fetch(logits)
-            return {s: int(out[s]) for s in ready}
+            step = self._call("decode", self.decode_fn, tokens,
+                              positions, tables, active)
+        step.ahead = ahead
+        step.owners = {slot: (req, req.admit_seq, slot)
+                       for slot, req in ready.items()}
+        return step
 
     def _kv_read_share(self):
         """Live pages over the pages the gather path reads, all decode
@@ -512,10 +584,10 @@ class ServeLoop:
                                       + np.arange(k + 1) + 1).ravel())
         with self._span("serve.spec.dispatch", draft_k=k,
                         fill=self.batcher.batch_fill()):
-            logits = self._call("spec", self.spec_fn, tokens, positions,
-                                tables, active)
+            step = self._call("spec", self.spec_fn, tokens, positions,
+                              tables, active)
         with self._span("serve.spec.fetch"):
-            out = self._fetch(logits)                      # [B, k+1]
+            out = self._fetch(step)                        # [B, k+1]
         # Which of the scored tokens the boundary emits is the scheduler's
         # decision, not the engine's: a ``serve.emit`` leaf of its own.
         with self._span("serve.emit"):
@@ -645,6 +717,35 @@ class ServeLoop:
                                        self.batcher.batch_fill(),
                                        self.batcher.kv_occupancy())
 
+        def _land(step, newly_prefilled=False):
+            """Fetch ``step``'s tokens and run the boundary that emits
+            them, to the requests that still hold their slots under the
+            admission the step was dispatched for: one that has meanwhile
+            ended by EOS, been preempted, or handed its slot on gets
+            nothing (a token computed ahead for it is counted)."""
+            with self._span(f"serve.{step.kind}.fetch"):
+                out = self._fetch(step)
+                by_slot, theirs = {}, []
+                for slot, (req, _, at) in step.owners.items():
+                    if step.ran_for(slot, self.batcher.running.get(slot)):
+                        by_slot[slot] = int(out[at])
+                        theirs.append(req)
+                    elif step.ahead:
+                        self.loop_stats["decode_ahead_dropped"] += 1
+                        if _metrics.enabled():
+                            _metrics.SERVE_DECODE_AHEAD_DROPPED.inc()
+            _emit(by_slot, theirs if newly_prefilled else ())
+
+        def _first_token(step):
+            """A prefill or the last chunk of a fill is dispatched: emit
+            what is in flight before it, then its requests' first tokens.
+            The host waits for both, so the decode step after them is
+            planned from tokens on the host."""
+            flight, self._flight = self._flight, None
+            if flight is not None:
+                _land(flight)
+            _land(step, newly_prefilled=True)
+
         def _one_boundary():
             with self._span("serve.admit"):
                 now = _now()
@@ -668,10 +769,9 @@ class ServeLoop:
                                key=lambda r: r.admit_seq)
                 if plain:
                     if self.bprefill_fn is not None and len(plain) > 1:
-                        _emit(self._batched_prefill(plain), plain)
+                        _first_token(self._batched_prefill(plain))
                     else:
-                        req = plain[0]
-                        _emit({req.slot: self._prefill(req)}, [req])
+                        _first_token(self._prefill(plain[0]))
                     continue
                 progressed = False
                 for req in sorted(todo, key=lambda r: r.admit_seq):
@@ -679,26 +779,43 @@ class ServeLoop:
                         continue
                     advanced.add(req.rid)
                     progressed = True
-                    done_fill, tok = self._chunk_fill(req)
-                    if done_fill:
-                        _emit({req.slot: tok}, [req])
+                    step = self._chunk_fill(req)
+                    if step is not None:
+                        _first_token(step)
                         break   # boundary may have changed the todo set
                 if not progressed:
                     break
             ready = {s: r for s, r in self.batcher.running.items()
                      if prefilled.get(r.rid) == r.admit_seq}
-            if ready:
+            flight, self._flight = self._flight, None
+            if flight is not None:
+                # The step before this one is still on the chip. Whoever
+                # it leaves running is known without its tokens (a request
+                # ends by max_new_tokens exactly when the count says so),
+                # and so are their positions and pages: dispatch the next
+                # step behind it, then read its tokens. A request that
+                # ends by EOS there is found out one step late.
+                nxt = {s: r for s, r in ready.items()
+                       if len(r.generated) + 1 < r.max_new_tokens}
+                if nxt and all(flight.ran_for(s, r)
+                               for s, r in ready.items()):
+                    self._flight = self._decode(nxt, after=flight)
+                _land(flight)
+            elif ready:
                 if self.spec_fn is not None:
                     _emit(self._spec_decode(ready))
+                elif self.mode == "continuous":
+                    self._flight = self._decode(ready)
                 else:
-                    _emit(self._decode(ready))
+                    _land(self._decode(ready))
             elif not self.batcher.running and pending:
                 # Idle until the next arrival (open loop: don't spin).
                 with self._span("serve.idle_wait"):
                     time.sleep(min(0.005,
                                    max(0.0, pending[0].arrival_t - _now())))
 
-        while pending or not self.batcher.idle():
+        self._flight = None
+        while pending or not self.batcher.idle() or self._flight is not None:
             self.loop_stats["boundaries"] += 1
             with self._span("serve.boundary"):
                 _one_boundary()
@@ -734,6 +851,9 @@ class ServeLoop:
         }
         snap.update(self.loop_stats, host_s=dict(self.loop_stats["host_s"]))
         snap["kv_read_share"] = self._kv_read_share()
+        snap["decode_ahead_share"] = (
+            self.loop_stats["decode_ahead_calls"]
+            / max(1, self.loop_stats["decode_calls"]))
         if self._select or self._windows:
             snap["attn"] = {
                 **{name: dict(by_kind)

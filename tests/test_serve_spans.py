@@ -127,17 +127,54 @@ def test_every_boundary_is_covered_by_disjoint_leaves(traced):
 
 
 def test_each_fetch_follows_its_dispatch_and_report_follows_emit(traced):
+    """A decode step's tokens are fetched one step late: the fetch of step
+    N follows the dispatch of step N+1 (or of the prefill or last chunk
+    whose first token the host has to wait for anyway, or nothing where
+    every slot ends at N). Every other program's fetch follows its own
+    pack and dispatch, with at most the landing of the decode step in
+    flight between them. Each fetch is followed by one ``serve.emit`` and
+    that by one ``serve.report``."""
+    landing = ["serve.decode.fetch", "serve.emit", "serve.report"]
+    behind = {"serve.admit", "serve.decode.dispatch",
+              "serve.prefill.dispatch", "serve.bprefill.dispatch",
+              "serve.chunk.dispatch"}
+    plain = traced["config"] == "plain"
+    unfetched, ahead = 0, 0           # decode steps, over the whole run
     for _, leaves in _boundaries(traced["events"]):
         names = [e[0] for e in leaves]
         for i, name in enumerate(names):
             call, _, kind = name[len("serve."):].partition(".")
-            if kind == "fetch":
+            if call == "decode" and kind == "dispatch":
+                unfetched += 1
+                assert unfetched <= 2, names      # N on the chip, N+1 queued
+            elif call == "decode" and kind == "fetch":
+                unfetched -= 1
+                assert unfetched >= 0, names
+                assert names[i - 1] in behind, names
+                ahead += names[i - 2:i] == ["serve.decode.pack",
+                                            "serve.decode.dispatch"]
+            elif kind == "fetch":
                 # pack, dispatch, fetch of one engine call, in that order
-                assert names[i - 2:i] == [f"serve.{call}.pack",
-                                          f"serve.{call}.dispatch"], names
-                assert leaves[i][1] >= leaves[i - 1][2]
+                j = i - 1 - names[:i][::-1].index(f"serve.{call}.dispatch")
+                assert names[j - 1] == f"serve.{call}.pack", names
+                assert names[j + 1:i] in ([], landing), names
+                assert leaves[i][1] >= leaves[j][2]
+            if kind == "fetch":
+                assert names[i + 1] == "serve.emit", names
             if name == "serve.report":
                 assert names[i - 1] == "serve.emit", names
+            if name == "serve.emit" and plain:
+                assert names[i + 1] == "serve.report", names
+        assert unfetched <= 1, names    # a boundary leaves one step at most
+        if names[1:3] == ["serve.decode.pack", "serve.decode.dispatch"]:
+            # a boundary of decoding alone: one emit, one report
+            assert names[3:] in (landing, []) or not plain, names
+    assert unfetched == 0
+    stats = traced["stats"]
+    assert stats["decode_ahead_calls"] == ahead
+    assert (ahead > 0) == plain
+    assert stats["decode_ahead_share"] == pytest.approx(
+        ahead / max(1, stats["decode_calls"]))
     # the hook is called once per emit, inside serve.report and nowhere else
     n_report = sum(e[0] == "serve.report" for e in traced["events"])
     assert n_report == len(traced["reports"]) > 0
@@ -179,26 +216,29 @@ def test_host_seconds_by_kind_stay_inside_the_wall_time(traced):
 
 
 def test_fetch_closes_with_the_token_on_the_host(monkeypatch):
-    """``serve.<p>.dispatch`` closes on the enqueue; ``serve.<p>.fetch``
-    opens after it and closes only when the token has been copied to the
-    host. Order of events in the loop's thread, without a profiler."""
+    """``serve.<p>.dispatch`` closes on the enqueue, the greedy pick
+    included (its result stays on the device, where the next decode step
+    takes it); ``serve.<p>.fetch`` opens after it and closes only when the
+    token has been copied to the host. For a decode step that is after the
+    NEXT step's dispatch. Order of events in the loop's thread, without a
+    profiler."""
+    from horovod_tpu.serving import loop as serve_loop
+
     order = []
     real_span, real_greedy = spans.span, engine.greedy
 
-    class Tokens:
-        def __init__(self, arr):
-            self.arr = arr
+    class Numpy:
+        """``numpy`` as the loop sees it: says when a device array has
+        been copied."""
 
-        def _host(self):
-            out = np.asarray(self.arr)      # waits for the device, copies
-            order.append("on_host")
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, x, *a, **k):
+            out = np.asarray(x, *a, **k)    # waits for the device, copies
+            if isinstance(x, jax.Array):
+                order.append("on_host")
             return out
-
-        def __array__(self, *a, **k):
-            return self._host()
-
-        def __int__(self):
-            return int(self._host())
 
     class Recorded:
         def __init__(self, name, cm):
@@ -214,22 +254,36 @@ def test_fetch_closes_with_the_token_on_the_host(monkeypatch):
 
     def greedy(logits):
         order.append("greedy")
-        return Tokens(real_greedy(logits))
+        return real_greedy(logits)
 
     monkeypatch.setattr(spans, "span", lambda name, **kw: Recorded(
         name, real_span(name, **kw)))
     monkeypatch.setattr(engine, "greedy", greedy)
+    monkeypatch.setattr(serve_loop, "np", Numpy())
     loop, cfg, _ = _make_loop()
     order.clear()                                     # drop the warm-up
     loop.run(_requests(cfg))
-    calls = [i for i, x in enumerate(order) if x == "greedy"]
-    assert len(calls) > 10
-    for i in calls:
+    picks = [i for i, x in enumerate(order) if x == "greedy"]
+    copies = [i for i, x in enumerate(order) if x == "on_host"]
+    assert len(picks) == len(copies) > 10   # each pick is fetched, once
+    for i in picks:
+        call = order[i - 1][len("open serve."):-len(".dispatch")]
+        assert order[i - 1:i + 2] == [
+            f"open serve.{call}.dispatch", "greedy",
+            f"close serve.{call}.dispatch"], order[i - 1:i + 2]
+    for i in copies:
         call = order[i - 1][len("open serve."):-len(".fetch")]
-        assert order[i - 2:i + 3] == [
-            f"close serve.{call}.dispatch", f"open serve.{call}.fetch",
-            "greedy", "on_host", f"close serve.{call}.fetch"], order[i - 2:
-                                                                     i + 3]
+        assert order[i - 1:i + 2] == [
+            f"open serve.{call}.fetch", "on_host",
+            f"close serve.{call}.fetch"], order[i - 1:i + 2]
+    # A decode step's tokens reach the host after the next step's enqueue.
+    decode = [x for x in order
+              if x in ("close serve.decode.dispatch",
+                       "close serve.decode.fetch")]
+    pairs = list(zip(decode, decode[1:], decode[2:]))
+    assert ("close serve.decode.dispatch", "close serve.decode.dispatch",
+            "close serve.decode.fetch") in pairs
+    assert ("close serve.decode.dispatch",) * 3 not in pairs   # depth one
 
 
 def test_chrome_sink_gets_the_same_names(traced):

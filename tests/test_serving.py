@@ -40,7 +40,7 @@ import jax.numpy as jnp  # noqa: E402
 from horovod_tpu.models import transformer as tfm  # noqa: E402
 from horovod_tpu.serving import engine, kv_cache  # noqa: E402
 from horovod_tpu.serving.loop import (ServeLoop,  # noqa: E402
-                                      poisson_requests)
+                                      poisson_requests, serve_stats)
 from horovod_tpu.serving.scheduler import Request  # noqa: E402
 
 pytestmark = pytest.mark.serve
@@ -453,6 +453,267 @@ def test_serve_loop_rejects_oversized_prompt():
     sl = ServeLoop(params, cfg, geo=geo, max_batch=1)
     with pytest.raises(ValueError):
         sl.run([Request(rid=0, prompt=list(range(16)), max_new_tokens=4)])
+
+
+# ---------------------------------------------------------------------------
+# one decode step ahead of the host (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+def _sequential_greedy(params, cfg, req):
+    """What the request must get, whatever the loop does: greedy decoding
+    one full forward pass a token, ended by its EOS or its budget."""
+    seq, out = list(req.prompt), []
+    while len(out) < req.max_new_tokens:
+        out.append(int(np.argmax(_ref_logits(params, cfg, seq))))
+        seq.append(out[-1])
+        if out[-1] == req.eos_id:
+            break
+    return out
+
+
+def _count_unfetched(loop):
+    """Wrap the loop's decode dispatch and its fetch; -> a dict whose
+    ``most`` is the largest number of decode steps that were dispatched and
+    not fetched at any time."""
+    seen = {"now": 0, "most": 0, "at_dispatch": 0}
+    dispatch, fetch = loop._decode, loop._fetch
+
+    def counted_decode(*args, **kw):
+        seen["at_dispatch"] = max(seen["at_dispatch"], seen["now"])
+        seen["now"] += 1
+        seen["most"] = max(seen["most"], seen["now"])
+        return dispatch(*args, **kw)
+
+    def counted_fetch(step):
+        seen["now"] -= step.kind == "decode"
+        return fetch(step)
+
+    loop._decode, loop._fetch = counted_decode, counted_fetch
+    return seen
+
+
+def _saturating(cfg, rng, n=10, **kw):
+    return _instant(poisson_requests(
+        n, rate=1e6, rng=rng, prompt_len=(2, 9), max_new=(3, 12),
+        vocab=cfg.vocab_size, **kw))
+
+
+@pytest.mark.parametrize("ending", ["max_tokens", "eos", "preemption",
+                                    "slot_handed_on"])
+def test_decode_ahead_emits_sequential_greedy(ending):
+    """A saturated loop whose decode steps run one ahead of the host gives
+    every request the tokens of sequential greedy decoding, in order:
+    when requests end by their budget; by EOS in mid-run (found out one
+    step late: the step computed ahead is dropped and counted); across
+    preemptions of a starved pool; and when a preempted request's slot goes
+    to ANOTHER request while a step computed for the first is in flight."""
+    cfg = _cfg()
+    params = tfm.init_params(jax.random.PRNGKey(11), cfg)
+    geo = kv_cache.geometry(n_pages=48, page_size=4, max_context=32)
+    if ending == "preemption":
+        geo = dataclasses.replace(geo, n_pages=11)    # 10 usable pages
+    reqs = _saturating(cfg, np.random.default_rng(36))
+    if ending == "eos":
+        # Each request's EOS is a token of its own greedy chain, from the
+        # third on: it ends there, in the middle of the batch's run.
+        for r in reqs:
+            chain = _sequential_greedy(params, cfg, r)
+            r.eos_id = chain[min(2 + r.rid % 3, len(chain) - 1)]
+    want = {r.rid: _sequential_greedy(params, cfg, r) for r in reqs}
+    sl = ServeLoop(params, cfg, geo=geo, max_batch=3, prefix_cache=False)
+    seen = _count_unfetched(sl)
+    handed = []
+    if ending == "slot_handed_on":
+        grow = sl.batcher._grow_pages
+
+        def grow_then_preempt(now):
+            # Once, with a step computed ahead on the chip: its youngest
+            # request is preempted to the BACK of the queue, so that the
+            # admission that follows gives its slot to someone else.
+            grow(now)
+            step = sl._flight
+            if handed or step is None or not step.ahead \
+                    or not sl.batcher.waiting:
+                return
+            victim = max((req for req, _, _ in step.owners.values()
+                          if sl.batcher.running.get(req.slot) is req),
+                         key=lambda r: r.admit_seq, default=None)
+            if victim is not None and victim.generated:
+                handed.append((victim.slot, victim.rid))
+                sl.batcher._preempt(victim, now)
+                sl.batcher.waiting.rotate(-1)
+
+        sl.batcher._grow_pages = grow_then_preempt
+    summary, finished = sl.run(reqs)
+    got = {r.rid: list(r.generated) for r in finished}
+    assert got == want
+    stats = serve_stats()
+    assert stats["decode_ahead_calls"] > 0
+    assert 0 < stats["decode_ahead_share"] < 1
+    assert stats["tokens"] == sum(len(c) for c in want.values())
+    # (c) depth one: while a step is on the chip the next one is queued
+    # behind it, and nothing more before the first is read.
+    assert seen["most"] == 2 and seen["at_dispatch"] == 1 and \
+        seen["now"] == 0
+    by_reason = {r.rid: r.finish_reason for r in finished}
+    if ending == "eos":
+        assert set(by_reason.values()) == {"eos"}
+        assert stats["decode_ahead_dropped"] > 0
+    else:
+        assert set(by_reason.values()) == {"max_tokens"}
+    if ending == "max_tokens":
+        assert stats["decode_ahead_dropped"] == 0
+    if ending == "preemption":
+        assert summary["preemptions"] > 0
+    if ending == "slot_handed_on":
+        (slot, rid), = handed
+        assert summary["preemptions"] == 1
+        assert stats["decode_ahead_dropped"] >= 1
+        # someone else took that slot at the same boundary
+        assert any(r.rid != rid and r.preemptions == 0 for r in finished)
+
+
+@pytest.mark.parametrize("how", ["spec", "static"])
+def test_decode_never_runs_ahead_of_speculation_or_a_static_batch(how):
+    """Speculation needs the tokens on the host to draft from, and the
+    static A/B baseline stays the loop it was: both read every step's
+    tokens before they dispatch the next."""
+    cfg = _cfg()
+    params = tfm.init_params(jax.random.PRNGKey(11), cfg)
+    geo = kv_cache.geometry(n_pages=48, page_size=4, max_context=32)
+    reqs = _saturating(cfg, np.random.default_rng(36), n=6)
+    want = {r.rid: _sequential_greedy(params, cfg, r) for r in reqs}
+    kw = {"spec": {"spec_tokens": 2}, "static": {"mode": "static"}}[how]
+    sl = ServeLoop(params, cfg, geo=geo, max_batch=3, prefix_cache=False,
+                   **kw)
+    seen = _count_unfetched(sl)
+    _, finished = sl.run(reqs)
+    assert {r.rid: list(r.generated) for r in finished} == want
+    stats = serve_stats()
+    assert stats["decode_ahead_calls"] == 0 == stats["decode_ahead_dropped"]
+    assert stats["decode_ahead_share"] == 0.0
+    assert seen["most"] == (0 if how == "spec" else 1)
+
+
+def test_a_hook_that_raises_leaves_the_cache_usable():
+    """The benchmark stops the loop by raising from ``load_reporter``,
+    with a step still in flight, and then calls the loop's programs on
+    ``loop.cache`` itself; a later ``run`` starts with nothing in flight."""
+    cfg = _cfg()
+    params = tfm.init_params(jax.random.PRNGKey(11), cfg)
+    geo = kv_cache.geometry(n_pages=48, page_size=4, max_context=32)
+
+    class Over(Exception):
+        pass
+
+    seen = []
+
+    def hook(*gauges):
+        seen.append(serve_stats()["tokens"])
+        if len(seen) == 9:
+            raise Over
+
+    sl = ServeLoop(params, cfg, geo=geo, max_batch=3, prefix_cache=False,
+                   load_reporter=hook, report_interval=1)
+    sl.warmup()
+    with pytest.raises(Over):
+        sl.run(_saturating(cfg, np.random.default_rng(36)))
+    assert sl._flight is not None          # the step dispatched ahead
+    # tokens are counted at the boundary that emits them
+    assert seen == sorted(seen) and seen[-1] > seen[0] > 0
+    prompt = [5, 9, 2, 7, 1]
+    table = np.zeros(geo.max_blocks, np.int32)
+    table[:2] = [40, 41]
+    toks = np.zeros(geo.max_kv, np.int32)
+    toks[:len(prompt)] = prompt
+    sl.cache, lg = sl.prefill_fn(params, sl.cache, toks,
+                                 np.int32(len(prompt)), table)
+    np.testing.assert_allclose(np.asarray(lg),
+                               _ref_logits(params, cfg, prompt),
+                               rtol=2e-4, atol=2e-4)
+    seq = prompt + [int(np.argmax(np.asarray(lg)))]
+    tokens, positions = np.zeros(3, np.int32), np.zeros(3, np.int32)
+    tables, active = np.zeros((3, geo.max_blocks), np.int32), np.zeros(3, bool)
+    tokens[1], positions[1], tables[1], active[1] = (seq[-1], len(seq) - 1,
+                                                     table, True)
+    sl.cache, lg = sl.decode_fn(params, sl.cache, tokens, positions, tables,
+                                active)
+    np.testing.assert_allclose(np.asarray(lg[1]),
+                               _ref_logits(params, cfg, seq),
+                               rtol=2e-4, atol=2e-4)
+    fresh = ServeLoop(params, cfg, geo=geo, max_batch=3, prefix_cache=False)
+    fresh._flight = sl._flight
+    reqs = _saturating(cfg, np.random.default_rng(37), n=4)
+    want = {r.rid: _sequential_greedy(params, cfg, r) for r in reqs}
+    _, finished = fresh.run(reqs)
+    assert {r.rid: list(r.generated) for r in finished} == want
+
+
+@pytest.mark.parametrize("model", ["dense", "experts"])
+def test_warmup_leaves_the_loop_nothing_to_compile(model, monkeypatch):
+    """Every program in every argument form the loop uses (a decode step's
+    ``tokens`` from the host, and from the step before it on the device)
+    is compiled by ``warmup()``: a run after it compiles nothing, which is
+    what the benchmark's ``no_compile_in_window`` asks. With experts each
+    fetch is still ONE transfer: a step dispatched while another is
+    unfetched owns its tokens and counts."""
+    import jax.monitoring
+
+    from horovod_tpu.serving import loop as serve_loop
+
+    if model == "dense":
+        cfg = _cfg()
+        geo = kv_cache.geometry(n_pages=48, page_size=4, max_context=32)
+        kw = {}
+    else:
+        cfg = tfm.olmoe_1b_7b(
+            vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=16,
+            d_expert=16, max_seq_len=64, n_experts=4, top_k=2,
+            dtype="float32", param_dtype="float32")
+        geo = kv_cache.geometry(n_pages=48, page_size=4, max_context=32)
+        kw = {"prefill_chunk": 8}
+        # every prompt fills by chunks, as where the cache is wide
+        monkeypatch.setattr(serve_loop, "PADDED_PREFILL_MAX_KV", 16)
+    params = tfm.init_params(jax.random.PRNGKey(3), cfg)
+    sl = ServeLoop(params, cfg, geo=geo, max_batch=3, **kw)
+    compiles, transfers = [], []
+    listening = [False]
+
+    def on_event(event, duration, **_):
+        if listening[0] and event.endswith("backend_compile_duration"):
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    fetch, device_get = sl._fetch, jax.device_get
+
+    def counted_fetch(step):
+        transfers.append(0)
+        return fetch(step)
+
+    def counted_get(tree):
+        transfers[-1] += 1
+        return device_get(tree)
+
+    sl._fetch = counted_fetch
+    sl.warmup()
+    warm = len(transfers)
+    reqs = _saturating(cfg, np.random.default_rng(5), n=8)
+    listening[0] = True
+    try:
+        if model == "experts":
+            monkeypatch.setattr(serve_loop.jax, "device_get", counted_get)
+        _, finished = sl.run(reqs)
+    finally:
+        listening[0] = False
+    assert len(finished) == 8
+    assert compiles == []
+    stats = serve_stats()
+    assert stats["decode_ahead_calls"] > 0
+    if model == "experts":
+        assert set(transfers[warm:]) == {1}
+        moe = stats["moe"]
+        assert moe["calls"]["decode"] == stats["decode_calls"]
+        assert moe["calls"]["chunk"] == stats["chunk_fills"] > 8
 
 
 # ---------------------------------------------------------------------------

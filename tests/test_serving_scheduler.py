@@ -563,6 +563,33 @@ def test_grow_reserves_spec_lookahead():
     assert len(bs.running[0].pages) == 4
 
 
+@pytest.mark.parametrize("page_size", [1, 2, 4])
+def test_a_step_ahead_writes_inside_the_reservation(page_size):
+    """What a decode step dispatched one step ahead of the host relies on
+    (serving/loop.py): before ``on_tokens`` has seen step N's token, every
+    request that step N does not end by its budget already owns the page of
+    position ``context_len``, which step N+1 writes; the reservation is
+    ``context_len + 1`` positions as it always was, and nobody owns a page
+    past its last position because of it."""
+    import numpy as np
+    rng = np.random.default_rng(36)
+    _, b = _mk(n_pages=24, page_size=page_size, max_batch=3)
+    for i in range(30):
+        b.submit(_req(i, prompt_len=int(rng.integers(1, 6)),
+                      max_new=int(rng.integers(1, 9))))
+    b.admit()
+    ahead = 0
+    while not b.idle():
+        for req in b.running.values():
+            assert len(req.pages) == req.pages_needed(page_size, 1)
+            if len(req.generated) + 1 < req.max_new_tokens:   # goes on
+                assert req.context_len < len(req.pages) * page_size
+                ahead += 1
+        b.on_tokens({s: int(rng.integers(0, 9)) for s in list(b.running)})
+        _conserved(b)
+    assert ahead > 50 and len(b.done) == 30
+
+
 def test_on_tokens_list_truncates_at_finish():
     _, b = _mk()
     b.submit(_req(0, prompt_len=2, max_new=8, eos=42))
