@@ -96,6 +96,65 @@ class LatentAttention:
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's scaling of rotary frequencies (arXiv:2309.00071, as
+    ``transformers`` computes it): each inverse frequency a blend of the
+    plain one and the one divided by ``factor``, by a linear ramp between
+    the dims that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_max`` positions; cos and sin times ``attention_factor``
+    (None = ``0.1 ln(factor) + 1``)."""
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class MultiHeadAttention:
+    """One kind of multi-head attention that a configuration describes by
+    name (``TransformerConfig.multihead``), as :class:`LatentAttention`
+    describes a latent kind: ``n_heads`` query heads over ``n_kv_heads``
+    key/value heads of ``head_dim`` (query head ``j`` reads key/value head
+    ``j // (n_heads / n_kv_heads)``; ``head_dim`` is the kind's own, not
+    ``d_model / n_heads``). ``window`` > 0: a query sees the last ``window``
+    positions, itself included. Rotary rule of the kind: ``rope_theta``,
+    the first ``rope_share`` of each head rotated (rotate-half within it),
+    ``yarn`` (a :class:`Yarn` or its fields) or plain. ``gate``: a sigmoid
+    gate a query head on the attention's output, from a ``d_model ->
+    n_heads`` projection of the layer's normed input."""
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    window: int = 0
+    rope_theta: float = 10000.0
+    rope_share: float = 1.0
+    yarn: Optional[Yarn] = None
+    gate: bool = False
+
+    def __post_init__(self):
+        if isinstance(self.yarn, dict):
+            object.__setattr__(self, "yarn", Yarn(**self.yarn))
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{self.n_heads} query heads do not divide "
+                             f"over {self.n_kv_heads} key/value heads")
+
+    @property
+    def group(self):
+        """Query heads that read one key/value head."""
+        return self.n_heads // self.n_kv_heads
+
+    @property
+    def kv_width(self):
+        """Lanes of a cached K or V row: the key/value heads, fused."""
+        return self.n_kv_heads * self.head_dim
+
+    @property
+    def rope_dim(self):
+        return int(self.head_dim * self.rope_share)
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32768
     d_model: int = 1024
@@ -132,6 +191,10 @@ class TransformerConfig:
     # ignored (a published pattern longer than the layers that are run).
     layer_attn: tuple = ()
     latent: tuple = ()
+    # name -> MultiHeadAttention or its fields: a layer so named is multi-head
+    # attention of THAT kind (its own head counts, ``head_dim``, window,
+    # rotary rule and gate) and not of ``n_heads``.
+    multihead: tuple = ()
     # The normed query and key/value latents times sqrt(d_model / rank).
     latent_rescale: bool = False
     # A sigmoid gate a head on the attention's output, from a d_model ->
@@ -182,6 +245,11 @@ class TransformerConfig:
         object.__setattr__(self, "latent", tuple(
             (name, a if isinstance(a, LatentAttention)
              else LatentAttention(**a)) for name, a in latent))
+        multihead = self.multihead.items() \
+            if isinstance(self.multihead, dict) else self.multihead
+        object.__setattr__(self, "multihead", tuple(
+            (name, a if isinstance(a, MultiHeadAttention)
+             else MultiHeadAttention(**a)) for name, a in multihead))
         object.__setattr__(self, "layer_attn", tuple(self.layer_attn))
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
         if self.experts_held:
@@ -203,6 +271,8 @@ class TransformerConfig:
 
     @property
     def head_dim(self):
+        """Of the multi-head attention of ``n_heads``; a kind that a layer
+        names (``multihead``) states its own."""
         return self.d_model // self.n_heads
 
     @property
@@ -216,10 +286,17 @@ class TransformerConfig:
         return jnp.dtype(self.dtype)
 
     def attn_of(self, li):
-        """Layer ``li``'s :class:`LatentAttention`, or None for the
-        multi-head attention of ``n_heads``."""
+        """Layer ``li``'s :class:`LatentAttention` or
+        :class:`MultiHeadAttention`, or None for the multi-head attention of
+        ``n_heads``."""
         name = self.layer_attn[li] if li < len(self.layer_attn) else None
-        return dict(self.latent).get(name)
+        return dict(self.latent + self.multihead).get(name)
+
+    @property
+    def described(self):
+        """Whether layers are described by kind (``latent``, ``multihead``):
+        such a model is filled by chunks and its layers' caches differ."""
+        return bool(self.latent or self.multihead)
 
     def is_moe(self, li):
         return self.n_experts > 0 and li >= self.dense_layers
@@ -332,6 +409,21 @@ def _latent_params(key, cfg, a: LatentAttention):
     return p
 
 
+def _multihead_params(key, cfg, a: MultiHeadAttention):
+    """A described multi-head layer's matrices: the query projection of
+    ``n_heads``, the fused key and value projections of ``n_kv_heads``, the
+    output projection, the head gate."""
+    D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
+    k = jax.random.split(key, 4)
+    p = {"wq": _dense_init(k[0], (D, a.n_heads, a.head_dim), D, pdt),
+         "wkv": _dense_init(k[1], (D, 2, a.n_kv_heads, a.head_dim), D, pdt),
+         "wo": _dense_init(k[2], (a.n_heads, a.head_dim, D),
+                           a.n_heads * a.head_dim, pdt)}
+    if a.gate:
+        p["w_attn_gate"] = _dense_init(k[3], (D, a.n_heads), D, pdt)
+    return p
+
+
 def init_params(key, cfg: TransformerConfig):
     """The parameter pytree, every array made from ``key`` directly in
     ``cfg.param_dtype``. Called outside ``jit`` each array is one small
@@ -363,6 +455,8 @@ def init_params(key, cfg: TransformerConfig):
             if cfg.qk_norm:
                 layer["q_norm"] = {"scale": jnp.ones((H, dh), pdt)}
                 layer["k_norm"] = {"scale": jnp.ones((H, dh), pdt)}
+        elif isinstance(a, MultiHeadAttention):
+            layer.update(_multihead_params(k[0], cfg, a))
         else:
             layer.update(_latent_params(k[0], cfg, a))
         if cfg.is_moe(i):
@@ -410,11 +504,13 @@ def param_specs(cfg: TransformerConfig):
                 layer["q_norm"] = {"scale": P(m, None)}
                 layer["k_norm"] = {"scale": P(m, None)}
         else:
-            # Latent attention is held whole on every device (data-parallel
-            # attention): no serving program shards it yet.
+            # Attention described by kind is held whole on every device
+            # (data-parallel attention): no serving program shards it yet.
+            make = _multihead_params if isinstance(a, MultiHeadAttention) \
+                else _latent_params
             layer.update(jax.tree.map(
                 lambda _: P(), jax.eval_shape(
-                    lambda: _latent_params(jax.random.PRNGKey(0), cfg, a))))
+                    lambda: make(jax.random.PRNGKey(0), cfg, a))))
         if cfg.is_moe(i):
             layer["router"] = P()
             if cfg.router == "sigmoid":
@@ -513,6 +609,100 @@ def _qkv(h, layer, cfg, positions=None):
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+def rope_inv_freq(a: MultiHeadAttention):
+    """The inverse frequencies ``[rope_dim / 2]`` (float64 numpy) of a
+    described kind's rotation and the factor on its cos and sin: plain
+    ``theta^(-2i / rope_dim)``, or YaRN's blend (:class:`Yarn`)."""
+    import numpy as np
+
+    dim = a.rope_dim
+    plain = a.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    y = a.yarn
+    if y is None:
+        return plain, 1.0
+
+    def turns_dim(turns):     # the dim that turns ``turns`` times in all
+        return dim * math.log(y.original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(a.rope_theta))
+
+    low = max(math.floor(turns_dim(y.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(y.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / ((high if high > low else low + 0.001) - low), 0, 1)
+    factor = y.attention_factor if y.attention_factor is not None \
+        else 0.1 * math.log(y.factor) + 1.0
+    return plain / y.factor * ramp + plain * (1 - ramp), float(factor)
+
+
+def _rope_kind(x, positions, a: MultiHeadAttention):
+    """A described kind's rotation of ``x [B, S, H, head_dim]`` at
+    ``positions [B, S]``: the first ``rope_dim`` dims of each head, first
+    and second half of them paired; float32."""
+    inv_freq, factor = rope_inv_freq(a)
+    half = a.rope_dim // 2
+    ang = positions.astype(jnp.float32)[..., None, None] \
+        * jnp.asarray(inv_freq, jnp.float32)
+    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:a.rope_dim]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                            xf[..., a.rope_dim:]], -1).astype(x.dtype)
+
+
+def _qkv_kind(h, layer, cfg, a: MultiHeadAttention, positions=None):
+    """A described multi-head layer's ``q [B, S, n_heads, dh]`` and ``k, v
+    [B, S, n_kv_heads, dh]`` from the normed input, rotated by the kind's
+    rule to ``positions [B, S]`` (None = 0..S-1): what the attention and the
+    cache take."""
+    dt = cfg.compute_dtype
+    q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
+    kv = jnp.einsum("bsd,dchk->cbshk", h, layer["wkv"].astype(dt))
+    if positions is None:
+        positions = jnp.arange(h.shape[1])[None]
+    return (_rope_kind(q, positions, a), _rope_kind(kv[0], positions, a),
+            kv[1])
+
+
+def grouped_attend(q, k, v, a: MultiHeadAttention, allowed, dt):
+    """Grouped-query attention with materialised scores: ``q [B, S, Hq,
+    dh]`` against ``k, v [B, T, Hkv, dh]`` under ``allowed [B, S, T]`` ->
+    ``[B, S, Hq, dh]`` (zeros for a query that is allowed nothing). The
+    plain tier: the trainer's forward pass, a CPU, a mesh, and what the
+    paged kernels are tested against."""
+    B, S, _, dh = q.shape
+    qg = q.reshape(B, S, a.n_kv_heads, a.group, dh)
+    logits = jnp.einsum("bsgjk,btgk->bgjst", qg, k,
+                        preferred_element_type=jnp.float32) \
+        / math.sqrt(dh)
+    ok = allowed[:, None, None]
+    probs = jax.nn.softmax(jnp.where(ok, logits, -1e30), -1)
+    probs = jnp.where(ok, probs, 0.0).astype(dt)
+    return jnp.einsum("bgjst,btgk->bsgjk", probs, v).reshape(q.shape)
+
+
+def attend_allowed(a, q_pos, k_pos, live=None):
+    """Which keys a described layer's queries may see (before any
+    selection): ``q_pos [B, S]``, ``k_pos [B, T]`` -> ``[B, S, T]``: not
+    later than the query, inside its window, and ``live [B, T]``."""
+    dist = q_pos[:, :, None] - k_pos[:, None, :]
+    ok = dist >= 0
+    if a.window:
+        ok &= dist < a.window
+    if live is not None:
+        ok &= live[:, None, :]
+    return ok
+
+
+def _attend_kind(a, dt):
+    """``attend(q, k, v)`` of a described multi-head layer over its own
+    window (no cache): the forward pass of the trainer and of the tests."""
+    def attend(q, k, v):
+        pos = jnp.broadcast_to(jnp.arange(k.shape[1])[None], k.shape[:2])
+        return grouped_attend(q, k, v, a, attend_allowed(a, pos, pos), dt)
+
+    return attend
 
 
 def _rope_head(x, positions, theta, width):
@@ -618,26 +808,13 @@ def latent_attend(q, rows, a: LatentAttention, allowed, dt):
     return jnp.einsum("bhst,btr->bshr", probs, rows[..., :a.kv_rank])
 
 
-def latent_allowed(a: LatentAttention, q_pos, k_pos, live=None):
-    """Which keys a latent layer's queries may see before any selection:
-    ``q_pos [B, S]``, ``k_pos [B, T]`` -> ``[B, S, T]``: not later than the
-    query, inside its window, and ``live [B, T]``."""
-    dist = q_pos[:, :, None] - k_pos[:, None, :]
-    ok = dist >= 0
-    if a.window:
-        ok &= dist < a.window
-    if live is not None:
-        ok &= live[:, None, :]
-    return ok
-
-
 def _attend_latent(a, dt):
     """``attend(q, row, index)`` of a latent layer over its own window (no
     cache): the forward pass of the trainer and of the tests. -> (output,
     the selection or None)."""
     def attend(q, row, index):
         pos = jnp.broadcast_to(jnp.arange(row.shape[1])[None], row.shape[:2])
-        allowed = latent_allowed(a, pos, pos)
+        allowed = attend_allowed(a, pos, pos)
         selected = None
         if index is not None:
             selected = select_keys(
@@ -936,6 +1113,15 @@ def _constrain(v, spec):
         if spec is not None else v
 
 
+def _head_gate(h, layer, dt):
+    """The head gate ``[B, S, H, 1]``: a sigmoid (float32) of a ``d_model ->
+    heads`` projection of the layer's normed input ``h``."""
+    gate = jax.nn.sigmoid(jnp.einsum(
+        "bsd,dh->bsh", h, layer["w_attn_gate"].astype(dt)
+    ).astype(jnp.float32))
+    return gate[..., None].astype(dt)
+
+
 def block(layer, x, cfg: TransformerConfig, attend, positions=None,
           mesh=None, out_spec=None, valid=None, li=0):
     """THE transformer block, written once: ``x + Wo attend(q, k, v)`` of the
@@ -954,7 +1140,10 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
     ``attend(q [B, S, H, W], row [B, S, W], index) -> (o [B, S, H,
     kv_rank], selected keys or None)`` (:func:`_latent_qkv`), takes the
     result through the value up-projection and the head gate, and returns
-    the selection in its routing (``{"selected": ..}``)."""
+    the selection in its routing (``{"selected": ..}``). A multi-head layer
+    of a described kind (:class:`MultiHeadAttention`) hands it ``attend(q
+    [B, S, Hq, dh], k, v [B, S, Hkv, dh]) -> [B, S, Hq, dh]``
+    (:func:`_qkv_kind`) and gates the result a head where the kind says."""
     dt = cfg.compute_dtype
     a = cfg.attn_of(li)
     selected = None
@@ -964,15 +1153,17 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
             q, k, v = _qkv(h, layer, cfg, positions)
             out = jnp.einsum("bshk,hkd->bsd", attend(q, k, v),
                              layer["wo"].astype(dt))
+        elif isinstance(a, MultiHeadAttention):
+            o = attend(*_qkv_kind(h, layer, cfg, a, positions))
+            if a.gate:
+                o = o * _head_gate(h, layer, dt)
+            out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
         else:
             o, selected = attend(*_latent_qkv(h, layer, cfg, a, positions))
             o = jnp.einsum("bshr,rhd->bshd", o,
                            layer["wkv_b"][..., a.nope_dim:].astype(dt))
             if cfg.attn_gate:
-                gate = jax.nn.sigmoid(jnp.einsum(
-                    "bsd,dh->bsh", h, layer["w_attn_gate"].astype(dt)
-                ).astype(jnp.float32))
-                o = o * gate[..., None].astype(dt)
+                o = o * _head_gate(h, layer, dt)
             out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
         x = x + _constrain(out, out_spec)
     h = _norm(x, layer["ln2"], cfg)
@@ -1000,10 +1191,11 @@ def _block_fn(cfg, mesh, impl, seq_spec, full_spec):
 
     def fn(layer, x, li=0):
         a = cfg.attn_of(li)
-        x, routing = block(
-            layer, x, cfg,
-            attend if a is None else _attend_latent(a, cfg.compute_dtype),
-            mesh=mesh, out_spec=seq_spec, li=li)
+        mine = attend if a is None else (
+            _attend_kind if isinstance(a, MultiHeadAttention)
+            else _attend_latent)(a, cfg.compute_dtype)
+        x, routing = block(layer, x, cfg, mine, mesh=mesh,
+                           out_spec=seq_spec, li=li)
         return _constrain(x, seq_spec), routing
 
     return fn

@@ -622,6 +622,19 @@ SERVE_KV_WINDOW = counter(
     "hvd_serve_kv_window",
     "(query, key) pairs the window latent layers attended over: min(live "
     "keys, window) a query, summed over those layers", ("program",))
+SERVE_KV_FULL_ROWS = counter(
+    "hvd_serve_kv_full_rows",
+    "K/V rows the full multi-head layers of a described kind read: a "
+    "slot's live rows once a call, summed over those layers", ("program",))
+SERVE_KV_WINDOW_ROWS = counter(
+    "hvd_serve_kv_window_rows",
+    "K/V rows the window multi-head layers read from their rings: "
+    "min(live rows, window - 1 + the call's queries) a slot, summed over "
+    "those layers", ("program",))
+SERVE_KV_WINDOW_ROWS_AS_FULL = counter(
+    "hvd_serve_kv_window_rows_as_full",
+    "K/V rows those window layers would read if they were sized and read "
+    "like full ones (every live row)", ("program",))
 SERVE_KV_SELECT_SHARE = gauge(
     "hvd_serve_kv_select_share",
     "hvd_serve_kv_selected over hvd_serve_kv_scored, all programs so far: "

@@ -1,4 +1,9 @@
-"""Decode attention over the paged KV cache, read in place (Pallas TPU).
+"""Attention over the paged KV cache, read in place (Pallas TPU): the decode
+step's kernel for the multi-head attention of ``n_heads``
+(:func:`paged_decode_attention`, the rest of this text), and the kernel of
+the multi-head kinds a configuration describes (:func:`paged_grouped_attention`
+below: fewer key/value heads than query heads, a window, a ring, a query
+block longer than one).
 
 One query token a slot against that slot's cached context. The cache is
 taken as :mod:`horovod_tpu.serving.kv_cache` holds it — one layer's K and
@@ -207,3 +212,213 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     )(lengths.astype(jnp.int32), block_tables.reshape(-1).astype(jnp.int32),
       q.reshape(B, 1, hd), k_pages, v_pages)
     return out.reshape(B, H, dh)
+
+
+# ---- grouped queries, windows, rings, query blocks -------------------------
+
+# The instruction names of the described kinds' layers (full and window), in
+# the decode step and in the chunk fill alike
+# (benchmark/layer_metrics/*_attn_dev_ms.agent.json match them).
+FULL_NAME = "paged_full_attention"
+WINDOW_NAME = "paged_window_attention"
+
+# Queries a grid step takes (a power of two: a row's query is ``row %
+# q_block``), and the key tokens a block aims for, (full, window) layers, for
+# one query a slot and for a block of them. Read on a v5e at 8 K/V heads of
+# 128 (PERF.md, PR 37): 32 slots of 7.8k live rows take 3.05 / 1.94 / 1.66 ms
+# at blocks of 128 / 512 / 1024 tokens against 1.25 ms of bytes (a block's
+# fixed cost, not its products, is what a short block pays); a window of 512
+# is 4 blocks of 128; a 512-query chunk at 8k 2.05 / 1.48 / 1.41 ms at 128 /
+# 256 / 512.
+_Q_BLOCK = 128
+_DECODE_BLOCK_TOKENS = (1024, 128)
+_CHUNK_BLOCK_TOKENS = (512, 256)
+_VMEM_LIMIT = 96 * 1024 * 1024
+
+
+def grouped_supported(page_size, head_dim, dtype):
+    """Whether :func:`paged_grouped_attention` tiles on a TPU: a page is
+    whole sublane tiles of ``dtype`` and a head whole lane tiles (a key/value
+    head is sliced out of the fused row)."""
+    sublanes = 8 * (4 // jnp.dtype(dtype).itemsize)
+    return page_size % sublanes == 0 and head_dim % 128 == 0
+
+
+def _grouped_kernel(pos0_ref, len_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
+                    k_buf, v_buf, sems, m_s, l_s, acc_s, *, page, ppb, width,
+                    ring, n_kv, head_dim, qb, window, sm_scale):
+    b, qi = pl.program_id(0), pl.program_id(1)
+    kv_len = len_ref[b]
+    q_first = pos0_ref[b] + qi * qb
+    bt = ppb * page
+    # Keys this query block can see: positions lo .. hi - 1.
+    hi = jnp.minimum(q_first + qb, kv_len)
+    lo = jnp.maximum(q_first - (window - 1), 0) if window else 0
+    first = lo // bt
+    n_blocks = jnp.maximum((hi + bt - 1) // bt - first, 0)
+    last_page = jnp.maximum(hi - 1, 0) // page
+    rows = acc_s.shape[1]
+
+    def copies(i, slot):
+        out = []
+        for j in range(ppb):
+            # Past the live pages: the last live one again. A position's
+            # page is a column of the table; a ring's columns come round.
+            p = jnp.minimum(i * ppb + j, last_page)
+            col = p % width if ring else jnp.minimum(p, width - 1)
+            pid = tables_ref[b * width + col]
+            at = pl.ds(j * page, page)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[pid], k_buf.at[slot, at], sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[pid], v_buf.at[slot, at], sems.at[1, slot]))
+        return out
+
+    m_s[...] = jnp.full_like(m_s, _NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+    # Row r of a key/value head's tile is query r % qb of the block, of one
+    # of the head's group (rows past group * qb are padding).
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    q_pos = q_first + row % qb
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for c in copies(first, 0):
+            c.start()
+
+    def block(n, _):
+        i = first + n
+        slot = n % 2
+
+        @pl.when(n + 1 < n_blocks)
+        def _next():
+            for c in copies(i + 1, 1 - slot):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        k = k_buf[slot]                                     # [bt, n_kv * dh]
+        v = v_buf[slot]
+        k_pos = i * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        ok = (k_pos <= q_pos) & (k_pos < kv_len)            # [rows, bt]
+        if window:
+            ok &= k_pos > q_pos - window
+        for g in range(n_kv):
+            lanes = slice(g * head_dim, (g + 1) * head_dim)
+            s = jax.lax.dot_general(
+                q_ref[0, 0, g], k[:, lanes], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(ok, s, _NEG_INF)
+            m_prev = m_s[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            # A row may see nothing of this block (another row of the block
+            # does): its exp(_NEG_INF - _NEG_INF) is masked, not trusted.
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            m_s[g] = m_new
+            l_s[g] = l_s[g] * alpha + jnp.sum(p, -1, keepdims=True)
+            acc_s[g] = acc_s[g] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v[:, lanes], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    jax.lax.fori_loop(0, n_blocks, block, None)
+
+    for g in range(n_kv):
+        l = l_s[g]
+        o_ref[0, 0, g] = (acc_s[g] * (1.0 / jnp.where(l > 0, l, 1.0))
+                          ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_kv_heads", "window", "ring", "q_block", "pages_per_block",
+    "interpret"))
+def paged_grouped_attention(q, k_pages, v_pages, tables, pos0, kv_len, *,
+                            n_kv_heads, window=0, ring=False, q_block=None,
+                            pages_per_block=None, interpret=False):
+    """``q [B, Q, Hq, dh]``, a slot's ``Q`` queries at the consecutive
+    positions ``pos0 [B] ..``, against one layer's cache ``k_pages, v_pages
+    [n_pages, page, Hkv * dh]`` -> ``[B, Q, Hq, dh]`` in ``q``'s dtype.
+
+    Query head ``j`` reads key/value head ``j // (Hq / Hkv)``. A query at
+    ``p`` sees the keys at positions ``<= p`` and ``< kv_len [B]`` (the
+    slot's live positions, this call's own included; 0 = an inactive slot,
+    zeros out), and with ``window`` those ``> p - window``. ``tables [B,
+    W]`` are the page ids of the layer's pages: position ``t`` lies in page
+    ``tables[b, t // page]``, or with ``ring`` in ``tables[b, (t // page) %
+    W]`` (a window layer's ring, ``kv_cache``). Only the pages that hold keys
+    some query of a block sees are read: from the window's first live page,
+    to the block's last query.
+
+    Structure: grid over slots and blocks of ``q_block`` queries; inside, the
+    block table is walked ``pages_per_block`` pages at a time through two
+    VMEM buffers (as :func:`paged_decode_attention` does), and each block of
+    keys is multiplied head by head: a key/value head's 128 lanes of the
+    fused rows against the ``group * q_block`` query rows that read it,
+    online softmax in float32 scratch. Scores exist a ``[group * q_block,
+    block]`` tile at a time."""
+    B, Q, Hq, dh = q.shape
+    n_pages, page, hd = k_pages.shape
+    n_kv = int(n_kv_heads)
+    if hd != n_kv * dh or v_pages.shape != k_pages.shape or Hq % n_kv:
+        raise ValueError(f"cache {k_pages.shape} / {v_pages.shape} does not "
+                         f"hold {n_kv} heads of {dh} under {Hq} query heads")
+    group = Hq // n_kv
+    qb = int(q_block or _Q_BLOCK)
+    if qb & (qb - 1):
+        raise ValueError(f"q_block {qb} is not a power of two")
+    qb = math.gcd(Q, qb)      # the largest power of two that divides both
+    nq = Q // qb
+    width = tables.shape[1]
+    ppb = pages_per_block
+    if ppb is None:
+        tokens = _DECODE_BLOCK_TOKENS if Q == 1 else _CHUNK_BLOCK_TOKENS
+        ppb = max(1, tokens[bool(window)] // page)
+    ppb = min(int(ppb), width)
+    bt = ppb * page
+    rows = -(-group * qb // 16) * 16      # whole bf16 sublane tiles
+    # [B, Q, Hq, dh] -> a tile of rows (group, query) a key/value head.
+    qt = q.reshape(B, nq, qb, n_kv, group, dh).transpose(0, 1, 3, 4, 2, 5)
+    qt = qt.reshape(B, nq, n_kv, group * qb, dh)
+    qt = jnp.pad(qt, ((0, 0),) * 3 + ((0, rows - group * qb), (0, 0)))
+    kernel = functools.partial(
+        _grouped_kernel, page=page, ppb=ppb, width=width, ring=bool(ring),
+        n_kv=n_kv, head_dim=dh, qb=qb, window=int(window),
+        sm_scale=1.0 / math.sqrt(dh))
+    itemsize = k_pages.dtype.itemsize
+    live = B * nq * (min(width * page, window + qb) if window
+                     else width * page)          # an upper bound
+    tile = pl.BlockSpec((1, 1, n_kv, rows, dh),
+                        lambda b, qi, *_: (b, qi, 0, 0, 0),
+                        memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, nq),
+            in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=tile,
+            scratch_shapes=[
+                pltpu.VMEM((2, bt, hd), k_pages.dtype),
+                pltpu.VMEM((2, bt, hd), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rows, dh), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, nq, n_kv, rows, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * n_kv * rows * dh * live,
+            transcendentals=n_kv * rows * live,
+            bytes_accessed=2 * live * hd * itemsize),
+        name=WINDOW_NAME if window else FULL_NAME,
+        interpret=interpret,
+    )(pos0.astype(jnp.int32), kv_len.astype(jnp.int32),
+      tables.reshape(-1).astype(jnp.int32), qt, k_pages, v_pages)
+    out = out[:, :, :, :group * qb].reshape(B, nq, n_kv, group, qb, dh)
+    return out.transpose(0, 1, 4, 2, 3, 5).reshape(B, Q, Hq, dh)

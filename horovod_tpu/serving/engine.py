@@ -33,16 +33,18 @@ slot costs nothing. Elsewhere (a CPU backend, a mesh: a ``shard_map`` over
 the cache's head shards is not written; ``attn_impl="gather"``) it gathers
 every slot's ``max_kv`` tokens through the block tables and runs
 ``transformer.causal_attend`` under a ``kv_pos <= position`` mask: the same
-mathematics, at the cost of ``max_kv`` whatever is live. The multi-token
-programs (prefill, batched prefill, chunk, spec) always gather: a paged
-kernel for ``q_len > 1`` is not written. ``transformer.resolve_attn`` is
+mathematics, at the cost of ``max_kv`` whatever is live. For these plain
+multi-head layers the multi-token programs (prefill, batched prefill, chunk,
+spec) always gather; the kernel with a query block is the described kinds'
+(below). ``transformer.resolve_attn`` is
 still consulted with the REAL (q_len, kv_len, causal) shape — q_len=1
 against ``max_kv`` cached tokens resolves to "gather" there (nothing for
 flash's q-tiling to eliminate), and an ``attn_impl`` that forces another
 tier is refused.
 
 The cache (:mod:`.kv_cache`) is one ``[n_pages, page, H*dh]`` array per
-layer for K and for V, the layout these programs compute in. Every
+layer for K and for V (``Hkv*dh`` lanes, and ring pages for a window layer,
+where a layer's kind says so), the layout these programs compute in. Every
 program takes it donated, writes layer ``li`` with ``ck[li].at[page_ids,
 slot].set(k.reshape(..., H*dh))`` (prefill: whole pages at
 ``block_table``) and reads it either through the kernel or with
@@ -72,8 +74,21 @@ Those four names are Pallas kernels (:mod:`horovod_tpu.ops.pallas_latent`)
 and the instruction names a device trace shows; they run on a TPU backend
 with no mesh (:func:`latent_kernels`). Elsewhere the same mathematics runs as
 plain ``jax.numpy`` (``transformer.index_scores``, ``select_keys``,
-``latent_attend``). A latent model is filled by chunks only: the padded
-prefills are not built for it.
+``latent_attend``).
+
+A MULTI-HEAD layer of a described kind (``TransformerConfig.multihead``:
+query heads over fewer key/value heads, its own ``head_dim``, a window, a
+rotary rule, a gate; ``transformer._qkv_kind`` makes its operands) also runs
+one way in both programs (:func:`_grouped_layer`): the window's K and V are
+scattered into the layer's pages, or into the slot's ring for a window layer,
+and the queries attend through
+:func:`~horovod_tpu.ops.pallas_paged_attention.paged_grouped_attention`, ONE
+kernel for one query a slot and for a block of them, over the live pages
+only, named ``paged_full_attention`` or ``paged_window_attention`` by the
+layer's kind (:func:`grouped_kernels`: a TPU backend with no mesh). Elsewhere
+the slot's pages (or ring) are gathered and attended with materialised scores
+(``transformer.grouped_attend``). A model whose layers are described by kind
+is filled by chunks only: the padded prefills are not built for it.
 
 The batch-slot ↔ request mapping, page ownership, and admission policy
 live host-side in :mod:`.scheduler`; this module never allocates.
@@ -113,7 +128,7 @@ def decode_attn(cfg, geo, mesh):
             f"resolved to {impl!r}; use attn_impl='auto' or 'gather'")
     if (cfg.attn_impl == "auto" and mesh is None
             and jax.default_backend() == "tpu"
-            and not cfg.latent
+            and not cfg.described
             and paged_attention.supported(
                 geo.page_size, cfg.n_heads * cfg.head_dim,
                 cfg.compute_dtype)):
@@ -128,6 +143,18 @@ def latent_kernels(cfg, geo, mesh):
     return (bool(cfg.latent) and mesh is None
             and cfg.attn_impl == "auto" and jax.default_backend() == "tpu"
             and all(pallas_latent.supported(a, geo) for _, a in cfg.latent))
+
+
+def grouped_kernels(cfg, geo, mesh):
+    """Whether the multi-head layers of a described kind read the cache
+    through :func:`paged_attention.paged_grouped_attention`, in the chunk
+    and the decode program alike: a TPU backend, no mesh, ``attn_impl`` left
+    open, and shapes the kernel tiles. Else they gather their pages."""
+    return (bool(cfg.multihead) and mesh is None
+            and cfg.attn_impl == "auto" and jax.default_backend() == "tpu"
+            and all(paged_attention.grouped_supported(
+                geo.page_size, a.head_dim, cfg.compute_dtype)
+                for _, a in cfg.multihead))
 
 
 def _check_positions(cfg, n, what):
@@ -178,6 +205,29 @@ def _cache_out(ck, cv, mesh, cfg):
     return {"k": tuple(map(out, ck)), "v": tuple(map(out, cv))}
 
 
+def _window_cells(a, q_pos, ok, tables, geo):
+    """Where a described layer's window goes: -> (the layer's columns of
+    ``tables [B, max_blocks + ring_blocks]``: the ring's for a window layer,
+    else the context's; the page id and the slot in it of each position of
+    ``q_pos [B, Q]``, trash page 0 where not ``ok``)."""
+    page = geo.page_size
+    if a.window:
+        table = tables[:, geo.max_blocks:]
+        blk = (q_pos // page) % geo.ring_blocks
+    else:
+        table = tables[:, :geo.max_blocks]
+        blk = jnp.minimum(q_pos // page, geo.max_blocks - 1)
+    page_ids = jnp.where(ok, jnp.take_along_axis(table, blk, axis=1), 0)
+    return table, page_ids, jnp.where(ok, q_pos % page, 0)
+
+
+def _ring_positions(p_hi, n_cells):
+    """The position each cell of a ring holds once ``p_hi [B]`` is the
+    highest written: the latest of its residue, ``p_hi`` and the ``n_cells -
+    1`` before (negative: nothing yet) -> ``[B, n_cells]``."""
+    return p_hi[:, None] - (p_hi[:, None] - jnp.arange(n_cells)) % n_cells
+
+
 def _latent_layer(a, q, row, index, rows_c, keys_c, *, q_pos, ok, tables,
                   geo, dt, kernels):
     """One latent layer of a chunk or decode program: write the window's
@@ -187,27 +237,17 @@ def _latent_layer(a, q, row, index, rows_c, keys_c, *, q_pos, ok, tables,
     max_blocks + ring_blocks]``."""
     page = geo.page_size
     B, Q = q_pos.shape
-    if a.window:
-        table = tables[:, geo.max_blocks:]
-        blk = (q_pos // page) % geo.ring_blocks
-    else:
-        table = tables[:, :geo.max_blocks]
-        blk = jnp.minimum(q_pos // page, geo.max_blocks - 1)
-    page_ids = jnp.where(ok, jnp.take_along_axis(table, blk, axis=1), 0)
-    slot = jnp.where(ok, q_pos % page, 0)
+    table, page_ids, slot = _window_cells(a, q_pos, ok, tables, geo)
     rows_c = rows_c.at[page_ids, slot].set(row)
     if a.window:
-        # The ring's cells hold the latest position of each residue that has
-        # been written: the highest this call writes, and the R - 1 before.
         n_cells = geo.ring_tokens
         ring = rows_c[table].reshape(B, n_cells, -1)
-        p_hi = jnp.max(jnp.where(ok, q_pos, -1), axis=1)          # [B]
-        k_pos = p_hi[:, None] - (p_hi[:, None] - jnp.arange(n_cells)) \
-            % n_cells
+        k_pos = _ring_positions(
+            jnp.max(jnp.where(ok, q_pos, -1), axis=1), n_cells)
         if kernels:
             o = pallas_latent.window_latent_attention(q, ring, q_pos, k_pos, a)
         else:
-            allowed = tfm.latent_allowed(a, q_pos, k_pos, k_pos >= 0)
+            allowed = tfm.attend_allowed(a, q_pos, k_pos, k_pos >= 0)
             o = tfm.latent_attend(q, ring, a, allowed, dt)
         return rows_c, keys_c, o, None
     k_pos = jnp.broadcast_to(jnp.arange(geo.max_kv)[None], (B, geo.max_kv))
@@ -222,7 +262,7 @@ def _latent_layer(a, q, row, index, rows_c, keys_c, *, q_pos, ok, tables,
         selected = pallas_latent.index_select(scores, a.index_topk)
     else:
         scores = tfm.index_scores(index["q"], index["w"], keys,
-                                  tfm.latent_allowed(a, q_pos, k_pos))
+                                  tfm.attend_allowed(a, q_pos, k_pos))
         selected = tfm.select_keys(scores, a.index_topk)
     # The selected rows. A long query window picks more rows than the slot
     # has (512 queries x 2048): the slot's pages are gathered once, whole
@@ -257,8 +297,41 @@ def _latent_layer(a, q, row, index, rows_c, keys_c, *, q_pos, ok, tables,
     return rows_c, keys_c, o, selected
 
 
+def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
+                   kernels):
+    """One multi-head layer of a described kind in a chunk or decode
+    program: write the window's ``k, v [B, Q, Hkv, dh]`` at the consecutive
+    positions ``q_pos [B, Q]`` where ``ok [B, Q]``, then attend ``q [B, Q,
+    Hq, dh]`` -> (the layer's arrays, ``o [B, Q, Hq, dh]``). A full layer's
+    pages are the context columns of ``tables [B, max_blocks +
+    ring_blocks]``, a window layer's the ring's. With ``kernels`` the
+    layer's arrays are read where they lie, over the live pages only; else
+    the slot's pages (``max_kv`` positions, or the ring) are gathered and
+    attended with materialised scores (``transformer.grouped_attend``)."""
+    B = q_pos.shape[0]
+    table, page_ids, slot = _window_cells(a, q_pos, ok, tables, geo)
+    k_c = k_c.at[page_ids, slot].set(_fused(k))
+    v_c = v_c.at[page_ids, slot].set(_fused(v))
+    p_hi = jnp.max(jnp.where(ok, q_pos, -1), axis=1)             # [B]
+    if kernels:
+        o = paged_attention.paged_grouped_attention(
+            q, k_c, v_c, table, q_pos[:, 0], p_hi + 1,
+            n_kv_heads=a.n_kv_heads, window=a.window, ring=bool(a.window))
+        return k_c, v_c, o
+    n_cells = table.shape[1] * geo.page_size
+    if a.window:
+        k_pos = _ring_positions(p_hi, n_cells)
+    else:
+        k_pos = jnp.broadcast_to(jnp.arange(n_cells)[None], (B, n_cells))
+    allowed = tfm.attend_allowed(a, q_pos, k_pos,
+                                 (k_pos >= 0) & (k_pos <= p_hi[:, None]))
+    rows = (c[table].reshape(B, n_cells, a.n_kv_heads, a.head_dim)
+            for c in (k_c, v_c))
+    return k_c, v_c, tfm.grouped_attend(q, *rows, a, allowed, dt)
+
+
 def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
-            latent=None):
+            latent=None, grouped=None):
     """Every layer of the model over ``x [B, S, D]`` through
     ``transformer.block``, the one block definition, with the serving
     attention: layer ``li``'s new K/V (after the Q/K norm and the rotation
@@ -268,7 +341,9 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
     ``attend(q, k, v)`` (:func:`_masked` over gathered pages, or the decode
     program's kernel over the layer's own arrays). A latent layer goes
     through ``latent(a, q, row, index, rows_c, keys_c)``
-    (:func:`_latent_layer` with the program's positions and tables). ->
+    (:func:`_latent_layer` with the program's positions and tables), a
+    multi-head layer of a described kind through ``grouped(a, q, k, v, k_c,
+    v_c)`` (:func:`_grouped_layer`, the same). ->
     (ck, cv, x after the final norm, what the layers report or None:
     ``counts`` and ``top`` of the expert layers, ``selected`` of the
     selecting ones, each stacked over those layers)."""
@@ -281,6 +356,10 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
                 ck[li], kk = write(ck[li], k)
                 cv[li], vv = write(cv[li], v)
                 return attend(q, kk, vv)
+        elif isinstance(a, tfm.MultiHeadAttention):
+            def write_and_attend(q, k, v, li=li, a=a):
+                ck[li], cv[li], o = grouped(a, q, k, v, ck[li], cv[li])
+                return o
         else:
             def write_and_attend(q, row, index, li=li, a=a):
                 ck[li], cv[li], o, selected = latent(a, q, row, index,
@@ -309,9 +388,10 @@ def _result(ck, cv, logits, moe, mesh, cfg):
 
 
 def _no_latent(cfg, what):
-    if cfg.latent:
-        raise ValueError(f"{what}: a model with latent attention layers is "
-                         f"filled by chunks (make_chunk_step) only")
+    if cfg.described:
+        raise ValueError(f"{what}: a model whose layers are described by "
+                         f"kind (latent, multihead) is filled by chunks "
+                         f"(make_chunk_step) only")
 
 
 def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
@@ -383,9 +463,11 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
         x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
                               params, cfg, positions)
         x = x[:, None, :]                                  # [B, 1, D]
-        latent = functools.partial(
-            _latent_layer, q_pos=positions[:, None], ok=active[:, None],
-            tables=block_tables, geo=geo, dt=dt, kernels=kernels)
+        kinds = dict(q_pos=positions[:, None], ok=active[:, None],
+                     tables=block_tables, geo=geo, dt=dt)
+        latent = functools.partial(_latent_layer, **kinds, kernels=kernels)
+        grouped = functools.partial(_grouped_layer, **kinds,
+                                    kernels=grouped_kernels(cfg, geo, mesh))
         block_tables = _context_tables(block_tables, geo)
         blk = positions // geo.page_size
         slot = positions % geo.page_size
@@ -421,7 +503,7 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
 
         ck, cv, x, moe = _layers(params, cache, x, positions[:, None], write,
                                  attend, active[:, None], cfg=cfg, mesh=mesh,
-                                 latent=latent)
+                                 latent=latent, grouped=grouped)
         logits = jnp.einsum("bsd,vd->bsv", x,
                             tfm.head_weights(params, cfg).astype(dt))[:, 0]
         return _result(ck, cv, logits, moe, mesh, cfg)
@@ -457,12 +539,15 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
         layer_cache = layer_cache.at[page_ids, slot_w].set(_fused(kv))
         return layer_cache, _gather_pages(layer_cache, block_tables, cfg)
 
-    latent = functools.partial(
-        _latent_layer, q_pos=pos, ok=valid, tables=tables, geo=geo,
-        dt=cfg.compute_dtype, kernels=latent_kernels(cfg, geo, mesh))
+    kinds = dict(q_pos=pos, ok=valid, tables=tables, geo=geo,
+                 dt=cfg.compute_dtype)
+    latent = functools.partial(_latent_layer, **kinds,
+                               kernels=latent_kernels(cfg, geo, mesh))
+    grouped = functools.partial(_grouped_layer, **kinds,
+                                kernels=grouped_kernels(cfg, geo, mesh))
     return _layers(params, cache, x, pos, write,
                    _masked(cfg, kv_mask[:, None, :, :]), valid, cfg=cfg,
-                   mesh=mesh, latent=latent)
+                   mesh=mesh, latent=latent, grouped=grouped)
 
 
 def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
@@ -487,9 +572,11 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
       (``ServeLoop``). The chunk's live score footprint [q_len, max_kv]
       is exactly what ``transformer.resolve_attn`` tiers on — q_len is
       the knob that walks this step from gather territory toward the
-      flash crossover, and the math is the gather-tier kernel
-      (``transformer.causal_attend``; on-TPU flash tiling of the same
-      mask is a drop-in behind the same signature).
+      flash crossover, and for a plain multi-head layer the math is the
+      gather-tier kernel (``transformer.causal_attend`` over ``max_kv``
+      gathered rows). A layer of a described kind reads its live pages
+      through the paged kernel with a query block instead
+      (:func:`_grouped_layer`): the tiling this text used to promise.
     - **speculative scoring** (B=max_batch, q_len=draft_k+1): one
       batched target pass scores ``[last_token, d_1..d_k]`` per slot;
       accept/reject happens host-side (:mod:`.speculate`).

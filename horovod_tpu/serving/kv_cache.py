@@ -1,7 +1,9 @@
 """Paged KV cache: the device-side half of the serving plane's memory.
 
 Geometry: for K and for V, one array per layer, ``[n_pages, page_size,
-n_heads * head_dim]``. A *page* holds ``page_size`` token slots; requests
+kv_heads * head_dim]`` (``n_heads * head_dim`` for the multi-head attention
+of ``n_heads``; ``n_kv_heads * head_dim`` for a kind that states fewer
+key/value heads than query heads). A *page* holds ``page_size`` token slots; requests
 own pages through the numpy-side
 :class:`~horovod_tpu.serving.scheduler.PageAllocator` and reach them
 through per-request **block tables** (page-id lists), so the jit'd
@@ -41,7 +43,9 @@ of ``ring_blocks`` pages for as long as it runs: position ``p`` lives in the
 ring's block ``(p // page) % ring_blocks``, and ``ring_blocks * page >=
 window - 1 + the longest query window of any program``, so that a program
 may write its whole window first and still find every key its first query
-sees. A ring (and not a block table that frees pages as the window slides)
+sees. A window layer of a described MULTI-HEAD kind
+(``TransformerConfig.multihead``) holds its K and its V the same way, both on
+rings from that pool, ``[ring_pages, page, n_kv_heads * head_dim]``. A ring (and not a block table that frees pages as the window slides)
 because its size never changes: nothing is allocated or freed at a token
 boundary, no request can be starved or preempted for window state, and the
 block table a program takes keeps one fixed width, ``max_blocks +
@@ -64,6 +68,8 @@ import math
 
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+from ..models.transformer import MultiHeadAttention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,7 +112,7 @@ def with_rings(geo, cfg, q_len, max_batch):
     ``window - 1 + q_len`` positions (``q_len``: the longest query window a
     program will run) in whole pages, ``max_batch`` of them and the trash
     page. Unchanged for a model without window layers."""
-    windows = [a.window for _, a in cfg.latent if a.window]
+    windows = [a.window for _, a in cfg.latent + cfg.multihead if a.window]
     if not windows:
         return geo
     blocks = -(-(max(windows) - 1 + int(q_len)) // geo.page_size)
@@ -131,15 +137,18 @@ def layer_shapes(cfg, geo, li):
     if a.window and not pages:
         raise ValueError("a window layer needs a geometry with rings "
                          "(kv_cache.with_rings)")
+    if isinstance(a, MultiHeadAttention):
+        shape = (pages, geo.page_size, a.kv_width)
+        return shape, shape
     return ((pages, geo.page_size, a.row_width),
             (pages, geo.page_size, a.index_dim) if a.index_topk else None)
 
 
 def make_cache(cfg, geo, mesh=None):
     """Allocate the zeroed cache: {"k": (...), "v": (...)}, each a tuple of
-    n_layers arrays in the model's compute dtype, [n_pages, page_size,
-    n_heads * head_dim] for a multi-head layer (:func:`layer_shapes` for a
-    latent one). With a mesh, the arrays are placed sharded on the
+    n_layers arrays in the model's compute dtype, each of its layer's own
+    shape (:func:`layer_shapes`: pages or ring pages, and the lanes of the
+    layer's kind). With a mesh, the arrays are placed sharded on the
     model axis (when that axis exists in the mesh)."""
     sharding = None
     if mesh is not None and cfg.model_axis in mesh.axis_names:

@@ -79,6 +79,7 @@ import numpy as np
 
 from ..observability import metrics as _metrics
 from ..observability import spans as _spans
+from ..models.transformer import LatentAttention, MultiHeadAttention
 from . import engine, kv_cache, speculate
 from .prefix_cache import PrefixCache
 from .scheduler import (DEFAULT_KV_PAGES, DEFAULT_MAX_BATCH,
@@ -243,7 +244,7 @@ class ServeLoop:
         self.report_interval = int(report_interval)
         # Latent layers fill by chunks whatever the width; rings of window
         # state cannot be shared between requests, so no prefix is.
-        padded = geo.max_kv <= PADDED_PREFILL_MAX_KV and not cfg.latent
+        padded = geo.max_kv <= PADDED_PREFILL_MAX_KV and not cfg.described
         if prefill_chunk is None:
             prefill_chunk = (2 * geo.page_size if padded
                              else LONG_PREFILL_CHUNK)
@@ -305,10 +306,21 @@ class ServeLoop:
         # windowed, by program kind, from the positions alone (as
         # ``kv_pages_read`` is: host arithmetic, nothing fetched).
         kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
-        self._select = [a.index_topk for a in kinds if a and a.index_topk]
-        self._windows = [a.window for a in kinds if a and a.window]
+        latent = [a for a in kinds if isinstance(a, LatentAttention)]
+        self._select = [a.index_topk for a in latent if a.index_topk]
+        self._windows = [a.window for a in latent if a.window]
+        # Multi-head layers of a described kind: the K/V rows a call reads
+        # (each live row once a layer: what the paged kernel has to move) and
+        # the (query, key) pairs it multiplies, full and window layers apart;
+        # and the rows the window layers would read were they sized like
+        # full ones.
+        multihead = [a for a in kinds if isinstance(a, MultiHeadAttention)]
+        self._mh_full = sum(1 for a in multihead if not a.window)
+        self._mh_windows = [a.window for a in multihead if a.window]
         self.attn_stats = {name: {} for name in (
-            "kv_scored", "kv_selected", "kv_window", "queries", "calls")}
+            "kv_scored", "kv_selected", "kv_window", "queries", "calls",
+            *(("kv_full_rows", "kv_window_rows", "kv_window_rows_as_full",
+               "qk_full_pairs", "qk_window_pairs") if multihead else ()))}
 
     @contextlib.contextmanager
     def _span(self, name, **args):
@@ -360,10 +372,11 @@ class ServeLoop:
         return packed[:n_tokens].reshape(step.logits.shape[:-1])
 
     def _count_attn(self, kind, live):
-        """One program call whose queries see ``live`` keys each (their
-        positions + 1): what its latent layers scored, selected and
-        windowed."""
-        if not (self._select or self._windows):
+        """One program call whose queries see ``live [slots, queries]`` keys
+        each (their positions + 1; a slot's queries are consecutive): what
+        its latent layers scored, selected and windowed, and what its
+        multi-head layers of a described kind read and multiplied."""
+        if not self._counts_attn:
             return
         live = np.asarray(live, np.int64)
         found = {
@@ -373,15 +386,38 @@ class ServeLoop:
             "kv_window": sum(int(np.minimum(live, w).sum())
                              for w in self._windows),
             "queries": live.size, "calls": 1}
+        counters = [(_metrics.SERVE_KV_SCORED, "kv_scored"),
+                    (_metrics.SERVE_KV_SELECTED, "kv_selected"),
+                    (_metrics.SERVE_KV_WINDOW, "kv_window")]
+        if self._mh_full or self._mh_windows:
+            rows = live.max(axis=1)         # a slot's live rows, read once
+            found.update(
+                kv_full_rows=int(rows.sum()) * self._mh_full,
+                kv_window_rows=sum(
+                    int(np.minimum(rows, w - 1 + live.shape[1]).sum())
+                    for w in self._mh_windows),
+                kv_window_rows_as_full=int(rows.sum())
+                * len(self._mh_windows),
+                qk_full_pairs=int(live.sum()) * self._mh_full,
+                qk_window_pairs=sum(int(np.minimum(live, w).sum())
+                                    for w in self._mh_windows))
+            counters += [
+                (_metrics.SERVE_KV_FULL_ROWS, "kv_full_rows"),
+                (_metrics.SERVE_KV_WINDOW_ROWS, "kv_window_rows"),
+                (_metrics.SERVE_KV_WINDOW_ROWS_AS_FULL,
+                 "kv_window_rows_as_full")]
         for name, n in found.items():
             by_kind = self.attn_stats[name]
             by_kind[kind] = by_kind.get(kind, 0) + n
         if _metrics.enabled():
-            for metric, name in ((_metrics.SERVE_KV_SCORED, "kv_scored"),
-                                 (_metrics.SERVE_KV_SELECTED, "kv_selected"),
-                                 (_metrics.SERVE_KV_WINDOW, "kv_window")):
+            for metric, name in counters:
                 metric.labels(program=kind).inc(found[name])
             _metrics.SERVE_KV_SELECT_SHARE.set(self._kv_select_share())
+
+    @property
+    def _counts_attn(self):
+        return bool(self._select or self._windows or self._mh_full
+                    or self._mh_windows)
 
     def _kv_select_share(self):
         scored = sum(self.attn_stats["kv_scored"].values())
@@ -489,7 +525,7 @@ class ServeLoop:
             bt = np.asarray(
                 self.batcher.block_table(req, self.geo.max_blocks),
                 np.int32)[None]
-            self._count_attn("chunk", np.arange(filled, end) + 1)
+            self._count_attn("chunk", np.arange(filled, end)[None] + 1)
         with self._span("serve.chunk.dispatch", rid=req.rid, start=filled,
                         end=end, target=target):
             step = self._call("chunk", self.chunk_fn, toks,
@@ -524,7 +560,7 @@ class ServeLoop:
                 active[slot] = True
             if ahead:
                 tokens = after.feed()
-            self._count_attn("decode", positions[active] + 1)
+            self._count_attn("decode", positions[active][:, None] + 1)
             # Pages of live context a decode step has to read, against the
             # B x max_blocks the gather path reads whatever is live.
             live_pages = int(
@@ -580,8 +616,8 @@ class ServeLoop:
                 positions[slot] = len(ctx) - 1
                 tables[slot] = self.batcher.block_table(req, mb)
                 active[slot] = True
-            self._count_attn("spec", (positions[active][:, None]
-                                      + np.arange(k + 1) + 1).ravel())
+            self._count_attn("spec", positions[active][:, None]
+                             + np.arange(k + 1) + 1)
         with self._span("serve.spec.dispatch", draft_k=k,
                         fill=self.batcher.batch_fill()):
             step = self._call("spec", self.spec_fn, tokens, positions,
@@ -854,7 +890,7 @@ class ServeLoop:
         snap["decode_ahead_share"] = (
             self.loop_stats["decode_ahead_calls"]
             / max(1, self.loop_stats["decode_calls"]))
-        if self._select or self._windows:
+        if self._counts_attn:
             snap["attn"] = {
                 **{name: dict(by_kind)
                    for name, by_kind in self.attn_stats.items()},

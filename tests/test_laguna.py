@@ -1,0 +1,399 @@
+"""A model whose layers are multi-head attention of kinds that differ
+(``benchmark/configs/laguna-s-2.1.json``: grouped queries, 48 or 72 query
+heads over 8 key/value heads of 128; a window on three layers in four, on K/V
+rings; YaRN on half of each head in the full layers and plain rotary positions
+on all of it in the window layers; a head gate; a dense first layer, a shared
+expert, a sigmoid router, a share of the experts held here) as an instance of
+``models/transformer.py``'s one block, at a tiny size on the CPU, against the
+benchmark's plain reference (``benchmark/reference/laguna.py``: the file the
+chip run is judged by).
+
+The tiny model is made the way the benchmark's runner makes the real one: the
+configuration FILE's ``model`` mapping applied to the file's own keys, here
+with every size shrunk and the published ratios kept (2 key/value heads under
+4 and 6 query heads, window 8, page 4, a ring shorter than the sequence, 16
+experts top-3 with 4 held), so that the mapping itself is tested. Everything
+runs in float32, where program and reference must agree to rounding although
+the one attends through pages and rings and the other over the whole sequence.
+"""
+import dataclasses
+import importlib.util
+import json
+import math
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.serving import kv_cache
+from horovod_tpu.serving import loop as serve_loop
+from horovod_tpu.serving.scheduler import Request
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, path))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+reference = _load("benchmark/reference/laguna.py", "laguna_reference")
+layers_runner = _load("benchmark/runners/serve_layers.py",
+                      "serve_layers_runner")
+runner = _load("benchmark/runners/serve_gqa.py", "serve_gqa_runner")
+FILE = json.load(open(os.path.join(ROOT, "benchmark", "configs",
+                                   "laguna-s-2.1.json")))
+PAGE, CHUNK, TOL = 4, 8, 2e-4
+KINDS = ["full_attention"] + ["sliding_attention"] * 3
+
+
+def _config(**overrides):
+    """The configuration file with every size shrunk; YaRN's original
+    length too (16), so that the blend is in play at these positions."""
+    config = dict(FILE)
+    config.update(
+        hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
+        shared_expert_intermediate_size=32, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16,
+        heads_by_kind={"full_attention": 4, "sliding_attention": 6},
+        num_attention_heads_per_layer=[4, 6, 6, 6] * 12,
+        layer_types=KINDS * 12, num_hidden_layers=5, sliding_window=8,
+        num_experts_published=16, experts_held=[4, 4], num_experts=4,
+        num_experts_per_tok=3, vocab_size=128, max_position_embeddings=256,
+        rope_parameters={
+            "full_attention": dict(
+                FILE["rope_parameters"]["full_attention"], factor=8,
+                original_max_position_embeddings=16, attention_factor=1.2),
+            "sliding_attention": FILE["rope_parameters"][
+                "sliding_attention"]})
+    config.update(overrides)
+    return config
+
+
+def _cfg(config, **overrides):
+    return dataclasses.replace(runner.model_config(config), dtype="float32",
+                               param_dtype="float32", **overrides)
+
+
+def _params(cfg, seed=0):
+    """Seeded weights as the benchmark's runner draws them: norm scales
+    around 1, so that none can be left out unseen."""
+    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
+    rng = np.random.default_rng(seed)
+
+    def jitter(path, x):
+        if getattr(path[-1], "key", None) == "scale":
+            return (1.0 + 0.1 * rng.standard_normal(x.shape)).astype(x.dtype)
+        return x
+
+    return jax.tree_util.tree_map_with_path(jitter, params)
+
+
+def _tokens(n, seed=1):
+    return np.random.default_rng(seed).integers(0, 128, n).tolist()
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def _want(config, params, tokens, last=None, fault=None):
+    hp = reference.hyper(config)
+    return reference.logits(
+        reference.from_horovod_tpu(params), jnp.asarray([tokens], jnp.int32),
+        hp, last=last, with_routes=True, kn=reference.knobs(hp, fault))
+
+
+def _loop(cfg, params, max_batch=2, n_pages=64, context=128, **kw):
+    geo = kv_cache.geometry(n_pages, PAGE, context)
+    return serve_loop.ServeLoop(params, cfg, geo=geo, max_batch=max_batch,
+                                prefill_chunk=CHUNK, **kw)
+
+
+def test_the_file_describes_its_layers():
+    """The configuration file's ``model`` mapping at the published sizes: the
+    pattern of the nine layers that are run, both kinds' head counts, rotary
+    rules and gates, the dense first layer, the experts held, and the
+    parameter count the cut was sized by."""
+    cfg = runner.model_config(FILE)
+    kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
+    assert [(a.n_heads, a.window) for a in kinds] == [
+        (48, 0), (72, 512), (72, 512), (72, 512)] * 2 + [(48, 0)]
+    full, window = kinds[0], kinds[1]
+    assert all(isinstance(a, tfm.MultiHeadAttention) for a in kinds)
+    assert (full.n_kv_heads, full.head_dim, full.group, full.kv_width,
+            full.rope_dim, full.rope_theta, full.gate) == (
+        8, 128, 6, 1024, 64, 500000, True)
+    assert (full.yarn.factor, full.yarn.original_max, full.yarn.beta_fast,
+            full.yarn.beta_slow) == (128, 8192, 32, 1)
+    assert (window.group, window.kv_width, window.rope_dim, window.yarn,
+            window.rope_theta, window.gate) == (9, 1024, 128, None, 10000,
+                                                True)
+    assert cfg.head_dim == 64 != full.head_dim     # 3072 / 48: not a layer's
+    assert [cfg.is_moe(li) for li in range(9)] == [False] + [True] * 8
+    assert (cfg.n_experts, cfg.n_held, cfg.top_k, cfg.router,
+            cfg.routed_scale, cfg.shared_experts) == (
+        256, 32, 10, "sigmoid", 2.5, 1)
+    shapes = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0),
+                                                    cfg))
+    n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    assert 3.19e9 < n < 3.21e9
+    assert jax.tree.structure(shapes) == jax.tree.structure(
+        tfm.param_specs(cfg),
+        is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
+    for key in FILE["reduced"]:
+        assert FILE[key] != FILE[key + "_published"]
+
+
+def test_yarn_frequencies_by_hand():
+    """The full layers' 32 inverse frequencies and factor at the published
+    ``rope_parameters``, against numbers worked by hand: the dim that turns
+    ``n`` times in 8192 positions is ``64 ln(8192 / (2 pi n)) / (2 ln
+    500000)`` = 9.04 for 32 turns and 17.49 for one, so frequencies 0..9
+    stay as they are, 18.. are divided by 128, and those between are blended
+    by (i - 9) / 9; the factor is 0.1 ln 128 + 1. Program and reference
+    agree, each computing its own."""
+    a = runner.model_config(FILE).attn_of(0)
+    freq, factor = tfm.rope_inv_freq(a)
+    theirs, theirs_factor = reference.inv_frequencies(
+        FILE["rope_parameters"]["full_attention"], FILE["head_dim"])
+    plain = 500000.0 ** (-np.arange(32) / 32)
+    assert freq.shape == (32,)
+    np.testing.assert_allclose(freq[:10], plain[:10], rtol=1e-12)
+    np.testing.assert_allclose(freq[18:], plain[18:] / 128, rtol=1e-12)
+    # Frequency 12: a third of the way: 2/3 plain + 1/3 interpolated.
+    np.testing.assert_allclose(
+        freq[12], plain[12] * (2 / 3 + 1 / 3 / 128), rtol=1e-12)
+    np.testing.assert_allclose(plain[12], math.exp(-0.375 * math.log(5e5)),
+                               rtol=1e-12)
+    np.testing.assert_allclose(freq[12], 0.0048808, rtol=1e-4)  # 0.0072926 x 0.66927
+    assert abs(factor - 1.4852030263919618) < 1e-15
+    assert abs(0.1 * math.log(128) + 1 - factor) < 1e-12
+    np.testing.assert_allclose(theirs, freq, rtol=1e-12)
+    assert theirs_factor == factor
+    # A window layer: plain, all 128 dims, theta 10,000.
+    freq, factor = tfm.rope_inv_freq(runner.model_config(FILE).attn_of(1))
+    np.testing.assert_allclose(freq, 10000.0 ** (-np.arange(64) / 64),
+                               rtol=1e-12)
+    assert factor == 1.0
+
+
+def test_forward_matches_the_reference():
+    """The trainer's forward pass (no cache): logits and the experts
+    chosen."""
+    config = _config()
+    cfg = _cfg(config)
+    params = _params(cfg)
+    tokens = _tokens(40)
+    want, routes = _want(config, params, tokens)
+    got = tfm.forward(params, jnp.asarray([tokens], jnp.int32), cfg)
+    assert _rel(got, want) < TOL
+    assert routes.shape == (4, 1, 40, 3)
+
+
+@pytest.mark.parametrize("n, why", [
+    (5, "a context shorter than the window and than a chunk"),
+    (37, "a window layer past its ring (16 cells) twice over"),
+    (16, "a prompt of whole chunks, one ring's worth"),
+])
+def test_chunk_fill_and_decode_match_the_reference(n, why):
+    """The loop's own programs through the caches, as the benchmark's check
+    drives them: the prompt in chunks of 8, then four decode steps; every
+    logit row of the last chunk and the steps, and the experts chosen at
+    EVERY position."""
+    config = _config()
+    cfg = _cfg(config)
+    params = _params(cfg)
+    loop = _loop(cfg, params)
+    assert loop.prefill_fn is None and loop.bprefill_fn is None
+    assert loop.prefix is None                      # rings are not shared
+    assert loop.geo.ring_blocks == 4                # 8 - 1 + 8 positions
+    pages = np.arange(1, 2 + (n + layers_runner.N_DECODE) // PAGE)
+    seq, got, tops, _ = layers_runner.served_rows(
+        loop, params, _tokens(n, seed=n), pages, ring_pages=[1, 2, 3, 4])
+    want, want_top = _want(config, params, seq, last=len(got))
+    assert _rel(got, want[0]) < TOL, why
+    assert layers_runner.flips(tops, np.asarray(want_top)[:, 0])[0] == 0
+
+
+def test_preemption_and_refill_keep_the_tokens():
+    """A pool too small for both requests' contexts: the younger is
+    preempted, loses its pages and its ring, and is filled again from its
+    first token; both emit the reference's greedy tokens. The counters of
+    the multi-head kinds follow the programs."""
+    config = _config()
+    cfg = _cfg(config)
+    params = _params(cfg)
+    loop = _loop(cfg, params, n_pages=13, context=48)
+    prompts = [_tokens(14, seed=7), _tokens(11, seed=8)]
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=20, arrival_t=1e-6)
+            for i, p in enumerate(prompts)]
+    _, finished = loop.run(reqs)
+    assert loop.batcher.stats["preemptions"] > 0
+    assert loop.batcher.ring_alloc.used_pages() == 0
+    stats = serve_loop.serve_stats()["attn"]
+    assert stats["calls"]["chunk"] > 4 and stats["calls"]["decode"] > 0
+    for kind in ("chunk", "decode"):
+        assert 0 < stats["kv_window_rows"][kind] \
+            <= stats["kv_window_rows_as_full"][kind]
+        # two full layers, three window layers
+        assert stats["kv_window_rows_as_full"][kind] * 2 \
+            == stats["kv_full_rows"][kind] * 3
+        assert stats["qk_full_pairs"][kind] >= stats["kv_full_rows"][kind]
+    assert stats["qk_full_pairs"]["decode"] == stats["kv_full_rows"]["decode"]
+    assert len(finished) == 2
+    for req in finished:
+        seq = list(req.prompt) + list(req.generated)
+        want = _want(config, params, seq[:-1], last=len(req.generated))[0]
+        assert np.argmax(want[0], -1).tolist() == req.generated
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Section 4 of the model-configs guide: over a deployment of four
+    chips, each holding 4 of the 16 experts, the routed parts all shares
+    give, with the shared expert counted once, add up to what the uncut
+    layer gives; and the program's expert layer on each share is that
+    share's part."""
+    config = _config()
+    whole = _cfg(_config(experts_held=[0, 16]))
+    params = _params(whole)
+    layer = params["layers"][1]
+    h = jnp.asarray(np.random.default_rng(3).standard_normal((1, 24, 64)),
+                    jnp.float32)
+    p = reference.from_horovod_tpu(params)["layers"][1]["mlp"]
+    hp = reference.hyper(_config(experts_held=[0, 16]))
+    with jax.default_matmul_precision("highest"):
+        shared, routed, _ = reference.moe_parts(h[0], p, hp)
+        total = jnp.zeros_like(routed)
+        for offset in range(0, 16, 4):
+            share_cfg = _cfg(_config(experts_held=[offset, 4]))
+            mine = dict(layer, **{
+                name: layer[name][offset:offset + 4]
+                for name in ("w_in", "w_gate", "w_out")})
+            got, routing = tfm._moe_ffn(h, mine, share_cfg)
+            held = dict(p, experts={name: x[offset:offset + 4]
+                                    for name, x in p["experts"].items()})
+            _, part, _ = reference.moe_parts(
+                h[0], held, dict(hp, experts_held=(offset, 4)))
+            assert _rel(got[0], shared + part) < TOL
+            assert int(routing["counts"].sum()) == int(
+                ((routing["top"] >= offset)
+                 & (routing["top"] < offset + 4)).sum())
+            total = total + part
+    assert _rel(total, routed) < TOL
+    uncut, _ = tfm._moe_ffn(h, layer, whole)
+    assert _rel(uncut[0], shared + routed) < TOL
+    assert config["experts_held"] == [4, 4]
+
+
+def _sabotaged(name, cfg, params):
+    """The program with one ASSUMED convention left out or changed; the
+    reference keeps it."""
+    def with_kinds(**changes):
+        kinds = dict(cfg.multihead)
+        for kind, fields in changes.items():
+            kinds[kind] = dataclasses.replace(kinds[kind], **fields)
+        return dataclasses.replace(cfg, multihead=tuple(kinds.items()))
+
+    if name == "no head gate":
+        return with_kinds(full_attention=dict(gate=False),
+                          sliding_attention=dict(gate=False)), params
+    if name == "window does not count the query":
+        return with_kinds(sliding_attention=dict(window=9)), params
+    if name == "full layers rotate the whole head":
+        return with_kinds(full_attention=dict(rope_share=1.0)), params
+    if name == "no YaRN on the full layers":
+        return with_kinds(full_attention=dict(yarn=None)), params
+    if name == "YaRN on the window layers too":
+        full = dict(cfg.multihead)["full_attention"]
+        return with_kinds(sliding_attention=dict(yarn=full.yarn)), params
+    if name == "weights not divided by their sum":
+        return dataclasses.replace(cfg, norm_topk=False), params
+    if name == "no routed scale":
+        return dataclasses.replace(cfg, routed_scale=1.0), params
+    assert name == "softmax router"
+    return dataclasses.replace(cfg, router="softmax"), params
+
+
+@pytest.mark.parametrize("name", [
+    "no head gate", "window does not count the query",
+    "full layers rotate the whole head", "no YaRN on the full layers",
+    "YaRN on the window layers too", "weights not divided by their sum",
+    "no routed scale", "softmax router"])
+def test_an_assumption_left_out_fails(name):
+    config = _config()
+    cfg = _cfg(config)
+    params = _params(cfg)
+    tokens = _tokens(40)
+    want = _want(config, params, tokens)[0]
+    bad_cfg, bad_params = _sabotaged(name, cfg, params)
+    got = tfm.forward(bad_params, jnp.asarray([tokens], jnp.int32), bad_cfg)
+    assert _rel(got, want) > 50 * TOL, name
+
+
+@pytest.mark.parametrize("fault", reference.FAULTS)
+def test_a_planted_fault_moves_the_reference(fault):
+    """The benchmark's controls: the reference itself with one thing wrong
+    (``reference.knobs``) is far from the sound reference, here as on the
+    chip; and the grouping fault is the program's ``j % Hkv`` twin."""
+    config = _config()
+    params = _params(_cfg(config))
+    tokens = _tokens(40)
+    want = _want(config, params, tokens)[0]
+    assert _rel(_want(config, params, tokens, fault=fault)[0], want) \
+        > 50 * TOL, fault
+    assert fault in FILE["controls"]["planted_faults"]["reference_faults"]
+
+
+@pytest.mark.parametrize("li, pages, lanes", [
+    (0, "n_pages", 32),     # full: 2 key/value heads of 16, on pages
+    (1, "ring_pages", 32),  # window: the same lanes, on rings, K and V both
+])
+def test_cache_shapes_by_layer_kind(li, pages, lanes):
+    cfg = _cfg(_config())
+    geo = kv_cache.with_rings(kv_cache.geometry(64, PAGE, 128), cfg, CHUNK, 2)
+    assert (geo.ring_blocks, geo.ring_pages) == (4, 9)
+    shape = (getattr(geo, pages), PAGE, lanes)
+    assert kv_cache.layer_shapes(cfg, geo, li) == (shape, shape)
+    # Two full layers on 64 pages, three window layers on 9 ring pages.
+    assert kv_cache.cache_bytes(cfg, geo) == 2 * 4 * PAGE * lanes * (
+        2 * 64 + 3 * 9)
+    with pytest.raises(ValueError, match="rings"):
+        kv_cache.layer_shapes(cfg, kv_cache.geometry(64, PAGE, 128), 1)
+
+
+def test_cache_shapes_of_the_plain_kind_stay():
+    """A multi-head layer that names no kind holds ``n_heads * (d_model /
+    n_heads)`` lanes on pages, as before; a model without window layers gets
+    no rings."""
+    cfg = tfm.tiny()
+    geo = kv_cache.with_rings(kv_cache.geometry(16, PAGE, 32), cfg, CHUNK, 2)
+    assert geo.ring_blocks == 0 and not cfg.described
+    shape = (16, PAGE, cfg.n_heads * cfg.head_dim)
+    assert kv_cache.layer_shapes(cfg, geo, 0) == (shape, shape)
+
+
+def test_the_cell_s_cache_at_the_published_widths():
+    """The cell's geometry: 1024 lanes a row (a sixth and a ninth of what
+    the query heads would take), three full layers on 32,769 pages and six
+    window layers on rings of 64 pages a slot."""
+    cfg = runner.model_config(FILE)
+    srv = FILE["assumed"]["serve"]
+    geo = kv_cache.with_rings(
+        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
+        cfg, 512, srv["max_batch"])
+    assert (geo.max_kv, geo.ring_blocks, geo.ring_pages) == (16384, 64, 2049)
+    assert kv_cache.layer_shapes(cfg, geo, 0)[0] == (32769, 16, 1024)
+    assert kv_cache.layer_shapes(cfg, geo, 1)[1] == (2049, 16, 1024)
+    full = 3 * 2 * 32769 * 16 * 1024 * 2
+    rings = 6 * 2 * 2049 * 16 * 1024 * 2
+    assert kv_cache.cache_bytes(cfg, geo) == full + rings
+    assert 6.43e9 < full < 6.45e9 and 0.80e9 < rings < 0.81e9

@@ -179,3 +179,171 @@ def test_serve_stats_count_the_pages_a_decode_step_reads(monkeypatch, forced):
     assert stats["decode_paged_calls"] == (3 if forced else 0)
     by_rid = lambda rs: {r.rid: r.generated for r in rs}  # noqa: E731
     assert by_rid(finished) == by_rid(reference)
+
+
+# ---- grouped queries, windows, rings, query blocks -------------------------
+
+def _grouped_case(rng, a, *, B, Q, width, lens, page=PAGE):
+    """Pages, tables, queries and what each slot has live for
+    :func:`paged_grouped_attention`: ``lens[b] = (pos0, valid queries)``,
+    0 valid = an inactive slot. Every cell a query may not see holds NaN
+    (a page no slot owns) or large garbage (the positions after a slot's
+    last, stale ring cells): a read of either shows in the output."""
+    from horovod_tpu.ops.pallas_paged_attention import paged_grouped_attention
+
+    n_pages = 1 + B * width
+    lanes = a.kv_width
+    tables = rng.permutation(np.arange(1, n_pages)).reshape(B, width).astype(
+        np.int32)
+    pages = np.full((2, n_pages, page, lanes), np.nan, np.float32)
+    pos0 = np.asarray([p for p, _ in lens], np.int32)
+    kv_len = np.asarray([p + n if n else 0 for p, n in lens], np.int32)
+    cells = width * page
+    for b, n in enumerate(kv_len):
+        # The positions a query of this slot may see, where they lie.
+        lo = max(0, int(pos0[b]) - (a.window - 1)) if a.window else 0
+        rows = 1e4 * rng.normal(size=(2, cells, lanes))
+        for t in range(lo, int(n)):
+            rows[:, t % cells if a.window else t] = rng.normal(
+                size=(2, lanes))
+        pages[:, tables[b]] = rows.reshape(2, width, page, lanes)
+    q = jnp.asarray(rng.normal(size=(B, Q, a.n_heads, a.head_dim)),
+                    jnp.bfloat16)
+    k_pages, v_pages = jnp.asarray(pages, jnp.bfloat16)
+
+    def run(**kw):
+        return paged_grouped_attention(
+            q, k_pages, v_pages, jnp.asarray(tables), jnp.asarray(pos0),
+            jnp.asarray(kv_len), n_kv_heads=a.n_kv_heads, window=a.window,
+            ring=bool(a.window), interpret=True, **kw)
+
+    # The gathered tier over the same pages (``engine._grouped_layer``'s).
+    q_pos = pos0[:, None] + np.arange(Q)[None]
+    p_hi = kv_len - 1
+    if a.window:
+        k_pos = p_hi[:, None] - (p_hi[:, None] - np.arange(cells)) % cells
+    else:
+        k_pos = np.broadcast_to(np.arange(cells)[None], (B, cells))
+    allowed = tfm.attend_allowed(
+        a, jnp.asarray(q_pos), jnp.asarray(k_pos),
+        jnp.asarray((k_pos >= 0) & (k_pos <= p_hi[:, None])))
+    clean = jnp.nan_to_num(jnp.asarray(pages), nan=0.0).astype(jnp.bfloat16)
+    k_all, v_all = (c[jnp.asarray(tables)].reshape(
+        B, cells, a.n_kv_heads, a.head_dim) for c in clean)
+    want = tfm.grouped_attend(q, k_all, v_all, a, allowed, jnp.bfloat16)
+    live = q_pos < kv_len[:, None]                  # the queries that count
+    return run, np.asarray(want, np.float32), live
+
+
+@pytest.mark.parametrize("heads, kv_heads, head_dim, window", [
+    (48, 8, 128, 0),      # the published full layer's grouping
+    (72, 8, 128, 512),    # the published window layer's, on a ring
+    (6, 2, 32, 40),       # a window the ring barely holds
+    (4, 4, 64, 0),        # Hq == Hkv: what paged_decode_attention computes
+], ids=["full-6x8", "window-9x8", "window-small", "one-each"])
+def test_grouped_kernel_decodes_like_the_gathered_tier(heads, kv_heads,
+                                                       head_dim, window):
+    """One query a slot (the decode step): slots of mixed lengths, one
+    inactive, a window layer's slots past their ring."""
+    a = tfm.MultiHeadAttention(heads, kv_heads, head_dim, window=window)
+    rng = np.random.default_rng(heads + window)
+    width = -(-(window - 1 + 1) // PAGE) if window else 6
+    lens = [(0, 1), (window + 3 * width * PAGE + 5 if window else 77, 1),
+            (31, 0), (17, 1)]
+    run, want, live = _grouped_case(rng, a, B=4, Q=1, width=width, lens=lens)
+    got = np.asarray(run(pages_per_block=3), np.float32)
+    assert np.isfinite(got).all()
+    assert (got[2] == 0).all()                      # zeros, never NaN
+    np.testing.assert_allclose(got[live], want[live], atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("heads, kv_heads, head_dim, window, q_block", [
+    (12, 2, 32, 0, 8), (18, 2, 32, 24, 8), (18, 2, 32, 24, 32),
+    (4, 4, 32, 0, 16),
+], ids=["full", "window", "window-one-block", "one-each"])
+def test_grouped_kernel_fills_a_chunk_like_the_gathered_tier(
+        heads, kv_heads, head_dim, window, q_block):
+    """A block of 32 queries a slot (the chunk fill), in query blocks of
+    ``q_block``: a first chunk, a chunk deep into the context (a window
+    layer's ring wrapped), a short last chunk (20 of 32 valid), an inactive
+    slot. Scores exist a tile at a time, so a query that sees nothing of a
+    key block another query of its block sees must stay exact."""
+    a = tfm.MultiHeadAttention(heads, kv_heads, head_dim, window=window)
+    rng = np.random.default_rng(heads + window + q_block)
+    Q = 32
+    width = -(-(window - 1 + Q) // PAGE) if window else 12
+    lens = [(0, Q), (128, Q), (64, 20), (96, 0)]
+    run, want, live = _grouped_case(rng, a, B=4, Q=Q, width=width, lens=lens)
+    got = np.asarray(run(q_block=q_block, pages_per_block=2), np.float32)
+    assert np.isfinite(got[live]).all()
+    assert (got[3] == 0).all()
+    np.testing.assert_allclose(got[live], want[live], atol=2e-2, rtol=2e-2)
+
+
+def test_grouped_kernel_reads_like_paged_decode_attention():
+    """``Hq == Hkv``, no window, one query: the two kernels agree."""
+    from horovod_tpu.ops.pallas_paged_attention import paged_grouped_attention
+
+    rng = np.random.default_rng(5)
+    H, dh, B = 4, 128, 3
+    n_pages = 1 + B * MAX_BLOCKS
+    tables = jnp.asarray(rng.permutation(np.arange(1, n_pages)).reshape(
+        B, MAX_BLOCKS), jnp.int32)
+    k_pages, v_pages = jnp.asarray(
+        rng.normal(size=(2, n_pages, PAGE, H * dh)), jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(B, H, dh)), jnp.bfloat16)
+    lengths = jnp.asarray([33, 0, 64], jnp.int32)
+    one = paged_decode_attention(q, k_pages, v_pages, tables, lengths,
+                                 interpret=True)
+    grouped = paged_grouped_attention(
+        q[:, None], k_pages, v_pages, tables, jnp.maximum(lengths - 1, 0),
+        lengths, n_kv_heads=H, interpret=True)[:, 0]
+    np.testing.assert_allclose(np.asarray(grouped, np.float32),
+                               np.asarray(one, np.float32), atol=2e-2,
+                               rtol=2e-2)
+
+
+def test_programs_with_the_grouped_kernel_match_the_gather_path(monkeypatch):
+    """The engine's choice steered to the kernel, as on the chip: a chunk
+    fill then decode steps of a model of two described kinds give the
+    gathered tier's logits."""
+    full = tfm.MultiHeadAttention(4, 2, 128, rope_share=0.5, gate=True,
+                                  yarn=dict(factor=8, original_max=16))
+    window = tfm.MultiHeadAttention(6, 2, 128, window=24, gate=True)
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=64, n_heads=4, n_layers=2, d_ff=64,
+        max_seq_len=256, norm="rmsnorm", pos="rope", ffn="swiglu",
+        tie_embeddings=False, dtype="float32",
+        layer_attn=("full", "window"),
+        multihead={"full": full, "window": window})
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    geo = kv_cache.with_rings(kv_cache.geometry(40, PAGE, 128), cfg, 32, 2)
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(0, 64, (1, 32)).astype(np.int32)
+    table = np.zeros((2, geo.table_width), np.int32)
+    table[0, :8] = np.arange(1, 9)
+    table[0, geo.max_blocks:] = np.arange(1, 1 + geo.ring_blocks)
+    active = np.asarray([True, False])
+    rows = {}
+    for kernels in (False, True):
+        monkeypatch.setattr(engine, "grouped_kernels",
+                            lambda *a, on=kernels: on)
+        monkeypatch.setattr(
+            engine.paged_attention, "paged_grouped_attention",
+            lambda *a, _fn=engine.paged_attention.paged_grouped_attention,
+            **kw: _fn(*a, **dict(kw, interpret=True, q_block=8)))
+        chunk = engine.make_chunk_step(cfg, geo, q_len=32)
+        decode = engine.make_decode_step(cfg, geo, max_batch=2)
+        cache = kv_cache.make_cache(cfg, geo)
+        out = []
+        for start in (0, 32, 64):
+            cache, lg = chunk(params, cache, tokens, np.asarray([start]),
+                              table[:1], np.ones(1, bool))
+            out.append(np.asarray(lg[0]))
+        for pos in (96, 97):
+            cache, lg = decode(params, cache, np.asarray([3, 0], np.int32),
+                               np.asarray([pos, 0], np.int32), table, active)
+            out.append(np.asarray(lg[:1]))
+        rows[kernels] = np.concatenate(out)
+        monkeypatch.undo()
+    np.testing.assert_allclose(rows[True], rows[False], atol=2e-4, rtol=2e-4)

@@ -35,7 +35,7 @@ def test_index_scores(q_len, pos0):
     q_pos = jnp.asarray(pos0)[:, None] + jnp.arange(q_len)[None]
     k_pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     want = tfm.index_scores(q_i, w, keys,
-                            tfm.latent_allowed(FULL, q_pos, k_pos))
+                            tfm.attend_allowed(FULL, q_pos, k_pos))
     got = pk.index_scores(q_i, w, keys, q_pos)
     assert np.array_equal(np.isfinite(got), np.isfinite(want))
     live = np.isfinite(want)
@@ -90,7 +90,7 @@ def test_window_latent_attention(q_len, pos0):
     p_hi = q_pos[:, -1:]
     k_pos = p_hi - (p_hi - jnp.arange(R)[None]) % R
     got = pk.window_latent_attention(q, ring, q_pos, k_pos, WINDOW)
-    allowed = tfm.latent_allowed(WINDOW, q_pos, k_pos, k_pos >= 0)
+    allowed = tfm.attend_allowed(WINDOW, q_pos, k_pos, k_pos >= 0)
     want = tfm.latent_attend(q, ring, WINDOW, allowed, jnp.float32)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 

@@ -464,3 +464,71 @@ def test_latent_layers_keep_their_kernels_names(monkeypatch):
         min(t + 1, 17) for n in prompts for t in range(n))
     assert attn["calls"]["chunk"] == sum(-(-n // 112) for n in prompts)
     assert 0 < attn["kv_select_share"] < 1
+
+
+def test_described_multihead_layers_keep_their_kernels_names(monkeypatch):
+    """A model of two described multi-head kinds (grouped queries; one on
+    pages, one windowed on rings), with the grouped kernel steered on as on
+    the chip: ``jit_chunk`` and ``jit_decode`` each call it once a layer
+    under the name of the layer's kind, the names the benchmark's readers
+    find in a device trace (``paged_full_attention``,
+    ``paged_window_attention``; compiled for the chip in
+    tests/test_tpu_compile.py), and the loop's counters of the rows read and
+    the pairs multiplied are host arithmetic on the positions."""
+    from horovod_tpu.ops import pallas_paged_attention as paged
+    from horovod_tpu.serving import engine
+    from horovod_tpu.serving.loop import serve_stats
+
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=3, d_ff=32,
+        max_seq_len=1024, norm="rmsnorm", pos="rope", ffn="swiglu",
+        tie_embeddings=False, dtype="float32",
+        layer_attn=("full", "window", "window"),
+        multihead={"full": dict(n_heads=4, n_kv_heads=2, head_dim=128),
+                   "window": dict(n_heads=6, n_kv_heads=2, head_dim=128,
+                                  window=17, gate=True)})
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    assert (paged.FULL_NAME, paged.WINDOW_NAME) == (
+        "paged_full_attention", "paged_window_attention")
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    geo = kv_cache.geometry(130, 16, 1024)
+    monkeypatch.setattr(engine, "grouped_kernels", lambda *a: True)
+    steered = ServeLoop(params, cfg, geo=geo, max_batch=2, prefill_chunk=112)
+    assert steered.geo.ring_tokens == 128 and steered.prefix is None
+    for fn, shape in ((steered.chunk_fn, (1, 112)),
+                      (steered.decode_fn, (2,))):
+        jaxpr = str(fn.trace(
+            params, steered.cache, i32(*shape), i32(shape[0]),
+            i32(shape[0], steered.geo.table_width),
+            jax.ShapeDtypeStruct(shape[:1], jnp.bool_)).jaxpr)
+        # The kernel's wrapper is jitted: one body a kind, called a layer.
+        assert jaxpr.count(f"name={paged.FULL_NAME}") == 1
+        assert jaxpr.count(f"name={paged.WINDOW_NAME}") == 1
+        assert jaxpr.count("name=paged_grouped_attention") == 3
+        assert "paged_decode_attention" not in jaxpr
+    monkeypatch.undo()
+    loop = ServeLoop(params, cfg, geo=geo, max_batch=2, prefill_chunk=112)
+    _, finished = loop.run(poisson_requests(
+        2, 1e6, np.random.default_rng(1), prompt_len=(130, 150),
+        max_new=(2, 3), vocab=cfg.vocab_size))
+    attn = serve_stats()["attn"]
+    prompts = [r.prompt_len for r in finished]
+    chunks = [(start, min(start + 112, n)) for n in prompts
+              for start in range(0, n, 112)]
+    # Every prompt token went through a chunk once: a chunk's full layer has
+    # to read the slot's live rows once, its two window layers what the
+    # window and the chunk's queries span; the pairs are query by query.
+    assert attn["queries"]["chunk"] == sum(prompts)
+    assert attn["calls"]["chunk"] == len(chunks)
+    assert attn["kv_full_rows"]["chunk"] == sum(end for _, end in chunks)
+    assert attn["kv_window_rows"]["chunk"] == 2 * sum(
+        min(end, 16 + end - start) for start, end in chunks)
+    assert attn["kv_window_rows_as_full"]["chunk"] \
+        == 2 * attn["kv_full_rows"]["chunk"]
+    assert attn["qk_full_pairs"]["chunk"] == sum(n * (n + 1) // 2
+                                                 for n in prompts)
+    assert attn["qk_window_pairs"]["chunk"] == 2 * sum(
+        min(t + 1, 17) for n in prompts for t in range(n))
+    assert attn["qk_window_pairs"]["decode"] \
+        == attn["kv_window_rows"]["decode"] \
+        == 17 * 2 * attn["queries"]["decode"]

@@ -456,3 +456,80 @@ def test_layered_cell_programs_fit_one_chip(topo, as_on_the_chip):
                 < block, m.group(0)
         for m in re.finditer(r" = \(?\w+\[([\d,]+)\]\S* sort\(", text):
             assert int(m.group(1).split(",")[-1]) < geo.max_kv, m.group(0)
+
+
+# ---- the serving programs at benchmark/configs/laguna-s-2.1.json's sizes ----
+
+def test_grouped_cell_programs_fit_one_chip(topo, as_on_the_chip):
+    """``laguna-serve-agent-over``'s two programs (the 512-token chunk fill
+    and the decode step) at the cell's geometry: nine layers of two described
+    multi-head kinds, 32 slots of a 16k context, the window layers' K and V on
+    rings. The chip's compiler takes the grouped paged kernel at the
+    published widths for one query a slot and for a block of 128; it is in
+    both programs under the instruction names the benchmark's readers match,
+    once a layer of its kind, and ``paged_decode_attention`` is in neither;
+    no program holds scores of ``[queries, max_kv]`` or a gathered copy of a
+    slot's pages; weights + cache + temporaries stay on the chip; the cache
+    is aliased through."""
+    import importlib.util
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna-s-2.1.json")) as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "serve_gqa", os.path.join(root, "benchmark", "runners",
+                                  "serve_gqa.py"))
+    runner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(runner)
+    cfg = runner.model_config(config)
+    srv = config["assumed"]["serve"]
+    B = srv["max_batch"]
+    geo = kv_cache.with_rings(
+        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
+        cfg, 512, B)
+    assert (geo.max_kv, geo.ring_tokens, geo.ring_pages) == (16384, 1024,
+                                                             2049)
+    assert engine.grouped_kernels(cfg, geo, None)
+    assert engine.decode_attn(cfg, geo, None) == "gather"   # no plain layer
+    params, cache = jax.tree.map(
+        lambda x: _on_chip(topo, x.shape, x.dtype),
+        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
+                                kv_cache.make_cache(cfg, geo))))
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert 13.6e9 < held < 13.7e9          # 81 % of the chip's 16.91e9
+
+    def slots(b, *q):
+        return [_on_chip(topo, s, d) for s, d in (
+            ((b, *q), jnp.int32), ((b,), jnp.int32),
+            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
+
+    kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
+    n_window = sum(1 for a in kinds if a.window)
+    for name, fn, args in (
+            ("chunk", engine.make_chunk_step(cfg, geo, q_len=512),
+             slots(1, 512)),
+            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
+             slots(B))):
+        compiled = fn.lower(params, cache, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
+        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
+        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
+        assert memory.temp_size_in_bytes < 0.2e9
+        text = compiled.as_text()
+        for kernel, n in (("paged_full_attention", len(kinds) - n_window),
+                          ("paged_window_attention", n_window),
+                          ("paged_decode_attention", 0)):
+            calls = [line for line in text.splitlines()
+                     if re.match(rf"\s*%{kernel}[.\d]* = ", line)
+                     and "tpu_custom_call" in line]
+            assert len(calls) == n, (name, kernel)
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
+            == 3 * len(cfg.moe_layers)
+        # No float array spans a slot's max_kv positions: neither gathered
+        # pages nor a query block's scores over them.
+        for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", text):
+            assert str(geo.max_kv) not in m.group(1).split(","), m.group(0)
