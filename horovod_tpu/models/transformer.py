@@ -985,41 +985,102 @@ def _moe_dense(x, w, top, layer, cfg):
     return jnp.einsum("bsed,bse->bsd", y, combine)
 
 
+# Rows of one block of the held experts' products. XLA's TPU ragged dot
+# takes its row tile from the number of rows it is given, the largest power
+# of two that divides it and 512 at most (``ragged_dot_tiling`` in the
+# compiled text); every (expert, tile) pair it visits multiplies a whole
+# tile, and a tile of no expert's rows is not visited: rows behind the
+# groups cost nothing. Where a chip holds an eighth of the experts a group
+# is 16-20 rows of a chunk, so under tiles of 512 (any multiple of 512 rows:
+# a chunk's 5120 or 4096, or a block of 1024) the product is bound by the
+# MXU at 25 rows multiplied for one. 3 x 256 rows take tiles of 256 and
+# hold a chunk's held eighth (640 +- 24 of 5120, 512 +- 21 of 4096) in one
+# block. Device ms on a TPU v5 lite (PERF.md section 6, PR 38). One product
+# [rows, 3072] x [32, 3072, 1024], 640 rows in 32 groups: 0.704 at 5120
+# rows, 0.700 at 1024, 0.412 at 640, 896, 1152 and 5248. One expert layer of
+# a 512-token chunk, Laguna's / dots3's shape, 640 / 521 pairs held: the
+# whole tail 2.52 / 5.70; blocks of 384 2.19 / 4.55, 640 2.08 / 4.67, 768
+# 2.04 / 4.73, 896 2.14 / 4.66, 1024 the whole tail's, 1152 2.21 / 4.74,
+# 1280 2.00 / 5.03.
+_HELD_BLOCK = 768
+
+
+def _expert_products(rows, sizes, layer, cfg):
+    """The experts' feed-forward of ``rows [R, D]`` sorted by expert,
+    ``sizes [E]`` rows each: one ``jax.lax.ragged_dot`` per projection."""
+    dt = cfg.compute_dtype
+    h = jax.lax.ragged_dot(rows, layer["w_in"].astype(dt), sizes)
+    if cfg.ffn == "swiglu":
+        gate = jax.lax.ragged_dot(rows, layer["w_gate"].astype(dt), sizes)
+        h = jax.nn.silu(gate) * h
+    else:
+        h = jax.nn.gelu(h)
+    return jax.lax.ragged_dot(h, layer["w_out"].astype(dt), sizes)
+
+
+def _held_blocks(flat, order, sizes, layer, cfg):
+    """The products of the sorted pairs that precede ``sizes.sum()``, a
+    block of ``_HELD_BLOCK`` rows at a time: -> (``[T*k, D]`` with zero rows
+    behind them, the rows multiplied). The count stays on the device and
+    every block that holds a pair runs (all of them where every pair is
+    held): nothing is dropped. The last block is moved back to end with the
+    rows, so a few rows may be multiplied twice, to the same result. Not
+    differentiable in reverse (the trip count is traced)."""
+    total, block = order.shape[0], _HELD_BLOCK
+    n = sizes.sum()
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+
+    def one(i, y):
+        lo = jnp.minimum(i * block, total - block)
+        mine = jnp.clip(ends, lo, lo + block) - jnp.clip(starts, lo, lo + block)
+        at = jax.lax.dynamic_slice_in_dim(order, lo, block)
+        out = _expert_products(flat[at // cfg.top_k], mine, layer, cfg)
+        # A row of no group holds whatever was there.
+        out = jnp.where((lo + jnp.arange(block) < n)[:, None], out, 0)
+        return jax.lax.dynamic_update_slice_in_dim(y, out, lo, 0)
+
+    blocks = (n + block - 1) // block
+    y = jax.lax.fori_loop(
+        0, blocks, one, jnp.zeros((total, flat.shape[1]), cfg.compute_dtype))
+    return y, blocks * block
+
+
 def _moe_grouped(x, w, top, layer, cfg):
     """Grouped dispatch: the ``tokens x top_k`` routed (token, expert) pairs
     sorted by expert, one ``jax.lax.ragged_dot`` per projection over the
     sorted rows, the results unsorted and summed per token under the
-    routing weights. Work and (for few tokens) weight bytes follow the
-    pairs and the experts they touch; nothing is dropped and no capacity
-    exists. On a TPU each product is one ``ragged-dot`` instruction of the
-    compiled program (a Mosaic kernel XLA brings): the name a trace reads
-    (docs/observability.md)."""
-    dt = cfg.compute_dtype
+    routing weights: -> (output, the rows the products ran over). Work and
+    (for few tokens) weight bytes follow the pairs and the experts they
+    touch; nothing is dropped and no capacity exists. On a TPU each product
+    is one ``ragged-dot`` instruction of the compiled program (a Mosaic
+    kernel XLA brings): the name a trace reads (docs/observability.md).
+
+    Under ``experts_held`` the pairs routed to an expert elsewhere sort
+    behind every group and belong to none: their weight is zero, and of
+    more rows than one block the products run over the blocks that hold a
+    pair (:func:`_held_blocks`; the same instructions, inside a ``while``)."""
     B, S, D = x.shape
     k, E = cfg.top_k, cfg.n_held
     if cfg.experts_held:
-        # Pairs routed to an expert elsewhere sort behind every group and
-        # belong to none: their rows are multiplied by nothing, and their
-        # weight is zero.
         top, held = _held(top, cfg)
         w = jnp.where(held, w, 0.0)
     experts = top.reshape(-1)                                     # [T*k]
     order = jnp.argsort(experts, stable=True)
-    rows = x.reshape(-1, D)[order // k]                           # [T*k, D]
+    flat, ran = x.reshape(-1, D), experts.shape[0]
+    blocked = bool(cfg.experts_held) and ran > _HELD_BLOCK
+    rows = None if blocked else flat[order // k]                  # [T*k, D]
     sizes = jnp.bincount(experts, length=E).astype(jnp.int32)
     with jax.named_scope("experts"):
-        h = jax.lax.ragged_dot(rows, layer["w_in"].astype(dt), sizes)
-        if cfg.ffn == "swiglu":
-            gate = jax.lax.ragged_dot(rows, layer["w_gate"].astype(dt),
-                                      sizes)
-            h = jax.nn.silu(gate) * h
+        if blocked:
+            y, ran = _held_blocks(flat, order, sizes, layer, cfg)
         else:
-            h = jax.nn.gelu(h)
-        y = jax.lax.ragged_dot(h, layer["w_out"].astype(dt), sizes)
-    if cfg.experts_held:      # a row of no group holds whatever was there
-        y = jnp.where((jnp.arange(y.shape[0]) < sizes.sum())[:, None], y, 0)
+            y = _expert_products(rows, sizes, layer, cfg)
+            if cfg.experts_held:      # as in a block: rows of no group
+                y = jnp.where(
+                    (jnp.arange(ran) < sizes.sum())[:, None], y, 0)
     y = y[jnp.argsort(order)].reshape(B, S, k, D)
-    return jnp.einsum("bskd,bsk->bsd", y, w.astype(dt))
+    return jnp.einsum("bskd,bsk->bsd", y, w.astype(cfg.compute_dtype)), ran
 
 
 def _moe_ffn(x, layer, cfg, mesh=None, valid=None):
@@ -1030,15 +1091,18 @@ def _moe_ffn(x, layer, cfg, mesh=None, valid=None):
     ``model`` axes and which is right, at ``n_experts / top_k`` times the
     work: a grouped product under an expert-sharded mesh is not written.
 
-    The routing is ``{"top": [b, s, k] experts, "counts": [n_held]}``, the
-    (token, expert) pairs each expert held here received from the rows
-    ``valid [b, s]`` marks (all by default): what ``serve_stats()["moe"]``
-    counts. A shared expert (``shared_experts``) is added for every row."""
+    The routing is ``{"top": [b, s, k] experts, "counts": [n_held], "rows":
+    []}``: the (token, expert) pairs each expert held here received from the
+    rows ``valid [b, s]`` marks (all by default), and the rows the experts'
+    products ran over (grouped: the sorted pairs given to them; dense: every
+    token for every expert): what ``serve_stats()["moe"]`` counts. A shared
+    expert (``shared_experts``) is added for every row."""
     w, top = _route(x, layer, cfg)
     if mesh is None:
-        y = _moe_grouped(x, w, top, layer, cfg)
+        y, rows = _moe_grouped(x, w, top, layer, cfg)
     else:
         y = _moe_dense(x, w, top, layer, cfg)
+        rows = top[..., 0].size * cfg.n_held
     if cfg.shared_experts:
         y = y + _ffn(x, layer["shared"], cfg)
     # Counted: the pairs this device computes (all of them, or those of the
@@ -1047,7 +1111,8 @@ def _moe_ffn(x, layer, cfg, mesh=None, valid=None):
     hit = jax.nn.one_hot(mine, cfg.n_held, dtype=jnp.int32)     # [b,s,k,E]
     if valid is not None:
         hit = hit * valid[..., None, None]
-    return y, {"top": top, "counts": hit.sum((0, 1, 2))}
+    return y, {"top": top, "counts": hit.sum((0, 1, 2)),
+               "rows": jnp.asarray(rows, jnp.int32)}
 
 
 # The measured flash-vs-gather crossover expressed as LIVE score

@@ -345,7 +345,7 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
     multi-head layer of a described kind through ``grouped(a, q, k, v, k_c,
     v_c)`` (:func:`_grouped_layer`, the same). ->
     (ck, cv, x after the final norm, what the layers report or None:
-    ``counts`` and ``top`` of the expert layers, ``selected`` of the
+    ``counts``, ``rows`` and ``top`` of the expert layers, ``selected`` of the
     selecting ones, each stacked over those layers)."""
     ck, cv = list(cache["k"]), list(cache["v"])
     reports = []
@@ -380,11 +380,20 @@ def _result(ck, cv, logits, moe, mesh, cfg):
     """What every program returns: the cache and float32 logits; for a
     model with experts also its routing, ``{"counts": [expert layers, E]
     pairs each expert held here received from the live rows, "top": [expert
-    layers, B, S, k]}`` (the loop fetches ``counts`` with the tokens; ``top``
-    stays on the device unless someone asks), and for one that selects its
-    keys ``"selected": [selecting layers, B, S, k]`` in the same dict."""
+    layers, B, S, k]}`` (``top`` stays on the device unless someone asks),
+    and behind it what the loop fetches with the tokens: ``[expert layers,
+    E + 1]``, the counts and in the last column the rows the experts'
+    products ran over. For a model that selects its keys the routing has
+    ``"selected": [selecting layers, B, S, k]``. Every entry of the routing
+    but ``counts`` is ``[layers, slot, position, ..]``: the benchmark's check
+    indexes them so, which is why the rows travel beside the dict."""
     out = (_cache_out(ck, cv, mesh, cfg), logits.astype(jnp.float32))
-    return out if moe is None else out + (moe,)
+    if moe is None:
+        return out
+    rows = moe.pop("rows", None)
+    if rows is None:
+        return out + (moe,)
+    return out + (moe, jnp.concatenate([moe["counts"], rows[:, None]], 1))
 
 
 def _no_latent(cfg, what):
@@ -651,7 +660,8 @@ def greedy(logits):
 
 @jax.jit
 def greedy_with_counts(logits, counts):
-    """The greedy tokens and a program's expert counts as ONE int32 vector
-    (tokens first), so that the host gets both with one transfer: a second
-    array costs a second round trip a boundary."""
+    """The greedy tokens and what a program counted of its experts (counts
+    and rows, :func:`_result`) as ONE int32 vector (tokens first), so that
+    the host gets both with one transfer: a second array costs a second
+    round trip a boundary."""
     return jnp.concatenate([greedy(logits).ravel(), counts.ravel()])

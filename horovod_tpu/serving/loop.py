@@ -100,6 +100,10 @@ PADDED_PREFILL_MAX_KV = 1024
 # Tokens per chunk there (where only prefix-cache suffixes chunk-fill, two
 # pages): some hundreds, so that one pass over the weights serves many.
 LONG_PREFILL_CHUNK = 512
+# What ``serve_stats()["moe"]`` sums by program kind: the (token, expert)
+# pairs of the experts held here, program calls, (layer, expert) weights
+# read, and the sorted rows the experts' products ran over.
+_MOE_COUNTS = ("pairs", "calls", "expert_reads", "rows")
 
 
 @dataclasses.dataclass
@@ -301,7 +305,7 @@ class ServeLoop:
         self._moe_pending = []
         self._moe_load = np.zeros((max(len(cfg.moe_layers), 1),
                                    max(cfg.n_held, 1)), np.int64)
-        self.moe_stats = {"pairs": {}, "expert_reads": {}, "calls": {}}
+        self.moe_stats = {name: {} for name in _MOE_COUNTS}
         # Latent layers: the (query, key) pairs scored, selected and
         # windowed, by program kind, from the positions alone (as
         # ``kv_pages_read`` is: host arithmetic, nothing fetched).
@@ -343,7 +347,7 @@ class ServeLoop:
         reads its tokens, and the counts of a model with experts wait for
         the next step."""
         self.cache, logits, *routing = fn(self.params, self.cache, *args)
-        counts = routing[0].get("counts") if routing else None
+        counts = routing[1] if len(routing) > 1 else None
         if not fetch:
             if counts is not None:
                 self._moe_pending.append((kind, counts))
@@ -355,7 +359,8 @@ class ServeLoop:
 
     def _fetch(self, step):
         """The step's greedy tokens on the host, and in the same transfer
-        its expert counts and those of the chunks before it."""
+        its expert counts (with the rows its products ran over, the last
+        column) and those of the chunks before it."""
         if step.counts is None:
             return np.asarray(step.out)
         packed, earlier = jax.device_get(
@@ -363,9 +368,11 @@ class ServeLoop:
         n_tokens = packed.size - step.counts.size
         mine = packed[n_tokens:].reshape(step.counts.shape)
         for kind, c in [*zip((k for k, _ in step.earlier), earlier),
-                        (step.kind, mine)]:            # c: [layers, E]
+                        (step.kind, mine)]:            # c: [layers, E + 1]
+            c, rows = c[:, :-1], c[:, -1]
             for name, n in (("pairs", c.sum()), ("calls", 1),
-                            ("expert_reads", np.count_nonzero(c))):
+                            ("expert_reads", np.count_nonzero(c)),
+                            ("rows", rows.sum())):
                 by_kind = self.moe_stats[name]
                 by_kind[kind] = by_kind.get(kind, 0) + int(n)
             self._moe_load += c
@@ -459,7 +466,7 @@ class ServeLoop:
                                    *slots(B, self.spec_tokens + 1)))
         # What the warm-up routed is not traffic.
         self._moe_load[:] = 0
-        self.moe_stats = {"pairs": {}, "expert_reads": {}, "calls": {}}
+        self.moe_stats = {name: {} for name in _MOE_COUNTS}
 
     # -- per-request engine calls ----------------------------------------
 
@@ -900,6 +907,8 @@ class ServeLoop:
             steps = ms["calls"].get("decode", 0) * len(self.cfg.moe_layers)
             snap["moe"] = {
                 **{name: dict(by_kind) for name, by_kind in ms.items()},
+                "row_fill": {kind: ms["pairs"][kind] / rows
+                             for kind, rows in ms["rows"].items() if rows},
                 "experts_touched_mean": (
                     ms["expert_reads"]["decode"] / steps if steps else 0.0),
                 "load_max_over_mean": (float(load.max() / load.mean())

@@ -397,3 +397,158 @@ def test_the_cell_s_cache_at_the_published_widths():
     rings = 6 * 2 * 2049 * 16 * 1024 * 2
     assert kv_cache.cache_bytes(cfg, geo) == full + rings
     assert 6.43e9 < full < 6.45e9 and 0.80e9 < rings < 0.81e9
+
+
+# ---- the held experts' products in blocks (PR 38) ---------------------------
+
+BLOCK = 16      # rows of a block here: the small shapes must loop
+
+
+def _small(model):
+    """(cfg, an expert layer's weights) of a small configuration: 4 of 16
+    experts held, 3 (Laguna) or 4 (dots3) a token."""
+    if model == "dots3":
+        from . import test_dots3 as other
+        cfg = other._cfg(other._config())
+        return cfg, other._params(cfg)["layers"][1]
+    cfg = _cfg(_config())
+    return cfg, _params(cfg)["layers"][1]
+
+
+def _whole_tail(x, w, top, layer, cfg):
+    """The parent's grouped products (PR 37), kept as the reference: every
+    sorted row given to one product a projection, the tail masked after."""
+    B, S, D = x.shape
+    k, E = cfg.top_k, cfg.n_held
+    if cfg.experts_held:
+        top, held = tfm._held(top, cfg)
+        w = jnp.where(held, w, 0.0)
+    experts = top.reshape(-1)
+    order = jnp.argsort(experts, stable=True)
+    rows = x.reshape(-1, D)[order // k]
+    sizes = jnp.bincount(experts, length=E).astype(jnp.int32)
+    h = jax.lax.ragged_dot(rows, layer["w_in"], sizes)
+    h = jax.nn.silu(jax.lax.ragged_dot(rows, layer["w_gate"], sizes)) * h
+    y = jax.lax.ragged_dot(h, layer["w_out"], sizes)
+    if cfg.experts_held:
+        y = jnp.where((jnp.arange(y.shape[0]) < sizes.sum())[:, None], y, 0)
+    y = y[jnp.argsort(order)].reshape(B, S, k, D)
+    return jnp.einsum("bskd,bsk->bsd", y, w)
+
+
+def _routed(cfg, tokens, n_held, seed=0):
+    """x, weights and experts ``[1, tokens, k]`` of which exactly ``n_held``
+    pairs go to an expert held here (4..7 of 16), in a random order."""
+    rng = np.random.default_rng(seed)
+    pairs = tokens * cfg.top_k
+    offset, count = cfg.experts_held
+    absent = np.setdiff1d(np.arange(16), np.arange(offset, offset + count))
+    top = np.concatenate([rng.integers(offset, offset + count, n_held),
+                          rng.choice(absent, pairs - n_held)])
+    top = rng.permutation(top).reshape(1, tokens, cfg.top_k)
+    x = rng.standard_normal((1, tokens, cfg.d_model)).astype(np.float32)
+    w = rng.uniform(0.1, 1.0, (1, tokens, cfg.top_k)).astype(np.float32)
+    return jnp.asarray(x), jnp.asarray(w), jnp.asarray(top, jnp.int32)
+
+
+@pytest.mark.parametrize("model", ["laguna", "dots3"])
+@pytest.mark.parametrize("held", ["eighth", "none", "all", "two_blocks",
+                                  "two_blocks_and_one"])
+def test_blocked_products_equal_the_whole_tail(monkeypatch, model, held):
+    """The products over the blocks that hold a pair give what one product
+    over every sorted row gave: whatever share of the pairs is held (all of
+    them runs every block: nothing is dropped), a count of whole blocks or
+    one more; ``rows`` is what the blocks multiplied."""
+    monkeypatch.setattr(tfm, "_HELD_BLOCK", BLOCK)
+    cfg, layer = _small(model)
+    pairs = 24 * cfg.top_k                  # 72 (not whole blocks) or 96
+    n = {"eighth": pairs // 8, "none": 0, "all": pairs,
+         "two_blocks": 2 * BLOCK, "two_blocks_and_one": 2 * BLOCK + 1}[held]
+    x, w, top = _routed(cfg, 24, n, seed=n)
+    got, rows = tfm._moe_grouped(x, w, top, layer, cfg)
+    want = _whole_tail(x, w, top, layer, cfg)
+    assert int(rows) == -(-n // BLOCK) * BLOCK
+    if n:
+        assert _rel(got, want) < 1e-6
+    else:
+        assert not np.asarray(got).any() and not np.asarray(want).any()
+
+
+def _lowered(fn, layer, cfg, x, w, top):
+    def products(x, w, top, layer):
+        out = fn(x, w, top, layer, cfg)
+        return out[0] if isinstance(out, tuple) else out
+    return jax.jit(products).lower(x, w, top, layer).as_text()
+
+
+def _control_flow(text):
+    return sum(text.count(f"stablehlo.{op}") for op in ("while", "case", "if"))
+
+
+@pytest.mark.parametrize("model", ["laguna", "dots3"])
+def test_a_chunk_loops_and_a_decode_step_does_not(monkeypatch, model):
+    """Under ``jax.jit``: a chunk's rows (more than a block) lower to ONE
+    loop around the products, a decode step's (a block or fewer) to the
+    single product with no control flow at all; and the cells' real shapes
+    fall on those sides of the real block."""
+    assert 512 * 8 > tfm._HELD_BLOCK >= 32 * 10     # both cells' k and slots
+    assert tfm._HELD_BLOCK % 512                    # never tiles of 512
+    monkeypatch.setattr(tfm, "_HELD_BLOCK", BLOCK)
+    cfg, layer = _small(model)
+    chunk = _routed(cfg, CHUNK, CHUNK * cfg.top_k // 4)
+    step = _routed(cfg, 4, 4)                       # four slots, a token each
+    assert chunk[2].size > BLOCK >= step[2].size
+    text = _lowered(tfm._moe_grouped, layer, cfg, *chunk)
+    assert _control_flow(text) == 1 and text.count("stablehlo.while") == 1
+    text = _lowered(tfm._moe_grouped, layer, cfg, *step)
+    assert _control_flow(text) == 0
+    for args in (chunk, step):      # the same three products either way
+        jaxpr = jax.make_jaxpr(
+            lambda *a: tfm._moe_grouped(*a, layer, cfg)[0])(*args)
+        assert str(jaxpr).count(" = ragged_dot") == 3
+    assert text == _lowered(_whole_tail, layer, cfg, *step)
+    got = jax.jit(lambda *a: tfm._moe_grouped(*a, layer, cfg)[0])(*chunk)
+    assert _rel(got, _whole_tail(*chunk, layer, cfg)) < 1e-6
+
+
+def test_without_experts_held_the_program_is_the_parent_s(monkeypatch):
+    """A configuration that holds every expert (OLMoE, every training
+    model) lowers to the text it lowered to before: the same instructions
+    in the same order, whatever the block."""
+    monkeypatch.setattr(tfm, "_HELD_BLOCK", BLOCK)
+    held = _cfg(_config(experts_held=[0, 16]))
+    cfg = dataclasses.replace(held, experts_held=())
+    assert cfg.n_held == 16
+    layer = _params(held)["layers"][1]
+    x, w, top = _routed(held, 24, 24 * cfg.top_k)
+    text = _lowered(tfm._moe_grouped, layer, cfg, x, w, top)
+    assert text == _lowered(_whole_tail, layer, cfg, x, w, top)
+    assert _control_flow(text) == 0
+    assert int(tfm._moe_grouped(x, w, top, layer, cfg)[1]) == top.size
+
+
+def test_serve_stats_count_the_rows_the_products_ran_over(monkeypatch):
+    """``serve_stats()["moe"]``: ``rows`` by program kind and ``row_fill`` =
+    ``pairs / rows``. A decode step's single product runs over every routed
+    row, so its fill is the held share of the live rows; a chunk's blocks
+    run over fewer rows than were routed, so its fill is well over that."""
+    monkeypatch.setattr(tfm, "_HELD_BLOCK", BLOCK)
+    cfg = _cfg(_config())
+    params = _params(cfg)
+    loop = _loop(cfg, params, max_batch=4)
+    reqs = [Request(rid=i, prompt=_tokens(19 + i, seed=i), max_new_tokens=6,
+                    arrival_t=1e-6) for i in range(4)]
+    loop.run(reqs)
+    moe = serve_loop.serve_stats()["moe"]
+    layers, k = len(cfg.moe_layers), cfg.top_k
+    routed = {"chunk": CHUNK * k, "decode": 4 * k}  # rows a layer a call
+    assert routed["chunk"] > BLOCK >= routed["decode"]
+    assert moe["rows"]["decode"] == moe["calls"]["decode"] * layers * 12
+    assert moe["rows"]["chunk"] % BLOCK == 0
+    assert 0 < moe["rows"]["chunk"] < moe["calls"]["chunk"] * layers * 24
+    for kind in ("chunk", "decode"):
+        assert moe["row_fill"][kind] == moe["pairs"][kind] / moe["rows"][kind]
+        assert 0 < moe["row_fill"][kind] <= 1
+    share = moe["pairs"]["chunk"] / (moe["calls"]["chunk"] * layers * 24)
+    assert moe["row_fill"]["chunk"] > 1.3 * share
+    assert moe["row_fill"]["decode"] <= 0.5         # 4 of 16 experts held

@@ -126,14 +126,14 @@ def _serve_rows(cfg, params, prompt, geo, route, n_decode=4):
     if route == "prefill":
         toks = np.zeros(geo.max_kv, np.int32)
         toks[:n] = prompt
-        cache, lg, _ = engine.make_prefill(cfg, geo)(
+        cache, lg, *_ = engine.make_prefill(cfg, geo)(
             params, cache, toks, np.int32(n), table)
     elif route == "bprefill":
         toks = np.zeros((2, geo.max_kv), np.int32)
         toks[1, :n] = prompt
         tables = np.zeros((2, mb), np.int32)
         tables[1] = table
-        cache, lg, _ = engine.make_batched_prefill(cfg, geo)(
+        cache, lg, *_ = engine.make_batched_prefill(cfg, geo)(
             params, cache, toks, np.asarray([1, n], np.int32), tables,
             np.asarray([False, True]))
         lg = lg[1]
@@ -143,7 +143,7 @@ def _serve_rows(cfg, params, prompt, geo, route, n_decode=4):
             end = min(start + 16, n)
             toks = np.zeros((1, 16), np.int32)
             toks[0, :end - start] = prompt[start:end]
-            cache, lg, _ = chunk(params, cache, toks,
+            cache, lg, *_ = chunk(params, cache, toks,
                                  np.asarray([start], np.int32), table[None],
                                  np.ones(1, bool))
         lg = lg[0, end - start - 1]
@@ -153,7 +153,7 @@ def _serve_rows(cfg, params, prompt, geo, route, n_decode=4):
     tables[0] = table
     for _ in range(n_decode):
         seq.append(int(np.argmax(rows[-1])))
-        cache, lg, moe = decode(
+        cache, lg, moe, ran = decode(
             params, cache, np.asarray([seq[-1], 0], np.int32),
             np.asarray([len(seq) - 1, 0], np.int32), tables,
             np.asarray([True, False]))
@@ -161,6 +161,11 @@ def _serve_rows(cfg, params, prompt, geo, route, n_decode=4):
     # One live slot of one token: top_k pairs a layer, none from the other.
     assert np.asarray(moe["counts"]).sum(1).tolist() == \
         [cfg.top_k] * cfg.n_layers
+    # ... and the products ran over both slots' pairs: the last column of
+    # what the loop fetches, behind the counts.
+    ran = np.asarray(ran)
+    assert (ran[:, :-1] == np.asarray(moe["counts"])).all()
+    assert ran[:, -1].tolist() == [2 * cfg.top_k] * cfg.n_layers
     return np.stack(rows), seq
 
 
@@ -190,7 +195,8 @@ def test_routed_product(case):
     layer = _params(cfg)["layers"][0]
     x = jax.random.normal(jax.random.PRNGKey(5), (3, 10, cfg.d_model))
     w, top = tfm._route(x, layer, cfg)
-    got = tfm._moe_grouped(x, w, top, layer, cfg)
+    got, rows = tfm._moe_grouped(x, w, top, layer, cfg)
+    assert rows == 3 * 10 * cfg.top_k       # every pair, in one product
     if case == "one_hot_sum":
         want = jnp.zeros_like(x)
         for e in range(cfg.n_experts):
@@ -201,6 +207,7 @@ def test_routed_product(case):
     elif case == "pair_counts":
         _, routing = tfm._moe_ffn(x, layer, cfg)
         assert int(routing["counts"].sum()) == 3 * 10 * cfg.top_k
+        assert int(routing["rows"]) == 3 * 10 * cfg.top_k
         assert np.asarray(routing["top"]).shape == (3, 10, cfg.top_k)
         valid = jnp.zeros((3, 10), bool).at[1].set(True)
         _, some = tfm._moe_ffn(x, layer, cfg, valid=valid)
@@ -209,7 +216,7 @@ def test_routed_product(case):
         perm = np.random.default_rng(3).permutation(30)
         xp = x.reshape(1, 30, -1)[:, perm]
         wp, tp = tfm._route(xp, layer, cfg)
-        back = tfm._moe_grouped(xp, wp, tp, layer, cfg)[0][np.argsort(perm)]
+        back = tfm._moe_grouped(xp, wp, tp, layer, cfg)[0][0][np.argsort(perm)]
         assert _rel(back, got.reshape(30, -1)) < 1e-5
     else:
         assert _rel(tfm._moe_dense(x, w, top, layer, cfg), got) < 1e-5
@@ -285,6 +292,12 @@ def test_serve_loop_fills_a_wide_cache_by_chunks(monkeypatch):
     assert moe["pairs"]["chunk"] == chunks * 32 * cfg.top_k * cfg.n_layers
     tokens = sum(len(r.generated) for r in finished)
     assert moe["pairs"]["decode"] == (tokens - 5) * cfg.top_k * cfg.n_layers
+    # Every expert is held here: the products run over every routed row, a
+    # chunk's all pairs, a decode step's those of its four slots.
+    assert moe["rows"]["chunk"] == moe["pairs"]["chunk"]
+    assert moe["row_fill"]["chunk"] == 1.0
+    assert moe["rows"]["decode"] == (
+        moe["calls"]["decode"] * 4 * cfg.top_k * cfg.n_layers)
     assert 1.0 <= moe["experts_touched_mean"] <= cfg.n_experts
     assert moe["load_max_over_mean"] >= 1.0
 

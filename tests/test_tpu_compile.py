@@ -447,6 +447,11 @@ def test_layered_cell_programs_fit_one_chip(topo, as_on_the_chip):
             assert len(calls) == n, (name, kernel)
         assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
             == 3 * len(cfg.moe_layers)
+        # The held experts' products: a chunk's in blocks whose rows the
+        # compiler tiles by 256 (``transformer._HELD_BLOCK`` rests on that
+        # rule), a decode step's 128 rows in one product as before.
+        assert set(re.findall(r'ragged_dot_tiling="(\d+),', text)) \
+            == {"256" if name == "chunk" else "128"}
         # Nothing float32 of the per-head score block's size, and no sort
         # as long as a row of scores.
         rows = args[0].shape[0] * (args[0].shape[1] if name == "chunk" else 1)
@@ -529,6 +534,11 @@ def test_grouped_cell_programs_fit_one_chip(topo, as_on_the_chip):
             assert len(calls) == n, (name, kernel)
         assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
             == 3 * len(cfg.moe_layers)
+        # The held experts' products: a chunk's in blocks whose rows the
+        # compiler tiles by 256 (``transformer._HELD_BLOCK`` rests on that
+        # rule), a decode step's 320 rows in one product as before.
+        assert set(re.findall(r'ragged_dot_tiling="(\d+),', text)) \
+            == {"256" if name == "chunk" else "64"}
         # No float array spans a slot's max_kv positions: neither gathered
         # pages nor a query block's scores over them.
         for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", text):
