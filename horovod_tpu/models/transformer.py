@@ -55,6 +55,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..observability import scopes
+
 
 def _round_up(n, m):
     return -(-n // m) * m
@@ -550,14 +552,15 @@ def filter_specs(specs, mesh):
 # ---------------------------------------------------------------------------
 # Forward
 
-# The named scopes below (embed, layer_norm, attention, mlp, loss; and grad,
-# grad_reduce, optimizer in parallel/data_parallel.make_train_step) are
-# metadata only: they reach every HLO operation's op_name, so XProf's and
-# TensorBoard's op views group a step by them (docs/observability.md). They
-# change no instruction and no program name.
+# The named scopes below (embed, layer_norm / rms_norm, attention, mlp with
+# experts inside it, loss, head; and grad, grad_reduce, optimizer in
+# parallel/data_parallel.make_train_step) are metadata only: they reach every
+# HLO operation's op_name, so a step's device time reads by scope
+# (docs/observability.md). They change no instruction and no program name;
+# observability/scopes.py is the one list of their names.
 
 def _layer_norm(x, p, eps=1e-5):
-    with jax.named_scope("layer_norm"):
+    with jax.named_scope(scopes.LAYER_NORM):
         mu = jnp.mean(x, -1, keepdims=True)
         var = jnp.var(x, -1, keepdims=True)
         y = (x - mu) * jax.lax.rsqrt(var + eps)
@@ -566,7 +569,7 @@ def _layer_norm(x, p, eps=1e-5):
 
 def _rms_norm(x, p, eps, axes=(-1,)):
     """``x * rsqrt(mean(x^2) + eps) * scale`` over ``axes``, in float32."""
-    with jax.named_scope("rms_norm"):
+    with jax.named_scope(scopes.RMS_NORM):
         xf = x.astype(jnp.float32)
         y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axes, keepdims=True) + eps)
         return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
@@ -1071,7 +1074,7 @@ def _moe_grouped(x, w, top, layer, cfg):
     blocked = bool(cfg.experts_held) and ran > _HELD_BLOCK
     rows = None if blocked else flat[order // k]                  # [T*k, D]
     sizes = jnp.bincount(experts, length=E).astype(jnp.int32)
-    with jax.named_scope("experts"):
+    with jax.named_scope(scopes.EXPERTS):
         if blocked:
             y, ran = _held_blocks(flat, order, sizes, layer, cfg)
         else:
@@ -1213,7 +1216,7 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
     a = cfg.attn_of(li)
     selected = None
     h = _norm(x, layer["ln1"], cfg)
-    with jax.named_scope("attention"):
+    with jax.named_scope(scopes.ATTENTION):
         if a is None:
             q, k, v = _qkv(h, layer, cfg, positions)
             out = jnp.einsum("bshk,hkd->bsd", attend(q, k, v),
@@ -1232,7 +1235,7 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
             out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
         x = x + _constrain(out, out_spec)
     h = _norm(x, layer["ln2"], cfg)
-    with jax.named_scope("mlp"):
+    with jax.named_scope(scopes.MLP):
         if cfg.is_moe(li):
             y, routing = _moe_ffn(h, layer, cfg, mesh, valid)
         else:
@@ -1299,6 +1302,14 @@ def head_weights(params, cfg):
     return params["embed"] if cfg.tie_embeddings else params["head"]
 
 
+def head_logits(x, params, cfg, spec="bsd,vd->bsv"):
+    """The final projection of ``x`` onto the vocabulary, in the compute
+    dtype (the trainer's loss projects inside its own scope, :func:`_nll`)."""
+    with jax.named_scope(scopes.HEAD):
+        return jnp.einsum(spec, x,
+                          head_weights(params, cfg).astype(cfg.compute_dtype))
+
+
 def forward(params, tokens, cfg: TransformerConfig, mesh=None,
             return_hidden=False):
     """tokens [B, S] int32 → logits [B, S, vocab] (compute dtype), or the
@@ -1319,7 +1330,7 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None,
         seq_spec = full_spec = None
 
     B, S = tokens.shape
-    with jax.named_scope("embed"):
+    with jax.named_scope(scopes.EMBED):
         x = add_positions(embed_tokens(params, tokens, cfg), params, cfg)
     x = _constrain(x, seq_spec)
 
@@ -1336,18 +1347,14 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None,
     x = _norm(x, params["final_ln"], cfg)
     if return_hidden:
         return x
-    logits = jnp.einsum("bsd,vd->bsv", x,
-                        head_weights(params, cfg).astype(dt))
-    return logits
+    return head_logits(x, params, cfg)
 
 
 def _nll(hidden, targets, embed):
     """-log p(target) per position from pre-projection hidden states."""
-    with jax.named_scope("loss"):
-        logits = jnp.einsum("bsd,vd->bsv", hidden,
-                            embed.astype(hidden.dtype))
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-        return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+    logits = jnp.einsum("bsd,vd->bsv", hidden, embed.astype(hidden.dtype))
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+    return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
 
 
 def loss_fn(params, batch, cfg: TransformerConfig, mesh=None):
@@ -1361,24 +1368,25 @@ def loss_fn(params, batch, cfg: TransformerConfig, mesh=None):
     targets = tokens[:, 1:]
     C = cfg.loss_chunk
     S = targets.shape[1]
-    head = head_weights(params, cfg)
-    if not C or S <= C:
-        hidden = forward(params, tokens[:, :-1], cfg, mesh=mesh,
-                         return_hidden=True)
-        return jnp.mean(_nll(hidden, targets, head))
-
-    if S % C != 0:
+    if C and S > C and S % C != 0:
         raise ValueError(f"seq len {S} must divide by loss_chunk {C}")
+    head = head_weights(params, cfg)
     hidden = forward(params, tokens[:, :-1], cfg, mesh=mesh,
                      return_hidden=True)
-    B, _, d = hidden.shape
-    h_chunks = hidden.reshape(B, S // C, C, d).swapaxes(0, 1)
-    t_chunks = targets.reshape(B, S // C, C).swapaxes(0, 1)
+    # Everything after the hidden states is the loss's: the projection, the
+    # softmax, the mean, and the scan that walks the chunks.
+    with jax.named_scope(scopes.LOSS):
+        if not C or S <= C:
+            return jnp.mean(_nll(hidden, targets, head))
 
-    def body(total, xs):
-        h, t = xs
-        return total + jnp.sum(_nll(h, t, head)), None
+        B, _, d = hidden.shape
+        h_chunks = hidden.reshape(B, S // C, C, d).swapaxes(0, 1)
+        t_chunks = targets.reshape(B, S // C, C).swapaxes(0, 1)
 
-    total, _ = jax.lax.scan(jax.checkpoint(body), jnp.float32(0.0),
-                            (h_chunks, t_chunks))
-    return total / (B * S)
+        def body(total, xs):
+            h, t = xs
+            return total + jnp.sum(_nll(h, t, head)), None
+
+        total, _ = jax.lax.scan(jax.checkpoint(body), jnp.float32(0.0),
+                                (h_chunks, t_chunks))
+        return total / (B * S)

@@ -18,6 +18,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 
+from ..observability import scopes
+
 
 def make_train_step(loss_fn, tx, mesh, data_axis="data", extra_reduce=None,
                     jit=True, donate=True, accum_steps=1,
@@ -200,21 +202,24 @@ def make_train_step(loss_fn, tx, mesh, data_axis="data", extra_reduce=None,
         check_vma=False,
     )
     def step(params, opt_state, batch):
-        # Named scopes are metadata for the profiler's op views
-        # (docs/observability.md); the compiled step is the same.
-        with jax.named_scope("grad"):
+        # Named scopes are metadata (docs/observability.md): the compiled
+        # step is the same, and every operation of it lies in one of the
+        # three phases, so its device time reads by phase and scope.
+        with jax.named_scope(scopes.GRAD):
             loss, grads = _shard_grad(params, batch)
-        with jax.named_scope("grad_reduce"):
+        with jax.named_scope(scopes.GRAD_REDUCE):
             if bucket_bytes > 0:
                 grads = _bucketed_grad_reduce(grads)
             else:
                 grads = jax.tree.map(_grad_reduce_all, grads)
             if extra_reduce is not None:
                 grads = extra_reduce(grads)
-        with jax.named_scope("optimizer"):
+        with jax.named_scope(scopes.OPTIMIZER):
             updates, opt_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
-        return params, opt_state, _pmean_all(loss)
+        with jax.named_scope(scopes.GRAD_REDUCE):   # a collective as well
+            loss = _pmean_all(loss)
+        return params, opt_state, loss
 
     if jit:
         step = jax.jit(step, donate_argnums=(0, 1) if donate else ())

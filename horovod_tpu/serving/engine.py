@@ -446,8 +446,7 @@ def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
                                  _masked(cfg, mask), valid, cfg=cfg,
                                  mesh=mesh)
         last = jnp.take(x[0], length - 1, axis=0)
-        logits = jnp.einsum("d,vd->v", last,
-                            tfm.head_weights(params, cfg).astype(dt))
+        logits = tfm.head_logits(last, params, cfg, "d,vd->v")
         return _result(ck, cv, logits, moe, mesh, cfg)
 
     return jax.jit(prefill, donate_argnums=(1,))
@@ -513,8 +512,7 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
         ck, cv, x, moe = _layers(params, cache, x, positions[:, None], write,
                                  attend, active[:, None], cfg=cfg, mesh=mesh,
                                  latent=latent, grouped=grouped)
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            tfm.head_weights(params, cfg).astype(dt))[:, 0]
+        logits = tfm.head_logits(x, params, cfg)[:, 0]
         return _result(ck, cv, logits, moe, mesh, cfg)
 
     return jax.jit(decode, donate_argnums=(1,))
@@ -607,9 +605,7 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
         ck, cv, x, moe = _chunk_forward(params, cache, tokens, positions,
                                         block_tables, active,
                                         cfg=cfg, geo=geo, mesh=mesh)
-        logits = jnp.einsum(
-            "bsd,vd->bsv", x,
-            tfm.head_weights(params, cfg).astype(cfg.compute_dtype))
+        logits = tfm.head_logits(x, params, cfg)
         return _result(ck, cv, logits, moe, mesh, cfg)
 
     chunk.__name__ = chunk.__qualname__ = name
@@ -644,9 +640,7 @@ def make_batched_prefill(cfg, geo, mesh=None, prefill_pad=None):
                                         cfg=cfg, geo=geo, mesh=mesh)
         last = jnp.take_along_axis(
             x, jnp.clip(lengths - 1, 0, pad - 1)[:, None, None], axis=1)
-        logits = jnp.einsum(
-            "bsd,vd->bsv", last,
-            tfm.head_weights(params, cfg).astype(cfg.compute_dtype))
+        logits = tfm.head_logits(last, params, cfg)
         return _result(ck, cv, logits[:, 0], moe, mesh, cfg)
 
     return jax.jit(bprefill, donate_argnums=(1,))
