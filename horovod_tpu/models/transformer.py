@@ -217,10 +217,12 @@ class TransformerConfig:
     # len and auto-shrunk to a divisor by the kernel).
     attn_block: int = 512
     # >0: the loss computes vocab logits + log-softmax in sequence chunks of
-    # this many positions (rematerialized), so the [S, vocab] float32 tensor
-    # never exists — at S=8k x 30k vocab that tensor plus its backward temps
-    # is gigabytes and caps single-chip sequence length before attention
-    # does. 0 = single full-sequence projection.
+    # this many positions, each chunk's gradients in the same trip of one
+    # scan (_chunked_nll), so the [S, vocab] float32 tensor never exists —
+    # at S=8k x 30k vocab that tensor plus its backward temps is gigabytes
+    # and caps single-chip sequence length before attention does. What the
+    # backward pass is handed instead: d(hidden) [B, S, d] and one float32
+    # [vocab, d]. 0 = single full-sequence projection.
     loss_chunk: int = 0
     # Rematerialize each transformer block in the backward pass
     # (jax.checkpoint): activation memory drops from O(n_layers * S * d *
@@ -1357,12 +1359,80 @@ def _nll(hidden, targets, embed):
     return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
 
 
+def _chunk_lse(h, t, head):
+    """One chunk of the chunked loss, in float32 -> (its logits [B, C, V],
+    their log-sum-exp [B, C, 1], the one-hot mask of its targets, its summed
+    -log p(target)). The target's logit is picked by comparing a vocabulary
+    iota with the target, a masked sum beside the softmax's own reductions,
+    where :func:`_nll` gathers: a gather's transpose is a scatter, which
+    inside a loop body runs one row at a time. The product is rounded to the
+    compute dtype and widened, as :func:`_nll` rounds it, so the chunked and
+    the full loss see the same logits."""
+    logits = jnp.einsum("bsd,vd->bsv", h, head).astype(jnp.float32)
+    top = jnp.max(logits, -1, keepdims=True)
+    lse = top + jnp.log(jnp.sum(jnp.exp(logits - top), -1, keepdims=True))
+    hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2) == t[..., None]
+    picked = jnp.sum(jnp.where(hit, logits, 0.0), -1)
+    return logits, lse, hit, jnp.sum(lse[..., 0] - picked)
+
+
+@jax.custom_vjp
+def _chunked_nll(h_chunks, t_chunks, head):
+    """Mean -log p(target) over chunks ``h_chunks`` [n, B, C, d] and
+    ``t_chunks`` [n, B, C], with ``head`` [V, d]. Called plainly (an
+    evaluation loop) it makes the logits and the sum, nothing else; under
+    differentiation :func:`_chunked_nll_fwd` takes its place."""
+    head_c = head.astype(h_chunks.dtype)
+
+    def body(total, xs):
+        return total + _chunk_lse(*xs, head_c)[-1], None
+
+    total, _ = jax.lax.scan(body, jnp.float32(0.0), (h_chunks, t_chunks))
+    return total / t_chunks.size
+
+
+def _chunked_nll_fwd(h_chunks, t_chunks, head):
+    """The loss and, in the same trip that makes a chunk's logits, its
+    gradients: ``dlogits = (softmax - onehot) / rows`` in the compute dtype,
+    ``dh = dlogits . head`` stacked by chunk, ``dhead += dlogits^T . h`` in
+    one float32 [V, d] carried through the scan. Nothing is kept to replay
+    and no second scan exists: the backward rule is a scaling."""
+    dt = h_chunks.dtype
+    head_c = head.astype(dt)
+    scale = 1.0 / t_chunks.size
+
+    def body(carry, xs):
+        total, dhead = carry
+        h, t = xs
+        logits, lse, hit, nll = _chunk_lse(h, t, head_c)
+        p = jnp.exp(logits - lse)
+        dlogits = (jnp.where(hit, p - 1.0, p) * scale).astype(dt)
+        dh = jnp.einsum("bsv,vd->bsd", dlogits, head_c)
+        dhead = dhead + jnp.einsum("bsv,bsd->vd", dlogits, h,
+                                   preferred_element_type=jnp.float32)
+        return (total + nll, dhead), dh
+
+    (total, dhead), dh = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.zeros(head.shape, jnp.float32)),
+        (h_chunks, t_chunks))
+    return total * scale, (dh, dhead.astype(head.dtype))
+
+
+def _chunked_nll_bwd(res, g):
+    dh, dhead = res
+    return (dh * g).astype(dh.dtype), None, (dhead * g).astype(dhead.dtype)
+
+
+_chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
+
+
 def loss_fn(params, batch, cfg: TransformerConfig, mesh=None):
     """Next-token cross-entropy. batch = {"tokens": [B, S+1] int32}.
 
     With ``cfg.loss_chunk > 0`` the vocab projection + log-softmax run per
-    sequence chunk under jax.checkpoint inside a scan (see the config
-    field's rationale); the chunked and full losses are identical.
+    sequence chunk inside one scan that also takes each chunk's gradients
+    (:func:`_chunked_nll`; see the config field's rationale); the chunked
+    and full losses are identical.
     """
     tokens = batch["tokens"]
     targets = tokens[:, 1:]
@@ -1382,11 +1452,4 @@ def loss_fn(params, batch, cfg: TransformerConfig, mesh=None):
         B, _, d = hidden.shape
         h_chunks = hidden.reshape(B, S // C, C, d).swapaxes(0, 1)
         t_chunks = targets.reshape(B, S // C, C).swapaxes(0, 1)
-
-        def body(total, xs):
-            h, t = xs
-            return total + jnp.sum(_nll(h, t, head)), None
-
-        total, _ = jax.lax.scan(jax.checkpoint(body), jnp.float32(0.0),
-                                (h_chunks, t_chunks))
-        return total / (B * S)
+        return _chunked_nll(h_chunks, t_chunks, head)

@@ -294,28 +294,73 @@ def test_strict_mode_and_masked_rows(n_blocks):
         and np.all(np.isfinite(np.asarray(g[2])))
 
 
-def test_chunked_loss_matches_full():
+def _assert_grads_close(g_full, g_chunk):
+    # bf16 compute: chunked summation reassociates, so grads agree to bf16
+    # rounding, not bitwise.
+    full, chunk = jax.tree.leaves(g_full), jax.tree.leaves(g_chunk)
+    assert len(full) == len(chunk)
+    for a, b in zip(full, chunk):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=1e-3, rtol=1e-2)
+
+
+@pytest.mark.parametrize("batch_rows", [1, 2])
+@pytest.mark.parametrize("n_chunks", [2, 4])
+@pytest.mark.parametrize("tied", [True, False])
+def test_chunked_loss_matches_full(tied, n_chunks, batch_rows):
     """cfg.loss_chunk computes the identical cross-entropy without ever
-    materializing the [S, vocab] float32 tensor (value and gradients)."""
+    materializing the [S, vocab] float32 tensor (value and every gradient
+    leaf), whether the head is the embedding or a weight of its own."""
     import dataclasses
 
     from horovod_tpu.models import transformer as tfm
 
-    cfg = tfm.tiny()
-    cfg_c = dataclasses.replace(cfg, loss_chunk=8)
+    cfg = dataclasses.replace(tfm.tiny(), tie_embeddings=tied)
+    cfg_c = dataclasses.replace(cfg, loss_chunk=32 // n_chunks)
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    assert ("head" in params) == (not tied)
     rng = np.random.default_rng(5)
     batch = {"tokens": jnp.asarray(
-        rng.integers(0, cfg.vocab_size, (2, 33)), jnp.int32)}
+        rng.integers(0, cfg.vocab_size, (batch_rows, 33)), jnp.int32)}
     l_full, g_full = jax.value_and_grad(tfm.loss_fn)(params, batch, cfg)
     l_chunk, g_chunk = jax.value_and_grad(tfm.loss_fn)(params, batch, cfg_c)
     np.testing.assert_allclose(float(l_full), float(l_chunk), rtol=1e-5)
-    # bf16 compute: chunked summation reassociates, so grads agree to bf16
-    # rounding, not bitwise.
-    for a, b in zip(jax.tree.leaves(g_full), jax.tree.leaves(g_chunk)):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   atol=1e-3, rtol=1e-2)
+    _assert_grads_close(g_full, g_chunk)
+    # called without differentiation (its own rule, no gradient built)
+    plain = jax.jit(tfm.loss_fn, static_argnums=2)
+    np.testing.assert_allclose(float(plain(params, batch, cfg_c)),
+                               float(plain(params, batch, cfg)), rtol=1e-5)
+
+
+def test_chunked_loss_matches_full_under_accumulation():
+    """Through ``make_train_step`` with two microbatches a step: the fused
+    rule's gradients are summed by the accumulation scan like any others."""
+    import dataclasses
+
+    import optax
+    from jax.sharding import Mesh
+
+    from horovod_tpu import parallel
+    from horovod_tpu.models import transformer as tfm
+
+    cfg = tfm.tiny()
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    tx = optax.sgd(1.0)               # the update IS the gradient
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(6)
+    batch = {"tokens": jnp.asarray(
+        rng.integers(0, cfg.vocab_size, (8, 33)), jnp.int32)}
+    out = {}
+    for chunk in (0, 8):
+        cfg_c = dataclasses.replace(cfg, loss_chunk=chunk)
+        step = parallel.make_train_step(
+            lambda p, b, c=cfg_c: tfm.loss_fn(p, b, c), tx, mesh,
+            accum_steps=2, donate=False)
+        new, _, loss = step(params, tx.init(params), batch)
+        out[chunk] = (float(loss), jax.tree.map(jnp.subtract, params, new))
+    np.testing.assert_allclose(out[0][0], out[8][0], rtol=1e-5)
+    _assert_grads_close(out[0][1], out[8][1])
 
 
 def test_flash_under_jit_and_vmapless_shapes():
