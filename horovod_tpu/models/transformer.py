@@ -34,7 +34,13 @@ layers, ``shared_experts`` an expert every token visits, ``router="sigmoid"``
 a sigmoid router with a selection bias, and ``experts_held`` the (offset,
 count) of the experts THIS device holds: the router scores all of them and
 the layer computes its own experts' part of the result (expert parallelism's
-one-device half). Nothing names a model.
+one-device half). ``state_space`` names layers whose mixer is a STATE-SPACE
+recurrence (:class:`StateSpaceMixer`, :func:`state_space_mix`: no attention,
+a constant state a sequence), ``layer_parts`` says which layers are a mixer
+ALONE or a feed-forward ALONE (one norm and one residual such a layer),
+``ffn="relu2"`` a feed-forward of ``relu(x W1)^2 W2``, and ``expert_latent``
+routed experts that work in a space narrower than the residual stream,
+between one shared down and one shared up projection. Nothing names a model.
 
 Written as an explicit parameter pytree + a mirrored PartitionSpec pytree
 (`param_specs`) instead of framework metadata, so the sharding story is
@@ -48,6 +54,7 @@ Reference parity anchors: `examples/pytorch` BERT fine-tune (model scale),
 """
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -157,6 +164,53 @@ class MultiHeadAttention:
 
 
 @dataclasses.dataclass(frozen=True)
+class StateSpaceMixer:
+    """One kind of state-space mixer that a configuration describes by name
+    (``TransformerConfig.state_space``): Mamba-2's selective state space
+    (arXiv:2405.21060). ``n_heads`` heads of ``head_dim`` channels, each with
+    a ``[head_dim, state_size]`` float32 state and one scalar decay; the
+    input and output maps ``B`` and ``C`` (``state_size`` wide) are shared by
+    the heads of one of ``n_groups`` groups (head ``h`` reads group ``h //
+    (n_heads / n_groups)``); a depthwise causal convolution over the last
+    ``conv_kernel`` tokens comes before. ``block`` (the source's
+    ``chunk_size``) is how many positions :func:`state_space_mix` multiplies
+    as one block; it changes no value. What a sequence carries from one
+    program run to the next: the last ``conv_kernel - 1`` inputs of the
+    convolution and the state."""
+    n_heads: int
+    head_dim: int
+    n_groups: int
+    state_size: int
+    conv_kernel: int = 4
+    block: int = 128
+
+    def __post_init__(self):
+        if self.n_heads % self.n_groups:
+            raise ValueError(f"{self.n_heads} heads do not divide over "
+                             f"{self.n_groups} groups")
+
+    @property
+    def d_inner(self):
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self):
+        """Channels the convolution runs over: x, B and C."""
+        return self.d_inner + 2 * self.n_groups * self.state_size
+
+    @property
+    def in_width(self):
+        """Columns of the in-projection: the gate z, x | B | C, a step a
+        head."""
+        return self.d_inner + self.conv_dim + self.n_heads
+
+    @property
+    def tail(self):
+        """Convolution inputs a sequence carries over."""
+        return self.conv_kernel - 1
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32768
     d_model: int = 1024
@@ -197,6 +251,19 @@ class TransformerConfig:
     # attention of THAT kind (its own head counts, ``head_dim``, window,
     # rotary rule and gate) and not of ``n_heads``.
     multihead: tuple = ()
+    # name -> StateSpaceMixer or its fields: a layer so named has a
+    # state-space mixer in the place of attention.
+    state_space: tuple = ()
+    # Per-layer halves: ``layer_parts[i]`` is "both" (attention, then a
+    # feed-forward: the default, and what a missing entry means), "mixer" (the
+    # mixer alone) or "ffn" (the feed-forward alone). A layer of one half has
+    # that half's norm and residual only.
+    layer_parts: tuple = ()
+    # >0: the routed experts work at this width. One projection ``d_model ->
+    # expert_latent`` before them and one back after their weighted sum, both
+    # shared by all experts; the router and the shared expert read the full
+    # width.
+    expert_latent: int = 0
     # The normed query and key/value latents times sqrt(d_model / rank).
     latent_rescale: bool = False
     # A sigmoid gate a head on the attention's output, from a d_model ->
@@ -208,6 +275,7 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     qk_norm: bool = False       # RMSNorm over the whole projected Q and K
     ffn: str = "gelu"           # | "swiglu": silu(x Wg) * (x Wu), then Wd
+    #                             | "relu2": relu(x W1)^2, then W2
     tie_embeddings: bool = True  # False: a separate output head "head"
     # "gather" (K/V all-gather, XLA logits) | "ring" (seq-sharded K/V over
     # ICI) | "flash" (fused pallas kernel, ops/pallas_attention.py) |
@@ -254,7 +322,17 @@ class TransformerConfig:
         object.__setattr__(self, "multihead", tuple(
             (name, a if isinstance(a, MultiHeadAttention)
              else MultiHeadAttention(**a)) for name, a in multihead))
+        state_space = self.state_space.items() \
+            if isinstance(self.state_space, dict) else self.state_space
+        object.__setattr__(self, "state_space", tuple(
+            (name, a if isinstance(a, StateSpaceMixer)
+             else StateSpaceMixer(**a)) for name, a in state_space))
         object.__setattr__(self, "layer_attn", tuple(self.layer_attn))
+        object.__setattr__(self, "layer_parts", tuple(self.layer_parts))
+        for part in self.layer_parts:
+            if part not in ("both", "mixer", "ffn"):
+                raise ValueError(f"layer_parts entries are 'both', 'mixer' "
+                                 f"or 'ffn', got {part!r}")
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
         if self.experts_held:
             offset, count = self.experts_held
@@ -264,7 +342,7 @@ class TransformerConfig:
                                  f"a range of the {self.n_experts} experts")
         for field, allowed in (("norm", ("layernorm", "rmsnorm")),
                                ("pos", ("learned", "rope")),
-                               ("ffn", ("gelu", "swiglu")),
+                               ("ffn", ("gelu", "swiglu", "relu2")),
                                ("router", ("softmax", "sigmoid"))):
             if getattr(self, field) not in allowed:
                 raise ValueError(f"{field} must be one of {allowed}, got "
@@ -290,20 +368,34 @@ class TransformerConfig:
         return jnp.dtype(self.dtype)
 
     def attn_of(self, li):
-        """Layer ``li``'s :class:`LatentAttention` or
-        :class:`MultiHeadAttention`, or None for the multi-head attention of
-        ``n_heads``."""
+        """Layer ``li``'s :class:`LatentAttention`,
+        :class:`MultiHeadAttention` or :class:`StateSpaceMixer`, or None for
+        the multi-head attention of ``n_heads``."""
         name = self.layer_attn[li] if li < len(self.layer_attn) else None
-        return dict(self.latent + self.multihead).get(name)
+        return dict(self.latent + self.multihead + self.state_space).get(name)
 
     @property
     def described(self):
-        """Whether layers are described by kind (``latent``, ``multihead``):
-        such a model is filled by chunks and its layers' caches differ."""
-        return bool(self.latent or self.multihead)
+        """Whether layers are described by kind (``latent``, ``multihead``,
+        ``state_space``, ``layer_parts``): such a model is filled by chunks
+        and its layers' caches differ."""
+        return bool(self.latent or self.multihead or self.state_space
+                    or self.layer_parts)
+
+    def _part(self, li):
+        return self.layer_parts[li] if li < len(self.layer_parts) else "both"
+
+    def has_mixer(self, li):
+        """Whether layer ``li`` has an attention or state-space half."""
+        return self._part(li) != "ffn"
+
+    def has_ffn(self, li):
+        """Whether layer ``li`` has a feed-forward half."""
+        return self._part(li) != "mixer"
 
     def is_moe(self, li):
-        return self.n_experts > 0 and li >= self.dense_layers
+        return (self.n_experts > 0 and li >= self.dense_layers
+                and self.has_ffn(li))
 
     @property
     def moe_layers(self):
@@ -363,10 +455,11 @@ def _norm_params(cfg, shape):
     return p
 
 
-def _ffn_params(k, cfg, lead, F):
+def _ffn_params(k, cfg, lead, F, D=None):
     """One feed-forward's matrices of width ``F`` from three keys (``lead``
-    = (experts,) for a stack of them)."""
-    D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
+    = (experts,) for a stack of them), reading and writing ``D`` dims
+    (``d_model`` by default; experts in a latent their latent's)."""
+    D, pdt = D or cfg.d_model, jnp.dtype(cfg.param_dtype)
     p = {"w_in": _dense_init(k[0], lead + (D, F), D, pdt),
          "w_out": _dense_init(k[1], lead + (F, D), F, pdt)}
     if cfg.ffn == "swiglu":
@@ -428,6 +521,40 @@ def _multihead_params(key, cfg, a: MultiHeadAttention):
     return p
 
 
+def _state_space_params(key, cfg, a: StateSpaceMixer):
+    """A state-space layer's parameters: the in-projection (z | x B C | a
+    step a head), the convolution's taps and bias, a head's step bias, log
+    decay rate and skip, the gated norm's scale, the out-projection. Steps
+    are drawn log-uniform over (0.001, 0.1) and decay rates uniform over (1,
+    16), as Mamba-2 initialises them, so that heads remember tens to
+    thousands of tokens."""
+    D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
+    k = jax.random.split(key, 5)
+    step = jnp.exp(jax.random.uniform(
+        k[3], (a.n_heads,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return {
+        "w_ssm_in": _dense_init(k[0], (D, a.in_width), D, pdt),
+        "conv_w": _dense_init(k[1], (a.conv_dim, a.conv_kernel),
+                              a.conv_kernel, pdt),
+        "conv_b": jnp.zeros((a.conv_dim,), pdt),
+        # softplus(dt_bias) = step
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
+        "a_log": jnp.log(jax.random.uniform(
+            k[4], (a.n_heads,), jnp.float32, 1.0, 16.0)).astype(pdt),
+        "ssm_skip": jnp.ones((a.n_heads,), pdt),
+        "ssm_norm": {"scale": jnp.ones((a.d_inner,), pdt)},
+        "w_ssm_out": _dense_init(k[2], (a.d_inner, D), a.d_inner, pdt),
+    }
+
+
+def _mixer_params(key, cfg, a):
+    """The parameters of a layer's mixer of a described kind."""
+    make = {MultiHeadAttention: _multihead_params,
+            LatentAttention: _latent_params,
+            StateSpaceMixer: _state_space_params}[type(a)]
+    return make(key, cfg, a)
+
+
 def init_params(key, cfg: TransformerConfig):
     """The parameter pytree, every array made from ``key`` directly in
     ``cfg.param_dtype``. Called outside ``jit`` each array is one small
@@ -449,34 +576,47 @@ def init_params(key, cfg: TransformerConfig):
                                      (cfg.vocab_size, D), D, pdt)
     for i in range(cfg.n_layers):
         k = jax.random.split(keys[2 + i], 8)
-        layer = {"ln1": _norm_params(cfg, (D,)),
-                 "ln2": _norm_params(cfg, (D,))}
+        layer = {}
         a = cfg.attn_of(i)
-        if a is None:
-            # column-parallel fused QKV [D, 3, H, dh]; row-parallel out
-            layer["wqkv"] = _dense_init(k[0], (D, 3, H, dh), D, pdt)
-            layer["wo"] = _dense_init(k[1], (H, dh, D), D, pdt)
-            if cfg.qk_norm:
-                layer["q_norm"] = {"scale": jnp.ones((H, dh), pdt)}
-                layer["k_norm"] = {"scale": jnp.ones((H, dh), pdt)}
-        elif isinstance(a, MultiHeadAttention):
-            layer.update(_multihead_params(k[0], cfg, a))
-        else:
-            layer.update(_latent_params(k[0], cfg, a))
-        if cfg.is_moe(i):
-            lead, F = (cfg.n_held,), cfg.ffn_width
-            layer["router"] = _dense_init(k[2], (D, cfg.n_experts), D, pdt)
-            if cfg.router == "sigmoid":
-                layer["router_bias"] = jnp.zeros((cfg.n_experts,), pdt)
-            if cfg.shared_experts:
-                layer["shared"] = _ffn_params(
-                    jax.random.split(jax.random.fold_in(k[2], 1), 3), cfg,
-                    (), cfg.shared_experts * F)
-        else:
-            lead, F = (), cfg.d_ff
-        layer.update(_ffn_params(k[3:6], cfg, lead, F))
+        if cfg.has_mixer(i):
+            layer["ln1"] = _norm_params(cfg, (D,))
+            if a is None:
+                # column-parallel fused QKV [D, 3, H, dh]; row-parallel out
+                layer["wqkv"] = _dense_init(k[0], (D, 3, H, dh), D, pdt)
+                layer["wo"] = _dense_init(k[1], (H, dh, D), D, pdt)
+                if cfg.qk_norm:
+                    layer["q_norm"] = {"scale": jnp.ones((H, dh), pdt)}
+                    layer["k_norm"] = {"scale": jnp.ones((H, dh), pdt)}
+            else:
+                layer.update(_mixer_params(k[0], cfg, a))
+        if cfg.has_ffn(i):
+            layer["ln2"] = _norm_params(cfg, (D,))
+            layer.update(_layer_ffn_params(k, cfg, i))
         params["layers"].append(layer)
     return params
+
+
+def _layer_ffn_params(k, cfg, i):
+    """Layer ``i``'s feed-forward from the layer's keys ``k``: dense, or the
+    router, the held experts (at ``expert_latent`` where that is set, with
+    the two projections around them) and the shared expert."""
+    D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
+    if not cfg.is_moe(i):
+        return _ffn_params(k[3:6], cfg, (), cfg.d_ff)
+    F, L = cfg.ffn_width, cfg.expert_latent
+    p = {"router": _dense_init(k[2], (D, cfg.n_experts), D, pdt)}
+    if cfg.router == "sigmoid":
+        p["router_bias"] = jnp.zeros((cfg.n_experts,), pdt)
+    if cfg.shared_experts:
+        p["shared"] = _ffn_params(
+            jax.random.split(jax.random.fold_in(k[2], 1), 3), cfg, (),
+            cfg.shared_experts * F)
+    if L:
+        kl = jax.random.split(jax.random.fold_in(k[2], 2), 2)
+        p["w_latent_in"] = _dense_init(kl[0], (D, L), D, pdt)
+        p["w_latent_out"] = _dense_init(kl[1], (L, D), L, pdt)
+    p.update(_ffn_params(k[3:6], cfg, (cfg.n_held,), F, L))
+    return p
 
 
 def param_specs(cfg: TransformerConfig):
@@ -499,31 +639,36 @@ def param_specs(cfg: TransformerConfig):
 
     layers = []
     for i in range(cfg.n_layers):
-        layer = {"ln1": dict(norm), "ln2": dict(norm)}
+        layer = {}
         a = cfg.attn_of(i)
-        if a is None:
-            layer["wqkv"] = P(None, None, m, None)   # heads over model axis
-            layer["wo"] = P(m, None, None)           # row-parallel
-            if cfg.qk_norm:
-                layer["q_norm"] = {"scale": P(m, None)}
-                layer["k_norm"] = {"scale": P(m, None)}
-        else:
-            # Attention described by kind is held whole on every device
-            # (data-parallel attention): no serving program shards it yet.
-            make = _multihead_params if isinstance(a, MultiHeadAttention) \
-                else _latent_params
-            layer.update(jax.tree.map(
-                lambda _: P(), jax.eval_shape(
-                    lambda: make(jax.random.PRNGKey(0), cfg, a))))
-        if cfg.is_moe(i):
-            layer["router"] = P()
-            if cfg.router == "sigmoid":
-                layer["router_bias"] = P()
-            if cfg.shared_experts:
-                layer["shared"] = ffn(P(None, m), P(m, None))
-            layer.update(ffn(P(e, None, m), P(e, m, None)))
-        else:
-            layer.update(ffn(P(None, m), P(m, None)))
+        if cfg.has_mixer(i):
+            layer["ln1"] = dict(norm)
+            if a is None:
+                layer["wqkv"] = P(None, None, m, None)   # heads over model
+                layer["wo"] = P(m, None, None)           # row-parallel
+                if cfg.qk_norm:
+                    layer["q_norm"] = {"scale": P(m, None)}
+                    layer["k_norm"] = {"scale": P(m, None)}
+            else:
+                # A mixer described by kind is held whole on every device
+                # (data-parallel attention): no serving program shards it.
+                layer.update(jax.tree.map(
+                    lambda _: P(), jax.eval_shape(
+                        lambda: _mixer_params(jax.random.PRNGKey(0), cfg,
+                                              a))))
+        if cfg.has_ffn(i):
+            layer["ln2"] = dict(norm)
+            if cfg.is_moe(i):
+                layer["router"] = P()
+                if cfg.router == "sigmoid":
+                    layer["router_bias"] = P()
+                if cfg.shared_experts:
+                    layer["shared"] = ffn(P(None, m), P(m, None))
+                if cfg.expert_latent:   # shared by the experts: everywhere
+                    layer["w_latent_in"] = layer["w_latent_out"] = P()
+                layer.update(ffn(P(e, None, m), P(e, m, None)))
+            else:
+                layer.update(ffn(P(None, m), P(m, None)))
         layers.append(layer)
     specs = {
         "embed": P(m, None),
@@ -645,6 +790,8 @@ def _rope_kind(x, positions, a: MultiHeadAttention):
     """A described kind's rotation of ``x [B, S, H, head_dim]`` at
     ``positions [B, S]``: the first ``rope_dim`` dims of each head, first
     and second half of them paired; float32."""
+    if not a.rope_dim:          # a kind that rotates nothing
+        return x
     inv_freq, factor = rope_inv_freq(a)
     half = a.rope_dim // 2
     ang = positions.astype(jnp.float32)[..., None, None] \
@@ -924,10 +1071,12 @@ def _attend_gather(q, k, v, cfg, full_spec=None):
 
 def _activation(h, layer, x, cfg, eq):
     """The feed-forward's hidden activation from the up projection ``h``:
-    GELU of it, or SiLU of the gate projection times it."""
+    GELU of it, its ReLU squared, or SiLU of the gate projection times it."""
     if cfg.ffn == "swiglu":
         gate = jnp.einsum(eq, x, layer["w_gate"].astype(cfg.compute_dtype))
         return jax.nn.silu(gate) * h
+    if cfg.ffn == "relu2":
+        return jnp.square(jax.nn.relu(h))
     return jax.nn.gelu(h)
 
 
@@ -1018,6 +1167,8 @@ def _expert_products(rows, sizes, layer, cfg):
     if cfg.ffn == "swiglu":
         gate = jax.lax.ragged_dot(rows, layer["w_gate"].astype(dt), sizes)
         h = jax.nn.silu(gate) * h
+    elif cfg.ffn == "relu2":
+        h = jnp.square(jax.nn.relu(h))
     else:
         h = jax.nn.gelu(h)
     return jax.lax.ragged_dot(h, layer["w_out"].astype(dt), sizes)
@@ -1101,13 +1252,25 @@ def _moe_ffn(x, layer, cfg, mesh=None, valid=None):
     rows ``valid [b, s]`` marks (all by default), and the rows the experts'
     products ran over (grouped: the sorted pairs given to them; dense: every
     token for every expert): what ``serve_stats()["moe"]`` counts. A shared
-    expert (``shared_experts``) is added for every row."""
+    expert (``shared_experts``) is added for every row. Under
+    ``expert_latent`` the routed experts read ``x W_latent_in`` and their
+    weighted sum goes through ``W_latent_out``; the router and the shared
+    expert read ``x`` itself."""
+    dt = cfg.compute_dtype
     w, top = _route(x, layer, cfg)
+    rows_in = x
+    if cfg.expert_latent:
+        with jax.named_scope(scopes.EXPERT_LATENT):
+            rows_in = jnp.einsum("bsd,dl->bsl", x,
+                                 layer["w_latent_in"].astype(dt))
     if mesh is None:
-        y, rows = _moe_grouped(x, w, top, layer, cfg)
+        y, rows = _moe_grouped(rows_in, w, top, layer, cfg)
     else:
-        y = _moe_dense(x, w, top, layer, cfg)
+        y = _moe_dense(rows_in, w, top, layer, cfg)
         rows = top[..., 0].size * cfg.n_held
+    if cfg.expert_latent:
+        with jax.named_scope(scopes.EXPERT_LATENT):
+            y = jnp.einsum("bsl,ld->bsd", y, layer["w_latent_out"].astype(dt))
     if cfg.shared_experts:
         y = y + _ffn(x, layer["shared"], cfg)
     # Counted: the pairs this device computes (all of them, or those of the
@@ -1118,6 +1281,147 @@ def _moe_ffn(x, layer, cfg, mesh=None, valid=None):
         hit = hit * valid[..., None, None]
     return y, {"top": top, "counts": hit.sum((0, 1, 2)),
                "rows": jnp.asarray(rows, jnp.int32)}
+
+
+def _ssd_step(x, step, rate, b_in, c_out, state):
+    """:func:`_ssd_blocks` for a window of ONE position: ``S = exp(step
+    rate) S + step x (x) B`` and ``y = S C``, elementwise over the state and
+    one sum over its last axis, so that a decode step reads a slot's state
+    once and writes it once, in place. (The blocked form at a block of one
+    makes the outer product a product of its own and reads the state twice:
+    0.5 GB more a layer at 128 slots.)"""
+    f32 = jnp.float32
+    B, _, H, P = x.shape
+    G, N = b_in.shape[2:]
+    step = step[:, 0].astype(f32)                                    # [B, H]
+    dx = (x[:, 0].astype(f32) * step[..., None]).reshape(B, G, H // G, P)
+    kept = jnp.exp(step * rate.astype(f32)).reshape(B, G, H // G)
+    state = state.astype(f32).reshape(B, G, H // G, P, N)
+    state = state * kept[..., None, None] \
+        + dx[..., None] * b_in[:, 0].astype(f32)[:, :, None, None, :]
+    y = jnp.sum(state * c_out[:, 0].astype(f32)[:, :, None, None, :], -1)
+    return y.reshape(B, 1, H, P), state.reshape(B, H, P, N)
+
+
+def _ssd_blocks(x, step, rate, b_in, c_out, state, block):
+    """The state-space recurrence of a window in its chunked form (Mamba-2's
+    state-space duality, arXiv:2405.21060 section 6), float32 throughout.
+
+    ``x [B, S, H, P]`` inputs, ``step [B, S, H]`` (0 = a position that leaves
+    the state alone), ``rate [H]`` (negative), ``b_in, c_out [B, S, G, N]``,
+    ``state [B, H, P, N]`` entering -> (``y [B, S, H, P]``, the state
+    leaving). Per head, with ``a_t = step_t * rate``: ``S_t = exp(a_t) S_{t-1}
+    + step_t x_t (x) B_t`` and ``y_t = S_t C_t``. Inside a block of ``block``
+    positions the sum over earlier positions is two matrix products (``C
+    B^T`` under the decays, then times ``step x``); a block's effect on the
+    state is one more, and the blocks are chained by a scan over their
+    states. A window of one is the recurrence itself, the one-token update
+    (:func:`_ssd_step`): the same values with no product to make."""
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+    B, S, H, P = x.shape
+    G, N = b_in.shape[2:]
+    if S == 1:
+        return _ssd_step(x, step, rate, b_in, c_out, state)
+    Q = min(block, S)
+    nb = -(-S // Q)
+    pad = [(0, 0), (0, nb * Q - S)]
+
+    def blocks(v):     # [B, S, ..] -> [B, nb, Q, ..], dead positions behind
+        v = jnp.pad(v.astype(f32), pad + [(0, 0)] * (v.ndim - 2))
+        return v.reshape(B, nb, Q, *v.shape[2:])
+
+    step = blocks(step)                                          # [B,nb,Q,H]
+    dx = (blocks(x) * step[..., None]).reshape(B, nb, Q, G, H // G, P)
+    b_in, c_out = blocks(b_in), blocks(c_out)                    # [B,nb,Q,G,N]
+    cs = jnp.cumsum(step * rate.astype(f32), axis=2)             # [B,nb,Q,H]
+    # Inside a block: position q takes exp(cs_q - cs_s) (C_q . B_s) of s <= q.
+    cs_h = cs.transpose(0, 1, 3, 2)                              # [B,nb,H,Q]
+    seg = cs_h[..., :, None] - cs_h[..., None, :]                # [.., q, s]
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+    decay = jnp.where(causal, jnp.exp(jnp.where(causal, seg, 0.0)), 0.0)
+    cb = jnp.einsum("bcqgn,bcsgn->bcgqs", c_out, b_in, precision=hi)
+    mix = cb[:, :, :, None] * decay.reshape(B, nb, G, H // G, Q, Q)
+    y = jnp.einsum("bcghqs,bcsghp->bcqghp", mix, dx, precision=hi)
+    # A block's own contribution to the state at its end, and its decay.
+    to_end = jnp.exp(cs[:, :, -1:] - cs).reshape(B, nb, Q, G, H // G)
+    grown = jnp.einsum("bcsgn,bcsghp->bcghpn", b_in, dx * to_end[..., None],
+                       precision=hi)                       # [B,nb,G,H/G,P,N]
+    kept = jnp.exp(cs[:, :, -1]).reshape(B, nb, G, H // G)
+
+    def chain(s, xs):          # -> the state leaving, the state entering
+        kept_c, grown_c = xs
+        return s * kept_c[..., None, None] + grown_c, s
+
+    state, entering = jax.lax.scan(
+        chain, state.astype(f32).reshape(B, G, H // G, P, N),
+        (jnp.moveaxis(kept, 1, 0), jnp.moveaxis(grown, 1, 0)))
+    entering = jnp.moveaxis(entering, 0, 1)                # [B,nb,G,H/G,P,N]
+    carried = jnp.einsum("bcqgn,bcghpn->bcqghp", c_out, entering,
+                         precision=hi)
+    y = y + carried * jnp.exp(cs).reshape(B, nb, Q, G, H // G)[..., None]
+    return (y.reshape(B, nb * Q, H, P)[:, :S], state.reshape(B, H, P, N))
+
+
+def state_space_mix(u, layer, a: StateSpaceMixer, cfg, tail=None, state=None,
+                    live=None):
+    """THE state-space mixer, written once: the normed input ``u [B, S, D]``
+    of a window of ``S`` consecutive tokens a sequence -> (``out [B, S, D]``,
+    the convolution tail leaving ``[B, conv_kernel - 1, conv_dim]`` in the
+    compute dtype, the state leaving ``[B, H, P, N]`` float32).
+
+    ``tail`` and ``state`` are what the sequence carried in (None = a
+    sequence that starts here: zeros). ``live [B, S]`` marks the window's
+    real positions, which come FIRST (padding is behind the tokens; an
+    inactive slot has none): a dead position advances neither the tail nor
+    the state, and its output is garbage nobody reads. `forward` calls this
+    with no state over the whole sequence, the serving chunk program with the
+    slot's, the decode step with a window of one.
+
+    ``[z | xBC | dt] = u W_in``; ``xBC`` through the depthwise causal
+    convolution and SiLU; ``step = softplus(dt + dt_bias)``, ``rate =
+    -exp(a_log)``; the recurrence (:func:`_ssd_blocks`) plus ``skip * x``;
+    the gate ``silu(z)`` first and then an RMS norm over each group's
+    channels; ``W_out``. The step, the decay, the state and the gated norm
+    are float32; the projections and the convolution's inputs are the
+    compute dtype's."""
+    dt, f32 = cfg.compute_dtype, jnp.float32
+    B, S, _ = u.shape
+    H, P, G, N, K = (a.n_heads, a.head_dim, a.n_groups, a.state_size,
+                     a.conv_kernel)
+    if tail is None:
+        tail = jnp.zeros((B, a.tail, a.conv_dim), dt)
+    if state is None:
+        state = jnp.zeros((B, H, P, N), f32)
+    if live is None:
+        live = jnp.ones((B, S), bool)
+    zxd = jnp.einsum("bsd,dw->bsw", u, layer["w_ssm_in"].astype(dt))
+    z = zxd[..., :a.d_inner]
+    xbc = zxd[..., a.d_inner:a.d_inner + a.conv_dim]
+    step = jax.nn.softplus(zxd[..., a.d_inner + a.conv_dim:].astype(f32)
+                           + layer["dt_bias"].astype(f32))
+    step = jnp.where(live[..., None], step, 0.0)
+    # The convolution over the carried inputs and the window's; the tail
+    # that leaves is the last live inputs.
+    seq = jnp.concatenate([tail.astype(dt), xbc], 1)      # [B, K-1+S, C]
+    at = jnp.sum(live, 1)[:, None] + jnp.arange(a.tail)[None]
+    tail = jnp.take_along_axis(seq, at[..., None], axis=1)
+    taps = layer["conv_w"].astype(f32)
+    conv = layer["conv_b"].astype(f32) + sum(
+        seq[:, j:j + S].astype(f32) * taps[:, j] for j in range(K))
+    xbc = jax.nn.silu(conv).astype(dt)
+    x = xbc[..., :a.d_inner].reshape(B, S, H, P)
+    b_in = xbc[..., a.d_inner:a.d_inner + G * N].reshape(B, S, G, N)
+    c_out = xbc[..., a.d_inner + G * N:].reshape(B, S, G, N)
+    y, state = _ssd_blocks(x, step, -jnp.exp(layer["a_log"].astype(f32)),
+                           b_in, c_out, state, a.block)
+    y = y + layer["ssm_skip"].astype(f32)[:, None] * x.astype(f32)
+    y = y.reshape(B, S, G, -1) * jax.nn.silu(z.astype(f32)).reshape(
+        B, S, G, -1)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
+    y = (y.reshape(B, S, -1)
+         * layer["ssm_norm"]["scale"].astype(f32)).astype(dt)
+    return (jnp.einsum("bsf,fd->bsd", y, layer["w_ssm_out"].astype(dt)),
+            tail, state)
 
 
 # The measured flash-vs-gather crossover expressed as LIVE score
@@ -1213,36 +1517,51 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
     the selection in its routing (``{"selected": ..}``). A multi-head layer
     of a described kind (:class:`MultiHeadAttention`) hands it ``attend(q
     [B, S, Hq, dh], k, v [B, S, Hkv, dh]) -> [B, S, Hq, dh]``
-    (:func:`_qkv_kind`) and gates the result a head where the kind says."""
+    (:func:`_qkv_kind`) and gates the result a head where the kind says. A
+    STATE-SPACE layer (:class:`StateSpaceMixer`) hands it the mixer itself,
+    ``attend(mix) -> out [B, S, D]`` with ``mix(tail, state, live) -> (out,
+    tail, state)`` (:func:`state_space_mix` on this layer's normed input):
+    the caller supplies what the sequences carried in and keeps what they
+    carry out. A layer of one half (``cfg.layer_parts``) runs that half
+    alone, under its own norm, and ``attend`` may be None for a layer with
+    no mixer."""
     dt = cfg.compute_dtype
     a = cfg.attn_of(li)
-    selected = None
-    h = _norm(x, layer["ln1"], cfg)
-    with jax.named_scope(scopes.ATTENTION):
-        if a is None:
-            q, k, v = _qkv(h, layer, cfg, positions)
-            out = jnp.einsum("bshk,hkd->bsd", attend(q, k, v),
-                             layer["wo"].astype(dt))
-        elif isinstance(a, MultiHeadAttention):
-            o = attend(*_qkv_kind(h, layer, cfg, a, positions))
-            if a.gate:
-                o = o * _head_gate(h, layer, dt)
-            out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-        else:
-            o, selected = attend(*_latent_qkv(h, layer, cfg, a, positions))
-            o = jnp.einsum("bshr,rhd->bshd", o,
-                           layer["wkv_b"][..., a.nope_dim:].astype(dt))
-            if cfg.attn_gate:
-                o = o * _head_gate(h, layer, dt)
-            out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-        x = x + _constrain(out, out_spec)
-    h = _norm(x, layer["ln2"], cfg)
-    with jax.named_scope(scopes.MLP):
-        if cfg.is_moe(li):
-            y, routing = _moe_ffn(h, layer, cfg, mesh, valid)
-        else:
-            y, routing = _ffn(h, layer, cfg), None
-        x = x + y
+    selected = routing = None
+    if cfg.has_mixer(li):
+        h = _norm(x, layer["ln1"], cfg)
+    if isinstance(a, StateSpaceMixer) and cfg.has_mixer(li):
+        with jax.named_scope(scopes.STATE_SPACE):
+            out = attend(functools.partial(state_space_mix, h, layer, a, cfg))
+            x = x + _constrain(out, out_spec)
+    elif cfg.has_mixer(li):
+        with jax.named_scope(scopes.ATTENTION):
+            if a is None:
+                q, k, v = _qkv(h, layer, cfg, positions)
+                out = jnp.einsum("bshk,hkd->bsd", attend(q, k, v),
+                                 layer["wo"].astype(dt))
+            elif isinstance(a, MultiHeadAttention):
+                o = attend(*_qkv_kind(h, layer, cfg, a, positions))
+                if a.gate:
+                    o = o * _head_gate(h, layer, dt)
+                out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
+            else:
+                o, selected = attend(*_latent_qkv(h, layer, cfg, a,
+                                                  positions))
+                o = jnp.einsum("bshr,rhd->bshd", o,
+                               layer["wkv_b"][..., a.nope_dim:].astype(dt))
+                if cfg.attn_gate:
+                    o = o * _head_gate(h, layer, dt)
+                out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
+            x = x + _constrain(out, out_spec)
+    if cfg.has_ffn(li):
+        h = _norm(x, layer["ln2"], cfg)
+        with jax.named_scope(scopes.MLP):
+            if cfg.is_moe(li):
+                y, routing = _moe_ffn(h, layer, cfg, mesh, valid)
+            else:
+                y = _ffn(h, layer, cfg)
+            x = x + y
     if selected is not None:
         routing = dict(routing or {}, selected=selected)
     return x, routing
@@ -1261,9 +1580,12 @@ def _block_fn(cfg, mesh, impl, seq_spec, full_spec):
 
     def fn(layer, x, li=0):
         a = cfg.attn_of(li)
-        mine = attend if a is None else (
-            _attend_kind if isinstance(a, MultiHeadAttention)
-            else _attend_latent)(a, cfg.compute_dtype)
+        if isinstance(a, StateSpaceMixer):   # every sequence starts here
+            mine = lambda mix: mix()[0]  # noqa: E731
+        else:
+            mine = attend if a is None else (
+                _attend_kind if isinstance(a, MultiHeadAttention)
+                else _attend_latent)(a, cfg.compute_dtype)
         x, routing = block(layer, x, cfg, mine, mesh=mesh,
                            out_spec=seq_spec, li=li)
         return _constrain(x, seq_spec), routing

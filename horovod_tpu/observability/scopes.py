@@ -14,7 +14,10 @@ A PHASE is a part of the train step (``parallel/data_parallel.py``
 files open their scopes with the constants below, so a name is spelled
 once. ``experts`` is opened inside ``mlp``: :func:`parse` returns the
 innermost scope, and a reader that wants the whole feed-forward layer asks
-for ``mlp|experts``.
+for ``mlp|experts``. ``state_space`` holds a state-space layer's whole mixer
+(projections, convolution, recurrence, gated norm) where ``attention`` holds
+an attention layer's; ``expert_latent`` the two projections around experts
+that work in a latent, inside ``mlp`` beside ``experts``.
 
 No JAX here: the benchmark's jax-free parent imports this module.
 """
@@ -23,8 +26,9 @@ import re
 
 PHASES = GRAD, GRAD_REDUCE, OPTIMIZER = ("grad", "grad_reduce", "optimizer")
 SCOPES = (EMBED, LAYER_NORM, RMS_NORM, ATTENTION, MLP, EXPERTS, LOSS,
-          HEAD) = ("embed", "layer_norm", "rms_norm", "attention", "mlp",
-                   "experts", "loss", "head")
+          HEAD, STATE_SPACE, EXPERT_LATENT) = (
+              "embed", "layer_norm", "rms_norm", "attention", "mlp",
+              "experts", "loss", "head", "state_space", "expert_latent")
 
 # What a transform writes around a component of the path it differentiates,
 # transposes or batches: ``transpose(jvp(attention))``. Components that are

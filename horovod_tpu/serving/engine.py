@@ -90,6 +90,18 @@ the slot's pages (or ring) are gathered and attended with materialised scores
 (``transformer.grouped_attend``). A model whose layers are described by kind
 is filled by chunks only: the padded prefills are not built for it.
 
+A STATE-SPACE layer (``TransformerConfig.state_space``) keeps no K/V: its
+slot's row of the layer's tail and state arrays (``kv_cache``: the row's index
+rides in the block table's last column) is read, zeroed if the window starts
+its sequence (position 0: whoever held the slot before, and a preempted
+request's replay alike), handed to ``transformer.state_space_mix`` with the
+window's live positions, and written back in place (:func:`_state_layer`):
+the chunk program runs the chunked scan over its 512 positions, the decode
+step the same function over a window of one. A chunk's padding must not
+advance the state, so for such a model a NEGATIVE token id marks a padding
+position (the loop pads so; positions are dead from the first negative id
+on). A layer with no mixer touches no cache.
+
 The batch-slot ↔ request mapping, page ownership, and admission policy
 live host-side in :mod:`.scheduler`; this module never allocates.
 """
@@ -212,7 +224,7 @@ def _window_cells(a, q_pos, ok, tables, geo):
     ``q_pos [B, Q]``, trash page 0 where not ``ok``)."""
     page = geo.page_size
     if a.window:
-        table = tables[:, geo.max_blocks:]
+        table = tables[:, geo.max_blocks:geo.max_blocks + geo.ring_blocks]
         blk = (q_pos // page) % geo.ring_blocks
     else:
         table = tables[:, :geo.max_blocks]
@@ -330,8 +342,43 @@ def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
     return k_c, v_c, tfm.grouped_attend(q, *rows, a, allowed, dt)
 
 
+def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables):
+    """One state-space layer of a chunk or decode program: the slots' rows of
+    the layer's tail and state arrays, zeroed where the window begins its
+    sequence (a live slot whose ``q_pos [B, Q]`` starts at 0), through
+    ``mix(tail, state, live) -> (out, tail, state)`` with ``ok [B, Q]`` the
+    live positions, and back into the same rows -> (the layer's arrays, ``out
+    [B, Q, D]``). A dead slot's row is left as it was.
+
+    A program over every slot (the decode step: batch row ``b`` IS slot
+    ``b``) takes rows ``1 ..`` where they lie, a slice and not a gather, and
+    writes the same slice back, which the compiler does in place: a gather
+    and a scatter of every row made a second copy of a layer's state (0.5 GB)
+    that lived until the program's end. A dead slot there advances nothing
+    (``mix`` leaves its tail and state bit for bit). Any other program (one
+    slot's chunk) finds its row in ``tables``' last column, trash row 0 for a
+    slot with no live position."""
+    alive = jnp.any(ok, axis=1)
+    begins = alive & (q_pos[:, 0] == 0)
+    whole = q_pos.shape[0] == state_c.shape[0] - 1
+    if whole:
+        tail, state = tail_c[1:], state_c[1:]
+    else:
+        rows = jnp.where(alive, tables[:, -1], 0)
+        tail, state = tail_c[rows], state_c[rows]
+    tail = jnp.where(begins[:, None, None], 0, tail)
+    state = jnp.where(begins[:, None, None, None], 0, state)
+    out, tail, state = mix(tail, state, ok)
+    tail = tail.astype(tail_c.dtype)
+    if whole:
+        return (jax.lax.dynamic_update_slice(tail_c, tail, (1, 0, 0)),
+                jax.lax.dynamic_update_slice(state_c, state, (1, 0, 0, 0)),
+                out)
+    return tail_c.at[rows].set(tail), state_c.at[rows].set(state), out
+
+
 def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
-            latent=None, grouped=None):
+            latent=None, grouped=None, state=None):
     """Every layer of the model over ``x [B, S, D]`` through
     ``transformer.block``, the one block definition, with the serving
     attention: layer ``li``'s new K/V (after the Q/K norm and the rotation
@@ -343,7 +390,9 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
     through ``latent(a, q, row, index, rows_c, keys_c)``
     (:func:`_latent_layer` with the program's positions and tables), a
     multi-head layer of a described kind through ``grouped(a, q, k, v, k_c,
-    v_c)`` (:func:`_grouped_layer`, the same). ->
+    v_c)`` (:func:`_grouped_layer`, the same), a state-space layer through
+    ``state(mix, tail_c, state_c)`` (:func:`_state_layer`, the same); a layer
+    with no mixer has no cache and attends nothing. ->
     (ck, cv, x after the final norm, what the layers report or None:
     ``counts``, ``rows`` and ``top`` of the expert layers, ``selected`` of the
     selecting ones, each stacked over those layers)."""
@@ -351,7 +400,13 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
     reports = []
     for li, layer in enumerate(params["layers"]):
         a = cfg.attn_of(li)
-        if a is None:
+        if not cfg.has_mixer(li):
+            write_and_attend = None
+        elif isinstance(a, tfm.StateSpaceMixer):
+            def write_and_attend(mix, li=li):
+                ck[li], cv[li], out = state(mix, ck[li], cv[li])
+                return out
+        elif a is None:
             def write_and_attend(q, k, v, li=li):
                 ck[li], kk = write(ck[li], k)
                 cv[li], vv = write(cv[li], v)
@@ -476,6 +531,8 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
         latent = functools.partial(_latent_layer, **kinds, kernels=kernels)
         grouped = functools.partial(_grouped_layer, **kinds,
                                     kernels=grouped_kernels(cfg, geo, mesh))
+        state = functools.partial(_state_layer, q_pos=positions[:, None],
+                                  ok=active[:, None], tables=block_tables)
         block_tables = _context_tables(block_tables, geo)
         blk = positions // geo.page_size
         slot = positions % geo.page_size
@@ -511,7 +568,7 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
 
         ck, cv, x, moe = _layers(params, cache, x, positions[:, None], write,
                                  attend, active[:, None], cfg=cfg, mesh=mesh,
-                                 latent=latent, grouped=grouped)
+                                 latent=latent, grouped=grouped, state=state)
         logits = tfm.head_logits(x, params, cfg)[:, 0]
         return _result(ck, cv, logits, moe, mesh, cfg)
 
@@ -532,10 +589,15 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
     tables, block_tables = block_tables, _context_tables(block_tables, geo)
     pos = positions[:, None] + jnp.arange(q_len)[None, :]    # [B, Q]
     pe = jnp.clip(pos, 0, cfg.max_seq_len - 1)
+    if cfg.state_space:       # a negative id: padding, from there on
+        padding = jnp.cumsum(tokens < 0, axis=1) > 0
+        tokens = jnp.maximum(tokens, 0)
     x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
                           params, cfg, pe)                   # [B, Q, D]
     blk = jnp.minimum(pos // geo.page_size, geo.max_blocks - 1)
     valid = (pos < max_kv) & active[:, None]
+    if cfg.state_space:
+        valid &= ~padding
     page_ids = jnp.take_along_axis(block_tables, blk, axis=1)
     page_ids = jnp.where(valid, page_ids, 0)                 # trash route
     slot_w = jnp.where(valid, pos % geo.page_size, 0)
@@ -552,9 +614,11 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
                                kernels=latent_kernels(cfg, geo, mesh))
     grouped = functools.partial(_grouped_layer, **kinds,
                                 kernels=grouped_kernels(cfg, geo, mesh))
+    state = functools.partial(_state_layer, q_pos=pos, ok=valid,
+                              tables=tables)
     return _layers(params, cache, x, pos, write,
                    _masked(cfg, kv_mask[:, None, :, :]), valid, cfg=cfg,
-                   mesh=mesh, latent=latent, grouped=grouped)
+                   mesh=mesh, latent=latent, grouped=grouped, state=state)
 
 
 def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
@@ -590,7 +654,9 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
 
     Writes for positions past ``max_kv`` or on inactive slots route to
     trash page 0, so padded draft lanes and short final chunks are
-    branch-free.
+    branch-free. For a model with state-space layers a negative token id
+    marks padding: that position and every one behind it is dead (its K/V go
+    to the trash page and it advances no state).
     """
     decode_attn(cfg, geo, mesh)
     q_len = geo.page_size if q_len is None else int(q_len)

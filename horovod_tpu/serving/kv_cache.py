@@ -53,6 +53,23 @@ ring_blocks`` (the ring's page ids ride behind the context's). Window
 layers sized like full ones would hold ``max_kv`` positions a slot for state
 that is never read again.
 
+A STATE-SPACE layer (``TransformerConfig.state_space``) holds no K/V at all:
+its ``"k"`` array is the convolution **tail**, ``[state_rows, conv_kernel - 1,
+conv_dim]`` in the compute dtype (the last inputs of the depthwise
+convolution), and its ``"v"`` array the recurrent **state**, ``[state_rows,
+heads, head_dim, state_size]`` in float32: one row a SLOT, the same bytes
+whatever the context. Row ``slot + 1`` is the slot's own for as long as the
+server runs (nothing is allocated or freed: the row's index is the slot's),
+and rides in the block table's LAST column, behind the ring's page ids, so
+that the one-slot chunk program finds it as the decode step does; row 0 is
+the **trash row**, where inactive slots' writes go. A row is dirty with
+whatever its slot's last request left: the programs zero it when a request's
+first position arrives (``engine._state_layer``), which is also what makes a
+preempted request's replay start clean. State cannot be shared by page, so a
+model with such layers has no prefix cache, and it cannot be rolled back, so
+no speculation (``ServeLoop`` refuses both). A layer with NO mixer
+(``layer_parts[i] == "ffn"``) has no cache: both entries are None.
+
 Tensor-parallel layout: the fused ``n_heads * head_dim`` dimension rides
 the mesh's ``model`` axis. Heads are its major part, so a shard of it is
 whole heads — the SAME heads the attention weights' shard produces
@@ -69,7 +86,7 @@ import math
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.transformer import MultiHeadAttention
+from ..models.transformer import MultiHeadAttention, StateSpaceMixer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,6 +101,7 @@ class CacheGeometry:
     max_blocks: int      # context pages a request may own
     ring_blocks: int = 0  # pages of a slot's ring (window layers); 0 = none
     ring_pages: int = 0   # the window layers' pool, trash page 0 included
+    state_rows: int = 0   # state-space rows, trash row 0 included; 0 = none
 
     @property
     def max_kv(self):
@@ -91,8 +109,9 @@ class CacheGeometry:
 
     @property
     def table_width(self):
-        """Columns of a block table: the context's pages, then the ring's."""
-        return self.max_blocks + self.ring_blocks
+        """Columns of a block table: the context's pages, then the ring's,
+        then the slot's state row where the model has state-space layers."""
+        return self.max_blocks + self.ring_blocks + bool(self.state_rows)
 
     @property
     def ring_tokens(self):
@@ -111,7 +130,10 @@ def with_rings(geo, cfg, q_len, max_batch):
     """``geo`` with a ring for every slot where ``cfg`` has window layers:
     ``window - 1 + q_len`` positions (``q_len``: the longest query window a
     program will run) in whole pages, ``max_batch`` of them and the trash
-    page. Unchanged for a model without window layers."""
+    page; and with a state row for every slot (and the trash row) where it
+    has state-space layers. Unchanged for a model with neither."""
+    if cfg.state_space:
+        geo = dataclasses.replace(geo, state_rows=int(max_batch) + 1)
     windows = [a.window for _, a in cfg.latent + cfg.multihead if a.window]
     if not windows:
         return geo
@@ -130,6 +152,14 @@ def spec(cfg):
 def layer_shapes(cfg, geo, li):
     """Shapes of layer ``li``'s ``("k", "v")`` arrays; None = no array."""
     a = cfg.attn_of(li)
+    if not cfg.has_mixer(li):
+        return None, None
+    if isinstance(a, StateSpaceMixer):
+        if not geo.state_rows:
+            raise ValueError("a state-space layer needs a geometry with "
+                             "state rows (kv_cache.with_rings)")
+        return ((geo.state_rows, a.tail, a.conv_dim),
+                (geo.state_rows, a.n_heads, a.head_dim, a.state_size))
     if a is None:
         shape = (geo.n_pages, geo.page_size, cfg.n_heads * cfg.head_dim)
         return shape, shape
@@ -144,25 +174,38 @@ def layer_shapes(cfg, geo, li):
             (pages, geo.page_size, a.index_dim) if a.index_topk else None)
 
 
+def _layer_dtypes(cfg, li):
+    """Dtypes of layer ``li``'s ``("k", "v")`` arrays: the compute dtype,
+    but float32 for a state-space layer's state."""
+    state = isinstance(cfg.attn_of(li), StateSpaceMixer)
+    return cfg.compute_dtype, jnp.dtype(jnp.float32) if state \
+        else cfg.compute_dtype
+
+
 def make_cache(cfg, geo, mesh=None):
     """Allocate the zeroed cache: {"k": (...), "v": (...)}, each a tuple of
-    n_layers arrays in the model's compute dtype, each of its layer's own
-    shape (:func:`layer_shapes`: pages or ring pages, and the lanes of the
-    layer's kind). With a mesh, the arrays are placed sharded on the
-    model axis (when that axis exists in the mesh)."""
+    n_layers arrays in the model's compute dtype (a state-space layer's
+    state in float32), each of its layer's own shape (:func:`layer_shapes`:
+    pages, ring pages or state rows, and the lanes of the layer's kind; None
+    for a layer with no mixer). With a mesh, the paged arrays are placed
+    sharded on the model axis (when that axis exists in the mesh)."""
     sharding = None
     if mesh is not None and cfg.model_axis in mesh.axis_names:
         sharding = NamedSharding(mesh, spec(cfg))
-    shapes = [layer_shapes(cfg, geo, li) for li in range(cfg.n_layers)]
+    if sharding is not None and cfg.state_space:
+        raise ValueError("state-space layers under a mesh are not written")
+    layers = [(layer_shapes(cfg, geo, li), _layer_dtypes(cfg, li))
+              for li in range(cfg.n_layers)]
     return {name: tuple(
-        None if s[i] is None
-        else jnp.zeros(s[i], cfg.compute_dtype, device=sharding)
-        for s in shapes) for i, name in enumerate(("k", "v"))}
+        None if shapes[i] is None
+        else jnp.zeros(shapes[i], dtypes[i], device=sharding)
+        for shapes, dtypes in layers) for i, name in enumerate(("k", "v"))}
 
 
 def cache_bytes(cfg, geo):
     """Total cache footprint in bytes (every layer's arrays)."""
-    size = jnp.dtype(cfg.compute_dtype).itemsize
-    return sum(size * math.prod(shape)
+    return sum(dtype.itemsize * math.prod(shape)
                for li in range(cfg.n_layers)
-               for shape in layer_shapes(cfg, geo, li) if shape is not None)
+               for shape, dtype in zip(layer_shapes(cfg, geo, li),
+                                       _layer_dtypes(cfg, li))
+               if shape is not None)

@@ -79,7 +79,8 @@ import numpy as np
 
 from ..observability import metrics as _metrics
 from ..observability import spans as _spans
-from ..models.transformer import LatentAttention, MultiHeadAttention
+from ..models.transformer import (LatentAttention, MultiHeadAttention,
+                                  StateSpaceMixer)
 from . import engine, kv_cache, speculate
 from .prefix_cache import PrefixCache
 from .scheduler import (DEFAULT_KV_PAGES, DEFAULT_MAX_BATCH,
@@ -104,6 +105,8 @@ LONG_PREFILL_CHUNK = 512
 # pairs of the experts held here, program calls, (layer, expert) weights
 # read, and the sorted rows the experts' products ran over.
 _MOE_COUNTS = ("pairs", "calls", "expert_reads", "rows")
+# What ``serve_stats()["state"]`` sums by program kind (state-space layers).
+_STATE_COUNTS = ("rows", "bytes", "tokens", "resets", "kv_bytes", "calls")
 
 
 @dataclasses.dataclass
@@ -247,7 +250,14 @@ class ServeLoop:
         self.load_reporter = load_reporter
         self.report_interval = int(report_interval)
         # Latent layers fill by chunks whatever the width; rings of window
-        # state cannot be shared between requests, so no prefix is.
+        # state and state-space rows cannot be shared between requests, so
+        # no prefix is; a state-space row cannot be rolled back either.
+        self.has_state = bool(cfg.state_space)
+        if self.has_state and self.spec_tokens > 0:
+            raise ValueError(
+                "spec_tokens > 0 with state-space layers: a rejected draft "
+                "would have to roll the slot's state back, which is not "
+                "written")
         padded = geo.max_kv <= PADDED_PREFILL_MAX_KV and not cfg.described
         if prefill_chunk is None:
             prefill_chunk = (2 * geo.page_size if padded
@@ -256,7 +266,8 @@ class ServeLoop:
         geo = self.geo = kv_cache.with_rings(
             geo, cfg, max(self.prefill_chunk, self.spec_tokens + 1),
             self.max_batch)
-        use_prefix = use_prefix and not geo.ring_blocks
+        use_prefix = (use_prefix and not geo.ring_blocks
+                      and not self.has_state)
         self.prefill_fn = (engine.make_prefill(cfg, geo, mesh)
                            if padded else None)
         self.decode_fn = engine.make_decode_step(cfg, geo, mesh, max_batch)
@@ -282,7 +293,7 @@ class ServeLoop:
             spec_tokens=self.spec_tokens,
             ring_allocator=(PageAllocator(geo.ring_pages, geo.page_size)
                             if geo.ring_blocks else None),
-            ring_blocks=geo.ring_blocks)
+            ring_blocks=geo.ring_blocks, state_rows=geo.state_rows)
         self.loop_stats = {"prefill_single": 0, "prefill_batched": 0,
                            "prefill_batch_calls": 0, "chunk_fills": 0,
                            "boundaries": 0,
@@ -325,6 +336,20 @@ class ServeLoop:
             "kv_scored", "kv_selected", "kv_window", "queries", "calls",
             *(("kv_full_rows", "kv_window_rows", "kv_window_rows_as_full",
                "qk_full_pairs", "qk_window_pairs") if multihead else ()))}
+        # State-space layers: by program kind, the (slot, layer) rows a call
+        # reads and writes back, their bytes both ways (tail and state),
+        # the (token, layer) positions scanned, the rows a call zeroed
+        # because a sequence began; and the K/V bytes the full multi-head
+        # layers read beside them (``kv_full_rows`` at the layers' width).
+        mixers = [a for a in kinds if isinstance(a, StateSpaceMixer)]
+        self._state_layers = len(mixers)
+        size = cfg.compute_dtype.itemsize
+        self._state_row_bytes = sum(
+            a.tail * a.conv_dim * size
+            + a.n_heads * a.head_dim * a.state_size * 4 for a in mixers)
+        self._kv_row_bytes = sum(2 * a.kv_width * size for a in multihead
+                                 if not a.window)
+        self.state_stats = {name: {} for name in _STATE_COUNTS}
 
     @contextlib.contextmanager
     def _span(self, name, **args):
@@ -381,11 +406,16 @@ class ServeLoop:
     def _count_attn(self, kind, live):
         """One program call whose queries see ``live [slots, queries]`` keys
         each (their positions + 1; a slot's queries are consecutive): what
-        its latent layers scored, selected and windowed, and what its
-        multi-head layers of a described kind read and multiplied."""
-        if not self._counts_attn:
+        its latent layers scored, selected and windowed, what its
+        multi-head layers of a described kind read and multiplied, and what
+        its state-space layers carried (:meth:`_count_state`)."""
+        if not (self._counts_attn or self._state_layers):
             return
         live = np.asarray(live, np.int64)
+        if self._state_layers:
+            self._count_state(kind, live)
+        if not self._counts_attn:
+            return
         found = {
             "kv_scored": int(live.sum()) * len(self._select),
             "kv_selected": sum(int(np.minimum(live, k).sum())
@@ -420,6 +450,22 @@ class ServeLoop:
             for metric, name in counters:
                 metric.labels(program=kind).inc(found[name])
             _metrics.SERVE_KV_SELECT_SHARE.set(self._kv_select_share())
+
+    def _count_state(self, kind, live):
+        """The same call's state-space layers (``live`` as in
+        :meth:`_count_attn`; a slot whose first query sees one key begins
+        its sequence there, and its rows are zeroed)."""
+        slots = live.shape[0]
+        found = {"rows": slots * self._state_layers,
+                 "bytes": 2 * slots * self._state_row_bytes,
+                 "tokens": live.size * self._state_layers,
+                 "resets": int((live[:, :1] == 1).sum()) * self._state_layers,
+                 "kv_bytes": int(live.max(axis=1, initial=0).sum())
+                 * self._kv_row_bytes,
+                 "calls": 1}
+        for name, n in found.items():
+            by_kind = self.state_stats[name]
+            by_kind[kind] = by_kind.get(kind, 0) + n
 
     @property
     def _counts_attn(self):
@@ -527,7 +573,10 @@ class ServeLoop:
             filled = (state[1] if state is not None
                       and state[0] == req.admit_seq else req.cached_tokens)
             end = min(filled + self.prefill_chunk, target)
-            toks = np.zeros((1, self.prefill_chunk), np.int32)
+            # Padding: 0, or for a model with state-space layers -1, which
+            # the program reads as a position that advances no state.
+            toks = np.full((1, self.prefill_chunk), -int(self.has_state),
+                           np.int32)
             toks[0, :end - filled] = ctx[filled:end]
             bt = np.asarray(
                 self.batcher.block_table(req, self.geo.max_blocks),
@@ -902,6 +951,9 @@ class ServeLoop:
                 **{name: dict(by_kind)
                    for name, by_kind in self.attn_stats.items()},
                 "kv_select_share": self._kv_select_share()}
+        if self._state_layers:
+            snap["state"] = {name: dict(by_kind)
+                             for name, by_kind in self.state_stats.items()}
         if self.moe:
             ms, load = self.moe_stats, self._moe_load
             steps = ms["calls"].get("decode", 0) * len(self.cfg.moe_layers)

@@ -28,7 +28,8 @@ in :mod:`.engine` executes):
 - **Preemption**: when a running request crosses a page boundary and no
   page is free, the *youngest* running request is evicted back to the
   waiting queue (its pages freed, its generated tokens kept so the
-  re-prefill replays prompt + generated prefix). Admission-reserved
+  re-prefill replays prompt + generated prefix from position 0, which is
+  also where the engine zeroes a state-space slot's row). Admission-reserved
   pages can therefore never deadlock the batch: the oldest request can
   always finish.
 
@@ -236,7 +237,7 @@ class ContinuousBatcher:
 
     def __init__(self, allocator, max_batch=DEFAULT_MAX_BATCH,
                  mode="continuous", prefix_cache=None, spec_tokens=0,
-                 ring_allocator=None, ring_blocks=0):
+                 ring_allocator=None, ring_blocks=0, state_rows=False):
         if mode not in ("continuous", "static"):
             raise ValueError(f"serve mode must be 'continuous' or "
                              f"'static', got {mode!r}")
@@ -254,6 +255,10 @@ class ContinuousBatcher:
         # slot always finds one.
         self.ring_alloc = ring_allocator
         self.ring_blocks = int(ring_blocks) if ring_allocator else 0
+        # State-space layers (kv_cache.py): row ``slot + 1`` of their arrays
+        # is the slot's own, so there is nothing to allocate or free; the
+        # block table carries it as its last column.
+        self.state_rows = bool(state_rows)
         self.waiting = collections.deque()
         self.running = {}          # slot -> Request
         self.done = []
@@ -462,14 +467,16 @@ class ContinuousBatcher:
     def block_table(self, req, max_blocks):
         """The request's page list padded with trash page 0 to the
         engine's fixed block-table width; behind it the pages of the
-        request's ring, where the model has window layers."""
+        request's ring, where the model has window layers, and last its
+        slot's state row (``slot + 1``), where it has state-space layers."""
         if len(req.pages) > max_blocks:
             raise ValueError(
                 f"request {req.rid} holds {len(req.pages)} pages > "
                 f"max_blocks {max_blocks} (context "
                 f"{req.context_len} too long for the cache geometry)")
         return (list(req.pages) + [0] * (max_blocks - len(req.pages))
-                + list(req.ring_pages))
+                + list(req.ring_pages)
+                + ([req.slot + 1] if self.state_rows else []))
 
     def idle(self):
         return not self.waiting and not self.running

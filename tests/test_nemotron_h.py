@@ -1,0 +1,503 @@
+"""A hybrid whose layers are EACH a mixer or a feed-forward alone
+(``benchmark/configs/nemotron-3-super-120b.json``: state-space (Mamba-2)
+layers on slot-owned state, one grouped-query attention layer on pages with no
+rotation, sigmoid-routed ``relu2`` experts in a latent beside a shared expert,
+a share of the experts held here) as an instance of ``models/transformer.py``'s
+one block, at a tiny size on the CPU, against the benchmark's plain reference
+(``benchmark/reference/nemotron_h.py``: the file the chip run is judged by,
+whose recurrence runs token by token where the program's runs in blocks).
+
+The tiny model is made the way the benchmark's runner makes the real one: the
+configuration FILE's ``model`` mapping applied to the file's own keys and the
+published pattern's letters, here with every size shrunk and the ratios kept
+(heads over groups, query over key/value heads, a latent narrower than the
+stream, 16 experts top-3 with 8 held). Everything runs in float32, where
+program and reference must agree to rounding although the one carries state
+through chunk programs and decode steps and the other scans the sequence once.
+"""
+import dataclasses
+import hashlib
+import importlib.util
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.serving import engine, kv_cache
+from horovod_tpu.serving import loop as serve_loop
+from horovod_tpu.serving.scheduler import Request
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, path))
+    module = importlib.util.module_from_spec(spec)
+    sys.path.insert(0, ROOT)
+    try:
+        spec.loader.exec_module(module)
+    finally:
+        sys.path.remove(ROOT)
+    return module
+
+
+reference = _load("benchmark/reference/nemotron_h.py", "nemotron_h_reference")
+runner = _load("benchmark/runners/serve_hybrid.py", "serve_hybrid_runner")
+FILE = json.load(open(os.path.join(ROOT, "benchmark", "configs",
+                                   "nemotron-3-super-120b.json")))
+PAGE, CHUNK, TOL = 4, 8, 2e-5
+
+
+def _config(**overrides):
+    """The configuration file with every size shrunk."""
+    config = json.loads(json.dumps(FILE))
+    config.update(
+        hidden_size=32, expand=2, mamba_num_heads=8, mamba_head_dim=8,
+        n_groups=2, ssm_state_size=16, chunk_size=4, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, moe_latent_size=16,
+        moe_intermediate_size=24, intermediate_size=24,
+        moe_shared_expert_intermediate_size=48, n_routed_experts_published=16,
+        n_routed_experts=8, experts_held=[4, 8], num_experts_per_tok=3,
+        vocab_size=96, max_position_embeddings=256,
+        # the period's last five letters, EMEM*: every kind, fewer to compile
+        num_hidden_layers=5, layers_run=[32, 37])
+    config["model"].update(dtype="float32", param_dtype="float32")
+    config["assumed"]["serve"]["chunk"] = CHUNK
+    config.update(overrides)
+    return config
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    config = _config()
+    cfg = runner.model_config(config)
+    params = runner.make_params(cfg, jax.random.PRNGKey(3))
+    return config, cfg, params
+
+
+def _tokens(n, seed=1):
+    return jax.random.randint(jax.random.PRNGKey(seed), (1, n), 0, 96)
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2))
+                 / np.sqrt(np.mean(want ** 2)))
+
+
+# ---- the description ------------------------------------------------------
+
+def test_the_pattern_names_every_layer(tiny):
+    config, cfg, params = tiny
+    assert FILE["hybrid_override_pattern"][26:37] == "EMEMEMEMEM*"
+    assert len(FILE["hybrid_override_pattern"]) == 88
+    kinds = [type(cfg.attn_of(li)).__name__ if cfg.has_mixer(li) else None
+             for li in range(cfg.n_layers)]
+    assert kinds == [None, "StateSpaceMixer"] * 2 + ["MultiHeadAttention"]
+    assert cfg.moe_layers == [0, 2] and cfg.described
+    for li, layer in enumerate(params["layers"]):
+        assert ("ln1" in layer) == cfg.has_mixer(li)
+        assert ("ln2" in layer) == cfg.has_ffn(li) == ("router" in layer)
+        assert "w_gate" not in layer                        # relu2: no gate
+    expert = params["layers"][0]
+    assert expert["w_in"].shape == (8, 16, 24)              # at the latent
+    assert expert["w_latent_in"].shape == (32, 16)
+    assert expert["shared"]["w_in"].shape == (32, 48)       # at the stream
+    assert expert["router"].shape == (32, 16)
+    specs = tfm.param_specs(cfg)
+    assert jax.tree.structure(specs, is_leaf=lambda s: isinstance(
+        s, jax.sharding.PartitionSpec)) == jax.tree.structure(params)
+
+
+def test_the_file_keeps_the_published_widths_and_counts():
+    """``reduced_why``'s count against ``init_params``' shapes at the
+    file's own sizes (shapes only: nothing is allocated)."""
+    cfg = runner.model_config(FILE)
+    a = cfg.attn_of(1)
+    assert (a.d_inner, a.conv_dim, a.in_width) == (8192, 10240, 18560)
+    assert (cfg.d_model, cfg.expert_latent, cfg.ffn_width, cfg.top_k,
+            cfg.n_experts, cfg.n_held) == (4096, 1024, 2688, 22, 512, 128)
+    shapes = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+
+    def count(tree):
+        return sum(x.size for x in jax.tree.leaves(tree))
+
+    layers = shapes["layers"]
+    assert round(count(layers[1]) / 1e6, 2) == 109.64      # state-space
+    assert round(count(layers[10]) / 1e6, 2) == 35.66      # attention
+    assert round(count(layers[0]) / 1e6, 1) == 759.2       # 128 + 1 experts
+    assert round(count(shapes) / 1e6) == 4648              # 9.30 GB in bf16
+    assert FILE["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                               "vocab_size"]
+
+
+@pytest.mark.parametrize("name,want", [
+    ("gpt2-medium", ("7be6b23fef6e4d70", 324.2838138082962,
+                     832.00741314888, 0.150390625)),
+    ("gpt2-large", ("1771dc771d1f3bed", 245.06268888654824,
+                    506.3751511115115, 0.031105294823646545)),
+    ("olmoe-1b-7b", ("c499741da78c9428", 291.8016100555367,
+                     2364.094068747887, -2.0894641876220703)),
+    ("dots3-note-prev", ("80d725bb23580829", 338.08831915794576,
+                         2377.352685188729, 2.488593339920044)),
+    ("laguna-s-2.1", ("f056f6601c751ab5", 204.14970615382572,
+                      2424.1291634586814, -0.6806838512420654)),
+])
+def test_a_standing_kind_builds_what_it_built_before(name, want):
+    """A layer with both halves is still the default: the five kinds of
+    model the benchmark had (tiny instances) make the parameter tree, the
+    parameters and the logits that the commit before this change made
+    (digests taken there)."""
+    latent = dict(n_heads=4, q_rank=16, kv_rank=16, nope_dim=8, rope_dim=8,
+                  v_dim=8)
+    experts = dict(vocab_size=128, d_model=32, n_heads=4, n_layers=3, d_ff=48,
+                   d_expert=16, max_seq_len=64, n_experts=8, router="sigmoid",
+                   shared_experts=1, dense_layers=1, norm_topk=True,
+                   norm="rmsnorm", pos="rope", ffn="swiglu",
+                   tie_embeddings=False, dtype="float32")
+    cfg = {
+        "gpt2-medium": lambda: tfm.tiny(),
+        "gpt2-large": lambda: tfm.TransformerConfig(
+            vocab_size=200, d_model=40, n_heads=5, n_layers=3, d_ff=96,
+            max_seq_len=32, dtype="float32"),
+        "olmoe-1b-7b": lambda: tfm.olmoe_1b_7b(
+            vocab_size=128, d_model=32, n_heads=4, n_layers=2, d_ff=16,
+            d_expert=16, max_seq_len=32, n_experts=8, top_k=2,
+            dtype="float32", param_dtype="float32"),
+        "dots3-note-prev": lambda: tfm.TransformerConfig(
+            **experts, top_k=2, routed_scale=2.0, experts_held=(2, 4),
+            layer_attn=("full", "swa", "full"),
+            latent={"full": dict(latent, index_heads=2, index_dim=8,
+                                 index_rope_dim=4, index_topk=4),
+                    "swa": dict(latent, window=4)},
+            latent_rescale=True, attn_gate=True),
+        "laguna-s-2.1": lambda: tfm.TransformerConfig(
+            **experts, top_k=3, routed_scale=2.5, experts_held=(4, 4),
+            layer_attn=("full", "win", "win"),
+            multihead={
+                "full": dict(n_heads=4, n_kv_heads=2, head_dim=8,
+                             rope_share=0.5, gate=True,
+                             yarn=dict(factor=8, original_max=16,
+                                       attention_factor=1.2)),
+                "win": dict(n_heads=6, n_kv_heads=2, head_dim=8, window=4,
+                            gate=True)}),
+    }[name]()
+    params = tfm.init_params(jax.random.PRNGKey(7), cfg)
+    shapes = sorted((jax.tree_util.keystr(p), tuple(x.shape)) for p, x in
+                    jax.tree_util.tree_leaves_with_path(params))
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (2, 12), 0,
+                                cfg.vocab_size)
+    logits = np.asarray(tfm.forward(params, tokens, cfg), np.float64)
+    tree, total, logits_abs, one = want
+    assert hashlib.sha256(json.dumps(shapes).encode()).hexdigest()[:16] == tree
+    assert sum(np.asarray(x, np.float64).sum()
+               for x in jax.tree.leaves(params)) == pytest.approx(total,
+                                                                  rel=1e-9)
+    assert np.abs(logits).sum() == pytest.approx(logits_abs, rel=1e-6)
+    assert logits[0, -1, 3] == pytest.approx(one, rel=1e-5, abs=1e-7)
+    assert all(cfg.has_mixer(li) and cfg.has_ffn(li)
+               for li in range(cfg.n_layers))
+
+
+# ---- the mixer: blocks against token by token ------------------------------
+
+@pytest.mark.parametrize("window", [1, 5, 128, 300])
+def test_chunked_mixer_against_the_recurrence(window):
+    """``state_space_mix`` (blocks of 128, a scan over blocks; the one-token
+    update for a window of one) against the reference's ``lax.scan`` over
+    positions, in float32, from a state and a tail that are not zero."""
+    a = tfm.StateSpaceMixer(n_heads=4, head_dim=4, n_groups=2, state_size=8)
+    cfg = tfm.TransformerConfig(
+        vocab_size=32, d_model=16, n_layers=1, state_space={"M": a},
+        layer_attn=("M",), layer_parts=("mixer",), norm="rmsnorm",
+        dtype="float32")
+    layer = tfm._state_space_params(jax.random.PRNGKey(0), cfg, a)
+    layer["conv_b"] = 0.1 * jax.random.normal(jax.random.PRNGKey(1),
+                                              (a.conv_dim,))
+    keys = jax.random.split(jax.random.PRNGKey(window), 3)
+    u = jax.random.normal(keys[0], (window, 16))
+    tail = jax.random.normal(keys[1], (a.tail, a.conv_dim))
+    state = jax.random.normal(keys[2], (4, 4, 8))
+    hp = {"ssm": (4, 4, 2, 8, 4), "eps": cfg.norm_eps, "chunk": 512}
+    p = {"in_proj": layer["w_ssm_in"], "conv_w": layer["conv_w"],
+         "conv_b": layer["conv_b"], "dt_bias": layer["dt_bias"],
+         "A_log": layer["a_log"], "D": layer["ssm_skip"],
+         "gate_norm": layer["ssm_norm"]["scale"],
+         "out_proj": layer["w_ssm_out"]}
+    with jax.default_matmul_precision("highest"):
+        out, state_out, tail_out = reference.mixer(
+            u, p, hp, reference.knobs(hp), state, tail)
+    got = tfm.state_space_mix(u[None], layer, a, cfg, tail[None],
+                              state[None])
+    for g, w in zip(got, (out, tail_out, state_out)):
+        assert _rel(g[0], w) < 1e-5
+
+
+def test_dead_positions_leave_tail_and_state_alone(tiny):
+    _, cfg, params = tiny
+    a, layer = cfg.attn_of(1), params["layers"][1]
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    u = jax.random.normal(keys[0], (3, 10, 32))
+    tail = jax.random.normal(keys[1], (3, a.tail, a.conv_dim))
+    state = jax.random.normal(keys[2], (3, 8, 8, 16))
+    live = jnp.arange(10)[None] < jnp.asarray([[6], [0], [10]])
+    out, t1, s1 = tfm.state_space_mix(u, layer, a, cfg, tail, state, live)
+    short = tfm.state_space_mix(u[:1, :6], layer, a, cfg, tail[:1], state[:1])
+    for g, w in zip((out[:1, :6], t1[:1], s1[:1]), short):
+        assert _rel(g, w) < 1e-6
+    # A slot with no live position: bit for bit.
+    assert np.array_equal(t1[1], tail[1]) and np.array_equal(s1[1], state[1])
+    whole = tfm.state_space_mix(u[2:], layer, a, cfg, tail[2:], state[2:])
+    assert _rel(s1[2], whole[2][0]) < 1e-6
+
+
+# ---- the model against the reference --------------------------------------
+
+def test_forward_against_the_reference(tiny):
+    config, cfg, params = tiny
+    tokens = _tokens(37)
+    hp = reference.hyper(config)
+    want, routes = reference.logits(reference.from_horovod_tpu(params),
+                                    tokens, hp, with_routes=True)
+    got = tfm.forward(params, tokens, cfg)
+    assert _rel(got, want) < TOL
+    assert routes.shape == (2, 1, 37, 3)
+
+
+@pytest.mark.parametrize("fault", reference.FAULTS)
+def test_a_reference_fault_moves_the_logits(tiny, fault):
+    config, cfg, params = tiny
+    tokens = _tokens(21)
+    hp = reference.hyper(config)
+    w = reference.from_horovod_tpu(params)
+    sound = reference.logits(w, tokens, hp)
+    bad = reference.logits(w, tokens, hp, kn=reference.knobs(hp, fault))
+    assert _rel(bad, sound) > 100 * TOL
+
+
+def test_a_bf16_state_fails_the_comparison(tiny, monkeypatch):
+    """Tight enough that a state kept in bfloat16 is refused."""
+    config, cfg, params = tiny
+    tokens = _tokens(37)
+    want = reference.logits(reference.from_horovod_tpu(params), tokens,
+                            reference.hyper(config))
+    sound = tfm._ssd_blocks
+
+    def rounded(x, step, rate, b_in, c_out, state, block):
+        ys = []         # block by block, the state between them in bfloat16
+        for at in range(0, x.shape[1], block):
+            y, state = sound(*(v[:, at:at + block]
+                               for v in (x, step)), rate,
+                             *(v[:, at:at + block] for v in (b_in, c_out)),
+                             state, block)
+            state = state.astype(jnp.bfloat16).astype(jnp.float32)
+            ys.append(y)
+        return jnp.concatenate(ys, 1), state
+
+    monkeypatch.setattr(tfm, "_ssd_blocks", rounded)
+    assert _rel(tfm.forward(params, tokens, cfg), want) > 5 * TOL
+
+
+def test_the_four_shares_add_up_to_the_whole_layer(tiny):
+    """Guide section 4: every chip's held experts' part, with the shared
+    expert counted once, is the uncut layer; and the program's layer is its
+    own share's."""
+    config, cfg, params = tiny
+    layer = params["layers"][0]
+    h = jax.random.normal(jax.random.PRNGKey(2), (1, 9, 32))
+    key = jax.random.PRNGKey(4)
+    whole_cfg = dataclasses.replace(cfg, experts_held=())
+    whole = tfm._layer_ffn_params(jax.random.split(key, 8), whole_cfg, 0)
+    whole["router_bias"] = 0.02 * jax.random.normal(key, (16,))
+    hp = dict(reference.hyper(config), experts_held=(0, 16))
+    kn = jax.tree.map(jnp.asarray, reference.knobs(hp))
+
+    def as_reference(p):
+        return reference.from_horovod_tpu({
+            "layers": [dict(p, ln2={"scale": jnp.ones(32)})], "embed": None,
+            "head": None, "final_ln": {"scale": None}})["layers"][0]
+
+    with jax.default_matmul_precision("highest"):
+        shared, routed, _ = reference.moe_parts(h[0], as_reference(whole), hp,
+                                                kn)
+        total = 0
+        for offset in range(0, 16, 4):
+            share = dict(whole, w_in=whole["w_in"][offset:offset + 4],
+                         w_out=whole["w_out"][offset:offset + 4])
+            hp_s = dict(hp, experts_held=(offset, 4))
+            s, r, _ = reference.moe_parts(h[0], as_reference(share), hp_s, kn)
+            assert _rel(s, shared) < 1e-6
+            total = total + r
+            got, _ = tfm._moe_ffn(h, share, dataclasses.replace(
+                cfg, experts_held=(offset, 4)))
+            assert _rel(got[0], s + r) < TOL
+    assert _rel(total, routed) < 1e-5
+    got, _ = tfm._moe_ffn(h, whole, whole_cfg)
+    assert _rel(got[0], shared + routed) < TOL
+    dense = tfm._moe_dense(
+        jnp.einsum("bsd,dl->bsl", h, whole["w_latent_in"]),
+        *tfm._route(h, whole, whole_cfg), whole, whole_cfg)
+    assert _rel(jnp.einsum("bsl,ld->bsd", dense, whole["w_latent_out"])[0],
+                routed) < TOL
+
+
+# ---- the cache and the programs -------------------------------------------
+
+def test_cache_shapes_by_layer_kind(tiny):
+    _, cfg, _ = tiny
+    geo = kv_cache.with_rings(kv_cache.geometry(33, PAGE, 64), cfg, CHUNK, 3)
+    assert (geo.state_rows, geo.ring_blocks, geo.table_width) == (4, 0, 17)
+    assert kv_cache.layer_shapes(cfg, geo, 0) == (None, None)   # experts
+    assert kv_cache.layer_shapes(cfg, geo, 1) == ((4, 3, 128), (4, 8, 8, 16))
+    assert kv_cache.layer_shapes(cfg, geo, 4) == ((33, PAGE, 32),) * 2
+    cache = kv_cache.make_cache(cfg, geo)
+    assert cache["k"][0] is None and cache["v"][0] is None
+    assert cache["k"][1].dtype == jnp.float32                   # compute dtype
+    assert cache["v"][1].dtype == jnp.float32
+    half = dataclasses.replace(cfg, dtype="bfloat16")
+    assert kv_cache.make_cache(half, geo)["k"][1].dtype == jnp.bfloat16
+    assert kv_cache.make_cache(half, geo)["v"][1].dtype == jnp.float32
+    assert kv_cache.cache_bytes(half, geo) == (
+        2 * (4 * 3 * 128 * 2 + 4 * 8 * 8 * 16 * 4) + 2 * 33 * PAGE * 32 * 2)
+    assert kv_cache.cache_bytes(cfg, geo) == sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    with pytest.raises(ValueError, match="state rows"):
+        kv_cache.layer_shapes(cfg, kv_cache.geometry(33, PAGE, 64), 1)
+
+
+def _loop(cfg, params, n_pages=65, max_batch=3, **kw):
+    return serve_loop.ServeLoop(
+        params, cfg, geo=kv_cache.geometry(n_pages, PAGE, 64),
+        max_batch=max_batch, prefill_chunk=CHUNK, **kw)
+
+
+def _greedy(params, cfg, req):
+    """Whether ``req.generated`` is what greedy decoding of ``forward``
+    generates after ``req.prompt``: one causal pass over prompt + generated
+    (padded to one length, so one compilation) predicts each of them."""
+    seq = list(req.prompt) + list(req.generated)
+    logits = tfm.forward(params, jnp.asarray([seq + [0] * (64 - len(seq))]),
+                         cfg)[0]
+    n = len(req.prompt)
+    return [int(t) for t in jnp.argmax(logits[n - 1:len(seq) - 1], -1)]
+
+
+@pytest.fixture(scope="module")
+def programs(tiny):
+    """One loop's compiled programs and cache for the cases below: each
+    starts its prompt in the rows the case before it left."""
+    _, cfg, params = tiny
+    return _loop(cfg, params)
+
+
+@pytest.mark.parametrize("n", [5, 19, 24])
+def test_chunks_then_decode_against_one_forward(tiny, programs, n):
+    """A prompt filled in chunks of 8 (padding -1) and decoded four steps
+    through the engine's programs, in a slot other than 0 and on rows that
+    are dirty from the second case on, against one full ``forward``: every
+    logit row of every chunk and step."""
+    _, cfg, params = tiny
+    loop = programs
+    geo, slot = loop.geo, 2
+    prompt = [int(t) for t in _tokens(n, seed=n)[0]]
+    table = np.zeros(geo.table_width, np.int32)
+    table[:8] = np.arange(1, 9)
+    table[-1] = slot + 1
+    rows = []
+    for start in range(0, n, CHUNK):
+        toks = np.full((1, CHUNK), -1, np.int32)
+        toks[0, :len(prompt[start:start + CHUNK])] = prompt[start:start + CHUNK]
+        loop.cache, lg, *_ = loop.chunk_fn(
+            params, loop.cache, toks, np.asarray([start], np.int32),
+            table[None], np.ones(1, bool))
+        rows.append(np.asarray(lg[0, :min(CHUNK, n - start)]))
+    seq = prompt + [int(np.argmax(rows[-1][-1]))]
+    tables = np.zeros((3, geo.table_width), np.int32)
+    tables[slot] = table
+    active = np.arange(3) == slot
+    for _ in range(4):
+        tokens, positions = np.zeros(3, np.int32), np.zeros(3, np.int32)
+        tokens[slot], positions[slot] = seq[-1], len(seq) - 1
+        loop.cache, lg, *_ = loop.decode_fn(params, loop.cache, tokens,
+                                            positions, tables, active)
+        rows.append(np.asarray(lg[slot:slot + 1]))
+        seq.append(int(np.argmax(rows[-1][-1])))
+    want = tfm.forward(params, jnp.asarray([seq[:-1]]), cfg)[0]
+    assert _rel(np.concatenate(rows), want) < TOL
+    # The other slots' rows were never touched.
+    assert not np.asarray(loop.cache["v"][1][1]).any()
+    assert np.asarray(loop.cache["v"][1][slot + 1]).any()
+
+
+def test_a_reused_slot_gives_the_logits_of_a_fresh_run(tiny):
+    """Five requests through three slots: the later ones start in rows the
+    earlier ones left dirty, and generate what a fresh model generates."""
+    _, cfg, params = tiny
+    loop = _loop(cfg, params)
+    loop.warmup()
+    rng = np.random.default_rng(0)
+    reqs = [Request(rid=i, prompt=rng.integers(0, 96, 9 + 3 * i).tolist(),
+                    max_new_tokens=5, arrival_t=0.001 * (i + 1))
+            for i in range(5)]
+    _, done = loop.run(reqs)
+    assert len(done) == 5
+    for r in done:
+        assert r.generated == _greedy(params, cfg, r), r.rid
+    state = serve_loop.serve_stats()["state"]
+    assert state["resets"]["chunk"] == 5 * 2          # requests x layers
+    assert state["resets"].get("decode", 0) == 0
+    assert state["rows"]["decode"] == state["tokens"]["decode"]
+    assert state["bytes"]["decode"] == 2 * state["rows"]["decode"] * (
+        3 * 128 * 4 + 8 * 8 * 16 * 4)
+    assert state["kv_bytes"]["decode"] > 0
+
+
+def test_a_preempted_request_replays_from_a_zeroed_row(tiny):
+    """Too few pages for three growing requests: the youngest is preempted,
+    its pages freed, and its replay (prompt + generated, from position 0)
+    finds its row zeroed: every request generates a fresh run's tokens."""
+    _, cfg, params = tiny
+    loop = _loop(cfg, params, n_pages=14)
+    rng = np.random.default_rng(1)
+    reqs = [Request(rid=i, prompt=rng.integers(0, 96, 10).tolist(),
+                    max_new_tokens=12, arrival_t=0.001 * (i + 1))
+            for i in range(3)]
+    summary, done = loop.run(reqs)
+    assert summary["preemptions"] > 0
+    for r in done:
+        assert r.generated == _greedy(params, cfg, r), r.rid
+
+
+def test_no_speculation_and_no_prefix_cache_over_state(tiny):
+    _, cfg, params = tiny
+    with pytest.raises(ValueError, match="roll the slot's state back"):
+        _loop(cfg, params, spec_tokens=2)
+    loop = _loop(cfg, params, prefix_cache=True)
+    assert loop.prefix is None and loop.prefill_fn is None
+    assert loop.batcher.state_rows
+    req = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=2)
+    loop.batcher.submit(req)
+    loop.batcher.admit()
+    assert loop.batcher.block_table(req, loop.geo.max_blocks)[-1] \
+        == req.slot + 1
+
+
+def test_the_scopes_reach_the_compiled_program(tiny):
+    """``state_space`` and ``expert_latent`` are in the lowered decode
+    program's op names, where the benchmark's readers find them."""
+    _, cfg, params = tiny
+    geo = kv_cache.with_rings(kv_cache.geometry(33, PAGE, 64), cfg, CHUNK, 2)
+    text = engine.make_decode_step(cfg, geo, max_batch=2).lower(
+        params, kv_cache.make_cache(cfg, geo), np.zeros(2, np.int32),
+        np.zeros(2, np.int32), np.zeros((2, geo.table_width), np.int32),
+        np.zeros(2, bool)).as_text(debug_info=True)
+    for scope in ("state_space", "expert_latent", "experts", "attention"):
+        assert f"/{scope}/" in text, scope

@@ -83,7 +83,8 @@ def test_parse(case):
 def test_names_are_spelled_once():
     assert scopes.PHASES == ("grad", "grad_reduce", "optimizer")
     assert scopes.SCOPES == ("embed", "layer_norm", "rms_norm", "attention",
-                             "mlp", "experts", "loss", "head")
+                             "mlp", "experts", "loss", "head", "state_space",
+                             "expert_latent")
     assert not set(scopes.PHASES) & set(scopes.SCOPES)
 
 

@@ -18,6 +18,7 @@ gathers nothing (PR 28).
 import dataclasses
 import os
 import re
+import sys
 
 import numpy as np
 import pytest
@@ -543,3 +544,77 @@ def test_grouped_cell_programs_fit_one_chip(topo, as_on_the_chip):
         # pages nor a query block's scores over them.
         for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", text):
             assert str(geo.max_kv) not in m.group(1).split(","), m.group(0)
+
+
+# ---- the serving programs at benchmark/configs/nemotron-3-super-120b.json ----
+
+def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip):
+    """``nemotron-serve-reason-over``'s two programs (the 512-token chunk fill
+    and the decode step) at the cell's geometry: eleven layers that are each
+    a mixer or a feed-forward, five state-space layers on slot-owned rows
+    (float32 state), one attention layer of 32 query heads over 2 key/value
+    heads on pages, five expert layers with no cache. The chip's compiler
+    takes the grouped paged kernel at a group of 16; weights + cache +
+    temporaries stay on the chip; the cache is aliased through, and the
+    decode step holds no second copy of a layer's state (0.54 GB: a gather
+    of the rows, or the blocked scan at a block of one, made one a layer)."""
+    import importlib.util
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron-3-super-120b.json")) as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "serve_hybrid", os.path.join(root, "benchmark", "runners",
+                                     "serve_hybrid.py"))
+    runner = importlib.util.module_from_spec(spec)
+    sys.path.insert(0, root)
+    try:
+        spec.loader.exec_module(runner)
+        cfg = runner.model_config(config)
+    finally:
+        sys.path.remove(root)
+    srv = config["assumed"]["serve"]
+    B, chunk = srv["max_batch"], srv["chunk"]
+    geo = kv_cache.with_rings(
+        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
+        cfg, chunk, B)
+    assert (geo.max_kv, geo.state_rows, geo.table_width) == (8192, B + 1, 513)
+    assert engine.grouped_kernels(cfg, geo, None)
+    params, cache = jax.tree.map(
+        lambda x: _on_chip(topo, x.shape, x.dtype),
+        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
+                                kv_cache.make_cache(cfg, geo))))
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert 4.64e9 < n_params < 4.66e9           # the file's reduced_why
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert kv_cache.cache_bytes(cfg, geo) == held - 2 * n_params
+    assert 13.0e9 < held < 13.2e9          # 78 % of the chip's 16.91e9
+    state = 4 * B * 128 * 64 * 128         # one layer's rows in float32
+
+    def slots(b, *q):
+        return [_on_chip(topo, s, d) for s, d in (
+            ((b, *q), jnp.int32), ((b,), jnp.int32),
+            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
+
+    for name, fn, args in (
+            ("chunk", engine.make_chunk_step(cfg, geo, q_len=chunk),
+             slots(1, chunk)),
+            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
+             slots(B))):
+        compiled = fn.lower(params, cache, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= kv_cache.cache_bytes(cfg, geo)
+        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
+        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
+        assert memory.temp_size_in_bytes < state / 2, name
+        text = compiled.as_text()
+        calls = [line for line in text.splitlines()
+                 if re.match(r"\s*%paged_full_attention[.\d]* = ", line)
+                 and "tpu_custom_call" in line]
+        assert len(calls) == 1, name
+        # Two products an expert layer: no gate matrix.
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
+            == 2 * len(cfg.moe_layers)
