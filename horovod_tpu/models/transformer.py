@@ -1363,7 +1363,7 @@ def _ssd_blocks(x, step, rate, b_in, c_out, state, block):
 
 
 def state_space_mix(u, layer, a: StateSpaceMixer, cfg, tail=None, state=None,
-                    live=None):
+                    live=None, recur=None):
     """THE state-space mixer, written once: the normed input ``u [B, S, D]``
     of a window of ``S`` consecutive tokens a sequence -> (``out [B, S, D]``,
     the convolution tail leaving ``[B, conv_kernel - 1, conv_dim]`` in the
@@ -1376,6 +1376,12 @@ def state_space_mix(u, layer, a: StateSpaceMixer, cfg, tail=None, state=None,
     the state, and its output is garbage nobody reads. `forward` calls this
     with no state over the whole sequence, the serving chunk program with the
     slot's, the decode step with a window of one.
+
+    ``recur(x, step, rate, b_in, c_out) -> (y, the state leaving)`` stands in
+    for the recurrence where the caller owns the state where it lies and
+    ``state`` is None: the decode step's kernel over the layer's own array
+    (``serving/engine.py`` ``_state_layer``), the same values as
+    :func:`_ssd_step`. Everything around it is this function's either way.
 
     ``[z | xBC | dt] = u W_in``; ``xBC`` through the depthwise causal
     convolution and SiLU; ``step = softplus(dt + dt_bias)``, ``rate =
@@ -1390,8 +1396,10 @@ def state_space_mix(u, layer, a: StateSpaceMixer, cfg, tail=None, state=None,
                      a.conv_kernel)
     if tail is None:
         tail = jnp.zeros((B, a.tail, a.conv_dim), dt)
-    if state is None:
-        state = jnp.zeros((B, H, P, N), f32)
+    if recur is None:
+        if state is None:
+            state = jnp.zeros((B, H, P, N), f32)
+        recur = functools.partial(_ssd_blocks, state=state, block=a.block)
     if live is None:
         live = jnp.ones((B, S), bool)
     zxd = jnp.einsum("bsd,dw->bsw", u, layer["w_ssm_in"].astype(dt))
@@ -1412,8 +1420,8 @@ def state_space_mix(u, layer, a: StateSpaceMixer, cfg, tail=None, state=None,
     x = xbc[..., :a.d_inner].reshape(B, S, H, P)
     b_in = xbc[..., a.d_inner:a.d_inner + G * N].reshape(B, S, G, N)
     c_out = xbc[..., a.d_inner + G * N:].reshape(B, S, G, N)
-    y, state = _ssd_blocks(x, step, -jnp.exp(layer["a_log"].astype(f32)),
-                           b_in, c_out, state, a.block)
+    y, state = recur(x, step, -jnp.exp(layer["a_log"].astype(f32)), b_in,
+                     c_out)
     y = y + layer["ssm_skip"].astype(f32)[:, None] * x.astype(f32)
     y = y.reshape(B, S, G, -1) * jax.nn.silu(z.astype(f32)).reshape(
         B, S, G, -1)

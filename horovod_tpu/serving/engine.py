@@ -97,7 +97,12 @@ its sequence (position 0: whoever held the slot before, and a preempted
 request's replay alike), handed to ``transformer.state_space_mix`` with the
 window's live positions, and written back in place (:func:`_state_layer`):
 the chunk program runs the chunked scan over its 512 positions, the decode
-step the same function over a window of one. A chunk's padding must not
+step the same function over a window of one. On a TPU backend with no mesh
+(:func:`state_kernels`) the decode step's recurrence is the Pallas kernel
+``ssm_decode_update`` (:mod:`horovod_tpu.ops.pallas_ssm`, the instruction
+name a device trace shows): one pass over the layer's own state array,
+updated in place and read out from the same registers, where the plain
+``transformer._ssd_step`` compiles to three. A chunk's padding must not
 advance the state, so for such a model a NEGATIVE token id marks a padding
 position (the loop pads so; positions are dead from the first negative id
 on). A layer with no mixer touches no cache.
@@ -115,6 +120,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..models import transformer as tfm
 from ..ops import pallas_latent
 from ..ops import pallas_paged_attention as paged_attention
+from ..ops import pallas_ssm
 from . import kv_cache
 
 
@@ -167,6 +173,17 @@ def grouped_kernels(cfg, geo, mesh):
             and all(paged_attention.grouped_supported(
                 geo.page_size, a.head_dim, cfg.compute_dtype)
                 for _, a in cfg.multihead))
+
+
+def state_kernels(cfg, geo, mesh):
+    """Whether the decode step's state-space layers update their state
+    through :func:`pallas_ssm.ssm_decode_update`, one pass over the layer's
+    own array: a TPU backend, no mesh, ``attn_impl`` left open, and shapes
+    the kernel tiles. Else (and in every chunk program) the state goes
+    through ``transformer._ssd_blocks``."""
+    return (bool(cfg.state_space) and mesh is None
+            and cfg.attn_impl == "auto" and jax.default_backend() == "tpu"
+            and all(pallas_ssm.supported(a) for _, a in cfg.state_space))
 
 
 def _check_positions(cfg, n, what):
@@ -342,13 +359,19 @@ def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
     return k_c, v_c, tfm.grouped_attend(q, *rows, a, allowed, dt)
 
 
-def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables):
+def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False):
     """One state-space layer of a chunk or decode program: the slots' rows of
     the layer's tail and state arrays, zeroed where the window begins its
     sequence (a live slot whose ``q_pos [B, Q]`` starts at 0), through
     ``mix(tail, state, live) -> (out, tail, state)`` with ``ok [B, Q]`` the
     live positions, and back into the same rows -> (the layer's arrays, ``out
     [B, Q, D]``). A dead slot's row is left as it was.
+
+    With ``kernels`` (the decode step on a TPU, :func:`state_kernels`) the
+    state array is not sliced at all: ``mix`` is handed the kernel as its
+    recurrence (``recur``), which updates rows ``1 ..`` of the array where
+    they lie and reads ``y`` out in the same pass, a slot that begins
+    entering on zeros inside it. The tail goes the way below.
 
     A program over every slot (the decode step: batch row ``b`` IS slot
     ``b``) takes rows ``1 ..`` where they lie, a slice and not a gather, and
@@ -361,20 +384,28 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables):
     alive = jnp.any(ok, axis=1)
     begins = alive & (q_pos[:, 0] == 0)
     whole = q_pos.shape[0] == state_c.shape[0] - 1
-    if whole:
+    recur = None
+    if whole and kernels:        # the state stays where it lies
+        tail, state = tail_c[1:], None
+        recur = functools.partial(
+            pallas_ssm.ssm_decode_update, state=state_c, begins=begins,
+            interpret=jax.default_backend() != "tpu")
+    elif whole:
         tail, state = tail_c[1:], state_c[1:]
     else:
         rows = jnp.where(alive, tables[:, -1], 0)
         tail, state = tail_c[rows], state_c[rows]
     tail = jnp.where(begins[:, None, None], 0, tail)
-    state = jnp.where(begins[:, None, None, None], 0, state)
-    out, tail, state = mix(tail, state, ok)
+    if recur is None:
+        state = jnp.where(begins[:, None, None, None], 0, state)
+    out, tail, state = mix(tail, state, ok, recur=recur)
     tail = tail.astype(tail_c.dtype)
-    if whole:
-        return (jax.lax.dynamic_update_slice(tail_c, tail, (1, 0, 0)),
-                jax.lax.dynamic_update_slice(state_c, state, (1, 0, 0, 0)),
-                out)
-    return tail_c.at[rows].set(tail), state_c.at[rows].set(state), out
+    if not whole:
+        return tail_c.at[rows].set(tail), state_c.at[rows].set(state), out
+    tail_c = jax.lax.dynamic_update_slice(tail_c, tail, (1, 0, 0))
+    if recur is None:
+        state = jax.lax.dynamic_update_slice(state_c, state, (1, 0, 0, 0))
+    return tail_c, state, out
 
 
 def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
@@ -532,7 +563,8 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
         grouped = functools.partial(_grouped_layer, **kinds,
                                     kernels=grouped_kernels(cfg, geo, mesh))
         state = functools.partial(_state_layer, q_pos=positions[:, None],
-                                  ok=active[:, None], tables=block_tables)
+                                  ok=active[:, None], tables=block_tables,
+                                  kernels=state_kernels(cfg, geo, mesh))
         block_tables = _context_tables(block_tables, geo)
         blk = positions // geo.page_size
         slot = positions % geo.page_size

@@ -390,12 +390,19 @@ def _greedy(params, cfg, req):
     return [int(t) for t in jnp.argmax(logits[n - 1:len(seq) - 1], -1)]
 
 
-@pytest.fixture(scope="module")
-def programs(tiny):
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["plain", "kernel"])
+def programs(tiny, request):
     """One loop's compiled programs and cache for the cases below: each
-    starts its prompt in the rows the case before it left."""
+    starts its prompt in the rows the case before it left. ``kernel``: the
+    decode step's state update through ``ops/pallas_ssm.py`` in interpret
+    mode, as the engine takes it on a TPU (steered here: the programs are
+    traced at their first call, so the steering lasts as long as they do)."""
     _, cfg, params = tiny
-    return _loop(cfg, params)
+    with pytest.MonkeyPatch.context() as steer:
+        if request.param:
+            steer.setattr(engine, "state_kernels", lambda *a: True)
+        yield _loop(cfg, params)
 
 
 @pytest.mark.parametrize("n", [5, 19, 24])

@@ -548,7 +548,7 @@ def test_grouped_cell_programs_fit_one_chip(topo, as_on_the_chip):
 
 # ---- the serving programs at benchmark/configs/nemotron-3-super-120b.json ----
 
-def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip):
+def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip, monkeypatch):
     """``nemotron-serve-reason-over``'s two programs (the 512-token chunk fill
     and the decode step) at the cell's geometry: eleven layers that are each
     a mixer or a feed-forward, five state-space layers on slot-owned rows
@@ -557,7 +557,8 @@ def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip):
     takes the grouped paged kernel at a group of 16; weights + cache +
     temporaries stay on the chip; the cache is aliased through, and the
     decode step holds no second copy of a layer's state (0.54 GB: a gather
-    of the rows, or the blocked scan at a block of one, made one a layer)."""
+    of the rows, or the blocked scan at a block of one, made one a layer) and
+    passes over it once, in the kernel ``ssm_decode_update``."""
     import importlib.util
     import json
 
@@ -593,6 +594,9 @@ def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip):
     assert kv_cache.cache_bytes(cfg, geo) == held - 2 * n_params
     assert 13.0e9 < held < 13.2e9          # 78 % of the chip's 16.91e9
     state = 4 * B * 128 * 64 * 128         # one layer's rows in float32
+    n_state = sum(isinstance(cfg.attn_of(li), tfm.StateSpaceMixer)
+                  and cfg.has_mixer(li) for li in range(cfg.n_layers))
+    assert n_state == 5
 
     def slots(b, *q):
         return [_on_chip(topo, s, d) for s, d in (
@@ -618,3 +622,33 @@ def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip):
         # Two products an expert layer: no gate matrix.
         assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
             == 2 * len(cfg.moe_layers)
+        # The decode step passes over a state-space layer's state ONCE: one
+        # kernel call a layer, which takes the layer's whole array and gives
+        # it back (aliased), and nothing else makes an array of a layer's
+        # rows (PR 43; XLA made three passes of `_ssd_step`). The chunk
+        # program's state goes the way it went.
+        updates = [line for line in text.splitlines()
+                   if re.match(r"\s*%ssm_decode_update[.\d]* = ", line)
+                   and "tpu_custom_call" in line]
+        assert len(updates) == (n_state if name == "decode" else 0), name
+        assert engine.state_kernels(cfg, geo, None)
+        if name == "decode":
+            rows = ["f32[%d,128,64,128]" % n for n in (B, B + 1)]
+            made = []
+            for line in text.splitlines():     # "%name = type op(..": layouts off
+                m = re.match(r"\s*(?:ROOT )?(%[\w.-]+) = (\([^)]*\)|\S+) "
+                             r"([\w-]+)\(", re.sub(r"\{[^}]*\}", "", line))
+                if m and any(r in m.group(2) for r in rows) \
+                        and m.group(3) not in ("parameter", "tuple",
+                                               "get-tuple-element"):
+                    made.append(m.group(1))
+            assert len(made) == n_state and all(
+                m.startswith("%ssm_decode_update") for m in made), made
+    # The kernel is the decode step's alone: the chunk program lowers to the
+    # same text whether the engine would take it or not.
+    chunk_text = []
+    for on in (True, False):
+        monkeypatch.setattr(engine, "state_kernels", lambda *a, on=on: on)
+        chunk_text.append(engine.make_chunk_step(cfg, geo, q_len=chunk).lower(
+            params, cache, *slots(1, chunk)).as_text())
+    assert chunk_text[0] == chunk_text[1]
