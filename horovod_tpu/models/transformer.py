@@ -82,7 +82,16 @@ class LatentAttention:
     included. ``index_topk`` > 0: a query sees the ``index_topk`` earlier
     keys that a small scorer rates highest (``index_heads`` heads of
     ``index_dim``, rotary on the first ``index_rope_dim``; DeepSeek-V3.2's
-    indexer), all of them while fewer precede it."""
+    indexer), all of them while fewer precede it. Neither: a query sees its
+    whole context.
+
+    ``q_rank`` 0: no query latent, one direct projection ``d_model ->
+    n_heads * (nope_dim + rope_dim)``. ``q_head_norm``: an RMSNorm over each
+    head's query (one scale of ``nope_dim + rope_dim`` for all heads) before
+    the rotation. ``yarn`` (a :class:`Yarn` or its fields): the rotated dims
+    turn at YaRN's frequencies. ``scale_mult``: a factor on the softmax scale
+    ``(nope_dim + rope_dim)^-1/2`` (DeepSeek's ``mscale^2`` under YaRN: it
+    multiplies the whole logit, so it cannot ride in cos and sin)."""
     n_heads: int
     q_rank: int
     kv_rank: int
@@ -95,6 +104,20 @@ class LatentAttention:
     index_dim: int = 0
     index_rope_dim: int = 0
     index_topk: int = 0
+    q_head_norm: bool = False
+    yarn: Optional["Yarn"] = None
+    scale_mult: float = 1.0
+
+    def __post_init__(self):
+        if isinstance(self.yarn, dict):
+            object.__setattr__(self, "yarn", Yarn(**self.yarn))
+        if self.index_topk and not self.q_rank:
+            raise ValueError("a key selection reads the query latent: "
+                             "index_topk needs q_rank > 0")
+
+    @property
+    def softmax_scale(self):
+        return self.scale_mult / math.sqrt(self.nope_dim + self.rope_dim)
 
     @property
     def row_width(self):
@@ -469,9 +492,10 @@ def _ffn_params(k, cfg, lead, F, D=None):
 
 def _latent_params(key, cfg, a: LatentAttention):
     """A latent-attention layer's matrices: query down/up through
-    ``q_rank``, key/value down to ``kv_rank + rope_dim`` and up to each
-    head's ``nope_dim + v_dim``, the output projection, the head gate, and
-    the selection's scorer."""
+    ``q_rank`` (or one direct projection where it is 0), the per-head query
+    norm, key/value down to ``kv_rank + rope_dim`` and up to each head's
+    ``nope_dim + v_dim``, the output projection, the head gate, and the
+    selection's scorer."""
     D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
     k = jax.random.split(key, 9)
     H = a.n_heads
@@ -483,17 +507,23 @@ def _latent_params(key, cfg, a: LatentAttention):
     # published widths, and rounding decides the output).
     q_in = D if cfg.latent_rescale else a.q_rank
     kv_in = D if cfg.latent_rescale else a.kv_rank
-    p = {
-        "wq_a": _dense_init(k[0], (D, a.q_rank), D, pdt),
-        "q_norm": {"scale": jnp.ones((a.q_rank,), pdt)},
-        "wq_b": _dense_init(k[1], (a.q_rank, H, a.nope_dim + a.rope_dim),
-                            q_in, pdt),
+    if a.q_rank:
+        p = {"wq_a": _dense_init(k[0], (D, a.q_rank), D, pdt),
+             "q_norm": {"scale": jnp.ones((a.q_rank,), pdt)},
+             "wq_b": _dense_init(k[1], (a.q_rank, H, a.nope_dim + a.rope_dim),
+                                 q_in, pdt)}
+    else:
+        p = {"wq": _dense_init(k[0], (D, H, a.nope_dim + a.rope_dim), D, pdt)}
+    if a.q_head_norm:
+        p["q_head_norm"] = {"scale": jnp.ones((a.nope_dim + a.rope_dim,),
+                                              pdt)}
+    p.update({
         "wkv_a": _dense_init(k[2], (D, a.kv_rank + a.rope_dim), D, pdt),
         "kv_norm": {"scale": jnp.ones((a.kv_rank,), pdt)},
         "wkv_b": _dense_init(k[3], (a.kv_rank, H, a.nope_dim + a.v_dim),
                              kv_in, pdt),
         "wo": _dense_init(k[4], (H, a.v_dim, D), H * a.v_dim, pdt),
-    }
+    })
     if cfg.attn_gate:
         p["w_attn_gate"] = _dense_init(k[5], (D, H), D, pdt)
     if a.index_topk:
@@ -761,9 +791,10 @@ def _qkv(h, layer, cfg, positions=None):
     return q, k, v
 
 
-def rope_inv_freq(a: MultiHeadAttention):
+def rope_inv_freq(a):
     """The inverse frequencies ``[rope_dim / 2]`` (float64 numpy) of a
-    described kind's rotation and the factor on its cos and sin: plain
+    described kind's rotation (multi-head or latent: ``rope_dim``,
+    ``rope_theta``, ``yarn``) and the factor on its cos and sin: plain
     ``theta^(-2i / rope_dim)``, or YaRN's blend (:class:`Yarn`)."""
     import numpy as np
 
@@ -786,7 +817,7 @@ def rope_inv_freq(a: MultiHeadAttention):
     return plain / y.factor * ramp + plain * (1 - ramp), float(factor)
 
 
-def _rope_kind(x, positions, a: MultiHeadAttention):
+def _rope_kind(x, positions, a):
     """A described kind's rotation of ``x [B, S, H, head_dim]`` at
     ``positions [B, S]``: the first ``rope_dim`` dims of each head, first
     and second half of them paired; float32."""
@@ -864,6 +895,14 @@ def _rope_head(x, positions, theta, width):
     return jnp.concatenate([turned, flat[..., width:]], -1).reshape(x.shape)
 
 
+def _rope_latent(x, positions, a: LatentAttention):
+    """A latent kind's rotation of ``x [B, S, H, rope_dim]``: plain, or at
+    YaRN's frequencies where the kind has them."""
+    if a.yarn is None:
+        return _rope(x, positions, a.rope_theta)
+    return _rope_kind(x, positions, a)
+
+
 def _latent_qkv(h, layer, cfg, a: LatentAttention, positions=None):
     """A latent layer's attention operands from the normed input ``h [B, S,
     D]``, in the ABSORBED form: -> ``(q [B, S, H, row_width], row [B, S,
@@ -889,17 +928,23 @@ def _latent_qkv(h, layer, cfg, a: LatentAttention, positions=None):
     dt = cfg.compute_dtype
     if positions is None:
         positions = jnp.arange(h.shape[1])[None]
-    a_q = math.sqrt(cfg.d_model / a.q_rank) if cfg.latent_rescale else 1.0
     a_kv = math.sqrt(cfg.d_model / a.kv_rank) if cfg.latent_rescale else 1.0
-    c_q = _rms_norm(jnp.einsum("bsd,dr->bsr", h, layer["wq_a"].astype(dt)),
-                    layer["q_norm"], cfg.norm_eps)
-    c_q = (c_q * a_q).astype(dt)
-    q = jnp.einsum("bsr,rhd->bshd", c_q, layer["wq_b"].astype(dt))
-    q_rope = _rope(q[..., a.nope_dim:], positions, a.rope_theta)
+    if a.q_rank:
+        a_q = math.sqrt(cfg.d_model / a.q_rank) if cfg.latent_rescale else 1.0
+        c_q = _rms_norm(
+            jnp.einsum("bsd,dr->bsr", h, layer["wq_a"].astype(dt)),
+            layer["q_norm"], cfg.norm_eps)
+        c_q = (c_q * a_q).astype(dt)
+        q = jnp.einsum("bsr,rhd->bshd", c_q, layer["wq_b"].astype(dt))
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
+    if a.q_head_norm:
+        q = _rms_norm(q, layer["q_head_norm"], cfg.norm_eps)
+    q_rope = _rope_latent(q[..., a.nope_dim:], positions, a)
     kv = jnp.einsum("bsd,dr->bsr", h, layer["wkv_a"].astype(dt))
     c_kv = _rms_norm(kv[..., :a.kv_rank], layer["kv_norm"], cfg.norm_eps)
     c_kv = (c_kv * a_kv).astype(dt)
-    k_r = _rope(kv[:, :, None, a.kv_rank:], positions, a.rope_theta)[:, :, 0]
+    k_r = _rope_latent(kv[:, :, None, a.kv_rank:], positions, a)[:, :, 0]
     q_lat = jnp.einsum("bshd,rhd->bshr", q[..., :a.nope_dim],
                        layer["wkv_b"][..., :a.nope_dim].astype(dt))
     pad = a.row_width - a.kv_rank - a.rope_dim
@@ -951,9 +996,8 @@ def latent_attend(q, rows, a: LatentAttention, allowed, dt):
     ``[B, S, H, kv_rank]`` (the probabilities times the rows' latent part;
     zeros for a query that is allowed nothing). The plain tier: the
     trainer's forward pass, a CPU, a mesh."""
-    scale = 1.0 / math.sqrt(a.nope_dim + a.rope_dim)
     logits = jnp.einsum("bshw,btw->bhst", q, rows,
-                        preferred_element_type=jnp.float32) * scale
+                        preferred_element_type=jnp.float32) * a.softmax_scale
     logits = jnp.where(allowed[:, None], logits, -1e30)
     probs = jax.nn.softmax(logits, -1)
     probs = jnp.where(allowed[:, None], probs, 0.0).astype(dt)

@@ -635,6 +635,15 @@ SERVE_KV_WINDOW_ROWS_AS_FULL = counter(
     "hvd_serve_kv_window_rows_as_full",
     "K/V rows those window layers would read if they were sized and read "
     "like full ones (every live row)", ("program",))
+SERVE_KV_LATENT_ROWS = counter(
+    "hvd_serve_kv_latent_rows",
+    "Latent rows the full-context latent layers (no window, no selection) "
+    "have to read: a slot's live rows once a call, summed over those layers",
+    ("program",))
+SERVE_QK_LATENT_PAIRS = counter(
+    "hvd_serve_qk_latent_pairs",
+    "(query, key) pairs those layers attended over: every live key of every "
+    "query, summed over those layers", ("program",))
 SERVE_KV_SELECT_SHARE = gauge(
     "hvd_serve_kv_select_share",
     "hvd_serve_kv_selected over hvd_serve_kv_scored, all programs so far: "

@@ -1,6 +1,7 @@
 """The serving kernels of latent attention layers (Pallas TPU): the key
 selection's scores and its top-k, attention over the selected latent rows,
-and windowed latent attention over a slot's ring.
+windowed latent attention over a slot's ring, and full-context latent
+attention over a slot's live pages where they lie.
 
 ``serving/engine.py`` (``_latent_layer``) runs them inside ``jit_chunk`` and
 ``jit_decode`` on a TPU backend with no mesh; each ``pallas_call`` carries a
@@ -11,7 +12,7 @@ device trace (``docs/observability.md``; the benchmark's ``*_dev_ms.doc`` and
 ``select_keys`` and ``latent_attend``, which the engine runs everywhere else
 and the tests compare these with (``interpret=True`` on the CPU).
 
-All four take operands in the compute dtype, accumulate in float32, take the
+All five take operands in the compute dtype, accumulate in float32, take the
 softmax in float32 and round the probabilities to the compute dtype before
 the product with the rows, as ``latent_attend`` does.
 
@@ -51,6 +52,7 @@ SCORES_NAME = "index_scores"
 SELECT_NAME = "index_select"
 SPARSE_NAME = "sparse_latent_attention"
 WINDOW_NAME = "window_latent_attention"
+PAGED_NAME = "paged_latent_attention"
 
 _VMEM_LIMIT = 64 * 1024 * 1024     # of a v5e's 128 MiB; the default is 16
 
@@ -66,6 +68,8 @@ def supported(a, geo):
                and a.index_topk <= geo.max_kv)
     if a.window:
         ok &= geo.ring_tokens % _LANES == 0
+    if not (a.window or a.index_topk):      # pages copied whole: bf16 tiles
+        ok &= geo.page_size % 16 == 0
     return bool(ok)
 
 
@@ -298,7 +302,7 @@ def sparse_latent_attention(q, picked, selected, a, *, interpret=None):
     n_valid = jnp.sum(selected >= 0, -1).astype(jnp.int32).reshape(-1)
     out = pl.pallas_call(
         functools.partial(_sparse_kernel, kv_rank=a.kv_rank,
-                          scale=1.0 / math.sqrt(a.nope_dim + a.rope_dim)),
+                          scale=a.softmax_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B * Q,),
@@ -358,8 +362,7 @@ def window_latent_attention(q, ring, q_pos, k_pos, a, *, interpret=None):
     nq = Q // tq
     out = pl.pallas_call(
         functools.partial(_window_kernel, n_heads=H, tq=tq, window=a.window,
-                          kv_rank=a.kv_rank,
-                          scale=1.0 / math.sqrt(a.nope_dim + a.rope_dim)),
+                          kv_rank=a.kv_rank, scale=a.softmax_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, nq),
@@ -385,3 +388,175 @@ def window_latent_attention(q, ring, q_pos, k_pos, a, *, interpret=None):
     )(q_pos[:, 0].astype(jnp.int32), q.reshape(B, nq, tq * H, W), ring,
       k_pos.astype(jnp.int32)[:, None, :])
     return out.reshape(B, Q, H, a.kv_rank)
+
+
+# ---- paged_latent_attention --------------------------------------------------
+
+# Queries a grid step takes (a power of two: a row's query is ``row %
+# q_block``; all heads of them are one tile of ``n_heads * q_block`` rows), and
+# the rows a block of keys aims for, for one query a slot and for a block of
+# them. Read on a v5e at 64 heads over 640-lane rows (PERF.md, PR 44): a
+# 512-query chunk at a context of 16k takes 8.84 / 8.01 / 7.63 / 7.53 ms at 8
+# / 16 / 32 / 64 queries a step (blocks of 512 rows; 256 and 1024 are slower
+# at every step size), 5.84 ms of operations at the bf16 peak; 16 slots of
+# 14.4k live rows take 0.88 / 0.68 / 0.60 / 0.59 ms at blocks of 256 / 512 /
+# 1024 / 2048 rows against 0.32 ms of bytes.
+_PAGED_Q_BLOCK = 64
+_PAGED_DECODE_TOKENS = 1024
+_PAGED_CHUNK_TOKENS = 512
+_PAGED_VMEM_LIMIT = 96 * 1024 * 1024
+
+
+def _paged_kernel(pos0_ref, len_ref, tables_ref, q_ref, r_hbm, o_ref, r_buf,
+                  sems, m_s, l_s, acc_s, *, page, ppb, width, qb, kv_rank,
+                  scale):
+    b, qi = pl.program_id(0), pl.program_id(1)
+    kv_len = len_ref[b]
+    q_first = pos0_ref[b] + qi * qb
+    bt = ppb * page
+    # Rows this query block can see: positions 0 .. hi - 1.
+    hi = jnp.minimum(q_first + qb, kv_len)
+    n_blocks = jnp.maximum((hi + bt - 1) // bt, 0)
+    last_page = jnp.maximum(hi - 1, 0) // page
+
+    def copies(i, slot):
+        out = []
+        for j in range(ppb):
+            # Past the live pages: the last live one again, never a page
+            # the slot does not own.
+            p = jnp.minimum(jnp.minimum(i * ppb + j, last_page), width - 1)
+            out.append(pltpu.make_async_copy(
+                r_hbm.at[tables_ref[b * width + p]],
+                r_buf.at[slot, pl.ds(j * page, page)], sems.at[slot]))
+        return out
+
+    m_s[...] = jnp.full_like(m_s, _NEG)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+    # Row r of the tile is query r % qb of the block, of head r // qb.
+    row = jax.lax.broadcasted_iota(jnp.int32, (acc_s.shape[0], 1), 0)
+    q_pos = q_first + row % qb
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def block(i, _):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            for c in copies(i + 1, 1 - slot):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        r = r_buf[slot]                                         # [bt, W]
+        s = jax.lax.dot_general(q_ref[0, 0], r, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        k_pos = i * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        ok = (k_pos <= q_pos) & (k_pos < kv_len)                # [rows, bt]
+        s = jnp.where(ok, s, _NEG)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+        # A row may see nothing of this block (another row of the block
+        # does): its exp(_NEG - _NEG) is masked, not trusted.
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        m_s[...] = m_new
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, -1, keepdims=True)
+        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+            p.astype(r.dtype), r[:, :kv_rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    jax.lax.fori_loop(0, n_blocks, block, None)
+
+    l = l_s[...]
+    o_ref[0, 0] = (acc_s[...] * (1.0 / jnp.where(l > 0, l, 1.0))
+                   ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "a", "q_block", "pages_per_block", "interpret"))
+def paged_latent_attention(q, rows, tables, pos0, kv_len, a, *, q_block=None,
+                           pages_per_block=None, interpret=None):
+    """``q [B, Q, H, W]`` (absorbed), a slot's ``Q`` queries at the
+    consecutive positions ``pos0 [B] ..``, against one layer's latent rows
+    ``rows [n_pages, page, W]`` where they lie -> ``[B, Q, H, kv_rank]`` in
+    ``q``'s dtype (the probabilities times the rows' latent part).
+
+    A query at ``p`` sees the rows at positions ``<= p`` and ``< kv_len [B]``
+    (the slot's live positions, this call's own included; 0 = an inactive
+    slot, zeros out). Position ``t`` lies in page ``tables[b, t // page]``
+    (``tables [B, max_blocks]``); only the pages that hold rows some query of
+    a block sees are read, so neither a page past a slot's live ones nor the
+    tail of its last page is ever attended.
+
+    Structure: grid over slots and blocks of ``q_block`` queries; a tile is
+    ``H * q_block`` rows (every head of the block's queries: all of them read
+    the same rows), the block table is walked ``pages_per_block`` pages at a
+    time through two VMEM buffers, one copy a page; scores exist a ``[H *
+    q_block, block]`` tile at a time. Jitted so that a program calling it
+    once a layer traces and lowers it once."""
+    B, Q, H, W = q.shape
+    n_pages, page, w_rows = rows.shape
+    if w_rows != W or a.kv_rank > W:
+        raise ValueError(f"rows {rows.shape} do not match queries of {W} "
+                         f"lanes over a latent of {a.kv_rank}")
+    qb = int(q_block or _PAGED_Q_BLOCK)
+    if qb & (qb - 1):
+        raise ValueError(f"q_block {qb} is not a power of two")
+    qb = math.gcd(Q, qb)      # the largest power of two that divides both
+    nq = Q // qb
+    width = tables.shape[1]
+    ppb = pages_per_block
+    if ppb is None:
+        tokens = _PAGED_DECODE_TOKENS if Q == 1 else _PAGED_CHUNK_TOKENS
+        ppb = max(1, tokens // page)
+    ppb = min(int(ppb), width)
+    bt = ppb * page
+    n_rows = -(-H * qb // 16) * 16        # whole bf16 sublane tiles
+    # [B, Q, H, W] -> a tile of rows (head, query) a query block.
+    qt = q.reshape(B, nq, qb, H, W).transpose(0, 1, 3, 2, 4)
+    qt = qt.reshape(B, nq, H * qb, W)
+    qt = jnp.pad(qt, ((0, 0), (0, 0), (0, n_rows - H * qb), (0, 0)))
+    kernel = functools.partial(
+        _paged_kernel, page=page, ppb=ppb, width=width, qb=qb,
+        kv_rank=a.kv_rank, scale=a.softmax_scale)
+    live = B * nq * width * page          # an upper bound
+
+    def tile(lanes):
+        return pl.BlockSpec((1, 1, n_rows, lanes),
+                            lambda b, qi, *_: (b, qi, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, nq),
+            in_specs=[tile(W), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=tile(a.kv_rank),
+            scratch_shapes=[
+                pltpu.VMEM((2, bt, W), rows.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((n_rows, 1), jnp.float32),
+                pltpu.VMEM((n_rows, 1), jnp.float32),
+                pltpu.VMEM((n_rows, a.kv_rank), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, nq, n_rows, a.kv_rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_PAGED_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * n_rows * (W + a.kv_rank) * live,
+            transcendentals=n_rows * live,
+            bytes_accessed=live * W * rows.dtype.itemsize),
+        name=PAGED_NAME,
+        interpret=_interpret() if interpret is None else interpret,
+    )(pos0.astype(jnp.int32), kv_len.astype(jnp.int32),
+      tables.reshape(-1).astype(jnp.int32), qt, rows)
+    out = out[:, :, :H * qb].reshape(B, nq, H, qb, a.kv_rank)
+    return out.transpose(0, 1, 3, 2, 4).reshape(B, Q, H, a.kv_rank)

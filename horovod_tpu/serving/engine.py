@@ -68,9 +68,15 @@ selection keys) are scattered into the layer's arrays, then
   per-head scores exist only a tile at a time inside the first kernel;
 - a **window** layer gathers the slot's ring (``ring_blocks`` pages, from
   the block table's tail), works out which position each ring cell holds,
-  and attends under the window (``window_latent_attention``).
+  and attends under the window (``window_latent_attention``);
+- a layer with **neither** attends over its whole context: the slot's LIVE
+  pages, read where they lie through the block table, for one query a slot
+  and for a block of queries alike (``paged_latent_attention``: the absorbed
+  form is multi-query attention over one row a token); no ``[Q, H, max_kv]``
+  scores and no gathered ``[B, max_kv, row_width]`` copy exist. The plain
+  tier gathers the slot's ``max_kv`` rows and masks.
 
-Those four names are Pallas kernels (:mod:`horovod_tpu.ops.pallas_latent`)
+Those five names are Pallas kernels (:mod:`horovod_tpu.ops.pallas_latent`)
 and the instruction names a device trace shows; they run on a TPU backend
 with no mesh (:func:`latent_kernels`). Elsewhere the same mathematics runs as
 plain ``jax.numpy`` (``transformer.index_scores``, ``select_keys``,
@@ -280,9 +286,17 @@ def _latent_layer(a, q, row, index, rows_c, keys_c, *, q_pos, ok, tables,
             o = tfm.latent_attend(q, ring, a, allowed, dt)
         return rows_c, keys_c, o, None
     k_pos = jnp.broadcast_to(jnp.arange(geo.max_kv)[None], (B, geo.max_kv))
-    if not a.index_topk:
-        raise ValueError("a full latent layer with no key selection is not "
-                         "served yet (it would attend over max_kv rows)")
+    if not a.index_topk:        # the whole context: the slot's live pages
+        p_hi = jnp.max(jnp.where(ok, q_pos, -1), axis=1)            # [B]
+        if kernels:
+            o = pallas_latent.paged_latent_attention(
+                q, rows_c, table, q_pos[:, 0], p_hi + 1, a)
+        else:
+            allowed = tfm.attend_allowed(a, q_pos, k_pos,
+                                         k_pos <= p_hi[:, None])
+            o = tfm.latent_attend(
+                q, rows_c[table].reshape(B, geo.max_kv, -1), a, allowed, dt)
+        return rows_c, keys_c, o, None
     keys_c = keys_c.at[page_ids, slot].set(index["k"])
     keys = keys_c[table].reshape(B, geo.max_kv, -1)
     if kernels:

@@ -324,6 +324,10 @@ class ServeLoop:
         latent = [a for a in kinds if isinstance(a, LatentAttention)]
         self._select = [a.index_topk for a in latent if a.index_topk]
         self._windows = [a.window for a in latent if a.window]
+        # Latent layers that attend over their whole context: the rows a
+        # call has to read (a slot's live rows once a layer) and the pairs.
+        self._latent_full = sum(1 for a in latent
+                                if not (a.index_topk or a.window))
         # Multi-head layers of a described kind: the K/V rows a call reads
         # (each live row once a layer: what the paged kernel has to move) and
         # the (query, key) pairs it multiplies, full and window layers apart;
@@ -335,7 +339,9 @@ class ServeLoop:
         self.attn_stats = {name: {} for name in (
             "kv_scored", "kv_selected", "kv_window", "queries", "calls",
             *(("kv_full_rows", "kv_window_rows", "kv_window_rows_as_full",
-               "qk_full_pairs", "qk_window_pairs") if multihead else ()))}
+               "qk_full_pairs", "qk_window_pairs") if multihead else ()),
+            *(("kv_latent_rows", "qk_latent_pairs") if self._latent_full
+              else ()))}
         # State-space layers: by program kind, the (slot, layer) rows a call
         # reads and writes back, their bytes both ways (tail and state),
         # the (token, layer) positions scanned, the rows a call zeroed
@@ -406,9 +412,9 @@ class ServeLoop:
     def _count_attn(self, kind, live):
         """One program call whose queries see ``live [slots, queries]`` keys
         each (their positions + 1; a slot's queries are consecutive): what
-        its latent layers scored, selected and windowed, what its
-        multi-head layers of a described kind read and multiplied, and what
-        its state-space layers carried (:meth:`_count_state`)."""
+        its latent layers scored, selected, windowed and read whole, what
+        its multi-head layers of a described kind read and multiplied, and
+        what its state-space layers carried (:meth:`_count_state`)."""
         if not (self._counts_attn or self._state_layers):
             return
         live = np.asarray(live, np.int64)
@@ -443,6 +449,13 @@ class ServeLoop:
                 (_metrics.SERVE_KV_WINDOW_ROWS, "kv_window_rows"),
                 (_metrics.SERVE_KV_WINDOW_ROWS_AS_FULL,
                  "kv_window_rows_as_full")]
+        if self._latent_full:
+            found.update(
+                kv_latent_rows=int(live.max(axis=1).sum())
+                * self._latent_full,
+                qk_latent_pairs=int(live.sum()) * self._latent_full)
+            counters += [(_metrics.SERVE_KV_LATENT_ROWS, "kv_latent_rows"),
+                         (_metrics.SERVE_QK_LATENT_PAIRS, "qk_latent_pairs")]
         for name, n in found.items():
             by_kind = self.attn_stats[name]
             by_kind[kind] = by_kind.get(kind, 0) + n
@@ -469,8 +482,8 @@ class ServeLoop:
 
     @property
     def _counts_attn(self):
-        return bool(self._select or self._windows or self._mh_full
-                    or self._mh_windows)
+        return bool(self._select or self._windows or self._latent_full
+                    or self._mh_full or self._mh_windows)
 
     def _kv_select_share(self):
         scored = sum(self.attn_stats["kv_scored"].values())

@@ -1,0 +1,467 @@
+"""Full-context latent attention with no key selection
+(``benchmark/configs/sarvam-105b.json``: a direct query projection, a per-head
+query norm, DeepSeek-style YaRN on the rotated dims with ``mscale^2`` on the
+softmax scale, a dense first layer, a shared expert, a sigmoid router with a
+selection bias, 4 of 16 experts held here) as an instance of
+``models/transformer.py``'s one block, at a tiny size on the CPU, against the
+benchmark's plain reference (``benchmark/reference/sarvam_mla.py``: the file
+the chip run is judged by).
+
+The tiny model is made the way the benchmark's runner makes the real one: the
+configuration FILE's ``model`` mapping applied to the file's own keys, here
+with every size shrunk (hidden 64, 4 heads of 16 + 8 over a latent of 16,
+YaRN's original length 16 so that the blend is in play, page 4). Everything
+runs in float32, where program and reference must agree to rounding although
+the one attends in the absorbed form through pages and the other in the
+expanded form with no cache.
+"""
+import dataclasses
+import hashlib
+import importlib.util
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops import pallas_latent
+from horovod_tpu.serving import engine, kv_cache
+from horovod_tpu.serving import loop as serve_loop
+from horovod_tpu.serving.scheduler import Request
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, path))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+reference = _load("benchmark/reference/sarvam_mla.py", "sarvam_reference")
+runner = _load("benchmark/runners/serve_latent.py", "serve_latent_runner")
+FILE = json.load(open(os.path.join(ROOT, "benchmark", "configs",
+                                   "sarvam-105b.json")))
+PAGE, CHUNK, TOL = 4, 8, 2e-4
+
+
+def _config(**overrides):
+    """The configuration file with every size shrunk."""
+    config = dict(FILE)
+    config.update(
+        hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
+        num_attention_heads=4, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, q_head_dim=24, head_dim=24, v_head_dim=16,
+        num_experts_published=16, experts_held=[4, 4], num_experts=4,
+        num_experts_per_tok=4, vocab_size=128, max_position_embeddings=256,
+        rope_scaling=dict(FILE["rope_scaling"],
+                          original_max_position_embeddings=16))
+    config.update(overrides)
+    return config
+
+
+def _cfg(config, **overrides):
+    return dataclasses.replace(runner.model_config(config), dtype="float32",
+                               param_dtype="float32", **overrides)
+
+
+def _params(cfg, seed=0):
+    """Seeded weights as the benchmark's runner draws them: norm scales
+    around 1 and the router's selection bias around 0, so that none can be
+    left out unseen."""
+    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
+    rng = np.random.default_rng(seed)
+
+    def jitter(path, x):
+        name = getattr(path[-1], "key", None)
+        if name == "scale":
+            return (1.0 + 0.1 * rng.standard_normal(x.shape)).astype(x.dtype)
+        if name == "router_bias":
+            return (0.1 * rng.standard_normal(x.shape)).astype(x.dtype)
+        return x
+
+    return jax.tree_util.tree_map_with_path(jitter, params)
+
+
+def _tokens(n, seed=1):
+    return np.random.default_rng(seed).integers(0, 128, n).tolist()
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def _want(config, params, tokens, fault=None, **kw):
+    hp = reference.hyper(config)
+    return reference.logits(
+        reference.from_horovod_tpu(params), jnp.asarray([tokens], jnp.int32),
+        hp, kn=reference.knobs(hp, fault), **kw)
+
+
+def _loop(cfg, params, max_batch=2, n_pages=64, context=128, **kw):
+    geo = kv_cache.geometry(n_pages, PAGE, context)
+    return serve_loop.ServeLoop(params, cfg, geo=geo, max_batch=max_batch,
+                                prefill_chunk=CHUNK, **kw)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    config = _config()
+    cfg = _cfg(config)
+    return config, cfg, _params(cfg)
+
+
+@pytest.fixture(params=["plain", "kernel"])
+def tier(request, monkeypatch):
+    """The plain tier (gathered pages, materialised scores) and the paged
+    kernel in interpret mode, as the chip runs it."""
+    monkeypatch.setattr(engine, "latent_kernels",
+                        lambda *a: request.param == "kernel")
+    return request.param
+
+
+# ---- the description ------------------------------------------------------
+
+def test_the_file_describes_its_layers():
+    """The configuration file's ``model`` mapping at the published sizes:
+    five layers of one latent kind with neither a window nor a selection, no
+    query latent, the query norm, YaRN and ``mscale^2``; the dense first
+    layer, the experts held, and the parameter count the cut was sized by."""
+    cfg = runner.model_config(FILE)
+    kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
+    assert len(kinds) == 5 and len(set(kinds)) == 1
+    a = kinds[0]
+    assert (a.n_heads, a.q_rank, a.kv_rank, a.nope_dim, a.rope_dim, a.v_dim,
+            a.row_width, a.window, a.index_topk, a.q_head_norm) == (
+        64, 0, 512, 128, 64, 128, 640, 0, 0, True)
+    assert a.yarn == tfm.Yarn(40, 4096, 32, 1, 1.0)
+    m = 0.1 * np.log(40.0) + 1.0
+    assert a.scale_mult == pytest.approx(m * m, rel=1e-12)
+    assert a.softmax_scale == pytest.approx(m * m / np.sqrt(192), rel=1e-12)
+    assert [cfg.is_moe(li) for li in range(5)] == [False] + [True] * 4
+    assert (cfg.n_experts, cfg.n_held, cfg.top_k, cfg.router,
+            cfg.routed_scale, cfg.shared_experts) == (
+        128, 32, 8, "sigmoid", 2.5, 1)
+    shapes = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0),
+                                                    cfg))
+    layer = shapes["layers"][1]
+    assert layer["wq"].shape == (4096, 64, 192) and "wq_a" not in layer
+    assert layer["q_head_norm"]["scale"].shape == (192,)
+    attention = sum(int(np.prod(layer[k].shape))
+                    for k in ("wq", "wkv_a", "wkv_b", "wo"))
+    assert attention == 94_633_984                         # 94.63 M
+    n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    assert 4.534e9 < n < 4.537e9                           # 9.07 GB in bf16
+    assert jax.tree.structure(shapes) == jax.tree.structure(
+        tfm.param_specs(cfg),
+        is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
+
+
+def test_a_file_whose_factors_disagree_is_refused():
+    with pytest.raises(SystemExit, match="softmax_scale_mult"):
+        runner.model_config(dict(FILE, softmax_scale_mult=1.0))
+
+
+def test_a_full_latent_layer_has_no_scorer_cache(tiny):
+    _, cfg, _ = tiny
+    geo = kv_cache.geometry(64, PAGE, 128)
+    for li in range(cfg.n_layers):
+        rows, keys = kv_cache.layer_shapes(cfg, geo, li)
+        assert rows == (64, PAGE, 128) and keys is None   # 16 + 8 -> 128
+    cache = kv_cache.make_cache(cfg, geo)
+    assert all(v is None for v in cache["v"])
+    assert kv_cache.with_rings(geo, cfg, CHUNK, 2) == geo   # no ring
+    with pytest.raises(ValueError, match="q_rank"):
+        tfm.LatentAttention(4, 0, 16, 16, 8, 16, index_topk=8)
+
+
+# ---- program against reference --------------------------------------------
+
+@pytest.mark.parametrize("n", [5, 37])
+def test_forward_is_the_expanded_reference(tiny, n):
+    """The absorbed layer (``q_rank`` 0, the query norm, YaRN, ``m^2``)
+    through ``transformer.forward`` against the reference's expanded form."""
+    config, cfg, params = tiny
+    tokens = _tokens(n)
+    got = tfm.forward(params, jnp.asarray([tokens]), cfg)
+    want, routes = _want(config, params, tokens, with_routes=True)
+    assert _rel(got, want) < TOL
+    assert routes.shape == (4, 1, n, 4)
+
+
+@pytest.mark.parametrize("fault", ["mscale_left_out", "yarn_not_interpolated",
+                                   "q_norm_left_out", "rope_key_left_out",
+                                   "shared_expert_left_out"])
+def test_a_fault_in_the_reference_moves_the_logits(tiny, fault):
+    """Tight enough that leaving out ``m^2``, the query norm, YaRN's
+    interpolation, the shared rotated key or the shared expert fails."""
+    config, cfg, params = tiny
+    tokens = _tokens(37)
+    got = tfm.forward(params, jnp.asarray([tokens]), cfg)
+    assert _rel(got, _want(config, params, tokens, fault)) > 100 * TOL
+
+
+def test_the_selection_bias_chooses(tiny):
+    config, cfg, params = tiny
+    tokens = _tokens(37)
+    _, sound = _want(config, params, tokens, with_routes=True)
+    _, bad = _want(config, params, tokens, "selection_bias_left_out",
+                   with_routes=True)
+    differ = (np.sort(np.asarray(sound), -1)
+              != np.sort(np.asarray(bad), -1)).any(-1)
+    assert differ.mean() > 0.1
+
+
+@pytest.mark.parametrize("n", [21, 32])
+def test_chunks_then_decode_is_one_forward(tiny, tier, n):
+    """A prompt filled in chunks of 8 (``n`` 21 ends inside a page, 32 on a
+    chunk's edge) and then decoded, through the loop's programs and pages,
+    against one full ``forward``."""
+    _, cfg, params = tiny
+    served = _load("benchmark/runners/serve_layers.py", "serve_layers_runner")
+    lp = _loop(cfg, params)
+    pages = np.arange(1, 2 + (n + served.N_DECODE) // PAGE)
+    seq, rows, tops, selected = served.served_rows(lp, params, _tokens(n),
+                                                   pages)
+    assert selected is None and tops.shape == (4, len(seq), 4)
+    full = tfm.forward(params, jnp.asarray([seq]), cfg)
+    assert _rel(rows, full[0, -len(rows):]) < TOL
+
+
+# ---- the kernel -----------------------------------------------------------
+
+def _kernel_case(q_len, lengths, seed=0):
+    """Queries, a paged array whose EVERY row is non-zero (free pages and
+    the tails of last pages hold stale rows), and each slot's table."""
+    a = tfm.LatentAttention(4, 0, 128, 16, 8, 16, scale_mult=1.87)
+    rng = np.random.default_rng(seed)
+    B, n_blocks = len(lengths), 6
+    rows = jnp.asarray(rng.standard_normal((1 + B * n_blocks + 3, PAGE * 2,
+                                            a.row_width)), jnp.float32)
+    tables = np.zeros((B, n_blocks), np.int32)
+    for b, n in enumerate(lengths):     # pages owned: the live ones only
+        own = -(-n // (PAGE * 2))
+        tables[b, :own] = 1 + b * n_blocks + rng.permutation(n_blocks)[:own]
+    q = jnp.asarray(rng.standard_normal((B, q_len, a.n_heads, a.row_width)),
+                    jnp.float32)
+    return a, q, rows, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+
+
+@pytest.mark.parametrize("q_len, lengths, q_block", [
+    (1, (13, 40, 0), None), (1, (48, 1, 17), None),
+    (8, (13, 40, 8), None), (16, (16, 43, 0), 8), (16, (48, 21, 30), 4)])
+def test_the_kernel_is_latent_attend(q_len, lengths, q_block):
+    """``paged_latent_attention`` in interpret mode against
+    ``tfm.latent_attend`` over the gathered pages, for one query a slot and
+    for a block: lengths that end inside a page, stale rows in the last
+    page's tail and in pages the slot does not own, an inactive slot."""
+    a, q, rows, tables, kv_len = _kernel_case(q_len, lengths)
+    pos0 = jnp.maximum(kv_len - q_len, 0)
+    got = pallas_latent.paged_latent_attention(
+        q, rows, tables, pos0, kv_len, a, q_block=q_block, pages_per_block=2,
+        interpret=True)
+    B, n = len(lengths), tables.shape[1] * rows.shape[1]
+    k_pos = jnp.broadcast_to(jnp.arange(n)[None], (B, n))
+    q_pos = pos0[:, None] + jnp.arange(q_len)[None]
+    allowed = tfm.attend_allowed(a, q_pos, k_pos, k_pos < kv_len[:, None])
+    want = tfm.latent_attend(q, rows[tables].reshape(B, n, -1), a, allowed,
+                             jnp.float32)
+    assert got.shape == (B, q_len, a.n_heads, a.kv_rank)
+    assert _rel(got, want) < 1e-5
+    dead = [b for b, n_live in enumerate(lengths) if n_live == 0]
+    assert not np.asarray(got)[dead].any()
+
+
+def test_the_kernel_never_reads_what_the_slot_does_not_own():
+    """Not-a-number in every page no slot owns: the output stays finite and
+    the same."""
+    a, q, rows, tables, kv_len = _kernel_case(8, (21, 40))
+    pos0 = kv_len - 8
+    clean = pallas_latent.paged_latent_attention(
+        q, rows, tables, pos0, kv_len, a, pages_per_block=2, interpret=True)
+    page = rows.shape[1]
+    owned = np.zeros(rows.shape[:2], bool)
+    for b, n in enumerate(np.asarray(kv_len)):
+        for t in range(n):
+            owned[np.asarray(tables)[b, t // page], t % page] = True
+    # Pages are copied whole, so the last live page's tail reaches the
+    # products under a zero probability (and nan * 0 is nan): it keeps its
+    # stale rows. Every page the slot does not own becomes not-a-number.
+    mine = np.isin(np.arange(rows.shape[0]), np.asarray(tables))
+    dirty = jnp.where(owned[..., None] | mine[:, None, None], rows, jnp.nan)
+    got = pallas_latent.paged_latent_attention(
+        q, dirty, tables, pos0, kv_len, a, pages_per_block=2, interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, clean, rtol=1e-6, atol=1e-6)
+
+
+# ---- through the loop -----------------------------------------------------
+
+def _requests(lengths, new=6, seed=3):
+    return [Request(rid=i, prompt=_tokens(n, seed + i), max_new_tokens=new,
+                    arrival_t=0.0, eos_id=-1)
+            for i, n in enumerate(lengths)]
+
+
+def _greedy(cfg, params, prompt, new, width=48):
+    """Sequential greedy decoding by one compiled ``forward`` over a padded
+    window (causal: what follows a position does not reach it)."""
+    forward = _greedy.compiled.setdefault(id(params), jax.jit(
+        lambda tokens: tfm.forward(params, tokens, cfg)))
+    seq = list(prompt)
+    for _ in range(new):
+        padded = np.zeros((1, width), np.int32)
+        padded[0, :len(seq)] = seq
+        seq.append(int(jnp.argmax(forward(padded)[0, len(seq) - 1])))
+    return seq[len(prompt):]
+
+
+_greedy.compiled = {}
+
+
+def test_a_slot_reused_after_another_request(tiny, tier):
+    """Five requests through two slots: every slot serves several, on pages
+    others freed; each chain is sequential greedy decoding."""
+    _, cfg, params = tiny
+    lp = _loop(cfg, params, prefix_cache=False)
+    lp.warmup()
+    reqs = _requests((21, 9, 30, 13, 26))
+    _, finished = lp.run(reqs)
+    assert len(finished) == 5
+    for r in finished:
+        assert r.generated == _greedy(cfg, params, r.prompt, 6), r.rid
+    attn = serve_loop._LAST_STATS["attn"]
+    assert attn["kv_latent_rows"]["chunk"] > 0
+    assert attn["qk_latent_pairs"]["decode"] \
+        == attn["kv_latent_rows"]["decode"]       # one query a slot
+
+
+def test_a_prefix_hit_over_latent_pages(tiny, tier):
+    """The prefix cache is not turned off by anything in this model: a
+    second request that shares 16 tokens (four pages) hits them, fills only
+    its own suffix, and emits what a fresh run emits."""
+    _, cfg, params = tiny
+    lp = _loop(cfg, params)
+    assert lp.prefix is not None
+    lp.warmup()
+    shared = _tokens(16, 9)
+    first = Request(rid=0, prompt=shared + _tokens(7, 10), max_new_tokens=5,
+                    arrival_t=0.0, eos_id=-1)
+    second = Request(rid=1, prompt=shared + _tokens(9, 11), max_new_tokens=5,
+                     arrival_t=0.0, eos_id=-1)
+    lp.run([first])
+    _, finished = lp.run([second])
+    assert lp.batcher.prefix_hit_ratio() > 0
+    assert lp.prefix.stats["hit_tokens"] == 16
+    assert finished[0].generated == _greedy(cfg, params, second.prompt, 5)
+
+
+def test_counters_are_host_arithmetic(tiny):
+    """``kv_latent_rows`` / ``qk_latent_pairs`` of one fill and one step."""
+    _, cfg, params = tiny
+    lp = _loop(cfg, params)
+    lp._count_attn("chunk", np.arange(8, 16)[None] + 1)
+    lp._count_attn("decode", np.asarray([20, 3])[:, None] + 1)
+    s = lp.attn_stats
+    assert s["kv_latent_rows"] == {"chunk": 16 * 5, "decode": (21 + 4) * 5}
+    assert s["qk_latent_pairs"] == {"chunk": sum(range(9, 17)) * 5,
+                                    "decode": (21 + 4) * 5}
+    assert s["queries"] == {"chunk": 8, "decode": 2}
+
+
+# ---- the chip's share -----------------------------------------------------
+
+def test_the_four_shares_add_up_to_the_uncut_layer(tiny):
+    """Guide section 4: what the four chips' held experts give, with the
+    shared expert counted once, is the uncut expert layer; and each share is
+    what the program computes with ``experts_held``."""
+    config, cfg, params = tiny
+    layer = params["layers"][1]
+    h = jnp.asarray(np.random.default_rng(5).standard_normal((1, 11, 64)),
+                    jnp.float32)
+    whole = dataclasses.replace(cfg, experts_held=())
+    rng = jax.random.PRNGKey(7)
+    full = {name: jax.random.normal(jax.random.fold_in(rng, i),
+                                    (16, *layer[name].shape[1:])) / 8.0
+            for i, name in enumerate(("w_in", "w_gate", "w_out"))}
+    uncut, _ = tfm._moe_ffn(h, dict(layer, **full), whole)
+    total, shared = 0.0, None
+    for offset in range(0, 16, 4):
+        held = {name: w[offset:offset + 4] for name, w in full.items()}
+        part, _ = tfm._moe_ffn(
+            h, dict(layer, **held),
+            dataclasses.replace(cfg, experts_held=(offset, 4)))
+        hp = reference.hyper(dict(config, experts_held=[offset, 4]))
+        mlp = reference.from_horovod_tpu(
+            dict(params, layers=[dict(layer, **held)]))["layers"][0]["mlp"]
+        kn = jax.tree.map(jnp.asarray, reference.knobs(hp))
+        with jax.default_matmul_precision("highest"):
+            shared, routed, _ = reference.moe_parts(h[0], mlp, hp, kn)
+        assert _rel(part[0], shared + routed) < TOL
+        total = total + routed
+    assert _rel(total + shared, uncut[0]) < TOL
+
+
+# ---- what stands ------------------------------------------------------------
+
+def _standing():
+    """Every configuration that stood before this kind was added, at its
+    test's tiny size."""
+    dots3 = _load("tests/test_dots3.py", "standing_dots3")
+    laguna = _load("tests/test_laguna.py", "standing_laguna")
+    nemotron = _load("tests/test_nemotron_h.py", "standing_nemotron")
+    olmoe = _load("tests/test_olmoe.py", "standing_olmoe")
+    return {
+        "gpt2": lambda: dataclasses.replace(tfm.tiny(), dtype="float32"),
+        "gpt2-moe": lambda: dataclasses.replace(tfm.tiny(n_experts=4),
+                                                dtype="float32"),
+        "olmoe": olmoe._tiny,
+        "dots3": lambda: dots3._cfg(dots3._config()),
+        "laguna": lambda: laguna._cfg(laguna._config()),
+        "nemotron": lambda: nemotron.runner.model_config(nemotron._config()),
+    }
+
+
+# Read at the commit before this kind was added (and again after it, where
+# the parameters' and the logits' bits were the same): the tree's names,
+# shapes and dtypes; the parameters' bits; the logits' sum and absolute sum.
+BEFORE = {
+    "gpt2": ("cc4cd16b2fa77829", "cbd6bb0daeef0e3f",
+             26.86668354183348, 830.3952171302299),
+    "gpt2-moe": ("f1e5ca5f9601c02d", "1618fc16a96a3bb7",
+                 -8.571801105956183, 815.3737999860223),
+    "olmoe": ("45ca8ddf957b3c67", "6f28cb44274d41ba",
+              163.5613178020576, 2485.642097896314),
+    "dots3": ("b51e92cddb1ebdc2", "9a4fb4d9808e075e",
+              54.04719592873607, 2490.710302407021),
+    "laguna": ("5d59e605ac0a288f", "c32b8d2342a1026b",
+               -0.39590076345484704, 2489.4818965856684),
+    "nemotron": ("33c483e0d514437d", "8bb489ccee542fdf",
+                 -76.91667951270938, 1802.9259913302958),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BEFORE))
+def test_what_stands_builds_what_it_built(name):
+    cfg = _standing()[name]()
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    shapes = hashlib.sha256(";".join(
+        f"{jax.tree_util.keystr(p)}:{x.shape}:{x.dtype}"
+        for p, x in leaves).encode()).hexdigest()[:16]
+    bits = hashlib.sha256(b"".join(
+        np.asarray(x).tobytes() for _, x in leaves)).hexdigest()[:16]
+    tokens = jnp.asarray(np.random.default_rng(1).integers(
+        0, cfg.vocab_size, (1, 24)), jnp.int32)
+    logits = np.asarray(tfm.forward(params, tokens, cfg), np.float64)
+    want = BEFORE[name]
+    assert (shapes, bits) == want[:2]
+    assert logits.sum() == pytest.approx(want[2], rel=1e-6, abs=1e-6)
+    assert np.abs(logits).sum() == pytest.approx(want[3], rel=1e-6)

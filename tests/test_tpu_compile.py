@@ -546,6 +546,83 @@ def test_grouped_cell_programs_fit_one_chip(topo, as_on_the_chip):
             assert str(geo.max_kv) not in m.group(1).split(","), m.group(0)
 
 
+# ---- the serving programs at benchmark/configs/sarvam-105b.json ----
+
+def test_full_latent_cell_programs_fit_one_chip(topo, as_on_the_chip):
+    """``sarvam-serve-longdoc-over``'s two programs (the 512-token chunk fill
+    and the decode step) at the cell's geometry: five layers of full-context
+    latent attention, 16 slots of a 32k context. The chip's compiler takes
+    ``paged_latent_attention`` at the published widths (64 heads over one
+    640-lane row a token) for one query a slot and for a block of queries; it
+    is in both programs under the name the benchmark's readers match, once a
+    layer, and none of the selection's or the window's kernels is; no program
+    holds scores of ``[.., max_kv]`` or a gathered copy of a slot's pages;
+    weights + cache + temporaries stay on the chip; the cache is aliased
+    through."""
+    import importlib.util
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "sarvam-105b.json")) as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "serve_latent", os.path.join(root, "benchmark", "runners",
+                                     "serve_latent.py"))
+    runner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(runner)
+    cfg = runner.model_config(config)
+    srv = config["assumed"]["serve"]
+    B = srv["max_batch"]
+    geo = kv_cache.with_rings(
+        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
+        cfg, srv["chunk"], B)
+    assert (geo.max_kv, geo.ring_blocks, geo.table_width) == (32768, 0, 2048)
+    assert engine.latent_kernels(cfg, geo, None)
+    params, cache = jax.tree.map(
+        lambda x: _on_chip(topo, x.shape, x.dtype),
+        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
+                                kv_cache.make_cache(cfg, geo))))
+    assert all(v is None for v in cache["v"])       # no scorer cache
+    assert kv_cache.cache_bytes(cfg, geo) == 5 * 32769 * 16 * 640 * 2
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert 12.4e9 < held < 12.5e9          # 73.5 % of the chip's 16.91e9
+
+    def slots(b, *q):
+        return [_on_chip(topo, s, d) for s, d in (
+            ((b, *q), jnp.int32), ((b,), jnp.int32),
+            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
+
+    for name, fn, args in (
+            ("chunk", engine.make_chunk_step(cfg, geo, q_len=srv["chunk"]),
+             slots(1, srv["chunk"])),
+            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
+             slots(B))):
+        compiled = fn.lower(params, cache, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
+        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
+        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
+        assert memory.temp_size_in_bytes < 0.6e9, (
+            name, memory.temp_size_in_bytes)
+        text = compiled.as_text()
+        for kernel, n in (("paged_latent_attention", cfg.n_layers),
+                          ("sparse_latent_attention", 0),
+                          ("window_latent_attention", 0),
+                          ("index_scores", 0), ("index_select", 0)):
+            calls = [line for line in text.splitlines()
+                     if re.match(rf"\s*%{kernel}[.\d]* = ", line)
+                     and "tpu_custom_call" in line]
+            assert len(calls) == n, (name, kernel)
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
+            == 3 * len(cfg.moe_layers)
+        # No float array spans a slot's max_kv positions: neither gathered
+        # pages nor a query block's scores over them.
+        for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", text):
+            assert str(geo.max_kv) not in m.group(1).split(","), m.group(0)
+
+
 # ---- the serving programs at benchmark/configs/nemotron-3-super-120b.json ----
 
 def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip, monkeypatch):
