@@ -905,22 +905,36 @@ def _rope_latent(x, positions, a: LatentAttention):
 
 def _latent_qkv(h, layer, cfg, a: LatentAttention, positions=None):
     """A latent layer's attention operands from the normed input ``h [B, S,
-    D]``, in the ABSORBED form: -> ``(q [B, S, H, row_width], row [B, S,
-    row_width], index)``.
+    D]``, for BOTH forms of the same attention: -> ``(q [B, S, H, row_width],
+    row [B, S, row_width], index, q_heads [B, S, H, nope_dim + row_width -
+    kv_rank])``. An ``attend`` reads the form it runs, and what it leaves
+    unread is dead code to the compiler.
 
     ``row`` is what the cache holds of a token: the key/value latent after
     its norm (and scale), the shared key dims after the rotation to
-    ``positions``, zeros up to ``row_width``. ``q`` is each head's query
-    against such rows: its ``nope_dim`` part multiplied through the head's
-    key up-projection into the latent's ``kv_rank`` dims (so that no head's
-    keys are ever made), its rotated part, zeros: ``q . row`` is the head's
-    logit before the scale, and the probabilities times ``row[..., :kv_rank]``
-    go through the value up-projection after the attention (:func:`block`).
-    Per (query, key) that is ``H * (row_width + kv_rank)`` multiply-adds
-    against ``H * (nope + rope + v)`` expanded: 4 x the operations at these
-    widths, and no ``[keys, H, nope + v]`` expansion of every attended row,
-    which for a selection that differs query by query would be ``kv_rank *
-    H * (nope + v)`` a pair, 100 x more.
+    ``positions``, zeros up to ``row_width``.
+
+    ``q`` is each head's query against such rows, the ABSORBED form: its
+    ``nope_dim`` part multiplied through the head's key up-projection into
+    the latent's ``kv_rank`` dims (so that no head's keys are ever made), its
+    rotated part, zeros: ``q . row`` is the head's logit before the scale,
+    and the probabilities times ``row[..., :kv_rank]`` go through the value
+    up-projection after the attention (:func:`latent_values`).
+
+    ``q_heads`` is each head's query as projected, the EXPANDED form: its
+    ``nope_dim`` part against the head's own keys (a row's latent times
+    ``wkv_b``), then what meets a row's tail (the rotated part, zeros).
+
+    Which form is cheaper follows from the number of queries that attend the
+    same rows. Per (query, key) a head costs ``row_width + kv_rank``
+    multiply-adds absorbed against ``nope + rope + v`` expanded, 3.4 x the
+    operations at the published widths; but the expanded form first makes
+    every attended row's keys and values, ``kv_rank * (nope + v)`` a head a
+    row, as much as 120 pairs. One query a slot (a decode step), or rows
+    that differ query by query (a selection), attend absorbed; a block of
+    some hundreds of queries over one context is cheaper expanded
+    (``ops/pallas_latent.py`` ``expands`` has the rule; the serving engine's
+    chunk program takes it).
 
     ``index`` (a layer with a selection): ``{"q": [B, S, J, d] scorer
     queries, "k": [B, S, d] this token's scorer key (what the cache holds),
@@ -948,8 +962,9 @@ def _latent_qkv(h, layer, cfg, a: LatentAttention, positions=None):
     q_lat = jnp.einsum("bshd,rhd->bshr", q[..., :a.nope_dim],
                        layer["wkv_b"][..., :a.nope_dim].astype(dt))
     pad = a.row_width - a.kv_rank - a.rope_dim
-    q_abs = jnp.concatenate(
-        [q_lat, q_rope, jnp.zeros((*q.shape[:3], pad), dt)], -1)
+    q_tail = [q_rope, jnp.zeros((*q.shape[:3], pad), dt)]
+    q_abs = jnp.concatenate([q_lat, *q_tail], -1)
+    q_heads = jnp.concatenate([q[..., :a.nope_dim], *q_tail], -1)
     row = jnp.concatenate(
         [c_kv, k_r, jnp.zeros((*kv.shape[:2], pad), dt)], -1)
     index = None
@@ -964,7 +979,7 @@ def _latent_qkv(h, layer, cfg, a: LatentAttention, positions=None):
             "q": _rope_head(q_i, positions, a.rope_theta, a.index_rope_dim),
             "k": _rope_head(k_i, positions, a.rope_theta, a.index_rope_dim),
             "w": w}
-    return q_abs, row, index
+    return q_abs, row, index, q_heads
 
 
 # The eps of the selection scorer's LayerNorm (DeepSeek-V3.2's indexer).
@@ -1004,11 +1019,18 @@ def latent_attend(q, rows, a: LatentAttention, allowed, dt):
     return jnp.einsum("bhst,btr->bshr", probs, rows[..., :a.kv_rank])
 
 
+def latent_values(o, wkv_b, a: LatentAttention):
+    """Absorbed attention's output ``o [B, S, H, kv_rank]`` (probabilities
+    times the rows' latent part) through the value up-projection of ``wkv_b
+    [kv_rank, H, nope_dim + v_dim]`` -> ``[B, S, H, v_dim]``."""
+    return jnp.einsum("bshr,rhd->bshd", o, wkv_b[..., a.nope_dim:])
+
+
 def _attend_latent(a, dt):
-    """``attend(q, row, index)`` of a latent layer over its own window (no
-    cache): the forward pass of the trainer and of the tests. -> (output,
-    the selection or None)."""
-    def attend(q, row, index):
+    """``attend(q, row, index, q_heads, wkv_b)`` of a latent layer over its
+    own window (no cache), absorbed: the forward pass of the trainer and of
+    the tests. -> (output ``[B, S, H, v_dim]``, the selection or None)."""
+    def attend(q, row, index, q_heads, wkv_b):
         pos = jnp.broadcast_to(jnp.arange(row.shape[1])[None], row.shape[:2])
         allowed = attend_allowed(a, pos, pos)
         selected = None
@@ -1017,7 +1039,8 @@ def _attend_latent(a, dt):
                 index_scores(index["q"], index["w"], index["k"], allowed),
                 a.index_topk)
             allowed = selection_mask(selected, row.shape[1])
-        return latent_attend(q, row, a, allowed, dt), selected
+        o = latent_attend(q, row, a, allowed, dt)
+        return latent_values(o, wkv_b, a), selected
 
     return attend
 
@@ -1562,11 +1585,12 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
 
     ``li`` is the layer's place in the model, for a configuration that
     describes its layers one by one (``cfg.attn_of``, ``cfg.is_moe``). A
-    latent layer hands ``attend`` its absorbed operands instead,
-    ``attend(q [B, S, H, W], row [B, S, W], index) -> (o [B, S, H,
-    kv_rank], selected keys or None)`` (:func:`_latent_qkv`), takes the
-    result through the value up-projection and the head gate, and returns
-    the selection in its routing (``{"selected": ..}``). A multi-head layer
+    latent layer hands ``attend`` the operands of both forms of its attention
+    instead, ``attend(q [B, S, H, W], row [B, S, W], index, q_heads, wkv_b)
+    -> (o [B, S, H, v_dim], selected keys or None)`` (:func:`_latent_qkv`;
+    absorbed, ``attend`` takes its result through :func:`latent_values`),
+    gates the result a head where the model does, and returns the selection
+    in its routing (``{"selected": ..}``). A multi-head layer
     of a described kind (:class:`MultiHeadAttention`) hands it ``attend(q
     [B, S, Hq, dh], k, v [B, S, Hkv, dh]) -> [B, S, Hq, dh]``
     (:func:`_qkv_kind`) and gates the result a head where the kind says. A
@@ -1598,10 +1622,9 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
                     o = o * _head_gate(h, layer, dt)
                 out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
             else:
-                o, selected = attend(*_latent_qkv(h, layer, cfg, a,
-                                                  positions))
-                o = jnp.einsum("bshr,rhd->bshd", o,
-                               layer["wkv_b"][..., a.nope_dim:].astype(dt))
+                o, selected = attend(
+                    *_latent_qkv(h, layer, cfg, a, positions),
+                    layer["wkv_b"].astype(dt))
                 if cfg.attn_gate:
                     o = o * _head_gate(h, layer, dt)
                 out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))

@@ -644,6 +644,12 @@ SERVE_QK_LATENT_PAIRS = counter(
     "hvd_serve_qk_latent_pairs",
     "(query, key) pairs those layers attended over: every live key of every "
     "query, summed over those layers", ("program",))
+SERVE_LATENT_EXPANDED_CALLS = counter(
+    "hvd_serve_latent_expanded_calls",
+    "Kernel calls of those layers that attended in the expanded form (each "
+    "block of rows expanded into the heads' keys and values once for all the "
+    "call's queries): a call's layers where its queries a slot make that "
+    "form the cheaper one, else 0 (absorbed)", ("program",))
 SERVE_KV_SELECT_SHARE = gauge(
     "hvd_serve_kv_select_share",
     "hvd_serve_kv_selected over hvd_serve_kv_scored, all programs so far: "

@@ -1,7 +1,7 @@
 """The serving kernels of latent attention layers (Pallas TPU): the key
 selection's scores and its top-k, attention over the selected latent rows,
 windowed latent attention over a slot's ring, and full-context latent
-attention over a slot's live pages where they lie.
+attention over a slot's live pages where they lie, absorbed and expanded.
 
 ``serving/engine.py`` (``_latent_layer``) runs them inside ``jit_chunk`` and
 ``jit_decode`` on a TPU backend with no mesh; each ``pallas_call`` carries a
@@ -12,7 +12,7 @@ device trace (``docs/observability.md``; the benchmark's ``*_dev_ms.doc`` and
 ``select_keys`` and ``latent_attend``, which the engine runs everywhere else
 and the tests compare these with (``interpret=True`` on the CPU).
 
-All five take operands in the compute dtype, accumulate in float32, take the
+All six take operands in the compute dtype, accumulate in float32, take the
 softmax in float32 and round the probabilities to the compute dtype before
 the product with the rows, as ``latent_attend`` does.
 
@@ -35,6 +35,14 @@ the product with the rows, as ``latent_attend`` does.
 ``window_latent_attention``   a tile of a slot's queries, all heads, against
     the slot's ring ``[ring_tokens, row_width]`` under ``0 <= q_pos - k_pos <
     window``.
+``paged_latent_attention``   a block of a slot's consecutive queries, all
+    heads, against the slot's live pages through its block table, absorbed
+    (one query a slot: the decode step).
+``paged_latent_attention_expanded``   all of a slot's queries of a call
+    against the same pages, a group of heads at a time: each block of rows
+    is multiplied by a head's slice of ``wkv_b`` in VMEM, once for all the
+    queries, which attend the head's own keys and values (a chunk fill;
+    :func:`expands` says for which calls this form is the cheaper one).
 """
 import functools
 import math
@@ -53,6 +61,7 @@ SELECT_NAME = "index_select"
 SPARSE_NAME = "sparse_latent_attention"
 WINDOW_NAME = "window_latent_attention"
 PAGED_NAME = "paged_latent_attention"
+PAGED_EXPANDED_NAME = "paged_latent_attention_expanded"
 
 _VMEM_LIMIT = 64 * 1024 * 1024     # of a v5e's 128 MiB; the default is 16
 
@@ -60,7 +69,9 @@ _VMEM_LIMIT = 64 * 1024 * 1024     # of a v5e's 128 MiB; the default is 16
 def supported(a, geo):
     """Whether layer kind ``a``'s kernels tile at this cache geometry: whole
     lane tiles of latent, of scorer key and of selected keys; a context of
-    whole 8 x 128 key blocks; a ring of whole lane tiles."""
+    whole 8 x 128 key blocks; a ring of whole lane tiles; for a full-context
+    kind whole bf16 tiles a page and whole lane tiles of a head's key and of
+    its value (the expanded form slices them out of one product)."""
     ok = a.kv_rank % _LANES == 0 and a.row_width % _LANES == 0
     if a.index_topk:
         ok &= (a.index_dim % _LANES == 0 and a.index_topk % _LANES == 0
@@ -68,8 +79,9 @@ def supported(a, geo):
                and a.index_topk <= geo.max_kv)
     if a.window:
         ok &= geo.ring_tokens % _LANES == 0
-    if not (a.window or a.index_topk):      # pages copied whole: bf16 tiles
-        ok &= geo.page_size % 16 == 0
+    if not (a.window or a.index_topk):
+        ok &= (geo.page_size % 16 == 0 and a.nope_dim % _LANES == 0
+               and a.v_dim % _LANES == 0)
     return bool(ok)
 
 
@@ -407,6 +419,21 @@ _PAGED_CHUNK_TOKENS = 512
 _PAGED_VMEM_LIMIT = 96 * 1024 * 1024
 
 
+def _block_copies(tables_ref, r_hbm, r_buf, sems, b, last_page, i, slot, *,
+                  page, ppb, width):
+    """The copies that bring slot ``b``'s ``i``-th block of ``ppb`` pages
+    into buffer ``slot``, one a page through its block table."""
+    out = []
+    for j in range(ppb):
+        # Past the live pages: the last live one again, never a page the
+        # slot does not own.
+        p = jnp.minimum(jnp.minimum(i * ppb + j, last_page), width - 1)
+        out.append(pltpu.make_async_copy(
+            r_hbm.at[tables_ref[b * width + p]],
+            r_buf.at[slot, pl.ds(j * page, page)], sems.at[slot]))
+    return out
+
+
 def _paged_kernel(pos0_ref, len_ref, tables_ref, q_ref, r_hbm, o_ref, r_buf,
                   sems, m_s, l_s, acc_s, *, page, ppb, width, qb, kv_rank,
                   scale):
@@ -419,16 +446,8 @@ def _paged_kernel(pos0_ref, len_ref, tables_ref, q_ref, r_hbm, o_ref, r_buf,
     n_blocks = jnp.maximum((hi + bt - 1) // bt, 0)
     last_page = jnp.maximum(hi - 1, 0) // page
 
-    def copies(i, slot):
-        out = []
-        for j in range(ppb):
-            # Past the live pages: the last live one again, never a page
-            # the slot does not own.
-            p = jnp.minimum(jnp.minimum(i * ppb + j, last_page), width - 1)
-            out.append(pltpu.make_async_copy(
-                r_hbm.at[tables_ref[b * width + p]],
-                r_buf.at[slot, pl.ds(j * page, page)], sems.at[slot]))
-        return out
+    copies = functools.partial(_block_copies, tables_ref, r_hbm, r_buf, sems,
+                               b, last_page, page=page, ppb=ppb, width=width)
 
     m_s[...] = jnp.full_like(m_s, _NEG)
     l_s[...] = jnp.zeros_like(l_s)
@@ -560,3 +579,203 @@ def paged_latent_attention(q, rows, tables, pos0, kv_len, a, *, q_block=None,
       tables.reshape(-1).astype(jnp.int32), qt, rows)
     out = out[:, :, :H * qb].reshape(B, nq, H, qb, a.kv_rank)
     return out.transpose(0, 1, 3, 2, 4).reshape(B, Q, H, a.kv_rank)
+
+
+# ---- paged_latent_attention_expanded -----------------------------------------
+
+# Heads a grid step takes (their slice of ``wkv_b`` stays in VMEM; every block
+# of rows is copied once for all of them and expanded once a head for ALL the
+# call's queries) and the rows a block of keys aims for. Read on a v5e at the
+# same sizes (PERF.md, PR 45): a 512-query chunk at a context of 16k / 4k /
+# 4.5k takes 4.34 / 1.27 / 1.50 ms at (16 heads, 1024 rows), 4.83 / 1.37 /
+# 1.50 at (16, 512), 4.45 / 1.27 / 1.53 at (8, 1024), 4.30 / 1.25 / 1.49 at
+# (32, 1024), 4.45 / 1.26 / 1.80 at (16, 2048); 7.40 / 2.14 absorbed, 3.1 /
+# 0.76 ms of operations at the bf16 peak. With the heads' loop unrolled whole
+# (8 heads, 512 rows) it takes 4.82 / 1.36 and seven times the code (of
+# which a program keeps a copy a layer on the device).
+_EXPANDED_HEADS = 16
+_EXPANDED_TOKENS = 1024
+
+
+def expands(a, q_len):
+    """Whether ``q_len`` consecutive queries of a slot attend kind ``a``'s
+    whole context in fewer operations EXPANDED than absorbed. A (query, key)
+    pair costs a head ``2 kv_rank + rope`` multiply-adds absorbed and ``nope
+    + rope + v`` expanded; expanding a row costs a head ``kv_rank (nope + v)``
+    once a call, whatever the queries. So: where ``q_len (2 kv_rank - nope -
+    v) > kv_rank (nope + v)``. At 512 / 128 / 128 that is ``q_len > 170``: a
+    chunk of 512 expands, a decode step and a speculation's few drafts stay
+    absorbed. A kind with a window or a selection never expands (a ring is
+    short; selected rows differ query by query)."""
+    if a.window or a.index_topk:
+        return False
+    return (q_len * (2 * a.kv_rank - a.nope_dim - a.v_dim)
+            > a.kv_rank * (a.nope_dim + a.v_dim))
+
+
+def _expanded_kernel(pos0_ref, len_ref, tables_ref, q_ref, w_ref, r_hbm,
+                     o_ref, r_buf, sems, k_s, m_s, l_s, acc_s, *, page, ppb,
+                     width, kv_rank, nope, v_dim, scale):
+    b = pl.program_id(0)
+    kv_len = len_ref[b]
+    q_first = pos0_ref[b]
+    heads, n_q = acc_s.shape[:2]
+    bt = ppb * page
+    # Rows the call's queries can see: positions 0 .. hi - 1.
+    hi = jnp.minimum(q_first + n_q, kv_len)
+    n_blocks = jnp.maximum((hi + bt - 1) // bt, 0)
+    last_page = jnp.maximum(hi - 1, 0) // page
+
+    copies = functools.partial(_block_copies, tables_ref, r_hbm, r_buf, sems,
+                               b, last_page, page=page, ppb=ppb, width=width)
+
+    m_s[...] = jnp.full_like(m_s, _NEG)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+    q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, (n_q, 1), 0)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def block(i, _):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            for c in copies(i + 1, 1 - slot):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        r = r_buf[slot]                                         # [bt, W]
+        latent = r[:, :kv_rank]
+        # A head's keys against its whole query: its own ``nope`` dims, made
+        # below, then the rows' tail, the same for every head.
+        k_s[:, nope:] = r[:, kv_rank:]
+        # Every row sees position 0, so from the first block on its running
+        # maximum is a real logit and exp(_NEG - m) is 0: the mask is a sum.
+        k_pos = i * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        mask = jnp.where((k_pos <= q_pos) & (k_pos < kv_len), 0.0, _NEG)
+
+        def head(h, _):
+            # The block's keys and values of this head, made once for all
+            # the queries and rounded as the absorbed query is.
+            lanes = pl.multiple_of(h * (nope + v_dim), _LANES)
+            kv = jax.lax.dot_general(
+                latent, w_ref[:, pl.ds(lanes, nope + v_dim)],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(r.dtype)
+            k_s[:, :nope] = kv[:, :nope]
+            s = jax.lax.dot_general(
+                q_ref[0, h], k_s[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + mask  # [Q, bt]
+            m_prev = m_s[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            m_s[h] = m_new
+            l_s[h] = l_s[h] * alpha + jnp.sum(p, -1, keepdims=True)
+            acc_s[h] = acc_s[h] * alpha + jax.lax.dot_general(
+                p.astype(r.dtype), kv[:, nope:], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        jax.lax.fori_loop(0, heads, head, None)
+
+    jax.lax.fori_loop(0, n_blocks, block, None)
+
+    l = l_s[...]
+    o_ref[0] = (acc_s[...] * (1.0 / jnp.where(l > 0, l, 1.0))
+                ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "a", "head_group", "pages_per_block", "interpret"))
+def paged_latent_attention_expanded(q, wkv_b, rows, tables, pos0, kv_len, a,
+                                    *, head_group=None, pages_per_block=None,
+                                    interpret=None):
+    """:func:`paged_latent_attention`'s result after the value up-projection,
+    ``[B, Q, H, v_dim]``, computed in the EXPANDED form: ``q [B, Q, H, nope +
+    row_width - kv_rank]`` are the heads' own queries (the ``nope`` dims,
+    then what meets a row's tail: the rotated dims and zeros), ``wkv_b
+    [kv_rank, H, nope + v_dim]`` the layer's key/value up-projection; rows,
+    tables, positions and lengths as there, and the same rows seen.
+
+    Each block of rows is copied to VMEM once a group of heads and its latent
+    part multiplied by a head's slice of ``wkv_b`` there: that block's keys
+    ``[block, nope]`` and values ``[block, v_dim]`` of the head, for ALL the
+    call's queries at once (what makes the form cheaper for a block of
+    queries, :func:`expands`, and is why the queries are not tiled). A head's
+    logits are ONE product of its query with ``[k | row_tail]`` (the rotated
+    key is the rows' own, shared by the heads), accumulated in float32, then
+    the scale; the same mask (as a sum) and online softmax; probabilities and
+    expanded rows rounded to the compute dtype before their products. The
+    expanded rows never leave VMEM.
+
+    Structure: grid over slots and groups of ``head_group`` heads, a loop
+    over a group's heads inside the loop over the blocks of rows; the block
+    table is walked as in :func:`paged_latent_attention`, ``pages_per_block``
+    pages at a time."""
+    B, Q, H, q_lanes = q.shape
+    n_pages, page, W = rows.shape
+    nope, v_dim = a.nope_dim, a.v_dim
+    if (q_lanes != nope + W - a.kv_rank
+            or wkv_b.shape != (a.kv_rank, H, nope + v_dim)):
+        raise ValueError(f"queries {q.shape} and wkv_b {wkv_b.shape} do not "
+                         f"match rows of {W} lanes over a latent of "
+                         f"{a.kv_rank}")
+    hg = _divisor(H, int(head_group or _EXPANDED_HEADS))
+    width = tables.shape[1]
+    ppb = min(int(pages_per_block or max(1, _EXPANDED_TOKENS // page)),
+              width)
+    bt = ppb * page
+    n_q = -(-Q // 16) * 16                # whole bf16 sublane tiles
+    # Head-major, as the products around the kernel make and take them.
+    qt = jnp.pad(q.transpose(0, 2, 1, 3),
+                 ((0, 0), (0, 0), (0, n_q - Q), (0, 0)))
+    kernel = functools.partial(
+        _expanded_kernel, page=page, ppb=ppb, width=width, kv_rank=a.kv_rank,
+        nope=nope, v_dim=v_dim, scale=a.softmax_scale)
+    live = B * width * page               # an upper bound
+
+    def heads(lanes):
+        return pl.BlockSpec((1, hg, n_q, lanes),
+                            lambda b, g, *_: (b, g, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, H // hg),
+            in_specs=[
+                heads(q_lanes),
+                pl.BlockSpec((a.kv_rank, hg * (nope + v_dim)),
+                             lambda b, g, *_: (0, g),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=heads(v_dim),
+            scratch_shapes=[
+                pltpu.VMEM((2, bt, W), rows.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((bt, q_lanes), rows.dtype),
+                pltpu.VMEM((hg, n_q, 1), jnp.float32),
+                pltpu.VMEM((hg, n_q, 1), jnp.float32),
+                pltpu.VMEM((hg, n_q, v_dim), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H, n_q, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_PAGED_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * H * live * (a.kv_rank * (nope + v_dim)
+                                  + n_q * (q_lanes + v_dim)),
+            transcendentals=H * n_q * live,
+            bytes_accessed=(H // hg) * live * W * rows.dtype.itemsize),
+        name=PAGED_EXPANDED_NAME,
+        interpret=_interpret() if interpret is None else interpret,
+    )(pos0.astype(jnp.int32), kv_len.astype(jnp.int32),
+      tables.reshape(-1).astype(jnp.int32), qt,
+      wkv_b.reshape(a.kv_rank, H * (nope + v_dim)), rows)
+    return out[:, :, :Q].transpose(0, 2, 1, 3)

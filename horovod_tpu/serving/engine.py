@@ -56,10 +56,11 @@ checks; for the decode program also that nothing of the gathered pages'
 size is left in it).
 
 A layer of LATENT attention (``TransformerConfig.latent``;
-``transformer._latent_qkv`` makes its absorbed operands) runs the same way in
-the chunk and the decode program, which for it differ only in the length of
-the query window (:func:`_latent_layer`): the window's latent rows (and
-selection keys) are scattered into the layer's arrays, then
+``transformer._latent_qkv`` makes the operands of both forms of its attention,
+absorbed and expanded) runs the same way in the chunk and the decode program,
+which for it differ only in the length of the query window
+(:func:`_latent_layer`): the window's latent rows (and selection keys) are
+scattered into the layer's arrays, then
 
 - a layer that **selects** scores every live key of the slot with the small
   scorer (``index_scores``), keeps each query's ``index_topk`` best
@@ -70,13 +71,22 @@ selection keys) are scattered into the layer's arrays, then
   the block table's tail), works out which position each ring cell holds,
   and attends under the window (``window_latent_attention``);
 - a layer with **neither** attends over its whole context: the slot's LIVE
-  pages, read where they lie through the block table, for one query a slot
-  and for a block of queries alike (``paged_latent_attention``: the absorbed
-  form is multi-query attention over one row a token); no ``[Q, H, max_kv]``
-  scores and no gathered ``[B, max_kv, row_width]`` copy exist. The plain
-  tier gathers the slot's ``max_kv`` rows and masks.
+  pages, read where they lie through the block table; no ``[Q, H, max_kv]``
+  scores and no gathered ``[B, max_kv, row_width]`` copy exist. In which
+  FORM follows from the call's queries a slot and the kind's widths
+  (``pallas_latent.expands``; at 512 / 128 / 128 from 171 queries on). One
+  query a slot (the decode step) and a speculation's few attend ABSORBED
+  (``paged_latent_attention``: multi-query attention over one row a token;
+  the head's query is multiplied into the latent before, the output through
+  the value up-projection after). A chunk's 512 queries attend EXPANDED
+  (``paged_latent_attention_expanded``): each block of rows is expanded in
+  VMEM into a head's keys and values once for all the queries, which then
+  attend at ``nope + rope`` and ``v`` dims a head, 0.3 x the operations a
+  (query, key) pair; the absorb product and the up-projection do not run
+  for that call. The plain tier gathers the slot's ``max_kv`` rows, masks,
+  and attends absorbed.
 
-Those five names are Pallas kernels (:mod:`horovod_tpu.ops.pallas_latent`)
+Those six names are Pallas kernels (:mod:`horovod_tpu.ops.pallas_latent`)
 and the instruction names a device trace shows; they run on a TPU backend
 with no mesh (:func:`latent_kernels`). Elsewhere the same mathematics runs as
 plain ``jax.numpy`` (``transformer.index_scores``, ``select_keys``,
@@ -263,17 +273,22 @@ def _ring_positions(p_hi, n_cells):
     return p_hi[:, None] - (p_hi[:, None] - jnp.arange(n_cells)) % n_cells
 
 
-def _latent_layer(a, q, row, index, rows_c, keys_c, *, q_pos, ok, tables,
-                  geo, dt, kernels):
+def _latent_layer(a, q, row, index, q_heads, wkv_b, rows_c, keys_c, *,
+                  q_pos, ok, tables, geo, dt, kernels):
     """One latent layer of a chunk or decode program: write the window's
     ``row [B, Q, W]`` (and selection key) at ``q_pos [B, Q]`` where ``ok [B,
-    Q]``, then attend ``q [B, Q, H, W]`` -> (the layer's arrays, ``o [B, Q,
-    H, kv_rank]``, the selected keys ``[B, Q, k]`` or None). ``tables [B,
-    max_blocks + ring_blocks]``."""
+    Q]``, then attend -> (the layer's arrays, ``o [B, Q, H, v_dim]``, the
+    selected keys ``[B, Q, k]`` or None). ``tables [B, max_blocks +
+    ring_blocks]``. The operands are ``transformer._latent_qkv``'s: ``q [B,
+    Q, H, W]`` attends absorbed and its result goes through the value
+    up-projection ``wkv_b`` here; ``q_heads`` and ``wkv_b`` attend expanded,
+    which the full-context kernel does where ``pallas_latent.expands`` finds
+    that form the cheaper one for ``Q`` queries."""
     page = geo.page_size
     B, Q = q_pos.shape
     table, page_ids, slot = _window_cells(a, q_pos, ok, tables, geo)
     rows_c = rows_c.at[page_ids, slot].set(row)
+    values = functools.partial(tfm.latent_values, wkv_b=wkv_b, a=a)
     if a.window:
         n_cells = geo.ring_tokens
         ring = rows_c[table].reshape(B, n_cells, -1)
@@ -284,10 +299,14 @@ def _latent_layer(a, q, row, index, rows_c, keys_c, *, q_pos, ok, tables,
         else:
             allowed = tfm.attend_allowed(a, q_pos, k_pos, k_pos >= 0)
             o = tfm.latent_attend(q, ring, a, allowed, dt)
-        return rows_c, keys_c, o, None
+        return rows_c, keys_c, values(o), None
     k_pos = jnp.broadcast_to(jnp.arange(geo.max_kv)[None], (B, geo.max_kv))
     if not a.index_topk:        # the whole context: the slot's live pages
         p_hi = jnp.max(jnp.where(ok, q_pos, -1), axis=1)            # [B]
+        if kernels and pallas_latent.expands(a, Q):
+            o = pallas_latent.paged_latent_attention_expanded(
+                q_heads, wkv_b, rows_c, table, q_pos[:, 0], p_hi + 1, a)
+            return rows_c, keys_c, o, None
         if kernels:
             o = pallas_latent.paged_latent_attention(
                 q, rows_c, table, q_pos[:, 0], p_hi + 1, a)
@@ -296,7 +315,7 @@ def _latent_layer(a, q, row, index, rows_c, keys_c, *, q_pos, ok, tables,
                                          k_pos <= p_hi[:, None])
             o = tfm.latent_attend(
                 q, rows_c[table].reshape(B, geo.max_kv, -1), a, allowed, dt)
-        return rows_c, keys_c, o, None
+        return rows_c, keys_c, values(o), None
     keys_c = keys_c.at[page_ids, slot].set(index["k"])
     keys = keys_c[table].reshape(B, geo.max_kv, -1)
     if kernels:
@@ -337,7 +356,7 @@ def _latent_layer(a, q, row, index, rows_c, keys_c, *, q_pos, ok, tables,
             picked.reshape(B * Q, *picked.shape[2:]), a,
             (selected >= 0).reshape(B * Q, 1, -1), dt).reshape(
                 B, Q, q.shape[2], a.kv_rank)
-    return rows_c, keys_c, o, selected
+    return rows_c, keys_c, values(o), selected
 
 
 def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
@@ -432,7 +451,7 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
     (layer_cache, k or v to attend over)``, then the window attends by
     ``attend(q, k, v)`` (:func:`_masked` over gathered pages, or the decode
     program's kernel over the layer's own arrays). A latent layer goes
-    through ``latent(a, q, row, index, rows_c, keys_c)``
+    through ``latent(a, q, row, index, q_heads, wkv_b, rows_c, keys_c)``
     (:func:`_latent_layer` with the program's positions and tables), a
     multi-head layer of a described kind through ``grouped(a, q, k, v, k_c,
     v_c)`` (:func:`_grouped_layer`, the same), a state-space layer through
@@ -461,8 +480,8 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
                 ck[li], cv[li], o = grouped(a, q, k, v, ck[li], cv[li])
                 return o
         else:
-            def write_and_attend(q, row, index, li=li, a=a):
-                ck[li], cv[li], o, selected = latent(a, q, row, index,
+            def write_and_attend(*operands, li=li, a=a):
+                ck[li], cv[li], o, selected = latent(a, *operands,
                                                      ck[li], cv[li])
                 return o, selected
 

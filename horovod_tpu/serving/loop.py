@@ -325,9 +325,12 @@ class ServeLoop:
         self._select = [a.index_topk for a in latent if a.index_topk]
         self._windows = [a.window for a in latent if a.window]
         # Latent layers that attend over their whole context: the rows a
-        # call has to read (a slot's live rows once a layer) and the pairs.
-        self._latent_full = sum(1 for a in latent
-                                if not (a.index_topk or a.window))
+        # call has to read (a slot's live rows once a layer), the pairs, and
+        # the kernel calls that took the expanded form (the engine's choice,
+        # from the call's queries a slot and the kind's widths).
+        self._latent_full = [a for a in latent
+                             if not (a.index_topk or a.window)]
+        self._latent_kernels = engine.latent_kernels(cfg, geo, mesh)
         # Multi-head layers of a described kind: the K/V rows a call reads
         # (each live row once a layer: what the paged kernel has to move) and
         # the (query, key) pairs it multiplies, full and window layers apart;
@@ -340,8 +343,8 @@ class ServeLoop:
             "kv_scored", "kv_selected", "kv_window", "queries", "calls",
             *(("kv_full_rows", "kv_window_rows", "kv_window_rows_as_full",
                "qk_full_pairs", "qk_window_pairs") if multihead else ()),
-            *(("kv_latent_rows", "qk_latent_pairs") if self._latent_full
-              else ()))}
+            *(("kv_latent_rows", "qk_latent_pairs", "latent_expanded_calls")
+              if self._latent_full else ()))}
         # State-space layers: by program kind, the (slot, layer) rows a call
         # reads and writes back, their bytes both ways (tail and state),
         # the (token, layer) positions scanned, the rows a call zeroed
@@ -450,12 +453,18 @@ class ServeLoop:
                 (_metrics.SERVE_KV_WINDOW_ROWS_AS_FULL,
                  "kv_window_rows_as_full")]
         if self._latent_full:
+            layers = len(self._latent_full)
             found.update(
-                kv_latent_rows=int(live.max(axis=1).sum())
-                * self._latent_full,
-                qk_latent_pairs=int(live.sum()) * self._latent_full)
+                kv_latent_rows=int(live.max(axis=1).sum()) * layers,
+                qk_latent_pairs=int(live.sum()) * layers,
+                latent_expanded_calls=sum(
+                    self._latent_kernels
+                    and engine.pallas_latent.expands(a, live.shape[1])
+                    for a in self._latent_full))
             counters += [(_metrics.SERVE_KV_LATENT_ROWS, "kv_latent_rows"),
-                         (_metrics.SERVE_QK_LATENT_PAIRS, "qk_latent_pairs")]
+                         (_metrics.SERVE_QK_LATENT_PAIRS, "qk_latent_pairs"),
+                         (_metrics.SERVE_LATENT_EXPANDED_CALLS,
+                          "latent_expanded_calls")]
         for name, n in found.items():
             by_kind = self.attn_stats[name]
             by_kind[kind] = by_kind.get(kind, 0) + n

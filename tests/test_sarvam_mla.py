@@ -16,6 +16,7 @@ the one attends in the absorbed form through pages and the other in the
 expanded form with no cache.
 """
 import dataclasses
+import functools
 import hashlib
 import importlib.util
 import json
@@ -107,8 +108,14 @@ def _want(config, params, tokens, fault=None, **kw):
 
 def _loop(cfg, params, max_batch=2, n_pages=64, context=128, **kw):
     geo = kv_cache.geometry(n_pages, PAGE, context)
+    kw.setdefault("prefill_chunk", CHUNK)
     return serve_loop.ServeLoop(params, cfg, geo=geo, max_batch=max_batch,
-                                prefill_chunk=CHUNK, **kw)
+                                **kw)
+
+
+# A latent wide enough for its head dims (64 over 16 + 16) that a chunk of 32
+# queries is cheaper expanded (``pallas_latent.expands``: from 22 queries on).
+WIDE, WIDE_CHUNK = dict(kv_lora_rank=64), 32
 
 
 @pytest.fixture(scope="module")
@@ -219,20 +226,37 @@ def test_the_selection_bias_chooses(tiny):
     assert differ.mean() > 0.1
 
 
-@pytest.mark.parametrize("n", [21, 32])
-def test_chunks_then_decode_is_one_forward(tiny, tier, n):
+@pytest.mark.parametrize("n, wide", [(21, False), (32, False), (75, True)])
+def test_chunks_then_decode_is_one_forward(tiny, tier, n, wide, monkeypatch):
     """A prompt filled in chunks of 8 (``n`` 21 ends inside a page, 32 on a
     chunk's edge) and then decoded, through the loop's programs and pages,
-    against one full ``forward``."""
+    against one full ``forward``. ``wide``: chunks of 32 over a latent of 64,
+    which the kernel tier attends EXPANDED, and then decode steps, absorbed,
+    over the rows those chunks wrote."""
     _, cfg, params = tiny
+    kw = {}
+    if wide:
+        cfg = _cfg(_config(**WIDE))
+        params, kw = _params(cfg), dict(prefill_chunk=WIDE_CHUNK)
+    traced = []
+    for name in ("paged_latent_attention", "paged_latent_attention_expanded"):
+        def spy(*args, name=name, fn=getattr(pallas_latent, name), **kwargs):
+            traced.append((name, args[0].shape[1]))
+            return fn(*args, **kwargs)
+        monkeypatch.setattr(pallas_latent, name, spy)
     served = _load("benchmark/runners/serve_layers.py", "serve_layers_runner")
-    lp = _loop(cfg, params)
+    lp = _loop(cfg, params, **kw)
     pages = np.arange(1, 2 + (n + served.N_DECODE) // PAGE)
     seq, rows, tops, selected = served.served_rows(lp, params, _tokens(n),
                                                    pages)
     assert selected is None and tops.shape == (4, len(seq), 4)
     full = tfm.forward(params, jnp.asarray([seq]), cfg)
     assert _rel(rows, full[0, -len(rows):]) < TOL
+    if tier == "kernel":    # one trace a layer and program
+        chunk = ("paged_latent_attention_expanded", WIDE_CHUNK) if wide \
+            else ("paged_latent_attention", CHUNK)
+        assert sorted(traced) == sorted(
+            [chunk, ("paged_latent_attention", 1)] * cfg.n_layers)
 
 
 # ---- the kernel -----------------------------------------------------------
@@ -242,7 +266,7 @@ def _kernel_case(q_len, lengths, seed=0):
     the tails of last pages hold stale rows), and each slot's table."""
     a = tfm.LatentAttention(4, 0, 128, 16, 8, 16, scale_mult=1.87)
     rng = np.random.default_rng(seed)
-    B, n_blocks = len(lengths), 6
+    B, n_blocks = len(lengths), max(6, -(-max(lengths) // (PAGE * 2)))
     rows = jnp.asarray(rng.standard_normal((1 + B * n_blocks + 3, PAGE * 2,
                                             a.row_width)), jnp.float32)
     tables = np.zeros((B, n_blocks), np.int32)
@@ -254,38 +278,90 @@ def _kernel_case(q_len, lengths, seed=0):
     return a, q, rows, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
 
 
-@pytest.mark.parametrize("q_len, lengths, q_block", [
-    (1, (13, 40, 0), None), (1, (48, 1, 17), None),
-    (8, (13, 40, 8), None), (16, (16, 43, 0), 8), (16, (48, 21, 30), 4)])
-def test_the_kernel_is_latent_attend(q_len, lengths, q_block):
+def _both_forms(a, q_heads, seed=7):
+    """A ``wkv_b`` and, from the heads' own queries ``q_heads [B, Q, H, nope
+    + tail]`` (``_kernel_case``'s, cut to that width), the absorbed ones."""
+    q_heads = q_heads[..., :a.nope_dim + a.row_width - a.kv_rank]
+    wkv_b = jnp.asarray(np.random.default_rng(seed).standard_normal(
+        (a.kv_rank, a.n_heads, a.nope_dim + a.v_dim)) / 8, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        q_lat = jnp.einsum("bshd,rhd->bshr", q_heads[..., :a.nope_dim],
+                           wkv_b[..., :a.nope_dim])
+    return q_heads, wkv_b, jnp.concatenate(
+        [q_lat, q_heads[..., a.nope_dim:]], -1)
+
+
+def _expanded_product(a, q_heads, wkv_b, rows, allowed):
+    """The expanded form written out in float32: every row's keys and
+    values of every head, materialised scores."""
+    with jax.default_matmul_precision("highest"):
+        kv = jnp.einsum("btr,rhd->bthd", rows[..., :a.kv_rank], wkv_b)
+        logits = (jnp.einsum("bshd,bthd->bhst", q_heads[..., :a.nope_dim],
+                             kv[..., :a.nope_dim])
+                  + jnp.einsum("bshd,btd->bhst", q_heads[..., a.nope_dim:],
+                               rows[..., a.kv_rank:])) * a.softmax_scale
+        probs = jax.nn.softmax(jnp.where(allowed[:, None], logits, -1e30), -1)
+        probs = jnp.where(allowed[:, None], probs, 0.0)
+        return jnp.einsum("bhst,bthd->bshd", probs, kv[..., a.nope_dim:])
+
+
+@pytest.mark.parametrize("q_len, lengths, q_block, form", [
+    (1, (13, 40, 0), None, "absorbed"), (1, (48, 1, 17), None, "absorbed"),
+    (8, (13, 40, 8), None, "absorbed"), (16, (16, 43, 0), 8, "absorbed"),
+    (16, (48, 21, 30), 4, "absorbed"),
+    (256, (300, 256, 0), None, "expanded"),
+    (256, (261, 389, 256), None, "expanded"),
+    (512, (700, 512, 517), None, "expanded")])
+def test_the_kernel_is_latent_attend(q_len, lengths, q_block, form):
     """``paged_latent_attention`` in interpret mode against
     ``tfm.latent_attend`` over the gathered pages, for one query a slot and
     for a block: lengths that end inside a page, stale rows in the last
-    page's tail and in pages the slot does not own, an inactive slot."""
+    page's tail and in pages the slot does not own, an inactive slot. And
+    ``paged_latent_attention_expanded`` for a chunk's worth of queries (a
+    first position that is no multiple of the key block of 16 rows, and one
+    that is) against the same, taken through the value up-projection, and
+    against the expanded form written out."""
     a, q, rows, tables, kv_len = _kernel_case(q_len, lengths)
     pos0 = jnp.maximum(kv_len - q_len, 0)
-    got = pallas_latent.paged_latent_attention(
-        q, rows, tables, pos0, kv_len, a, q_block=q_block, pages_per_block=2,
-        interpret=True)
     B, n = len(lengths), tables.shape[1] * rows.shape[1]
     k_pos = jnp.broadcast_to(jnp.arange(n)[None], (B, n))
     q_pos = pos0[:, None] + jnp.arange(q_len)[None]
     allowed = tfm.attend_allowed(a, q_pos, k_pos, k_pos < kv_len[:, None])
-    want = tfm.latent_attend(q, rows[tables].reshape(B, n, -1), a, allowed,
-                             jnp.float32)
-    assert got.shape == (B, q_len, a.n_heads, a.kv_rank)
+    gathered = rows[tables].reshape(B, n, -1)
+    if form == "expanded":
+        q_heads, wkv_b, q = _both_forms(a, q)
+        got = pallas_latent.paged_latent_attention_expanded(
+            q_heads, wkv_b, rows, tables, pos0, kv_len, a, head_group=2,
+            pages_per_block=2, interpret=True)
+        with jax.default_matmul_precision("highest"):
+            want = tfm.latent_values(tfm.latent_attend(
+                q, gathered, a, allowed, jnp.float32), wkv_b, a)
+        assert _rel(got, _expanded_product(a, q_heads, wkv_b, gathered,
+                                           allowed)) < 1e-5
+    else:
+        got = pallas_latent.paged_latent_attention(
+            q, rows, tables, pos0, kv_len, a, q_block=q_block,
+            pages_per_block=2, interpret=True)
+        want = tfm.latent_attend(q, gathered, a, allowed, jnp.float32)
+    assert got.shape == want.shape
     assert _rel(got, want) < 1e-5
     dead = [b for b, n_live in enumerate(lengths) if n_live == 0]
     assert not np.asarray(got)[dead].any()
 
 
-def test_the_kernel_never_reads_what_the_slot_does_not_own():
+@pytest.mark.parametrize("form", ["absorbed", "expanded"])
+def test_the_kernel_never_reads_what_the_slot_does_not_own(form):
     """Not-a-number in every page no slot owns: the output stays finite and
     the same."""
     a, q, rows, tables, kv_len = _kernel_case(8, (21, 40))
     pos0 = kv_len - 8
-    clean = pallas_latent.paged_latent_attention(
-        q, rows, tables, pos0, kv_len, a, pages_per_block=2, interpret=True)
+    kernel = functools.partial(pallas_latent.paged_latent_attention, q)
+    if form == "expanded":
+        kernel = functools.partial(
+            pallas_latent.paged_latent_attention_expanded,
+            *_both_forms(a, q)[:2])
+    clean = kernel(rows, tables, pos0, kv_len, a, pages_per_block=2,
+                   interpret=True)
     page = rows.shape[1]
     owned = np.zeros(rows.shape[:2], bool)
     for b, n in enumerate(np.asarray(kv_len)):
@@ -296,8 +372,8 @@ def test_the_kernel_never_reads_what_the_slot_does_not_own():
     # stale rows. Every page the slot does not own becomes not-a-number.
     mine = np.isin(np.arange(rows.shape[0]), np.asarray(tables))
     dirty = jnp.where(owned[..., None] | mine[:, None, None], rows, jnp.nan)
-    got = pallas_latent.paged_latent_attention(
-        q, dirty, tables, pos0, kv_len, a, pages_per_block=2, interpret=True)
+    got = kernel(dirty, tables, pos0, kv_len, a, pages_per_block=2,
+                 interpret=True)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, clean, rtol=1e-6, atol=1e-6)
 
@@ -374,6 +450,54 @@ def test_counters_are_host_arithmetic(tiny):
     assert s["qk_latent_pairs"] == {"chunk": sum(range(9, 17)) * 5,
                                     "decode": (21 + 4) * 5}
     assert s["queries"] == {"chunk": 8, "decode": 2}
+    assert s["latent_expanded_calls"] == {"chunk": 0, "decode": 0}
+
+
+def test_the_form_follows_the_queries_and_the_widths(monkeypatch):
+    """``_latent_layer`` attends a full-context kind EXPANDED where the
+    call's queries a slot make that the cheaper form, from the kind's widths
+    alone: at the published ones a decode step and a speculation's drafts
+    absorbed, a chunk of 512 expanded (from 171 queries on); a kind with a
+    window or a selection never; the plain tier never. And the loop counts
+    the calls that expanded, a layer each, by host arithmetic."""
+    a = runner.model_config(FILE).attn_of(0)
+    assert [pallas_latent.expands(a, q) for q in (1, 8, 170, 171, 512)] \
+        == [False, False, False, True, True]
+    assert not pallas_latent.expands(dataclasses.replace(a, window=4096), 512)
+    assert not pallas_latent.expands(dataclasses.replace(
+        a, q_rank=1536, index_topk=2048, index_heads=4, index_dim=128), 512)
+    geo = kv_cache.geometry(64, 16, 512)
+
+    def kernels_of(q_len, kernels):
+        S = jax.ShapeDtypeStruct
+        H, W, f32 = a.n_heads, a.row_width, jnp.float32
+        jaxpr = jax.make_jaxpr(functools.partial(
+            engine._latent_layer, a, index=None, keys_c=None, geo=geo,
+            dt=f32, kernels=kernels))(
+            q=S((1, q_len, H, W), f32), row=S((1, q_len, W), f32),
+            q_heads=S((1, q_len, H, a.nope_dim + W - a.kv_rank), f32),
+            wkv_b=S((a.kv_rank, H, a.nope_dim + a.v_dim), f32),
+            rows_c=S((geo.n_pages, geo.page_size, W), f32),
+            q_pos=S((1, q_len), jnp.int32), ok=S((1, q_len), jnp.bool_),
+            tables=S((1, geo.table_width), jnp.int32))
+        return [e.params["name"] for e in jaxpr.eqns    # the jitted calls
+                if e.params.get("name", "").startswith("paged_")]
+
+    for q_len in (1, 8):
+        assert kernels_of(q_len, True) == ["paged_latent_attention"]
+    assert kernels_of(512, True) == ["paged_latent_attention_expanded"]
+    assert kernels_of(512, False) == []
+    # The loop's counter, on a latent wide enough for a chunk of 32.
+    cfg = _cfg(_config(**WIDE))
+    for kernels, chunk in ((True, 2 * cfg.n_layers), (False, 0)):
+        monkeypatch.setattr(engine, "latent_kernels", lambda *_: kernels)
+        lp = _loop(cfg, _params(cfg), prefill_chunk=WIDE_CHUNK)
+        for start in (0, 32):
+            lp._count_attn("chunk", np.arange(start, start + 32)[None] + 1)
+        lp._count_attn("decode", np.asarray([70, 3])[:, None] + 1)
+        assert lp.attn_stats["latent_expanded_calls"] == {
+            "chunk": chunk, "decode": 0}
+        assert lp.attn_stats["calls"] == {"chunk": 2, "decode": 1}
 
 
 # ---- the chip's share -----------------------------------------------------

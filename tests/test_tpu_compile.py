@@ -552,13 +552,16 @@ def test_full_latent_cell_programs_fit_one_chip(topo, as_on_the_chip):
     """``sarvam-serve-longdoc-over``'s two programs (the 512-token chunk fill
     and the decode step) at the cell's geometry: five layers of full-context
     latent attention, 16 slots of a 32k context. The chip's compiler takes
-    ``paged_latent_attention`` at the published widths (64 heads over one
-    640-lane row a token) for one query a slot and for a block of queries; it
-    is in both programs under the name the benchmark's readers match, once a
-    layer, and none of the selection's or the window's kernels is; no program
-    holds scores of ``[.., max_kv]`` or a gathered copy of a slot's pages;
-    weights + cache + temporaries stay on the chip; the cache is aliased
-    through."""
+    both forms' kernels at the published widths (64 heads over one 640-lane
+    row a token): ``paged_latent_attention`` for one query a slot in the
+    decode step, ``paged_latent_attention_expanded`` for the chunk's 512
+    queries (inside its VMEM limit), each under a name the benchmark's readers
+    match (``^paged_latent_attention``), once a layer, and none of the
+    selection's or the window's kernels is; the chunk holds nothing of the
+    absorbed form (no ``[1, 512, 64, 640]`` query, no ``[.., 64, 512]`` output
+    in the latent), the decode step no expanded kernel; no program holds
+    scores of ``[.., max_kv]`` or a gathered copy of a slot's pages; weights
+    + cache + temporaries stay on the chip; the cache is aliased through."""
     import importlib.util
     import json
 
@@ -607,7 +610,9 @@ def test_full_latent_cell_programs_fit_one_chip(topo, as_on_the_chip):
         assert memory.temp_size_in_bytes < 0.6e9, (
             name, memory.temp_size_in_bytes)
         text = compiled.as_text()
-        for kernel, n in (("paged_latent_attention", cfg.n_layers),
+        expanded = cfg.n_layers * (name == "chunk")
+        for kernel, n in (("paged_latent_attention_expanded", expanded),
+                          ("paged_latent_attention", cfg.n_layers - expanded),
                           ("sparse_latent_attention", 0),
                           ("window_latent_attention", 0),
                           ("index_scores", 0), ("index_select", 0)):
@@ -617,10 +622,17 @@ def test_full_latent_cell_programs_fit_one_chip(topo, as_on_the_chip):
             assert len(calls) == n, (name, kernel)
         assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
             == 3 * len(cfg.moe_layers)
-        # No float array spans a slot's max_kv positions: neither gathered
-        # pages nor a query block's scores over them.
+        a = cfg.attn_of(0)
+        absorbed = {f"{a.n_heads},{a.row_width}", f"{a.n_heads},{a.kv_rank}"}
         for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", text):
+            # No float array spans a slot's max_kv positions: neither
+            # gathered pages nor a query block's scores over them.
             assert str(geo.max_kv) not in m.group(1).split(","), m.group(0)
+            # The absorbed form has left nothing in the chunk: no query
+            # [.., 64, 640], no output in the latent [.., 64, 512].
+            if name == "chunk":
+                assert ",".join(m.group(1).split(",")[-2:]) not in absorbed, \
+                    m.group(0)
 
 
 # ---- the serving programs at benchmark/configs/nemotron-3-super-120b.json ----
