@@ -8,16 +8,30 @@ VMEM (online softmax forward, FlashAttention-2 recomputation backward)
 with float32 accumulators in scratch, so memory is O(S·D) and 16k+
 sequences train on one chip.
 
-Structure: every kernel runs on a grid ``(B*H, live block pairs)``: the
-pairs a causal mode keeps (``blocks * (blocks + 1) / 2`` of them; all in
-mode ``"none"``) are enumerated on the host into two int32 tables that
-ride in SMEM as scalar prefetch, and the index maps and the kernels read
-their block coordinates from them. A masked-out pair is therefore never a
-grid step and never a copy. The inner coordinate streams the contraction
-blocks (K blocks for the forward/dq kernels, Q blocks for the dk/dv
-kernel); accumulators live in VMEM scratch, initialized on a row's first
-pair and flushed to the output refs on its last. ``block_q == block_k``
-keeps the causal frontier exactly one diagonal block.
+Structure: two kernels, the forward and ONE backward, each on a grid
+``(B*H, live block pairs)``: the pairs a causal mode keeps (``blocks *
+(blocks + 1) / 2`` of them; all in mode ``"none"``) are enumerated on the
+host into two int32 tables that ride in SMEM as scalar prefetch, and the
+index maps and the kernels read their block coordinates from them. A
+masked-out pair is therefore never a grid step and never a copy. The inner
+coordinate streams the contraction blocks (K blocks under a Q block in the
+forward, Q blocks under a K block in the backward); accumulators live in
+VMEM scratch, initialized on a row's first pair and flushed to the output
+refs on its last. ``block_q == block_k`` keeps the causal frontier exactly
+one diagonal block.
+
+The backward computes a pair's scores, ``p``, ``dO.vT`` and ``ds`` once and
+feeds all three gradients from them (five products a pair): dk and dv into
+the K block's scratch, dq into the head's WHOLE dq, a float32
+``[n, block, D]`` output block whose index moves only with the head, so it
+stays in VMEM across the head's pairs, takes ``ds.k`` at row block ``qi``
+in ascending K order, and is written back once (scaled and cast outside,
+fused by XLA into the transpose back to ``[B, S, H, D]``). That block is
+what bounds the shape: ``_bwd_vmem_limit`` asks for more than the
+compiler's default VMEM from S 8192 (bf16) on, and past the chip's VMEM
+(S over 65,536 at head_dim 64) two kernels run instead, a dq kernel and
+the same kernel without its dq, each computing ``p`` and ``ds`` (seven
+products a pair): the same bits, a third slower.
 
 Precision: every product takes its operands in the dtype the arrays have
 and accumulates in float32; scores, softmax statistics, lse, delta and the
@@ -141,8 +155,58 @@ def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 # ---------------------------------------------------------------------------
 # Backward (FlashAttention-2): recompute P per block pair.
 
+def _bwd_kernel(kj_ref, qi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, *outs, sm_scale, mode, n):
+    # Q blocks (with dO, lse, delta) stream under each K/V block, and each
+    # pair's p and ds feed all three gradients. dq_ref is the head's whole
+    # dq, [1, n, block, D] float32: it stays in VMEM from the head's first
+    # pair to its last, and a Q block receives its K blocks in ascending
+    # order, as the dq kernel's scratch does. Called without it (the
+    # shapes of the two kernels), this is the dk/dv kernel.
+    *dq_ref, dk_ref, dv_ref, dk_s, dv_s = outs
+    dq_ref = dq_ref[0] if dq_ref else None
+    t = pl.program_id(1)
+    kj, qi = kj_ref[t], qi_ref[t]
+
+    if dq_ref is not None:
+        @pl.when(t == 0)
+        def _init_dq():
+            dq_ref[:] = jnp.zeros_like(dq_ref)
+
+    @pl.when(qi == (0 if mode == "none" else kj))
+    def _init():
+        dk_s[:] = jnp.zeros_like(dk_s)
+        dv_s[:] = jnp.zeros_like(dv_s)
+
+    q, owed = _q_operand(q_ref, sm_scale)
+    k = k_ref[0]
+    do = do_ref[0]
+    lse = lse_ref[0, 0, 0][:, None]
+    delta = delta_ref[0, 0, 0][:, None]
+    s = _scaled(_dot(q, k, _NT), owed)                     # [bq, bk]
+    p = jnp.exp(s - lse)
+    if mode != "none":
+        # explicit zero, not just s = -1e30: a fully-masked row's
+        # sentinel lse would cancel the sentinel s in the exp.
+        p = jnp.where(_diag_keep(kj == qi, mode, *s.shape), p, 0.0)
+    dv_s[:] = dv_s[:] + _dot(p.astype(do.dtype), do, _TN)
+    dp = _dot(do, v_ref[0], _NT)
+    ds = (p * (dp - delta)).astype(q.dtype)
+    dk_s[:] = dk_s[:] + _dot(ds, q, _TN)
+    if dq_ref is not None:
+        dq_ref[0, qi] = dq_ref[0, qi] + _dot(ds, k, _NN)
+
+    @pl.when(qi == n - 1)
+    def _flush():
+        dk_ref[0] = _scaled(dk_s[:], owed).astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+
+
 def _bwd_dq_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_s, *, sm_scale, mode, n):
+    # dq alone, K/V blocks streaming under each Q block and p and ds
+    # computed again: for the shapes whose dq cannot stay in VMEM
+    # (``_bwd_vmem_limit``), beside _bwd_kernel without a dq.
     t = pl.program_id(1)
     qi, kj = qi_ref[t], kj_ref[t]
 
@@ -157,8 +221,6 @@ def _bwd_dq_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     s = _scaled(_dot(q, k, _NT), owed)
     p = jnp.exp(s - lse)
     if mode != "none":
-        # explicit zero, not just s = -1e30: a fully-masked row's
-        # sentinel lse would cancel the sentinel s in the exp.
         p = jnp.where(_diag_keep(kj == qi, mode, *s.shape), p, 0.0)
     dp = _dot(do_ref[0], v_ref[0], _NT)
     ds = p * (dp - delta)
@@ -167,37 +229,6 @@ def _bwd_dq_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     @pl.when(kj == (n - 1 if mode == "none" else qi))
     def _flush():
         dq_ref[0] = (dq_s[:] * sm_scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(kj_ref, qi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_s, dv_s, *, sm_scale,
-                    mode, n):
-    # Q blocks (with dO, lse, delta) stream under each K/V block.
-    t = pl.program_id(1)
-    kj, qi = kj_ref[t], qi_ref[t]
-
-    @pl.when(qi == (0 if mode == "none" else kj))
-    def _init():
-        dk_s[:] = jnp.zeros_like(dk_s)
-        dv_s[:] = jnp.zeros_like(dv_s)
-
-    q, owed = _q_operand(q_ref, sm_scale)
-    do = do_ref[0]
-    lse = lse_ref[0, 0, 0][:, None]
-    delta = delta_ref[0, 0, 0][:, None]
-    s = _scaled(_dot(q, k_ref[0], _NT), owed)              # [bq, bk]
-    p = jnp.exp(s - lse)
-    if mode != "none":
-        p = jnp.where(_diag_keep(kj == qi, mode, *s.shape), p, 0.0)
-    dv_s[:] = dv_s[:] + _dot(p.astype(do.dtype), do, _TN)
-    dp = _dot(do, v_ref[0], _NT)
-    ds = p * (dp - delta)
-    dk_s[:] = dk_s[:] + _dot(ds.astype(q.dtype), q, _TN)
-
-    @pl.when(qi == n - 1)
-    def _flush():
-        dk_ref[0] = _scaled(dk_s[:], owed).astype(dk_ref.dtype)
-        dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -237,7 +268,7 @@ def _spec(block_shape, table):
 
 
 def _call(kernel, pairs, in_specs, out_specs, out_shape, scratch, interpret,
-          operands, **kw):
+          operands, vmem_limit_bytes=None, **kw):
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -246,7 +277,8 @@ def _call(kernel, pairs, in_specs, out_specs, out_shape, scratch, interpret,
             in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret, **kw)(*pairs, *operands)
 
 
@@ -274,23 +306,35 @@ def _call_bwd(q, k, v, do, lse, delta, sm_scale, mode, block, interpret):
     outer, inner = _spec((1, block, D), 0), _spec((1, block, D), 1)
     row_o, row_i = _spec((1, 1, 1, block), 0), _spec((1, 1, 1, block), 1)
     operands = (q, k, v, do, lse, delta)
+    kw = dict(sm_scale=sm_scale, mode=mode, n=n)
+    vmem_limit = _bwd_vmem_limit(S, D, q.dtype, block)
+    fused = vmem_limit is not None
+
+    # K, V at the outer (K) block; Q, dO, lse, delta stream; the resident
+    # dq is the head's whichever the pair: its block moves only with bh.
+    resident = pl.BlockSpec((1, n, block, D),
+                            lambda bh, t, *tables: (bh, 0, 0, 0),
+                            memory_space=pltpu.VMEM)
+    *dq, dk, dv = _call(
+        functools.partial(_bwd_kernel, **kw),
+        _live_pairs(n, mode, q_under_k=True),
+        [inner, outer, outer, inner, row_i, row_i],
+        fused * [resident] + [outer, outer],
+        fused * [jax.ShapeDtypeStruct((BH, n, block, D), jnp.float32)]
+        + [jax.ShapeDtypeStruct((BH, S, D), k.dtype),
+           jax.ShapeDtypeStruct((BH, S, D), v.dtype)],
+        [_scratch((block, D)), _scratch((block, D))], interpret, operands,
+        vmem_limit_bytes=vmem_limit or None)
+    if fused:
+        # one elementwise pass that XLA fuses into _unfold's transpose
+        return (dq[0].reshape(BH, S, D) * sm_scale).astype(q.dtype), dk, dv
 
     # Q, dO, lse, delta at the outer (Q) block; K, V stream.
     dq, = _call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, mode=mode, n=n),
-        _live_pairs(n, mode),
+        functools.partial(_bwd_dq_kernel, **kw), _live_pairs(n, mode),
         [outer, inner, inner, outer, row_o, row_o], [outer],
         [jax.ShapeDtypeStruct((BH, S, D), q.dtype)],
         [_scratch((block, D))], interpret, operands)
-
-    # K, V at the outer (K) block; Q, dO, lse, delta stream.
-    dk, dv = _call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, mode=mode, n=n),
-        _live_pairs(n, mode, q_under_k=True),
-        [inner, outer, outer, inner, row_i, row_i], [outer, outer],
-        [jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-         jax.ShapeDtypeStruct((BH, S, D), v.dtype)],
-        [_scratch((block, D)), _scratch((block, D))], interpret, operands)
     return dq, dk, dv
 
 
@@ -337,6 +381,36 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # smaller request is a caller testing or debugging the block loop, and
 # stands.
 _BIG_BLOCK = 1024
+
+
+# The one backward kernel keeps a head's dq, float32 [S, D] with D padded to
+# the 128 lanes, in VMEM as an output block, which Pallas buffers twice:
+# 4 MiB at [16, 4096, 64], 16 at S 16384, where the compiler's default of
+# 16 MiB refuses the kernel ("scoped allocation 19.50M" compiled for a
+# described v5e; 24.66M once Mosaic may take room for the score tiles). A
+# raised limit costs nothing (on a v5e S 8192 takes 4.616 ms a layer at the
+# default and 4.612 at 20 MiB) and keeps the one kernel ahead of the two:
+# 16.85 against 23.24 ms at [16, 16384, 64] (PERF.md, PR 46). So the kernel
+# asks for what it needs wherever that is over the default, up to
+# _VMEM_MOST of the chip's 128 MiB ([16, 65536, 64] compiles at 96 MiB,
+# [16, 131072, 64] does not at 126); past it the two kernels run, which
+# keep nothing a head long.
+_VMEM_DEFAULT = 16 << 20
+_VMEM_MOST = 100 << 20
+
+
+def _bwd_vmem_limit(S, D, dtype, block):
+    """How the backward runs at this shape: the one kernel's
+    ``vmem_limit_bytes`` (0: the compiler's default is enough), or None
+    where a head's dq cannot stay in VMEM and the two kernels run."""
+    lanes = -(-D // 128) * 128
+    dq = 2 * S * lanes * 4
+    # q, k, v, dO in and dk, dv out, twice each; two float32 accumulators
+    blocks = block * lanes * (12 * jnp.dtype(dtype).itemsize + 2 * 4)
+    need = dq + blocks + (8 << 20)          # room for the score tiles
+    if need <= _VMEM_DEFAULT:
+        return 0
+    return need if need <= _VMEM_MOST else None
 
 
 def _validate(q, k, v, block):

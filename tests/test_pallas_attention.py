@@ -78,47 +78,81 @@ def test_bf16_inputs_and_partial_block():
                         interpret=True)
 
 
-def _out_and_grads(attn, q, k, v):
-    """attn's output and the gradients of a loss over it, as float32."""
+def _naive_lse(q, k, v, mode):
+    """float32 attention and its log-sum-exp under the kernels' three
+    masks; a row with no visible key (row 0 under "strict") gives o = 0
+    and an lse the loss below leaves out."""
+    S, D = q.shape[1], q.shape[-1]
+    s = jnp.einsum("bshk,bthk->bhst", q, k) / np.sqrt(D)
+    if mode != "none":
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool),
+                               k=-1 if mode == "strict" else 0), s, -np.inf)
+    lse = jax.nn.logsumexp(s, -1)
+    p = jnp.where(jnp.isfinite(lse)[..., None], jnp.exp(s - lse[..., None]),
+                  0.0)
+    return (jnp.einsum("bhst,bthk->bshk", p, v),
+            jnp.where(jnp.isfinite(lse), lse, -1e30))
+
+
+def _lse_loss(attn, with_lse):
+    """A loss over attn's (o, lse) whose cotangent on lse is no constant
+    (ring attention's merge feeds one back), or over o alone."""
     def loss(q, k, v):
-        o = attn(q, k, v)
-        return jnp.sum(jnp.sin(o.astype(jnp.float32))), o
+        o, lse = attn(q, k, v)
+        out = jnp.sum(jnp.sin(o.astype(jnp.float32)))
+        if with_lse:
+            out += jnp.sum(jnp.where(lse > -1e29, jnp.cos(lse), 0.0))
+        return out, o
+    return loss
 
-    (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
-        q, k, v)
-    return [np.asarray(a, np.float32) for a in (o,) + tuple(g)]
 
-
+@pytest.mark.parametrize("with_lse", [False, True], ids=["o", "o+lse"])
+@pytest.mark.parametrize("mode", ["diag", "strict", "none"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
 @pytest.mark.parametrize("block", [64, 256])
-def test_bf16_forward_and_gradients_match_gather_and_naive(block):
-    """bf16 operands into every product, f32 everything else: forward and
-    the three gradients agree with the gather attention the S 512 cells
-    run (bf16 logits, so the coarser of the two) on the same bf16 inputs,
-    and with the float32 naive attention to what bf16 inputs allow."""
+def test_forward_and_gradients_match_gather_and_naive(block, dtype, mode,
+                                                      with_lse):
+    """Operands in the arrays' dtype into every product, f32 everything
+    else: forward and the three gradients of the one backward kernel agree
+    with the float32 naive attention to what the inputs allow, in every
+    mode, with and without a cotangent on lse; and in bf16 causal with the
+    gather attention the S 512 cells run (bf16 logits, so the coarser of
+    the two), which they beat."""
     from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.ops.pallas_attention import flash_attention_lse
 
     rng = np.random.default_rng(7)
     B, S, H, D = 1, 256, 2, 64
-    cfg = tfm.TransformerConfig(vocab_size=8, d_model=H * D, n_heads=H,
-                                n_layers=1, d_ff=8, max_seq_len=S,
-                                dtype="bfloat16")
-    q, k, v = (jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.bfloat16)
+    q, k, v = (jnp.asarray(rng.normal(size=(B, S, H, D)), dtype)
                for _ in range(3))
-    flash = _out_and_grads(
-        lambda q, k, v: flash_attention(q, k, v, causal=True, block=block,
-                                        interpret=True), q, k, v)
-    gather = _out_and_grads(
-        lambda q, k, v: tfm.causal_attend(q, k, v, cfg), q, k, v)
-    naive = _out_and_grads(
-        lambda q, k, v: _naive(q.astype(jnp.float32), k.astype(jnp.float32),
-                               v.astype(jnp.float32), True), q, k, v)
-    for f, g, n, name in zip(flash, gather, naive, ("o", "dq", "dk", "dv")):
-        np.testing.assert_allclose(f, g, atol=4e-2, rtol=4e-2,
-                                   err_msg=f"{name} against gather")
-        np.testing.assert_allclose(f, n, atol=2e-2, rtol=2e-2,
+
+    def run(attn):
+        (_, o), g = jax.value_and_grad(
+            _lse_loss(attn, with_lse), argnums=(0, 1, 2), has_aux=True)(
+                q, k, v)
+        return [np.asarray(a, np.float32) for a in (o,) + tuple(g)]
+
+    flash = run(lambda q, k, v: flash_attention_lse(
+        q, k, v, mode=mode, block=block, interpret=True))
+    naive = run(lambda q, k, v: _naive_lse(
+        q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
+        mode))
+    tol = 2e-2 if dtype == jnp.bfloat16 else 3e-4
+    for f, n, name in zip(flash, naive, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(f, n, atol=tol, rtol=tol,
                                    err_msg=f"{name} against f32 naive")
-        # and closer to the float32 answer than the gather path is
-        assert np.abs(f - n).mean() <= np.abs(g - n).mean(), name
+    if dtype == jnp.bfloat16 and mode == "diag" and not with_lse:
+        cfg = tfm.TransformerConfig(vocab_size=8, d_model=H * D, n_heads=H,
+                                    n_layers=1, d_ff=8, max_seq_len=S,
+                                    dtype="bfloat16")
+        gather = run(lambda q, k, v: (tfm.causal_attend(q, k, v, cfg), None))
+        for f, g, n, name in zip(flash, gather, naive,
+                                 ("o", "dq", "dk", "dv")):
+            np.testing.assert_allclose(f, g, atol=4e-2, rtol=4e-2,
+                                       err_msg=f"{name} against gather")
+            # and closer to the float32 answer than the gather path is
+            assert np.abs(f - n).mean() <= np.abs(g - n).mean(), name
 
 
 def _sub_jaxprs(params):
@@ -140,11 +174,29 @@ def _eqns(jaxpr, name):
                 yield from _eqns(sub, name)
 
 
+@pytest.fixture(params=["one", "two"])
+def backward(request, monkeypatch):
+    """Both sides of the backward's rule on the shape
+    (``_bwd_vmem_limit``): "one" kernel as every test shape gets it, "two"
+    as a shape gets them whose dq would not fit the chip's VMEM, here by
+    leaving the chip none."""
+    if request.param == "two":
+        _no_vmem(monkeypatch)
+    return request.param
+
+
+def _no_vmem(monkeypatch):
+    from horovod_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "_VMEM_DEFAULT", 0)
+    monkeypatch.setattr(pa, "_VMEM_MOST", 0)
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize("mode", ["diag", "strict", "none"])
-def test_every_product_takes_input_dtype_and_gives_f32(dtype, mode):
-    """Forward and both backward kernels: each dot_general's operands have
-    the arrays' dtype (nothing is widened on its way to the MXU) and its
+def test_every_product_takes_input_dtype_and_gives_f32(dtype, mode, backward):
+    """Forward and backward kernels: each dot_general's operands have the
+    arrays' dtype (nothing is widened on its way to the MXU) and its
     result is float32 (nothing is accumulated narrower)."""
     from horovod_tpu.ops.pallas_attention import flash_attention_lse
 
@@ -159,18 +211,66 @@ def test_every_product_takes_input_dtype_and_gives_f32(dtype, mode):
     kernels = list(_eqns(jaxpr.jaxpr, "pallas_call"))
     dots = [[d for sub in _sub_jaxprs(kern.params)
              for d in _eqns(sub, "dot_general")] for kern in kernels]
-    assert sorted(len(d) for d in dots) == [2, 3, 4]   # fwd, dq, dk/dv
+    # fwd and the one backward kernel's five products, or fwd, dq, dk/dv
+    assert sorted(len(d) for d in dots) == {"one": [2, 5],
+                                            "two": [2, 3, 4]}[backward]
     for d in sum(dots, []):
         assert [a.aval.dtype for a in d.invars] == [dtype, dtype], d
         assert d.outvars[0].aval.dtype == jnp.float32, d
     # scratch (m, l and the accumulators: rank 2) and the lse / delta
-    # blocks (rank 4) are float32 whatever the arrays are
+    # blocks and the resident dq (rank 4) are float32 whatever the arrays
     for kern in kernels:
         for sub in _sub_jaxprs(kern.params):
             refs = [a.aval for a in sub.invars]
             assert len([r for r in refs if len(r.shape) == 2]) in (1, 2, 3)
             assert all(r.dtype == jnp.float32 for r in refs
                        if len(r.shape) in (2, 4)), refs
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_one_backward_kernel_gives_the_two_kernels_bits(dtype, monkeypatch):
+    """Same products on the same operands, each dq block summed over its
+    K blocks in the same order: the two sides of the rule agree bit for
+    bit, with a cotangent on lse."""
+    from horovod_tpu.ops import pallas_attention as pa
+
+    rng = np.random.default_rng(46)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 256, 2, 32)), dtype)
+               for _ in range(3))
+    grad = jax.grad(lambda q, k, v: _lse_loss(
+        lambda *a: pa.flash_attention_lse(*a, mode="diag", block=64,
+                                          interpret=True), True)(q, k, v)[0],
+        argnums=(0, 1, 2))
+    one = grad(q, k, v)
+    _no_vmem(monkeypatch)
+    assert pa._bwd_vmem_limit(256, 32, dtype, 64) is None
+    for a, b, name in zip(one, grad(q, k, v), ("dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32), name)
+
+
+def test_backward_rule_by_shape():
+    """_bwd_vmem_limit at the shapes it was compiled and measured at: the
+    default VMEM to [16, 4096, 128] bf16, a raised limit from there to
+    S 65,536, the two kernels past it."""
+    from horovod_tpu.ops.pallas_attention import (_VMEM_DEFAULT, _VMEM_MOST,
+                                                  _bwd_vmem_limit)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert _bwd_vmem_limit(4096, 64, bf16, 1024) == 0
+    assert _bwd_vmem_limit(4096, 128, bf16, 1024) == 0
+    assert _bwd_vmem_limit(512, 64, bf16, 512) == 0
+    assert _bwd_vmem_limit(4096, 64, bf16, 256) == 0
+    for S, D, dtype in ((8192, 64, bf16), (4096, 64, f32), (16384, 64, bf16),
+                        (65536, 64, bf16), (32768, 128, f32)):
+        assert _VMEM_DEFAULT < _bwd_vmem_limit(S, D, dtype, 1024) \
+            <= _VMEM_MOST, (S, D, dtype)
+    # what it asks for grows with the resident dq: 2 x S x 128 lanes x 4 B
+    assert _bwd_vmem_limit(32768, 64, bf16, 1024) \
+        - _bwd_vmem_limit(16384, 64, bf16, 1024) == 2 * 16384 * 128 * 4
+    assert _bwd_vmem_limit(131072, 64, bf16, 1024) is None
+    assert _bwd_vmem_limit(65536, 256, bf16, 1024) is None
 
 
 # sha256 (first 16 hex digits) over o, lse, dq, dk, dv of _f32_digest's
