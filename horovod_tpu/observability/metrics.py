@@ -650,6 +650,21 @@ SERVE_LATENT_EXPANDED_CALLS = counter(
     "block of rows expanded into the heads' keys and values once for all the "
     "call's queries): a call's layers where its queries a slot make that "
     "form the cheaper one, else 0 (absorbed)", ("program",))
+# ``serve_stats()[family][counter]`` -> the counter that exports it, by
+# program kind: ``ServeLoop._add`` drives these from the engine's account of
+# each call (``serving.engine.work``); a counter with no entry is in
+# ``serve_stats()`` only.
+SERVE_WORK_COUNTERS = {"attn": {
+    "kv_scored": SERVE_KV_SCORED,
+    "kv_selected": SERVE_KV_SELECTED,
+    "kv_window": SERVE_KV_WINDOW,
+    "kv_full_rows": SERVE_KV_FULL_ROWS,
+    "kv_window_rows": SERVE_KV_WINDOW_ROWS,
+    "kv_window_rows_as_full": SERVE_KV_WINDOW_ROWS_AS_FULL,
+    "kv_latent_rows": SERVE_KV_LATENT_ROWS,
+    "qk_latent_pairs": SERVE_QK_LATENT_PAIRS,
+    "latent_expanded_calls": SERVE_LATENT_EXPANDED_CALLS,
+}}
 SERVE_KV_SELECT_SHARE = gauge(
     "hvd_serve_kv_select_share",
     "hvd_serve_kv_selected over hvd_serve_kv_scored, all programs so far: "

@@ -123,14 +123,39 @@ advance the state, so for such a model a NEGATIVE token id marks a padding
 position (the loop pads so; positions are dead from the first negative id
 on). A layer with no mixer touches no cache.
 
+What a layer KIND does in a serving program is this module's, said once
+(``transformer.py`` has the mathematics, ``kv_cache.py`` the format,
+``scheduler.py`` the pages; ``loop.py`` knows program kinds and no layer
+kind):
+
+- whether its kernels run: :func:`_kernels_may_run` ("a TPU backend, no
+  mesh, ``attn_impl`` left open") is the one question :func:`decode_attn`,
+  :func:`latent_kernels`, :func:`grouped_kernels` and :func:`state_kernels`
+  ask before their kind's own ``supported(...)``; each is asked once a
+  program build (:func:`_kernels`);
+- how its window is written and attended: a program hands :func:`_layers`
+  the window (``q_pos``, ``ok``, ``tables``) and the kernel flags once, and
+  ``_layers`` calls :func:`_latent_layer`, :func:`_grouped_layer` and
+  :func:`_state_layer` itself, each looked up through this module when the
+  program is traced (tests and the benchmark's planted faults replace them
+  there);
+- what work that is: :func:`_latent_work`, :func:`_grouped_work` and
+  :func:`_state_work` beside them give the counters of one layer for one
+  call from the call's positions, and :func:`work` sums them over the
+  model's layers for ``ServeLoop``, which tallies them by program kind as
+  ``hvd.serve_stats()["attn" | "state"]`` (the benchmark's roofline shares
+  read those).
+
 The batch-slot ↔ request mapping, page ownership, and admission policy
 live host-side in :mod:`.scheduler`; this module never allocates.
 """
 
+import collections
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import transformer as tfm
@@ -147,22 +172,37 @@ def _constrain(x, mesh, spec):
         x, NamedSharding(mesh, spec))
 
 
-def decode_attn(cfg, geo, mesh):
-    """Which attention the decode program runs, chosen from what can be
-    seen here: ``"paged"`` (the Pallas kernel that reads the cache in
-    place, :mod:`horovod_tpu.ops.pallas_paged_attention`) on a TPU backend
-    with no mesh, when ``attn_impl`` leaves the choice open and the cache's
-    shape tiles; else ``"gather"``. ``attn_impl="gather"`` forces the
-    gather path; the other tiers have no q_len=1 paged form and raise."""
+def _kernels_may_run(cfg, mesh):
+    """What the four gates below ask first: a TPU backend, no mesh (a
+    ``shard_map`` over a kernel's cache shards is not written), and
+    ``attn_impl`` left open (``"gather"`` forces the plain tier)."""
+    return (mesh is None and cfg.attn_impl == "auto"
+            and jax.default_backend() == "tpu")
+
+
+def _check_gathers(cfg, geo, mesh):
+    """Every serving program's plain multi-head layers have the gather tier
+    or the paged kernel and nothing else: ``transformer.resolve_attn`` is
+    consulted with the decode step's REAL shape (one query against ``max_kv``
+    cached tokens resolves to "gather" whatever the backend), and an
+    ``attn_impl`` that forces another tier is refused."""
     impl = tfm.resolve_attn(cfg, 1, mesh, kv_len=geo.max_kv, causal=True)
     if impl != "gather":
         raise ValueError(
             f"serving decode needs the gather attention path for its "
             f"q_len=1 paged reads, but attn_impl={cfg.attn_impl!r} "
             f"resolved to {impl!r}; use attn_impl='auto' or 'gather'")
-    if (cfg.attn_impl == "auto" and mesh is None
-            and jax.default_backend() == "tpu"
-            and not cfg.described
+
+
+def decode_attn(cfg, geo, mesh):
+    """Which attention the decode program's plain multi-head layers run:
+    ``"paged"`` (the Pallas kernel that reads the cache in place,
+    :mod:`horovod_tpu.ops.pallas_paged_attention`) where kernels may run
+    (:func:`_kernels_may_run`) and the cache's shape tiles; else
+    ``"gather"``. ``attn_impl="gather"`` forces the gather path; the other
+    tiers have no q_len=1 paged form and raise."""
+    _check_gathers(cfg, geo, mesh)
+    if (_kernels_may_run(cfg, mesh) and not cfg.described
             and paged_attention.supported(
                 geo.page_size, cfg.n_heads * cfg.head_dim,
                 cfg.compute_dtype)):
@@ -171,21 +211,18 @@ def decode_attn(cfg, geo, mesh):
 
 
 def latent_kernels(cfg, geo, mesh):
-    """Whether the latent layers run their Pallas kernels: a TPU backend,
-    no mesh (a ``shard_map`` over them is not written), ``attn_impl`` left
-    open, and shapes the kernels tile."""
-    return (bool(cfg.latent) and mesh is None
-            and cfg.attn_impl == "auto" and jax.default_backend() == "tpu"
+    """Whether the latent layers run their Pallas kernels: where kernels may
+    run, and shapes the kernels tile."""
+    return (bool(cfg.latent) and _kernels_may_run(cfg, mesh)
             and all(pallas_latent.supported(a, geo) for _, a in cfg.latent))
 
 
 def grouped_kernels(cfg, geo, mesh):
     """Whether the multi-head layers of a described kind read the cache
     through :func:`paged_attention.paged_grouped_attention`, in the chunk
-    and the decode program alike: a TPU backend, no mesh, ``attn_impl`` left
-    open, and shapes the kernel tiles. Else they gather their pages."""
-    return (bool(cfg.multihead) and mesh is None
-            and cfg.attn_impl == "auto" and jax.default_backend() == "tpu"
+    and the decode program alike: where kernels may run, and shapes the
+    kernel tiles. Else they gather their pages."""
+    return (bool(cfg.multihead) and _kernels_may_run(cfg, mesh)
             and all(paged_attention.grouped_supported(
                 geo.page_size, a.head_dim, cfg.compute_dtype)
                 for _, a in cfg.multihead))
@@ -194,12 +231,20 @@ def grouped_kernels(cfg, geo, mesh):
 def state_kernels(cfg, geo, mesh):
     """Whether the decode step's state-space layers update their state
     through :func:`pallas_ssm.ssm_decode_update`, one pass over the layer's
-    own array: a TPU backend, no mesh, ``attn_impl`` left open, and shapes
-    the kernel tiles. Else (and in every chunk program) the state goes
-    through ``transformer._ssd_blocks``."""
-    return (bool(cfg.state_space) and mesh is None
-            and cfg.attn_impl == "auto" and jax.default_backend() == "tpu"
+    own array: where kernels may run, and shapes the kernel tiles. Else (and
+    in every chunk program) the state goes through
+    ``transformer._ssd_blocks``."""
+    return (bool(cfg.state_space) and _kernels_may_run(cfg, mesh)
             and all(pallas_ssm.supported(a) for _, a in cfg.state_space))
+
+
+def _kernels(cfg, geo, mesh, one_query=False):
+    """The three gates of the described kinds, each asked once a program
+    build: ``{"latent", "grouped", "state"}``. The state-space kernel is the
+    one-token recurrence, so only the decode step (``one_query``) has it."""
+    return {"latent": latent_kernels(cfg, geo, mesh),
+            "grouped": grouped_kernels(cfg, geo, mesh),
+            "state": one_query and state_kernels(cfg, geo, mesh)}
 
 
 def _check_positions(cfg, n, what):
@@ -273,6 +318,13 @@ def _ring_positions(p_hi, n_cells):
     return p_hi[:, None] - (p_hi[:, None] - jnp.arange(n_cells)) % n_cells
 
 
+def _expands(a, q_len, kernels):
+    """Whether a full-context latent layer's kernel attends in the EXPANDED
+    form in a call of ``q_len`` queries a slot: what :func:`_latent_layer`
+    runs and :func:`_latent_work` counts."""
+    return bool(kernels and pallas_latent.expands(a, q_len))
+
+
 def _latent_layer(a, q, row, index, q_heads, wkv_b, rows_c, keys_c, *,
                   q_pos, ok, tables, geo, dt, kernels):
     """One latent layer of a chunk or decode program: write the window's
@@ -303,7 +355,7 @@ def _latent_layer(a, q, row, index, q_heads, wkv_b, rows_c, keys_c, *,
     k_pos = jnp.broadcast_to(jnp.arange(geo.max_kv)[None], (B, geo.max_kv))
     if not a.index_topk:        # the whole context: the slot's live pages
         p_hi = jnp.max(jnp.where(ok, q_pos, -1), axis=1)            # [B]
-        if kernels and pallas_latent.expands(a, Q):
+        if _expands(a, Q, kernels):
             o = pallas_latent.paged_latent_attention_expanded(
                 q_heads, wkv_b, rows_c, table, q_pos[:, 0], p_hi + 1, a)
             return rows_c, keys_c, o, None
@@ -359,6 +411,28 @@ def _latent_layer(a, q, row, index, q_heads, wkv_b, rows_c, keys_c, *,
     return rows_c, keys_c, values(o), selected
 
 
+def _latent_work(a, live, kernels):
+    """What ONE latent layer of kind ``a`` does in a call whose queries see
+    ``live [slots, queries]`` keys each (their positions + 1; a slot's
+    queries are consecutive), for ``serve_stats()["attn"]``: the (query,
+    key) pairs a selecting layer scores and then attends over, those a
+    window layer attends over; for a layer that attends its whole context
+    the rows it has to read (a slot's live rows once), its pairs, and
+    whether its kernel took the expanded form."""
+    found = {}
+    if a.index_topk:
+        found.update(kv_scored=live.sum(),
+                     kv_selected=np.minimum(live, a.index_topk).sum())
+    if a.window:
+        found.update(kv_window=np.minimum(live, a.window).sum())
+    if not (a.index_topk or a.window):
+        found.update(kv_latent_rows=live.max(axis=1, initial=0).sum(),
+                     qk_latent_pairs=live.sum(),
+                     latent_expanded_calls=_expands(a, live.shape[1],
+                                                    kernels))
+    return {"attn": found}
+
+
 def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
                    kernels):
     """One multi-head layer of a described kind in a chunk or decode
@@ -390,6 +464,30 @@ def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
     rows = (c[table].reshape(B, n_cells, a.n_kv_heads, a.head_dim)
             for c in (k_c, v_c))
     return k_c, v_c, tfm.grouped_attend(q, *rows, a, allowed, dt)
+
+
+def _grouped_work(a, live, itemsize):
+    """What ONE multi-head layer of a described kind does in a call
+    (``live`` as in :func:`_latent_work`): the K/V rows it reads (each live
+    row of a slot once: what the paged kernel has to move; a window layer
+    those of its ring, and the rows it would read were it sized like a full
+    one) and the (query, key) pairs it multiplies, full and window layers
+    apart. A full layer's rows in bytes (K and V at ``itemsize``) are what
+    ``serve_stats()["state"]`` sets beside the state-space layers' bytes."""
+    rows = live.max(axis=1, initial=0)      # a slot's live rows, read once
+    found = dict.fromkeys(("kv_full_rows", "kv_window_rows",
+                           "kv_window_rows_as_full", "qk_full_pairs",
+                           "qk_window_pairs"), 0)
+    if a.window:
+        found.update(
+            kv_window_rows=np.minimum(
+                rows, a.window - 1 + live.shape[1]).sum(),
+            kv_window_rows_as_full=rows.sum(),
+            qk_window_pairs=np.minimum(live, a.window).sum())
+        return {"attn": found}
+    found.update(kv_full_rows=rows.sum(), qk_full_pairs=live.sum())
+    return {"attn": found,
+            "state": {"kv_bytes": rows.sum() * 2 * a.kv_width * itemsize}}
 
 
 def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False):
@@ -431,7 +529,8 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False):
     tail = jnp.where(begins[:, None, None], 0, tail)
     if recur is None:
         state = jnp.where(begins[:, None, None, None], 0, state)
-    out, tail, state = mix(tail, state, ok, recur=recur)
+    out, tail, state = (mix(tail, state, ok) if recur is None
+                        else mix(tail, state, ok, recur=recur))
     tail = tail.astype(tail_c.dtype)
     if not whole:
         return tail_c.at[rows].set(tail), state_c.at[rows].set(state), out
@@ -441,22 +540,93 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False):
     return tail_c, state, out
 
 
+def _state_work(a, live, itemsize):
+    """What ONE state-space layer does in a call (``live`` as in
+    :func:`_latent_work`), for ``serve_stats()["state"]``: the slots' rows it
+    reads and writes back, their bytes both ways (the tail at ``itemsize``,
+    the float32 state), the positions it scans, and the rows it zeroes
+    because a sequence begins (a slot whose first query sees one key)."""
+    slots = live.shape[0]
+    row = (a.tail * a.conv_dim * itemsize
+           + a.n_heads * a.head_dim * a.state_size * 4)
+    return {"state": {"rows": slots, "bytes": 2 * slots * row,
+                      "tokens": live.size,
+                      "resets": (live[:, :1] == 1).sum()}}
+
+
+# The counters a family always has, whatever kinds the model's layers are
+# (``calls`` too, and ``queries`` in ``attn``).
+_ALWAYS_COUNTED = {"attn": ("kv_scored", "kv_selected", "kv_window"),
+                   "state": ("kv_bytes",)}
+
+
+def work(cfg, geo, mesh):
+    """-> ``count(live) -> {"attn": {counter: n}, "state": {counter: n}}``:
+    what the layers of a described kind do in ONE call of a chunk, decode or
+    spec program whose queries see ``live [slots, queries]`` keys each, by
+    host arithmetic on the positions alone (nothing is fetched): the work
+    functions beside the layer functions, summed over the model's layers,
+    with ``queries`` and ``calls`` once a call. A family the model has no
+    layer for is absent (``attn``: latent and described multi-head kinds;
+    ``state``: state-space kinds). ``ServeLoop`` tallies the result by
+    program kind; the benchmark's roofline shares read the tallies."""
+    kinds = collections.Counter(
+        cfg.attn_of(li) for li in range(cfg.n_layers) if cfg.has_mixer(li))
+    kinds.pop(None, None)
+    families = [family for family, classes in (
+        ("attn", (tfm.LatentAttention, tfm.MultiHeadAttention)),
+        ("state", tfm.StateSpaceMixer))
+        if any(isinstance(a, classes) for a in kinds)]
+    latent = latent_kernels(cfg, geo, mesh)
+    itemsize = cfg.compute_dtype.itemsize
+
+    def count(live):
+        if not families:
+            return {}
+        live = np.asarray(live, np.int64)
+        found = {family: dict.fromkeys(_ALWAYS_COUNTED[family], 0)
+                 | {"calls": 1} for family in families}
+        if "attn" in found:
+            found["attn"]["queries"] = live.size
+        for a, layers in kinds.items():
+            if isinstance(a, tfm.StateSpaceMixer):
+                mine = _state_work(a, live, itemsize)
+            elif isinstance(a, tfm.MultiHeadAttention):
+                mine = _grouped_work(a, live, itemsize)
+            else:
+                mine = _latent_work(a, live, latent)
+            for family in mine.keys() & found.keys():
+                for name, n in mine[family].items():
+                    found[family][name] = (found[family].get(name, 0)
+                                           + layers * int(n))
+        return found
+
+    return count
+
+
 def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
-            latent=None, grouped=None, state=None):
+            window=None, geo=None, kernels=None):
     """Every layer of the model over ``x [B, S, D]`` through
     ``transformer.block``, the one block definition, with the serving
-    attention: layer ``li``'s new K/V (after the Q/K norm and the rotation
-    to ``positions [B, S]``, so the cache holds keys as attention reads
-    them) go into the cache by ``write(layer_cache, fused) ->
+    attention. ``positions [B, S]`` and ``valid [B, S]`` are the block's own
+    operands (the rotation's positions, the experts' live rows).
+
+    A plain multi-head layer: layer ``li``'s new K/V (after the Q/K norm and
+    the rotation to ``positions``, so the cache holds keys as attention
+    reads them) go into the cache by ``write(layer_cache, fused) ->
     (layer_cache, k or v to attend over)``, then the window attends by
     ``attend(q, k, v)`` (:func:`_masked` over gathered pages, or the decode
-    program's kernel over the layer's own arrays). A latent layer goes
-    through ``latent(a, q, row, index, q_heads, wkv_b, rows_c, keys_c)``
-    (:func:`_latent_layer` with the program's positions and tables), a
-    multi-head layer of a described kind through ``grouped(a, q, k, v, k_c,
-    v_c)`` (:func:`_grouped_layer`, the same), a state-space layer through
-    ``state(mix, tail_c, state_c)`` (:func:`_state_layer`, the same); a layer
-    with no mixer has no cache and attends nothing. ->
+    program's kernel over the layer's own arrays): the padded prefill, the
+    paged decode and the gathering programs really differ there.
+
+    A layer of a described kind is written and attended HERE, from what the
+    program says once: its ``window`` (``q_pos``, ``ok``, ``tables``: where
+    the window's positions go and which are live) and ``kernels``
+    (:func:`_kernels`). A latent layer goes through :func:`_latent_layer`, a
+    multi-head layer of a described kind through :func:`_grouped_layer`, a
+    state-space layer through :func:`_state_layer` (each looked up through
+    this module when the program is traced); a layer with no mixer has no
+    cache and attends nothing. ->
     (ck, cv, x after the final norm, what the layers report or None:
     ``counts``, ``rows`` and ``top`` of the expert layers, ``selected`` of the
     selecting ones, each stacked over those layers)."""
@@ -468,7 +638,11 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
             write_and_attend = None
         elif isinstance(a, tfm.StateSpaceMixer):
             def write_and_attend(mix, li=li):
-                ck[li], cv[li], out = state(mix, ck[li], cv[li])
+                # ``kernels=`` only where the kernel runs: elsewhere the
+                # call is ``(mix, tail_c, state_c, q_pos, ok, tables)``.
+                flag = {"kernels": True} if kernels["state"] else {}
+                ck[li], cv[li], out = _state_layer(mix, ck[li], cv[li],
+                                                   **window, **flag)
                 return out
         elif a is None:
             def write_and_attend(q, k, v, li=li):
@@ -477,12 +651,15 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
                 return attend(q, kk, vv)
         elif isinstance(a, tfm.MultiHeadAttention):
             def write_and_attend(q, k, v, li=li, a=a):
-                ck[li], cv[li], o = grouped(a, q, k, v, ck[li], cv[li])
+                ck[li], cv[li], o = _grouped_layer(
+                    a, q, k, v, ck[li], cv[li], **window, geo=geo,
+                    dt=cfg.compute_dtype, kernels=kernels["grouped"])
                 return o
         else:
             def write_and_attend(*operands, li=li, a=a):
-                ck[li], cv[li], o, selected = latent(a, *operands,
-                                                     ck[li], cv[li])
+                ck[li], cv[li], o, selected = _latent_layer(
+                    a, *operands, ck[li], cv[li], **window, geo=geo,
+                    dt=cfg.compute_dtype, kernels=kernels["latent"])
                 return o, selected
 
         x, report = tfm.block(layer, x, cfg, write_and_attend,
@@ -535,7 +712,7 @@ def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
     preempted request can replay prompt + generated prefix through the
     same compiled program; it must cover whole pages.
     """
-    decode_attn(cfg, geo, mesh)
+    _check_gathers(cfg, geo, mesh)
     _no_latent(cfg, "make_prefill")
     pad = geo.max_kv if prefill_pad is None else int(prefill_pad)
     if pad % geo.page_size != 0:
@@ -583,21 +760,15 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
     trash page 0 and their logits are garbage the scheduler never reads.
     """
     paged = decode_attn(cfg, geo, mesh) == "paged"
-    kernels = latent_kernels(cfg, geo, mesh)
-    dt = cfg.compute_dtype
+    kernels = _kernels(cfg, geo, mesh, one_query=True)
 
     def decode(params, cache, tokens, positions, block_tables, active):
         x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
                               params, cfg, positions)
         x = x[:, None, :]                                  # [B, 1, D]
-        kinds = dict(q_pos=positions[:, None], ok=active[:, None],
-                     tables=block_tables, geo=geo, dt=dt)
-        latent = functools.partial(_latent_layer, **kinds, kernels=kernels)
-        grouped = functools.partial(_grouped_layer, **kinds,
-                                    kernels=grouped_kernels(cfg, geo, mesh))
-        state = functools.partial(_state_layer, q_pos=positions[:, None],
-                                  ok=active[:, None], tables=block_tables,
-                                  kernels=state_kernels(cfg, geo, mesh))
+        # The window of the described kinds: one query a slot.
+        window = dict(q_pos=positions[:, None], ok=active[:, None],
+                      tables=block_tables)
         block_tables = _context_tables(block_tables, geo)
         blk = positions // geo.page_size
         slot = positions % geo.page_size
@@ -633,7 +804,7 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
 
         ck, cv, x, moe = _layers(params, cache, x, positions[:, None], write,
                                  attend, active[:, None], cfg=cfg, mesh=mesh,
-                                 latent=latent, grouped=grouped, state=state)
+                                 window=window, geo=geo, kernels=kernels)
         logits = tfm.head_logits(x, params, cfg)[:, 0]
         return _result(ck, cv, logits, moe, mesh, cfg)
 
@@ -641,7 +812,7 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
 
 
 def _chunk_forward(params, cache, tokens, positions, block_tables,
-                   active, *, cfg, geo, mesh):
+                   active, *, cfg, geo, mesh, kernels=None):
     """Shared body for every multi-token paged step: embed a [B, Q]
     token window starting at each slot's ``positions[b]``, scatter its
     K/V through the block tables, attend over the gathered pages under
@@ -673,17 +844,10 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
         layer_cache = layer_cache.at[page_ids, slot_w].set(_fused(kv))
         return layer_cache, _gather_pages(layer_cache, block_tables, cfg)
 
-    kinds = dict(q_pos=pos, ok=valid, tables=tables, geo=geo,
-                 dt=cfg.compute_dtype)
-    latent = functools.partial(_latent_layer, **kinds,
-                               kernels=latent_kernels(cfg, geo, mesh))
-    grouped = functools.partial(_grouped_layer, **kinds,
-                                kernels=grouped_kernels(cfg, geo, mesh))
-    state = functools.partial(_state_layer, q_pos=pos, ok=valid,
-                              tables=tables)
     return _layers(params, cache, x, pos, write,
                    _masked(cfg, kv_mask[:, None, :, :]), valid, cfg=cfg,
-                   mesh=mesh, latent=latent, grouped=grouped, state=state)
+                   mesh=mesh, window=dict(q_pos=pos, ok=valid, tables=tables),
+                   geo=geo, kernels=kernels)
 
 
 def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
@@ -723,19 +887,17 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
     marks padding: that position and every one behind it is dead (its K/V go
     to the trash page and it advances no state).
     """
-    decode_attn(cfg, geo, mesh)
+    _check_gathers(cfg, geo, mesh)
+    kernels = _kernels(cfg, geo, mesh)
     q_len = geo.page_size if q_len is None else int(q_len)
     if q_len < 1:
         raise ValueError(f"chunk q_len must be >= 1, got {q_len}")
     _check_positions(cfg, geo.max_kv, "cache width")
-    # Consulted for the same reason decode pins "gather": the chunk's
-    # REAL (q_len, kv_len, causal) footprint decides the kernel tier.
-    tfm.resolve_attn(cfg, q_len, mesh, kv_len=geo.max_kv, causal=True)
 
     def chunk(params, cache, tokens, positions, block_tables, active):
         ck, cv, x, moe = _chunk_forward(params, cache, tokens, positions,
-                                        block_tables, active,
-                                        cfg=cfg, geo=geo, mesh=mesh)
+                                        block_tables, active, cfg=cfg,
+                                        geo=geo, mesh=mesh, kernels=kernels)
         logits = tfm.head_logits(x, params, cfg)
         return _result(ck, cv, logits, moe, mesh, cfg)
 
@@ -761,7 +923,7 @@ def make_batched_prefill(cfg, geo, mesh=None, prefill_pad=None):
         raise ValueError(f"prefill_pad {pad} must be a multiple of "
                          f"page_size {geo.page_size}")
     _check_positions(cfg, pad, "prefill_pad")
-    decode_attn(cfg, geo, mesh)
+    _check_gathers(cfg, geo, mesh)
     _no_latent(cfg, "make_batched_prefill")
 
     def bprefill(params, cache, tokens, lengths, block_tables, active):

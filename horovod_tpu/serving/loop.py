@@ -79,8 +79,6 @@ import numpy as np
 
 from ..observability import metrics as _metrics
 from ..observability import spans as _spans
-from ..models.transformer import (LatentAttention, MultiHeadAttention,
-                                  StateSpaceMixer)
 from . import engine, kv_cache, speculate
 from .prefix_cache import PrefixCache
 from .scheduler import (DEFAULT_KV_PAGES, DEFAULT_MAX_BATCH,
@@ -105,8 +103,6 @@ LONG_PREFILL_CHUNK = 512
 # pairs of the experts held here, program calls, (layer, expert) weights
 # read, and the sorted rows the experts' products ran over.
 _MOE_COUNTS = ("pairs", "calls", "expert_reads", "rows")
-# What ``serve_stats()["state"]`` sums by program kind (state-space layers).
-_STATE_COUNTS = ("rows", "bytes", "tokens", "resets", "kv_bytes", "calls")
 
 
 @dataclasses.dataclass
@@ -306,59 +302,26 @@ class ServeLoop:
         # runs it while the host plans the step after it. Never more than
         # this one.
         self._flight = None
-        # A model with experts: the (token, expert) pairs every program
-        # routed, by program kind and by (layer, expert). A program's
-        # counts wait on the device for the fetch of its tokens (packed
-        # with them into one transfer: ``_Step.out``), those of a chunk
-        # whose tokens nobody fetches for the next program dispatched
-        # after it (``_moe_pending``, then that step's ``earlier``).
-        self.moe = cfg.n_experts > 0
+        # What the programs did, by family, counter and program kind:
+        # ``{family: {counter: {program kind: n}}}``, published whole as
+        # ``serve_stats()[family]``. The families ``attn`` and ``state`` are
+        # the engine's account of a call's layers (``engine.work``: host
+        # arithmetic on the call's positions, as ``kv_pages_read`` is;
+        # nothing fetched; absent where the model has no such layer). The
+        # family ``moe`` (a model with experts) is what every program routed:
+        # a program's counts wait on the device for the fetch of its tokens
+        # (packed with them into one transfer: ``_Step.out``), those of a
+        # chunk whose tokens nobody fetches for the next program dispatched
+        # after it (``_moe_pending``, then that step's ``earlier``);
+        # ``_moe_load`` is the same by (layer, expert).
+        self._work = engine.work(cfg, geo, mesh)
+        self.tally = {family: {name: {} for name in found} for family, found
+                      in self._work(np.zeros((0, 1), np.int64)).items()}
+        if cfg.n_experts > 0:
+            self.tally["moe"] = {name: {} for name in _MOE_COUNTS}
         self._moe_pending = []
         self._moe_load = np.zeros((max(len(cfg.moe_layers), 1),
                                    max(cfg.n_held, 1)), np.int64)
-        self.moe_stats = {name: {} for name in _MOE_COUNTS}
-        # Latent layers: the (query, key) pairs scored, selected and
-        # windowed, by program kind, from the positions alone (as
-        # ``kv_pages_read`` is: host arithmetic, nothing fetched).
-        kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
-        latent = [a for a in kinds if isinstance(a, LatentAttention)]
-        self._select = [a.index_topk for a in latent if a.index_topk]
-        self._windows = [a.window for a in latent if a.window]
-        # Latent layers that attend over their whole context: the rows a
-        # call has to read (a slot's live rows once a layer), the pairs, and
-        # the kernel calls that took the expanded form (the engine's choice,
-        # from the call's queries a slot and the kind's widths).
-        self._latent_full = [a for a in latent
-                             if not (a.index_topk or a.window)]
-        self._latent_kernels = engine.latent_kernels(cfg, geo, mesh)
-        # Multi-head layers of a described kind: the K/V rows a call reads
-        # (each live row once a layer: what the paged kernel has to move) and
-        # the (query, key) pairs it multiplies, full and window layers apart;
-        # and the rows the window layers would read were they sized like
-        # full ones.
-        multihead = [a for a in kinds if isinstance(a, MultiHeadAttention)]
-        self._mh_full = sum(1 for a in multihead if not a.window)
-        self._mh_windows = [a.window for a in multihead if a.window]
-        self.attn_stats = {name: {} for name in (
-            "kv_scored", "kv_selected", "kv_window", "queries", "calls",
-            *(("kv_full_rows", "kv_window_rows", "kv_window_rows_as_full",
-               "qk_full_pairs", "qk_window_pairs") if multihead else ()),
-            *(("kv_latent_rows", "qk_latent_pairs", "latent_expanded_calls")
-              if self._latent_full else ()))}
-        # State-space layers: by program kind, the (slot, layer) rows a call
-        # reads and writes back, their bytes both ways (tail and state),
-        # the (token, layer) positions scanned, the rows a call zeroed
-        # because a sequence began; and the K/V bytes the full multi-head
-        # layers read beside them (``kv_full_rows`` at the layers' width).
-        mixers = [a for a in kinds if isinstance(a, StateSpaceMixer)]
-        self._state_layers = len(mixers)
-        size = cfg.compute_dtype.itemsize
-        self._state_row_bytes = sum(
-            a.tail * a.conv_dim * size
-            + a.n_heads * a.head_dim * a.state_size * 4 for a in mixers)
-        self._kv_row_bytes = sum(2 * a.kv_width * size for a in multihead
-                                 if not a.window)
-        self.state_stats = {name: {} for name in _STATE_COUNTS}
 
     @contextlib.contextmanager
     def _span(self, name, **args):
@@ -404,99 +367,36 @@ class ServeLoop:
         for kind, c in [*zip((k for k, _ in step.earlier), earlier),
                         (step.kind, mine)]:            # c: [layers, E + 1]
             c, rows = c[:, :-1], c[:, -1]
-            for name, n in (("pairs", c.sum()), ("calls", 1),
-                            ("expert_reads", np.count_nonzero(c)),
-                            ("rows", rows.sum())):
-                by_kind = self.moe_stats[name]
-                by_kind[kind] = by_kind.get(kind, 0) + int(n)
+            self._add("moe", kind, {
+                "pairs": c.sum(), "calls": 1,
+                "expert_reads": np.count_nonzero(c), "rows": rows.sum()})
             self._moe_load += c
         return packed[:n_tokens].reshape(step.logits.shape[:-1])
 
-    def _count_attn(self, kind, live):
-        """One program call whose queries see ``live [slots, queries]`` keys
-        each (their positions + 1; a slot's queries are consecutive): what
-        its latent layers scored, selected, windowed and read whole, what
-        its multi-head layers of a described kind read and multiplied, and
-        what its state-space layers carried (:meth:`_count_state`)."""
-        if not (self._counts_attn or self._state_layers):
-            return
-        live = np.asarray(live, np.int64)
-        if self._state_layers:
-            self._count_state(kind, live)
-        if not self._counts_attn:
-            return
-        found = {
-            "kv_scored": int(live.sum()) * len(self._select),
-            "kv_selected": sum(int(np.minimum(live, k).sum())
-                               for k in self._select),
-            "kv_window": sum(int(np.minimum(live, w).sum())
-                             for w in self._windows),
-            "queries": live.size, "calls": 1}
-        counters = [(_metrics.SERVE_KV_SCORED, "kv_scored"),
-                    (_metrics.SERVE_KV_SELECTED, "kv_selected"),
-                    (_metrics.SERVE_KV_WINDOW, "kv_window")]
-        if self._mh_full or self._mh_windows:
-            rows = live.max(axis=1)         # a slot's live rows, read once
-            found.update(
-                kv_full_rows=int(rows.sum()) * self._mh_full,
-                kv_window_rows=sum(
-                    int(np.minimum(rows, w - 1 + live.shape[1]).sum())
-                    for w in self._mh_windows),
-                kv_window_rows_as_full=int(rows.sum())
-                * len(self._mh_windows),
-                qk_full_pairs=int(live.sum()) * self._mh_full,
-                qk_window_pairs=sum(int(np.minimum(live, w).sum())
-                                    for w in self._mh_windows))
-            counters += [
-                (_metrics.SERVE_KV_FULL_ROWS, "kv_full_rows"),
-                (_metrics.SERVE_KV_WINDOW_ROWS, "kv_window_rows"),
-                (_metrics.SERVE_KV_WINDOW_ROWS_AS_FULL,
-                 "kv_window_rows_as_full")]
-        if self._latent_full:
-            layers = len(self._latent_full)
-            found.update(
-                kv_latent_rows=int(live.max(axis=1).sum()) * layers,
-                qk_latent_pairs=int(live.sum()) * layers,
-                latent_expanded_calls=sum(
-                    self._latent_kernels
-                    and engine.pallas_latent.expands(a, live.shape[1])
-                    for a in self._latent_full))
-            counters += [(_metrics.SERVE_KV_LATENT_ROWS, "kv_latent_rows"),
-                         (_metrics.SERVE_QK_LATENT_PAIRS, "qk_latent_pairs"),
-                         (_metrics.SERVE_LATENT_EXPANDED_CALLS,
-                          "latent_expanded_calls")]
+    def _add(self, family, kind, found):
+        """One ``kind`` program call's ``found {counter: n}`` into the
+        tally, and into the family's Prometheus counters where it has any."""
+        exported = (_metrics.SERVE_WORK_COUNTERS.get(family, {})
+                    if _metrics.enabled() else {})
         for name, n in found.items():
-            by_kind = self.attn_stats[name]
-            by_kind[kind] = by_kind.get(kind, 0) + n
-        if _metrics.enabled():
-            for metric, name in counters:
-                metric.labels(program=kind).inc(found[name])
+            by_kind = self.tally[family][name]
+            by_kind[kind] = by_kind.get(kind, 0) + int(n)
+            if name in exported:
+                exported[name].labels(program=kind).inc(int(n))
+        if family == "attn" and _metrics.enabled():
             _metrics.SERVE_KV_SELECT_SHARE.set(self._kv_select_share())
 
-    def _count_state(self, kind, live):
-        """The same call's state-space layers (``live`` as in
-        :meth:`_count_attn`; a slot whose first query sees one key begins
-        its sequence there, and its rows are zeroed)."""
-        slots = live.shape[0]
-        found = {"rows": slots * self._state_layers,
-                 "bytes": 2 * slots * self._state_row_bytes,
-                 "tokens": live.size * self._state_layers,
-                 "resets": int((live[:, :1] == 1).sum()) * self._state_layers,
-                 "kv_bytes": int(live.max(axis=1, initial=0).sum())
-                 * self._kv_row_bytes,
-                 "calls": 1}
-        for name, n in found.items():
-            by_kind = self.state_stats[name]
-            by_kind[kind] = by_kind.get(kind, 0) + n
-
-    @property
-    def _counts_attn(self):
-        return bool(self._select or self._windows or self._latent_full
-                    or self._mh_full or self._mh_windows)
+    def _count(self, kind, live):
+        """One program call whose queries see ``live [slots, queries]`` keys
+        each (their positions + 1; a slot's queries are consecutive): what
+        the engine says its layers did (``engine.work``)."""
+        for family, found in self._work(live).items():
+            self._add(family, kind, found)
 
     def _kv_select_share(self):
-        scored = sum(self.attn_stats["kv_scored"].values())
-        return (sum(self.attn_stats["kv_selected"].values()) / scored
+        attn = self.tally["attn"]
+        scored = sum(attn["kv_scored"].values())
+        return (sum(attn["kv_selected"].values()) / scored
                 if scored else 0.0)
 
     def warmup(self):
@@ -534,7 +434,8 @@ class ServeLoop:
                                    *slots(B, self.spec_tokens + 1)))
         # What the warm-up routed is not traffic.
         self._moe_load[:] = 0
-        self.moe_stats = {name: {} for name in _MOE_COUNTS}
+        if "moe" in self.tally:
+            self.tally["moe"] = {name: {} for name in _MOE_COUNTS}
 
     # -- per-request engine calls ----------------------------------------
 
@@ -603,7 +504,7 @@ class ServeLoop:
             bt = np.asarray(
                 self.batcher.block_table(req, self.geo.max_blocks),
                 np.int32)[None]
-            self._count_attn("chunk", np.arange(filled, end)[None] + 1)
+            self._count("chunk", np.arange(filled, end)[None] + 1)
         with self._span("serve.chunk.dispatch", rid=req.rid, start=filled,
                         end=end, target=target):
             step = self._call("chunk", self.chunk_fn, toks,
@@ -638,7 +539,7 @@ class ServeLoop:
                 active[slot] = True
             if ahead:
                 tokens = after.feed()
-            self._count_attn("decode", positions[active][:, None] + 1)
+            self._count("decode", positions[active][:, None] + 1)
             # Pages of live context a decode step has to read, against the
             # B x max_blocks the gather path reads whatever is live.
             live_pages = int(
@@ -694,8 +595,8 @@ class ServeLoop:
                 positions[slot] = len(ctx) - 1
                 tables[slot] = self.batcher.block_table(req, mb)
                 active[slot] = True
-            self._count_attn("spec", positions[active][:, None]
-                             + np.arange(k + 1) + 1)
+            self._count("spec", positions[active][:, None]
+                        + np.arange(k + 1) + 1)
         with self._span("serve.spec.dispatch", draft_k=k,
                         fill=self.batcher.batch_fill()):
             step = self._call("spec", self.spec_fn, tokens, positions,
@@ -968,26 +869,21 @@ class ServeLoop:
         snap["decode_ahead_share"] = (
             self.loop_stats["decode_ahead_calls"]
             / max(1, self.loop_stats["decode_calls"]))
-        if self._counts_attn:
-            snap["attn"] = {
-                **{name: dict(by_kind)
-                   for name, by_kind in self.attn_stats.items()},
-                "kv_select_share": self._kv_select_share()}
-        if self._state_layers:
-            snap["state"] = {name: dict(by_kind)
-                             for name, by_kind in self.state_stats.items()}
-        if self.moe:
-            ms, load = self.moe_stats, self._moe_load
+        for family, counters in self.tally.items():
+            snap[family] = {name: dict(by_kind)
+                            for name, by_kind in counters.items()}
+        if "attn" in snap:
+            snap["attn"]["kv_select_share"] = self._kv_select_share()
+        if "moe" in snap:
+            ms, load = self.tally["moe"], self._moe_load
             steps = ms["calls"].get("decode", 0) * len(self.cfg.moe_layers)
-            snap["moe"] = {
-                **{name: dict(by_kind) for name, by_kind in ms.items()},
-                "row_fill": {kind: ms["pairs"][kind] / rows
-                             for kind, rows in ms["rows"].items() if rows},
-                "experts_touched_mean": (
+            snap["moe"].update(
+                row_fill={kind: ms["pairs"][kind] / rows
+                          for kind, rows in ms["rows"].items() if rows},
+                experts_touched_mean=(
                     ms["expert_reads"]["decode"] / steps if steps else 0.0),
-                "load_max_over_mean": (float(load.max() / load.mean())
-                                       if load.any() else 0.0),
-            }
+                load_max_over_mean=(float(load.max() / load.mean())
+                                    if load.any() else 0.0))
         _LAST_STATS.clear()
         _LAST_STATS.update(snap)
 

@@ -483,6 +483,70 @@ def test_a_preempted_request_replays_from_a_zeroed_row(tiny):
         assert r.generated == _greedy(params, cfg, r), r.rid
 
 
+def _wrapped_state_layer(entered):
+    sound = engine._state_layer
+
+    def wrapper(mix, tail_c, state_c, *, q_pos, ok, tables):
+        entered.append("engine._state_layer")
+        return sound(mix, tail_c, state_c, q_pos=q_pos, ok=ok, tables=tables)
+
+    return engine, "_state_layer", wrapper
+
+
+def _wrapped_state_space_mix(entered):
+    sound = tfm.state_space_mix
+
+    def wrapper(u, layer, a, cfg, tail=None, state=None, live=None):
+        entered.append("tfm.state_space_mix")
+        return sound(u, layer, a, cfg, tail, state, live)
+
+    return tfm, "state_space_mix", wrapper
+
+
+@pytest.mark.parametrize("wrap", [_wrapped_state_layer,
+                                  _wrapped_state_space_mix],
+                         ids=["engine._state_layer", "tfm.state_space_mix"])
+def test_the_seams_the_benchmark_plants_its_faults_in(tiny, monkeypatch, wrap):
+    """``benchmark/tests/test_serve_hybrid_cpu.py`` proves the hybrid cell's
+    ``correct`` by replacing ``engine._state_layer`` and
+    ``tfm.state_space_mix`` with wrappers of EXACTLY these signatures (no
+    ``kernels=``, no ``recur=``: what the plain tier calls them with). Both
+    programs look the two up through their modules when they are traced, so
+    a wrapper planted before the first call is entered by the chunk and by
+    the decode program, and a sound one changes nothing."""
+    _, cfg, params = tiny
+    entered = []
+    # The plain tier, whatever the ``programs`` fixture has steered on.
+    monkeypatch.setattr(engine, "state_kernels", lambda *a: False)
+    monkeypatch.setattr(*wrap(entered))
+    loop = _loop(cfg, params)
+    geo, slot, n = loop.geo, 1, 5
+    prompt = [int(t) for t in _tokens(n, seed=n)[0]]
+    table = np.zeros(geo.table_width, np.int32)
+    table[:8] = np.arange(1, 9)
+    table[-1] = slot + 1
+    toks = np.full((1, CHUNK), -1, np.int32)
+    toks[0, :n] = prompt
+    loop.cache, lg, *_ = loop.chunk_fn(
+        params, loop.cache, toks, np.zeros(1, np.int32), table[None],
+        np.ones(1, bool))
+    in_chunk = len(entered)
+    assert in_chunk > 0
+    rows = [np.asarray(lg[0, :n])]
+    seq = prompt + [int(np.argmax(rows[0][-1]))]
+    tables = np.zeros((3, geo.table_width), np.int32)
+    tables[slot] = table
+    tokens, positions = np.zeros(3, np.int32), np.zeros(3, np.int32)
+    tokens[slot], positions[slot] = seq[-1], n
+    loop.cache, lg, *_ = loop.decode_fn(params, loop.cache, tokens,
+                                        positions, tables,
+                                        np.arange(3) == slot)
+    assert len(entered) > in_chunk
+    rows.append(np.asarray(lg[slot:slot + 1]))
+    want = tfm.forward(params, jnp.asarray([seq]), cfg)[0]
+    assert _rel(np.concatenate(rows), want) < TOL
+
+
 def test_no_speculation_and_no_prefix_cache_over_state(tiny):
     _, cfg, params = tiny
     with pytest.raises(ValueError, match="roll the slot's state back"):

@@ -443,9 +443,9 @@ def test_counters_are_host_arithmetic(tiny):
     """``kv_latent_rows`` / ``qk_latent_pairs`` of one fill and one step."""
     _, cfg, params = tiny
     lp = _loop(cfg, params)
-    lp._count_attn("chunk", np.arange(8, 16)[None] + 1)
-    lp._count_attn("decode", np.asarray([20, 3])[:, None] + 1)
-    s = lp.attn_stats
+    lp._count("chunk", np.arange(8, 16)[None] + 1)
+    lp._count("decode", np.asarray([20, 3])[:, None] + 1)
+    s = lp.tally["attn"]
     assert s["kv_latent_rows"] == {"chunk": 16 * 5, "decode": (21 + 4) * 5}
     assert s["qk_latent_pairs"] == {"chunk": sum(range(9, 17)) * 5,
                                     "decode": (21 + 4) * 5}
@@ -493,11 +493,11 @@ def test_the_form_follows_the_queries_and_the_widths(monkeypatch):
         monkeypatch.setattr(engine, "latent_kernels", lambda *_: kernels)
         lp = _loop(cfg, _params(cfg), prefill_chunk=WIDE_CHUNK)
         for start in (0, 32):
-            lp._count_attn("chunk", np.arange(start, start + 32)[None] + 1)
-        lp._count_attn("decode", np.asarray([70, 3])[:, None] + 1)
-        assert lp.attn_stats["latent_expanded_calls"] == {
+            lp._count("chunk", np.arange(start, start + 32)[None] + 1)
+        lp._count("decode", np.asarray([70, 3])[:, None] + 1)
+        assert lp.tally["attn"]["latent_expanded_calls"] == {
             "chunk": chunk, "decode": 0}
-        assert lp.attn_stats["calls"] == {"chunk": 2, "decode": 1}
+        assert lp.tally["attn"]["calls"] == {"chunk": 2, "decode": 1}
 
 
 # ---- the chip's share -----------------------------------------------------

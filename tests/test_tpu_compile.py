@@ -32,7 +32,6 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops.pallas_attention import (flash_attention,
                                               flash_attention_lse)
-from horovod_tpu.ops.pallas_norm import batch_norm_train
 from horovod_tpu.parallel import expert_parallel, make_ring_attention
 from horovod_tpu.serving import engine, kv_cache
 
@@ -113,22 +112,6 @@ def test_flash_strict_mask_compiles(topo, seq, block):
 
     x = _on_chip(topo, (1, seq, 16, 64))
     assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 2
-
-
-# First and last ResNet-50 stage activations at batch 128.
-@pytest.mark.parametrize("shape", [(128, 56, 56, 64), (128, 7, 7, 2048)])
-def test_batch_norm_train_compiles(topo, shape):
-    x = _on_chip(topo, shape)
-    g = _on_chip(topo, shape[-1:], jnp.float32)
-
-    def fwd(x, gamma, beta):
-        return batch_norm_train(x, gamma, beta, 1e-5, False)
-
-    def loss(x, gamma, beta):
-        return fwd(x, gamma, beta)[0].astype(jnp.float32).sum()
-
-    assert _mosaic_calls(fwd, x, g, g) == 1
-    assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), x, g, g) == 2
 
 
 def test_ring_attention_flash_compiles_on_four_chips(topo):

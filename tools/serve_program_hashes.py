@@ -1,0 +1,215 @@
+"""sha256 of the lowered text of every program a ``ServeLoop`` builds.
+
+A refactor of ``serving/engine.py`` or ``serving/loop.py`` that is meant to
+change no program's instructions runs this on both trees and compares the
+two outputs: ``jit_fn.lower(...).as_text()`` of ``decode_fn``, ``chunk_fn``,
+``spec_fn`` and, where built, ``prefill_fn`` and ``bprefill_fn``, with the
+arguments ``ServeLoop.warmup`` hands them. Nothing is compiled or run.
+Each program gets two hashes: ``text`` of the lowered text, ``cse`` of the
+same module after MLIR's common-subexpression pass, which is what stays equal
+when a change only stops making one value twice (``positions[:, None]``
+built once where it was built for every kind of layer). In both, the payload
+of a Pallas kernel (Mosaic bytecode, which carries the file paths and line
+numbers of the Python frames that traced it: a line added to ``engine.py``,
+or a checkout at another path, changes it) is replaced by the kernel's
+module printed without locations.
+
+    python tools/serve_program_hashes.py --tier cpu      # JAX_PLATFORMS=cpu
+    python tools/serve_program_hashes.py --tier chip     # on the chip
+    python tools/serve_program_hashes.py --tier described
+
+``cpu``: the plain tier, at the tiny configurations the tier-1 tests serve
+(``tests/test_serving.py``, ``test_olmoe.py``, ``test_dots3.py``,
+``test_laguna.py``, ``test_nemotron_h.py``, ``test_sarvam_mla.py``; the
+helpers of those files build them). ``chip``: the kernels' tier, a
+``ServeLoop`` built as each serve cell's runner builds it (the cell's
+configuration file, geometry, slots and chunk; parameters by shape only).
+``described``: the same with no chip, lowered for a described ``v5e:2x2``
+with the engine told it sees a TPU (a rehearsal of ``chip``; its text is not
+the chip's). One JSON line: ``{"tier": .., "hashes": {"<model>.<program>":
+{"text": sha256, "cse": sha256}}}``.
+"""
+import argparse
+import base64
+import hashlib
+import importlib
+import json
+import os
+import re
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "tests")]
+
+# cell's configuration -> its runner (``benchmark/configs/<name>.json``).
+CELLS = ("gpt2-large", "olmoe-1b-7b", "dots3-note-prev", "laguna-s-2.1",
+         "nemotron-3-super-120b", "sarvam-105b")
+
+
+def _sha(text):
+    """sha256 of a lowered text, every kernel's payload without locations."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def bare(match):
+        ctx = mlir.JaxIrContext()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            kernel = ir.Module.parse(base64.b64decode(match.group(2)))
+            asm = kernel.operation.get_asm(enable_debug_info=False)
+        return match.group(1) + hashlib.sha256(asm.encode()).hexdigest()
+
+    text = re.sub(r'(\\22body\\22: \\22)([A-Za-z0-9+/=]+)', bare, text)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def _hashes(lowered):
+    from jax._src.lib.mlir import passmanager
+
+    text = _sha(lowered.as_text())
+    module = lowered.compiler_ir("stablehlo")     # the pass rewrites it
+    with module.context:
+        passmanager.PassManager.parse(
+            "builtin.module(func.func(cse))").run(module.operation)
+    return {"text": text,
+            "cse": _sha(module.operation.get_asm(enable_debug_info=False))}
+
+
+def _programs(loop, like):
+    """{program: its two hashes} of ``loop``; ``like(shape, dtype)`` makes an
+    argument."""
+    import jax
+    import numpy as np
+
+    B, mb, geo = loop.max_batch, loop.geo.table_width, loop.geo
+    params, cache = jax.tree.map(lambda x: like(x.shape, x.dtype),
+                                 (loop.params, loop.cache))
+
+    def slots(b, *q):
+        return [like(s, d) for s, d in (((b, *q), np.int32), ((b,), np.int32),
+                                        ((b, mb), np.int32), ((b,), np.bool_))]
+
+    calls = {
+        "decode": (loop.decode_fn, slots(B)),
+        "chunk": (loop.chunk_fn, slots(1, loop.prefill_chunk)),
+        "spec": (loop.spec_fn, slots(B, loop.spec_tokens + 1)),
+        "prefill": (loop.prefill_fn, [like((geo.max_kv,), np.int32),
+                                      like((), np.int32),
+                                      like((mb,), np.int32)]),
+        "bprefill": (loop.bprefill_fn, slots(B, geo.max_kv)),
+    }
+    return {name: _hashes(fn.lower(params, cache, *args))
+            for name, (fn, args) in calls.items() if fn is not None}
+
+
+def _tiny_loops():
+    """(name, -> ServeLoop) of the tiny configurations the tests serve."""
+    import jax
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.serving import kv_cache
+    from horovod_tpu.serving.loop import ServeLoop
+
+    def abstract(cfg):
+        return jax.eval_shape(
+            lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+
+    serving = importlib.import_module("test_serving")
+    cfg = serving._cfg()
+    geo = kv_cache.geometry(n_pages=32, page_size=8, max_context=64)
+    yield "tiny", lambda: ServeLoop(abstract(cfg), cfg, geo=geo, max_batch=4)
+    yield "tiny-spec", lambda: ServeLoop(abstract(cfg), cfg, geo=geo,
+                                         max_batch=2, spec_tokens=3)
+    moe = importlib.import_module("test_olmoe")._tiny()
+    yield "olmoe", lambda: ServeLoop(
+        abstract(moe), moe, max_batch=4,
+        geo=kv_cache.geometry(n_pages=65, page_size=8, max_context=128))
+    for name, kw in (("dots3", {}), ("laguna", {}), ("sarvam_mla", {}),
+                     ("sarvam_mla-spec", {"spec_tokens": 3})):
+        test = importlib.import_module("test_" + name.partition("-")[0])
+        kind = test._cfg(test._config())
+        yield name, lambda: test._loop(kind, abstract(kind), **kw)
+    test = importlib.import_module("test_nemotron_h")
+    hybrid = test.runner.model_config(test._config())
+    yield "nemotron_h", lambda: test._loop(hybrid, abstract(hybrid))
+
+
+def _cell_loops():
+    """(name, -> ServeLoop) as each serve cell's runner builds it, parameters by
+    shape only."""
+    import jax
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.serving import kv_cache
+    from horovod_tpu.serving.loop import ServeLoop
+
+    for name in CELLS:
+        with open(os.path.join(ROOT, "benchmark", "configs",
+                               name + ".json")) as f:
+            config = json.load(f)
+        srv = config["assumed"]["serve"]
+        if config["runner"] == "serve":
+            cfg = tfm.TransformerConfig(
+                vocab_size=config["vocab_size"], d_model=config["n_embd"],
+                n_heads=config["n_head"], n_layers=config["n_layer"],
+                d_ff=config["n_inner"], max_seq_len=config["n_positions"],
+                dtype=config["assumed"]["compute_dtype"])
+        else:
+            runner = importlib.import_module(
+                "benchmark.runners." + config["runner"])
+            cfg = runner.model_config(config)
+        params = jax.eval_shape(
+            lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+        geo = kv_cache.geometry(srv["n_pages"], srv["page_size"],
+                                srv["context"])
+        kw = {"prefill_chunk": srv["chunk"]} if "chunk" in srv else {}
+        yield name, lambda: ServeLoop(params, cfg, geo=geo,
+                                      max_batch=srv["max_batch"], **kw)
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tier", choices=("cpu", "chip", "described"),
+                    required=True)
+    ap.add_argument("--only", default="", help="comma-separated names")
+    args = ap.parse_args()
+    import jax
+    from horovod_tpu.serving import kv_cache
+
+    if args.tier == "cpu":
+        loops = _tiny_loops()
+    elif args.tier == "chip":
+        if jax.default_backend() != "tpu":
+            raise SystemExit("--tier chip needs the chip")
+        loops = _cell_loops()
+    else:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        device = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices[0]
+        jax.default_backend = lambda: "tpu"
+        make = kv_cache.make_cache      # no 12 GB of zeros on the host
+        kv_cache.make_cache = lambda *a, **kw: jax.eval_shape(
+            lambda: make(*a, **kw))
+        loops = _cell_loops()
+
+    def like(shape, dtype):
+        if args.tier == "described":
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=SingleDeviceSharding(device))
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    only = set(filter(None, args.only.split(",")))
+    hashes = {}
+    for name, build in loops:
+        if only and name not in only:
+            continue
+        for program, pair in _programs(build(), like).items():
+            hashes[f"{name}.{program}"] = pair
+    print(json.dumps({"tier": args.tier, "hashes": hashes}, sort_keys=True))
+
+
+if __name__ == "__main__":
+    main()
