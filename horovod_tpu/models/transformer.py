@@ -40,7 +40,11 @@ a constant state a sequence), ``layer_parts`` says which layers are a mixer
 ALONE or a feed-forward ALONE (one norm and one residual such a layer),
 ``ffn="relu2"`` a feed-forward of ``relu(x W1)^2 W2``, and ``expert_latent``
 routed experts that work in a space narrower than the residual stream,
-between one shared down and one shared up projection. Nothing names a model.
+between one shared down and one shared up projection. ``delta_rule`` names
+layers whose mixer is gated delta-rule LINEAR attention
+(:class:`DeltaRuleMixer`, :func:`delta_rule_mix`: a ``[key, value]`` matrix a
+head that decays a key channel and is corrected, not added to, by each token).
+Nothing names a model.
 
 Written as an explicit parameter pytree + a mirrored PartitionSpec pytree
 (`param_specs`) instead of framework metadata, so the sharding story is
@@ -56,7 +60,7 @@ Reference parity anchors: `examples/pytorch` BERT fine-tune (model scale),
 import dataclasses
 import functools
 import math
-from typing import Optional
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -153,8 +157,10 @@ class MultiHeadAttention:
     positions, itself included. Rotary rule of the kind: ``rope_theta``,
     the first ``rope_share`` of each head rotated (rotate-half within it),
     ``yarn`` (a :class:`Yarn` or its fields) or plain. ``gate``: a sigmoid
-    gate a query head on the attention's output, from a ``d_model ->
-    n_heads`` projection of the layer's normed input."""
+    gate on the attention's output, from a projection of the layer's normed
+    input: True one a query head (``d_model -> n_heads``), ``"channel"`` one
+    a channel of every head (``d_model -> n_heads * head_dim``;
+    arXiv:2505.06708's elementwise form)."""
     n_heads: int
     n_kv_heads: int
     head_dim: int
@@ -162,11 +168,14 @@ class MultiHeadAttention:
     rope_theta: float = 10000.0
     rope_share: float = 1.0
     yarn: Optional[Yarn] = None
-    gate: bool = False
+    gate: Union[bool, str] = False
 
     def __post_init__(self):
         if isinstance(self.yarn, dict):
             object.__setattr__(self, "yarn", Yarn(**self.yarn))
+        if self.gate not in (False, True, "channel"):
+            raise ValueError(f"gate is False, True or 'channel', "
+                             f"got {self.gate!r}")
         if self.n_heads % self.n_kv_heads:
             raise ValueError(f"{self.n_heads} query heads do not divide "
                              f"over {self.n_kv_heads} key/value heads")
@@ -232,6 +241,63 @@ class StateSpaceMixer:
         """Convolution inputs a sequence carries over."""
         return self.conv_kernel - 1
 
+    @property
+    def state_shape(self):
+        """A sequence's float32 state."""
+        return self.n_heads, self.head_dim, self.state_size
+
+
+@dataclasses.dataclass(frozen=True)
+class DeltaRuleMixer:
+    """One kind of gated delta-rule linear attention that a configuration
+    describes by name (``TransformerConfig.delta_rule``): Kimi Delta
+    Attention (arXiv:2510.26692; the gated delta rule of arXiv:2412.06464
+    with a decay a CHANNEL). ``n_heads`` heads, each with a float32 state
+    ``S [head_dim, head_dim]`` (key by value) that a token first decays a key
+    channel (``Diag(alpha) S``, ``alpha`` in (0, 1]) and then CORRECTS along
+    its unit key: ``S += beta k (v - S^T k)^T``, what the state already holds
+    for the key taken out before the value goes in. ``beta`` is a sigmoid,
+    doubled under ``neg_eigval`` (``I - beta k k^T`` then has an eigenvalue in
+    (-1, 1) along ``k``). Queries, keys and values each pass a depthwise
+    causal convolution over the last ``conv_kernel`` tokens; the decay and the
+    output gate come through low-rank pairs of width ``low_rank`` (0 =
+    ``head_dim``). What a sequence carries from one program run to the next:
+    the last ``conv_kernel - 1`` projected q | k | v, and the state."""
+    n_heads: int
+    head_dim: int
+    conv_kernel: int = 4
+    low_rank: int = 0
+    neg_eigval: bool = True
+
+    @property
+    def d_inner(self):
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self):
+        """Channels the convolutions run over: q, k and v."""
+        return 3 * self.d_inner
+
+    @property
+    def rank(self):
+        return self.low_rank or self.head_dim
+
+    @property
+    def tail(self):
+        """Convolution inputs a sequence carries over."""
+        return self.conv_kernel - 1
+
+    @property
+    def state_shape(self):
+        """A sequence's float32 state, held VALUE-major: ``[heads, value,
+        key]``, the key channels on the minor dimension, where the decay,
+        the key and the query of a token are vectors."""
+        return self.n_heads, self.head_dim, self.head_dim
+
+
+# The mixers that carry a state a sequence and attend nothing.
+RECURRENT = (StateSpaceMixer, DeltaRuleMixer)
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -277,6 +343,9 @@ class TransformerConfig:
     # name -> StateSpaceMixer or its fields: a layer so named has a
     # state-space mixer in the place of attention.
     state_space: tuple = ()
+    # name -> DeltaRuleMixer or its fields: a layer so named has gated
+    # delta-rule linear attention in the place of attention.
+    delta_rule: tuple = ()
     # Per-layer halves: ``layer_parts[i]`` is "both" (attention, then a
     # feed-forward: the default, and what a missing entry means), "mixer" (the
     # mixer alone) or "ffn" (the feed-forward alone). A layer of one half has
@@ -350,6 +419,11 @@ class TransformerConfig:
         object.__setattr__(self, "state_space", tuple(
             (name, a if isinstance(a, StateSpaceMixer)
              else StateSpaceMixer(**a)) for name, a in state_space))
+        delta_rule = self.delta_rule.items() \
+            if isinstance(self.delta_rule, dict) else self.delta_rule
+        object.__setattr__(self, "delta_rule", tuple(
+            (name, a if isinstance(a, DeltaRuleMixer)
+             else DeltaRuleMixer(**a)) for name, a in delta_rule))
         object.__setattr__(self, "layer_attn", tuple(self.layer_attn))
         object.__setattr__(self, "layer_parts", tuple(self.layer_parts))
         for part in self.layer_parts:
@@ -392,17 +466,25 @@ class TransformerConfig:
 
     def attn_of(self, li):
         """Layer ``li``'s :class:`LatentAttention`,
-        :class:`MultiHeadAttention` or :class:`StateSpaceMixer`, or None for
-        the multi-head attention of ``n_heads``."""
+        :class:`MultiHeadAttention`, :class:`StateSpaceMixer` or
+        :class:`DeltaRuleMixer`, or None for the multi-head attention of
+        ``n_heads``."""
         name = self.layer_attn[li] if li < len(self.layer_attn) else None
-        return dict(self.latent + self.multihead + self.state_space).get(name)
+        return dict(self.latent + self.multihead + self.recurrent).get(name)
+
+    @property
+    def recurrent(self):
+        """The described kinds that carry a state a sequence (``(name,
+        kind)`` pairs): a model with any is padded, cached and speculated
+        differently (``serving/``)."""
+        return self.state_space + self.delta_rule
 
     @property
     def described(self):
         """Whether layers are described by kind (``latent``, ``multihead``,
-        ``state_space``, ``layer_parts``): such a model is filled by chunks
-        and its layers' caches differ."""
-        return bool(self.latent or self.multihead or self.state_space
+        ``state_space``, ``delta_rule``, ``layer_parts``): such a model is
+        filled by chunks and its layers' caches differ."""
+        return bool(self.latent or self.multihead or self.recurrent
                     or self.layer_parts)
 
     def _part(self, li):
@@ -546,7 +628,10 @@ def _multihead_params(key, cfg, a: MultiHeadAttention):
          "wkv": _dense_init(k[1], (D, 2, a.n_kv_heads, a.head_dim), D, pdt),
          "wo": _dense_init(k[2], (a.n_heads, a.head_dim, D),
                            a.n_heads * a.head_dim, pdt)}
-    if a.gate:
+    if a.gate == "channel":
+        p["w_attn_gate"] = _dense_init(k[3], (D, a.n_heads, a.head_dim), D,
+                                       pdt)
+    elif a.gate:
         p["w_attn_gate"] = _dense_init(k[3], (D, a.n_heads), D, pdt)
     return p
 
@@ -577,11 +662,47 @@ def _state_space_params(key, cfg, a: StateSpaceMixer):
     }
 
 
+def _delta_rule_params(key, cfg, a: DeltaRuleMixer):
+    """A delta-rule layer's parameters: the fused q | k | v projection, the
+    three convolutions' taps (no bias), one fused narrow projection (the
+    decay's and the gate's low-rank inputs and ``beta`` a head), the two
+    low-rank output halves, a head's log decay rate, a channel's decay bias,
+    the gate's bias, the per-head norm's scale (shared by the heads), the
+    out-projection. The decay is drawn as :func:`_state_space_params` draws
+    Mamba-2's: a channel's step log-uniform over (0.001, 0.1) behind the
+    softplus, a head's rate uniform over (1, 16), and the low-rank half that
+    moves the step at a tenth, so that channels remember tens to a thousand
+    tokens (at N(0, 1 / fan_in) throughout a state forgets within a few)."""
+    D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
+    k = jax.random.split(key, 8)
+    r, H = a.rank, a.n_heads
+    step = jnp.exp(jax.random.uniform(
+        k[5], (a.d_inner,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return {
+        "w_dr_in": _dense_init(k[0], (D, a.conv_dim), D, pdt),
+        "dr_conv_w": _dense_init(k[1], (a.conv_dim, a.conv_kernel),
+                                 a.conv_kernel, pdt),
+        # columns: the decay's low-rank input | the gate's | beta a head
+        "w_dr_low": _dense_init(k[2], (D, 2 * r + H), D, pdt),
+        "w_dr_decay": (0.1 * _dense_init(k[3], (r, a.d_inner), r)
+                       ).astype(pdt),
+        "w_dr_gate": _dense_init(k[4], (r, a.d_inner), r, pdt),
+        # softplus(dr_dt_bias) = step
+        "dr_dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
+        "dr_a_log": jnp.log(jax.random.uniform(
+            k[6], (H,), jnp.float32, 1.0, 16.0)).astype(pdt),
+        "dr_gate_bias": jnp.zeros((a.d_inner,), pdt),
+        "dr_norm": {"scale": jnp.ones((a.head_dim,), pdt)},
+        "w_dr_out": _dense_init(k[7], (a.d_inner, D), a.d_inner, pdt),
+    }
+
+
 def _mixer_params(key, cfg, a):
     """The parameters of a layer's mixer of a described kind."""
     make = {MultiHeadAttention: _multihead_params,
             LatentAttention: _latent_params,
-            StateSpaceMixer: _state_space_params}[type(a)]
+            StateSpaceMixer: _state_space_params,
+            DeltaRuleMixer: _delta_rule_params}[type(a)]
     return make(key, cfg, a)
 
 
@@ -1499,6 +1620,258 @@ def state_space_mix(u, layer, a: StateSpaceMixer, cfg, tail=None, state=None,
             tail, state)
 
 
+# Positions of a sub-block of the chunked delta rule: inside one, a decay
+# between two positions is exponentiated as their DIFFERENCE, exactly; across
+# two, as a product of two factors that are each at most one.
+_DELTA_SUB = 16
+# Positions that :func:`delta_rule_mix` takes as one block of the chunked
+# form (the family's); it changes no value, and ``benchmark/flops_linear``
+# counts the recurrence at it.
+_DELTA_BLOCK = 64
+
+
+def _delta_step(q, k, v, g, beta, state):
+    """:func:`_delta_blocks` for a window of ONE position, the update itself:
+    ``S' = Diag(exp g) S``, ``u = v - S'^T k``, ``S = S' + beta k u^T``, ``o =
+    S^T q``, elementwise over the state (held value-major, ``[B, H, value,
+    key]``) and sums over its key axis. The read-out is taken from the
+    DECAYED state, ``o = S'^T q + (beta k . q) u``, the same value: both
+    sums then read the state in one pass and the update is a second, in
+    place, where ``S^T q`` of the new state would be a third."""
+    f32 = jnp.float32
+    q, k, v, g = (t[:, 0].astype(f32) for t in (q, k, v, g))      # [B, H, d]
+    kept = state.astype(f32) * jnp.exp(g)[:, :, None, :]
+    held = jnp.sum(kept * k[:, :, None, :], -1)                   # S'^T k
+    seen = jnp.sum(kept * q[:, :, None, :], -1)                   # S'^T q
+    bk = beta[:, 0].astype(f32)[..., None] * k
+    u = v - held
+    state = kept + u[..., :, None] * bk[..., None, :]
+    o = seen + jnp.sum(bk * q, -1, keepdims=True) * u
+    return o[:, None], state
+
+
+def _decayed_dots(lefts, y, G):
+    """For each ``x`` of ``lefts``: ``P[.., i, j] = sum_c x[.., i, c] y[.., j,
+    c] exp(G[.., i, c] - G[.., j, c])`` for ``j <= i`` and 0 for ``j > i``,
+    over a block of ``C`` positions (``x, y, G [.., C, d]``; ``G`` the running
+    sum of a channel's log decays, never rising; ``C`` whole sub-blocks of
+    :data:`_DELTA_SUB`).
+
+    ``exp(-G_j)`` alone overflows float32 after a few positions of strong
+    decay, so it is never formed. Inside a sub-block the difference is taken
+    first, exactly. Across sub-blocks ``a > b`` it is split at the first
+    position of ``a``, ``ref_a``: ``exp(G_i - ref_a) exp(ref_a - G_j)``, both
+    exponents at most 0, and the sum over the channels is a matrix product."""
+    hi = jax.lax.Precision.HIGHEST
+    c = _DELTA_SUB
+    *lead, C, d = y.shape
+    ns = C // c
+
+    def sub(t):
+        return t.reshape(*lead, ns, c, d)
+
+    Gs, ys = sub(G), sub(y)
+    ref = Gs[..., :1, :]                                      # [.., a, 1, d]
+    earlier = (jnp.arange(ns)[:, None] > jnp.arange(ns)[None, :])[
+        :, :, None, None]                                     # [a, b, 1, 1]
+    gap = ref[..., :, None, :, :] - Gs[..., None, :, :, :]   # [.., a, b, j, d]
+    y_ref = jnp.where(earlier, ys[..., None, :, :, :]
+                      * jnp.exp(jnp.where(earlier, gap, 0.0)), 0.0)
+    y_ref = y_ref.reshape(*lead, ns, C, d)
+    within = Gs[..., :, None, :] - Gs[..., None, :, :]       # [.., a, i, j, d]
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    weight = jnp.where(lower[..., None],
+                       jnp.exp(jnp.where(lower[..., None], within, 0.0)), 0.0)
+    eye = jnp.eye(ns, dtype=y.dtype)
+    found = []
+    for x in lefts:
+        xs = sub(x)
+        across = jnp.einsum("...aid,...ajd->...aij", xs * jnp.exp(Gs - ref),
+                            y_ref, precision=hi)              # [.., a, i, C]
+        diag = jnp.sum(xs[..., :, None, :] * ys[..., None, :, :] * weight, -1)
+        diag = jnp.einsum("...aij,ab->...aibj", diag, eye)
+        found.append((across + diag.reshape(*lead, ns, c, C)
+                      ).reshape(*lead, C, C))
+    return found
+
+
+def _unit_lower_inverse(A):
+    """``(I + A)^-1`` of strictly lower-triangular ``A [.., n, n]``, by
+    halves from the bottom up: the inverses of all diagonal blocks of ``s``
+    rows at once, two neighbours joined by ``-bottom^-1 A_21 top^-1`` into
+    the inverse of a block of ``2 s``, from ``s = 1`` (where the inverse is
+    1) to ``n`` (padded to a power of two with the identity): ``log2 n``
+    rounds of two small batched products, each a step of exact block
+    substitution. The finite product ``(I - A)(I + A^2)(I + A^4) ..`` is the
+    same matrix and is not used: its factors hold binomially large powers of
+    ``A`` that cancel, and keys that share a direction (SiLU leaves them a
+    positive mean, so ``k_i . k_j`` is a few tenths for EVERY pair) lose
+    every digit of float32 in that cancellation."""
+    hi = jax.lax.Precision.HIGHEST
+    *lead, n, _ = A.shape
+    m = 1 << (n - 1).bit_length()
+    if m != n:
+        A = jnp.pad(A, [(0, 0)] * len(lead) + [(0, m - n)] * 2)
+    D = jnp.ones((*lead, m, 1, 1), A.dtype)
+    s = 1
+    while s < m:
+        pairs = m // (2 * s)
+        blocks = jnp.moveaxis(jnp.diagonal(
+            A.reshape(*lead, pairs, 2 * s, pairs, 2 * s), axis1=-4,
+            axis2=-2), -1, -3)                          # [.., pairs, 2s, 2s]
+        top, bottom = D[..., 0::2, :, :], D[..., 1::2, :, :]
+        corner = -jnp.matmul(
+            jnp.matmul(bottom, blocks[..., s:, :s], precision=hi), top,
+            precision=hi)
+        D = jnp.concatenate([
+            jnp.concatenate([top, jnp.zeros_like(top)], -1),
+            jnp.concatenate([corner, bottom], -1)], -2)
+        s *= 2
+    return D[..., 0, :n, :n]
+
+
+def _delta_blocks(q, k, v, g, beta, state, block):
+    """The gated delta rule of a window in its chunked form (the WY
+    representation of arXiv:2412.06464 section 3 with Kimi Delta Attention's
+    decay a channel), float32 throughout.
+
+    ``q, k, v [B, S, H, d]`` (unit keys, scaled unit queries), ``g [B, S, H,
+    d]`` a key channel's log decay (at most 0), ``beta [B, S, H]`` (``g`` and
+    ``beta`` 0 = a position that leaves the state alone), ``state [B, H, d,
+    d]`` entering, value-major -> (``o [B, S, H, d]``, the state leaving).
+    Per head: ``S' = Diag(exp g_t) S_{t-1}``, ``S_t = S' + beta_t k_t (v_t -
+    S'^T k_t)^T``, ``o_t = S_t^T q_t``.
+
+    Each token corrects what the state holds for its key, so inside a block
+    the positions do not superpose as a state-space layer's do. With ``G_i``
+    the running sum of ``g`` inside the block, ``A_ij = beta_i (k_i exp(G_i -
+    G_j)) . k_j`` for ``j < i`` and ``T = (I + A)^-1 Diag(beta)``: the
+    corrections ``u_i`` that the tokens really write are ``U - W S_0`` with
+    ``U = T V`` and ``W = T (K exp G)``, one triangular inverse a block and
+    head (:func:`_unit_lower_inverse`), and then ``o_i = (q_i exp G_i)^T S_0 +
+    sum_{j<=i} ((q_i exp(G_i - G_j)) . k_j) u_j`` and ``S_C = Diag(exp G_C)
+    S_0 + sum_j (k_j exp(G_C - G_j)) u_j^T`` are matrix products.
+    Everything that does not read the entering state is made for all
+    blocks at once; the blocks are chained by a scan over their states. A
+    window of one is the update itself (:func:`_delta_step`)."""
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+    B, S, H, d = q.shape
+    if S == 1:
+        return _delta_step(q, k, v, g, beta, state)
+    C = _round_up(min(block, S), _DELTA_SUB)
+    nb = -(-S // C)
+
+    def blocks(t):    # [B, S, H, ..] -> [B, nb, H, C, ..], dead ones behind
+        t = jnp.pad(t.astype(f32), [(0, 0), (0, nb * C - S)]
+                    + [(0, 0)] * (t.ndim - 2))
+        return jnp.moveaxis(t.reshape(B, nb, C, *t.shape[2:]), 2, 3)
+
+    q, k, v, g, beta = (blocks(t) for t in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=-2)                                # [B,nb,H,C,d]
+    kk, qk = _decayed_dots((k, q), k, G)                      # [B,nb,H,C,C]
+    at = jnp.arange(C)
+    A = jnp.where(at[:, None] > at[None, :], kk, 0.0) * beta[..., None]
+    T = _unit_lower_inverse(A) * beta[..., None, :]
+    decayed = jnp.exp(G)
+    W = jnp.matmul(T, k * decayed, precision=hi)              # [B,nb,H,C,d]
+    U = jnp.matmul(T, v, precision=hi)
+    G_end = G[..., -1:, :]
+    reads = jnp.concatenate([W, q * decayed], -2)             # [B,nb,H,2C,d]
+
+    def chain(s, xs):          # s [B, H, value, key]
+        reads_c, U_c, qk_c, k_end, kept = xs
+        held = jnp.einsum("bhck,bhvk->bhcv", reads_c, s, precision=hi)
+        u = U_c - held[:, :, :C]
+        o = held[:, :, C:] + jnp.matmul(qk_c, u, precision=hi)
+        s = s * kept[:, :, None, :] + jnp.einsum(
+            "bhcv,bhck->bhvk", u, k_end, precision=hi)
+        return s, o
+
+    state, o = jax.lax.scan(
+        chain, state.astype(f32),
+        tuple(jnp.moveaxis(t, 1, 0) for t in (
+            reads, U, qk, k * jnp.exp(G_end - G), jnp.exp(G_end[..., 0, :]))))
+    o = jnp.moveaxis(o, (0, 3), (1, 2))                       # [B,nb,C,H,d]
+    return o.reshape(B, nb * C, H, d)[:, :S], state
+
+
+def delta_rule_mix(u, layer, a: DeltaRuleMixer, cfg, tail=None, state=None,
+                   live=None, recur=None):
+    """THE delta-rule mixer, written once, with :func:`state_space_mix`'s
+    contract: the normed input ``u [B, S, D]`` of a window of ``S``
+    consecutive tokens a sequence -> (``out [B, S, D]``, the convolution tail
+    leaving ``[B, conv_kernel - 1, 3 d_inner]`` in the compute dtype, the
+    state leaving ``[B, H, d, d]`` float32, value-major).
+
+    ``tail`` and ``state`` are what the sequence carried in (None = a
+    sequence that starts here: zeros). ``live [B, S]`` marks the window's
+    real positions, which come FIRST: a dead position advances neither the
+    tail nor the state, and its output is garbage nobody reads. `forward`
+    calls this with no state over the whole sequence, the serving chunk
+    program with the slot's, the decode step with a window of one.
+    ``recur(q, k, v, g, beta) -> (o, the state leaving)`` stands in for the
+    recurrence where the caller owns the state where it lies and ``state`` is
+    None; everything around it is this function's either way.
+
+    ``[q | k | v] = u W_in`` through three depthwise causal convolutions (no
+    bias) and SiLU; each head's ``q`` and ``k`` to unit length (``x
+    rsqrt(sum x^2 + 1e-6)``), ``q`` times ``d^-1/2``; the decay a key channel
+    ``g = -exp(a_log) softplus((u W_fa) W_fb + dt_bias)``; ``beta =
+    sigmoid(u W_b)``, doubled under ``neg_eigval``; the recurrence
+    (:func:`_delta_blocks`); an RMS norm over each head's ``d`` outputs (one
+    scale for all heads) times ``sigmoid((u W_ga) W_gb + gate_bias)``;
+    ``W_out``. The convolutions' sums, the normalisation, the decay,
+    ``beta``, the state and the gated norm are float32; the projections and
+    the convolutions' inputs are the compute dtype's."""
+    dt, f32 = cfg.compute_dtype, jnp.float32
+    B, S, _ = u.shape
+    H, d, K, r = a.n_heads, a.head_dim, a.conv_kernel, a.rank
+    if tail is None:
+        tail = jnp.zeros((B, a.tail, a.conv_dim), dt)
+    if recur is None:
+        if state is None:
+            state = jnp.zeros((B, *a.state_shape), f32)
+        recur = functools.partial(_delta_blocks, state=state,
+                                  block=_DELTA_BLOCK)
+    if live is None:
+        live = jnp.ones((B, S), bool)
+    qkv = jnp.einsum("bsd,dw->bsw", u, layer["w_dr_in"].astype(dt))
+    low = jnp.einsum("bsd,dw->bsw", u, layer["w_dr_low"].astype(dt))
+    # The convolutions over the carried inputs and the window's; the tail
+    # that leaves is the last live inputs.
+    seq = jnp.concatenate([tail.astype(dt), qkv], 1)      # [B, K-1+S, 3 HD]
+    at = jnp.sum(live, 1)[:, None] + jnp.arange(a.tail)[None]
+    tail = jnp.take_along_axis(seq, at[..., None], axis=1)
+    taps = layer["dr_conv_w"].astype(f32)
+    qkv = jax.nn.silu(sum(
+        seq[:, j:j + S].astype(f32) * taps[:, j] for j in range(K)))
+    q, k, v = (qkv[..., i * a.d_inner:(i + 1) * a.d_inner].reshape(B, S, H, d)
+               for i in range(3))
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+        / math.sqrt(d)
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    step = jax.nn.softplus(
+        jnp.einsum("bsr,rf->bsf", low[..., :r],
+                   layer["w_dr_decay"].astype(dt),
+                   preferred_element_type=f32)
+        + layer["dr_dt_bias"].astype(f32)).reshape(B, S, H, d)
+    g = -jnp.exp(layer["dr_a_log"].astype(f32))[:, None] * step
+    g = jnp.where(live[..., None, None], g, 0.0)
+    beta = jax.nn.sigmoid(low[..., 2 * r:].astype(f32)) \
+        * (2.0 if a.neg_eigval else 1.0)
+    beta = jnp.where(live[..., None], beta, 0.0)
+    o, state = recur(q, k, v, g, beta)
+    gate = jax.nn.sigmoid(
+        jnp.einsum("bsr,rf->bsf", low[..., r:2 * r],
+                   layer["w_dr_gate"].astype(dt), preferred_element_type=f32)
+        + layer["dr_gate_bias"].astype(f32))
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps) \
+        * layer["dr_norm"]["scale"].astype(f32)
+    y = (o.reshape(B, S, -1) * gate).astype(dt)
+    return (jnp.einsum("bsf,fd->bsd", y, layer["w_dr_out"].astype(dt)),
+            tail, state)
+
+
 # The measured flash-vs-gather crossover expressed as LIVE score
 # elements rather than a bare query length: causal self-attention at the
 # measured S=1024 v5e crossover materializes S*S/2 = 524288 live logits,
@@ -1563,11 +1936,14 @@ def _constrain(v, spec):
 
 
 def _head_gate(h, layer, dt):
-    """The head gate ``[B, S, H, 1]``: a sigmoid (float32) of a ``d_model ->
-    heads`` projection of the layer's normed input ``h``."""
-    gate = jax.nn.sigmoid(jnp.einsum(
-        "bsd,dh->bsh", h, layer["w_attn_gate"].astype(dt)
-    ).astype(jnp.float32))
+    """The gate on an attention's output, ``[B, S, H, 1]`` (one a head) or
+    ``[B, S, H, dh]`` (one a channel) by the shape of its matrix: a sigmoid
+    (float32) of a projection of the layer's normed input ``h``."""
+    w = layer["w_attn_gate"].astype(dt)
+    if w.ndim == 3:
+        return jax.nn.sigmoid(jnp.einsum(
+            "bsd,dhk->bshk", h, w).astype(jnp.float32)).astype(dt)
+    gate = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", h, w).astype(jnp.float32))
     return gate[..., None].astype(dt)
 
 
@@ -1593,10 +1969,12 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
     in its routing (``{"selected": ..}``). A multi-head layer
     of a described kind (:class:`MultiHeadAttention`) hands it ``attend(q
     [B, S, Hq, dh], k, v [B, S, Hkv, dh]) -> [B, S, Hq, dh]``
-    (:func:`_qkv_kind`) and gates the result a head where the kind says. A
-    STATE-SPACE layer (:class:`StateSpaceMixer`) hands it the mixer itself,
-    ``attend(mix) -> out [B, S, D]`` with ``mix(tail, state, live) -> (out,
-    tail, state)`` (:func:`state_space_mix` on this layer's normed input):
+    (:func:`_qkv_kind`) and gates the result a head or a channel where the
+    kind says. A RECURRENT layer (:class:`StateSpaceMixer`,
+    :class:`DeltaRuleMixer`) hands it the mixer itself, ``attend(mix) -> out
+    [B, S, D]`` with ``mix(tail, state, live) -> (out, tail, state)``
+    (:func:`state_space_mix` or :func:`delta_rule_mix` on this layer's normed
+    input):
     the caller supplies what the sequences carried in and keeps what they
     carry out. A layer of one half (``cfg.layer_parts``) runs that half
     alone, under its own norm, and ``attend`` may be None for a layer with
@@ -1606,9 +1984,14 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
     selected = routing = None
     if cfg.has_mixer(li):
         h = _norm(x, layer["ln1"], cfg)
-    if isinstance(a, StateSpaceMixer) and cfg.has_mixer(li):
-        with jax.named_scope(scopes.STATE_SPACE):
-            out = attend(functools.partial(state_space_mix, h, layer, a, cfg))
+    if isinstance(a, RECURRENT) and cfg.has_mixer(li):
+        # Looked up here, when a program is traced: tests and the benchmark's
+        # planted faults replace the two functions in this module.
+        scope, mix = ((scopes.STATE_SPACE, state_space_mix)
+                      if isinstance(a, StateSpaceMixer)
+                      else (scopes.LINEAR_ATTENTION, delta_rule_mix))
+        with jax.named_scope(scope):
+            out = attend(functools.partial(mix, h, layer, a, cfg))
             x = x + _constrain(out, out_spec)
     elif cfg.has_mixer(li):
         with jax.named_scope(scopes.ATTENTION):
@@ -1655,7 +2038,7 @@ def _block_fn(cfg, mesh, impl, seq_spec, full_spec):
 
     def fn(layer, x, li=0):
         a = cfg.attn_of(li)
-        if isinstance(a, StateSpaceMixer):   # every sequence starts here
+        if isinstance(a, RECURRENT):   # every sequence starts here
             mine = lambda mix: mix()[0]  # noqa: E731
         else:
             mine = attend if a is None else (

@@ -650,6 +650,21 @@ SERVE_LATENT_EXPANDED_CALLS = counter(
     "block of rows expanded into the heads' keys and values once for all the "
     "call's queries): a call's layers where its queries a slot make that "
     "form the cheaper one, else 0 (absorbed)", ("program",))
+SERVE_DELTA_ROWS = counter(
+    "hvd_serve_delta_rows",
+    "(slot, layer) state rows the delta-rule linear-attention layers read "
+    "and wrote back: a call's slots times those layers", ("program",))
+SERVE_DELTA_BYTES = counter(
+    "hvd_serve_delta_bytes",
+    "Bytes of those rows both ways: the convolutions' tail in the compute "
+    "dtype and the float32 [heads, head_dim, head_dim] state", ("program",))
+SERVE_DELTA_TOKENS = counter(
+    "hvd_serve_delta_tokens",
+    "(token, layer) positions those layers passed over", ("program",))
+SERVE_DELTA_RESETS = counter(
+    "hvd_serve_delta_resets",
+    "(slot, layer) rows those layers zeroed because a sequence began",
+    ("program",))
 # ``serve_stats()[family][counter]`` -> the counter that exports it, by
 # program kind: ``ServeLoop._add`` drives these from the engine's account of
 # each call (``serving.engine.work``); a counter with no entry is in
@@ -664,6 +679,11 @@ SERVE_WORK_COUNTERS = {"attn": {
     "kv_latent_rows": SERVE_KV_LATENT_ROWS,
     "qk_latent_pairs": SERVE_QK_LATENT_PAIRS,
     "latent_expanded_calls": SERVE_LATENT_EXPANDED_CALLS,
+}, "state": {
+    "delta_rows": SERVE_DELTA_ROWS,
+    "delta_bytes": SERVE_DELTA_BYTES,
+    "delta_tokens": SERVE_DELTA_TOKENS,
+    "delta_resets": SERVE_DELTA_RESETS,
 }}
 SERVE_KV_SELECT_SHARE = gauge(
     "hvd_serve_kv_select_share",

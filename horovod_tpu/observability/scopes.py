@@ -16,8 +16,10 @@ once. ``experts`` is opened inside ``mlp``: :func:`parse` returns the
 innermost scope, and a reader that wants the whole feed-forward layer asks
 for ``mlp|experts``. ``state_space`` holds a state-space layer's whole mixer
 (projections, convolution, recurrence, gated norm) where ``attention`` holds
-an attention layer's; ``expert_latent`` the two projections around experts
-that work in a latent, inside ``mlp`` beside ``experts``.
+an attention layer's, and ``linear_attention`` a delta-rule layer's
+(projections, convolutions, decay, recurrence, gated norm); ``expert_latent``
+the two projections around experts that work in a latent, inside ``mlp``
+beside ``experts``.
 
 No JAX here: the benchmark's jax-free parent imports this module.
 """
@@ -26,9 +28,10 @@ import re
 
 PHASES = GRAD, GRAD_REDUCE, OPTIMIZER = ("grad", "grad_reduce", "optimizer")
 SCOPES = (EMBED, LAYER_NORM, RMS_NORM, ATTENTION, MLP, EXPERTS, LOSS,
-          HEAD, STATE_SPACE, EXPERT_LATENT) = (
+          HEAD, STATE_SPACE, EXPERT_LATENT, LINEAR_ATTENTION) = (
               "embed", "layer_norm", "rms_norm", "attention", "mlp",
-              "experts", "loss", "head", "state_space", "expert_latent")
+              "experts", "loss", "head", "state_space", "expert_latent",
+              "linear_attention")
 
 # What a transform writes around a component of the path it differentiates,
 # transposes or batches: ``transpose(jvp(attention))``. Components that are

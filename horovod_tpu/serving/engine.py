@@ -123,6 +123,16 @@ advance the state, so for such a model a NEGATIVE token id marks a padding
 position (the loop pads so; positions are dead from the first negative id
 on). A layer with no mixer touches no cache.
 
+A DELTA-RULE layer (``TransformerConfig.delta_rule``: gated delta-rule linear
+attention, a ``[value, key]`` float32 matrix a head) carries its rows through
+the same :func:`_state_layer`, zeroed and written back the same way, with
+``transformer.delta_rule_mix`` as its ``mix``: the chunk program runs the
+CHUNKED form over its window (blocks of 64 positions, one triangular inverse a
+block and head, a scan over the blocks' states), the decode step the update
+itself over a window of one, which XLA compiles to two passes over the layer's
+own rows in place (both read-outs in one, the update in the other) and no
+copy. It has no Pallas kernel yet.
+
 What a layer KIND does in a serving program is this module's, said once
 (``transformer.py`` has the mathematics, ``kv_cache.py`` the format,
 ``scheduler.py`` the pages; ``loop.py`` knows program kinds and no layer
@@ -139,10 +149,11 @@ kind):
   :func:`_state_layer` itself, each looked up through this module when the
   program is traced (tests and the benchmark's planted faults replace them
   there);
-- what work that is: :func:`_latent_work`, :func:`_grouped_work` and
-  :func:`_state_work` beside them give the counters of one layer for one
-  call from the call's positions, and :func:`work` sums them over the
-  model's layers for ``ServeLoop``, which tallies them by program kind as
+- what work that is: :func:`_latent_work`, :func:`_grouped_work`,
+  :func:`_state_work` and :func:`_linear_work` beside them give the counters
+  of one layer for one call from the call's positions, and :func:`work` sums
+  them over the model's layers for ``ServeLoop``, which tallies them by
+  program kind as
   ``hvd.serve_stats()["attn" | "state"]`` (the benchmark's roofline shares
   read those).
 
@@ -152,6 +163,7 @@ live host-side in :mod:`.scheduler`; this module never allocates.
 
 import collections
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -241,7 +253,8 @@ def state_kernels(cfg, geo, mesh):
 def _kernels(cfg, geo, mesh, one_query=False):
     """The three gates of the described kinds, each asked once a program
     build: ``{"latent", "grouped", "state"}``. The state-space kernel is the
-    one-token recurrence, so only the decode step (``one_query``) has it."""
+    one-token recurrence, so only the decode step (``one_query``) has it; a
+    delta-rule layer has none, so the answer for it is no."""
     return {"latent": latent_kernels(cfg, geo, mesh),
             "grouped": grouped_kernels(cfg, geo, mesh),
             "state": one_query and state_kernels(cfg, geo, mesh)}
@@ -547,11 +560,20 @@ def _state_work(a, live, itemsize):
     the float32 state), the positions it scans, and the rows it zeroes
     because a sequence begins (a slot whose first query sees one key)."""
     slots = live.shape[0]
-    row = (a.tail * a.conv_dim * itemsize
-           + a.n_heads * a.head_dim * a.state_size * 4)
+    row = a.tail * a.conv_dim * itemsize + math.prod(a.state_shape) * 4
     return {"state": {"rows": slots, "bytes": 2 * slots * row,
                       "tokens": live.size,
                       "resets": (live[:, :1] == 1).sum()}}
+
+
+def _linear_work(a, live, itemsize):
+    """What ONE delta-rule layer does in a call: :func:`_state_work`'s four
+    counts of its own rows (tail, float32 ``[heads, head_dim, head_dim]``
+    state), under names of their own in ``serve_stats()["state"]`` (a model
+    may have both kinds): ``delta_rows``, ``delta_bytes``, ``delta_tokens``,
+    ``delta_resets``."""
+    counted = _state_work(a, live, itemsize)["state"]
+    return {"state": {"delta_" + name: n for name, n in counted.items()}}
 
 
 # The counters a family always has, whatever kinds the model's layers are
@@ -568,14 +590,15 @@ def work(cfg, geo, mesh):
     functions beside the layer functions, summed over the model's layers,
     with ``queries`` and ``calls`` once a call. A family the model has no
     layer for is absent (``attn``: latent and described multi-head kinds;
-    ``state``: state-space kinds). ``ServeLoop`` tallies the result by
-    program kind; the benchmark's roofline shares read the tallies."""
+    ``state``: state-space and delta-rule kinds). ``ServeLoop`` tallies the
+    result by program kind; the benchmark's roofline shares read the
+    tallies."""
     kinds = collections.Counter(
         cfg.attn_of(li) for li in range(cfg.n_layers) if cfg.has_mixer(li))
     kinds.pop(None, None)
     families = [family for family, classes in (
         ("attn", (tfm.LatentAttention, tfm.MultiHeadAttention)),
-        ("state", tfm.StateSpaceMixer))
+        ("state", tfm.RECURRENT))
         if any(isinstance(a, classes) for a in kinds)]
     latent = latent_kernels(cfg, geo, mesh)
     itemsize = cfg.compute_dtype.itemsize
@@ -591,6 +614,8 @@ def work(cfg, geo, mesh):
         for a, layers in kinds.items():
             if isinstance(a, tfm.StateSpaceMixer):
                 mine = _state_work(a, live, itemsize)
+            elif isinstance(a, tfm.DeltaRuleMixer):
+                mine = _linear_work(a, live, itemsize)
             elif isinstance(a, tfm.MultiHeadAttention):
                 mine = _grouped_work(a, live, itemsize)
             else:
@@ -624,9 +649,9 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
     the window's positions go and which are live) and ``kernels``
     (:func:`_kernels`). A latent layer goes through :func:`_latent_layer`, a
     multi-head layer of a described kind through :func:`_grouped_layer`, a
-    state-space layer through :func:`_state_layer` (each looked up through
-    this module when the program is traced); a layer with no mixer has no
-    cache and attends nothing. ->
+    state-space or delta-rule layer through :func:`_state_layer` (each looked
+    up through this module when the program is traced); a layer with no mixer
+    has no cache and attends nothing. ->
     (ck, cv, x after the final norm, what the layers report or None:
     ``counts``, ``rows`` and ``top`` of the expert layers, ``selected`` of the
     selecting ones, each stacked over those layers)."""
@@ -636,11 +661,12 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
         a = cfg.attn_of(li)
         if not cfg.has_mixer(li):
             write_and_attend = None
-        elif isinstance(a, tfm.StateSpaceMixer):
-            def write_and_attend(mix, li=li):
+        elif isinstance(a, tfm.RECURRENT):
+            def write_and_attend(mix, li=li, a=a):
                 # ``kernels=`` only where the kernel runs: elsewhere the
                 # call is ``(mix, tail_c, state_c, q_pos, ok, tables)``.
-                flag = {"kernels": True} if kernels["state"] else {}
+                flag = {"kernels": True} if kernels["state"] and isinstance(
+                    a, tfm.StateSpaceMixer) else {}
                 ck[li], cv[li], out = _state_layer(mix, ck[li], cv[li],
                                                    **window, **flag)
                 return out
@@ -825,14 +851,14 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
     tables, block_tables = block_tables, _context_tables(block_tables, geo)
     pos = positions[:, None] + jnp.arange(q_len)[None, :]    # [B, Q]
     pe = jnp.clip(pos, 0, cfg.max_seq_len - 1)
-    if cfg.state_space:       # a negative id: padding, from there on
+    if cfg.recurrent:         # a negative id: padding, from there on
         padding = jnp.cumsum(tokens < 0, axis=1) > 0
         tokens = jnp.maximum(tokens, 0)
     x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
                           params, cfg, pe)                   # [B, Q, D]
     blk = jnp.minimum(pos // geo.page_size, geo.max_blocks - 1)
     valid = (pos < max_kv) & active[:, None]
-    if cfg.state_space:
+    if cfg.recurrent:
         valid &= ~padding
     page_ids = jnp.take_along_axis(block_tables, blk, axis=1)
     page_ids = jnp.where(valid, page_ids, 0)                 # trash route
@@ -883,7 +909,7 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
 
     Writes for positions past ``max_kv`` or on inactive slots route to
     trash page 0, so padded draft lanes and short final chunks are
-    branch-free. For a model with state-space layers a negative token id
+    branch-free. For a model whose layers carry a state a negative token id
     marks padding: that position and every one behind it is dead (its K/V go
     to the trash page and it advances no state).
     """

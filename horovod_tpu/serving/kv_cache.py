@@ -58,7 +58,11 @@ its ``"k"`` array is the convolution **tail**, ``[state_rows, conv_kernel - 1,
 conv_dim]`` in the compute dtype (the last inputs of the depthwise
 convolution), and its ``"v"`` array the recurrent **state**, ``[state_rows,
 heads, head_dim, state_size]`` in float32: one row a SLOT, the same bytes
-whatever the context. Row ``slot + 1`` is the slot's own for as long as the
+whatever the context. A DELTA-RULE layer (``TransformerConfig.delta_rule``)
+holds its rows the same way and in the same place: the tail is the last
+projected q | k | v, ``[state_rows, conv_kernel - 1, 3 * heads * head_dim]``,
+and the state a ``[value, key]`` matrix a head, ``[state_rows, heads, head_dim,
+head_dim]`` in float32. Row ``slot + 1`` is the slot's own for as long as the
 server runs (nothing is allocated or freed: the row's index is the slot's),
 and rides in the block table's LAST column, behind the ring's page ids, so
 that the one-slot chunk program finds it as the decode step does; row 0 is
@@ -86,7 +90,7 @@ import math
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.transformer import MultiHeadAttention, StateSpaceMixer
+from ..models.transformer import RECURRENT, MultiHeadAttention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,8 +135,8 @@ def with_rings(geo, cfg, q_len, max_batch):
     ``window - 1 + q_len`` positions (``q_len``: the longest query window a
     program will run) in whole pages, ``max_batch`` of them and the trash
     page; and with a state row for every slot (and the trash row) where it
-    has state-space layers. Unchanged for a model with neither."""
-    if cfg.state_space:
+    has layers that carry a state. Unchanged for a model with neither."""
+    if cfg.recurrent:
         geo = dataclasses.replace(geo, state_rows=int(max_batch) + 1)
     windows = [a.window for _, a in cfg.latent + cfg.multihead if a.window]
     if not windows:
@@ -154,12 +158,12 @@ def layer_shapes(cfg, geo, li):
     a = cfg.attn_of(li)
     if not cfg.has_mixer(li):
         return None, None
-    if isinstance(a, StateSpaceMixer):
+    if isinstance(a, RECURRENT):
         if not geo.state_rows:
-            raise ValueError("a state-space layer needs a geometry with "
-                             "state rows (kv_cache.with_rings)")
+            raise ValueError("a layer that carries a state needs a geometry "
+                             "with state rows (kv_cache.with_rings)")
         return ((geo.state_rows, a.tail, a.conv_dim),
-                (geo.state_rows, a.n_heads, a.head_dim, a.state_size))
+                (geo.state_rows, *a.state_shape))
     if a is None:
         shape = (geo.n_pages, geo.page_size, cfg.n_heads * cfg.head_dim)
         return shape, shape
@@ -176,15 +180,15 @@ def layer_shapes(cfg, geo, li):
 
 def _layer_dtypes(cfg, li):
     """Dtypes of layer ``li``'s ``("k", "v")`` arrays: the compute dtype,
-    but float32 for a state-space layer's state."""
-    state = isinstance(cfg.attn_of(li), StateSpaceMixer)
+    but float32 for a recurrent layer's state."""
+    state = isinstance(cfg.attn_of(li), RECURRENT)
     return cfg.compute_dtype, jnp.dtype(jnp.float32) if state \
         else cfg.compute_dtype
 
 
 def make_cache(cfg, geo, mesh=None):
     """Allocate the zeroed cache: {"k": (...), "v": (...)}, each a tuple of
-    n_layers arrays in the model's compute dtype (a state-space layer's
+    n_layers arrays in the model's compute dtype (a recurrent layer's
     state in float32), each of its layer's own shape (:func:`layer_shapes`:
     pages, ring pages or state rows, and the lanes of the layer's kind; None
     for a layer with no mixer). With a mesh, the paged arrays are placed
@@ -192,8 +196,9 @@ def make_cache(cfg, geo, mesh=None):
     sharding = None
     if mesh is not None and cfg.model_axis in mesh.axis_names:
         sharding = NamedSharding(mesh, spec(cfg))
-    if sharding is not None and cfg.state_space:
-        raise ValueError("state-space layers under a mesh are not written")
+    if sharding is not None and cfg.recurrent:
+        raise ValueError("layers that carry a state (state-space, delta "
+                         "rule) under a mesh are not written")
     layers = [(layer_shapes(cfg, geo, li), _layer_dtypes(cfg, li))
               for li in range(cfg.n_layers)]
     return {name: tuple(

@@ -246,14 +246,14 @@ class ServeLoop:
         self.load_reporter = load_reporter
         self.report_interval = int(report_interval)
         # Latent layers fill by chunks whatever the width; rings of window
-        # state and state-space rows cannot be shared between requests, so
-        # no prefix is; a state-space row cannot be rolled back either.
-        self.has_state = bool(cfg.state_space)
+        # state and a slot's state rows cannot be shared between requests, so
+        # no prefix is; a state row cannot be rolled back either.
+        self.has_state = bool(cfg.recurrent)
         if self.has_state and self.spec_tokens > 0:
             raise ValueError(
-                "spec_tokens > 0 with state-space layers: a rejected draft "
-                "would have to roll the slot's state back, which is not "
-                "written")
+                "spec_tokens > 0 with layers that carry a state: a rejected "
+                "draft would have to roll the slot's state back, which is "
+                "not written")
         padded = geo.max_kv <= PADDED_PREFILL_MAX_KV and not cfg.described
         if prefill_chunk is None:
             prefill_chunk = (2 * geo.page_size if padded
@@ -496,7 +496,7 @@ class ServeLoop:
             filled = (state[1] if state is not None
                       and state[0] == req.admit_seq else req.cached_tokens)
             end = min(filled + self.prefill_chunk, target)
-            # Padding: 0, or for a model with state-space layers -1, which
+            # Padding: 0, or for a model whose layers carry a state -1, which
             # the program reads as a position that advances no state.
             toks = np.full((1, self.prefill_chunk), -int(self.has_state),
                            np.int32)

@@ -2,10 +2,10 @@
 said once: the four kernel gates ask one question, and ``engine.work`` counts
 a call's layers where the layer functions are defined.
 
-The expected numbers of the six kinds are what ``ServeLoop._count_attn`` and
-``_count_state`` counted at the commit before the counting moved (PR 47:
-computed there on these configurations and these ``live``, written here as
-literals)."""
+The expected numbers of the first six kinds are what
+``ServeLoop._count_attn`` and ``_count_state`` counted at the commit before
+the counting moved (PR 47: computed there on these configurations and these
+``live``, written here as literals)."""
 import numpy as np
 import pytest
 
@@ -29,6 +29,7 @@ KINDS = {
                          tfm.MultiHeadAttention(**GROUPED, window=5)),
     "state-space": ("state_space", tfm.StateSpaceMixer(
         n_heads=8, head_dim=8, n_groups=2, state_size=16)),
+    "delta-rule": ("delta_rule", tfm.DeltaRuleMixer(n_heads=4, head_dim=8)),
 }
 # One chunk of 8 queries at positions 8..15 of one slot; one decode step over
 # three slots at positions 20, 3 and 0 (the last begins its sequence).
@@ -68,6 +69,16 @@ WANT = {
                                 kv_bytes=0, calls=1)},
         "decode": {"state": dict(rows=9, bytes=101376, tokens=9, resets=3,
                                  kv_bytes=0, calls=1)}},
+    # Hand-counted (this kind was added after the counting moved): a row is a
+    # tail of 3 x 96 float32 and a state of 4 x 8 x 8 float32, 2,176 bytes,
+    # read and written back; three layers.
+    "delta-rule": {
+        "chunk": {"state": dict(delta_rows=3, delta_bytes=3 * 2 * 2176,
+                                delta_tokens=24, delta_resets=0, kv_bytes=0,
+                                calls=1)},
+        "decode": {"state": dict(delta_rows=9, delta_bytes=9 * 2 * 2176,
+                                 delta_tokens=9, delta_resets=3, kv_bytes=0,
+                                 calls=1)}},
 }
 
 

@@ -84,7 +84,7 @@ def test_names_are_spelled_once():
     assert scopes.PHASES == ("grad", "grad_reduce", "optimizer")
     assert scopes.SCOPES == ("embed", "layer_norm", "rms_norm", "attention",
                              "mlp", "experts", "loss", "head", "state_space",
-                             "expert_latent")
+                             "expert_latent", "linear_attention")
     assert not set(scopes.PHASES) & set(scopes.SCOPES)
 
 

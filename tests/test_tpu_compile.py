@@ -735,3 +735,97 @@ def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip, monkeypatch):
         chunk_text.append(engine.make_chunk_step(cfg, geo, q_len=chunk).lower(
             params, cache, *slots(1, chunk)).as_text())
     assert chunk_text[0] == chunk_text[1]
+
+
+# ---- the serving programs at benchmark/configs/solar-open2-250b.json ----
+
+def test_linear_cell_programs_fit_one_chip(topo, as_on_the_chip):
+    """``solar2-serve-longctx-over``'s two programs (the 2,048-token chunk
+    fill and the decode step of 16 slots) at the cell's geometry: three
+    delta-rule layers on slot-owned rows (float32 ``[64, 128, 128]`` a slot)
+    beside one softmax layer of 64 query heads over 8 key/value heads on
+    pages of a 65,536-token context, 40 held experts a layer. Weights + cache
+    + temporaries stay on the chip; the cache is aliased through; the chunk
+    program computes the recurrence in its CHUNKED form (a chain over 32
+    blocks of 64 positions a layer, no loop over 2,048 positions); neither
+    program holds a second copy of a layer's state or anything as wide as
+    the context; the softmax layer reads its pages through the paged kernel,
+    once a program."""
+    import importlib.util
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "solar-open2-250b.json")) as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "serve_linear", os.path.join(root, "benchmark", "runners",
+                                     "serve_linear.py"))
+    runner = importlib.util.module_from_spec(spec)
+    sys.path.insert(0, root)
+    try:
+        spec.loader.exec_module(runner)
+        cfg = runner.model_config(config)
+    finally:
+        sys.path.remove(root)
+    srv = config["assumed"]["serve"]
+    B, chunk = srv["max_batch"], srv["chunk"]
+    geo = kv_cache.with_rings(
+        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
+        cfg, chunk, B)
+    assert (geo.max_kv, geo.state_rows, geo.table_width, B, chunk) == (
+        65536, 17, 4097, 16, 2048)
+    assert engine.grouped_kernels(cfg, geo, None)
+    assert not engine.state_kernels(cfg, geo, None)     # no such layer
+    params, cache = jax.tree.map(
+        lambda x: _on_chip(topo, x.shape, x.dtype),
+        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
+                                kv_cache.make_cache(cfg, geo))))
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert 3.30e9 < n_params < 3.32e9           # the file's reduced_why
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert kv_cache.cache_bytes(cfg, geo) == held - 2 * n_params
+    assert 11.1e9 < held < 11.2e9          # 66 % of the chip's 16.91e9
+    state = 4 * B * 64 * 128 * 128         # one layer's slots in float32
+    n_linear = sum(isinstance(cfg.attn_of(li), tfm.DeltaRuleMixer)
+                   for li in range(cfg.n_layers))
+    assert n_linear == 3
+
+    def slots(b, *q):
+        return [_on_chip(topo, s, d) for s, d in (
+            ((b, *q), jnp.int32), ((b,), jnp.int32),
+            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
+
+    for name, fn, args in (
+            ("chunk", engine.make_chunk_step(cfg, geo, q_len=chunk),
+             slots(1, chunk)),
+            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
+             slots(B))):
+        compiled = fn.lower(params, cache, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= kv_cache.cache_bytes(cfg, geo)
+        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
+        assert held + memory.temp_size_in_bytes + fresh < 0.8 * 16.91e9, name
+        text = compiled.as_text()
+        calls = [line for line in text.splitlines()
+                 if re.match(r"\s*%paged_full_attention[.\d]* = ", line)
+                 and "tpu_custom_call" in line]
+        assert len(calls) == 1, name
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
+            == 3 * len(cfg.moe_layers)
+        # Nothing as wide as the context: no scores [.., max_kv], no
+        # gathered pages.
+        assert not re.search(r"(f32|bf16)\[[\d,]*65536[\d,]*\]", text), name
+        chains = [line for line in text.splitlines()
+                  if " while(" in line and "f32[32,1,64,128,128]" in line]
+        if name == "decode":
+            # A second copy of a layer's slots would be this large.
+            assert memory.temp_size_in_bytes < state, name
+            assert not chains
+        else:
+            assert memory.temp_size_in_bytes < 1.5e9, name
+            # One chain over the 32 blocks' states a linear layer; nothing
+            # walks the 2,048 positions one by one.
+            assert len(chains) == n_linear
+            assert not re.search(r"f32\[2048,1,64,128(,128)?\]", text)
