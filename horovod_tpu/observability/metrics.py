@@ -665,6 +665,12 @@ SERVE_DELTA_RESETS = counter(
     "hvd_serve_delta_resets",
     "(slot, layer) rows those layers zeroed because a sequence began",
     ("program",))
+SERVE_DELTA_KERNEL_CALLS = counter(
+    "hvd_serve_delta_kernel_calls",
+    "(call, layer) pairs of those layers whose recurrence went through the "
+    "kernel of the chunked form, kda_chunk_scan: a call of more than one "
+    "query a slot where the kernel runs, else 0 (a decode step; a CPU "
+    "backend; a mesh)", ("program",))
 # ``serve_stats()[family][counter]`` -> the counter that exports it, by
 # program kind: ``ServeLoop._add`` drives these from the engine's account of
 # each call (``serving.engine.work``); a counter with no entry is in
@@ -684,6 +690,7 @@ SERVE_WORK_COUNTERS = {"attn": {
     "delta_bytes": SERVE_DELTA_BYTES,
     "delta_tokens": SERVE_DELTA_TOKENS,
     "delta_resets": SERVE_DELTA_RESETS,
+    "delta_kernel_calls": SERVE_DELTA_KERNEL_CALLS,
 }}
 SERVE_KV_SELECT_SHARE = gauge(
     "hvd_serve_kv_select_share",

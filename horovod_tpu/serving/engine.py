@@ -128,10 +128,17 @@ attention, a ``[value, key]`` float32 matrix a head) carries its rows through
 the same :func:`_state_layer`, zeroed and written back the same way, with
 ``transformer.delta_rule_mix`` as its ``mix``: the chunk program runs the
 CHUNKED form over its window (blocks of 64 positions, one triangular inverse a
-block and head, a scan over the blocks' states), the decode step the update
+block and head, a chain over the blocks' states), the decode step the update
 itself over a window of one, which XLA compiles to two passes over the layer's
 own rows in place (both read-outs in one, the update in the other) and no
-copy. It has no Pallas kernel yet.
+copy. On a TPU backend with no mesh (:func:`linear_kernels`) the chunked form
+is ONE Pallas kernel a layer, ``kda_chunk_scan``
+(:mod:`horovod_tpu.ops.pallas_kda`, the instruction name a device trace
+shows): the slot's row of state, gathered and zeroed as ever, is entered into
+it, a block's operands and the head's state stay in VMEM through the decayed
+products, the inverse and the chain, and the state it gives back goes to the
+same row; elsewhere ``transformer._delta_blocks`` is the same mathematics as
+XLA's fusions.
 
 What a layer KIND does in a serving program is this module's, said once
 (``transformer.py`` has the mathematics, ``kv_cache.py`` the format,
@@ -140,9 +147,9 @@ kind):
 
 - whether its kernels run: :func:`_kernels_may_run` ("a TPU backend, no
   mesh, ``attn_impl`` left open") is the one question :func:`decode_attn`,
-  :func:`latent_kernels`, :func:`grouped_kernels` and :func:`state_kernels`
-  ask before their kind's own ``supported(...)``; each is asked once a
-  program build (:func:`_kernels`);
+  :func:`latent_kernels`, :func:`grouped_kernels`, :func:`state_kernels` and
+  :func:`linear_kernels` ask before their kind's own ``supported(...)``; each
+  is asked once a program build (:func:`_kernels`);
 - how its window is written and attended: a program hands :func:`_layers`
   the window (``q_pos``, ``ok``, ``tables``) and the kernel flags once, and
   ``_layers`` calls :func:`_latent_layer`, :func:`_grouped_layer` and
@@ -171,6 +178,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import transformer as tfm
+from ..ops import pallas_kda
 from ..ops import pallas_latent
 from ..ops import pallas_paged_attention as paged_attention
 from ..ops import pallas_ssm
@@ -185,7 +193,7 @@ def _constrain(x, mesh, spec):
 
 
 def _kernels_may_run(cfg, mesh):
-    """What the four gates below ask first: a TPU backend, no mesh (a
+    """What the five gates below ask first: a TPU backend, no mesh (a
     ``shard_map`` over a kernel's cache shards is not written), and
     ``attn_impl`` left open (``"gather"`` forces the plain tier)."""
     return (mesh is None and cfg.attn_impl == "auto"
@@ -250,14 +258,27 @@ def state_kernels(cfg, geo, mesh):
             and all(pallas_ssm.supported(a) for _, a in cfg.state_space))
 
 
+def linear_kernels(cfg, geo, mesh):
+    """Whether a window of more than one position takes its delta-rule
+    layers' recurrence through :func:`pallas_kda.kda_chunk_scan`, one kernel
+    for the chunked form: where kernels may run, and shapes the kernel tiles.
+    Else (and in every decode step, whose window is one position: the update
+    itself, ``transformer._delta_step``) it goes through
+    ``transformer._delta_blocks``."""
+    return (bool(cfg.delta_rule) and _kernels_may_run(cfg, mesh)
+            and all(pallas_kda.supported(a) for _, a in cfg.delta_rule))
+
+
 def _kernels(cfg, geo, mesh, one_query=False):
-    """The three gates of the described kinds, each asked once a program
-    build: ``{"latent", "grouped", "state"}``. The state-space kernel is the
-    one-token recurrence, so only the decode step (``one_query``) has it; a
-    delta-rule layer has none, so the answer for it is no."""
+    """The four gates of the described kinds, each asked once a program
+    build: ``{"latent", "grouped", "state", "linear"}``. The state-space
+    kernel is the one-token recurrence, so only the decode step
+    (``one_query``) has it; the delta-rule kernel is the chunked form, so
+    only a program of more than one query has it."""
     return {"latent": latent_kernels(cfg, geo, mesh),
             "grouped": grouped_kernels(cfg, geo, mesh),
-            "state": one_query and state_kernels(cfg, geo, mesh)}
+            "state": one_query and state_kernels(cfg, geo, mesh),
+            "linear": not one_query and linear_kernels(cfg, geo, mesh)}
 
 
 def _check_positions(cfg, n, what):
@@ -511,11 +532,15 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False):
     live positions, and back into the same rows -> (the layer's arrays, ``out
     [B, Q, D]``). A dead slot's row is left as it was.
 
-    With ``kernels`` (the decode step on a TPU, :func:`state_kernels`) the
-    state array is not sliced at all: ``mix`` is handed the kernel as its
-    recurrence (``recur``), which updates rows ``1 ..`` of the array where
-    they lie and reads ``y`` out in the same pass, a slot that begins
-    entering on zeros inside it. The tail goes the way below.
+    With ``kernels`` and ONE query a slot (the decode step on a TPU,
+    :func:`state_kernels`) the state array is not sliced at all: ``mix`` is
+    handed the kernel as its recurrence (``recur``), which updates rows ``1
+    ..`` of the array where they lie and reads ``y`` out in the same pass, a
+    slot that begins entering on zeros inside it. With ``kernels`` and a
+    window of more (a chunk on a TPU, :func:`linear_kernels`) the rows are
+    read and zeroed the way below and ENTERED into the kernel of the chunked
+    form, :func:`pallas_kda.kda_chunk_scan`, as ``mix``'s recurrence; what it
+    gives back is written to the same rows. The tail goes the way below.
 
     A program over every slot (the decode step: batch row ``b`` IS slot
     ``b``) takes rows ``1 ..`` where they lie, a slice and not a gather, and
@@ -528,27 +553,34 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False):
     alive = jnp.any(ok, axis=1)
     begins = alive & (q_pos[:, 0] == 0)
     whole = q_pos.shape[0] == state_c.shape[0] - 1
+    interpret = jax.default_backend() != "tpu"
+    one_query = q_pos.shape[1] == 1
+    in_place = bool(kernels) and whole and one_query
     recur = None
-    if whole and kernels:        # the state stays where it lies
+    if in_place:                 # the state stays where it lies
         tail, state = tail_c[1:], None
         recur = functools.partial(
             pallas_ssm.ssm_decode_update, state=state_c, begins=begins,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret)
     elif whole:
         tail, state = tail_c[1:], state_c[1:]
     else:
         rows = jnp.where(alive, tables[:, -1], 0)
         tail, state = tail_c[rows], state_c[rows]
     tail = jnp.where(begins[:, None, None], 0, tail)
-    if recur is None:
+    if not in_place:
         state = jnp.where(begins[:, None, None, None], 0, state)
+        if kernels and not one_query:       # the rows enter the kernel
+            recur, state = functools.partial(
+                pallas_kda.kda_chunk_scan, state=state,
+                interpret=interpret), None
     out, tail, state = (mix(tail, state, ok) if recur is None
                         else mix(tail, state, ok, recur=recur))
     tail = tail.astype(tail_c.dtype)
     if not whole:
         return tail_c.at[rows].set(tail), state_c.at[rows].set(state), out
     tail_c = jax.lax.dynamic_update_slice(tail_c, tail, (1, 0, 0))
-    if recur is None:
+    if not in_place:
         state = jax.lax.dynamic_update_slice(state_c, state, (1, 0, 0, 0))
     return tail_c, state, out
 
@@ -566,13 +598,16 @@ def _state_work(a, live, itemsize):
                       "resets": (live[:, :1] == 1).sum()}}
 
 
-def _linear_work(a, live, itemsize):
+def _linear_work(a, live, itemsize, kernels):
     """What ONE delta-rule layer does in a call: :func:`_state_work`'s four
     counts of its own rows (tail, float32 ``[heads, head_dim, head_dim]``
     state), under names of their own in ``serve_stats()["state"]`` (a model
     may have both kinds): ``delta_rows``, ``delta_bytes``, ``delta_tokens``,
-    ``delta_resets``."""
+    ``delta_resets``; and ``delta_kernel_calls``, whether its recurrence went
+    through ``kda_chunk_scan`` (``kernels``, :func:`linear_kernels`, in a call
+    of more than one query a slot: what :func:`_kernels` answers)."""
     counted = _state_work(a, live, itemsize)["state"]
+    counted["kernel_calls"] = bool(kernels and live.shape[1] > 1)
     return {"state": {"delta_" + name: n for name, n in counted.items()}}
 
 
@@ -601,6 +636,7 @@ def work(cfg, geo, mesh):
         ("state", tfm.RECURRENT))
         if any(isinstance(a, classes) for a in kinds)]
     latent = latent_kernels(cfg, geo, mesh)
+    linear = linear_kernels(cfg, geo, mesh)
     itemsize = cfg.compute_dtype.itemsize
 
     def count(live):
@@ -615,7 +651,7 @@ def work(cfg, geo, mesh):
             if isinstance(a, tfm.StateSpaceMixer):
                 mine = _state_work(a, live, itemsize)
             elif isinstance(a, tfm.DeltaRuleMixer):
-                mine = _linear_work(a, live, itemsize)
+                mine = _linear_work(a, live, itemsize, linear)
             elif isinstance(a, tfm.MultiHeadAttention):
                 mine = _grouped_work(a, live, itemsize)
             else:
@@ -665,8 +701,9 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
             def write_and_attend(mix, li=li, a=a):
                 # ``kernels=`` only where the kernel runs: elsewhere the
                 # call is ``(mix, tail_c, state_c, q_pos, ok, tables)``.
-                flag = {"kernels": True} if kernels["state"] and isinstance(
-                    a, tfm.StateSpaceMixer) else {}
+                kind = ("state" if isinstance(a, tfm.StateSpaceMixer)
+                        else "linear")
+                flag = {"kernels": True} if kernels[kind] else {}
                 ck[li], cv[li], out = _state_layer(mix, ck[li], cv[li],
                                                    **window, **flag)
                 return out

@@ -1,5 +1,5 @@
 """What a layer kind does in a serving program is ``serving/engine.py``'s,
-said once: the four kernel gates ask one question, and ``engine.work`` counts
+said once: the five kernel gates ask one question, and ``engine.work`` counts
 a call's layers where the layer functions are defined.
 
 The expected numbers of the first six kinds are what
@@ -71,13 +71,14 @@ WANT = {
                                  kv_bytes=0, calls=1)}},
     # Hand-counted (this kind was added after the counting moved): a row is a
     # tail of 3 x 96 float32 and a state of 4 x 8 x 8 float32, 2,176 bytes,
-    # read and written back; three layers.
+    # read and written back; three layers. No kernel runs on a CPU backend.
     "delta-rule": {
         "chunk": {"state": dict(delta_rows=3, delta_bytes=3 * 2 * 2176,
-                                delta_tokens=24, delta_resets=0, kv_bytes=0,
-                                calls=1)},
+                                delta_tokens=24, delta_resets=0,
+                                delta_kernel_calls=0, kv_bytes=0, calls=1)},
         "decode": {"state": dict(delta_rows=9, delta_bytes=9 * 2 * 2176,
-                                 delta_tokens=9, delta_resets=3, kv_bytes=0,
+                                 delta_tokens=9, delta_resets=3,
+                                 delta_kernel_calls=0, kv_bytes=0,
                                  calls=1)}},
 }
 
@@ -111,6 +112,20 @@ def test_work_counts_what_the_loop_counted(kind):
         for family, counters in WANT[kind]["chunk"].items()}
 
 
+@pytest.mark.parametrize("program, calls", [("chunk", 3), ("decode", 0)])
+def test_the_delta_kernel_is_counted_where_it_runs(monkeypatch, program,
+                                                   calls):
+    """With the gate open (:func:`engine.linear_kernels`) a call of more than
+    one query a slot counts its three delta-rule layers; a decode step, whose
+    window is the one-position update, none."""
+    monkeypatch.setattr(engine, "linear_kernels", lambda *a: True)
+    count = engine.work(_config("delta-rule"), kv_cache.geometry(64, 4, 128),
+                        None)
+    want = dict(WANT["delta-rule"][program]["state"],
+                delta_kernel_calls=calls)
+    assert count(LIVE[program]) == {"state": want}
+
+
 def test_work_of_a_plain_model_is_nothing():
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
                                 n_layers=2, d_ff=64, max_seq_len=64)
@@ -127,14 +142,15 @@ class _Mesh:
 GATES = {"decode_attn": (lambda *a: engine.decode_attn(*a) == "paged", None),
          "latent_kernels": (engine.latent_kernels, "latent-full"),
          "grouped_kernels": (engine.grouped_kernels, "multihead-full"),
-         "state_kernels": (engine.state_kernels, "state-space")}
+         "state_kernels": (engine.state_kernels, "state-space"),
+         "linear_kernels": (engine.linear_kernels, "delta-rule")}
 
 
 @pytest.mark.parametrize("gate", list(GATES))
 @pytest.mark.parametrize("closed_by", ["a CPU backend", "a mesh",
                                        "attn_impl gather"])
 def test_the_gates_ask_one_question(monkeypatch, gate, closed_by):
-    """Each of the four gates is open on a TPU backend with no mesh and
+    """Each of the five gates is open on a TPU backend with no mesh and
     ``attn_impl="auto"`` (at shapes its kernel takes) and closed by any one
     of the three, whatever the shapes."""
     ask, kind = GATES[gate]
@@ -146,6 +162,8 @@ def test_the_gates_ask_one_question(monkeypatch, gate, closed_by):
                 n_heads=8, n_kv_heads=2, head_dim=128)),)),
             "state-space": dict(state_space=(("a", tfm.StateSpaceMixer(
                 n_heads=128, head_dim=64, n_groups=8, state_size=128)),)),
+            "delta-rule": dict(delta_rule=(("a", tfm.DeltaRuleMixer(
+                n_heads=8, head_dim=128)),)),
             None: {}}[kind]
     fields = dict(vocab_size=64, d_model=1024, n_heads=8, n_layers=1,
                   d_ff=64, max_seq_len=4096, dtype="bfloat16", pos="rope",

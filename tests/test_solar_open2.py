@@ -500,16 +500,11 @@ def programs(tiny):
     return _loop(cfg, params)
 
 
-@pytest.mark.parametrize("n", [5, 19, 24])
-def test_chunks_then_decode_against_one_forward(tiny, programs, n):
-    """A prompt filled in chunks of 8 (padding -1) and decoded four steps
-    through the engine's programs, in a slot other than 0 and on rows that
-    are dirty from the second case on, against one full ``forward``: every
-    logit row of every chunk and step."""
-    _, cfg, params = tiny
-    loop = programs
-    geo, slot = loop.geo, 2
-    prompt = [int(t) for t in _tokens(n, seed=n)[0]]
+def _fill_then_decode(loop, params, prompt, slot, steps=3):
+    """``prompt`` through ``loop``'s chunk program in chunks of 8 (padding
+    -1), then ``steps`` decode steps, all in ``slot`` -> (every logit row,
+    the prompt and what was generated)."""
+    geo, n = loop.geo, len(prompt)
     table = np.zeros(geo.table_width, np.int32)
     table[:8] = np.arange(1, 9)
     table[-1] = slot + 1
@@ -524,16 +519,29 @@ def test_chunks_then_decode_against_one_forward(tiny, programs, n):
     seq = prompt + [int(np.argmax(rows[-1][-1]))]
     tables = np.zeros((3, geo.table_width), np.int32)
     tables[slot] = table
-    active = np.arange(3) == slot
-    for _ in range(4):
+    for _ in range(steps):
         tokens, positions = np.zeros(3, np.int32), np.zeros(3, np.int32)
         tokens[slot], positions[slot] = seq[-1], len(seq) - 1
         loop.cache, lg, *_ = loop.decode_fn(params, loop.cache, tokens,
-                                            positions, tables, active)
+                                            positions, tables,
+                                            np.arange(3) == slot)
         rows.append(np.asarray(lg[slot:slot + 1]))
         seq.append(int(np.argmax(rows[-1][-1])))
+    return np.concatenate(rows), seq
+
+
+@pytest.mark.parametrize("n", [5, 19, 24])
+def test_chunks_then_decode_against_one_forward(tiny, programs, n):
+    """A prompt filled in chunks of 8 (padding -1) and decoded four steps
+    through the engine's programs, in a slot other than 0 and on rows that
+    are dirty from the second case on, against one full ``forward``: every
+    logit row of every chunk and step."""
+    _, cfg, params = tiny
+    loop, slot = programs, 2
+    prompt = [int(t) for t in _tokens(n, seed=n)[0]]
+    rows, seq = _fill_then_decode(loop, params, prompt, slot, steps=4)
     want = tfm.forward(params, jnp.asarray([seq[:-1]]), cfg)[0]
-    assert _rel(np.concatenate(rows), want) < TOL
+    assert _rel(rows, want) < TOL
     # The other slots' rows were never touched.
     assert not np.asarray(loop.cache["v"][1][1]).any()
     assert np.asarray(loop.cache["v"][1][slot + 1]).any()
@@ -556,7 +564,9 @@ def test_a_reused_slot_gives_the_logits_of_a_fresh_run(tiny):
         assert r.generated == _greedy(params, cfg, r), r.rid
     state = serve_loop.serve_stats()["state"]
     assert set(state) == {"delta_rows", "delta_bytes", "delta_tokens",
-                          "delta_resets", "kv_bytes", "calls"}
+                          "delta_resets", "delta_kernel_calls", "kv_bytes",
+                          "calls"}
+    assert not any(state["delta_kernel_calls"].values())      # a CPU backend
     assert state["delta_resets"]["chunk"] == 5 * 3        # requests x layers
     assert state["delta_resets"].get("decode", 0) == 0
     assert state["delta_rows"]["decode"] == state["delta_tokens"]["decode"] \
@@ -580,6 +590,66 @@ def test_a_preempted_request_replays_from_a_zeroed_row(tiny):
     assert summary["preemptions"] > 0
     for r in done:
         assert r.generated == _greedy(params, cfg, r), r.rid
+
+
+# ---- the chunk program's recurrence through the kernel ---------------------
+
+def test_the_kernel_gives_what_the_plain_programs_give(tiny, monkeypatch):
+    """With the gate forced open (``engine.linear_kernels``; the kernel
+    interpreted) the chunk program takes its three delta-rule layers'
+    recurrence through ``kda_chunk_scan`` and the decode step does not:
+    chunks then decode steps give the plain programs' logits, tails and
+    states; a prompt in a row another left dirty gives a fresh ``forward``'s
+    logits; a loop with reused slots and one short of pages generates what a
+    fresh model generates, and counts three kernel calls a chunk call."""
+    _, cfg, params = tiny
+    plain = _loop(cfg, params)
+    monkeypatch.setattr(engine, "linear_kernels", lambda *a: True)
+    forced = _loop(cfg, params)
+    linear = [li for li in range(cfg.n_layers)
+              if isinstance(cfg.attn_of(li), tfm.DeltaRuleMixer)]
+    assert len(linear) == 3
+
+    def calls(fn, *shape):
+        b = shape[0]
+        text = fn.lower(
+            params, forced.cache, np.zeros(shape, np.int32),
+            np.zeros(b, np.int32),
+            np.zeros((b, forced.geo.table_width), np.int32),
+            np.zeros(b, bool)).as_text(debug_info=True)
+        return text.count("kda_chunk_scan/pallas_call")
+
+    assert calls(forced.chunk_fn, 1, CHUNK) > 0
+    assert calls(forced.decode_fn, 3) == 0
+    assert calls(plain.chunk_fn, 1, CHUNK) == 0
+
+    prompt = [int(t) for t in _tokens(19, seed=19)[0]]
+    got, want = (_fill_then_decode(loop, params, prompt, slot=2)[0]
+                 for loop in (forced, plain))
+    assert _rel(got, want) < TOL
+    for li in linear:
+        for kept in ("k", "v"):                     # tails, states
+            assert _rel(forced.cache[kept][li][3],
+                        plain.cache[kept][li][3]) < TOL
+            assert not np.asarray(forced.cache[kept][li][1]).any()
+    # The same slot again: its rows are dirty, the window begins on zeros.
+    prompt = [int(t) for t in _tokens(13, seed=13)[0]]
+    got, seq = _fill_then_decode(forced, params, prompt, slot=2, steps=1)
+    assert _rel(got, tfm.forward(params, jnp.asarray([seq[:-1]]), cfg)[0]) < TOL
+
+    # Reused slots, and a request preempted for pages and replayed.
+    loop = _loop(cfg, params, n_pages=14)
+    rng = np.random.default_rng(1)
+    reqs = [Request(rid=i, prompt=rng.integers(0, 96, 10).tolist(),
+                    max_new_tokens=12, arrival_t=0.001 * (i + 1))
+            for i in range(4)]
+    summary, done = loop.run(reqs)
+    assert summary["preemptions"] > 0 and len(done) == 4
+    for r in done:
+        assert r.generated == _greedy(params, cfg, r), r.rid
+    state = serve_loop.serve_stats()["state"]
+    assert state["delta_kernel_calls"]["chunk"] == 3 * state["calls"]["chunk"]
+    assert state["delta_kernel_calls"].get("decode", 0) == 0
 
 
 def test_no_speculation_no_prefix_cache_and_negative_padding(tiny):

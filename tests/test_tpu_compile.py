@@ -746,11 +746,12 @@ def test_linear_cell_programs_fit_one_chip(topo, as_on_the_chip):
     beside one softmax layer of 64 query heads over 8 key/value heads on
     pages of a 65,536-token context, 40 held experts a layer. Weights + cache
     + temporaries stay on the chip; the cache is aliased through; the chunk
-    program computes the recurrence in its CHUNKED form (a chain over 32
-    blocks of 64 positions a layer, no loop over 2,048 positions); neither
-    program holds a second copy of a layer's state or anything as wide as
-    the context; the softmax layer reads its pages through the paged kernel,
-    once a program."""
+    program computes the recurrence in its CHUNKED form in ONE kernel a
+    layer, ``kda_chunk_scan`` (PR 49: no chain of XLA's over the 32 blocks'
+    states, no loop over 2,048 positions), and the decode step has none;
+    neither program holds a second copy of a layer's state or anything as
+    wide as the context; the softmax layer reads its pages through the paged
+    kernel, once a program."""
     import importlib.util
     import json
 
@@ -777,6 +778,7 @@ def test_linear_cell_programs_fit_one_chip(topo, as_on_the_chip):
         65536, 17, 4097, 16, 2048)
     assert engine.grouped_kernels(cfg, geo, None)
     assert not engine.state_kernels(cfg, geo, None)     # no such layer
+    assert engine.linear_kernels(cfg, geo, None)
     params, cache = jax.tree.map(
         lambda x: _on_chip(topo, x.shape, x.dtype),
         jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
@@ -817,15 +819,20 @@ def test_linear_cell_programs_fit_one_chip(topo, as_on_the_chip):
         # Nothing as wide as the context: no scores [.., max_kv], no
         # gathered pages.
         assert not re.search(r"(f32|bf16)\[[\d,]*65536[\d,]*\]", text), name
-        chains = [line for line in text.splitlines()
-                  if " while(" in line and "f32[32,1,64,128,128]" in line]
+        # The chunked form is ONE kernel a linear layer (not the fallback's
+        # two), and XLA chains nothing over the 32 blocks' states; the
+        # decode step's window is the one-position update: no kernel.
+        scans = [line for line in text.splitlines()
+                 if re.match(r"\s*%kda_chunk_scan[.\d]* = ", line)
+                 and "tpu_custom_call" in line]
+        assert not [line for line in text.splitlines()
+                    if " while(" in line and "f32[32,1,64,128,128]" in line]
         if name == "decode":
             # A second copy of a layer's slots would be this large.
             assert memory.temp_size_in_bytes < state, name
-            assert not chains
+            assert not scans
         else:
             assert memory.temp_size_in_bytes < 1.5e9, name
-            # One chain over the 32 blocks' states a linear layer; nothing
-            # walks the 2,048 positions one by one.
-            assert len(chains) == n_linear
+            assert len(scans) == n_linear
+            # Nothing walks the 2,048 positions one by one.
             assert not re.search(r"f32\[2048,1,64,128(,128)?\]", text)

@@ -3,8 +3,10 @@
 on, at one layer's published widths (``transformer._delta_blocks`` against a
 ``lax.scan`` of ``transformer._delta_step``: 64 heads of 128, 2,048 positions,
 a non-zero entering state, float32): the largest difference of the outputs
-and of the state leaving, and where the first lies. One JSON line, also
-appended to ``chiprun_out/delta_rule_chip_check.jsonl``.
+and of the state leaving, and where the first lies; on a TPU also the Pallas
+kernel of the chunked form, ``ops/pallas_kda.kda_chunk_scan``, against the
+same recurrence (``kernel_*``). One JSON line, also appended to
+``chiprun_out/delta_rule_chip_check.jsonl``.
 
     python3 tools/delta_rule_chip_check.py [--positions 2048] [--seed 0]
 """
@@ -21,6 +23,7 @@ import jax.numpy as jnp                               # noqa: E402
 import numpy as np                                    # noqa: E402
 
 from horovod_tpu.models import transformer as tfm     # noqa: E402
+from horovod_tpu.ops import pallas_kda                # noqa: E402
 
 
 def main():
@@ -61,8 +64,11 @@ def main():
             jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
         return jnp.moveaxis(o, 0, 1), s
 
+    forms = [("chunked", chunked), ("recurrence", recurrence)]
+    if jax.default_backend() == "tpu":
+        forms.append(("kernel", pallas_kda.kda_chunk_scan))
     found = {}
-    for name, fn in (("chunked", chunked), ("recurrence", recurrence)):
+    for name, fn in forms:
         out = jax.block_until_ready(fn(q, k, v, g, beta, state))
         t0 = time.perf_counter()
         out = jax.block_until_ready(fn(q, k, v, g, beta, state))
@@ -82,6 +88,14 @@ def main():
                                            - np.asarray(s2)).max()),
             "chunked_ms": found["chunked_ms"],
             "recurrence_ms": found["recurrence_ms"]}
+    if "kernel" in found:
+        o3, s3 = found["kernel"]
+        line.update(
+            kernel_out_diff_max=float(np.abs(np.asarray(o3)
+                                             - np.asarray(o2)).max()),
+            kernel_state_diff_max=float(np.abs(np.asarray(s3)
+                                               - np.asarray(s2)).max()),
+            kernel_ms=found["kernel_ms"])
     print(json.dumps(line))
     out_dir = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "chiprun_out")
