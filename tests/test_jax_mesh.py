@@ -117,6 +117,183 @@ def test_dp_train_step_matches_single_device(mesh):
     assert np.allclose(np.asarray(p1["b"]), np.asarray(p2["b"]), rtol=1e-5)
 
 
+@pytest.mark.parametrize("shape, data_axis", [
+    ({"data": 8}, "data"), ({"data": 4, "model": 2}, "data"),
+    ({"data": 4, "fsdp": 2}, ("data", "fsdp"))],
+    ids=["data8", "data4_model2", "data4_fsdp2"])
+def test_dp_train_step_compile_options_and_counter(shape, data_axis):
+    """On the CPU's devices the step is compiled with no option (only a TPU
+    mesh whose every device is a data shard gets the asynchronous-collective
+    options, tests/test_tpu_compile.py) and trains to the reference's
+    numbers; the counter reads no asynchronous all-reduce of n > 0 in its
+    compiled text."""
+    from horovod_tpu.parallel import data_parallel
+
+    mesh = create_mesh(shape, devices=jax.devices("cpu"))
+    axes = (data_axis,) if isinstance(data_axis, str) else data_axis
+    assert data_parallel._overlap_options(mesh, axes) is None
+
+    def loss_fn(params, batch):
+        x, y = batch
+        return jnp.mean((x @ params["w"] + params["b"] - y) ** 2)
+
+    rng = np.random.default_rng(0)
+    params = {"w": rng.normal(size=(4, 1)).astype(np.float32),
+              "b": np.zeros((1,), np.float32)}
+    x = rng.normal(size=(32, 4)).astype(np.float32)
+    y = rng.normal(size=(32, 1)).astype(np.float32)
+    tx = optax.sgd(0.1)
+    loss1, g = jax.value_and_grad(loss_fn)(params, (x, y))
+    want = optax.apply_updates(params, tx.update(g, tx.init(params))[0])
+
+    step = make_train_step(loss_fn, tx, mesh, data_axis=data_axis)
+    assert type(step).__name__ == "PjitFunction"
+    args = (replicate(params, mesh), replicate(tx.init(params), mesh),
+            shard_batch((x, y), mesh, data_axis))
+    text = step.lower(*args).compile().as_text()
+    n, n_async = data_parallel.grad_collective_counts(text)
+    assert n > 0 and n_async == 0
+    got, _, loss2 = step(*args)
+    assert np.allclose(float(loss1), float(loss2), rtol=1e-5)
+    assert np.allclose(np.asarray(want["w"]), np.asarray(got["w"]),
+                       rtol=1e-5)
+
+
+_SYNC_TEXT = """HloModule jit_step, is_scheduled=true
+
+%region_1.2 (a: f32[], b: f32[]) -> f32[] {
+  ROOT %add = f32[] add(%a, %b)
+}
+
+ENTRY %main.3_spmd (p0: f32[8,4]) -> (f32[8,4], f32[]) {
+  %p0 = f32[8,4]{1,0} parameter(0)
+  %all-reduce.1 = (f32[8,4]{1,0}, f32[4]{0}) all-reduce(%p0, %x), to_apply=%region_1.2
+  %all-reduce.2 = f32[] all-reduce(%loss), to_apply=%region_1.2
+}
+"""
+# One collective in three phases (start, the matmul fusion it runs beside,
+# done), one ``all-reduce-start``, and the loss's synchronous mean.
+_ASYNC_TEXT = """HloModule jit_step, is_scheduled=true
+
+%fused_computation.7 (param_0.1: f32[8,4]) -> (f32[8,4], u32[]) {
+  %all-reduce.5 = f32[8,4]{1,0} all-reduce(%param_0.1), to_apply=%region_1.2
+}
+
+%async_collective_fusion.8.clone (param_0.2: f32[8,4]) -> (f32[8,4], u32[]) {
+  %convolution.3 = bf16[8,4]{1,0} convolution(%a, %b)
+  %all-reduce.6 = f32[8,4]{1,0} all-reduce(%param_0.2), to_apply=%region_1.2
+}
+
+%fused_computation.9 (param_0.3: f32[8,4]) -> f32[8,4] {
+  %all-reduce.7 = f32[8,4]{1,0} all-reduce(%param_0.3), to_apply=%region_1.2
+}
+
+ENTRY %main.3_spmd (p0: f32[8,4]) -> (f32[8,4], f32[]) {
+  %async-collective-start.1 = (f32[8,4]{1,0}, u32[]) fusion(%g), kind=kCustom, calls=%fused_computation.7
+  %get-tuple-element.4 = f32[8,4]{1,0} get-tuple-element(%async-collective-start.1), index=0
+  %fusion.8 = (f32[8,4]{1,0}, u32[]) fusion(%get-tuple-element.4), kind=kOutput, calls=%async_collective_fusion.8.clone
+  %async-collective-done.1 = f32[8,4]{1,0} fusion(%fusion.8), kind=kCustom, calls=%fused_computation.9
+  %all-reduce-start.2 = f32[4]{0} all-reduce-start(%h), to_apply=%region_1.2
+  %all-reduce-done.2 = f32[4]{0} all-reduce-done(%all-reduce-start.2)
+  ROOT %all-reduce.3 = f32[] all-reduce(%loss), to_apply=%region_1.2
+}
+"""
+
+
+@pytest.mark.parametrize("text, counts", [(_SYNC_TEXT, (2, 0)),
+                                          (_ASYNC_TEXT, (3, 2))],
+                         ids=["synchronous", "asynchronous"])
+def test_grad_collective_counts_reads_compiled_text(text, counts):
+    """A collective's three phases count once; what is under no
+    ``async-collective-`` instruction is synchronous."""
+    from horovod_tpu.parallel.data_parallel import grad_collective_counts
+
+    assert grad_collective_counts(text) == counts
+
+
+# One step of one chip as the profiler names it: a synchronous all-reduce
+# (the parent's step), and an asynchronous one in its phases: the start, a
+# matmul fusion and an update fusion that carry its steps, the done.
+_TRACE_NAMES = [
+    "%all-reduce.1 = f32[8,4]{1,0} all-reduce(f32[8,4]{1,0} %p), "
+    "to_apply=%region_1.2",
+    "%async-collective-start.1 = (f32[8,4]{1,0}, u32[]) fusion(%g), "
+    "kind=kCustom, calls=%fused_computation.7",
+    "%fusion.8 = (f32[8,4]{1,0}, u32[]) fusion(%a), kind=kOutput, "
+    "calls=%async_collective_fusion.8",
+    "%fusion.9 = (f32[8,4]{1,0}, u32[]) fusion(%b), kind=kLoop, "
+    "output_to_operand_aliasing={{0}: (0, {})}, "
+    "calls=%async_collective_fusion.9",
+    "%async-collective-done.1 = f32[8,4]{1,0} fusion(%fusion.9), "
+    "kind=kCustom, calls=%fused_computation.9",
+    "%fusion.10 = f32[8,4]{1,0} fusion(%c), kind=kLoop, "
+    "calls=%fused_computation.10",
+]
+
+
+@pytest.mark.parametrize("events, want", [
+    # the parent's step: nothing asynchronous to read
+    ([(0, 0, 4_000_000), (5, 4_000_000, 1_000_000)],
+     {"allreduce_ms": 4.0, "allreduce_exposed_ms": 4.0,
+      "allreduce_async_ms": None, "allreduce_async_exposed_ms": 0.0,
+      "optimizer_overlap_dev_ms": None}),
+    # this PR's: start 0.1, matmul 2, update 1.5, done 0.5, a plain update 1
+    ([(1, 0, 100_000), (2, 100_000, 2_000_000), (3, 2_100_000, 1_500_000),
+      (4, 3_600_000, 500_000), (5, 4_100_000, 1_000_000)],
+     {"allreduce_ms": None, "allreduce_exposed_ms": 0.0,
+      "allreduce_async_ms": 4.1, "allreduce_async_exposed_ms": 0.6,
+      "optimizer_overlap_dev_ms": 1.5})],
+    ids=["synchronous", "asynchronous"])
+def test_benchmark_readers_of_the_asynchronous_all_reduces(events, want):
+    """``benchmark/layer_metrics``' files of PR 50 read an asynchronous
+    collective by its instructions' names: in flight from start to done
+    (with the fusions that carry it), exposed in the start and the done, and
+    the update fusions among the carriers. The accepted readers see only the
+    synchronous ones."""
+    import importlib
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        n, s, d = zip(*events)
+        trace = {"names": _TRACE_NAMES, "planes": [{
+            "name": "/device:TPU:0", "lines": [{
+                "name": "XLA Ops", "n": list(n), "s": list(s),
+                "d": list(d)}]}]}
+        ctx = {"trace": trace, "fields": {"trace_steps": 1}}
+        for metric, value in want.items():
+            with open(os.path.join(root, "benchmark", "layer_metrics",
+                                   metric + ".json")) as f:
+                src = json.load(f)
+            reader = importlib.import_module(
+                "benchmark.readers." + src["reader"])
+            got = reader.read(ctx, src.get("params", {}))
+            assert got == pytest.approx(value), metric
+    finally:
+        sys.path.remove(root)
+
+
+def test_dp_train_step_unjitted(mesh):
+    """``jit=False`` hands back the shard_map'ed step itself: no compile
+    option is attached to it, its caller jits it."""
+    def loss_fn(params, batch):
+        return jnp.mean((batch @ params["w"]) ** 2)
+
+    tx = optax.sgd(0.1)
+    step = make_train_step(loss_fn, tx, mesh, jit=False)
+    assert not hasattr(step, "lower")
+    params = {"w": jnp.ones((4, 1), jnp.float32)}
+    x = np.ones((16, 4), np.float32)
+    p, o, loss = jax.jit(step)(replicate(params, mesh),
+                               replicate(tx.init(params), mesh),
+                               shard_batch(x, mesh))
+    assert np.allclose(float(loss), 16.0)
+    assert np.allclose(np.asarray(p["w"]), 1.0 - 0.1 * 8.0)
+
+
 def test_train_step_loss_decreases(mesh):
     def loss_fn(params, batch):
         x, y = batch
