@@ -160,7 +160,27 @@ class MultiHeadAttention:
     gate on the attention's output, from a projection of the layer's normed
     input: True one a query head (``d_model -> n_heads``), ``"channel"`` one
     a channel of every head (``d_model -> n_heads * head_dim``;
-    arXiv:2505.06708's elementwise form)."""
+    arXiv:2505.06708's elementwise form). ``bias``: a bias on the query,
+    key/value and output projections.
+
+    ``differential`` (Differential Attention, arXiv:2410.05258): adjacent
+    heads pair (``2j``, ``2j + 1``, queries and key/value heads alike, query
+    pair ``p`` reading key/value pair ``p // group``); a pair's two softmaxes,
+    each over its own key head and BOTH over the pair's two value heads side
+    by side (``2 * head_dim`` wide), are subtracted, ``a1 - lambda a2``, with
+    ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`` from four
+    learned ``head_dim`` vectors a layer and ``lambda_init = 0.8 - 0.6
+    exp(-0.3 i)`` of the layer's index ``i``; the difference passes an
+    RMSNorm over its ``2 * head_dim`` (one learned scale a layer) and is
+    scaled by ``1 - lambda_init``. It is ATTENDED as ordinary grouped
+    attention at twice the head width (:attr:`attended`): a pair's first
+    query padded with zeros behind, its second with zeros before, against the
+    pair's two key heads side by side, which is what the fused cache row
+    already holds.
+
+    ``kv_from``: the index of an EARLIER layer whose keys and values this
+    layer attends (YOCO's shared cache, arXiv:2405.05254). Such a layer
+    projects queries only and owns no cache."""
     n_heads: int
     n_kv_heads: int
     head_dim: int
@@ -169,6 +189,9 @@ class MultiHeadAttention:
     rope_share: float = 1.0
     yarn: Optional[Yarn] = None
     gate: Union[bool, str] = False
+    bias: bool = False
+    differential: bool = False
+    kv_from: Optional[int] = None
 
     def __post_init__(self):
         if isinstance(self.yarn, dict):
@@ -179,6 +202,13 @@ class MultiHeadAttention:
         if self.n_heads % self.n_kv_heads:
             raise ValueError(f"{self.n_heads} query heads do not divide "
                              f"over {self.n_kv_heads} key/value heads")
+        if self.differential and (self.n_kv_heads % 2 or self.gate):
+            raise ValueError("differential attention pairs adjacent heads "
+                             "(an even number of key/value heads) and is "
+                             "not written with a gate")
+        if self.kv_from is not None and self.window:
+            raise ValueError("a layer that attends another layer's keys and "
+                             "values reads pages, not a ring: no window")
 
     @property
     def group(self):
@@ -193,6 +223,23 @@ class MultiHeadAttention:
     @property
     def rope_dim(self):
         return int(self.head_dim * self.rope_share)
+
+    @property
+    def attended(self):
+        """The kind as its attention and its cache see it: itself, or for
+        differential attention the same heads in pairs, ``n_kv_heads / 2``
+        key/value heads of ``2 * head_dim`` under ``n_heads`` padded
+        queries (the same fused cache row)."""
+        if not self.differential:
+            return self
+        return dataclasses.replace(
+            self, n_kv_heads=self.n_kv_heads // 2,
+            head_dim=2 * self.head_dim, differential=False)
+
+
+def lambda_init(li):
+    """Differential attention's ``lambda_init`` of layer ``li``."""
+    return 0.8 - 0.6 * math.exp(-0.3 * li)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -295,8 +342,58 @@ class DeltaRuleMixer:
         return self.n_heads, self.head_dim, self.head_dim
 
 
+@dataclasses.dataclass(frozen=True)
+class SelectiveScanMixer:
+    """One kind of PER-CHANNEL selective state space that a configuration
+    describes by name (``TransformerConfig.selective_scan``): Mamba-1's
+    (arXiv:2312.00752). ``d_inner`` channels, each with ``state_size`` float32
+    states of its own decay: ``h[n, c] = exp(step[c] A[n, c]) h[n, c] +
+    step[c] x[c] B[n]`` and ``y[c] = sum_n h[n, c] C[n] + D[c] x[c]``, with
+    ``step`` (through a rank ``dt_rank`` pair), ``B`` and ``C`` projected from
+    the convolved input a token. No heads and no scalar decay
+    (:class:`StateSpaceMixer`'s block products cannot say it): every (state,
+    channel) pair decays by itself, so the recurrence is vector work along the
+    positions. A depthwise causal convolution over the last ``conv_kernel``
+    tokens comes before. What a sequence carries from one program run to the
+    next: the last ``conv_kernel - 1`` inputs of the convolution and the
+    state."""
+    d_inner: int
+    dt_rank: int
+    state_size: int = 16
+    conv_kernel: int = 4
+
+    @property
+    def conv_dim(self):
+        """Channels the convolution runs over: x alone."""
+        return self.d_inner
+
+    @property
+    def tail(self):
+        """Convolution inputs a sequence carries over."""
+        return self.conv_kernel - 1
+
+    @property
+    def state_shape(self):
+        """A sequence's float32 state, held STATE-major: ``[state, channel]``,
+        the channels on the minor dimension, where a token's step and input
+        are vectors (and where a TPU array's minor dimension is whole lane
+        tiles: 16 states there would be stored at 128)."""
+        return self.state_size, self.d_inner
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedMemoryUnit:
+    """One kind of mixer with no cache and no attention that a configuration
+    describes by name (``TransformerConfig.gated_memory``): SambaY's Gated
+    Memory Unit (arXiv:2507.06607), ``(silu(u W_1) * m) W_2`` with ``m`` the
+    MEMORY of layer ``memory_from`` at the same position: that layer's
+    :class:`SelectiveScanMixer` output before its gate, ``d_inner`` wide."""
+    d_inner: int
+    memory_from: int
+
+
 # The mixers that carry a state a sequence and attend nothing.
-RECURRENT = (StateSpaceMixer, DeltaRuleMixer)
+RECURRENT = (StateSpaceMixer, DeltaRuleMixer, SelectiveScanMixer)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -346,6 +443,12 @@ class TransformerConfig:
     # name -> DeltaRuleMixer or its fields: a layer so named has gated
     # delta-rule linear attention in the place of attention.
     delta_rule: tuple = ()
+    # name -> SelectiveScanMixer or its fields: a layer so named has a
+    # per-channel selective state space in the place of attention.
+    selective_scan: tuple = ()
+    # name -> GatedMemoryUnit or its fields: a layer so named gates the
+    # memory of an earlier selective-scan layer in the place of attention.
+    gated_memory: tuple = ()
     # Per-layer halves: ``layer_parts[i]`` is "both" (attention, then a
     # feed-forward: the default, and what a missing entry means), "mixer" (the
     # mixer alone) or "ffn" (the feed-forward alone). A layer of one half has
@@ -363,7 +466,7 @@ class TransformerConfig:
     attn_gate: bool = False
     norm: str = "layernorm"     # | "rmsnorm" (scale only, no mean, no bias)
     norm_eps: float = 1e-5
-    pos: str = "learned"        # | "rope" (rotate-half, per head)
+    pos: str = "learned"        # | "rope" (rotate-half, per head) | "none"
     rope_theta: float = 10000.0
     qk_norm: bool = False       # RMSNorm over the whole projected Q and K
     ffn: str = "gelu"           # | "swiglu": silu(x Wg) * (x Wu), then Wd
@@ -404,26 +507,17 @@ class TransformerConfig:
             raise ValueError(
                 f"attn_impl must be 'auto', 'gather', 'ring' or 'flash', "
                 f"got {self.attn_impl!r}")
-        latent = self.latent.items() if isinstance(self.latent, dict) \
-            else self.latent
-        object.__setattr__(self, "latent", tuple(
-            (name, a if isinstance(a, LatentAttention)
-             else LatentAttention(**a)) for name, a in latent))
-        multihead = self.multihead.items() \
-            if isinstance(self.multihead, dict) else self.multihead
-        object.__setattr__(self, "multihead", tuple(
-            (name, a if isinstance(a, MultiHeadAttention)
-             else MultiHeadAttention(**a)) for name, a in multihead))
-        state_space = self.state_space.items() \
-            if isinstance(self.state_space, dict) else self.state_space
-        object.__setattr__(self, "state_space", tuple(
-            (name, a if isinstance(a, StateSpaceMixer)
-             else StateSpaceMixer(**a)) for name, a in state_space))
-        delta_rule = self.delta_rule.items() \
-            if isinstance(self.delta_rule, dict) else self.delta_rule
-        object.__setattr__(self, "delta_rule", tuple(
-            (name, a if isinstance(a, DeltaRuleMixer)
-             else DeltaRuleMixer(**a)) for name, a in delta_rule))
+        for field, kind in (("latent", LatentAttention),
+                            ("multihead", MultiHeadAttention),
+                            ("state_space", StateSpaceMixer),
+                            ("delta_rule", DeltaRuleMixer),
+                            ("selective_scan", SelectiveScanMixer),
+                            ("gated_memory", GatedMemoryUnit)):
+            named = getattr(self, field)
+            named = named.items() if isinstance(named, dict) else named
+            object.__setattr__(self, field, tuple(
+                (name, a if isinstance(a, kind) else kind(**a))
+                for name, a in named))
         object.__setattr__(self, "layer_attn", tuple(self.layer_attn))
         object.__setattr__(self, "layer_parts", tuple(self.layer_parts))
         for part in self.layer_parts:
@@ -438,7 +532,7 @@ class TransformerConfig:
                 raise ValueError(f"experts_held {self.experts_held} is not "
                                  f"a range of the {self.n_experts} experts")
         for field, allowed in (("norm", ("layernorm", "rmsnorm")),
-                               ("pos", ("learned", "rope")),
+                               ("pos", ("learned", "rope", "none")),
                                ("ffn", ("gelu", "swiglu", "relu2")),
                                ("router", ("softmax", "sigmoid"))):
             if getattr(self, field) not in allowed:
@@ -466,26 +560,44 @@ class TransformerConfig:
 
     def attn_of(self, li):
         """Layer ``li``'s :class:`LatentAttention`,
-        :class:`MultiHeadAttention`, :class:`StateSpaceMixer` or
-        :class:`DeltaRuleMixer`, or None for the multi-head attention of
+        :class:`MultiHeadAttention`, :class:`StateSpaceMixer`,
+        :class:`DeltaRuleMixer`, :class:`SelectiveScanMixer` or
+        :class:`GatedMemoryUnit`, or None for the multi-head attention of
         ``n_heads``."""
         name = self.layer_attn[li] if li < len(self.layer_attn) else None
-        return dict(self.latent + self.multihead + self.recurrent).get(name)
+        return dict(self.latent + self.multihead + self.recurrent
+                    + self.gated_memory).get(name)
+
+    def _reads(self, li, field):
+        """Whether a later layer names ``li`` in ``field`` of its kind."""
+        return any(getattr(self.attn_of(lj), field, None) == li
+                   for lj in range(li + 1, self.n_layers))
+
+    def hands_memory(self, li):
+        """Whether a later layer (a :class:`GatedMemoryUnit`) reads layer
+        ``li``'s memory."""
+        return self._reads(li, "memory_from")
+
+    def shares_kv(self, li):
+        """Whether a later layer attends layer ``li``'s keys and values
+        (``MultiHeadAttention.kv_from``)."""
+        return self._reads(li, "kv_from")
 
     @property
     def recurrent(self):
         """The described kinds that carry a state a sequence (``(name,
         kind)`` pairs): a model with any is padded, cached and speculated
         differently (``serving/``)."""
-        return self.state_space + self.delta_rule
+        return self.state_space + self.delta_rule + self.selective_scan
 
     @property
     def described(self):
         """Whether layers are described by kind (``latent``, ``multihead``,
-        ``state_space``, ``delta_rule``, ``layer_parts``): such a model is
-        filled by chunks and its layers' caches differ."""
+        ``state_space``, ``delta_rule``, ``selective_scan``,
+        ``gated_memory``, ``layer_parts``): such a model is filled by chunks
+        and its layers' caches differ."""
         return bool(self.latent or self.multihead or self.recurrent
-                    or self.layer_parts)
+                    or self.gated_memory or self.layer_parts)
 
     def _part(self, li):
         return self.layer_parts[li] if li < len(self.layer_parts) else "both"
@@ -620,14 +732,27 @@ def _latent_params(key, cfg, a: LatentAttention):
 
 def _multihead_params(key, cfg, a: MultiHeadAttention):
     """A described multi-head layer's matrices: the query projection of
-    ``n_heads``, the fused key and value projections of ``n_kv_heads``, the
-    output projection, the head gate."""
+    ``n_heads``, the fused key and value projections of ``n_kv_heads`` (none
+    where the layer attends another's, ``kv_from``), the output projection,
+    the head gate; with ``bias`` a bias on each projection; with
+    ``differential`` the four ``lambda`` vectors (N(0, 0.1)) and the scale of
+    the pairs' norm."""
     D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
-    k = jax.random.split(key, 4)
+    k = jax.random.split(key, 5)
     p = {"wq": _dense_init(k[0], (D, a.n_heads, a.head_dim), D, pdt),
-         "wkv": _dense_init(k[1], (D, 2, a.n_kv_heads, a.head_dim), D, pdt),
          "wo": _dense_init(k[2], (a.n_heads, a.head_dim, D),
                            a.n_heads * a.head_dim, pdt)}
+    if a.kv_from is None:
+        p["wkv"] = _dense_init(k[1], (D, 2, a.n_kv_heads, a.head_dim), D, pdt)
+    if a.bias:
+        p["bq"] = jnp.zeros((a.n_heads, a.head_dim), pdt)
+        p["bo"] = jnp.zeros((D,), pdt)
+        if a.kv_from is None:
+            p["bkv"] = jnp.zeros((2, a.n_kv_heads, a.head_dim), pdt)
+    if a.differential:
+        p["diff_lambda"] = (0.1 * jax.random.normal(
+            k[4], (4, a.head_dim), jnp.float32)).astype(pdt)
+        p["diff_norm"] = {"scale": jnp.ones((2 * a.head_dim,), pdt)}
     if a.gate == "channel":
         p["w_attn_gate"] = _dense_init(k[3], (D, a.n_heads, a.head_dim), D,
                                        pdt)
@@ -697,12 +822,54 @@ def _delta_rule_params(key, cfg, a: DeltaRuleMixer):
     }
 
 
+def _selective_scan_params(key, cfg, a: SelectiveScanMixer):
+    """A selective-scan layer's parameters: the in-projection (x | the gate
+    z), the convolution's taps and bias, the projection of the convolved x to
+    (the step's low rank | B | C), the step's up-projection and bias, the log
+    decay rates ``[state, channel]``, the skip, the out-projection. As Mamba-1
+    initialises them: state ``n`` of every channel decays at rate ``n + 1``,
+    the step's bias is the inverse softplus of a step drawn log-uniform over
+    (0.001, 0.1) and its up-projection uniform within ``dt_rank ** -0.5``, so
+    that a channel's states remember from a handful of tokens to a
+    thousand."""
+    D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
+    C, N, R = a.d_inner, a.state_size, a.dt_rank
+    k = jax.random.split(key, 6)
+    step = jnp.exp(jax.random.uniform(
+        k[4], (C,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return {
+        "w_scan_in": _dense_init(k[0], (D, 2 * C), D, pdt),
+        "scan_conv_w": _dense_init(k[1], (C, a.conv_kernel), a.conv_kernel,
+                                   pdt),
+        "scan_conv_b": jnp.zeros((C,), pdt),
+        "w_scan_x": _dense_init(k[2], (C, R + 2 * N), C, pdt),
+        "w_scan_dt": jax.random.uniform(
+            k[3], (R, C), jnp.float32, -R ** -0.5, R ** -0.5).astype(pdt),
+        # softplus(scan_dt_bias) = step
+        "scan_dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
+        "scan_a_log": jnp.broadcast_to(jnp.log(jnp.arange(
+            1, N + 1, dtype=jnp.float32))[:, None], (N, C)).astype(pdt),
+        "scan_skip": jnp.ones((C,), pdt),
+        "w_scan_out": _dense_init(k[5], (C, D), C, pdt),
+    }
+
+
+def _gated_memory_params(key, cfg, a: GatedMemoryUnit):
+    """A gated memory unit's two matrices."""
+    D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
+    k = jax.random.split(key, 2)
+    return {"w_gmu_in": _dense_init(k[0], (D, a.d_inner), D, pdt),
+            "w_gmu_out": _dense_init(k[1], (a.d_inner, D), a.d_inner, pdt)}
+
+
 def _mixer_params(key, cfg, a):
     """The parameters of a layer's mixer of a described kind."""
     make = {MultiHeadAttention: _multihead_params,
             LatentAttention: _latent_params,
             StateSpaceMixer: _state_space_params,
-            DeltaRuleMixer: _delta_rule_params}[type(a)]
+            DeltaRuleMixer: _delta_rule_params,
+            SelectiveScanMixer: _selective_scan_params,
+            GatedMemoryUnit: _gated_memory_params}[type(a)]
     return make(key, cfg, a)
 
 
@@ -959,14 +1126,82 @@ def _qkv_kind(h, layer, cfg, a: MultiHeadAttention, positions=None):
     """A described multi-head layer's ``q [B, S, n_heads, dh]`` and ``k, v
     [B, S, n_kv_heads, dh]`` from the normed input, rotated by the kind's
     rule to ``positions [B, S]`` (None = 0..S-1): what the attention and the
-    cache take."""
+    cache take. ``k`` and ``v`` are None for a layer that attends another's
+    (``kv_from``). A differential kind's come out as its attention takes them
+    (``a.attended``): the key/value heads in pairs side by side, every query
+    as wide as a pair with zeros in the other head's half, and times
+    ``sqrt(2)`` (in float32, before it is rounded) so that the attention's
+    ``1 / sqrt(2 dh)`` is the kind's ``1 / sqrt(dh)``."""
     dt = cfg.compute_dtype
-    q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-    kv = jnp.einsum("bsd,dchk->cbshk", h, layer["wkv"].astype(dt))
+    if a.bias or a.differential:
+        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt),
+                       preferred_element_type=jnp.float32)
+        if a.bias:
+            q = q + layer["bq"].astype(jnp.float32)
+        q = (q * math.sqrt(2.0) if a.differential else q).astype(dt)
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
+    kv = _kv_kind(h, layer, cfg, a)
     if positions is None:
         positions = jnp.arange(h.shape[1])[None]
-    return (_rope_kind(q, positions, a), _rope_kind(kv[0], positions, a),
-            kv[1])
+    q = _rope_kind(q, positions, a)
+    if a.differential:
+        first = (jnp.arange(a.n_heads) % 2 == 0)[:, None]
+        zeros = jnp.zeros_like(q)
+        q = jnp.where(first, jnp.concatenate([q, zeros], -1),
+                      jnp.concatenate([zeros, q], -1))
+    return (q, *_keys_values(kv, a, positions))
+
+
+def _kv_kind(h, layer, cfg, a: MultiHeadAttention):
+    """The fused key/value projection ``[2, B, S, n_kv_heads, dh]`` (with its
+    bias) of a described multi-head layer, or None where it attends another
+    layer's."""
+    if a.kv_from is not None:
+        return None
+    dt = cfg.compute_dtype
+    kv = jnp.einsum("bsd,dchk->cbshk", h, layer["wkv"].astype(dt))
+    if a.bias:
+        kv = kv + layer["bkv"].astype(dt)[:, None, None]
+    return kv
+
+
+def _keys_values(kv, a: MultiHeadAttention, positions):
+    """``k`` (rotated) and ``v`` of :func:`_kv_kind`'s projection as the
+    attention and the cache take them; (None, None) for None."""
+    if kv is None:
+        return None, None
+    k, v = _rope_kind(kv[0], positions, a), kv[1]
+    if a.differential:
+        k, v = (x.reshape(*x.shape[:2], a.n_kv_heads // 2, 2 * a.head_dim)
+                for x in (k, v))
+    return k, v
+
+
+def project_kv(h, layer, cfg, a: MultiHeadAttention, positions):
+    """The ``k, v`` of :func:`_qkv_kind` alone: all that a fill which leaves
+    the stack at this layer computes of it for the positions whose logits
+    nobody reads."""
+    return _keys_values(_kv_kind(h, layer, cfg, a), a, positions)
+
+
+def differential_combine(o, layer, a: MultiHeadAttention, li, eps, dt):
+    """Differential attention's difference from the attended pairs: ``o [B,
+    S, n_heads, 2 dh]`` (head ``2p`` the first softmax of pair ``p``, head
+    ``2p + 1`` the second, each over the pair's two value heads) -> ``[B, S,
+    n_heads, dh]``: ``RMSNorm(a1 - lambda a2) * (1 - lambda_init)`` a pair,
+    float32, laid out as the output projection's ``n_heads`` heads of
+    ``dh``."""
+    f32 = jnp.float32
+    lam = layer["diff_lambda"].astype(f32)
+    init = lambda_init(li)
+    lam = (jnp.exp(jnp.sum(lam[0] * lam[1]))
+           - jnp.exp(jnp.sum(lam[2] * lam[3])) + init)
+    o = o.astype(f32)
+    d = o[:, :, 0::2] - lam * o[:, :, 1::2]
+    d = d * jax.lax.rsqrt(jnp.mean(d * d, -1, keepdims=True) + eps)
+    d = d * layer["diff_norm"]["scale"].astype(f32) * (1.0 - init)
+    return d.reshape(*o.shape[:2], a.n_heads, a.head_dim).astype(dt)
 
 
 def grouped_attend(q, k, v, a: MultiHeadAttention, allowed, dt):
@@ -1620,6 +1855,127 @@ def state_space_mix(u, layer, a: StateSpaceMixer, cfg, tail=None, state=None,
             tail, state)
 
 
+# Positions that :func:`selective_scan_mix` takes as one trip of its scan over
+# a window; it changes no value.
+_SCAN_BLOCK = 16
+
+
+def _scan_step(x, step, rate, b_in, c_out, state):
+    """The per-channel selective recurrence for ONE position, float32: ``x,
+    step [B, C]``, ``rate [N, C]`` (negative), ``b_in, c_out [B, N]``, ``state
+    [B, N, C]`` -> (``y [B, C]``, the state leaving): ``h = exp(step rate) h +
+    (step x) B`` elementwise over (state, channel), ``y = sum_n h C``. A
+    position whose ``step`` is 0 leaves the state bit for bit."""
+    state = state * jnp.exp(step[:, None, :] * rate) \
+        + (step * x)[:, None, :] * b_in[:, :, None]
+    return jnp.sum(state * c_out[:, :, None], 1), state
+
+
+def _scan_blocks(x, step, rate, b_in, c_out, state, block):
+    """:func:`_scan_step` over a window, float32: ``x, step [B, S, C]``,
+    ``b_in, c_out [B, S, N]``, ``state [B, N, C]`` entering -> (``y [B, S,
+    C]``, the state leaving). The window is walked ``block`` positions a trip
+    of one scan, each position the update itself, so nothing of the size of a
+    state a position outlives a block (the ``[S, N, C]`` history of a window
+    of 512 is 168 MB at 5120 channels of 16 states). A window of one is the
+    update, with no scan around it."""
+    f32 = jnp.float32
+    B, S, C = x.shape
+    x, step, b_in, c_out = (v.astype(f32) for v in (x, step, b_in, c_out))
+    state, rate = state.astype(f32), rate.astype(f32)
+    if S == 1:
+        y, state = _scan_step(x[:, 0], step[:, 0], rate, b_in[:, 0],
+                              c_out[:, 0], state)
+        return y[:, None], state
+    Q = min(block, S)
+    nb = -(-S // Q)
+
+    def blocks(v):     # [B, S, W] -> [nb, Q, B, W], dead positions behind
+        v = jnp.pad(v, ((0, 0), (0, nb * Q - S), (0, 0)))
+        return v.reshape(B, nb, Q, -1).transpose(1, 2, 0, 3)
+
+    def trip(state, xs):
+        ys = []
+        for x_t, step_t, b_t, c_t in zip(*xs):
+            y_t, state = _scan_step(x_t, step_t, rate, b_t, c_t, state)
+            ys.append(y_t)
+        return state, jnp.stack(ys)
+
+    state, y = jax.lax.scan(trip, state, tuple(
+        blocks(v) for v in (x, step, b_in, c_out)))
+    return y.reshape(nb * Q, B, C).transpose(1, 0, 2)[:, :S], state
+
+
+def selective_scan_mix(u, layer, a: SelectiveScanMixer, cfg, tail=None,
+                       state=None, live=None, recur=None, with_memory=False):
+    """THE per-channel selective state space (Mamba-1), written once: the
+    normed input ``u [B, S, D]`` of a window of ``S`` consecutive tokens a
+    sequence -> (``out [B, S, D]``, the convolution tail leaving ``[B,
+    conv_kernel - 1, d_inner]`` in the compute dtype, the state leaving ``[B,
+    N, d_inner]`` float32). ``tail``, ``state``, ``live`` and ``recur`` are
+    :func:`state_space_mix`'s: what the sequence carried in (None = zeros),
+    the window's real positions (first; a dead one advances nothing), and a
+    caller's own recurrence ``recur(x, step, rate, b_in, c_out) -> (y, the
+    state leaving)`` over operands shaped as :func:`_scan_blocks` takes them.
+    ``with_memory``: ``out`` is the pair ``(out, m)`` with ``m [B, S,
+    d_inner]`` the MEMORY, the scan's output (skip included) before the gate,
+    in the compute dtype: what a :class:`GatedMemoryUnit` of a later layer
+    gates.
+
+    ``[x | z] = u W_in``; ``x`` through the depthwise causal convolution (with
+    its bias) and SiLU; ``[dt | B | C] = x W_x``; ``step = softplus(dt W_dt +
+    dt_bias)``, ``rate = -exp(a_log)``; the recurrence (:func:`_scan_blocks`)
+    plus ``skip * x``; times ``silu(z)``; ``W_out``. The step, the decay and
+    the state are float32; the projections and the convolution's inputs are
+    the compute dtype's."""
+    dt, f32 = cfg.compute_dtype, jnp.float32
+    B, S, _ = u.shape
+    C, N, R, K = a.d_inner, a.state_size, a.dt_rank, a.conv_kernel
+    if tail is None:
+        tail = jnp.zeros((B, a.tail, C), dt)
+    if recur is None:
+        if state is None:
+            state = jnp.zeros((B, N, C), f32)
+        recur = functools.partial(_scan_blocks, state=state,
+                                  block=_SCAN_BLOCK)
+    if live is None:
+        live = jnp.ones((B, S), bool)
+    xz = jnp.einsum("bsd,dw->bsw", u, layer["w_scan_in"].astype(dt))
+    x, z = xz[..., :C], xz[..., C:]
+    seq = jnp.concatenate([tail.astype(dt), x], 1)          # [B, K-1+S, C]
+    at = jnp.sum(live, 1)[:, None] + jnp.arange(a.tail)[None]
+    tail = jnp.take_along_axis(seq, at[..., None], axis=1)
+    taps = layer["scan_conv_w"].astype(f32)
+    conv = layer["scan_conv_b"].astype(f32) + sum(
+        seq[:, j:j + S].astype(f32) * taps[:, j] for j in range(K))
+    x = jax.nn.silu(conv).astype(dt)
+    low = jnp.einsum("bsc,cw->bsw", x, layer["w_scan_x"].astype(dt))
+    step = jnp.einsum("bsr,rc->bsc", low[..., :R],
+                      layer["w_scan_dt"].astype(dt))
+    step = jax.nn.softplus(step.astype(f32)
+                           + layer["scan_dt_bias"].astype(f32))
+    step = jnp.where(live[..., None], step, 0.0)
+    y, state = recur(x, step, -jnp.exp(layer["scan_a_log"].astype(f32)),
+                     low[..., R:R + N], low[..., R + N:])
+    y = y + layer["scan_skip"].astype(f32) * x.astype(f32)
+    out = jnp.einsum(
+        "bsc,cd->bsd", (y * jax.nn.silu(z.astype(f32))).astype(dt),
+        layer["w_scan_out"].astype(dt))
+    return ((out, y.astype(dt)) if with_memory else out), tail, state
+
+
+def gated_memory_mix(u, layer, a: GatedMemoryUnit, cfg, memory):
+    """THE gated memory unit: the normed input ``u [B, S, D]`` and the
+    ``memory [B, S, d_inner]`` of the layer it names, position by position ->
+    ``(silu(u W_1) * memory) W_2 [B, S, D]``. Nothing is carried."""
+    dt = cfg.compute_dtype
+    gate = jnp.einsum("bsd,dc->bsc", u, layer["w_gmu_in"].astype(dt))
+    gated = jax.nn.silu(gate.astype(jnp.float32)) \
+        * memory.astype(jnp.float32)
+    return jnp.einsum("bsc,cd->bsd", gated.astype(dt),
+                      layer["w_gmu_out"].astype(dt))
+
+
 # Positions of a sub-block of the chunked delta rule: inside one, a decay
 # between two positions is exponentiated as their DIFFERENCE, exactly; across
 # two, as a product of two factors that are each at most one.
@@ -1976,7 +2332,14 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
     (:func:`state_space_mix` or :func:`delta_rule_mix` on this layer's normed
     input):
     the caller supplies what the sequences carried in and keeps what they
-    carry out. A layer of one half (``cfg.layer_parts``) runs that half
+    carry out. A selective-scan layer whose memory a later layer reads
+    (``cfg.hands_memory``) gives ``attend`` a ``mix`` whose ``out`` is the
+    pair ``(out, memory)``: ``attend`` keeps the memory and returns ``out``.
+    A multi-head layer that attends ANOTHER layer's keys and values
+    (``kv_from``) hands ``attend(q, None, None)``: the caller has them. A
+    GATED MEMORY layer hands ``attend(mix) -> out`` with ``mix(memory) ->
+    out`` (:func:`gated_memory_mix`): the caller has the memory of the layer
+    it names. A layer of one half (``cfg.layer_parts``) runs that half
     alone, under its own norm, and ``attend`` may be None for a layer with
     no mixer."""
     dt = cfg.compute_dtype
@@ -1986,12 +2349,23 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
         h = _norm(x, layer["ln1"], cfg)
     if isinstance(a, RECURRENT) and cfg.has_mixer(li):
         # Looked up here, when a program is traced: tests and the benchmark's
-        # planted faults replace the two functions in this module.
-        scope, mix = ((scopes.STATE_SPACE, state_space_mix)
-                      if isinstance(a, StateSpaceMixer)
-                      else (scopes.LINEAR_ATTENTION, delta_rule_mix))
+        # planted faults replace the functions in this module.
+        if isinstance(a, SelectiveScanMixer):
+            scope, mix = scopes.STATE_SPACE, functools.partial(
+                selective_scan_mix, h, layer, a, cfg,
+                with_memory=cfg.hands_memory(li))
+        else:
+            scope, mix = ((scopes.STATE_SPACE, state_space_mix)
+                          if isinstance(a, StateSpaceMixer)
+                          else (scopes.LINEAR_ATTENTION, delta_rule_mix))
+            mix = functools.partial(mix, h, layer, a, cfg)
         with jax.named_scope(scope):
-            out = attend(functools.partial(mix, h, layer, a, cfg))
+            out = attend(mix)
+            x = x + _constrain(out, out_spec)
+    elif isinstance(a, GatedMemoryUnit) and cfg.has_mixer(li):
+        with jax.named_scope(scopes.GATED_MEMORY):
+            out = attend(functools.partial(gated_memory_mix, h, layer, a,
+                                           cfg))
             x = x + _constrain(out, out_spec)
     elif cfg.has_mixer(li):
         with jax.named_scope(scopes.ATTENTION):
@@ -2001,9 +2375,14 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
                                  layer["wo"].astype(dt))
             elif isinstance(a, MultiHeadAttention):
                 o = attend(*_qkv_kind(h, layer, cfg, a, positions))
+                if a.differential:
+                    o = differential_combine(o, layer, a, li, cfg.norm_eps,
+                                             dt)
                 if a.gate:
                     o = o * _head_gate(h, layer, dt)
                 out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
+                if a.bias:
+                    out = out + layer["bo"].astype(dt)
             else:
                 o, selected = attend(
                     *_latent_qkv(h, layer, cfg, a, positions),
@@ -2026,7 +2405,8 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
 
 
 def _block_fn(cfg, mesh, impl, seq_spec, full_spec):
-    """``(layer, x) -> (x, routing)`` with the trainer's attention."""
+    """``(layer, x, li, carried) -> (x, routing, carried)`` with the
+    trainer's attention."""
     if (impl == "ring" and mesh is not None
             and cfg.seq_axis in mesh.axis_names):
         attend = lambda q, k, v: _attend_ring(q, k, v, cfg, mesh)  # noqa: E731
@@ -2036,17 +2416,37 @@ def _block_fn(cfg, mesh, impl, seq_spec, full_spec):
         attend = lambda q, k, v: _attend_gather(  # noqa: E731
             q, k, v, cfg, full_spec)
 
-    def fn(layer, x, li=0):
+    def fn(layer, x, li=0, carried=None):
+        """``carried``: what earlier layers handed on for later ones (the keys
+        and values of a layer whose cache others share, a selective-scan
+        layer's memory), by name -> ``(x, routing, carried)``, this layer's
+        own added."""
         a = cfg.attn_of(li)
+        carried = dict(carried or {})
         if isinstance(a, RECURRENT):   # every sequence starts here
-            mine = lambda mix: mix()[0]  # noqa: E731
+            def mine(mix):
+                out = mix()[0]
+                if cfg.hands_memory(li):
+                    out, carried[f"memory{li}"] = out
+                return out
+        elif isinstance(a, GatedMemoryUnit):
+            mine = lambda mix: mix(  # noqa: E731
+                carried[f"memory{a.memory_from}"])
+        elif isinstance(a, MultiHeadAttention):
+            kind = _attend_kind(a.attended, cfg.compute_dtype)
+
+            def mine(q, k, v):
+                if a.kv_from is not None:
+                    k, v = carried[f"kv{a.kv_from}"]
+                elif cfg.shares_kv(li):
+                    carried[f"kv{li}"] = (k, v)
+                return kind(q, k, v)
         else:
-            mine = attend if a is None else (
-                _attend_kind if isinstance(a, MultiHeadAttention)
-                else _attend_latent)(a, cfg.compute_dtype)
+            mine = attend if a is None else _attend_latent(
+                a, cfg.compute_dtype)
         x, routing = block(layer, x, cfg, mine, mesh=mesh,
                            out_spec=seq_spec, li=li)
-        return _constrain(x, seq_spec), routing
+        return _constrain(x, seq_spec), routing, carried
 
     return fn
 
@@ -2119,13 +2519,15 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None,
     impl = resolve_attn(cfg, S, mesh)
     fn = _block_fn(cfg, mesh, impl, seq_spec, full_spec)
 
-    def block_(x, layer, li):
-        return fn(layer, x, li)[0]
+    def block_(x, layer, li, carried):
+        x, _, carried = fn(layer, x, li, carried)
+        return x, carried
 
     if cfg.remat:
         block_ = jax.checkpoint(block_, static_argnums=(2,))
+    carried = {}
     for li, layer in enumerate(params["layers"]):
-        x = block_(x, layer, li)
+        x, carried = block_(x, layer, li, carried)
     x = _norm(x, params["final_ln"], cfg)
     if return_hidden:
         return x

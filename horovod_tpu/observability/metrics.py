@@ -671,6 +671,36 @@ SERVE_DELTA_KERNEL_CALLS = counter(
     "kernel of the chunked form, kda_chunk_scan: a call of more than one "
     "query a slot where the kernel runs, else 0 (a decode step; a CPU "
     "backend; a mesh)", ("program",))
+SERVE_KV_SHARED_ROWS = counter(
+    "hvd_serve_kv_shared_rows",
+    "K/V rows read by multi-head layers that own no cache (they attend the "
+    "pages of the layer they name, kv_from): a slot's live rows once a call, "
+    "summed over those layers", ("program",))
+SERVE_FILL_ROWS = counter(
+    "hvd_serve_fill_rows",
+    "Positions a program took through the layers BELOW the layer at which "
+    "the model's fill leaves the stack (engine.fill_exit; only such a model "
+    "has the counter): every position of every call", ("program",))
+SERVE_TAIL_ROWS = counter(
+    "hvd_serve_tail_rows",
+    "Positions a program took through the layers from that layer up: every "
+    "position of a decode step, one a slot of the chunk that ends a prompt, "
+    "none of any other chunk", ("program",))
+SERVE_SCAN_ROWS = counter(
+    "hvd_serve_scan_rows",
+    "(slot, layer) state rows the selective-scan layers read and wrote "
+    "back: a call's slots times those layers", ("program",))
+SERVE_SCAN_BYTES = counter(
+    "hvd_serve_scan_bytes",
+    "Bytes of those rows both ways: the convolution's tail in the compute "
+    "dtype and the float32 [state, channel] state", ("program",))
+SERVE_SCAN_TOKENS = counter(
+    "hvd_serve_scan_tokens",
+    "(token, layer) positions those layers passed over", ("program",))
+SERVE_SCAN_RESETS = counter(
+    "hvd_serve_scan_resets",
+    "(slot, layer) rows those layers zeroed because a sequence began",
+    ("program",))
 # ``serve_stats()[family][counter]`` -> the counter that exports it, by
 # program kind: ``ServeLoop._add`` drives these from the engine's account of
 # each call (``serving.engine.work``); a counter with no entry is in
@@ -685,7 +715,14 @@ SERVE_WORK_COUNTERS = {"attn": {
     "kv_latent_rows": SERVE_KV_LATENT_ROWS,
     "qk_latent_pairs": SERVE_QK_LATENT_PAIRS,
     "latent_expanded_calls": SERVE_LATENT_EXPANDED_CALLS,
+    "kv_shared_rows": SERVE_KV_SHARED_ROWS,
+    "fill_rows": SERVE_FILL_ROWS,
+    "tail_rows": SERVE_TAIL_ROWS,
 }, "state": {
+    "scan_rows": SERVE_SCAN_ROWS,
+    "scan_bytes": SERVE_SCAN_BYTES,
+    "scan_tokens": SERVE_SCAN_TOKENS,
+    "scan_resets": SERVE_SCAN_RESETS,
     "delta_rows": SERVE_DELTA_ROWS,
     "delta_bytes": SERVE_DELTA_BYTES,
     "delta_tokens": SERVE_DELTA_TOKENS,

@@ -19,7 +19,9 @@ for ``mlp|experts``. ``state_space`` holds a state-space layer's whole mixer
 an attention layer's, and ``linear_attention`` a delta-rule layer's
 (projections, convolutions, decay, recurrence, gated norm); ``expert_latent``
 the two projections around experts that work in a latent, inside ``mlp``
-beside ``experts``.
+beside ``experts``. A selective-scan layer's whole mixer is under
+``state_space`` too (one kind or the other a model), and ``gated_memory``
+holds a gated memory unit's two products and its gate.
 
 No JAX here: the benchmark's jax-free parent imports this module.
 """
@@ -28,10 +30,11 @@ import re
 
 PHASES = GRAD, GRAD_REDUCE, OPTIMIZER = ("grad", "grad_reduce", "optimizer")
 SCOPES = (EMBED, LAYER_NORM, RMS_NORM, ATTENTION, MLP, EXPERTS, LOSS,
-          HEAD, STATE_SPACE, EXPERT_LATENT, LINEAR_ATTENTION) = (
+          HEAD, STATE_SPACE, EXPERT_LATENT, LINEAR_ATTENTION,
+          GATED_MEMORY) = (
               "embed", "layer_norm", "rms_norm", "attention", "mlp",
               "experts", "loss", "head", "state_space", "expert_latent",
-              "linear_attention")
+              "linear_attention", "gated_memory")
 
 # What a transform writes around a component of the path it differentiates,
 # transposes or batches: ``transpose(jvp(attention))``. Components that are

@@ -244,7 +244,7 @@ def grouped_kernels(cfg, geo, mesh):
     kernel tiles. Else they gather their pages."""
     return (bool(cfg.multihead) and _kernels_may_run(cfg, mesh)
             and all(paged_attention.grouped_supported(
-                geo.page_size, a.head_dim, cfg.compute_dtype)
+                geo.page_size, a.attended.head_dim, cfg.compute_dtype)
                 for _, a in cfg.multihead))
 
 
@@ -279,6 +279,32 @@ def _kernels(cfg, geo, mesh, one_query=False):
             "grouped": grouped_kernels(cfg, geo, mesh),
             "state": one_query and state_kernels(cfg, geo, mesh),
             "linear": not one_query and linear_kernels(cfg, geo, mesh)}
+
+
+def fill_exit(cfg):
+    """Where a model's FILL leaves the stack: the index of the layer after
+    whose key/value write a prompt's positions need nothing more, or None
+    (every model whose last layer owns a cache: the whole stack runs on every
+    position).
+
+    It is the last layer that owns any cache, where that is a multi-head
+    layer whose pages later layers attend (``kv_from``) and NO later layer
+    owns a cache: what stands above it then reads only its own position (a
+    feed-forward, a gated memory unit on the memory of that position) or
+    that layer's pages, so the logits of position ``t`` need the layers above
+    at ``t`` alone, and a fill needs logits at ONE position. A fill program
+    runs the layers below on every position, this layer's key/value
+    projection and write, and (the chunk that ends a prompt) everything from
+    this layer's queries up on the prompt's last row alone."""
+    owners = [li for li in range(cfg.n_layers)
+              if kv_cache.owns_cache(cfg, li)]
+    if not owners or owners[-1] == cfg.n_layers - 1:
+        return None
+    last = owners[-1]
+    a = cfg.attn_of(last)
+    if isinstance(a, tfm.MultiHeadAttention) and cfg.shares_kv(last):
+        return last
+    return None
 
 
 def _check_positions(cfg, n, what):
@@ -472,7 +498,12 @@ def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
     """One multi-head layer of a described kind in a chunk or decode
     program: write the window's ``k, v [B, Q, Hkv, dh]`` at the consecutive
     positions ``q_pos [B, Q]`` where ``ok [B, Q]``, then attend ``q [B, Q,
-    Hq, dh]`` -> (the layer's arrays, ``o [B, Q, Hq, dh]``). A full layer's
+    Hq, dh]`` -> (the layer's arrays, ``o [B, Q, Hq, dh]``). ``q`` None:
+    the write alone. ``k`` None: nothing is written and ``k_c, v_c`` are
+    attended as they are (a layer that attends ANOTHER layer's pages,
+    ``kv_from``; a fill's last row, whose layer wrote its window before:
+    :func:`fill_exit`). ``a`` is the kind as its attention sees it
+    (``MultiHeadAttention.attended``). A full layer's
     pages are the context columns of ``tables [B, max_blocks +
     ring_blocks]``, a window layer's the ring's. With ``kernels`` the
     layer's arrays are read where they lie, over the live pages only; else
@@ -480,8 +511,11 @@ def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
     attended with materialised scores (``transformer.grouped_attend``)."""
     B = q_pos.shape[0]
     table, page_ids, slot = _window_cells(a, q_pos, ok, tables, geo)
-    k_c = k_c.at[page_ids, slot].set(_fused(k))
-    v_c = v_c.at[page_ids, slot].set(_fused(v))
+    if k is not None:
+        k_c = k_c.at[page_ids, slot].set(_fused(k))
+        v_c = v_c.at[page_ids, slot].set(_fused(v))
+    if q is None:
+        return k_c, v_c, None
     p_hi = jnp.max(jnp.where(ok, q_pos, -1), axis=1)             # [B]
     if kernels:
         o = paged_attention.paged_grouped_attention(
@@ -506,8 +540,11 @@ def _grouped_work(a, live, itemsize):
     row of a slot once: what the paged kernel has to move; a window layer
     those of its ring, and the rows it would read were it sized like a full
     one) and the (query, key) pairs it multiplies, full and window layers
-    apart. A full layer's rows in bytes (K and V at ``itemsize``) are what
-    ``serve_stats()["state"]`` sets beside the state-space layers' bytes."""
+    apart. A layer that attends ANOTHER layer's pages (``kv_from``) counts
+    its rows as ``kv_shared_rows`` (K/V rows read by a layer that owns none)
+    and its pairs with the full layers'. A full or sharing layer's rows in
+    bytes (K and V at ``itemsize``) are what ``serve_stats()["state"]`` sets
+    beside the recurrent layers' bytes."""
     rows = live.max(axis=1, initial=0)      # a slot's live rows, read once
     found = dict.fromkeys(("kv_full_rows", "kv_window_rows",
                            "kv_window_rows_as_full", "qk_full_pairs",
@@ -519,7 +556,9 @@ def _grouped_work(a, live, itemsize):
             kv_window_rows_as_full=rows.sum(),
             qk_window_pairs=np.minimum(live, a.window).sum())
         return {"attn": found}
-    found.update(kv_full_rows=rows.sum(), qk_full_pairs=live.sum())
+    found.update(qk_full_pairs=live.sum())
+    found["kv_full_rows" if a.kv_from is None
+          else "kv_shared_rows"] = rows.sum()
     return {"attn": found,
             "state": {"kv_bytes": rows.sum() * 2 * a.kv_width * itemsize}}
 
@@ -569,7 +608,8 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False):
         tail, state = tail_c[rows], state_c[rows]
     tail = jnp.where(begins[:, None, None], 0, tail)
     if not in_place:
-        state = jnp.where(begins[:, None, None, None], 0, state)
+        state = jnp.where(
+            begins[(slice(None),) + (None,) * (state_c.ndim - 1)], 0, state)
         if kernels and not one_query:       # the rows enter the kernel
             recur, state = functools.partial(
                 pallas_kda.kda_chunk_scan, state=state,
@@ -581,7 +621,8 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False):
         return tail_c.at[rows].set(tail), state_c.at[rows].set(state), out
     tail_c = jax.lax.dynamic_update_slice(tail_c, tail, (1, 0, 0))
     if not in_place:
-        state = jax.lax.dynamic_update_slice(state_c, state, (1, 0, 0, 0))
+        state = jax.lax.dynamic_update_slice(
+            state_c, state, (1,) + (0,) * (state_c.ndim - 1))
     return tail_c, state, out
 
 
@@ -611,6 +652,15 @@ def _linear_work(a, live, itemsize, kernels):
     return {"state": {"delta_" + name: n for name, n in counted.items()}}
 
 
+def _scan_work(a, live, itemsize):
+    """What ONE selective-scan layer does in a call: :func:`_state_work`'s
+    four counts of its own rows (tail, float32 ``[state, channel]`` state),
+    under names of their own in ``serve_stats()["state"]``: ``scan_rows``,
+    ``scan_bytes``, ``scan_tokens``, ``scan_resets``."""
+    counted = _state_work(a, live, itemsize)["state"]
+    return {"state": {"scan_" + name: n for name, n in counted.items()}}
+
+
 # The counters a family always has, whatever kinds the model's layers are
 # (``calls`` too, and ``queries`` in ``attn``).
 _ALWAYS_COUNTED = {"attn": ("kv_scored", "kv_selected", "kv_window"),
@@ -618,19 +668,32 @@ _ALWAYS_COUNTED = {"attn": ("kv_scored", "kv_selected", "kv_window"),
 
 
 def work(cfg, geo, mesh):
-    """-> ``count(live) -> {"attn": {counter: n}, "state": {counter: n}}``:
-    what the layers of a described kind do in ONE call of a chunk, decode or
-    spec program whose queries see ``live [slots, queries]`` keys each, by
-    host arithmetic on the positions alone (nothing is fetched): the work
-    functions beside the layer functions, summed over the model's layers,
-    with ``queries`` and ``calls`` once a call. A family the model has no
-    layer for is absent (``attn``: latent and described multi-head kinds;
-    ``state``: state-space and delta-rule kinds). ``ServeLoop`` tallies the
-    result by program kind; the benchmark's roofline shares read the
-    tallies."""
-    kinds = collections.Counter(
-        cfg.attn_of(li) for li in range(cfg.n_layers) if cfg.has_mixer(li))
-    kinds.pop(None, None)
+    """-> ``count(live, ends=None) -> {"attn": {counter: n}, "state":
+    {counter: n}}``: what the layers of a described kind do in ONE call of a
+    chunk, decode or spec program whose queries see ``live [slots, queries]``
+    keys each, by host arithmetic on the positions alone (nothing is
+    fetched): the work functions beside the layer functions, summed over the
+    model's layers, with ``queries`` and ``calls`` once a call. A family the
+    model has no layer for is absent (``attn``: latent and described
+    multi-head kinds; ``state``: the recurrent kinds). ``ServeLoop`` tallies
+    the result by program kind; the benchmark's roofline shares read the
+    tallies.
+
+    ``ends`` says what a FILL program is to a model whose fill leaves the
+    stack (:func:`fill_exit`; ignored for any other): False, a chunk that
+    ends no prompt (the layers from the exit up see nothing); True, the one
+    that ends it (they see each slot's last query); None, a program that
+    runs the whole stack on every position (the decode step). Such a model
+    also counts ``fill_rows`` and ``tail_rows``: the positions that went
+    through the layers below the exit and through those from it up."""
+    leaves = fill_exit(cfg)
+    below, above = collections.Counter(), collections.Counter()
+    for li in range(cfg.n_layers):
+        a = cfg.attn_of(li)
+        if cfg.has_mixer(li) and a is not None \
+                and not isinstance(a, tfm.GatedMemoryUnit):
+            (below if leaves is None or li < leaves else above)[a] += 1
+    kinds = set(below) | set(above)
     families = [family for family, classes in (
         ("attn", (tfm.LatentAttention, tfm.MultiHeadAttention)),
         ("state", tfm.RECURRENT))
@@ -639,7 +702,18 @@ def work(cfg, geo, mesh):
     linear = linear_kernels(cfg, geo, mesh)
     itemsize = cfg.compute_dtype.itemsize
 
-    def count(live):
+    def one(a, live):
+        if isinstance(a, tfm.StateSpaceMixer):
+            return _state_work(a, live, itemsize)
+        if isinstance(a, tfm.DeltaRuleMixer):
+            return _linear_work(a, live, itemsize, linear)
+        if isinstance(a, tfm.SelectiveScanMixer):
+            return _scan_work(a, live, itemsize)
+        if isinstance(a, tfm.MultiHeadAttention):
+            return _grouped_work(a, live, itemsize)
+        return _latent_work(a, live, latent)
+
+    def count(live, ends=None):
         if not families:
             return {}
         live = np.asarray(live, np.int64)
@@ -647,26 +721,32 @@ def work(cfg, geo, mesh):
                  | {"calls": 1} for family in families}
         if "attn" in found:
             found["attn"]["queries"] = live.size
-        for a, layers in kinds.items():
-            if isinstance(a, tfm.StateSpaceMixer):
-                mine = _state_work(a, live, itemsize)
-            elif isinstance(a, tfm.DeltaRuleMixer):
-                mine = _linear_work(a, live, itemsize, linear)
-            elif isinstance(a, tfm.MultiHeadAttention):
-                mine = _grouped_work(a, live, itemsize)
-            else:
-                mine = _latent_work(a, live, latent)
-            for family in mine.keys() & found.keys():
-                for name, n in mine[family].items():
-                    found[family][name] = (found[family].get(name, 0)
-                                           + layers * int(n))
+        seen = [(below, live)]
+        if leaves is not None:
+            top = live if ends is None else live[:, live.shape[1] - 1:] \
+                if ends else live[:, :0]
+            seen.append((above, top))
+            found["attn"].update(kv_shared_rows=0, fill_rows=live.size,
+                                 tail_rows=top.size)
+        for layers_of, sees in seen:
+            for a, layers in layers_of.items():
+                mine = one(a, sees)
+                for family in mine.keys() & found.keys():
+                    for name, n in mine[family].items():
+                        found[family][name] = (found[family].get(name, 0)
+                                               + layers * int(n))
         return found
 
     return count
 
 
+# The gate of :func:`_kernels` that a recurrent kind's kernel answers to (a
+# kind with no entry has no kernel).
+_KERNEL_OF = {tfm.StateSpaceMixer: "state", tfm.DeltaRuleMixer: "linear"}
+
+
 def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
-            window=None, geo=None, kernels=None):
+            window=None, geo=None, kernels=None, ends=None):
     """Every layer of the model over ``x [B, S, D]`` through
     ``transformer.block``, the one block definition, with the serving
     attention. ``positions [B, S]`` and ``valid [B, S]`` are the block's own
@@ -687,36 +767,82 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
     multi-head layer of a described kind through :func:`_grouped_layer`, a
     state-space or delta-rule layer through :func:`_state_layer` (each looked
     up through this module when the program is traced); a layer with no mixer
-    has no cache and attends nothing. ->
+    has no cache and attends nothing. A multi-head layer that names another's
+    keys and values (``kv_from``) attends THAT layer's arrays and writes
+    nothing; a selective-scan layer's memory is kept for the gated memory
+    units that name it.
+
+    ``ends`` (a fill program of a model whose fill leaves the stack,
+    :func:`fill_exit`; None = the whole stack on every position): at the exit
+    layer the window's keys and values are written, and then False returns
+    ``x`` None (no layer above is touched: the program has none of their
+    weights), True runs everything from that layer's queries up on each slot's
+    LAST live row, so ``x`` comes out ``[B, 1, D]``. ->
     (ck, cv, x after the final norm, what the layers report or None:
     ``counts``, ``rows`` and ``top`` of the expert layers, ``selected`` of the
     selecting ones, each stacked over those layers)."""
     ck, cv = list(cache["k"]), list(cache["v"])
-    reports = []
+    reports, memory = [], {}
+    leaves = None if ends is None else fill_exit(cfg)
     for li, layer in enumerate(params["layers"]):
         a = cfg.attn_of(li)
+        if li == leaves:
+            # The fill leaves the stack here: this layer's keys and values of
+            # the whole window go to its pages; what is above runs on each
+            # slot's last live row, or (a chunk that ends no prompt) not at
+            # all.
+            with jax.named_scope(tfm.scopes.ATTENTION):
+                k, v = tfm.project_kv(tfm._norm(x, layer["ln1"], cfg), layer,
+                                      cfg, a, positions)
+            ck[li], cv[li], _ = _grouped_layer(
+                a.attended, None, k, v, ck[li], cv[li], **window, geo=geo,
+                dt=cfg.compute_dtype, kernels=kernels["grouped"])
+            if not ends:
+                return ck, cv, None, None
+            at = jnp.maximum(jnp.sum(window["ok"], 1) - 1, 0)[:, None]
+
+            def last(v, at=at):
+                return jnp.take_along_axis(
+                    v, at.reshape(at.shape + (1,) * (v.ndim - 2)), axis=1)
+
+            x, positions, valid = last(x), last(positions), last(valid)
+            memory = {src: last(m) for src, m in memory.items()}
+            window = dict(window, q_pos=last(window["q_pos"]),
+                          ok=jnp.any(window["ok"], 1, keepdims=True))
         if not cfg.has_mixer(li):
             write_and_attend = None
         elif isinstance(a, tfm.RECURRENT):
             def write_and_attend(mix, li=li, a=a):
                 # ``kernels=`` only where the kernel runs: elsewhere the
                 # call is ``(mix, tail_c, state_c, q_pos, ok, tables)``.
-                kind = ("state" if isinstance(a, tfm.StateSpaceMixer)
-                        else "linear")
-                flag = {"kernels": True} if kernels[kind] else {}
+                kind = _KERNEL_OF.get(type(a))
+                flag = {"kernels": True} if kernels.get(kind) else {}
                 ck[li], cv[li], out = _state_layer(mix, ck[li], cv[li],
                                                    **window, **flag)
+                if cfg.hands_memory(li):
+                    out, memory[li] = out
                 return out
+        elif isinstance(a, tfm.GatedMemoryUnit):
+            def write_and_attend(mix, a=a):
+                return mix(memory[a.memory_from])
         elif a is None:
             def write_and_attend(q, k, v, li=li):
                 ck[li], kk = write(ck[li], k)
                 cv[li], vv = write(cv[li], v)
                 return attend(q, kk, vv)
         elif isinstance(a, tfm.MultiHeadAttention):
-            def write_and_attend(q, k, v, li=li, a=a):
-                ck[li], cv[li], o = _grouped_layer(
-                    a, q, k, v, ck[li], cv[li], **window, geo=geo,
+            def write_and_attend(q, k, v, li=li, a=a, written=li == leaves):
+                # Whose pages: the layer's own, or the layer's it names,
+                # which are read and not written (as the layer's own are on
+                # a fill's last row: they were written above).
+                own = a.kv_from is None
+                src = li if own else a.kv_from
+                k_c, v_c, o = _grouped_layer(
+                    a.attended, q, *((None, None) if written else (k, v)),
+                    ck[src], cv[src], **window, geo=geo,
                     dt=cfg.compute_dtype, kernels=kernels["grouped"])
+                if own:
+                    ck[li], cv[li] = k_c, v_c
                 return o
         else:
             def write_and_attend(*operands, li=li, a=a):
@@ -746,7 +872,8 @@ def _result(ck, cv, logits, moe, mesh, cfg):
     ``"selected": [selecting layers, B, S, k]``. Every entry of the routing
     but ``counts`` is ``[layers, slot, position, ..]``: the benchmark's check
     indexes them so, which is why the rows travel beside the dict."""
-    out = (_cache_out(ck, cv, mesh, cfg), logits.astype(jnp.float32))
+    out = (_cache_out(ck, cv, mesh, cfg),
+           None if logits is None else logits.astype(jnp.float32))
     if moe is None:
         return out
     rows = moe.pop("rows", None)
@@ -875,7 +1002,7 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
 
 
 def _chunk_forward(params, cache, tokens, positions, block_tables,
-                   active, *, cfg, geo, mesh, kernels=None):
+                   active, *, cfg, geo, mesh, kernels=None, ends=None):
     """Shared body for every multi-token paged step: embed a [B, Q]
     token window starting at each slot's ``positions[b]``, scatter its
     K/V through the block tables, attend over the gathered pages under
@@ -910,10 +1037,11 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
     return _layers(params, cache, x, pos, write,
                    _masked(cfg, kv_mask[:, None, :, :]), valid, cfg=cfg,
                    mesh=mesh, window=dict(q_pos=pos, ok=valid, tables=tables),
-                   geo=geo, kernels=kernels)
+                   geo=geo, kernels=kernels, ends=ends)
 
 
-def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
+def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk",
+                    ends=None):
     """Compiled ``(params, cache, tokens, positions, block_tables,
     active) -> (cache, logits)`` — a ``q_len``-token window for every
     slot, the generalization of :func:`make_decode_step` to q_len > 1.
@@ -949,8 +1077,19 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
     branch-free. For a model whose layers carry a state a negative token id
     marks padding: that position and every one behind it is dead (its K/V go
     to the trash page and it advances no state).
+
+    ``ends`` is for a model whose fill leaves the stack (:func:`fill_exit`;
+    it must stay None for any other): False compiles the chunk that ENDS NO
+    PROMPT, which runs the layers below the exit and the exit layer's
+    key/value write, holds no weight above it and returns ``(cache, None)``;
+    True the chunk that ends one: the same, then everything above on each
+    slot's last live row, logits ``[B, 1, vocab]``. None is the whole stack
+    on every position, as for every other model.
     """
     _check_gathers(cfg, geo, mesh)
+    if ends is not None and fill_exit(cfg) is None:
+        raise ValueError("ends: this model's fill runs the whole stack "
+                         "(engine.fill_exit is None)")
     kernels = _kernels(cfg, geo, mesh)
     q_len = geo.page_size if q_len is None else int(q_len)
     if q_len < 1:
@@ -960,8 +1099,9 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk"):
     def chunk(params, cache, tokens, positions, block_tables, active):
         ck, cv, x, moe = _chunk_forward(params, cache, tokens, positions,
                                         block_tables, active, cfg=cfg,
-                                        geo=geo, mesh=mesh, kernels=kernels)
-        logits = tfm.head_logits(x, params, cfg)
+                                        geo=geo, mesh=mesh, kernels=kernels,
+                                        ends=ends)
+        logits = None if x is None else tfm.head_logits(x, params, cfg)
         return _result(ck, cv, logits, moe, mesh, cfg)
 
     chunk.__name__ = chunk.__qualname__ = name

@@ -71,8 +71,17 @@ whatever its slot's last request left: the programs zero it when a request's
 first position arrives (``engine._state_layer``), which is also what makes a
 preempted request's replay start clean. State cannot be shared by page, so a
 model with such layers has no prefix cache, and it cannot be rolled back, so
-no speculation (``ServeLoop`` refuses both). A layer with NO mixer
-(``layer_parts[i] == "ffn"``) has no cache: both entries are None.
+no speculation (``ServeLoop`` refuses both). A SELECTIVE-SCAN layer
+(``TransformerConfig.selective_scan``) holds its rows the same way: the tail
+``[state_rows, conv_kernel - 1, d_inner]`` and the state ``[state_rows,
+state_size, d_inner]`` in float32 (state-major: the channels are the lanes). A
+layer with NO mixer (``layer_parts[i] == "ffn"``) has no cache: both entries
+are None. So are those of a multi-head layer that attends ANOTHER layer's keys
+and values (``MultiHeadAttention.kv_from``: it reads that layer's pages, which
+are held, and counted by :func:`cache_bytes`, once) and of a gated memory unit
+(``TransformerConfig.gated_memory``: what it reads is an activation of the
+same program run, never cached). A DIFFERENTIAL kind's rows are its key/value
+heads fused, as any kind's: its attention reads them as pairs.
 
 Tensor-parallel layout: the fused ``n_heads * head_dim`` dimension rides
 the mesh's ``model`` axis. Heads are its major part, so a shard of it is
@@ -90,7 +99,8 @@ import math
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.transformer import RECURRENT, MultiHeadAttention
+from ..models.transformer import (RECURRENT, GatedMemoryUnit,
+                                  MultiHeadAttention)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,10 +163,19 @@ def spec(cfg):
     return P(None, None, cfg.model_axis)
 
 
+def owns_cache(cfg, li):
+    """Whether layer ``li`` holds anything between two program runs: not a
+    layer with no mixer, not a gated memory unit, not a layer that attends
+    another layer's keys and values."""
+    a = cfg.attn_of(li)
+    return not (not cfg.has_mixer(li) or isinstance(a, GatedMemoryUnit)
+                or getattr(a, "kv_from", None) is not None)
+
+
 def layer_shapes(cfg, geo, li):
     """Shapes of layer ``li``'s ``("k", "v")`` arrays; None = no array."""
     a = cfg.attn_of(li)
-    if not cfg.has_mixer(li):
+    if not owns_cache(cfg, li):
         return None, None
     if isinstance(a, RECURRENT):
         if not geo.state_rows:

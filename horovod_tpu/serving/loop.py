@@ -222,6 +222,10 @@ class ServeLoop:
     than ``PADDED_PREFILL_MAX_KV`` gets ``prefill_fn`` and ``bprefill_fn``
     (padded to ``max_kv``) and chunk-fills only prefix-cache suffixes; a
     wider one gets neither (both are None) and chunk-fills every prompt.
+    ``chunk_end_fn`` is None but for a model whose fill leaves the stack
+    part-way up (``engine.fill_exit``): its ``chunk_fn`` holds no layer above
+    the exit and returns no logits, and ``chunk_end_fn`` runs the chunk that
+    ends a prompt.
     """
 
     def __init__(self, params, cfg, geo=None, mesh=None,
@@ -273,9 +277,23 @@ class ServeLoop:
         self.bprefill_fn = (engine.make_batched_prefill(cfg, geo, mesh)
                             if padded and batch_prefill
                             and self.max_batch > 1 else None)
-        self.chunk_fn = (engine.make_chunk_step(
-            cfg, geo, mesh, q_len=self.prefill_chunk)
-            if use_prefix or not padded else None)
+        # A model may say that its fill leaves the stack part-way up
+        # (``engine.fill_exit``): the chunk that ends no prompt is then a
+        # program of its own, with no layer above the exit and no logits, and
+        # the chunk that ends one (``chunk_end_fn``) returns its slot's one
+        # row. Every other model has the one chunk program.
+        self.fill_exit = engine.fill_exit(cfg)
+
+        def chunk_step(ends):
+            return engine.make_chunk_step(cfg, geo, mesh,
+                                          q_len=self.prefill_chunk, ends=ends)
+
+        self.chunk_fn = self.chunk_end_fn = None
+        if self.fill_exit is not None:
+            self.chunk_fn, self.chunk_end_fn = (chunk_step(False),
+                                                chunk_step(True))
+        elif use_prefix or not padded:
+            self.chunk_fn = chunk_step(None)
         self.spec_fn = (engine.make_chunk_step(
             cfg, geo, mesh, q_len=self.spec_tokens + 1, name="spec")
             if self.spec_tokens > 0 else None)
@@ -386,11 +404,12 @@ class ServeLoop:
         if family == "attn" and _metrics.enabled():
             _metrics.SERVE_KV_SELECT_SHARE.set(self._kv_select_share())
 
-    def _count(self, kind, live):
+    def _count(self, kind, live, ends=None):
         """One program call whose queries see ``live [slots, queries]`` keys
         each (their positions + 1; a slot's queries are consecutive): what
-        the engine says its layers did (``engine.work``)."""
-        for family, found in self._work(live).items():
+        the engine says its layers did (``engine.work``; ``ends``: whether a
+        fill's chunk ends its prompt, None for any other program)."""
+        for family, found in self._work(live, ends).items():
             self._add(family, kind, found)
 
     def _kv_select_share(self):
@@ -427,7 +446,11 @@ class ServeLoop:
             self._fetch(self._call("bprefill", self.bprefill_fn, toks,
                                    np.ones(B, np.int32), tables, active))
         if self.chunk_fn is not None:
-            self._fetch(self._call("chunk", self.chunk_fn,
+            if self.chunk_end_fn is not None:
+                self._call("chunk", self.chunk_fn,
+                           *slots(1, self.prefill_chunk), fetch=False)
+            self._fetch(self._call("chunk",
+                                   self.chunk_end_fn or self.chunk_fn,
                                    *slots(1, self.prefill_chunk)))
         if self.spec_fn is not None:
             self._fetch(self._call("spec", self.spec_fn,
@@ -504,18 +527,23 @@ class ServeLoop:
             bt = np.asarray(
                 self.batcher.block_table(req, self.geo.max_blocks),
                 np.int32)[None]
-            self._count("chunk", np.arange(filled, end)[None] + 1)
+            last = end >= target
+            self._count("chunk", np.arange(filled, end)[None] + 1, ends=last)
         with self._span("serve.chunk.dispatch", rid=req.rid, start=filled,
                         end=end, target=target):
-            step = self._call("chunk", self.chunk_fn, toks,
-                              np.asarray([filled], np.int32), bt,
-                              np.ones(1, bool), fetch=end >= target)
+            step = self._call("chunk",
+                              last and self.chunk_end_fn or self.chunk_fn,
+                              toks, np.asarray([filled], np.int32), bt,
+                              np.ones(1, bool), fetch=last)
         self.loop_stats["chunk_fills"] += 1
         if step is None:
             self._fills[req.rid] = (req.admit_seq, end)
             return None
         self._fills.pop(req.rid, None)
-        step.owners = {req.slot: (req, req.admit_seq, (0, end - 1 - filled))}
+        # The request's next token: the last real position's row, which is
+        # the only row of a fill that left the stack.
+        row = 0 if self.fill_exit is not None else end - 1 - filled
+        step.owners = {req.slot: (req, req.admit_seq, (0, row))}
         return step
 
     def _decode(self, ready, after=None):

@@ -344,6 +344,24 @@ def test_program_names_the_trace_readers_depend_on():
     assert module(loop.chunk_fn, params, cache, i32(1, loop.prefill_chunk),
                   i32(1), i32(1, mb),
                   jax.ShapeDtypeStruct((1,), jnp.bool_)) == "jit_chunk"
+    # A model whose fill leaves the stack has two fill programs: both are
+    # ``jit_chunk`` to a reader of the trace (the fill's device time is
+    # theirs together).
+    head = dict(n_heads=4, n_kv_heads=2, head_dim=16, rope_share=0.0)
+    early = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
+        max_seq_len=64, pos="none", layer_attn=("full", "cross"),
+        multihead={"full": head, "cross": dict(head, kv_from=0)})
+    wide = kv_cache.with_rings(geo, early, 8, B)
+    early_args = (
+        jax.eval_shape(lambda key: tfm.init_params(key, early),
+                       jax.random.PRNGKey(0)),
+        jax.eval_shape(lambda: kv_cache.make_cache(early, wide, None)),
+        i32(1, 8), i32(1), i32(1, wide.table_width),
+        jax.ShapeDtypeStruct((1,), jnp.bool_))
+    for ends in (False, True):
+        assert module(engine.make_chunk_step(early, wide, q_len=8, ends=ends),
+                      *early_args) == "jit_chunk"
     tx = optax.sgd(0.1)
     step = make_train_step(lambda p, b: tfm.loss_fn(p, b, cfg), tx,
                            Mesh(np.array(jax.devices()[:1]), ("data",)))

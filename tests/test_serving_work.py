@@ -30,6 +30,8 @@ KINDS = {
     "state-space": ("state_space", tfm.StateSpaceMixer(
         n_heads=8, head_dim=8, n_groups=2, state_size=16)),
     "delta-rule": ("delta_rule", tfm.DeltaRuleMixer(n_heads=4, head_dim=8)),
+    "selective-scan": ("selective_scan", tfm.SelectiveScanMixer(
+        d_inner=16, dt_rank=2, state_size=4)),
 }
 # One chunk of 8 queries at positions 8..15 of one slot; one decode step over
 # three slots at positions 20, 3 and 0 (the last begins its sequence).
@@ -80,6 +82,15 @@ WANT = {
                                  delta_tokens=9, delta_resets=3,
                                  delta_kernel_calls=0, kv_bytes=0,
                                  calls=1)}},
+    # Hand-counted: a row is a tail of 3 x 16 float32 and a state of 4 x 16
+    # float32, 448 bytes, read and written back; three layers.
+    "selective-scan": {
+        "chunk": {"state": dict(scan_rows=3, scan_bytes=3 * 2 * 448,
+                                scan_tokens=24, scan_resets=0, kv_bytes=0,
+                                calls=1)},
+        "decode": {"state": dict(scan_rows=9, scan_bytes=9 * 2 * 448,
+                                 scan_tokens=9, scan_resets=3, kv_bytes=0,
+                                 calls=1)}},
 }
 
 
@@ -124,6 +135,44 @@ def test_the_delta_kernel_is_counted_where_it_runs(monkeypatch, program,
     want = dict(WANT["delta-rule"][program]["state"],
                 delta_kernel_calls=calls)
     assert count(LIVE[program]) == {"state": want}
+
+
+@pytest.mark.parametrize("ends, tail", [(None, 8), (False, 0), (True, 1)])
+def test_work_of_a_fill_that_leaves_the_stack(ends, tail):
+    """A scan, a window layer, a full layer whose pages a later layer
+    attends, a gated memory unit, that later layer: the fill leaves the stack
+    at the full layer (``engine.fill_exit``). A program that runs the whole
+    stack (``ends`` None) counts both attention layers over every query; a
+    chunk that ends no prompt counts nothing from the exit up, the one that
+    ends it the slot's last query there; ``fill_rows`` and ``tail_rows`` say
+    which it was. Hand-counted on a chunk of 8 queries at positions 8..15."""
+    head = dict(n_heads=4, n_kv_heads=2, head_dim=16, rope_share=0.0,
+                differential=True, bias=True)
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_layers=5, d_ff=64,
+        max_seq_len=256, dtype="float32", pos="none",
+        layer_attn=("scan", "window", "full", "gmu", "cross"),
+        selective_scan={"scan": dict(d_inner=16, dt_rank=2, state_size=4)},
+        gated_memory={"gmu": dict(d_inner=16, memory_from=0)},
+        multihead={"window": dict(head, window=5), "full": head,
+                   "cross": dict(head, kv_from=2)})
+    assert engine.fill_exit(cfg) == 2
+    count = engine.work(cfg, kv_cache.geometry(64, 4, 128), None)
+    live = LIVE["chunk"]
+    rows = 16 if tail else 0               # the slot's live rows, once
+    pairs = {8: 100, 1: 16, 0: 0}[tail]    # every live key of every query
+    assert count(live, ends) == {
+        "attn": dict(BASE, queries=8, fill_rows=8, tail_rows=tail,
+                     kv_full_rows=rows, kv_shared_rows=rows,
+                     qk_full_pairs=2 * pairs, kv_window_rows=12,
+                     kv_window_rows_as_full=16, qk_window_pairs=40),
+        "state": dict(scan_rows=1, scan_bytes=2 * 448, scan_tokens=8,
+                      scan_resets=0, calls=1,
+                      kv_bytes=2 * rows * 2 * 32 * 4)}
+    # ``ends`` means nothing to a model whose fill runs the whole stack.
+    plain = engine.work(_config("multihead-full"),
+                        kv_cache.geometry(64, 4, 128), None)
+    assert plain(live, ends) == plain(live) == WANT["multihead-full"]["chunk"]
 
 
 def test_work_of_a_plain_model_is_nothing():

@@ -84,7 +84,10 @@ def test_names_are_spelled_once():
     assert scopes.PHASES == ("grad", "grad_reduce", "optimizer")
     assert scopes.SCOPES == ("embed", "layer_norm", "rms_norm", "attention",
                              "mlp", "experts", "loss", "head", "state_space",
-                             "expert_latent", "linear_attention")
+                             "expert_latent", "linear_attention",
+                             "gated_memory")
+    assert scopes.parse("jit(decode)/gated_memory/dot_general") == (
+        None, "gated_memory", False)
     assert not set(scopes.PHASES) & set(scopes.SCOPES)
 
 

@@ -940,3 +940,119 @@ def test_linear_cell_programs_fit_one_chip(topo, as_on_the_chip):
             assert len(scans) == n_linear
             # Nothing walks the 2,048 positions one by one.
             assert not re.search(r"f32\[2048,1,64,128(,128)?\]", text)
+
+
+def test_sambay_cell_programs_fit_one_chip(topo, as_on_the_chip):
+    """``phi4flash-serve-think-over``'s three programs (the 512-token chunk
+    that ends no prompt, the one that ends one, the decode step of 32 slots)
+    at the cell's geometry, the configuration UNCUT: nine selective-scan
+    layers on slot-owned rows (float32 ``[16, 5120]`` a slot), eight
+    differential window layers on rings, ONE full layer on pages of a
+    32,768-token context that seven more layers read, seven gated memory
+    units, a vocabulary of 200,064. Weights + cache + temporaries stay on the
+    chip; the cache is aliased through and nothing holds a second copy of the
+    shared layer's pages or of a layer's state; the chunk that ends no prompt
+    takes NO parameter above the exit layer and returns no logits, and
+    neither chunk makes ``[512, vocab]`` logits or a ``[512, 16, 5120]``
+    float32 history of the state; nothing is as wide as the context; the
+    decode step reads the shared pages through the paged kernel eight times
+    and the rings eight."""
+    import importlib.util
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "serve_sambay", os.path.join(root, "benchmark", "runners",
+                                     "serve_sambay.py"))
+    runner = importlib.util.module_from_spec(spec)
+    sys.path.insert(0, root)
+    try:
+        spec.loader.exec_module(runner)
+        cfg = runner.model_config(config)
+    finally:
+        sys.path.remove(root)
+    srv = config["assumed"]["serve"]
+    B, chunk = srv["max_batch"], srv["chunk"]
+    geo = kv_cache.with_rings(
+        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
+        cfg, chunk, B)
+    assert (geo.max_kv, geo.ring_blocks, geo.ring_pages, geo.state_rows,
+            geo.table_width, B, chunk) == (32768, 64, 2049, 33, 2113, 32, 512)
+    leaves = engine.fill_exit(cfg)
+    assert leaves == config["kv_from"] == 17
+    assert engine.grouped_kernels(cfg, geo, None)
+    params, cache = jax.tree.map(
+        lambda x: _on_chip(topo, x.shape, x.dtype),
+        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
+                                kv_cache.make_cache(cfg, geo))))
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert 3.85e9 < n_params < 3.855e9          # the file's reduced_why
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert kv_cache.cache_bytes(cfg, geo) == held - 2 * n_params
+    assert 14.4e9 < held < 14.6e9          # 86 % of the chip's 16.91e9
+    pages = 2 * geo.n_pages * geo.page_size * 1280     # the shared K or V
+    state = 4 * B * 16 * 5120              # one layer's slots in float32
+    n_above = len(jax.tree.leaves(params["layers"][leaves + 1:]))
+
+    def slots(b, *q):
+        return [_on_chip(topo, s, d) for s, d in (
+            ((b, *q), jnp.int32), ((b,), jnp.int32),
+            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
+
+    for name, fn, args in (
+            ("chunk", engine.make_chunk_step(cfg, geo, q_len=chunk,
+                                             ends=False), slots(1, chunk)),
+            ("chunk_end", engine.make_chunk_step(cfg, geo, q_len=chunk,
+                                                 ends=True), slots(1, chunk)),
+            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
+             slots(B))):
+        lowered = fn.lower(params, cache, *args)
+        compiled = lowered.compile()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= kv_cache.cache_bytes(cfg, geo)
+        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
+        assert held + memory.temp_size_in_bytes + fresh < 0.97 * 16.91e9, (
+            name, memory.temp_size_in_bytes)
+        # No second copy of the shared pages, whoever reads them.
+        assert memory.temp_size_in_bytes < pages, name
+        text = compiled.as_text()
+
+        def kernel_calls(kernel):
+            return len([line for line in text.splitlines()
+                        if re.match(rf"\s*%{kernel}[.\d]* = ", line)
+                        and "tpu_custom_call" in line])
+
+        # Nothing as wide as the context (no scores [.., max_kv], no gathered
+        # pages), no history of the state over the window's positions, no
+        # logits of every position.
+        assert not re.search(r"(f32|bf16)\[[\d,]*32768[\d,]*\]", text), name
+        assert not re.search(r"f32\[(\d+,)*512,(\d+,)*(16,5120|5120,16)\]",
+                             text), name
+        assert not re.search(r"\[(1,)?512,200064\]", text), name
+        n_args = len(jax.tree.leaves(lowered.args_info))
+        kept = text[text.index("\nENTRY "):].count(" parameter(")
+        if name == "chunk":
+            # The fill leaves the stack: no weight above the exit layer is an
+            # argument of the compiled program (a gated memory unit's first
+            # matrix is the one [2560, 5120] in the model), and there are no
+            # logits.
+            assert kept <= n_args - n_above - 2, (kept, n_args, n_above)
+            assert "bf16[2560,5120]" not in text
+            assert fresh < 4096, name          # a tuple's pointers
+            assert kernel_calls("paged_full_attention") == 0
+            assert kernel_calls("paged_window_attention") == 8
+            assert not re.search(r",200064\]", text)
+        elif name == "chunk_end":
+            assert kept == n_args and "bf16[2560,5120]" in text
+            assert kernel_calls("paged_full_attention") == 8
+            assert kernel_calls("paged_window_attention") == 8
+        else:
+            assert kernel_calls("paged_full_attention") == 8
+            assert kernel_calls("paged_window_attention") == 8
+            # A second copy of a layer's slots would be this large.
+            assert memory.temp_size_in_bytes < 8 * state + 2 * B * 200064 * 4
+        print(name, memory.temp_size_in_bytes, fresh, kept, n_args)

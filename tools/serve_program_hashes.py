@@ -2,8 +2,9 @@
 
 A refactor of ``serving/engine.py`` or ``serving/loop.py`` that is meant to
 change no program's instructions runs this on both trees and compares the
-two outputs: ``jit_fn.lower(...).as_text()`` of ``decode_fn``, ``chunk_fn``,
-``spec_fn`` and, where built, ``prefill_fn`` and ``bprefill_fn``, with the
+two outputs: ``jit_fn.lower(...).as_text()`` of ``decode_fn``, ``chunk_fn`` (and
+``chunk_end_fn`` where it is a program of its own), ``spec_fn`` and, where
+built, ``prefill_fn`` and ``bprefill_fn``, with the
 arguments ``ServeLoop.warmup`` hands them. Nothing is compiled or run.
 Each program gets two hashes: ``text`` of the lowered text, ``cse`` of the
 same module after MLIR's common-subexpression pass, which is what stays equal
@@ -43,7 +44,8 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "tests")]
 
 # cell's configuration -> its runner (``benchmark/configs/<name>.json``).
 CELLS = ("gpt2-large", "olmoe-1b-7b", "dots3-note-prev", "laguna-s-2.1",
-         "nemotron-3-super-120b", "sarvam-105b")
+         "nemotron-3-super-120b", "sarvam-105b", "solar-open2-250b",
+         "phi-4-mini-flash-reasoning")
 
 
 def _sha(text):
@@ -94,6 +96,10 @@ def _programs(loop, like):
     calls = {
         "decode": (loop.decode_fn, slots(B)),
         "chunk": (loop.chunk_fn, slots(1, loop.prefill_chunk)),
+        # A program of its own only where the model's fill leaves the stack
+        # (and an attribute only since the loop has such models).
+        "chunk_end": (getattr(loop, "chunk_end_fn", None),
+                      slots(1, loop.prefill_chunk)),
         "spec": (loop.spec_fn, slots(B, loop.spec_tokens + 1)),
         "prefill": (loop.prefill_fn, [like((geo.max_kv,), np.int32),
                                       like((), np.int32),
