@@ -479,13 +479,15 @@ class TransformerConfig:
     # Q/K block size of the flash kernel (perf knob; clipped to the seq
     # len and auto-shrunk to a divisor by the kernel).
     attn_block: int = 512
-    # >0: the loss computes vocab logits + log-softmax in sequence chunks of
-    # this many positions, each chunk's gradients in the same trip of one
-    # scan (_chunked_nll), so the [S, vocab] float32 tensor never exists —
-    # at S=8k x 30k vocab that tensor plus its backward temps is gigabytes
-    # and caps single-chip sequence length before attention does. What the
-    # backward pass is handed instead: d(hidden) [B, S, d] and one float32
-    # [vocab, d]. 0 = single full-sequence projection.
+    # The loss computes vocab logits + log-softmax in sequence chunks, each
+    # chunk's gradients in the same trip of one scan (_chunked_nll), so the
+    # [S, vocab] float32 tensor never exists — at S=8k x 30k vocab that
+    # tensor plus its backward temps is gigabytes and caps single-chip
+    # sequence length before attention does. What the backward pass is
+    # handed instead: d(hidden) [B, S, d] and one float32 [vocab, d].
+    # 0 = the program picks the chunk from the shapes it sees
+    # (_loss_positions); >0 = this many positions, a cap a caller sets for
+    # memory.
     loss_chunk: int = 0
     # Rematerialize each transformer block in the backward pass
     # (jax.checkpoint): activation memory drops from O(n_layers * S * d *
@@ -2486,7 +2488,8 @@ def head_weights(params, cfg):
 
 def head_logits(x, params, cfg, spec="bsd,vd->bsv"):
     """The final projection of ``x`` onto the vocabulary, in the compute
-    dtype (the trainer's loss projects inside its own scope, :func:`_nll`)."""
+    dtype (the trainer's loss projects inside its own scope,
+    :func:`_chunked_nll`)."""
     with jax.named_scope(scopes.HEAD):
         return jnp.einsum(spec, x,
                           head_weights(params, cfg).astype(cfg.compute_dtype))
@@ -2534,22 +2537,15 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None,
     return head_logits(x, params, cfg)
 
 
-def _nll(hidden, targets, embed):
-    """-log p(target) per position from pre-projection hidden states."""
-    logits = jnp.einsum("bsd,vd->bsv", hidden, embed.astype(hidden.dtype))
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-    return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
-
-
 def _chunk_lse(h, t, head):
     """One chunk of the chunked loss, in float32 -> (its logits [B, C, V],
     their log-sum-exp [B, C, 1], the one-hot mask of its targets, its summed
     -log p(target)). The target's logit is picked by comparing a vocabulary
     iota with the target, a masked sum beside the softmax's own reductions,
-    where :func:`_nll` gathers: a gather's transpose is a scatter, which
-    inside a loop body runs one row at a time. The product is rounded to the
-    compute dtype and widened, as :func:`_nll` rounds it, so the chunked and
-    the full loss see the same logits."""
+    where ``take_along_axis`` would gather: a gather's transpose is a
+    scatter, which inside a loop body runs one row at a time. The product is
+    rounded to the compute dtype and widened, as a plain projection in that
+    dtype rounds it, so the loss is the same however many chunks make it."""
     logits = jnp.einsum("bsd,vd->bsv", h, head).astype(jnp.float32)
     top = jnp.max(logits, -1, keepdims=True)
     lse = top + jnp.log(jnp.sum(jnp.exp(logits - top), -1, keepdims=True))
@@ -2608,30 +2604,61 @@ def _chunked_nll_bwd(res, g):
 _chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
 
 
+# Rows (batch x positions) that one trip of the loss's loop takes at most
+# where the caller set no ``loss_chunk``. Read once on a v5e at 8 x 512,
+# d 1024, V 50257 (PERF.md, PR 52), the loss alone / ``step_dev_ms`` of
+# gpt2m-train-s512 (78.90 with the unchunked loss before it):
+#   1024 rows, 4 trips: 8.11 ms / 73.77
+#   2048 rows, 2 trips: 7.69 ms / 73.65
+#   4096 rows, 1 trip:  7.57 ms / 72.90 (XLA inlines a loop of one trip, and
+#     on one chip the embedding's AdamW update then fuses into the
+#     weight-gradient product; in a loop it is 2.2 ms of its own)
+_LOSS_ROWS = 4096
+
+
+def _loss_positions(B, S, cap, whole):
+    """Positions a trip of the loss's loop takes of each of ``B`` sequences
+    of ``S``. A caller's ``cap`` (``cfg.loss_chunk`` > 0) is taken as it is.
+    Else the program picks: the largest divisor of S that keeps a trip at or
+    under ``_LOSS_ROWS`` rows; the whole sequence where the rows already fit,
+    where ``whole`` says that positions are sharded over a mesh axis (a loop
+    over S would walk from device to device), and where no divisor comes
+    within a factor of two of ``_LOSS_ROWS`` (S prime, B above it): a loop of
+    S trips of B rows is not an answer."""
+    if cap:
+        if S > cap and S % cap != 0:
+            raise ValueError(f"seq len {S} must divide by loss_chunk {cap}")
+        return min(cap, S)
+    most = _LOSS_ROWS // B
+    if whole or most >= S:
+        return S
+    C = max((c for c in range(1, most + 1) if S % c == 0), default=0)
+    return C if 2 * B * C >= _LOSS_ROWS else S
+
+
 def loss_fn(params, batch, cfg: TransformerConfig, mesh=None):
     """Next-token cross-entropy. batch = {"tokens": [B, S+1] int32}.
 
-    With ``cfg.loss_chunk > 0`` the vocab projection + log-softmax run per
-    sequence chunk inside one scan that also takes each chunk's gradients
-    (:func:`_chunked_nll`; see the config field's rationale); the chunked
-    and full losses are identical.
+    One path: the vocab projection + log-softmax run per chunk of positions
+    inside one scan that also takes each chunk's gradients
+    (:func:`_chunked_nll`), so no ``[B, S, vocab]`` tensor is kept for the
+    backward pass. ``cfg.loss_chunk`` 0: the program picks the chunk from
+    the shapes it sees (:func:`_loss_positions`); > 0: the caller's. The
+    value does not depend on the chunk.
     """
     tokens = batch["tokens"]
     targets = tokens[:, 1:]
-    C = cfg.loss_chunk
-    S = targets.shape[1]
-    if C and S > C and S % C != 0:
-        raise ValueError(f"seq len {S} must divide by loss_chunk {C}")
+    B, S = targets.shape
+    sharded = (mesh is not None and cfg.seq_axis in mesh.axis_names
+               and mesh.shape[cfg.seq_axis] > 1)
+    C = _loss_positions(B, S, cfg.loss_chunk, sharded)
     head = head_weights(params, cfg)
     hidden = forward(params, tokens[:, :-1], cfg, mesh=mesh,
                      return_hidden=True)
     # Everything after the hidden states is the loss's: the projection, the
     # softmax, the mean, and the scan that walks the chunks.
     with jax.named_scope(scopes.LOSS):
-        if not C or S <= C:
-            return jnp.mean(_nll(hidden, targets, head))
-
-        B, _, d = hidden.shape
+        d = hidden.shape[-1]
         h_chunks = hidden.reshape(B, S // C, C, d).swapaxes(0, 1)
         t_chunks = targets.reshape(B, S // C, C).swapaxes(0, 1)
         return _chunked_nll(h_chunks, t_chunks, head)

@@ -405,32 +405,62 @@ def _assert_grads_close(g_full, g_chunk):
                                    atol=1e-3, rtol=1e-2)
 
 
-@pytest.mark.parametrize("batch_rows", [1, 2])
-@pytest.mark.parametrize("n_chunks", [2, 4])
+def _plain_loss(params, batch, cfg):
+    """The plain reference the loss's rule is held to: one projection in the
+    compute dtype, a float32 ``log_softmax``, ``take_along_axis`` and the
+    compiler's own transpose (``transformer._nll`` until PR 52)."""
+    from horovod_tpu.models import transformer as tfm
+
+    tokens = batch["tokens"]
+    hidden = tfm.forward(params, tokens[:, :-1], cfg, return_hidden=True)
+    head = tfm.head_weights(params, cfg)
+    logits = jnp.einsum("bsd,vd->bsv", hidden, head.astype(hidden.dtype))
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+    picked = jnp.take_along_axis(logp, tokens[:, 1:, None], -1)[..., 0]
+    return -jnp.mean(picked)
+
+
+@pytest.mark.parametrize("tokens, loss_chunk, rows, trips", [
+    # a caller's chunk: 2 and 4 chunks of S 32, B 1 and 2
+    ((1, 33), 16, None, 2), ((2, 33), 16, None, 2),
+    ((1, 33), 8, None, 4), ((2, 33), 8, None, 4),
+    # loss_chunk 0, the program picks (transformer._loss_positions):
+    ((2, 33), 0, None, 1),      # rows under _LOSS_ROWS: one trip
+    ((2, 33), 0, 16, 4),        # 4 trips of [2, 8]
+    ((3, 41), 0, 32, 4),        # B.S = 120 no multiple of 32: 4 of [3, 10]
+    ((2, 38), 0, 32, 1),        # S 37 is prime: the whole sequence
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
 @pytest.mark.parametrize("tied", [True, False])
-def test_chunked_loss_matches_full(tied, n_chunks, batch_rows):
-    """cfg.loss_chunk computes the identical cross-entropy without ever
-    materializing the [S, vocab] float32 tensor (value and every gradient
-    leaf), whether the head is the embedding or a weight of its own."""
+def test_chunked_loss_matches_full(monkeypatch, tied, tokens, loss_chunk,
+                                   rows, trips):
+    """Whatever chunk the caller or the program picks, ``loss_fn`` computes
+    the plain cross-entropy without ever keeping the [B, S, vocab] float32
+    tensor (value and every gradient leaf), whether the head is the
+    embedding or a weight of its own."""
     import dataclasses
 
     from horovod_tpu.models import transformer as tfm
 
-    cfg = dataclasses.replace(tfm.tiny(), tie_embeddings=tied)
-    cfg_c = dataclasses.replace(cfg, loss_chunk=32 // n_chunks)
+    if rows:
+        monkeypatch.setattr(tfm, "_LOSS_ROWS", rows)
+    cfg = dataclasses.replace(tfm.tiny(), tie_embeddings=tied,
+                              loss_chunk=loss_chunk)
+    B, S = tokens[0], tokens[1] - 1
+    assert S // tfm._loss_positions(B, S, loss_chunk, False) == trips
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
     assert ("head" in params) == (not tied)
     rng = np.random.default_rng(5)
     batch = {"tokens": jnp.asarray(
-        rng.integers(0, cfg.vocab_size, (batch_rows, 33)), jnp.int32)}
-    l_full, g_full = jax.value_and_grad(tfm.loss_fn)(params, batch, cfg)
-    l_chunk, g_chunk = jax.value_and_grad(tfm.loss_fn)(params, batch, cfg_c)
+        rng.integers(0, cfg.vocab_size, tokens), jnp.int32)}
+    l_full, g_full = jax.value_and_grad(_plain_loss)(params, batch, cfg)
+    l_chunk, g_chunk = jax.value_and_grad(tfm.loss_fn)(params, batch, cfg)
     np.testing.assert_allclose(float(l_full), float(l_chunk), rtol=1e-5)
     _assert_grads_close(g_full, g_chunk)
     # called without differentiation (its own rule, no gradient built)
-    plain = jax.jit(tfm.loss_fn, static_argnums=2)
-    np.testing.assert_allclose(float(plain(params, batch, cfg_c)),
-                               float(plain(params, batch, cfg)), rtol=1e-5)
+    np.testing.assert_allclose(
+        float(jax.jit(tfm.loss_fn, static_argnums=2)(params, batch, cfg)),
+        float(jax.jit(_plain_loss, static_argnums=2)(params, batch, cfg)),
+        rtol=1e-5)
 
 
 def test_chunked_loss_matches_full_under_accumulation():

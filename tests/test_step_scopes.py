@@ -152,8 +152,12 @@ def _compiled_step(loss_chunk, remat, accum_steps=1):
 @pytest.mark.parametrize("loss_chunk,remat,accum_steps", [
     (0, False, 1), (16, False, 1), (0, True, 1), (16, True, 1),
     (16, False, 2)])
-def test_every_instruction_of_the_step_lies_in_a_phase(loss_chunk, remat,
+def test_every_instruction_of_the_step_lies_in_a_phase(monkeypatch,
+                                                       loss_chunk, remat,
                                                        accum_steps):
+    if not loss_chunk:
+        # the program picks: 4 trips of a shard's [2, 8] at this tiny size
+        monkeypatch.setattr(tfm, "_LOSS_ROWS", 16)
     parsed = []
     for opcode, path in _instructions(
             _compiled_step(loss_chunk, remat, accum_steps)):
@@ -173,10 +177,9 @@ def test_every_instruction_of_the_step_lies_in_a_phase(loss_chunk, remat,
     # every collective is the gradients' (the loss's mean included)
     reduces = [x for x in parsed if x[0].startswith("all-reduce")]
     assert reduces and all(x[2][0] == "grad_reduce" for x in reduces)
-    if loss_chunk:
-        # one loop, the forward's: it takes each chunk's gradients too
-        loops = [x for x in parsed if "while" in x[1] and x[2][1] == "loss"]
-        assert loops and not any(x[2][2] for x in loops)
+    # one loop, the forward's: it takes each chunk's gradients too
+    loops = [x for x in parsed if "while" in x[1] and x[2][1] == "loss"]
+    assert loops and not any(x[2][2] for x in loops)
     unscoped = [x for x in by_phase["grad"] if x[2][1] is None]
     limit = UNSCOPED_GRAD_LIMIT if accum_steps == 1 \
         else UNSCOPED_GRAD_LIMIT_ACCUM

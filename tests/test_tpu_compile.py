@@ -166,6 +166,9 @@ def test_ragged_expert_dispatch_compiles_on_four_chips(topo):
 
 # ---- the data-parallel train step: its gradient all-reduces ----------------
 
+_STEP_TEXTS = {}      # two tests read each of the compiled texts
+
+
 def _train_step_text(devices, n_layers, **overrides):
     """Compiled text of ``make_train_step`` over a cut of ``gpt2-medium`` at
     the dp4 cell's batch (8 x 512 a chip) on a ``data`` mesh of ``devices``."""
@@ -173,6 +176,9 @@ def _train_step_text(devices, n_layers, **overrides):
 
     from horovod_tpu.parallel import data_parallel
 
+    key = (len(devices), n_layers, tuple(sorted(overrides.items())))
+    if key in _STEP_TEXTS:
+        return _STEP_TEXTS[key]
     mesh = Mesh(np.array(devices), ("data",))
     cfg = tfm.TransformerConfig(vocab_size=50257, d_model=1024, n_heads=16,
                                 n_layers=n_layers, d_ff=4096,
@@ -190,8 +196,10 @@ def _train_step_text(devices, n_layers, **overrides):
         lambda p, b: tfm.loss_fn(p, b, cfg), tx, mesh, **overrides)
     if overrides.get("jit") is False:
         step = jax.jit(step, donate_argnums=(0, 1))
-    return step.lower(on(params), on(jax.eval_shape(tx.init, params)),
-                      batch).compile().as_text()
+    _STEP_TEXTS[key] = step.lower(
+        on(params), on(jax.eval_shape(tx.init, params)),
+        batch).compile().as_text()
+    return _STEP_TEXTS[key]
 
 
 def _bytes(shape_text):
@@ -206,6 +214,26 @@ def _program(text):
     they came from (the table of stack frames, each instruction's index)."""
     body = text[text.index("\n\n", text.index("\nStackFrames")):]
     return re.sub(r" stack_frame_id=\d+", "", body)
+
+
+@pytest.mark.parametrize("chips", [4, 1], ids=["data4", "one_chip"])
+def test_train_step_loss_keeps_no_float32_logits(topo, chips):
+    """At the S 512 cells' 8 x 512 a chip (``loss_chunk`` 0) the 4,096 rows
+    are ONE trip of the loss's rule, a loop the compiler inlines (PERF.md,
+    PR 52): the step holds no ``while``; the logits are a buffer once, in
+    bf16; their float32 widening, the softmax and ``dlogits`` live inside
+    the three products' fusions and are no buffer of the step."""
+    assert tfm._LOSS_ROWS == 8 * 512
+    text = _train_step_text(topo.devices[:chips], 4 if chips == 4 else 2)
+    assert " while(" not in text
+    entry = text[text.index("\nENTRY "):].splitlines()
+    # operands are names there: a shape in a line is its result's
+    assert not [line for line in entry if "f32[8,512,50257]" in line]
+    made = [line for line in entry if "bf16[8,512,50257]" in line
+            and " get-tuple-element(" not in line]
+    assert len(made) == 1 and "jvp(loss)" in made[0], made
+    # and the float32 logits are computed: inside fusions
+    assert "f32[8,512,50257]" in text
 
 
 @pytest.mark.parametrize("chips", [4, 1], ids=["data4", "one_chip"])
