@@ -23,9 +23,13 @@ wrappers per framework, elastic state/run, timeline, and a ``tpurun``
 launcher.
 """
 
+import time as _time
+
+_T_FIRST = _time.perf_counter()   # the start-up account's "import" begins
+
 __version__ = "0.1.0"
 
-from .basics import basics as _basics
+from .basics import basics as _basics  # noqa: E402
 from .exceptions import (  # noqa: F401
     CheckpointError,
     HorovodInternalError,
@@ -120,18 +124,22 @@ def init():
     if _os.environ.get("HVD_ELASTIC") == "1":
         from .runner.elastic import worker as _worker
 
-        rc = _worker.rendezvous_init()
+        with _startup.phase("init.core"):
+            rc = _worker.rendezvous_init()
+        _startup.account.rank = _basics.rank()
         _maybe_init_jax_mesh()
         return rc
     from .runner import network as _network
 
-    if _network.NEGOTIATE in (_os.environ.get("HVD_CONTROLLER_ADDR", ""),
-                              _os.environ.get("HVD_JAX_COORD_ADDR", "")):
-        # Multi-host static launch: rank 0 registers real ports probed on
-        # ITS host; everyone else reads them (runner/network.py — the
-        # driver/task-service analog).
-        _network.negotiate_endpoints_from_env()
-    rc = _basics.init()
+    with _startup.phase("init.core"):
+        if _network.NEGOTIATE in (_os.environ.get("HVD_CONTROLLER_ADDR", ""),
+                                  _os.environ.get("HVD_JAX_COORD_ADDR", "")):
+            # Multi-host static launch: rank 0 registers real ports probed
+            # on ITS host; everyone else reads them (runner/network.py — the
+            # driver/task-service analog).
+            _network.negotiate_endpoints_from_env()
+        rc = _basics.init()
+    _startup.account.rank = _basics.rank()
     _maybe_init_jax_mesh()
     return rc
 
@@ -139,6 +147,7 @@ def init():
 def shutdown():
     import sys as _sys
 
+    _startup.write()   # a rank that is stopped after this still left its line
     if "horovod_tpu.jax.distributed" in _sys.modules:
         from .jax import distributed as _jd
 
@@ -216,6 +225,18 @@ def serve_stats():
     return _serve_loop.serve_stats()
 
 
+def startup_stats():
+    """This process's start-up account
+    (horovod_tpu/observability/startup.py): the phases of the start from
+    the launcher's first line to the serving loop's warm-up, each with its
+    offset from the process's start and its seconds, the seconds JAX spent
+    tracing, lowering, compiling and reading the compile cache (one row a
+    program this package names, one for everything else), and the cache's
+    hits and misses. The same line is appended to ``$HVD_STARTUP_LOG`` at
+    exit. See docs/observability.md."""
+    return _startup.stats()
+
+
 def compression_stats():
     """One merged view of every compression surface: the core wire codecs
     (int8 error-feedback ring / top-k allgather — compress_stats()) plus
@@ -263,3 +284,6 @@ from .ops import zerocopy as bridge  # noqa: E402  (hvd.bridge.stats / as_buffer
 from . import elastic  # noqa: F401,E402  (hvd.elastic.run / State / ObjectState)
 from . import profiler  # noqa: F401,E402  (xplane trace windows)
 from . import observability  # noqa: F401,E402  (metrics / stall / spans)
+from .observability import startup as _startup  # noqa: E402
+
+_startup.imported(_T_FIRST)

@@ -35,6 +35,8 @@ its committed leaves on HOST (see ``elastic.JaxState``).
 import os
 import warnings
 
+from ..observability import startup as _startup
+
 _initialized_here = False
 _client_mode = False
 
@@ -121,17 +123,18 @@ def initialize_from_env(timeout=None):
         return False
     rank = int(os.environ.get("HVD_RANK", "0"))
     timeout = timeout or int(os.environ.get("HVD_JAX_COORD_TIMEOUT", "120"))
-    if os.environ.get("HVD_JAX_COORD_MODE") == "client":
-        _client_connect(addr, size, rank, timeout)
-        _client_mode = True
-    else:
-        jax.distributed.initialize(
-            coordinator_address=addr,
-            num_processes=size,
-            process_id=rank,
-            initialization_timeout=timeout,
-        )
-        _client_mode = False
+    with _startup.phase("init.distributed"):
+        if os.environ.get("HVD_JAX_COORD_MODE") == "client":
+            _client_connect(addr, size, rank, timeout)
+            _client_mode = True
+        else:
+            jax.distributed.initialize(
+                coordinator_address=addr,
+                num_processes=size,
+                process_id=rank,
+                initialization_timeout=timeout,
+            )
+            _client_mode = False
     _initialized_here = True
     # Force backend creation NOW: the multi-process device exchange is a
     # collective rendezvous, and every rank is synchronized at this point
@@ -139,7 +142,8 @@ def initialize_from_env(timeout=None):
     # jax op can deadlock an elastic epoch — e.g. a respawned worker stuck
     # in the exchange while a survivor waits in a core collective that the
     # newcomer would only reach after the exchange.
-    jax.devices()
+    with _startup.phase("init.devices"):
+        jax.devices()
     return True
 
 

@@ -24,7 +24,7 @@ processes and the launchers' jax-free parents can import it freely.
 
 import os
 
-from . import metrics, spans, stall  # noqa: F401
+from . import metrics, spans, stall, startup  # noqa: F401
 from .metrics import enabled  # noqa: F401
 from .spans import merge_traces  # noqa: F401
 

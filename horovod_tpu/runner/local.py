@@ -16,6 +16,8 @@ import subprocess
 import sys
 import time
 
+from ..observability import startup as _startup
+
 
 # How libtpu lays `local_size` one-chip processes over one host's chips
 # (x,y,z process grid): the layouts that have run on a v5e host, as the
@@ -73,6 +75,9 @@ def slot_env(rank, size, local_rank=None, local_size=None, cross_rank=None,
     data plane across processes (see horovod_tpu/jax/distributed.py).
     """
     env = dict(os.environ)
+    # The start-up account's "launch": this launcher's own start, which the
+    # rank takes off its own (observability/startup.py).
+    env[_startup.LAUNCH_ENV] = repr(_startup.account.started()[0])
     env["HVD_RANK"] = str(rank)
     env["HVD_SIZE"] = str(size)
     env["HVD_LOCAL_RANK"] = str(local_rank if local_rank is not None else rank)

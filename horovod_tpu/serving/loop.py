@@ -79,6 +79,7 @@ import numpy as np
 
 from ..observability import metrics as _metrics
 from ..observability import spans as _spans
+from ..observability import startup as _startup
 from . import engine, kv_cache, speculate
 from .prefix_cache import PrefixCache
 from .scheduler import (DEFAULT_KV_PAGES, DEFAULT_MAX_BATCH,
@@ -228,6 +229,7 @@ class ServeLoop:
     ends a prompt.
     """
 
+    @_startup.phase("serve.build")
     def __init__(self, params, cfg, geo=None, mesh=None,
                  max_batch=DEFAULT_MAX_BATCH, mode="continuous",
                  load_reporter=None, report_interval=16,
@@ -431,30 +433,35 @@ class ServeLoop:
                     np.zeros((b, mb), np.int32), np.zeros(b, bool))
 
         if self.prefill_fn is not None:
-            self._fetch(self._call(
-                "prefill", self.prefill_fn,
-                np.zeros(self.geo.max_kv, np.int32), np.int32(1),
-                np.zeros(mb, np.int32)))
+            with _startup.phase("warmup.prefill"):
+                self._fetch(self._call(
+                    "prefill", self.prefill_fn,
+                    np.zeros(self.geo.max_kv, np.int32), np.int32(1),
+                    np.zeros(mb, np.int32)))
         # The decode step in both forms of its ``tokens``: from the host,
         # and the step before it handing them over on the device.
-        first = self._call("decode", self.decode_fn, *slots(B))
-        self._fetch(self._call("decode", self.decode_fn, first.feed(),
-                               *slots(B)[1:]))
-        self._fetch(first)
+        with _startup.phase("warmup.decode"):
+            first = self._call("decode", self.decode_fn, *slots(B))
+            self._fetch(self._call("decode", self.decode_fn, first.feed(),
+                                   *slots(B)[1:]))
+            self._fetch(first)
         if self.bprefill_fn is not None:
-            toks, _, tables, active = slots(B, self.geo.max_kv)
-            self._fetch(self._call("bprefill", self.bprefill_fn, toks,
-                                   np.ones(B, np.int32), tables, active))
+            with _startup.phase("warmup.bprefill"):
+                toks, _, tables, active = slots(B, self.geo.max_kv)
+                self._fetch(self._call("bprefill", self.bprefill_fn, toks,
+                                       np.ones(B, np.int32), tables, active))
         if self.chunk_fn is not None:
-            if self.chunk_end_fn is not None:
-                self._call("chunk", self.chunk_fn,
-                           *slots(1, self.prefill_chunk), fetch=False)
-            self._fetch(self._call("chunk",
-                                   self.chunk_end_fn or self.chunk_fn,
-                                   *slots(1, self.prefill_chunk)))
+            with _startup.phase("warmup.chunk"):
+                if self.chunk_end_fn is not None:
+                    self._call("chunk", self.chunk_fn,
+                               *slots(1, self.prefill_chunk), fetch=False)
+                self._fetch(self._call("chunk",
+                                       self.chunk_end_fn or self.chunk_fn,
+                                       *slots(1, self.prefill_chunk)))
         if self.spec_fn is not None:
-            self._fetch(self._call("spec", self.spec_fn,
-                                   *slots(B, self.spec_tokens + 1)))
+            with _startup.phase("warmup.spec"):
+                self._fetch(self._call("spec", self.spec_fn,
+                                       *slots(B, self.spec_tokens + 1)))
         # What the warm-up routed is not traffic.
         self._moe_load[:] = 0
         if "moe" in self.tally:
@@ -655,6 +662,7 @@ class ServeLoop:
     def run(self, requests, clock=time.monotonic):
         """Serve `requests` (arrival_t = seconds from start) to
         completion; returns (summary dict, finished Request list)."""
+        _startup.close()       # the start is over: the account takes no more
         for r in requests:
             if r.prompt_len >= self.geo.max_kv:
                 raise ValueError(f"request {r.rid}: prompt {r.prompt_len} "
