@@ -38,6 +38,20 @@ package owns (``PROGRAMS``) and one, ``other``, for everything else (weight
 initialisers, eager operations). ``counts`` holds the number of events of
 each kind and the persistent cache's hits and misses.
 
+``ServeLoop`` keeps its programs across starts where JAX's persistent cache
+is on (:mod:`horovod_tpu.serving.programs`): a program found there is
+neither traced nor lowered, and no event of JAX's says it was read, so the
+store reports it itself. ``counts.program_hits`` are the programs it loaded,
+and a hit's seconds (the store's entry and JAX's own executable read back
+and loaded) are in that program's ``load`` like JAX's reads;
+``counts.program_misses`` are the programs it had to lower and compile
+(their seconds arrive as JAX's events do). An entry is good for one
+program on one installation: it misses when the configuration, the
+geometry, a static argument, the mesh, an argument's shape, dtype or
+sharding, the backend, ``jax`` / ``jaxlib`` / libtpu, ``XLA_FLAGS`` /
+``LIBTPU_INIT_ARGS`` or ANY ``.py`` under ``horovod_tpu/`` changes, and
+when JAX's cache has evicted the executable it points at.
+
 The account closes (``closed_s``) at the program's first real work: when
 ``jit_step`` has been compiled, or at the first ``ServeLoop.run``. Nothing
 after that is a start's (a later ``phase`` is no span either); a process
@@ -84,6 +98,8 @@ _DURATIONS = {
 }
 _COUNTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
            "/jax/compilation_cache/cache_misses": "cache_misses"}
+# What ``serving/programs.py`` reports of its store.
+_PROGRAM_COUNTS = ("program_hits", "program_misses")
 
 
 def log_path():
@@ -106,7 +122,8 @@ class Account:
         self.t_first = time.perf_counter()   # the package's first line
         self.phases = []                     # (name, perf_counter, seconds)
         self.sums = {"other": [0.0] * len(SUMS)}
-        self.counts = dict.fromkeys((*SUMS, *_COUNTS.values()), 0)
+        self.counts = dict.fromkeys(
+            (*SUMS, *_COUNTS.values(), *_PROGRAM_COUNTS), 0)
         self.rank = None
         self.closed = None                   # perf_counter at the close
         self.listening = False
@@ -197,6 +214,22 @@ class Account:
         name = _COUNTS.get(event)
         if name is not None and self.closed is None:
             self.counts[name] += 1
+
+    # -- the loop's store of programs (serving/programs.py) ---------------
+
+    def program_loaded(self, row, seconds):
+        """The store had ``row``'s program (``jit_decode``, ...): it was
+        read back and loaded in ``seconds``, with no trace and no lowering."""
+        if self.closed is None:
+            self.counts["program_hits"] += 1
+            self.counts["load"] += 1
+            self.sums.setdefault(row, [0.0] * len(SUMS))[LOAD] += seconds
+
+    def program_missed(self):
+        """The store had no program that loads: JAX's events of the trace,
+        the lowering and the compile follow."""
+        if self.closed is None:
+            self.counts["program_misses"] += 1
 
     # -- handing it over --------------------------------------------------
 

@@ -9,6 +9,8 @@ Layout (docs/serving.md is the architecture doc):
 - :mod:`.speculate`    — jax-free drafters + the spec accept/reject rule
 - :mod:`.kv_cache`     — paged K/V arrays, heads sharded on the TP axis
 - :mod:`.engine`       — jit'd prefill / decode / chunk steps with block tables
+- :mod:`.programs`     — the loop's executables kept across starts (where JAX's
+  persistent compile cache is on)
 - :mod:`.loop`         — the serve loop: Poisson load, latency spans, gauges
 
 Lazy submodule access keeps the jax-free halves (scheduler, autoscale,
@@ -19,7 +21,7 @@ pure-numpy tests — without pulling jax into the process.
 import importlib
 
 _SUBMODULES = ("scheduler", "autoscale", "prefix_cache", "speculate",
-               "kv_cache", "engine", "loop")
+               "kv_cache", "engine", "programs", "loop")
 
 
 def __getattr__(name):

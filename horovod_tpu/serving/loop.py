@@ -80,7 +80,7 @@ import numpy as np
 from ..observability import metrics as _metrics
 from ..observability import spans as _spans
 from ..observability import startup as _startup
-from . import engine, kv_cache, speculate
+from . import engine, kv_cache, programs, speculate
 from .prefix_cache import PrefixCache
 from .scheduler import (DEFAULT_KV_PAGES, DEFAULT_MAX_BATCH,
                         DEFAULT_PAGE_SIZE, ContinuousBatcher, PageAllocator,
@@ -270,13 +270,28 @@ class ServeLoop:
             self.max_batch)
         use_prefix = (use_prefix and not geo.ring_blocks
                       and not self.has_state)
-        self.prefill_fn = (engine.make_prefill(cfg, geo, mesh)
-                           if padded else None)
-        self.decode_fn = engine.make_decode_step(cfg, geo, mesh, max_batch)
-        # Whether that program reads the cache through the paged kernel
+        # Whether the decode step reads the cache through the paged kernel
         # (the engine's choice, from backend, mesh and shapes).
         self.decode_paged = engine.decode_attn(cfg, geo, mesh) == "paged"
-        self.bprefill_fn = (engine.make_batched_prefill(cfg, geo, mesh)
+        keep = programs.on()
+
+        def kept(program, one_query=False, **static):
+            # Where JAX's persistent compile cache is on, a program's
+            # executable is found again by what decides it, with no trace
+            # and no lowering (``programs``); elsewhere this is ``program``.
+            if not keep:
+                return program
+            return programs.Program(
+                program, cfg=cfg, geo=geo, mesh=mesh,
+                paged=self.decode_paged,
+                kernels=engine._kernels(cfg, geo, mesh, one_query), **static)
+
+        self.prefill_fn = (kept(engine.make_prefill(cfg, geo, mesh))
+                           if padded else None)
+        self.decode_fn = kept(
+            engine.make_decode_step(cfg, geo, mesh, max_batch),
+            one_query=True, max_batch=self.max_batch)
+        self.bprefill_fn = (kept(engine.make_batched_prefill(cfg, geo, mesh))
                             if padded and batch_prefill
                             and self.max_batch > 1 else None)
         # A model may say that its fill leaves the stack part-way up
@@ -287,8 +302,10 @@ class ServeLoop:
         self.fill_exit = engine.fill_exit(cfg)
 
         def chunk_step(ends):
-            return engine.make_chunk_step(cfg, geo, mesh,
-                                          q_len=self.prefill_chunk, ends=ends)
+            return kept(
+                engine.make_chunk_step(cfg, geo, mesh,
+                                       q_len=self.prefill_chunk, ends=ends),
+                q_len=self.prefill_chunk, ends=ends)
 
         self.chunk_fn = self.chunk_end_fn = None
         if self.fill_exit is not None:

@@ -253,7 +253,30 @@ def test_a_cache_read_goes_to_the_program_that_asked(acct):
     assert stats["sums"]["jit_chunk"]["compile"] == 4.0
     assert stats["sums"]["jit_chunk"]["load"] == 0.0
     assert stats["counts"] == {"trace": 0, "lower": 0, "compile": 2,
-                               "load": 1, "cache_hits": 1, "cache_misses": 1}
+                               "load": 1, "cache_hits": 1, "cache_misses": 1,
+                               "program_hits": 0, "program_misses": 0}
+
+
+def test_the_store_of_programs_reports_its_own_reads(acct):
+    """A kept program fires none of JAX's events: the store says what it
+    loaded, into the same ``load`` sum, and what it had to compile; nothing
+    once the account is closed."""
+    acct.program_loaded("jit_decode", 1.5)
+    acct.program_loaded("jit_chunk", 0.5)
+    acct.program_loaded("jit_chunk", 0.25)
+    acct.program_missed()
+    stats = acct.stats()
+    assert stats["sums"]["jit_decode"] == {"trace": 0.0, "lower": 0.0,
+                                           "compile": 0.0, "load": 1.5}
+    assert stats["sums"]["jit_chunk"]["load"] == 0.75
+    assert stats["counts"] == {"trace": 0, "lower": 0, "compile": 0,
+                               "load": 3, "cache_hits": 0, "cache_misses": 0,
+                               "program_hits": 3, "program_misses": 1}
+    acct.close()
+    acct.program_loaded("jit_decode", 9.0)
+    acct.program_missed()
+    assert acct.stats()["counts"] == stats["counts"]
+    assert acct.stats()["sums"] == stats["sums"]
 
 
 def test_a_trace_inside_a_trace_is_counted_once(acct):
