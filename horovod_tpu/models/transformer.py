@@ -180,7 +180,13 @@ class MultiHeadAttention:
 
     ``kv_from``: the index of an EARLIER layer whose keys and values this
     layer attends (YOCO's shared cache, arXiv:2405.05254). Such a layer
-    projects queries only and owns no cache."""
+    projects queries only and owns no cache.
+
+    ``softmax_scale``: the factor on ``q . k`` where the kind states its own
+    (a source's ``attention_multiplier``); None = ``head_dim ** -0.5``. The
+    queries carry it (times ``sqrt(head_dim)``, in float32 before they are
+    rounded), so every attention, plain or kernel, stays at ``head_dim **
+    -0.5``."""
     n_heads: int
     n_kv_heads: int
     head_dim: int
@@ -192,6 +198,7 @@ class MultiHeadAttention:
     bias: bool = False
     differential: bool = False
     kv_from: Optional[int] = None
+    softmax_scale: Optional[float] = None
 
     def __post_init__(self):
         if isinstance(self.yarn, dict):
@@ -209,6 +216,17 @@ class MultiHeadAttention:
         if self.kv_from is not None and self.window:
             raise ValueError("a layer that attends another layer's keys and "
                              "values reads pages, not a ring: no window")
+        if self.softmax_scale is not None and self.differential:
+            raise ValueError("softmax_scale is not written for differential "
+                             "attention (its queries already carry sqrt(2))")
+
+    @property
+    def query_mult(self):
+        """What the queries are multiplied by so that an attention at
+        ``head_dim ** -0.5`` scores at ``softmax_scale``; 1.0 = nothing."""
+        if self.softmax_scale is None:
+            return 1.0
+        return float(self.softmax_scale) * math.sqrt(self.head_dim)
 
     @property
     def group(self):
@@ -472,6 +490,16 @@ class TransformerConfig:
     ffn: str = "gelu"           # | "swiglu": silu(x Wg) * (x Wu), then Wd
     #                             | "relu2": relu(x W1)^2, then W2
     tie_embeddings: bool = True  # False: a separate output head "head"
+    # Three scalars a source may state (Granite's ``embedding_multiplier``,
+    # ``residual_multiplier``, ``logits_scaling``): the token embeddings times
+    # ``embed_mult``, every half's output times ``residual_mult`` before it
+    # joins the stream, the logits DIVIDED by ``logits_div``. At 1.0 nothing
+    # is multiplied: the programs are what they were. (A kind's own softmax
+    # scale is ``MultiHeadAttention.softmax_scale``.) The trainer's loss
+    # projects for itself and refuses a ``logits_div`` it would not apply.
+    embed_mult: float = 1.0
+    residual_mult: float = 1.0
+    logits_div: float = 1.0
     # "gather" (K/V all-gather, XLA logits) | "ring" (seq-sharded K/V over
     # ICI) | "flash" (fused pallas kernel, ops/pallas_attention.py) |
     # "auto" (resolve per seq-len/mesh at trace time — see resolve_attn)
@@ -1135,12 +1163,13 @@ def _qkv_kind(h, layer, cfg, a: MultiHeadAttention, positions=None):
     ``sqrt(2)`` (in float32, before it is rounded) so that the attention's
     ``1 / sqrt(2 dh)`` is the kind's ``1 / sqrt(dh)``."""
     dt = cfg.compute_dtype
-    if a.bias or a.differential:
+    if a.bias or a.differential or a.query_mult != 1.0:
         q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt),
                        preferred_element_type=jnp.float32)
         if a.bias:
             q = q + layer["bq"].astype(jnp.float32)
-        q = (q * math.sqrt(2.0) if a.differential else q).astype(dt)
+        q = (q * math.sqrt(2.0) if a.differential
+             else q * a.query_mult).astype(dt)
     else:
         q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
     kv = _kv_kind(h, layer, cfg, a)
@@ -2347,6 +2376,13 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
     dt = cfg.compute_dtype
     a = cfg.attn_of(li)
     selected = routing = None
+
+    def joined(x, out):
+        """The stream plus a half's output (times ``residual_mult``)."""
+        if cfg.residual_mult != 1.0:
+            out = (out * cfg.residual_mult).astype(out.dtype)
+        return x + out
+
     if cfg.has_mixer(li):
         h = _norm(x, layer["ln1"], cfg)
     if isinstance(a, RECURRENT) and cfg.has_mixer(li):
@@ -2363,12 +2399,12 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
             mix = functools.partial(mix, h, layer, a, cfg)
         with jax.named_scope(scope):
             out = attend(mix)
-            x = x + _constrain(out, out_spec)
+            x = joined(x, _constrain(out, out_spec))
     elif isinstance(a, GatedMemoryUnit) and cfg.has_mixer(li):
         with jax.named_scope(scopes.GATED_MEMORY):
             out = attend(functools.partial(gated_memory_mix, h, layer, a,
                                            cfg))
-            x = x + _constrain(out, out_spec)
+            x = joined(x, _constrain(out, out_spec))
     elif cfg.has_mixer(li):
         with jax.named_scope(scopes.ATTENTION):
             if a is None:
@@ -2392,7 +2428,7 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
                 if cfg.attn_gate:
                     o = o * _head_gate(h, layer, dt)
                 out = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-            x = x + _constrain(out, out_spec)
+            x = joined(x, _constrain(out, out_spec))
     if cfg.has_ffn(li):
         h = _norm(x, layer["ln2"], cfg)
         with jax.named_scope(scopes.MLP):
@@ -2400,7 +2436,7 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
                 y, routing = _moe_ffn(h, layer, cfg, mesh, valid)
             else:
                 y = _ffn(h, layer, cfg)
-            x = x + y
+            x = joined(x, y)
     if selected is not None:
         routing = dict(routing or {}, selected=selected)
     return x, routing
@@ -2465,8 +2501,12 @@ def apply_block(layer, x, cfg: TransformerConfig, mesh=None, impl=None,
 
 
 def embed_tokens(params, tokens, cfg):
-    """Token embeddings in the compute dtype, shaped like ``tokens``."""
-    return params["embed"].astype(cfg.compute_dtype)[tokens]
+    """Token embeddings in the compute dtype, shaped like ``tokens`` (times
+    ``cfg.embed_mult``)."""
+    x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    if cfg.embed_mult != 1.0:
+        x = (x * cfg.embed_mult).astype(x.dtype)
+    return x
 
 
 def add_positions(x, params, cfg, positions=None):
@@ -2491,8 +2531,11 @@ def head_logits(x, params, cfg, spec="bsd,vd->bsv"):
     dtype (the trainer's loss projects inside its own scope,
     :func:`_chunked_nll`)."""
     with jax.named_scope(scopes.HEAD):
-        return jnp.einsum(spec, x,
-                          head_weights(params, cfg).astype(cfg.compute_dtype))
+        logits = jnp.einsum(
+            spec, x, head_weights(params, cfg).astype(cfg.compute_dtype))
+        if cfg.logits_div != 1.0:
+            logits = (logits / cfg.logits_div).astype(logits.dtype)
+        return logits
 
 
 def forward(params, tokens, cfg: TransformerConfig, mesh=None,
@@ -2646,6 +2689,9 @@ def loss_fn(params, batch, cfg: TransformerConfig, mesh=None):
     the shapes it sees (:func:`_loss_positions`); > 0: the caller's. The
     value does not depend on the chunk.
     """
+    if cfg.logits_div != 1.0:
+        raise ValueError("loss_fn projects for itself and does not apply "
+                         "logits_div: such a model is served, not trained")
     tokens = batch["tokens"]
     targets = tokens[:, 1:]
     B, S = targets.shape

@@ -236,12 +236,24 @@ _CHUNK_BLOCK_TOKENS = (512, 256)
 _VMEM_LIMIT = 96 * 1024 * 1024
 
 
-def grouped_supported(page_size, head_dim, dtype):
+def _heads_packed(head_dim, n_kv_heads):
+    """Key/value heads that :func:`paged_grouped_attention` takes as ONE head
+    of 128 lanes: 1 for a head of whole lane tiles; ``128 / head_dim`` for a
+    narrower one whose heads fill tiles so (64 wide: two); 0 where neither."""
+    if head_dim % 128 == 0:
+        return 1
+    pack = 128 // head_dim if 128 % head_dim == 0 else 0
+    return pack if pack and n_kv_heads and n_kv_heads % pack == 0 else 0
+
+
+def grouped_supported(page_size, head_dim, dtype, n_kv_heads=None):
     """Whether :func:`paged_grouped_attention` tiles on a TPU: a page is
     whole sublane tiles of ``dtype`` and a head whole lane tiles (a key/value
-    head is sliced out of the fused row)."""
+    head is sliced out of the fused row), or, given ``n_kv_heads``, a narrower
+    head that fills a lane tile with its neighbours (:func:`_heads_packed`)."""
     sublanes = 8 * (4 // jnp.dtype(dtype).itemsize)
-    return page_size % sublanes == 0 and head_dim % 128 == 0
+    return (page_size % sublanes == 0
+            and _heads_packed(head_dim, n_kv_heads) > 0)
 
 
 def _grouped_kernel(pos0_ref, len_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -357,13 +369,31 @@ def paged_grouped_attention(q, k_pages, v_pages, tables, pos0, kv_len, *,
     keys is multiplied head by head: a key/value head's 128 lanes of the
     fused rows against the ``group * q_block`` query rows that read it,
     online softmax in float32 scratch. Scores exist a ``[group * q_block,
-    block]`` tile at a time."""
+    block]`` tile at a time.
+
+    Heads NARROWER than a lane tile (64 wide) are attended ``pack = 128 /
+    dh`` at a time as one head of 128 lanes, the fused row as it lies: a
+    query is widened to the pack's 128 lanes with zeros under the pack's
+    other heads, so its scores are its own head's; its result's lanes under
+    the other heads (their values under this head's probabilities) are
+    dropped. The scale stays the narrow head's."""
     B, Q, Hq, dh = q.shape
     n_pages, page, hd = k_pages.shape
     n_kv = int(n_kv_heads)
     if hd != n_kv * dh or v_pages.shape != k_pages.shape or Hq % n_kv:
         raise ValueError(f"cache {k_pages.shape} / {v_pages.shape} does not "
                          f"hold {n_kv} heads of {dh} under {Hq} query heads")
+    sm_scale = 1.0 / math.sqrt(dh)
+    pack = _heads_packed(dh, n_kv) if dh % 128 else 1
+    if pack > 1:
+        narrow, group1 = dh, Hq // n_kv
+        # Query head j reads key/value head j // group1, lane slot
+        # (j // group1) % pack of its pack.
+        at = (jnp.arange(Hq) // group1) % pack                       # [Hq]
+        mine = at[:, None] == jnp.arange(pack)[None]            # [Hq, pack]
+        q = jnp.where(mine[..., None], q[..., None, :], 0).reshape(
+            B, Q, Hq, pack * narrow)
+        n_kv, dh = n_kv // pack, pack * narrow
     group = Hq // n_kv
     qb = int(q_block or _Q_BLOCK)
     if qb & (qb - 1):
@@ -385,7 +415,7 @@ def paged_grouped_attention(q, k_pages, v_pages, tables, pos0, kv_len, *,
     kernel = functools.partial(
         _grouped_kernel, page=page, ppb=ppb, width=width, ring=bool(ring),
         n_kv=n_kv, head_dim=dh, qb=qb, window=int(window),
-        sm_scale=1.0 / math.sqrt(dh))
+        sm_scale=sm_scale)
     itemsize = k_pages.dtype.itemsize
     live = B * nq * (min(width * page, window + qb) if window
                      else width * page)          # an upper bound
@@ -421,4 +451,8 @@ def paged_grouped_attention(q, k_pages, v_pages, tables, pos0, kv_len, *,
     )(pos0.astype(jnp.int32), kv_len.astype(jnp.int32),
       tables.reshape(-1).astype(jnp.int32), qt, k_pages, v_pages)
     out = out[:, :, :, :group * qb].reshape(B, nq, n_kv, group, qb, dh)
-    return out.transpose(0, 1, 4, 2, 3, 5).reshape(B, Q, Hq, dh)
+    out = out.transpose(0, 1, 4, 2, 3, 5).reshape(B, Q, Hq, dh)
+    if pack > 1:        # each head's own lanes of its pack's result
+        out = jnp.sum(jnp.where(mine[..., None],
+                                out.reshape(B, Q, Hq, pack, narrow), 0), 3)
+    return out

@@ -62,14 +62,30 @@ _LANES = 128
 _GROUPS_BLOCK = 8
 
 
+def _packs(hpg, rows):
+    """PACKS a group is taken as: 1 where its ``hpg * rows`` registers are the
+    128 lanes that hold their packed values, else how many times 128 they are
+    (each pack whole heads: 128 / rows of them), or 0 where they do not
+    divide so."""
+    m = hpg * rows
+    if m == _LANES:
+        return 1
+    if m % _LANES or _LANES % rows:
+        return 0
+    return m // _LANES
+
+
 def supported(a):
     """Whether the kernel tiles on a TPU for the mixer ``a``: a state row is
     one register's lanes, a head whole registers, and a group's registers
-    as many as the lanes that hold their packed values (the shapes compiled
-    and measured: 16 heads of 64 a group; interpret mode takes any)."""
+    as many as the lanes that hold their packed values, or a whole multiple
+    of them (the shapes compiled and measured: 16 heads of 64 a group, and 64
+    heads of 64 in one group taken as four packs of 16 that share ``B`` and
+    ``C``; interpret mode takes any)."""
     return (a.state_size == _LANES and a.head_dim % _SUBLANES == 0
             and a.n_heads % a.n_groups == 0
-            and a.n_heads // a.n_groups * (a.head_dim // _SUBLANES) == _LANES)
+            and _packs(a.n_heads // a.n_groups,
+                       a.head_dim // _SUBLANES) > 0)
 
 
 def _pack(v, hpg):
@@ -145,6 +161,12 @@ def ssm_decode_update(x, step, rate, b_in, c_out, state, begins, *,
                          f"{B} slots of float32 [{H}, {P}, {N}] behind a "
                          f"trash row")
     hpg, rows = H // G, P // _SUBLANES
+    # A group wider than the 128 lanes of its packed values is taken as that
+    # many groups of consecutive heads, each handed the group's B and C.
+    packs = max(_packs(hpg, rows), 1)
+    if packs > 1:
+        b_in, c_out = (jnp.repeat(v, packs, axis=2) for v in (b_in, c_out))
+        G, hpg = G * packs, hpg // packs
     m = hpg * rows
     gb = min(int(groups_block or _GROUPS_BLOCK), G)
     while G % gb:

@@ -121,7 +121,12 @@ updated in place and read out from the same registers, where the plain
 ``transformer._ssd_step`` compiles to three. A chunk's padding must not
 advance the state, so for such a model a NEGATIVE token id marks a padding
 position (the loop pads so; positions are dead from the first negative id
-on). A layer with no mixer touches no cache.
+on). A layer with no mixer touches no cache. Where the prefix cache holds
+state (``kv_cache``: snapshot rows behind the slots') a hit's state does not
+come through any layer program: :func:`make_state_copy` builds the two row
+copies (``jit_state_snapshot``, ``jit_state_restore``), the loop restores
+before the fill's first chunk, and that chunk starts past position 0 and
+zeroes nothing.
 
 A DELTA-RULE layer (``TransformerConfig.delta_rule``: gated delta-rule linear
 attention, a ``[value, key]`` float32 matrix a head) carries its rows through
@@ -244,7 +249,8 @@ def grouped_kernels(cfg, geo, mesh):
     kernel tiles. Else they gather their pages."""
     return (bool(cfg.multihead) and _kernels_may_run(cfg, mesh)
             and all(paged_attention.grouped_supported(
-                geo.page_size, a.attended.head_dim, cfg.compute_dtype)
+                geo.page_size, a.attended.head_dim, cfg.compute_dtype,
+                a.attended.n_kv_heads)
                 for _, a in cfg.multihead))
 
 
@@ -563,7 +569,8 @@ def _grouped_work(a, live, itemsize):
             "state": {"kv_bytes": rows.sum() * 2 * a.kv_width * itemsize}}
 
 
-def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False):
+def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False,
+                 snapshots=0):
     """One state-space layer of a chunk or decode program: the slots' rows of
     the layer's tail and state arrays, zeroed where the window begins its
     sequence (a live slot whose ``q_pos [B, Q]`` starts at 0), through
@@ -588,21 +595,28 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False):
     that lived until the program's end. A dead slot there advances nothing
     (``mix`` leaves its tail and state bit for bit). Any other program (one
     slot's chunk) finds its row in ``tables``' last column, trash row 0 for a
-    slot with no live position."""
+    slot with no live position.
+
+    ``snapshots``: rows behind the slots' that hold snapshots of state
+    (``kv_cache``; the prefix cache's). No layer program reads or writes
+    them: a hit's state is copied into the slot's row by a program of its own
+    BEFORE the fill's first chunk (:func:`make_state_copy`), which then
+    starts past position 0 and zeroes nothing."""
     alive = jnp.any(ok, axis=1)
     begins = alive & (q_pos[:, 0] == 0)
-    whole = q_pos.shape[0] == state_c.shape[0] - 1
+    slots = state_c.shape[0] - 1 - snapshots
+    whole = q_pos.shape[0] == slots
     interpret = jax.default_backend() != "tpu"
     one_query = q_pos.shape[1] == 1
     in_place = bool(kernels) and whole and one_query
     recur = None
     if in_place:                 # the state stays where it lies
-        tail, state = tail_c[1:], None
+        tail, state = tail_c[1:1 + slots], None
         recur = functools.partial(
             pallas_ssm.ssm_decode_update, state=state_c, begins=begins,
             interpret=interpret)
     elif whole:
-        tail, state = tail_c[1:], state_c[1:]
+        tail, state = tail_c[1:1 + slots], state_c[1:1 + slots]
     else:
         rows = jnp.where(alive, tables[:, -1], 0)
         tail, state = tail_c[rows], state_c[rows]
@@ -817,6 +831,8 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
                 # call is ``(mix, tail_c, state_c, q_pos, ok, tables)``.
                 kind = _KERNEL_OF.get(type(a))
                 flag = {"kernels": True} if kernels.get(kind) else {}
+                if geo.snapshot_rows:
+                    flag["snapshots"] = geo.snapshot_rows
                 ck[li], cv[li], out = _state_layer(mix, ck[li], cv[li],
                                                    **window, **flag)
                 if cfg.hands_memory(li):
@@ -1041,7 +1057,7 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
 
 
 def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk",
-                    ends=None):
+                    ends=None, head="all"):
     """Compiled ``(params, cache, tokens, positions, block_tables,
     active) -> (cache, logits)`` — a ``q_len``-token window for every
     slot, the generalization of :func:`make_decode_step` to q_len > 1.
@@ -1085,11 +1101,23 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk",
     True the chunk that ends one: the same, then everything above on each
     slot's last live row, logits ``[B, 1, vocab]``. None is the whole stack
     on every position, as for every other model.
+
+    ``head`` cuts the vocabulary projection alone (the whole stack still runs
+    on every position): ``"all"`` every position's logits; ``"last"`` each
+    slot's last live row, ``[B, 1, vocab]``; ``"none"`` no projection,
+    ``(cache, None)``. A fill needs one row of logits a prompt, and float32 logits of
+    512 positions of a 100,352-row vocabulary are 205 MB a queued program.
     """
     _check_gathers(cfg, geo, mesh)
     if ends is not None and fill_exit(cfg) is None:
         raise ValueError("ends: this model's fill runs the whole stack "
                          "(engine.fill_exit is None)")
+    if head not in ("all", "last", "none") or (
+            head != "all" and (ends is not None or not cfg.recurrent)):
+        raise ValueError(
+            f"head is 'all', 'last' or 'none', and 'all' with ends or for a "
+            f"model whose padding is no negative id (no recurrent layer: the "
+            f"last live row is then not the program's to find), got {head!r}")
     kernels = _kernels(cfg, geo, mesh)
     q_len = geo.page_size if q_len is None else int(q_len)
     if q_len < 1:
@@ -1101,11 +1129,55 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk",
                                         block_tables, active, cfg=cfg,
                                         geo=geo, mesh=mesh, kernels=kernels,
                                         ends=ends)
+        if head == "none":
+            x = None
+        elif head == "last":         # each slot's last live row
+            live = jnp.cumprod((tokens >= 0).astype(jnp.int32), 1)
+            at = jnp.maximum(jnp.sum(live, 1) - 1, 0)
+            x = jnp.take_along_axis(x, at[:, None, None], axis=1)
         logits = None if x is None else tfm.head_logits(x, params, cfg)
         return _result(ck, cv, logits, moe, mesh, cfg)
 
     chunk.__name__ = chunk.__qualname__ = name
     return jax.jit(chunk, donate_argnums=(1,))
+
+
+def make_state_copy(cfg, geo, name):
+    """The program that copies one row of every recurrent layer's tail and
+    state arrays over another (scalar row indices; the cache donated, every
+    other row and every other layer's arrays as they were). The loop builds
+    it twice, as two programs a trace can tell apart: ``"state_snapshot"``,
+    compiled ``(cache, slot_row, snapshot_row) -> cache``, copies a slot's
+    rows into a snapshot row the prefix cache owns; ``"state_restore"``,
+    compiled ``(cache, slot_row, snapshot_row) -> cache``, copies the other
+    way, into the rows of the slot a hit was admitted to, in place of the
+    zeroing. (Two directions, not two names of one program: JAX's compile
+    cache keeps ONE executable for two modules that differ in their name
+    alone, and the trace then shows one name.) 76 MB each way at 36 layers
+    of ``[64, 64, 128]`` float32: 0.2 ms of the chip's memory."""
+    if name not in ("state_snapshot", "state_restore"):
+        raise ValueError(f"no state copy {name!r}")
+    layers = [li for li in range(cfg.n_layers)
+              if isinstance(cfg.attn_of(li), tfm.RECURRENT)
+              and kv_cache.owns_cache(cfg, li)]
+
+    def copy(cache, slot_row, snapshot_row):
+        src, dst = ((slot_row, snapshot_row) if name == "state_snapshot"
+                    else (snapshot_row, slot_row))
+
+        def row(c):
+            at = (src,) + (0,) * (c.ndim - 1)
+            taken = jax.lax.dynamic_slice(c, at, (1,) + c.shape[1:])
+            return jax.lax.dynamic_update_slice(
+                c, taken, (dst,) + (0,) * (c.ndim - 1))
+
+        with jax.named_scope(name):
+            return {kv: tuple(row(c) if li in layers else c
+                              for li, c in enumerate(cache[kv]))
+                    for kv in ("k", "v")}
+
+    copy.__name__ = copy.__qualname__ = name
+    return jax.jit(copy, donate_argnums=(0,))
 
 
 def make_batched_prefill(cfg, geo, mesh=None, prefill_pad=None):

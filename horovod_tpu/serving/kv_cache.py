@@ -70,8 +70,14 @@ the **trash row**, where inactive slots' writes go. A row is dirty with
 whatever its slot's last request left: the programs zero it when a request's
 first position arrives (``engine._state_layer``), which is also what makes a
 preempted request's replay start clean. State cannot be shared by page, so a
-model with such layers has no prefix cache, and it cannot be rolled back, so
-no speculation (``ServeLoop`` refuses both). A SELECTIVE-SCAN layer
+model with such layers has no prefix cache unless the geometry has SNAPSHOT
+rows: ``snapshot_rows`` further rows of every recurrent layer's tail and state
+arrays, behind the slots' (row ``state_rows + j`` is snapshot ``j``), owned by
+the nodes of the prefix tree (``prefix_cache``): a copy of a slot's rows as
+they stood at one page-aligned length of one prompt, which a hit copies back
+into a slot's rows in place of the zeroing (``engine.make_state_copy``). No
+program but that copy touches them. State cannot be rolled back, so no
+speculation (``ServeLoop`` refuses it). A SELECTIVE-SCAN layer
 (``TransformerConfig.selective_scan``) holds its rows the same way: the tail
 ``[state_rows, conv_kernel - 1, d_inner]`` and the state ``[state_rows,
 state_size, d_inner]`` in float32 (state-major: the channels are the lanes). A
@@ -116,6 +122,7 @@ class CacheGeometry:
     ring_blocks: int = 0  # pages of a slot's ring (window layers); 0 = none
     ring_pages: int = 0   # the window layers' pool, trash page 0 included
     state_rows: int = 0   # state-space rows, trash row 0 included; 0 = none
+    snapshot_rows: int = 0  # rows behind them that hold snapshots of state
 
     @property
     def max_kv(self):
@@ -140,14 +147,16 @@ def geometry(n_pages, page_size, max_context):
                          max_blocks=max_blocks)
 
 
-def with_rings(geo, cfg, q_len, max_batch):
+def with_rings(geo, cfg, q_len, max_batch, snapshot_rows=0):
     """``geo`` with a ring for every slot where ``cfg`` has window layers:
     ``window - 1 + q_len`` positions (``q_len``: the longest query window a
     program will run) in whole pages, ``max_batch`` of them and the trash
-    page; and with a state row for every slot (and the trash row) where it
-    has layers that carry a state. Unchanged for a model with neither."""
+    page; and with a state row for every slot (and the trash row), and
+    ``snapshot_rows`` behind them, where it has layers that carry a state.
+    Unchanged for a model with neither."""
     if cfg.recurrent:
-        geo = dataclasses.replace(geo, state_rows=int(max_batch) + 1)
+        geo = dataclasses.replace(geo, state_rows=int(max_batch) + 1,
+                                  snapshot_rows=int(snapshot_rows))
     windows = [a.window for _, a in cfg.latent + cfg.multihead if a.window]
     if not windows:
         return geo
@@ -181,8 +190,8 @@ def layer_shapes(cfg, geo, li):
         if not geo.state_rows:
             raise ValueError("a layer that carries a state needs a geometry "
                              "with state rows (kv_cache.with_rings)")
-        return ((geo.state_rows, a.tail, a.conv_dim),
-                (geo.state_rows, *a.state_shape))
+        rows = geo.state_rows + geo.snapshot_rows
+        return (rows, a.tail, a.conv_dim), (rows, *a.state_shape)
     if a is None:
         shape = (geo.n_pages, geo.page_size, cfg.n_heads * cfg.head_dim)
         return shape, shape

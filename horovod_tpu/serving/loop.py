@@ -218,6 +218,15 @@ class ServeLoop:
       2 pages, or ``LONG_PREFILL_CHUNK`` where every prompt is
       chunk-filled); ``batch_prefill=False`` forces the per-request
       prefill fallback (the counted A/B baseline).
+    - ``snapshot_rows``: for a model with recurrent layers, the pool of
+      snapshot rows that lets the prefix cache hold their state beside
+      the pages (0, the default: no prefix cache for such a model, and
+      every program what it was). A caller's argument, no environment
+      name.
+    - ``fill_head``: ``"all"`` (every position's logits from the chunk
+      program) or ``"last"`` (``chunk_fn`` projects nothing and
+      ``chunk_end_fn`` the prompt's last row: a model with recurrent
+      layers and a large vocabulary).
 
     Which programs exist follows from the geometry alone: a cache no wider
     than ``PADDED_PREFILL_MAX_KV`` gets ``prefill_fn`` and ``bprefill_fn``
@@ -226,7 +235,9 @@ class ServeLoop:
     ``chunk_end_fn`` is None but for a model whose fill leaves the stack
     part-way up (``engine.fill_exit``): its ``chunk_fn`` holds no layer above
     the exit and returns no logits, and ``chunk_end_fn`` runs the chunk that
-    ends a prompt.
+    ends a prompt; and for ``fill_head="last"``, where the two differ in the
+    head alone and a third, ``chunk_tail_fn`` (``jit_chunk_tail``), is
+    ``chunk_end_fn`` one page wide for the last few tokens of a prompt.
     """
 
     @_startup.phase("serve.build")
@@ -234,7 +245,8 @@ class ServeLoop:
                  max_batch=DEFAULT_MAX_BATCH, mode="continuous",
                  load_reporter=None, report_interval=16,
                  prefix_cache=None, spec_tokens=None, drafter=None,
-                 prefill_chunk=None, batch_prefill=True):
+                 prefill_chunk=None, batch_prefill=True, snapshot_rows=0,
+                 fill_head="all"):
         if geo is None:
             geo = kv_cache.geometry(DEFAULT_KV_PAGES, DEFAULT_PAGE_SIZE,
                                     cfg.max_seq_len)
@@ -252,8 +264,10 @@ class ServeLoop:
         self.load_reporter = load_reporter
         self.report_interval = int(report_interval)
         # Latent layers fill by chunks whatever the width; rings of window
-        # state and a slot's state rows cannot be shared between requests, so
-        # no prefix is; a state row cannot be rolled back either.
+        # state cannot be shared between requests, so no prefix is; a slot's
+        # state rows can only be COPIED, from snapshot rows the prefix cache
+        # owns (``snapshot_rows``: 0 = no prefix for such a model); a state
+        # row cannot be rolled back.
         self.has_state = bool(cfg.recurrent)
         if self.has_state and self.spec_tokens > 0:
             raise ValueError(
@@ -267,9 +281,10 @@ class ServeLoop:
         self.prefill_chunk = min(geo.max_kv, int(prefill_chunk))
         geo = self.geo = kv_cache.with_rings(
             geo, cfg, max(self.prefill_chunk, self.spec_tokens + 1),
-            self.max_batch)
+            self.max_batch,
+            snapshot_rows=int(snapshot_rows) if use_prefix else 0)
         use_prefix = (use_prefix and not geo.ring_blocks
-                      and not self.has_state)
+                      and (not self.has_state or geo.snapshot_rows > 0))
         # Whether the decode step reads the cache through the paged kernel
         # (the engine's choice, from backend, mesh and shapes).
         self.decode_paged = engine.decode_attn(cfg, geo, mesh) == "paged"
@@ -301,16 +316,32 @@ class ServeLoop:
         # row. Every other model has the one chunk program.
         self.fill_exit = engine.fill_exit(cfg)
 
-        def chunk_step(ends):
+        def chunk_step(ends, q_len=self.prefill_chunk, **how):
             return kept(
-                engine.make_chunk_step(cfg, geo, mesh,
-                                       q_len=self.prefill_chunk, ends=ends),
-                q_len=self.prefill_chunk, ends=ends)
+                engine.make_chunk_step(cfg, geo, mesh, q_len=q_len,
+                                       ends=ends, **how),
+                q_len=q_len, ends=ends, **how)
 
-        self.chunk_fn = self.chunk_end_fn = None
+        self.chunk_fn = self.chunk_end_fn = self.chunk_tail_fn = None
         if self.fill_exit is not None:
             self.chunk_fn, self.chunk_end_fn = (chunk_step(False),
                                                 chunk_step(True))
+        elif fill_head == "last":
+            # The whole stack on every position, the vocabulary's projection
+            # on the one row a fill reads: none in a chunk that ends no
+            # prompt, the last live row's in the one that ends it.
+            self.chunk_fn, self.chunk_end_fn = (
+                chunk_step(None, head="none"), chunk_step(None, head="last"))
+            # A fill that left a snapshot at its prompt's last whole page
+            # ends on the few tokens behind it: the same program one page
+            # wide (``jit_chunk_tail``), which costs its pass over the
+            # weights and not 512 positions' arithmetic.
+            if geo.page_size < self.prefill_chunk:
+                self.chunk_tail_fn = chunk_step(
+                    None, q_len=geo.page_size, head="last", name="chunk_tail")
+        elif fill_head != "all":
+            raise ValueError(f"fill_head is 'all' or 'last', "
+                             f"got {fill_head!r}")
         elif use_prefix or not padded:
             self.chunk_fn = chunk_step(None)
         self.spec_fn = (engine.make_chunk_step(
@@ -319,26 +350,27 @@ class ServeLoop:
         self.drafter = drafter if drafter is not None \
             else speculate.NGramDrafter()
         self.cache = kv_cache.make_cache(cfg, geo, mesh)
-        self.alloc = PageAllocator(geo.n_pages, geo.page_size)
-        self.prefix = PrefixCache(self.alloc) if use_prefix else None
-        self.batcher = ContinuousBatcher(
-            self.alloc, max_batch, mode, prefix_cache=self.prefix,
-            spec_tokens=self.spec_tokens,
-            ring_allocator=(PageAllocator(geo.ring_pages, geo.page_size)
-                            if geo.ring_blocks else None),
-            ring_blocks=geo.ring_blocks, state_rows=geo.state_rows)
+        # A prefix cache that holds state: the two copies between a slot's
+        # rows and a snapshot row.
+        self.snapshots = (use_prefix and self.has_state
+                          and geo.snapshot_rows > 0)
+        self.snapshot_fn = self.restore_fn = None
+        if self.snapshots:
+            self.snapshot_fn = engine.make_state_copy(cfg, geo,
+                                                      "state_snapshot")
+            self.restore_fn = engine.make_state_copy(cfg, geo,
+                                                     "state_restore")
+        self._use_prefix = use_prefix
+        self.reset()
         self.loop_stats = {"prefill_single": 0, "prefill_batched": 0,
                            "prefill_batch_calls": 0, "chunk_fills": 0,
                            "boundaries": 0,
                            "decode_calls": 0, "decode_paged_calls": 0,
                            "decode_ahead_calls": 0, "decode_ahead_dropped": 0,
                            "kv_pages_read": 0, "kv_pages_gathered_before": 0,
+                           "state_snapshots": 0, "state_restores": 0,
+                           "fill_waits": 0,
                            "host_s": dict.fromkeys(HOST_KINDS, 0.0)}
-        self._fills = {}   # rid -> (admit_seq, tokens materialized)
-        # The decode step that is dispatched and not fetched yet: the chip
-        # runs it while the host plans the step after it. Never more than
-        # this one.
-        self._flight = None
         # What the programs did, by family, counter and program kind:
         # ``{family: {counter: {program kind: n}}}``, published whole as
         # ``serve_stats()[family]``. The families ``attn`` and ``state`` are
@@ -359,6 +391,34 @@ class ServeLoop:
         self._moe_pending = []
         self._moe_load = np.zeros((max(len(cfg.moe_layers), 1),
                                    max(cfg.n_held, 1)), np.int64)
+
+    def reset(self):
+        """The host's half anew: every page and snapshot row free, an empty
+        prefix tree, no request running or waiting. The device's arrays stay
+        as they are (dirty, as a slot's rows always are between requests);
+        the tallies go on. What ``__init__`` builds its scheduler with, and
+        what a caller uses between two runs that must share nothing."""
+        geo = self.geo
+        self.alloc = PageAllocator(geo.n_pages, geo.page_size)
+        self.prefix = (PrefixCache(self.alloc, geo.snapshot_rows,
+                                   first_row=geo.state_rows)
+                       if self._use_prefix else None)
+        self.batcher = ContinuousBatcher(
+            self.alloc, self.max_batch, self.mode, prefix_cache=self.prefix,
+            spec_tokens=self.spec_tokens,
+            ring_allocator=(PageAllocator(geo.ring_pages, geo.page_size)
+                            if geo.ring_blocks else None),
+            ring_blocks=geo.ring_blocks, state_rows=geo.state_rows)
+        self._fills = {}   # rid -> (admit_seq, tokens materialized)
+        # The decode step that is dispatched and not fetched yet: the chip
+        # runs it while the host plans the step after it. Never more than
+        # this one.
+        self._flight = None
+        # Where the cache holds state: the lengths at which each running
+        # request's fill ends a chunk to leave a snapshot (``_marks_of``).
+        self._marks = {}   # rid -> (admit_seq, {length, ..})
+        self._common = {}  # (rid, rid) -> tokens their prompts share
+        self._looked = {}  # rid -> (admit_seq, the tree's snapshots) at rebind
 
     @contextlib.contextmanager
     def _span(self, name, **args):
@@ -475,6 +535,13 @@ class ServeLoop:
                 self._fetch(self._call("chunk",
                                        self.chunk_end_fn or self.chunk_fn,
                                        *slots(1, self.prefill_chunk)))
+                if self.chunk_tail_fn is not None:
+                    self._fetch(self._call("chunk", self.chunk_tail_fn,
+                                           *slots(1, self.geo.page_size)))
+                # The fill's two row copies (the trash row onto itself).
+                if self.snapshots:
+                    for fn in (self.snapshot_fn, self.restore_fn):
+                        self.cache = fn(self.cache, np.int32(0), np.int32(0))
         if self.spec_fn is not None:
             with _startup.phase("warmup.spec"):
                 self._fetch(self._call("spec", self.spec_fn,
@@ -539,36 +606,149 @@ class ServeLoop:
         with self._span("serve.chunk.pack"):
             ctx = list(req.prompt) + list(req.generated)
             target = len(ctx)
-            state = self._fills.get(req.rid)
-            filled = (state[1] if state is not None
-                      and state[0] == req.admit_seq else req.cached_tokens)
-            end = min(filled + self.prefill_chunk, target)
+            filled = self._filled(req)
+            if self.snapshots and not self._begun(req):
+                self._restore(req)
+            marks = self._marks_of(req) if self.snapshots else ()
+            end = min([filled + self.prefill_chunk, target]
+                      + [m for m in marks if m > filled])
+            last = end >= target
+            # The few tokens behind a prompt's last whole page: the program
+            # one page wide, where the loop has one.
+            tail = (last and self.chunk_tail_fn is not None
+                    and end - filled <= self.geo.page_size)
+            fn = (self.chunk_tail_fn if tail
+                  else last and self.chunk_end_fn or self.chunk_fn)
             # Padding: 0, or for a model whose layers carry a state -1, which
             # the program reads as a position that advances no state.
-            toks = np.full((1, self.prefill_chunk), -int(self.has_state),
-                           np.int32)
+            toks = np.full(
+                (1, self.geo.page_size if tail else self.prefill_chunk),
+                -int(self.has_state), np.int32)
             toks[0, :end - filled] = ctx[filled:end]
             bt = np.asarray(
                 self.batcher.block_table(req, self.geo.max_blocks),
                 np.int32)[None]
-            last = end >= target
-            self._count("chunk", np.arange(filled, end)[None] + 1, ends=last)
+            self._count("chunk_tail" if tail else "chunk",
+                        np.arange(filled, end)[None] + 1, ends=last)
         with self._span("serve.chunk.dispatch", rid=req.rid, start=filled,
                         end=end, target=target):
-            step = self._call("chunk",
-                              last and self.chunk_end_fn or self.chunk_fn,
-                              toks, np.asarray([filled], np.int32), bt,
+            step = self._call("chunk", fn, toks,
+                              np.asarray([filled], np.int32), bt,
                               np.ones(1, bool), fetch=last)
         self.loop_stats["chunk_fills"] += 1
+        if end in marks:
+            self._snapshot(req, end)
         if step is None:
             self._fills[req.rid] = (req.admit_seq, end)
             return None
         self._fills.pop(req.rid, None)
+        self._marks.pop(req.rid, None)
         # The request's next token: the last real position's row, which is
-        # the only row of a fill that left the stack.
-        row = 0 if self.fill_exit is not None else end - 1 - filled
+        # the only row of a fill that left the stack or cut its head.
+        row = 0 if self.chunk_end_fn is not None else end - 1 - filled
         step.owners = {req.slot: (req, req.admit_seq, (0, row))}
         return step
+
+    # -- state beside the pages (a prefix cache that holds state) --------
+
+    def _marks_of(self, req):
+        """The page-aligned lengths at which ``req``'s fill ends a chunk and
+        leaves a snapshot (the POLICY, docs/serving.md): the last whole page
+        of its prompt (what the session's next turn will match), the length
+        to which the tree's pages matched it beyond any row (a boundary that
+        another prompt shares), and what requests waiting on it asked for
+        (:meth:`_waits`)."""
+        seq, marks = self._marks.get(req.rid, (None, None))
+        if seq != req.admit_seq:
+            page = self.geo.page_size
+            marks = {req.prompt_len // page * page, req.seen_tokens}
+            self._marks[req.rid] = (req.admit_seq, marks)
+        return marks
+
+    def _begun(self, req):
+        """Whether a chunk of ``req``'s fill, as it is admitted now, ran."""
+        state = self._fills.get(req.rid)
+        return state is not None and state[0] == req.admit_seq
+
+    def _filled(self, req):
+        """Tokens of ``req``'s context that are materialised."""
+        return (self._fills[req.rid][1] if self._begun(req)
+                else req.cached_tokens)
+
+    def _restore(self, req):
+        """The snapshot row a hit was admitted with, copied into its slot's
+        rows (once: the request then holds none)."""
+        if req.snapshot_row < 0:
+            return
+        with self._span("serve.restore.dispatch", rid=req.rid,
+                        row=req.snapshot_row, at=req.cached_tokens):
+            self.cache = self.restore_fn(self.cache, np.int32(req.slot + 1),
+                                         np.int32(req.snapshot_row))
+        req.snapshot_row = -1
+        self.loop_stats["state_restores"] += 1
+
+    def _snapshot(self, req, n):
+        """``req``'s fill stands at ``n`` tokens of its prompt (the chunk that
+        ends there is dispatched): its pages so far join the tree and the
+        slot's rows are copied into a row of the node at ``n``. Every hit
+        that still waits for its restore gets it FIRST: the row this takes
+        may be one of theirs (rows are not pinned), and the device runs the
+        copies in the order they are dispatched."""
+        self.prefix.insert(req.prompt[:n], req.pages)
+        for other in self.batcher.running.values():
+            self._restore(other)
+        row = self.prefix.snapshot(req.prompt, n)
+        if row is None:
+            return
+        with self._span("serve.snapshot.dispatch", rid=req.rid, row=row,
+                        at=n):
+            self.cache = self.snapshot_fn(self.cache, np.int32(req.slot + 1),
+                                          np.int32(row))
+        self.loop_stats["state_snapshots"] += 1
+
+    def _shared(self, a, b):
+        """Tokens the prompts of ``a`` and ``b`` share from the start, in
+        whole pages (lists compare in C; found once a pair)."""
+        key = (a.rid, b.rid)
+        if key not in self._common:
+            if len(self._common) > 4096:
+                self._common.clear()
+            page = self.geo.page_size
+            lo, hi = 0, min(a.prompt_len, b.prompt_len) // page
+            while lo < hi:          # the most pages that are equal
+                mid = (lo + hi + 1) // 2
+                if a.prompt[:mid * page] == b.prompt[:mid * page]:
+                    lo = mid
+                else:
+                    hi = mid - 1
+            self._common[key] = lo * page
+        return self._common[key]
+
+    def _waits(self, req, filling):
+        """Whether ``req``, whose fill has not begun, should let a fill ahead
+        of it go first: an EARLIER admission among ``filling`` whose prompt
+        shares more whole pages with ``req``'s than the cache serves it now,
+        and which has not passed that length. That fill is told to leave a
+        snapshot there (a mark), and ``req`` starts from it a few boundaries
+        on instead of filling the same tokens beside it: thirty-two sessions
+        that arrive together and share a system prompt fill it once. The
+        earliest admission never waits, so somebody always moves."""
+        taken = self.prefix.stats["snapshots"]
+        if self._looked.get(req.rid) != (req.admit_seq, taken):
+            # The tree has a snapshot it had not when this was last asked.
+            self._looked[req.rid] = (req.admit_seq, taken)
+            self.batcher.rebind(req)
+        page = self.geo.page_size
+        cap = (req.prompt_len - 1) // page * page
+        for lead in filling:
+            if lead.admit_seq >= req.admit_seq:
+                continue
+            at = min(self._shared(lead, req), cap)
+            if at > req.cached_tokens and self._filled(lead) < at:
+                self._marks_of(lead).add(at)
+                self.loop_stats["fill_waits"] += 1
+                return True
+        return False
 
     def _decode(self, ready, after=None):
         """Dispatch one jit'd decode step over the slots of ``ready``; ->
@@ -708,6 +888,8 @@ class ServeLoop:
             for req in done:
                 prefilled.pop(req.rid, None)
                 self._fills.pop(req.rid, None)
+                self._marks.pop(req.rid, None)
+                self._looked.pop(req.rid, None)
                 finished.append(req)
                 ttft = req.first_token_t - req.arrival_t
                 _metrics.SERVE_TTFT_SECONDS.observe(max(0.0, ttft))
@@ -845,6 +1027,9 @@ class ServeLoop:
                 for req in sorted(todo, key=lambda r: r.admit_seq):
                     if req.rid in advanced:
                         continue
+                    if (self.snapshots and not self._begun(req)
+                            and self._waits(req, todo)):
+                        continue
                     advanced.add(req.rid)
                     progressed = True
                     step = self._chunk_fill(req)
@@ -910,6 +1095,13 @@ class ServeLoop:
                                  if self.prefix is not None else 0),
             "prefix_nodes": (len(self.prefix)
                              if self.prefix is not None else 0),
+            "prefix_hit_tokens": st["prefix_hit_tokens"],
+            "prefix_prompt_tokens": st["prefix_prompt_tokens"],
+            "snapshot_rows": self.geo.snapshot_rows,
+            "snapshot_rows_owned": (self.prefix.rows_owned()
+                                    if self.snapshots else 0),
+            "snapshot_row_evictions": (self.prefix.stats["row_evictions"]
+                                       if self.snapshots else 0),
             "spec_tokens": self.spec_tokens,
             "spec_steps": st["spec_steps"],
             "spec_accepted_per_step": round(

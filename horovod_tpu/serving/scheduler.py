@@ -114,6 +114,10 @@ class PageAllocator:
         self.page_size = int(page_size)
         self._free = collections.deque(range(1, self.n_pages))
         self._ref = {}             # page -> refcount (>= 1 while owned)
+        # Called with a page when ``free`` leaves it ONE holder: the prefix
+        # cache's hook (it may be that holder, and the page's node evictable
+        # again). None = nobody listens.
+        self.on_cache_only = None
 
     @property
     def usable_pages(self):
@@ -179,6 +183,8 @@ class PageAllocator:
             if self._ref[p] == 0:
                 del self._ref[p]
                 self._free.append(p)
+            elif self._ref[p] == 1 and self.on_cache_only is not None:
+                self.on_cache_only(p)
 
 
 _WAITING, _RUNNING, _DONE = "waiting", "running", "done"
@@ -207,6 +213,12 @@ class Request:
     admit_seq: int = -1         # admission order (preemption picks max)
     cached_tokens: int = 0      # prompt tokens covered by a prefix hit
     ring_pages: list = dataclasses.field(default_factory=list)
+    # A cache that holds state (prefix_cache): the snapshot row that holds
+    # the recurrent layers' state at ``cached_tokens`` (-1: none, the slot's
+    # rows start from zeros), and how far the tree's PAGES matched the prompt
+    # beyond it (a boundary that another prompt shares and no row serves).
+    snapshot_row: int = -1
+    seen_tokens: int = 0
 
     @property
     def prompt_len(self):
@@ -394,6 +406,7 @@ class ContinuousBatcher:
         req.slot = -1
         req.state = _WAITING
         req.cached_tokens = 0   # re-resolved against the cache at readmit
+        req.snapshot_row, req.seen_tokens = -1, 0
         req.preemptions += 1
         self.stats["preemptions"] += 1
         self.waiting.appendleft(req)
@@ -414,9 +427,9 @@ class ContinuousBatcher:
                       if s not in self.running]
         while self.waiting and free_slots:
             req = self.waiting[0]
-            shared, cached = [], 0
+            shared, cached, row, seen = [], 0, None, 0
             if self.prefix is not None:
-                shared, cached = self.prefix.lookup(req.prompt)
+                shared, cached, row, seen = self.prefix.match(req.prompt)
                 # Pin the hit before any allocation can LRU-evict it:
                 # at refcount 2 these pages are invisible to evict().
                 self.alloc.share(shared)
@@ -435,6 +448,8 @@ class ContinuousBatcher:
             self.waiting.popleft()
             req.pages = shared + pages
             req.cached_tokens = cached
+            req.snapshot_row = -1 if row is None else row
+            req.seen_tokens = seen
             req.slot = free_slots.pop(0)
             req.state = _RUNNING
             req.admitted_t = now
@@ -456,6 +471,26 @@ class ContinuousBatcher:
         never written."""
         if self.prefix is not None and req.slot >= 0:
             self.prefix.insert(req.prompt, req.pages)
+
+    def rebind(self, req):
+        """Resolve a running request's prompt against the cache AGAIN, before
+        it has written anything: where the cache now serves more of it than
+        at admission (another request's fill got there meanwhile), the
+        request's own leading pages are given back for the shared ones.
+        -> whether anything changed."""
+        if self.prefix is None or req.slot < 0:
+            return False
+        shared, cached, row, seen = self.prefix.match(req.prompt)
+        req.seen_tokens = max(req.seen_tokens, seen)
+        if cached <= req.cached_tokens:
+            return False
+        self.alloc.share(shared)
+        self.alloc.free(req.pages[:len(shared)])
+        req.pages[:len(shared)] = shared
+        self.stats["prefix_hit_tokens"] += cached - req.cached_tokens
+        req.cached_tokens = cached
+        req.snapshot_row = -1 if row is None else row
+        return True
 
     def prefix_hit_ratio(self):
         """Fraction of admitted prompt tokens served from cached pages —

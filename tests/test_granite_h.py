@@ -1,0 +1,363 @@
+"""Granite-4.0-H-Micro's block at a tiny size on the CPU: the program (the one
+``transformer.block`` under ``forward``, and ``ServeLoop``'s chunk and decode
+programs through the cache) against ``benchmark/reference/granite_h.py``, the
+four multipliers, and the prefix cache that holds state: a hit gives what a
+cold fill gives and what the reference gives; rows and pages are conserved;
+faults planted in the program fail."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.runners import serve_lm, serve_share
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.serving import engine, kv_cache
+from horovod_tpu.serving.loop import ServeLoop, serve_stats
+from horovod_tpu.serving.scheduler import Request
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PAGE, CHUNK = 8, 16
+
+
+def tiny_config():
+    """The published file with every size shrunk (widths kept in their
+    ratios: d_inner = 2 x hidden, query heads 2 x key/value heads)."""
+    with open(os.path.join(
+            ROOT, "benchmark/configs/granite-4.0-h-micro.json")) as f:
+        config = json.load(f)
+    config.update(
+        hidden_size=64, intermediate_size=96, shared_intermediate_size=96,
+        num_attention_heads=4, num_key_value_heads=2, attention_head_dim=16,
+        attention_multiplier=0.1, vocab_size=128, num_hidden_layers=4,
+        layer_types=["mamba", "mamba", "attention", "mamba"],
+        mamba_n_heads=16, mamba_d_head=8, mamba_d_state=16,
+        mamba_chunk_size=8, max_position_embeddings=4096)
+    config["model"] = dict(config["model"], dtype="float32",
+                           param_dtype="float32")
+    return config
+
+
+@pytest.fixture(scope="module")
+def model():
+    config = tiny_config()
+    cfg = serve_share.model_config(config)
+    params = serve_share.make_params(cfg, jax.random.PRNGKey(7))
+    reference = serve_lm.load_reference(config)
+    return config, cfg, params, reference, reference.hyper(config)
+
+
+def rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2))
+                 / np.sqrt(np.mean(want ** 2)))
+
+
+def tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 128, n).tolist()
+
+
+def test_the_file_describes_the_published_model():
+    with open(os.path.join(
+            ROOT, "benchmark/configs/granite-4.0-h-micro.json")) as f:
+        config = json.load(f)
+    cfg = serve_share.model_config(config)
+    kinds = [type(cfg.attn_of(li)).__name__ for li in range(cfg.n_layers)]
+    assert [i for i, k in enumerate(kinds) if k == "MultiHeadAttention"] \
+        == [5, 15, 25, 35]
+    assert kinds.count("StateSpaceMixer") == 36
+    shapes = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0),
+                                                    cfg))
+    n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    assert abs(n / 1e9 - 3.19) < 0.01
+    a = cfg.attn_of(5)
+    assert (a.query_mult, a.rope_dim) == (0.015625 * 8, 0)
+    assert (cfg.embed_mult, cfg.residual_mult, cfg.logits_div) == (12, 0.22, 8)
+
+
+def test_forward_gives_the_reference_on_every_position(model):
+    _, cfg, params, reference, hp = model
+    t = np.asarray([tokens(45)], np.int32)
+    want = reference.logits(reference.from_horovod_tpu(params), t, hp)
+    assert rel(tfm.forward(params, t, cfg), want) < 2e-5
+
+
+def four_groups(cfg, params):
+    """``cfg`` with the gated norm over FOUR groups of channels and nothing
+    else changed: the one group's B and C handed to every group."""
+    m = dict(cfg.state_space)["mamba"]
+    bad = dataclasses.replace(cfg, state_space=(
+        ("mamba", dataclasses.replace(m, n_groups=4)),))
+    d, n = m.d_inner, m.state_size
+
+    def tiled(v, axis):      # [.. z | x | B | C | dt ..] along ``axis``
+        v = jnp.moveaxis(v, axis, 0)
+        lead = v.shape[0] - (d + 2 * n) - (m.n_heads if v.shape[0]
+                                            == m.in_width else 0)
+        parts = [v[:lead + d], *[v[lead + d + i * n:lead + d + (i + 1) * n]
+                                 for i in (0, 0, 0, 0, 1, 1, 1, 1)],
+                 v[lead + d + 2 * n:]]
+        return jnp.moveaxis(jnp.concatenate(parts), 0, axis)
+
+    layers = [dict(layer, w_ssm_in=tiled(layer["w_ssm_in"], 1),
+                   conv_w=tiled(layer["conv_w"], 0),
+                   conv_b=tiled(layer["conv_b"], 0))
+              if "w_ssm_in" in layer else layer for layer in params["layers"]]
+    return bad, dict(params, layers=layers)
+
+
+@pytest.mark.parametrize("left_out", [
+    dict(embed_mult=1.0), dict(residual_mult=1.0), dict(logits_div=1.0),
+    "softmax_scale", "rope", "grouped_norm", dict(tie_embeddings=False)])
+def test_each_piece_left_out_fails(model, left_out):
+    """The four multipliers, the 'nope', the one-group norm and the tied head:
+    a program without one of them is not the reference's model."""
+    _, cfg, params, reference, hp = model
+    theirs = params
+    if left_out == "grouped_norm":
+        bad, theirs = four_groups(cfg, params)
+    elif isinstance(left_out, dict):
+        bad = dataclasses.replace(cfg, **left_out)
+        if not bad.tie_embeddings:
+            theirs = dict(params, head=0.1 * jax.random.normal(
+                jax.random.PRNGKey(1), params["embed"].shape))
+    else:
+        a = dict(cfg.multihead)["attention"]
+        a = (dataclasses.replace(a, softmax_scale=None)
+             if left_out == "softmax_scale"
+             else dataclasses.replace(a, rope_share=1.0))
+        bad = dataclasses.replace(cfg, multihead=(("attention", a),))
+    t = np.asarray([tokens(45)], np.int32)
+    want = reference.logits(reference.from_horovod_tpu(params), t, hp)
+    assert rel(tfm.forward(theirs, t, bad), want) > 0.02
+
+
+@pytest.mark.parametrize("fault", [
+    "embedding_multiplier_left_out", "residual_multiplier_left_out",
+    "logits_scaling_left_out", "attention_scale_head_dim",
+    "state_not_restored", "tail_not_restored", "stale_snapshot",
+    "state_in_bfloat16"])
+def test_the_references_faults_move_the_logits(model, fault):
+    _, cfg, params, reference, hp = model
+    t = np.asarray([tokens(45)], np.int32)
+    w = reference.from_horovod_tpu(params)
+    rows = (40, 41, 42, 43, 44)
+    want = reference.logits(w, t, hp, rows=rows)
+    bad = reference.logits(w, t, hp, rows=rows,
+                           kn=reference.knobs(hp, fault, hit_at=32,
+                                              stale_by=16))
+    # (bfloat16 state: 25 times what float32 rounding leaves, 2e-5)
+    assert rel(bad, want) > (2e-4 if fault == "state_in_bfloat16" else 0.01)
+    same = reference.logits(w, t, hp, rows=rows,
+                            kn=reference.knobs(hp, None, hit_at=32))
+    assert rel(same, want) == 0.0
+
+
+def make_loop(model, rows, **kw):
+    _, cfg, params, _, _ = model
+    geo = kv_cache.geometry(kw.pop("n_pages", 96), PAGE, 256)
+    return ServeLoop(params, cfg, geo=geo, max_batch=kw.pop("max_batch", 2),
+                     prefill_chunk=CHUNK, snapshot_rows=rows, **kw)
+
+
+def serve(loop, prompt, rid=0, new=4):
+    """One request served alone -> (its logit rows ``[new, V]`` read off the
+    loop's steps, the request): the benchmark's own check."""
+    return serve_share.served_rows(loop, prompt, rid, new)[:2]
+
+
+def reference_rows(model, req):
+    _, _, params, reference, hp = model
+    seq = list(req.prompt) + req.generated[:-1]
+    rows = tuple(range(len(req.prompt) - 1, len(seq)))
+    return np.asarray(reference.logits(
+        reference.from_horovod_tpu(params), np.asarray([seq], np.int32), hp,
+        rows=rows)[0])
+
+
+def conserved(loop):
+    p = loop.prefix
+    assert p.rows_free() + p.rows_owned() == loop.geo.snapshot_rows
+    held = set(p.cached_pages())
+    for r in loop.batcher.running.values():
+        held |= set(r.pages)
+    assert loop.alloc.free_pages() + len(held) == loop.alloc.usable_pages
+    owned = [n.row for n in p._rows.values()]
+    assert sorted(owned) == sorted(p._rows) and len(set(owned)) == len(owned)
+
+
+def test_chunks_and_decode_through_dirty_rows_give_the_reference(model):
+    loop = make_loop(model, 0, fill_head="last")
+    assert loop.prefix is None and loop.chunk_end_fn is not None
+    serve(loop, tokens(60, 9))                     # leaves slot 0 dirty
+    got, req = serve(loop, tokens(53, 1), rid=1)
+    assert float(np.abs(np.asarray(loop.cache["v"][0][1])).max()) > 0
+    assert rel(got, reference_rows(model, req)) < 2e-5
+
+
+def test_a_hit_gives_what_cold_gives_and_the_reference(model):
+    a = tokens(70, 2)
+    b = a[:64] + tokens(30, 3)         # A's whole pages, then a new tail
+    cold = make_loop(model, 0, fill_head="last")
+    want_a, _ = serve(cold, a)
+    want_b, cold_b = serve(cold, b, rid=1)
+    assert cold_b.cached_tokens == 0
+    loop = make_loop(model, 4, fill_head="last")
+    got_a, _ = serve(loop, a)
+    conserved(loop)
+    got_b, hit_b = serve(loop, b, rid=1)
+    assert hit_b.cached_tokens == 64
+    assert serve_stats()["state_restores"] == 1
+    np.testing.assert_allclose(got_a, want_a, rtol=0, atol=0)
+    assert rel(got_b, want_b) < 2e-6
+    assert rel(got_b, reference_rows(model, hit_b)) < 2e-5
+    conserved(loop)
+
+
+def test_a_session_of_three_turns_and_two_sessions_on_one_prefix(model):
+    loop = make_loop(model, 6, fill_head="last")
+    prefix = tokens(48, 4)
+    said = prefix + tokens(21, 5)
+    hits = []
+    for turn in range(3):              # turn k + 1 extends turn k
+        got, req = serve(loop, said, rid=turn)
+        hits.append(req.cached_tokens)
+        assert rel(got, reference_rows(model, req)) < 2e-5
+        said = said + tokens(19, 6 + turn)
+        conserved(loop)
+    assert hits == [0, 64, 88 // PAGE * PAGE]
+    # A second session shares the system prompt only: its pages match to 48,
+    # no row lies there, so it fills cold and LEAVES one there; the third
+    # starts from it.
+    got, second = serve(loop, prefix + tokens(30, 20), rid=10)
+    assert (second.cached_tokens, second.seen_tokens) == (0, 48)
+    got, third = serve(loop, prefix + tokens(30, 21), rid=11)
+    assert third.cached_tokens == 48
+    assert rel(got, reference_rows(model, third)) < 2e-5
+    conserved(loop)
+
+
+def test_a_match_longer_than_its_deepest_snapshot(model):
+    loop = make_loop(model, 1, fill_head="last")       # ONE row
+    a = tokens(70, 30)
+    serve(loop, a)                                     # row at 64
+    serve(loop, tokens(40, 31), rid=1)                 # takes the row
+    assert loop.prefix.stats["row_evictions"] == 1
+    got, req = serve(loop, a + tokens(10, 32), rid=2)  # pages match, no row
+    assert (req.cached_tokens, req.seen_tokens) == (0, 64)
+    assert rel(got, reference_rows(model, req)) < 2e-5
+    conserved(loop)
+
+
+def test_snapshot_rows_hold_the_float32_state_bit_for_bit(model):
+    """What the benchmark's ``state_rel`` cannot see: a state rounded ONCE, at
+    the snapshot. The pool's rows are float32 whatever the compute dtype, and
+    the two copy programs move a row as it is."""
+    config = tiny_config()
+    config["model"] = dict(config["model"], dtype="bfloat16")
+    low = serve_share.model_config(config)
+    geo = kv_cache.with_rings(kv_cache.geometry(96, PAGE, 256), low, CHUNK,
+                              2, snapshot_rows=3)
+    for li, (tail, state) in enumerate(zip(*(kv_cache.make_cache(
+            low, geo)[name] for name in ("k", "v")))):
+        if isinstance(low.attn_of(li), tfm.RECURRENT):
+            assert state.dtype == jnp.float32 and tail.dtype == jnp.bfloat16
+            assert state.shape[0] == tail.shape[0] == 2 + 1 + 3
+    loop = make_loop(model, 3, fill_head="last")
+    serve(loop, tokens(60, 40))                        # slot 0's rows dirty
+    first = loop.geo.state_rows                        # the pool's first row
+    cache = loop.snapshot_fn(loop.cache, np.int32(1), np.int32(first + 2))
+    cache = loop.restore_fn(cache, np.int32(2), np.int32(first + 2))
+    for name in ("k", "v"):
+        for li, rows in enumerate(cache[name]):
+            if isinstance(model[1].attn_of(li), tfm.RECURRENT):
+                rows = np.asarray(rows)
+                assert np.abs(rows[1]).max() > 0
+                assert (rows[first + 2] == rows[1]).all()
+                assert (rows[2] == rows[1]).all()
+    loop.cache = cache
+
+
+def test_eviction_under_pressure_conserves_rows_and_pages(model):
+    loop = make_loop(model, 3, fill_head="last", n_pages=40, max_batch=2)
+    rng = np.random.default_rng(40)
+    prefix = tokens(32, 41)
+    reqs = [Request(rid=i, prompt=prefix + tokens(int(rng.integers(20, 90)),
+                                                  50 + i),
+                    max_new_tokens=6, arrival_t=0.0) for i in range(9)]
+    seen = []
+    loop.load_reporter, loop.report_interval = (
+        lambda *a: (conserved(loop), seen.append(1))), 1
+    _, done = loop.run(reqs)
+    assert len(done) == 9 and seen
+    assert loop.prefix.stats["evictions"] > 0
+    assert loop.prefix.stats["row_evictions"] > 0
+    conserved(loop)
+    # what it served under eviction is still the model
+    got, req = serve(loop, prefix + tokens(25, 99), rid=99)
+    assert req.cached_tokens in (0, 32)
+    assert rel(got, reference_rows(model, req)) < 2e-5
+
+
+@pytest.mark.parametrize("fault", ["state_not_restored", "tail_not_restored",
+                                   "snapshot_of_the_chunk_before"])
+def test_faults_planted_in_the_program_fail(model, monkeypatch, fault):
+    make = engine.make_state_copy
+
+    def broken(cfg, geo, name):
+        sound = make(cfg, geo, name)
+        if name != "state_restore":
+            return sound
+        lost = "v" if fault == "state_not_restored" else "k"
+
+        def restore(cache, src, dst):
+            kept = cache[lost]
+            out = sound(dict(cache, **{lost: tuple(
+                None if c is None else c + 0 for c in kept)}), src, dst)
+            return dict(out, **{lost: kept})
+        return restore
+
+    a = tokens(70, 2)
+    b = a[:64] + tokens(12, 3)
+    if fault == "snapshot_of_the_chunk_before":
+        # the copy is dispatched BEFORE the chunk that ends at the mark
+        fill = ServeLoop._chunk_fill
+
+        def chunk_fill(self, req):
+            marks = self._marks_of(req)
+            at = self._filled(req)
+            end = min([at + self.prefill_chunk, req.context_len]
+                      + [m for m in marks if m > at])
+            if end in marks and end < req.context_len:
+                marks.discard(end)          # the sound one is not taken
+                self.prefix.insert(req.prompt[:end], req.pages)
+                row = self.prefix.snapshot(req.prompt, end)
+                self.cache = self.snapshot_fn(
+                    self.cache, np.int32(req.slot + 1), np.int32(row))
+            return fill(self, req)
+        monkeypatch.setattr(ServeLoop, "_chunk_fill", chunk_fill)
+    else:
+        monkeypatch.setattr(engine, "make_state_copy", broken)
+    loop = make_loop(model, 4, fill_head="last")
+    serve(loop, a)
+    got, req = serve(loop, b, rid=1)
+    assert req.cached_tokens == 64
+    assert rel(got, reference_rows(model, req)) > 1e-3
+
+
+def test_snapshot_rows_0_builds_todays_programs(model):
+    """No snapshot rows: no prefix for a model with state, the geometry and
+    the chunk program's text what they are without the argument."""
+    _, cfg, params, _, _ = model
+    loop = make_loop(model, 0)
+    assert loop.prefix is None and not loop.snapshots
+    assert loop.geo.snapshot_rows == 0 and loop.chunk_end_fn is None
+    geo = kv_cache.with_rings(kv_cache.geometry(96, PAGE, 256), cfg, CHUNK, 2)
+    assert geo == loop.geo
+    with_rows = make_loop(model, 3)
+    assert with_rows.geo.state_rows == 3 and with_rows.geo.snapshot_rows == 3
+    assert with_rows.cache["v"][0].shape[0] == 6

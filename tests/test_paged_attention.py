@@ -240,7 +240,8 @@ def _grouped_case(rng, a, *, B, Q, width, lens, page=PAGE):
     (72, 8, 128, 512),    # the published window layer's, on a ring
     (6, 2, 32, 40),       # a window the ring barely holds
     (4, 4, 64, 0),        # Hq == Hkv: what paged_decode_attention computes
-], ids=["full-6x8", "window-9x8", "window-small", "one-each"])
+    (32, 8, 64, 0),       # heads of 64 over 8: two heads a lane tile
+], ids=["full-6x8", "window-9x8", "window-small", "one-each", "narrow-4x8"])
 def test_grouped_kernel_decodes_like_the_gathered_tier(heads, kv_heads,
                                                        head_dim, window):
     """One query a slot (the decode step): slots of mixed lengths, one
@@ -259,8 +260,8 @@ def test_grouped_kernel_decodes_like_the_gathered_tier(heads, kv_heads,
 
 @pytest.mark.parametrize("heads, kv_heads, head_dim, window, q_block", [
     (12, 2, 32, 0, 8), (18, 2, 32, 24, 8), (18, 2, 32, 24, 32),
-    (4, 4, 32, 0, 16),
-], ids=["full", "window", "window-one-block", "one-each"])
+    (4, 4, 32, 0, 16), (8, 4, 64, 0, 16),
+], ids=["full", "window", "window-one-block", "one-each", "narrow"])
 def test_grouped_kernel_fills_a_chunk_like_the_gathered_tier(
         heads, kv_heads, head_dim, window, q_block):
     """A block of 32 queries a slot (the chunk fill), in query blocks of
@@ -347,3 +348,18 @@ def test_programs_with_the_grouped_kernel_match_the_gather_path(monkeypatch):
         rows[kernels] = np.concatenate(out)
         monkeypatch.undo()
     np.testing.assert_allclose(rows[True], rows[False], atol=2e-4, rtol=2e-4)
+
+
+def test_who_takes_the_grouped_kernel():
+    """A head of whole lane tiles, or narrower heads that fill one together
+    (64 wide in pairs: the pack is attended as one head of 128)."""
+    from horovod_tpu.ops.pallas_paged_attention import (_heads_packed,
+                                                        grouped_supported)
+
+    bf16 = jnp.bfloat16
+    assert grouped_supported(16, 128, bf16) and grouped_supported(16, 256, bf16)
+    assert not grouped_supported(16, 64, bf16)         # no head count given
+    assert grouped_supported(16, 64, bf16, 8)
+    assert not grouped_supported(16, 64, bf16, 3)      # an odd head is left
+    assert not grouped_supported(16, 96, bf16, 8)
+    assert (_heads_packed(64, 8), _heads_packed(32, 8)) == (2, 4)

@@ -23,6 +23,9 @@ from horovod_tpu.serving import engine
 CELL = (2, 128, 64, 8, 128)
 TINY = (3, 8, 8, 2, 16)
 ODD = (2, 6, 16, 2, 32)
+# `granite-serve-agent-share-over`: 64 heads of 64 in ONE group, which the
+# kernel takes as four packs of 16 heads that share B and C.
+ONE_GROUP = (2, 64, 64, 1, 128)
 
 
 def _operands(shape, dtype=jnp.float32, spare_rows=0, seed=0):
@@ -52,8 +55,8 @@ def _close(got, want, tol=2e-6):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("shape", [CELL, TINY, ODD],
-                         ids=["cell", "tiny", "odd"])
+@pytest.mark.parametrize("shape", [CELL, TINY, ODD, ONE_GROUP],
+                         ids=["cell", "tiny", "odd", "one-group"])
 def test_against_the_one_token_update(shape, dtype):
     """``y`` and the state leaving, for operands in the compute dtype the
     mixer hands over (the cell's is bfloat16; the state is float32 always)."""
@@ -142,6 +145,13 @@ def test_who_takes_the_kernel():
                                 state_size=16)
     assert pallas_ssm.supported(served)
     assert not pallas_ssm.supported(small)
+    # 64 heads of 64 in one group: four packs of 16 heads (512 registers);
+    # 24 heads of 64 are one and a half packs.
+    assert pallas_ssm.supported(tfm.StateSpaceMixer(
+        n_heads=64, head_dim=64, n_groups=1, state_size=128))
+    assert pallas_ssm._packs(64, 8) == 4 and pallas_ssm._packs(16, 8) == 1
+    assert not pallas_ssm.supported(tfm.StateSpaceMixer(
+        n_heads=24, head_dim=64, n_groups=1, state_size=128))
     cfg = tfm.TransformerConfig(
         vocab_size=32, d_model=16, n_layers=1, state_space={"M": served},
         layer_attn=("M",), layer_parts=("mixer",), norm="rmsnorm")

@@ -410,7 +410,7 @@ def test_prefix_insert_then_lookup_shares_pages():
     prompt = list(range(10))             # 2 full pages + 2-token tail
     assert pc.insert(prompt, pages) == 2   # only full pages are cached
     assert a.refcount(pages[0]) == 2 and a.refcount(pages[2]) == 1
-    hit, n = pc.lookup(prompt)
+    hit, n = pc.match(prompt)[:2]
     assert hit == pages[:2] and n == 8
     # lookup takes NO references — sharing is the caller's decision
     assert a.refcount(pages[0]) == 2
@@ -423,11 +423,11 @@ def test_prefix_lookup_is_strict():
     a, pc = _cache(page_size=4)
     pages = a.alloc(2)
     pc.insert(list(range(8)), pages)
-    hit, n = pc.lookup(list(range(8)))
+    hit, n = pc.match(list(range(8)))[:2]
     assert hit == pages[:1] and n == 4   # NOT both pages
-    hit, n = pc.lookup(list(range(9)))
+    hit, n = pc.match(list(range(9)))[:2]
     assert hit == pages[:2] and n == 8   # one tail token -> full match
-    assert pc.lookup(list(range(3)))[1] == 0   # sub-page prompt: miss
+    assert pc.match(list(range(3))).tokens == 0   # sub-page prompt: miss
 
 
 def test_prefix_radix_shares_common_nodes():
@@ -448,11 +448,105 @@ def test_prefix_lru_eviction_order():
     pc.insert([1, 1, 1, 1, 9], pa)
     pc.insert([2, 2, 2, 2, 9], pb)
     a.free(pa + pb)                      # cache is now the only holder
-    pc.lookup([1, 1, 1, 1, 9])           # touch A — B becomes LRU
+    pc.match([1, 1, 1, 1, 9])            # touch A — B becomes LRU
     assert pc.evict(1) == 1
-    assert pc.lookup([2, 2, 2, 2, 9])[1] == 0   # B gone
-    assert pc.lookup([1, 1, 1, 1, 9])[1] == 4   # A survives
+    assert pc.match([2, 2, 2, 2, 9]).tokens == 0   # B gone
+    assert pc.match([1, 1, 1, 1, 9]).tokens == 4   # A survives
     assert a.refcount(pb[0]) == 0
+
+
+def test_prefix_evict_costs_what_it_frees():
+    """A tree of 2,000 nodes: a call that frees 3 pages looks at a handful
+    of heap entries, not at the tree; a leaf a request shares is offered
+    again by the allocator when the request lets go."""
+    a, pc = _cache(n_pages=2100, page_size=4)
+    held = []
+    for chain in range(20):              # 20 chains of 100 pages
+        pages = a.alloc(100)
+        prompt = [chain] * 4 + list(range(396)) + [7]
+        assert pc.insert(prompt, pages) == 100
+        held.append(pages)
+    for pages in held[1:]:
+        a.free(pages)                    # chain 0 stays shared by a request
+    assert len(pc) == 2000
+    before = pc.stats["evict_visits"]
+    assert pc.evict(3) == 3
+    assert pc.stats["evict_visits"] - before <= 3 + 20
+    assert pc.match([0] * 4 + list(range(396)) + [7]).tokens == 400   # untouched
+    # the oldest chain went first, leaf by leaf
+    assert pc.match([1] * 4 + list(range(396)) + [7]).tokens == 400 - 12
+    visits = pc.stats["evict_visits"]
+    assert pc.evict(1900 - 3 + 5) == 1900 - 3    # all but the shared chain
+    assert pc.stats["evict_visits"] - visits <= 2 * 1900
+    a.free(held[0])                      # ... which the allocator now offers
+    assert pc.evict(1) == 1 and len(pc) == 99
+
+
+def test_prefix_snapshot_rows_are_held_like_pages():
+    a = sched.PageAllocator(64, 4)
+    pc = prefix_cache.PrefixCache(a, snapshot_rows=2, first_row=5)
+    pa, pb, pd = a.alloc(3), a.alloc(2), a.alloc(1)
+    A = list(range(12)) + [99]
+    B = [50, 51, 52, 53, 54, 55, 56, 57, 99]
+    D = [60, 61, 62, 63, 99]
+    pc.insert(A, pa), pc.insert(B, pb), pc.insert(D, pd)
+
+    def held():
+        assert pc.rows_free() + pc.rows_owned() == 2
+
+    assert pc.match(A) == ([], 0, None, 12)        # pages seen, no row
+    assert pc.snapshot(A, 8) == 5 and pc.snapshot(A, 8) is None
+    assert pc.snapshot(A, 6) is None and pc.snapshot([1, 2, 3, 4], 4) is None
+    held()
+    assert pc.match(A) == (pa[:2], 8, 5, 12)       # usable down to the row
+    assert pc.snapshot(B, 8) == 6
+    pc.match(A)                                    # row 5 is the newer source
+    assert pc.snapshot(D, 4) == 6                  # B's goes: LRU by use
+    assert pc.stats["row_evictions"] == 1 and pc.match(B) == ([], 0, None, 8)
+    held()
+    a.free(pa + pb + pd)
+    assert pc.evict(64) == 6 and pc.rows_free() == 2
+    held()
+
+
+def test_a_superseded_snapshot_row_goes_first():
+    """A session that goes on holds one row: turn k's goes when turn k + 1
+    has left its own and a row is needed, before the older row at the fork
+    where two sessions part."""
+    a = sched.PageAllocator(64, 4)
+    pc = prefix_cache.PrefixCache(a, snapshot_rows=3)
+    system = [1, 2, 3, 4]
+    one = system + [10, 11, 12, 13, 14, 15, 16, 17, 18, 19, 20, 21, 9]
+    two = system + [30, 31, 32, 33, 9]
+    mine = a.alloc(4)
+    pc.insert(one, mine), pc.insert(two, mine[:1] + a.alloc(1))
+    assert pc.snapshot(one, 4) == 0          # the fork
+    assert pc.snapshot(one, 8) == 1          # session one, turn 1
+    assert pc.snapshot(one, 16) == 2         # ... turn 2: row 1 superseded
+    assert pc.snapshot(two, 8) == 1          # taken from turn 1, not the fork
+    assert pc.match(one)[:3] == (pc.match(one).pages, 16, 2)
+    assert pc.match(two).row == 1 and pc.match(system + [5] * 5).row == 0
+    assert pc.rows_free() + pc.rows_owned() == 3
+
+
+def test_rebind_swaps_own_pages_for_shared_ones():
+    a = sched.PageAllocator(64, 4)
+    pc = prefix_cache.PrefixCache(a, snapshot_rows=2, first_row=3)
+    b = sched.ContinuousBatcher(a, 2, prefix_cache=pc, state_rows=3)
+    lead = sched.Request(rid=0, prompt=list(range(12)) + [1], max_new_tokens=2)
+    late = sched.Request(rid=1, prompt=list(range(12)) + [2], max_new_tokens=2)
+    b.submit(lead), b.submit(late)
+    b.admit()
+    assert late.cached_tokens == 0 and not b.rebind(late)
+    pc.insert(lead.prompt[:8], lead.pages)
+    assert pc.snapshot(lead.prompt, 8) == 3
+    mine = list(late.pages)
+    assert b.rebind(late)
+    assert (late.cached_tokens, late.snapshot_row) == (8, 3)
+    assert late.pages[:2] == lead.pages[:2] and late.pages[2:] == mine[2:]
+    assert a.refcount(mine[0]) == 0 and a.refcount(lead.pages[0]) == 3
+    assert b.stats["prefix_hit_tokens"] == 8
+    assert a.free_pages() + a.used_pages() == a.usable_pages
 
 
 def test_prefix_evict_skips_shared_and_interior_pages():

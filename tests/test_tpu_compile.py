@@ -1084,3 +1084,89 @@ def test_sambay_cell_programs_fit_one_chip(topo, as_on_the_chip):
             # A second copy of a layer's slots would be this large.
             assert memory.temp_size_in_bytes < 8 * state + 2 * B * 200064 * 4
         print(name, memory.temp_size_in_bytes, fresh, kept, n_args)
+
+
+# ---- the serving programs at benchmark/configs/granite-4.0-h-micro.json ----
+
+def test_share_cell_programs_fit_one_chip(topo, as_on_the_chip):
+    """``granite-serve-agent-share-over``'s programs at the cell's geometry
+    (33 state rows and the pool of snapshot rows behind them), on ONE period
+    of the model's ten layers (nine Mamba-2 layers of 64 heads in one group,
+    one attention layer of 32 query heads over 8 key/value heads of 64; the
+    cell runs four such periods): the chip's compiler takes both kernels at
+    the new shapes (``ssm_decode_update`` as four packs of 16 heads,
+    ``paged_full_attention`` with two 64-wide heads a lane tile), the cache
+    is aliased through every program, the decode step passes over a layer's
+    state once and touches no snapshot row's worth of temporaries, the fill's
+    two programs cut the head, and the state copy is in place."""
+    import dataclasses
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        from benchmark.runners import serve_share
+    finally:
+        sys.path.remove(root)
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        config = json.load(f)
+    whole = serve_share.model_config(config)
+    cfg = dataclasses.replace(whole, n_layers=10,
+                              layer_attn=whole.layer_attn[:10])
+    srv = config["assumed"]["serve"]
+    B, chunk, rows = srv["max_batch"], srv["chunk"], srv["snapshot_rows"]
+    geo = kv_cache.with_rings(
+        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
+        cfg, chunk, B, snapshot_rows=rows)
+    assert (geo.max_kv, geo.state_rows, geo.snapshot_rows,
+            geo.table_width) == (16384, B + 1, rows, 1025)
+    assert engine._kernels(cfg, geo, None, one_query=True) == {
+        "latent": False, "grouped": True, "state": True, "linear": False}
+    # The whole model and its cache, by shape: what the serve block's why says.
+    full_geo = kv_cache.with_rings(geo, whole, chunk, B, snapshot_rows=rows)
+    n_params = sum(x.size for x in jax.tree.leaves(jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), whole))))
+    assert 3.18e9 < n_params < 3.20e9
+    row = 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
+    assert kv_cache.cache_bytes(whole, full_geo) == (
+        (B + 1 + rows) * row + 4 * 2 * srv["n_pages"] * 16 * 512 * 2)
+    assert 2 * n_params + kv_cache.cache_bytes(whole, full_geo) < 14.2e9
+
+    params, cache = jax.tree.map(
+        lambda x: _on_chip(topo, x.shape, x.dtype),
+        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
+                                kv_cache.make_cache(cfg, geo))))
+    layer_state = 4 * (B + 1 + rows) * 64 * 64 * 128
+
+    def slots(b, *q):
+        return [_on_chip(topo, s, d) for s, d in (
+            ((b, *q), jnp.int32), ((b,), jnp.int32),
+            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
+
+    def calls(text, kernel):
+        return len([line for line in text.splitlines()
+                    if re.match(rf"\s*%{kernel}[.\d]* = ", line)
+                    and "tpu_custom_call" in line])
+
+    scalar = _on_chip(topo, (), jnp.int32)
+    for name, fn, args, ssm in (
+            ("chunk", engine.make_chunk_step(cfg, geo, q_len=chunk,
+                                             head="none"),
+             [params, cache] + slots(1, chunk), 0),
+            ("chunk_end", engine.make_chunk_step(cfg, geo, q_len=chunk,
+                                                 head="last"),
+             [params, cache] + slots(1, chunk), 0),
+            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
+             [params, cache] + slots(B), 9),
+            ("copy", engine.make_state_copy(cfg, geo, "state_snapshot"),
+             [cache, scalar, scalar], 0)):
+        compiled = fn.lower(*args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= kv_cache.cache_bytes(cfg, geo)
+        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
+        # no copy of a layer's rows, and no float32 logits of 512 positions
+        assert memory.temp_size_in_bytes + fresh < layer_state, name
+        text = compiled.as_text()
+        assert calls(text, "paged_full_attention") == (name != "copy"), name
+        assert calls(text, "ssm_decode_update") == ssm, name

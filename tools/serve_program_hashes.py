@@ -21,8 +21,11 @@ module printed without locations.
 
 ``cpu``: the plain tier, at the tiny configurations the tier-1 tests serve
 (``tests/test_serving.py``, ``test_olmoe.py``, ``test_dots3.py``,
-``test_laguna.py``, ``test_nemotron_h.py``, ``test_sarvam_mla.py``; the
-helpers of those files build them). ``chip``: the kernels' tier, a
+``test_laguna.py``, ``test_nemotron_h.py``, ``test_sarvam_mla.py``,
+``test_solar_open2.py``, ``test_phi4_flash.py``, ``test_granite_h.py``; the
+helpers of those files build them; ``granite_h`` with no snapshot rows and
+``granite_h-share`` with the prefix cache that holds state and its two copy
+programs). ``chip``: the kernels' tier, a
 ``ServeLoop`` built as each serve cell's runner builds it (the cell's
 configuration file, geometry, slots and chunk; parameters by shape only).
 ``described``: the same with no chip, lowered for a described ``v5e:2x2``
@@ -45,7 +48,7 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "tests")]
 # cell's configuration -> its runner (``benchmark/configs/<name>.json``).
 CELLS = ("gpt2-large", "olmoe-1b-7b", "dots3-note-prev", "laguna-s-2.1",
          "nemotron-3-super-120b", "sarvam-105b", "solar-open2-250b",
-         "phi-4-mini-flash-reasoning")
+         "phi-4-mini-flash-reasoning", "granite-4.0-h-micro")
 
 
 def _sha(text):
@@ -100,14 +103,25 @@ def _programs(loop, like):
         # (and an attribute only since the loop has such models).
         "chunk_end": (getattr(loop, "chunk_end_fn", None),
                       slots(1, loop.prefill_chunk)),
+        # The fill's last few tokens, one page wide (with a cut head only).
+        "chunk_tail": (getattr(loop, "chunk_tail_fn", None),
+                       slots(1, geo.page_size)),
         "spec": (loop.spec_fn, slots(B, loop.spec_tokens + 1)),
         "prefill": (loop.prefill_fn, [like((geo.max_kv,), np.int32),
                                       like((), np.int32),
                                       like((mb,), np.int32)]),
         "bprefill": (loop.bprefill_fn, slots(B, geo.max_kv)),
     }
-    return {name: _hashes(fn.lower(params, cache, *args))
-            for name, (fn, args) in calls.items() if fn is not None}
+    found = {name: _hashes(fn.lower(params, cache, *args))
+             for name, (fn, args) in calls.items() if fn is not None}
+    # The two copies of a prefix cache that holds state (an attribute only
+    # since the loop has one).
+    for name in ("snapshot", "restore"):
+        fn = getattr(loop, name + "_fn", None)
+        if fn is not None:
+            found["state_" + name] = _hashes(fn.lower(
+                cache, like((), np.int32), like((), np.int32)))
+    return found
 
 
 def _tiny_loops():
@@ -136,9 +150,16 @@ def _tiny_loops():
         test = importlib.import_module("test_" + name.partition("-")[0])
         kind = test._cfg(test._config())
         yield name, lambda: test._loop(kind, abstract(kind), **kw)
-    test = importlib.import_module("test_nemotron_h")
-    hybrid = test.runner.model_config(test._config())
-    yield "nemotron_h", lambda: test._loop(hybrid, abstract(hybrid))
+    for name in ("nemotron_h", "solar_open2", "phi4_flash"):
+        test = importlib.import_module("test_" + name)
+        kind = test.runner.model_config(test._config())
+        yield name, lambda: test._loop(kind, abstract(kind))
+    test = importlib.import_module("test_granite_h")
+    shared = test.serve_share.model_config(test.tiny_config())
+    model = (None, shared, abstract(shared), None, None)
+    yield "granite_h", lambda: test.make_loop(model, 0)
+    yield "granite_h-share", lambda: test.make_loop(model, 3,
+                                                    fill_head="last")
 
 
 def _cell_loops():
@@ -169,6 +190,8 @@ def _cell_loops():
         geo = kv_cache.geometry(srv["n_pages"], srv["page_size"],
                                 srv["context"])
         kw = {"prefill_chunk": srv["chunk"]} if "chunk" in srv else {}
+        kw.update({key: srv[key] for key in ("snapshot_rows", "fill_head")
+                   if key in srv})
         yield name, lambda: ServeLoop(params, cfg, geo=geo,
                                       max_batch=srv["max_batch"], **kw)
 
