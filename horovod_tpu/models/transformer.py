@@ -1832,10 +1832,12 @@ def state_space_mix(u, layer, a: StateSpaceMixer, cfg, tail=None, state=None,
     slot's, the decode step with a window of one.
 
     ``recur(x, step, rate, b_in, c_out) -> (y, the state leaving)`` stands in
-    for the recurrence where the caller owns the state where it lies and
-    ``state`` is None: the decode step's kernel over the layer's own array
-    (``serving/engine.py`` ``_state_layer``), the same values as
-    :func:`_ssd_step`. Everything around it is this function's either way.
+    for the recurrence where the caller owns the state and ``state`` is None
+    (``serving/engine.py`` ``_state_layer``): the decode step's kernel over
+    the layer's own array where it lies, the same values as :func:`_ssd_step`,
+    and the chunk program's kernel of the chunked form with the slot's rows
+    bound to it, the same values as :func:`_ssd_blocks`. Everything around it
+    is this function's either way.
 
     ``[z | xBC | dt] = u W_in``; ``xBC`` through the depthwise causal
     convolution and SiLU; ``step = softplus(dt + dt_bias)``, ``rate =

@@ -1,5 +1,7 @@
-"""A decode step's state update and read-out of a state-space layer in ONE
-pass over the state, in place (Pallas TPU).
+"""The state-space layers' recurrence as Pallas TPU kernels: a decode step's
+state update and read-out in ONE pass over the state, in place
+(``ssm_decode_update``, below), and a window's chunked form in one kernel a
+layer (``ssm_chunk_scan``, further down, with its own notes).
 
 ``transformer._ssd_step`` is the definition: per slot and head, ``S = keep S
 + dx (x) B`` and ``y = sum_n S C`` over a state ``S [P, N]`` in float32, with
@@ -206,3 +208,261 @@ def ssm_decode_update(x, step, rate, b_in, c_out, state, begins, *,
     )(begins.astype(jnp.int32), keep.reshape(-1), _pack(dx, hpg),
       lanes(b_in), lanes(c_out), state)
     return _unpack(y, hpg).reshape(B, 1, H, P), state
+
+
+# ---- a window of more than one position: the chunked form in one kernel ----
+#
+# ``transformer._ssd_blocks`` is the definition (Mamba-2's state-space
+# duality): inside a block position q takes ``exp(cs_q - cs_s) (C_q . B_s)
+# step_s x_s`` of every s <= q, with ``cs`` the running sum of ``step rate``;
+# the state entering gives it ``exp(cs_q) (C_q . S)``; and the block leaves
+# ``S exp(cs_end) + sum_s exp(cs_end - cs_s) step_s x_s (x) B_s``. XLA computes
+# that as a dozen fusions over ``[blocks, heads, block, block]`` float32
+# tensors in HBM (33.5 MB each a layer at 64 heads and a block of 256) and
+# four einsums at ``HIGHEST``: 4.9 ms of a 28.3 ms chunk program at 36 layers
+# of 512 positions (PERF.md, PR 58). ``ssm_chunk_scan`` is one kernel a layer:
+#
+# - A grid over (sequence, pack of heads of one group, block of ``a.block``
+#   positions), the blocks in order. The pack's state ``[heads P, N]`` enters
+#   at the window's first block, is kept in VMEM TRANSPOSED (``(h, p)`` on the
+#   lanes: both products with it are then plain ones) and is written out once,
+#   at the last. (Taking the layer's whole array aliased in and out, the row
+#   scalar-prefetched as the decode kernel has it, spared the program two
+#   fusions over 2 MB a layer and read SLOWER: a chunk 26.6 ms for 25.3.)
+# - A block is walked in tiles of 128 positions, each tile the chunked form's
+#   block (which changes no value: inside a tile a decay is the exponential of
+#   a difference, between tiles it goes through the state). Per tile: the
+#   running sum as a product with a triangle of ones and its rows by a
+#   product with the identity (exact: every sum is parts of one value times
+#   one); ``C B^T`` once for the pack; per head the decay tile under the
+#   causal mask times ``C B^T`` times ``step_s``, against ``x``; the
+#   state's read-out for all heads in one product; the tile's effect on the
+#   state in one more.
+# - Operands stay where their lanes lie: the heads one lane tile holds (two of
+#   64 channels) are taken together, a head's column of per-position weights
+#   broadcast over the tile and kept on the head's own lanes. (Head by head on
+#   64-lane slices the same kernel took 3.79 ms for this one's 3.40.)
+# - Precision is ``_ssd_blocks``': state, decay, step and every sum float32,
+#   every product as accurate as ``HIGHEST`` (:func:`_dot`).
+#
+# Read on a v5e (PERF.md, PR 58; 36 layers of 512 positions of 64 heads of 64,
+# each call on its own operands, chained through the state, with the
+# harness's own 1.0 ms): ``_ssd_blocks`` 4.8 to 5.0 ms, the kernel 3.40; with
+# the in-block product at ``HIGHEST`` over two float32 operands (six passes)
+# 4.25; packs of 8 / 16 / 32 heads 3.76 / 3.40 / 3.37; a tile of 256 positions
+# 5.6 for 4.5 (an earlier form). What a grid step waits for, by knocking
+# pieces out: a kernel that only copies ``x`` to ``y`` and carries the state
+# 1.70 (the floor: 16 MB a layer); the rest of the skeleton 0.26; the
+# products with the state, their splits into parts and the running sums 0.82;
+# the heads' decay tiles and in-block products 0.62.
+
+# The instruction name of the chunk program's kernel.
+CHUNK_KERNEL_NAME = "ssm_chunk_scan"
+
+# Positions the kernel multiplies as one tile: a lane tile.
+_TILE = 128
+# Heads a grid step takes (whole heads of one group: they share B and C).
+_HEADS_PACK = 16
+
+_HI = jax.lax.Precision.HIGHEST
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _heads_pack(hpg, most=None):
+    """Heads of a group that a grid step takes: the most that divide it, up
+    to ``most``."""
+    hb = min(int(most or _HEADS_PACK), hpg)
+    while hpg % hb:
+        hb -= 1
+    return hb
+
+
+def chunk_supported(a, q_len):
+    """Whether :func:`ssm_chunk_scan` tiles on a TPU for a window of ``q_len``
+    positions of the mixer ``a``, from shapes alone: what :func:`supported`
+    asks (a state row one register's lanes, whole registers a head, heads
+    that pack), a pack's channels whole lane tiles, blocks of whole tiles,
+    and a window of whole blocks. (Interpret mode takes any.)"""
+    hb = _heads_pack(a.n_heads // a.n_groups)
+    return (supported(a) and (hb * a.head_dim) % _LANES == 0
+            and a.block % _TILE == 0 and q_len % a.block == 0)
+
+
+def _parts(v):
+    """A float32 value as the three bfloat16 values that sum to it exactly
+    (8 + 8 + 8 bits of its 24), smallest first; a bfloat16 value is its one
+    part."""
+    if v.dtype == jnp.bfloat16:
+        return [v]
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hi = v.astype(bf16)
+    rest = v - hi.astype(f32)
+    mid = rest.astype(bf16)
+    return [(rest - mid.astype(f32)).astype(bf16), mid, hi]
+
+
+def _dot(x, y, dims=_NN):
+    """``x . y`` as accurate as ``Precision.HIGHEST`` makes a float32
+    product, in float32: two float32 operands at ``HIGHEST`` (six passes of
+    the matrix unit); where an operand IS a bfloat16 value its middle and low
+    parts are zero and the passes that multiply them are left out, so a
+    float32 operand against it is its three parts, one pass each, and two
+    bfloat16 operands are one pass. The same products summed in float32.
+    (The three parts behind one another in ONE product, so that the other
+    operand is loaded once, read 7 % slower on a v5e: PERF.md, PR 58.)"""
+    if x.dtype == y.dtype == jnp.float32:
+        return jax.lax.dot_general(x, y, (dims, ((), ())), precision=_HI,
+                                   preferred_element_type=jnp.float32)
+    total = None
+    for a in _parts(x):
+        for b in _parts(y):
+            part = jax.lax.dot_general(a, b, (dims, ((), ())),
+                                       preferred_element_type=jnp.float32)
+            total = part if total is None else total + part
+    return total
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _chunk_kernel(x_ref, step_ref, rate_ref, b_ref, c_ref, s_ref, y_ref,
+                  out_ref, sc_s, sc_dx, *, heads, tile):
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hb, T = heads, tile
+    Q, W = x_ref.shape[1:]
+    P, N = s_ref.shape[2:]
+    block, last = pl.program_id(2), pl.num_programs(2) - 1
+
+    # The pack's state, carried across the window's blocks TRANSPOSED: a
+    # state row ``S[h, p, :]`` down the sublanes, ``(h, p)`` on the lanes, so
+    # that both products with it are plain ones.
+    @pl.when(block == 0)
+    def _():
+        sc_s[...] = s_ref[0].reshape(W, N).T
+
+    # Matrices of ones and zeros, bfloat16 values exactly: the running sum
+    # (lower triangle), the transposition (identity), a head's value to its
+    # P lanes.
+    lower = _iota((T, T), 0) >= _iota((T, T), 1)
+    ones_below = lower.astype(bf16)
+    identity = (_iota((T, T), 0) == _iota((T, T), 1)).astype(bf16)
+    to_lanes = (_iota((hb, W), 1) // P == _iota((hb, W), 0)).astype(bf16)
+    rate = rate_ref[0]                                          # [1, hb]
+    # The heads a lane tile holds are taken together (two of 64 channels),
+    # every operand where its lanes lie: a head's column of weights is
+    # broadcast over the tile and kept on the head's own lanes.
+    per = min(hb, max(1, _LANES // P))
+    slab = per * P
+    head_of = _iota((T, slab), 1) // P
+
+    def spread(v, first):          # [T, hb] -> a column over each head's lanes
+        out = v[:, first:first + 1]
+        for k in range(1, per):
+            out = jnp.where(head_of == k, v[:, first + k:first + k + 1], out)
+        return out
+
+    for t in range(Q // T):
+        at = pl.ds(t * T, T)
+        step = step_ref[0, 0, at, :]                            # [T, hb]
+        cs = _dot(ones_below, step * rate)                      # [T, hb]
+        end = cs[T - 1:]                                        # [1, hb]
+        # A position's weight in the state at the tile's end, and what the
+        # state entering is worth at a position.
+        grows, carries = step * jnp.exp(end - cs), jnp.exp(cs)
+        # The running sum and the step as rows, [2 hb, T]: transposed
+        # exactly (every sum is a part of one value times one).
+        across = _dot(jnp.concatenate([cs, step], 1), identity, _TN)
+        x, b, c = x_ref[0, at, :], b_ref[0, at, :], c_ref[0, at, :]
+        state = sc_s[...]                                       # [N, W]
+        carried = _dot(c, state)                                # [T, W]
+        cb = _dot(c, b, _NT)                            # [T, T], a group's
+        for first in range(0, hb, per):
+            lanes = slice(first * P, first * P + slab)
+            xs = x[:, lanes]                                    # [T, slab]
+            y = carried[:, lanes] * spread(carries, first)
+            for k in range(per):
+                h = first + k
+                # q takes exp(cs_q - cs_s) (C_q . B_s) step_s of s <= q.
+                seg = cs[:, h:h + 1] - across[h:h + 1, :]
+                mix = cb * jnp.exp(jnp.where(lower, seg, -jnp.inf))
+                mix = mix * across[hb + h:hb + h + 1, :]
+                y = y + _dot(mix, xs if per == 1
+                             else jnp.where(head_of == k, xs, 0))
+            y_ref[0, at, lanes] = y
+            sc_dx[:, lanes] = xs.astype(f32) * spread(grows, first)
+        # The tile's own contribution to the state at its end, and its decay.
+        grown = _dot(b.T, sc_dx[...])                           # [N, W]
+        kept = jnp.exp(_dot(jnp.broadcast_to(end, (_SUBLANES, hb)),
+                            to_lanes)[:1])                      # [1, W]
+        sc_s[...] = state * kept + grown
+
+    @pl.when(block == last)
+    def _():
+        out_ref[0] = sc_s[...].T.reshape(hb, P, N)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "heads_pack", "tile",
+                                             "interpret"))
+def ssm_chunk_scan(x, step, rate, b_in, c_out, state, *, block,
+                   heads_pack=None, tile=None, interpret=False):
+    """``transformer._ssd_blocks`` for a window of more than one position:
+    ``x [B, S, H, P]``, ``step [B, S, H]``, ``rate [H]``, ``b_in, c_out [B,
+    S, G, N]``, ``state [B, H, P, N]`` entering, ``block`` the mixer's ->
+    (``y [B, S, H, P]`` float32, the state leaving, float32).
+
+    Jitted so that a program calling it once a layer traces and lowers the
+    kernel once."""
+    f32 = jnp.float32
+    B, S, H, P = x.shape
+    G, N = b_in.shape[2:]
+    if state.shape != (B, H, P, N):
+        raise ValueError(f"state {state.shape} is not [{B}, {H}, {P}, {N}]")
+    hpg = H // G
+    hb = _heads_pack(hpg, heads_pack)
+    packs = H // hb
+    Q = min(int(block), -(-S // _SUBLANES) * _SUBLANES)
+    nb = -(-S // Q)
+    T = int(tile or (_TILE if Q % _TILE == 0 else Q))
+    if Q % T:
+        raise ValueError(f"a block of {Q} positions is no whole tiles of {T}")
+    behind = [(0, 0), (0, nb * Q - S), (0, 0)]        # dead positions
+
+    def flat(v):                      # [B, S, .., ..] -> [B, nb Q, ..]
+        return jnp.pad(v.reshape(B, S, -1), behind)
+
+    steps = jnp.pad(step.astype(f32), behind).reshape(
+        B, nb * Q, packs, hb).transpose(0, 2, 1, 3)        # [B, packs, S, hb]
+    seq = pl.BlockSpec((1, Q, hb * P), lambda b, p, n: (b, n, p),
+                       memory_space=pltpu.VMEM)
+    maps = pl.BlockSpec((1, Q, N), lambda b, p, n: (b, n, p * hb // hpg),
+                        memory_space=pltpu.VMEM)
+    rows = pl.BlockSpec((1, hb, P, N), lambda b, p, n: (b, p, 0, 0),
+                        memory_space=pltpu.VMEM)
+    tiles = B * packs * nb * (Q // T)
+    y, state = pl.pallas_call(
+        functools.partial(_chunk_kernel, heads=hb, tile=T),
+        grid=(B, packs, nb),
+        in_specs=[seq,
+                  pl.BlockSpec((1, 1, Q, hb), lambda b, p, n: (b, p, n, 0),
+                               memory_space=pltpu.VMEM),
+                  pl.BlockSpec((1, 1, hb), lambda b, p, n: (p, 0, 0),
+                               memory_space=pltpu.VMEM),
+                  maps, maps, rows],
+        out_specs=[seq, rows],
+        out_shape=[jax.ShapeDtypeStruct((B, nb * Q, H * P), f32),
+                   jax.ShapeDtypeStruct((B, H, P, N), f32)],
+        scratch_shapes=[pltpu.VMEM((N, hb * P), f32),
+                        pltpu.VMEM((T, hb * P), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * tiles * T * hb * P * (T + 2 * N) + 2 * tiles * T * T * N,
+            transcendentals=tiles * T * hb * (T + 2 * P),
+            bytes_accessed=B * nb * Q * H * P * (x.dtype.itemsize + 4)
+            + 2 * B * H * P * N * 4),
+        name=CHUNK_KERNEL_NAME,
+        interpret=interpret,
+    )(flat(x), steps, rate.astype(f32).reshape(packs, 1, hb),
+      flat(b_in), flat(c_out), state.astype(f32))
+    return y[:, :S].reshape(B, S, H, P), state

@@ -114,11 +114,16 @@ request's replay alike), handed to ``transformer.state_space_mix`` with the
 window's live positions, and written back in place (:func:`_state_layer`):
 the chunk program runs the chunked scan over its 512 positions, the decode
 step the same function over a window of one. On a TPU backend with no mesh
-(:func:`state_kernels`) the decode step's recurrence is the Pallas kernel
-``ssm_decode_update`` (:mod:`horovod_tpu.ops.pallas_ssm`, the instruction
-name a device trace shows): one pass over the layer's own state array,
-updated in place and read out from the same registers, where the plain
-``transformer._ssd_step`` compiles to three. A chunk's padding must not
+(:func:`state_kernels`) both recurrences are Pallas kernels of
+:mod:`horovod_tpu.ops.pallas_ssm`, under the instruction names a device trace
+shows. The decode step's is ``ssm_decode_update``: one pass over the layer's
+own state array, updated in place and read out from the same registers, where
+the plain ``transformer._ssd_step`` compiles to three. A window of whole
+blocks of the mixer's (the 512-position chunk; not the page-wide tail) runs
+the chunked form as ``ssm_chunk_scan``, ONE kernel a layer with the pack's
+state in VMEM across the window's blocks, where the plain
+``transformer._ssd_blocks`` compiles to a dozen fusions over ``[blocks,
+heads, block, block]`` float32 tensors in HBM. A chunk's padding must not
 advance the state, so for such a model a NEGATIVE token id marks a padding
 position (the loop pads so; positions are dead from the first negative id
 on). A layer with no mixer touches no cache. Where the prefix cache holds
@@ -254,14 +259,22 @@ def grouped_kernels(cfg, geo, mesh):
                 for _, a in cfg.multihead))
 
 
-def state_kernels(cfg, geo, mesh):
-    """Whether the decode step's state-space layers update their state
+def state_kernels(cfg, geo, mesh, q_len=1):
+    """Whether a program of ``q_len`` queries a slot takes its state-space
+    layers' recurrence through a Pallas kernel: where kernels may run, and
+    shapes the kernel tiles. The decode step (one query) updates its state
     through :func:`pallas_ssm.ssm_decode_update`, one pass over the layer's
-    own array: where kernels may run, and shapes the kernel tiles. Else (and
-    in every chunk program) the state goes through
-    ``transformer._ssd_blocks``."""
+    own array (:func:`pallas_ssm.supported`); a window of more runs the
+    chunked form as :func:`pallas_ssm.ssm_chunk_scan`, one kernel a layer
+    (:func:`pallas_ssm.chunk_supported`: whole blocks of the mixer's, so the
+    page-wide ``jit_chunk_tail`` is not one). Else the state goes through
+    ``transformer._ssd_step`` / ``_ssd_blocks``."""
+    def tiles(a):
+        return (pallas_ssm.supported(a) if q_len == 1
+                else pallas_ssm.chunk_supported(a, q_len))
+
     return (bool(cfg.state_space) and _kernels_may_run(cfg, mesh)
-            and all(pallas_ssm.supported(a) for _, a in cfg.state_space))
+            and all(tiles(a) for _, a in cfg.state_space))
 
 
 def linear_kernels(cfg, geo, mesh):
@@ -275,16 +288,19 @@ def linear_kernels(cfg, geo, mesh):
             and all(pallas_kda.supported(a) for _, a in cfg.delta_rule))
 
 
-def _kernels(cfg, geo, mesh, one_query=False):
+def _kernels(cfg, geo, mesh, q_len=None):
     """The four gates of the described kinds, each asked once a program
-    build: ``{"latent", "grouped", "state", "linear"}``. The state-space
-    kernel is the one-token recurrence, so only the decode step
-    (``one_query``) has it; the delta-rule kernel is the chunked form, so
-    only a program of more than one query has it."""
+    build, for a program of ``q_len`` queries a slot (1: the decode step;
+    None: a padded prefill, which no model of a described kind has):
+    ``{"latent", "grouped", "state", "linear"}``. The state-space kernels
+    are two, the one-token update and the chunked form, and the gate answers
+    for the one the program's window takes; the delta-rule kernel is the
+    chunked form, so only a program of more than one query has it."""
     return {"latent": latent_kernels(cfg, geo, mesh),
             "grouped": grouped_kernels(cfg, geo, mesh),
-            "state": one_query and state_kernels(cfg, geo, mesh),
-            "linear": not one_query and linear_kernels(cfg, geo, mesh)}
+            "state": q_len is not None and state_kernels(cfg, geo, mesh,
+                                                         q_len),
+            "linear": q_len != 1 and linear_kernels(cfg, geo, mesh)}
 
 
 def fill_exit(cfg):
@@ -569,7 +585,7 @@ def _grouped_work(a, live, itemsize):
             "state": {"kv_bytes": rows.sum() * 2 * a.kv_width * itemsize}}
 
 
-def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False,
+def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=None,
                  snapshots=0):
     """One state-space layer of a chunk or decode program: the slots' rows of
     the layer's tail and state arrays, zeroed where the window begins its
@@ -578,15 +594,19 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False,
     live positions, and back into the same rows -> (the layer's arrays, ``out
     [B, Q, D]``). A dead slot's row is left as it was.
 
-    With ``kernels`` and ONE query a slot (the decode step on a TPU,
-    :func:`state_kernels`) the state array is not sliced at all: ``mix`` is
-    handed the kernel as its recurrence (``recur``), which updates rows ``1
-    ..`` of the array where they lie and reads ``y`` out in the same pass, a
-    slot that begins entering on zeros inside it. With ``kernels`` and a
-    window of more (a chunk on a TPU, :func:`linear_kernels`) the rows are
-    read and zeroed the way below and ENTERED into the kernel of the chunked
-    form, :func:`pallas_kda.kda_chunk_scan`, as ``mix``'s recurrence; what it
-    gives back is written to the same rows. The tail goes the way below.
+    ``kernels`` is the layer's MIXER where the program takes its recurrence
+    through a Pallas kernel (:func:`_kernels`' gate of its kind is open), and
+    the mixer's kind and the window say which. A state-space mixer and ONE
+    query a slot (the decode step on a TPU): the state array is not sliced at
+    all, ``mix`` is handed :func:`pallas_ssm.ssm_decode_update` as its
+    recurrence (``recur``), which updates rows ``1 ..`` of the array where
+    they lie and reads ``y`` out in the same pass, a slot that begins entering
+    on zeros inside it. A window of more (a chunk on a TPU): the rows are read
+    and zeroed the way below and ENTERED into the kernel of the kind's chunked
+    form as ``mix``'s recurrence, :func:`pallas_ssm.ssm_chunk_scan` at the
+    mixer's block for a state-space mixer, :func:`pallas_kda.kda_chunk_scan`
+    for a delta-rule mixer; what it gives back is written to the same rows.
+    The tail goes the way below.
 
     A program over every slot (the decode step: batch row ``b`` IS slot
     ``b``) takes rows ``1 ..`` where they lie, a slice and not a gather, and
@@ -608,7 +628,8 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False,
     whole = q_pos.shape[0] == slots
     interpret = jax.default_backend() != "tpu"
     one_query = q_pos.shape[1] == 1
-    in_place = bool(kernels) and whole and one_query
+    in_place = (isinstance(kernels, tfm.StateSpaceMixer) and whole
+                and one_query)
     recur = None
     if in_place:                 # the state stays where it lies
         tail, state = tail_c[1:1 + slots], None
@@ -624,10 +645,13 @@ def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=False,
     if not in_place:
         state = jnp.where(
             begins[(slice(None),) + (None,) * (state_c.ndim - 1)], 0, state)
-        if kernels and not one_query:       # the rows enter the kernel
+        if kernels is not None and not one_query:   # the rows enter the kernel
+            scan = (functools.partial(pallas_ssm.ssm_chunk_scan,
+                                      block=kernels.block)
+                    if isinstance(kernels, tfm.StateSpaceMixer)
+                    else pallas_kda.kda_chunk_scan)
             recur, state = functools.partial(
-                pallas_kda.kda_chunk_scan, state=state,
-                interpret=interpret), None
+                scan, state=state, interpret=interpret), None
     out, tail, state = (mix(tail, state, ok) if recur is None
                         else mix(tail, state, ok, recur=recur))
     tail = tail.astype(tail_c.dtype)
@@ -830,7 +854,7 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
                 # ``kernels=`` only where the kernel runs: elsewhere the
                 # call is ``(mix, tail_c, state_c, q_pos, ok, tables)``.
                 kind = _KERNEL_OF.get(type(a))
-                flag = {"kernels": True} if kernels.get(kind) else {}
+                flag = {"kernels": a} if kernels.get(kind) else {}
                 if geo.snapshot_rows:
                     flag["snapshots"] = geo.snapshot_rows
                 ck[li], cv[li], out = _state_layer(mix, ck[li], cv[li],
@@ -966,7 +990,7 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
     trash page 0 and their logits are garbage the scheduler never reads.
     """
     paged = decode_attn(cfg, geo, mesh) == "paged"
-    kernels = _kernels(cfg, geo, mesh, one_query=True)
+    kernels = _kernels(cfg, geo, mesh, 1)
 
     def decode(params, cache, tokens, positions, block_tables, active):
         x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
@@ -1118,10 +1142,10 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk",
             f"head is 'all', 'last' or 'none', and 'all' with ends or for a "
             f"model whose padding is no negative id (no recurrent layer: the "
             f"last live row is then not the program's to find), got {head!r}")
-    kernels = _kernels(cfg, geo, mesh)
     q_len = geo.page_size if q_len is None else int(q_len)
     if q_len < 1:
         raise ValueError(f"chunk q_len must be >= 1, got {q_len}")
+    kernels = _kernels(cfg, geo, mesh, q_len)
     _check_positions(cfg, geo.max_kv, "cache width")
 
     def chunk(params, cache, tokens, positions, block_tables, active):

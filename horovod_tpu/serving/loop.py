@@ -299,7 +299,9 @@ class ServeLoop:
             return programs.Program(
                 program, cfg=cfg, geo=geo, mesh=mesh,
                 paged=self.decode_paged,
-                kernels=engine._kernels(cfg, geo, mesh, one_query), **static)
+                kernels=engine._kernels(
+                    cfg, geo, mesh, 1 if one_query else static.get("q_len")),
+                **static)
 
         self.prefill_fn = (kept(engine.make_prefill(cfg, geo, mesh))
                            if padded else None)

@@ -16,6 +16,7 @@ import pytest
 
 from benchmark.runners import serve_lm, serve_share
 from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops import pallas_ssm
 from horovod_tpu.serving import engine, kv_cache
 from horovod_tpu.serving.loop import ServeLoop, serve_stats
 from horovod_tpu.serving.scheduler import Request
@@ -301,6 +302,46 @@ def test_eviction_under_pressure_conserves_rows_and_pages(model):
     got, req = serve(loop, prefix + tokens(25, 99), rid=99)
     assert req.cached_tokens in (0, 32)
     assert rel(got, reference_rows(model, req)) < 2e-5
+
+
+@pytest.mark.parametrize("case", ["cold", "hit"])
+def test_the_chunk_kernel_gives_what_the_blocked_form_gives(model, monkeypatch,
+                                                            case):
+    """The fill's recurrence through ``ssm_chunk_scan`` (interpret mode, the
+    gate steered as the engine opens it on a TPU: the 16-token chunk is two
+    of this model's blocks of 8, the page-wide tail one) and the decode
+    step's through ``ssm_decode_update``: a cold fill, and a hit whose state
+    is restored from its snapshot row and then goes through the kernel, each
+    against the reference at the tolerance the plain programs hold, and
+    against the plain programs themselves."""
+    a = tokens(70, 2)
+    b = a[:64] + tokens(30, 3)
+    plain = make_loop(model, 4, fill_head="last")
+    want_a, _ = serve(plain, a)
+    want_b, _ = serve(plain, b, rid=1)
+    entered = []
+    scan = pallas_ssm.ssm_chunk_scan
+
+    def counted(*args, **kw):
+        entered.append(args[0].shape[1])
+        return scan(*args, **kw)
+
+    monkeypatch.setattr(engine, "state_kernels", lambda *a: True)
+    monkeypatch.setattr(pallas_ssm, "ssm_chunk_scan", counted)
+    loop = make_loop(model, 4, fill_head="last")
+    got, req = serve(loop, a)
+    want = want_a
+    if case == "hit":
+        got, req = serve(loop, b, rid=1)
+        want = want_b
+        assert req.cached_tokens == 64
+        assert serve_stats()["state_restores"] == 1
+    # Three Mamba-2 layers a program: the chunk with and without a head, and
+    # the page-wide tail where the prompt's last tokens took it.
+    assert entered and len(entered) % 3 == 0 and set(entered) <= {CHUNK, PAGE}
+    assert rel(got, want) < 2e-6
+    assert rel(got, reference_rows(model, req)) < 2e-5
+    conserved(loop)
 
 
 @pytest.mark.parametrize("fault", ["state_not_restored", "tail_not_restored",

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops import pallas_ssm
 from horovod_tpu.serving import engine, kv_cache
 from horovod_tpu.serving import loop as serve_loop
 from horovod_tpu.serving.scheduler import Request
@@ -395,14 +396,48 @@ def _greedy(params, cfg, req):
 def programs(tiny, request):
     """One loop's compiled programs and cache for the cases below: each
     starts its prompt in the rows the case before it left. ``kernel``: the
-    decode step's state update through ``ops/pallas_ssm.py`` in interpret
-    mode, as the engine takes it on a TPU (steered here: the programs are
-    traced at their first call, so the steering lasts as long as they do)."""
+    state-space layers' recurrence through ``ops/pallas_ssm.py`` in interpret
+    mode, as the engine takes it on a TPU (``ssm_chunk_scan`` in the chunk
+    program, whose 8 positions are two of this model's blocks of 4, and
+    ``ssm_decode_update`` in the decode step; steered here: the programs are
+    traced at their first call, so the steering lasts as long as they do).
+    ``loop.entered`` names the kernels a trace went through."""
     _, cfg, params = tiny
     with pytest.MonkeyPatch.context() as steer:
+        entered = set()
         if request.param:
             steer.setattr(engine, "state_kernels", lambda *a: True)
-        yield _loop(cfg, params)
+            for name in ("ssm_chunk_scan", "ssm_decode_update"):
+                def counted(*a, name=name, sound=getattr(pallas_ssm, name),
+                            **kw):
+                    entered.add(name)
+                    return sound(*a, **kw)
+                steer.setattr(pallas_ssm, name, counted)
+        loop = _loop(cfg, params)
+        loop.entered = entered
+        yield loop
+
+
+def test_which_recurrence_the_programs_trace(tiny, programs):
+    """A chunk and a decode step with no live slot (nothing moves): the
+    kernel tier traces both kernels, once a state-space layer, the plain tier
+    neither."""
+    _, cfg, params = tiny
+    loop = programs
+    before = jax.tree.map(np.asarray, loop.cache)
+    idle = np.zeros((1, loop.geo.table_width), np.int32)
+    loop.cache, *_ = loop.chunk_fn(
+        params, loop.cache, np.full((1, CHUNK), -1, np.int32),
+        np.zeros(1, np.int32), idle, np.zeros(1, bool))
+    loop.cache, *_ = loop.decode_fn(
+        params, loop.cache, np.zeros(3, np.int32), np.zeros(3, np.int32),
+        np.repeat(idle, 3, 0), np.zeros(3, bool))
+    steered = engine.state_kernels(cfg, loop.geo, None) is True
+    assert loop.entered == ({"ssm_chunk_scan", "ssm_decode_update"}
+                            if steered else set())
+    for was, now in zip(jax.tree.leaves(before),
+                        jax.tree.leaves(jax.tree.map(np.asarray, loop.cache))):
+        assert np.array_equal(was[1:], now[1:])     # row / page 0 is trash
 
 
 @pytest.mark.parametrize("n", [5, 19, 24])

@@ -773,7 +773,11 @@ def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip, monkeypatch):
     temporaries stay on the chip; the cache is aliased through, and the
     decode step holds no second copy of a layer's state (0.54 GB: a gather
     of the rows, or the blocked scan at a block of one, made one a layer) and
-    passes over it once, in the kernel ``ssm_decode_update``."""
+    passes over it once, in the kernel ``ssm_decode_update``; the chunk
+    program runs its recurrence as ONE ``ssm_chunk_scan`` a state-space layer
+    (eight packs of 16 heads, blocks of 128) and keeps none of the blocked
+    form's per-head ``[128, 128]`` decay tensors (``f32[4,8,16,128,128]``
+    in the compiled text of the blocked form)."""
     import importlib.util
     import json
 
@@ -841,12 +845,19 @@ def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip, monkeypatch):
         # kernel call a layer, which takes the layer's whole array and gives
         # it back (aliased), and nothing else makes an array of a layer's
         # rows (PR 43; XLA made three passes of `_ssd_step`). The chunk
-        # program's state goes the way it went.
-        updates = [line for line in text.splitlines()
-                   if re.match(r"\s*%ssm_decode_update[.\d]* = ", line)
-                   and "tpu_custom_call" in line]
-        assert len(updates) == (n_state if name == "decode" else 0), name
+        # program's recurrence is one kernel a layer too (PR 58), and the
+        # blocked form's decays of every head against every pair of a
+        # block's positions are in no buffer.
+        for kernel, program in (("ssm_decode_update", "decode"),
+                                ("ssm_chunk_scan", "chunk")):
+            found = [line for line in text.splitlines()
+                     if re.match(rf"\s*%{kernel}[.\d]* = ", line)
+                     and "tpu_custom_call" in line]
+            assert len(found) == (n_state if name == program else 0), name
         assert engine.state_kernels(cfg, geo, None)
+        assert engine.state_kernels(cfg, geo, None, chunk)
+        assert not engine.state_kernels(cfg, geo, None, 16)
+        assert "f32[4,8,16,128,128]" not in text
         if name == "decode":
             rows = ["f32[%d,128,64,128]" % n for n in (B, B + 1)]
             made = []
@@ -859,14 +870,13 @@ def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip, monkeypatch):
                     made.append(m.group(1))
             assert len(made) == n_state and all(
                 m.startswith("%ssm_decode_update") for m in made), made
-    # The kernel is the decode step's alone: the chunk program lowers to the
-    # same text whether the engine would take it or not.
-    chunk_text = []
-    for on in (True, False):
-        monkeypatch.setattr(engine, "state_kernels", lambda *a, on=on: on)
-        chunk_text.append(engine.make_chunk_step(cfg, geo, q_len=chunk).lower(
-            params, cache, *slots(1, chunk)).as_text())
-    assert chunk_text[0] == chunk_text[1]
+    # With the gate closed the chunk program is the blocked form's: no
+    # kernel, and the decays in a buffer of their own.
+    monkeypatch.setattr(engine, "state_kernels", lambda *a: False)
+    plain = engine.make_chunk_step(cfg, geo, q_len=chunk).lower(
+        params, cache, *slots(1, chunk)).compile().as_text()
+    assert "ssm_chunk_scan" not in plain
+    assert "f32[4,8,16,128,128]" in plain
 
 
 # ---- the serving programs at benchmark/configs/solar-open2-250b.json ----
@@ -1088,7 +1098,7 @@ def test_sambay_cell_programs_fit_one_chip(topo, as_on_the_chip):
 
 # ---- the serving programs at benchmark/configs/granite-4.0-h-micro.json ----
 
-def test_share_cell_programs_fit_one_chip(topo, as_on_the_chip):
+def test_share_cell_programs_fit_one_chip(topo, as_on_the_chip, monkeypatch):
     """``granite-serve-agent-share-over``'s programs at the cell's geometry
     (33 state rows and the pool of snapshot rows behind them), on ONE period
     of the model's ten layers (nine Mamba-2 layers of 64 heads in one group,
@@ -1098,7 +1108,10 @@ def test_share_cell_programs_fit_one_chip(topo, as_on_the_chip):
     ``paged_full_attention`` with two 64-wide heads a lane tile), the cache
     is aliased through every program, the decode step passes over a layer's
     state once and touches no snapshot row's worth of temporaries, the fill's
-    two programs cut the head, and the state copy is in place."""
+    two programs cut the head and run the recurrence as ONE
+    ``ssm_chunk_scan`` a Mamba-2 layer with no ``f32[1,2,64,256,256]`` decay
+    tensor (PR 58), the page-wide tail program stays the blocked form's, and
+    the state copy is in place."""
     import dataclasses
     import json
 
@@ -1121,8 +1134,17 @@ def test_share_cell_programs_fit_one_chip(topo, as_on_the_chip):
         cfg, chunk, B, snapshot_rows=rows)
     assert (geo.max_kv, geo.state_rows, geo.snapshot_rows,
             geo.table_width) == (16384, B + 1, rows, 1025)
-    assert engine._kernels(cfg, geo, None, one_query=True) == {
-        "latent": False, "grouped": True, "state": True, "linear": False}
+    on = {"latent": False, "grouped": True, "state": True, "linear": False}
+    assert engine._kernels(cfg, geo, None, 1) == on
+    assert engine._kernels(cfg, geo, None, chunk) == on
+    # A window that is no whole block (the page-wide tail), a mesh, a model
+    # with no state-space layer: the blocked form.
+    off = dict(on, state=False)
+    assert engine._kernels(cfg, geo, None, srv["page_size"]) == off
+    assert engine._kernels(cfg, geo, Mesh(np.array(topo.devices[:1]),
+                                          ("data",)), chunk) == dict(
+        off, grouped=False)
+    assert not engine._kernels(_gpt2_large(), geo, None, chunk)["state"]
     # The whole model and its cache, by shape: what the serve block's why says.
     full_geo = kv_cache.with_rings(geo, whole, chunk, B, snapshot_rows=rows)
     n_params = sum(x.size for x in jax.tree.leaves(jax.eval_shape(
@@ -1150,17 +1172,22 @@ def test_share_cell_programs_fit_one_chip(topo, as_on_the_chip):
                     and "tpu_custom_call" in line])
 
     scalar = _on_chip(topo, (), jnp.int32)
-    for name, fn, args, ssm in (
+    page = srv["page_size"]
+    for name, fn, args, ssm, scans in (
             ("chunk", engine.make_chunk_step(cfg, geo, q_len=chunk,
                                              head="none"),
-             [params, cache] + slots(1, chunk), 0),
+             [params, cache] + slots(1, chunk), 0, 9),
             ("chunk_end", engine.make_chunk_step(cfg, geo, q_len=chunk,
                                                  head="last"),
-             [params, cache] + slots(1, chunk), 0),
+             [params, cache] + slots(1, chunk), 0, 9),
+            ("chunk_tail", engine.make_chunk_step(cfg, geo, q_len=page,
+                                                  head="last",
+                                                  name="chunk_tail"),
+             [params, cache] + slots(1, page), 0, 0),
             ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
-             [params, cache] + slots(B), 9),
+             [params, cache] + slots(B), 9, 0),
             ("copy", engine.make_state_copy(cfg, geo, "state_snapshot"),
-             [cache, scalar, scalar], 0)):
+             [cache, scalar, scalar], 0, 0)):
         compiled = fn.lower(*args).compile()
         memory = compiled.memory_analysis()
         assert memory.alias_size_in_bytes >= kv_cache.cache_bytes(cfg, geo)
@@ -1170,3 +1197,13 @@ def test_share_cell_programs_fit_one_chip(topo, as_on_the_chip):
         text = compiled.as_text()
         assert calls(text, "paged_full_attention") == (name != "copy"), name
         assert calls(text, "ssm_decode_update") == ssm, name
+        assert calls(text, "ssm_chunk_scan") == scans, name
+        # the blocked form's decays (its compiled text drops the leading 1)
+        assert "f32[1,2,64,256,256]" not in text, name
+        assert "f32[2,64,256,256]" not in text, name
+    # With the gate closed the chunk program is the blocked form's.
+    monkeypatch.setattr(engine, "state_kernels", lambda *a: False)
+    plain = engine.make_chunk_step(cfg, geo, q_len=chunk, head="none").lower(
+        params, cache, *slots(1, chunk)).compile().as_text()
+    assert calls(plain, "ssm_chunk_scan") == 0
+    assert "f32[2,64,256,256]" in plain
