@@ -186,7 +186,19 @@ class MultiHeadAttention:
     (a source's ``attention_multiplier``); None = ``head_dim ** -0.5``. The
     queries carry it (times ``sqrt(head_dim)``, in float32 before they are
     rounded), so every attention, plain or kernel, stays at ``head_dim **
-    -0.5``."""
+    -0.5``.
+
+    ``v_head_dim``: the width of a VALUE head where it is not the key's
+    (None = ``head_dim``). Queries and keys are ``head_dim`` wide and score
+    at ``head_dim ** -0.5``; values, a head's output and ``wo``'s rows are
+    ``v_dim`` wide, and a layer's K and V arrays have lanes of their own
+    (:attr:`k_width`, :attr:`v_width`). The key and value projections are
+    then two arrays, ``wk`` and ``wv``. ``value_scale``: a factor on the
+    projected value (in float32, before it is rounded and cached). ``sink``:
+    one learned scalar ``b_j`` a query head a layer joins the softmax's
+    DENOMINATOR and nothing else, ``a_k = exp(s_k) / (exp(b_j) + sum_k'
+    exp(s_k'))``: a column appended to the scores and dropped after the
+    softmax, never a stored key."""
     n_heads: int
     n_kv_heads: int
     head_dim: int
@@ -199,6 +211,9 @@ class MultiHeadAttention:
     differential: bool = False
     kv_from: Optional[int] = None
     softmax_scale: Optional[float] = None
+    v_head_dim: Optional[int] = None
+    value_scale: float = 1.0
+    sink: bool = False
 
     def __post_init__(self):
         if isinstance(self.yarn, dict):
@@ -219,6 +234,19 @@ class MultiHeadAttention:
         if self.softmax_scale is not None and self.differential:
             raise ValueError("softmax_scale is not written for differential "
                              "attention (its queries already carry sqrt(2))")
+        if self.v_dim != self.head_dim and (
+                self.differential or self.bias or self.gate == "channel"
+                or self.kv_from is not None):
+            raise ValueError("a value width of its own is written for plain "
+                             "grouped heads only (no pairs, bias, channel "
+                             "gate or shared keys and values)")
+        if self.value_scale != 1.0 and (self.differential or self.bias):
+            raise ValueError("value_scale is not written for differential "
+                             "attention or beside a bias")
+        if self.sink and (self.differential or self.kv_from is not None):
+            raise ValueError("a sink is not written for differential "
+                             "attention or a layer that attends another's "
+                             "keys and values")
 
     @property
     def query_mult(self):
@@ -234,9 +262,20 @@ class MultiHeadAttention:
         return self.n_heads // self.n_kv_heads
 
     @property
-    def kv_width(self):
-        """Lanes of a cached K or V row: the key/value heads, fused."""
+    def v_dim(self):
+        """The width of a value head, of a head's output and of ``wo``'s
+        rows."""
+        return self.head_dim if self.v_head_dim is None else self.v_head_dim
+
+    @property
+    def k_width(self):
+        """Lanes of a cached K row: the key heads, fused."""
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def v_width(self):
+        """Lanes of a cached V row: the value heads, fused."""
+        return self.n_kv_heads * self.v_dim
 
     @property
     def rope_dim(self):
@@ -253,6 +292,12 @@ class MultiHeadAttention:
         return dataclasses.replace(
             self, n_kv_heads=self.n_kv_heads // 2,
             head_dim=2 * self.head_dim, differential=False)
+
+    @property
+    def split_kv(self):
+        """Whether the key and value projections are two arrays (``wk``,
+        ``wv``) and not the fused ``wkv``: where their widths differ."""
+        return self.v_dim != self.head_dim
 
 
 def lambda_init(li):
@@ -763,17 +808,24 @@ def _latent_params(key, cfg, a: LatentAttention):
 def _multihead_params(key, cfg, a: MultiHeadAttention):
     """A described multi-head layer's matrices: the query projection of
     ``n_heads``, the fused key and value projections of ``n_kv_heads`` (none
-    where the layer attends another's, ``kv_from``), the output projection,
-    the head gate; with ``bias`` a bias on each projection; with
+    where the layer attends another's, ``kv_from``; two arrays, ``wk`` and
+    ``wv``, where a value head has a width of its own), the output
+    projection, the head gate; with ``bias`` a bias on each projection; with
     ``differential`` the four ``lambda`` vectors (N(0, 0.1)) and the scale of
-    the pairs' norm."""
+    the pairs' norm; with ``sink`` one scalar a query head, zeros."""
     D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
     k = jax.random.split(key, 5)
     p = {"wq": _dense_init(k[0], (D, a.n_heads, a.head_dim), D, pdt),
-         "wo": _dense_init(k[2], (a.n_heads, a.head_dim, D),
-                           a.n_heads * a.head_dim, pdt)}
-    if a.kv_from is None:
+         "wo": _dense_init(k[2], (a.n_heads, a.v_dim, D),
+                           a.n_heads * a.v_dim, pdt)}
+    if a.split_kv:
+        kk, kv = jax.random.split(k[1])
+        p["wk"] = _dense_init(kk, (D, a.n_kv_heads, a.head_dim), D, pdt)
+        p["wv"] = _dense_init(kv, (D, a.n_kv_heads, a.v_dim), D, pdt)
+    elif a.kv_from is None:
         p["wkv"] = _dense_init(k[1], (D, 2, a.n_kv_heads, a.head_dim), D, pdt)
+    if a.sink:
+        p["sink"] = jnp.zeros((a.n_heads,), pdt)
     if a.bias:
         p["bq"] = jnp.zeros((a.n_heads, a.head_dim), pdt)
         p["bo"] = jnp.zeros((D,), pdt)
@@ -1185,12 +1237,21 @@ def _qkv_kind(h, layer, cfg, a: MultiHeadAttention, positions=None):
 
 
 def _kv_kind(h, layer, cfg, a: MultiHeadAttention):
-    """The fused key/value projection ``[2, B, S, n_kv_heads, dh]`` (with its
-    bias) of a described multi-head layer, or None where it attends another
-    layer's."""
+    """The key and value projections of a described multi-head layer: the
+    fused ``[2, B, S, n_kv_heads, dh]`` (with its bias), or the pair ``(k [B,
+    S, n_kv_heads, dh], v [B, S, n_kv_heads, v_dim])`` where a value head has
+    a width of its own or a scale (``value_scale``: in float32, before the
+    value is rounded); None where the layer attends another layer's."""
     if a.kv_from is not None:
         return None
     dt = cfg.compute_dtype
+    if a.split_kv or a.value_scale != 1.0:
+        wk, wv = ((layer["wk"], layer["wv"]) if a.split_kv
+                  else (layer["wkv"][:, 0], layer["wkv"][:, 1]))
+        v = jnp.einsum("bsd,dhk->bshk", h, wv.astype(dt),
+                       preferred_element_type=jnp.float32)
+        return (jnp.einsum("bsd,dhk->bshk", h, wk.astype(dt)),
+                (v * a.value_scale).astype(dt))
     kv = jnp.einsum("bsd,dchk->cbshk", h, layer["wkv"].astype(dt))
     if a.bias:
         kv = kv + layer["bkv"].astype(dt)[:, None, None]
@@ -1235,21 +1296,32 @@ def differential_combine(o, layer, a: MultiHeadAttention, li, eps, dt):
     return d.reshape(*o.shape[:2], a.n_heads, a.head_dim).astype(dt)
 
 
-def grouped_attend(q, k, v, a: MultiHeadAttention, allowed, dt):
+def grouped_attend(q, k, v, a: MultiHeadAttention, allowed, dt, sink=None):
     """Grouped-query attention with materialised scores: ``q [B, S, Hq,
-    dh]`` against ``k, v [B, T, Hkv, dh]`` under ``allowed [B, S, T]`` ->
-    ``[B, S, Hq, dh]`` (zeros for a query that is allowed nothing). The
-    plain tier: the trainer's forward pass, a CPU, a mesh, and what the
-    paged kernels are tested against."""
-    B, S, _, dh = q.shape
+    dh]`` against ``k [B, T, Hkv, dh]``, ``v [B, T, Hkv, dv]`` under ``allowed
+    [B, S, T]`` -> ``[B, S, Hq, dv]`` (zeros for a query that is allowed
+    nothing). ``sink [Hq]``: a head's scalar joins its rows' denominators (a
+    column appended to the scores, dropped after the softmax). The plain
+    tier: the trainer's forward pass, a CPU, a mesh, and what the paged
+    kernels are tested against."""
+    B, S, Hq, dh = q.shape
     qg = q.reshape(B, S, a.n_kv_heads, a.group, dh)
     logits = jnp.einsum("bsgjk,btgk->bgjst", qg, k,
                         preferred_element_type=jnp.float32) \
         / math.sqrt(dh)
     ok = allowed[:, None, None]
-    probs = jax.nn.softmax(jnp.where(ok, logits, -1e30), -1)
+    logits = jnp.where(ok, logits, -1e30)
+    if sink is None:
+        probs = jax.nn.softmax(logits, -1)
+    else:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(a.n_kv_heads, a.group, 1, 1),
+            logits.shape[:-1] + (1,))
+        probs = jax.nn.softmax(jnp.concatenate([logits, col], -1),
+                               -1)[..., :-1]
     probs = jnp.where(ok, probs, 0.0).astype(dt)
-    return jnp.einsum("bgjst,btgk->bsgjk", probs, v).reshape(q.shape)
+    return jnp.einsum("bgjst,btgk->bsgjk", probs, v).reshape(
+        B, S, Hq, v.shape[-1])
 
 
 def attend_allowed(a, q_pos, k_pos, live=None):
@@ -1268,9 +1340,10 @@ def attend_allowed(a, q_pos, k_pos, live=None):
 def _attend_kind(a, dt):
     """``attend(q, k, v)`` of a described multi-head layer over its own
     window (no cache): the forward pass of the trainer and of the tests."""
-    def attend(q, k, v):
+    def attend(q, k, v, sink=None):
         pos = jnp.broadcast_to(jnp.arange(k.shape[1])[None], k.shape[:2])
-        return grouped_attend(q, k, v, a, attend_allowed(a, pos, pos), dt)
+        return grouped_attend(q, k, v, a, attend_allowed(a, pos, pos), dt,
+                              sink)
 
     return attend
 
@@ -2357,9 +2430,10 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
     gates the result a head where the model does, and returns the selection
     in its routing (``{"selected": ..}``). A multi-head layer
     of a described kind (:class:`MultiHeadAttention`) hands it ``attend(q
-    [B, S, Hq, dh], k, v [B, S, Hkv, dh]) -> [B, S, Hq, dh]``
-    (:func:`_qkv_kind`) and gates the result a head or a channel where the
-    kind says. A RECURRENT layer (:class:`StateSpaceMixer`,
+    [B, S, Hq, dh], k [B, S, Hkv, dh], v [B, S, Hkv, dv]) -> [B, S, Hq, dv]``
+    (:func:`_qkv_kind`; ``sink=`` the layer's ``[Hq]`` scalars where the kind
+    has them) and gates the result a head or a channel where the kind
+    says. A RECURRENT layer (:class:`StateSpaceMixer`,
     :class:`DeltaRuleMixer`) hands it the mixer itself, ``attend(mix) -> out
     [B, S, D]`` with ``mix(tail, state, live) -> (out, tail, state)``
     (:func:`state_space_mix` or :func:`delta_rule_mix` on this layer's normed
@@ -2414,7 +2488,8 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
                 out = jnp.einsum("bshk,hkd->bsd", attend(q, k, v),
                                  layer["wo"].astype(dt))
             elif isinstance(a, MultiHeadAttention):
-                o = attend(*_qkv_kind(h, layer, cfg, a, positions))
+                o = attend(*_qkv_kind(h, layer, cfg, a, positions),
+                           **({"sink": layer["sink"]} if a.sink else {}))
                 if a.differential:
                     o = differential_combine(o, layer, a, li, cfg.norm_eps,
                                              dt)
@@ -2475,12 +2550,12 @@ def _block_fn(cfg, mesh, impl, seq_spec, full_spec):
         elif isinstance(a, MultiHeadAttention):
             kind = _attend_kind(a.attended, cfg.compute_dtype)
 
-            def mine(q, k, v):
+            def mine(q, k, v, **sink):
                 if a.kv_from is not None:
                     k, v = carried[f"kv{a.kv_from}"]
                 elif cfg.shares_kv(li):
                     carried[f"kv{li}"] = (k, v)
-                return kind(q, k, v)
+                return kind(q, k, v, **sink)
         else:
             mine = attend if a is None else _attend_latent(
                 a, cfg.compute_dtype)

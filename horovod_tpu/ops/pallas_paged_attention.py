@@ -246,19 +246,44 @@ def _heads_packed(head_dim, n_kv_heads):
     return pack if pack and n_kv_heads and n_kv_heads % pack == 0 else 0
 
 
-def grouped_supported(page_size, head_dim, dtype, n_kv_heads=None):
+def _key_cover(head_dim):
+    """Lanes of the fused key row that :func:`paged_grouped_attention` takes
+    for ONE key head: the head's own where they are whole lane tiles; for a
+    head of one and a half tiles (192) the two tiles and the half of a
+    neighbour's that the aligned slice around it holds, ``head_dim + 64``:
+    head ``g`` lies at lanes ``g * head_dim``, so an even head starts on a
+    tile and an odd one 64 lanes into one."""
+    halves = head_dim > 128 and head_dim % 128 == 64
+    return head_dim + 64 if halves else head_dim
+
+
+def grouped_supported(page_size, head_dim, dtype, n_kv_heads=None,
+                      v_head_dim=None):
     """Whether :func:`paged_grouped_attention` tiles on a TPU: a page is
     whole sublane tiles of ``dtype`` and a head whole lane tiles (a key/value
     head is sliced out of the fused row), or, given ``n_kv_heads``, a narrower
-    head that fills a lane tile with its neighbours (:func:`_heads_packed`)."""
+    head that fills a lane tile with its neighbours (:func:`_heads_packed`).
+    ``v_head_dim``: a value head of a width of its own has to be whole lane
+    tiles, and its key head whole tiles or whole tiles and a half under an
+    even number of heads (:func:`_key_cover`: every aligned slice around a
+    head then lies inside the row)."""
     sublanes = 8 * (4 // jnp.dtype(dtype).itemsize)
-    return (page_size % sublanes == 0
-            and _heads_packed(head_dim, n_kv_heads) > 0)
+    if page_size % sublanes:
+        return False
+    if v_head_dim is None or v_head_dim == head_dim:
+        return _heads_packed(head_dim, n_kv_heads) > 0
+    return v_head_dim % 128 == 0 and (
+        head_dim % 128 == 0
+        or (head_dim % 128 == 64 and bool(n_kv_heads)
+            and n_kv_heads % 2 == 0))
 
 
-def _grouped_kernel(pos0_ref, len_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
-                    k_buf, v_buf, sems, m_s, l_s, acc_s, *, page, ppb, width,
-                    ring, n_kv, head_dim, qb, window, sm_scale):
+def _grouped_kernel(pos0_ref, len_ref, tables_ref, q_ref, k_hbm, v_hbm, *refs,
+                    page, ppb, width, ring, n_kv, k_at, head_dim, v_dim, qb,
+                    window, sm_scale, has_sink):
+    # ``refs``: the sink's tile where the kind has one, the output, scratch.
+    sink_ref = refs[0] if has_sink else None
+    o_ref, k_buf, v_buf, sems, m_s, l_s, acc_s = refs[has_sink:]
     b, qi = pl.program_id(0), pl.program_id(1)
     kv_len = len_ref[b]
     q_first = pos0_ref[b] + qi * qb
@@ -286,8 +311,15 @@ def _grouped_kernel(pos0_ref, len_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
                 v_hbm.at[pid], v_buf.at[slot, at], sems.at[1, slot]))
         return out
 
-    m_s[...] = jnp.full_like(m_s, _NEG_INF)
-    l_s[...] = jnp.zeros_like(l_s)
+    if has_sink:
+        # The sink is the row's starting state: a score that is already in
+        # the running maximum and sum, with no value (padding rows hold
+        # ``_NEG_INF`` and start empty).
+        m_s[...] = sink_ref[...]
+        l_s[...] = jnp.where(sink_ref[...] > 0.5 * _NEG_INF, 1.0, 0.0)
+    else:
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
     acc_s[...] = jnp.zeros_like(acc_s)
 
     # Row r of a key/value head's tile is query r % qb of the block, of one
@@ -318,9 +350,9 @@ def _grouped_kernel(pos0_ref, len_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         if window:
             ok &= k_pos > q_pos - window
         for g in range(n_kv):
-            lanes = slice(g * head_dim, (g + 1) * head_dim)
             s = jax.lax.dot_general(
-                q_ref[0, 0, g], k[:, lanes], (((1,), (1,)), ((), ())),
+                q_ref[0, 0, g], k[:, k_at[g]:k_at[g] + head_dim],
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
             s = jnp.where(ok, s, _NEG_INF)
             m_prev = m_s[g]
@@ -332,7 +364,8 @@ def _grouped_kernel(pos0_ref, len_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
             m_s[g] = m_new
             l_s[g] = l_s[g] * alpha + jnp.sum(p, -1, keepdims=True)
             acc_s[g] = acc_s[g] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v[:, lanes], (((1,), (0,)), ((), ())),
+                p.astype(v.dtype), v[:, g * v_dim:(g + 1) * v_dim],
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
     jax.lax.fori_loop(0, n_blocks, block, None)
@@ -348,10 +381,11 @@ def _grouped_kernel(pos0_ref, len_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
     "interpret"))
 def paged_grouped_attention(q, k_pages, v_pages, tables, pos0, kv_len, *,
                             n_kv_heads, window=0, ring=False, q_block=None,
-                            pages_per_block=None, interpret=False):
+                            pages_per_block=None, interpret=False, sink=None):
     """``q [B, Q, Hq, dh]``, a slot's ``Q`` queries at the consecutive
-    positions ``pos0 [B] ..``, against one layer's cache ``k_pages, v_pages
-    [n_pages, page, Hkv * dh]`` -> ``[B, Q, Hq, dh]`` in ``q``'s dtype.
+    positions ``pos0 [B] ..``, against one layer's cache ``k_pages [n_pages,
+    page, Hkv * dh]``, ``v_pages [n_pages, page, Hkv * dv]`` -> ``[B, Q, Hq,
+    dv]`` in ``q``'s dtype.
 
     Query head ``j`` reads key/value head ``j // (Hq / Hkv)``. A query at
     ``p`` sees the keys at positions ``<= p`` and ``< kv_len [B]`` (the
@@ -376,15 +410,32 @@ def paged_grouped_attention(q, k_pages, v_pages, tables, pos0, kv_len, *,
     query is widened to the pack's 128 lanes with zeros under the pack's
     other heads, so its scores are its own head's; its result's lanes under
     the other heads (their values under this head's probabilities) are
-    dropped. The scale stays the narrow head's."""
+    dropped. The scale stays the narrow head's.
+
+    A VALUE head of a width of its own (``dv != dh``, whole lane tiles) is
+    sliced out of its own fused row; the output and the accumulator are
+    ``dv`` wide. A KEY head of one and a half lane tiles (192) is read as the
+    fused row holds it, no lane of padding stored: head ``g`` is taken with
+    the aligned 256 lanes around it (:func:`_key_cover`; an even head and the
+    first half of the next, or the second half of the one before and an odd
+    head), and its queries are laid into those 256 lanes with zeros under the
+    neighbour's 64, so the product over 256 is the head's own over 192. That
+    is two passes of a 128-deep systolic array, which a contraction over 192
+    costs anyway. The scale is ``dh ** -0.5`` of the head's own width.
+
+    ``sink [Hq]``: one scalar a query head that joins its rows' softmax
+    DENOMINATOR and nothing else: the online softmax starts a row at ``m =
+    sink, l = 1`` in place of ``m = -inf, l = 0``; no key is stored."""
     B, Q, Hq, dh = q.shape
     n_pages, page, hd = k_pages.shape
     n_kv = int(n_kv_heads)
-    if hd != n_kv * dh or v_pages.shape != k_pages.shape or Hq % n_kv:
+    dv = v_pages.shape[2] // n_kv
+    if (hd != n_kv * dh or v_pages.shape[:2] != k_pages.shape[:2]
+            or v_pages.shape[2] != n_kv * dv or Hq % n_kv):
         raise ValueError(f"cache {k_pages.shape} / {v_pages.shape} does not "
                          f"hold {n_kv} heads of {dh} under {Hq} query heads")
     sm_scale = 1.0 / math.sqrt(dh)
-    pack = _heads_packed(dh, n_kv) if dh % 128 else 1
+    pack = _heads_packed(dh, n_kv) if dh % 128 and dv == dh else 1
     if pack > 1:
         narrow, group1 = dh, Hq // n_kv
         # Query head j reads key/value head j // group1, lane slot
@@ -394,7 +445,22 @@ def paged_grouped_attention(q, k_pages, v_pages, tables, pos0, kv_len, *,
         q = jnp.where(mine[..., None], q[..., None, :], 0).reshape(
             B, Q, Hq, pack * narrow)
         n_kv, dh = n_kv // pack, pack * narrow
+        dv = dh
     group = Hq // n_kv
+    # The lanes of the fused key row a head is multiplied over, and where the
+    # head's own lie among them.
+    cover = _key_cover(dh) if dv != dh else dh
+    k_at = tuple(g * dh // 128 * 128 if cover != dh else g * dh
+                 for g in range(n_kv))
+    if cover != dh:
+        if k_at[-1] + cover > hd:
+            raise ValueError(f"{n_kv} key heads of {dh} do not tile")
+        # A head's queries where its keys lie among the lanes taken: from
+        # the first lane (an even head) or behind the neighbour's 64.
+        odd = (jnp.arange(Hq) // group) % 2 == 1
+        q = jnp.where(odd[:, None],
+                      jnp.pad(q, ((0, 0),) * 3 + ((cover - dh, 0),)),
+                      jnp.pad(q, ((0, 0),) * 3 + ((0, cover - dh),)))
     qb = int(q_block or _Q_BLOCK)
     if qb & (qb - 1):
         raise ValueError(f"q_block {qb} is not a power of two")
@@ -409,49 +475,61 @@ def paged_grouped_attention(q, k_pages, v_pages, tables, pos0, kv_len, *,
     bt = ppb * page
     rows = -(-group * qb // 16) * 16      # whole bf16 sublane tiles
     # [B, Q, Hq, dh] -> a tile of rows (group, query) a key/value head.
-    qt = q.reshape(B, nq, qb, n_kv, group, dh).transpose(0, 1, 3, 4, 2, 5)
-    qt = qt.reshape(B, nq, n_kv, group * qb, dh)
+    qt = q.reshape(B, nq, qb, n_kv, group, cover).transpose(0, 1, 3, 4, 2, 5)
+    qt = qt.reshape(B, nq, n_kv, group * qb, cover)
     qt = jnp.pad(qt, ((0, 0),) * 3 + ((0, rows - group * qb), (0, 0)))
     kernel = functools.partial(
         _grouped_kernel, page=page, ppb=ppb, width=width, ring=bool(ring),
-        n_kv=n_kv, head_dim=dh, qb=qb, window=int(window),
-        sm_scale=sm_scale)
+        n_kv=n_kv, k_at=k_at, head_dim=cover, v_dim=dv, qb=qb,
+        window=int(window), sm_scale=sm_scale, has_sink=sink is not None)
     itemsize = k_pages.dtype.itemsize
     live = B * nq * (min(width * page, window + qb) if window
                      else width * page)          # an upper bound
-    tile = pl.BlockSpec((1, 1, n_kv, rows, dh),
-                        lambda b, qi, *_: (b, qi, 0, 0, 0),
-                        memory_space=pltpu.VMEM)
+
+    def tile(lanes):
+        return pl.BlockSpec((1, 1, n_kv, rows, lanes),
+                            lambda b, qi, *_: (b, qi, 0, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    operands, in_specs = [], []
+    if sink is not None:
+        # Row r of head g's tile is query head g * group + r // qb.
+        st = jnp.repeat(sink.astype(jnp.float32).reshape(n_kv, group), qb, 1)
+        operands.append(jnp.pad(st, ((0, 0), (0, rows - group * qb)),
+                                constant_values=_NEG_INF)[..., None])
+        in_specs.append(pl.BlockSpec((n_kv, rows, 1),
+                                     lambda b, qi, *_: (0, 0, 0),
+                                     memory_space=pltpu.VMEM))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, nq),
-            in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=tile,
+            in_specs=[tile(cover), pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)] + in_specs,
+            out_specs=tile(dv),
             scratch_shapes=[
                 pltpu.VMEM((2, bt, hd), k_pages.dtype),
-                pltpu.VMEM((2, bt, hd), v_pages.dtype),
+                pltpu.VMEM((2, bt, v_pages.shape[2]), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((n_kv, rows, 1), jnp.float32),
                 pltpu.VMEM((n_kv, rows, 1), jnp.float32),
-                pltpu.VMEM((n_kv, rows, dh), jnp.float32),
+                pltpu.VMEM((n_kv, rows, dv), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((B, nq, n_kv, rows, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nq, n_kv, rows, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
-            flops=4 * n_kv * rows * dh * live,
+            flops=2 * n_kv * rows * (cover + dv) * live,
             transcendentals=n_kv * rows * live,
-            bytes_accessed=2 * live * hd * itemsize),
+            bytes_accessed=live * (hd + v_pages.shape[2]) * itemsize),
         name=WINDOW_NAME if window else FULL_NAME,
         interpret=interpret,
     )(pos0.astype(jnp.int32), kv_len.astype(jnp.int32),
-      tables.reshape(-1).astype(jnp.int32), qt, k_pages, v_pages)
-    out = out[:, :, :, :group * qb].reshape(B, nq, n_kv, group, qb, dh)
-    out = out.transpose(0, 1, 4, 2, 3, 5).reshape(B, Q, Hq, dh)
+      tables.reshape(-1).astype(jnp.int32), qt, k_pages, v_pages, *operands)
+    out = out[:, :, :, :group * qb].reshape(B, nq, n_kv, group, qb, dv)
+    out = out.transpose(0, 1, 4, 2, 3, 5).reshape(B, Q, Hq, dv)
     if pack > 1:        # each head's own lanes of its pack's result
         out = jnp.sum(jnp.where(mine[..., None],
                                 out.reshape(B, Q, Hq, pack, narrow), 0), 3)

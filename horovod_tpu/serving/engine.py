@@ -255,7 +255,7 @@ def grouped_kernels(cfg, geo, mesh):
     return (bool(cfg.multihead) and _kernels_may_run(cfg, mesh)
             and all(paged_attention.grouped_supported(
                 geo.page_size, a.attended.head_dim, cfg.compute_dtype,
-                a.attended.n_kv_heads)
+                a.attended.n_kv_heads, a.attended.v_dim)
                 for _, a in cfg.multihead))
 
 
@@ -516,11 +516,13 @@ def _latent_work(a, live, kernels):
 
 
 def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
-                   kernels):
+                   kernels, sink=None):
     """One multi-head layer of a described kind in a chunk or decode
-    program: write the window's ``k, v [B, Q, Hkv, dh]`` at the consecutive
-    positions ``q_pos [B, Q]`` where ``ok [B, Q]``, then attend ``q [B, Q,
-    Hq, dh]`` -> (the layer's arrays, ``o [B, Q, Hq, dh]``). ``q`` None:
+    program: write the window's ``k [B, Q, Hkv, dh]`` and ``v [B, Q, Hkv,
+    dv]`` at the consecutive positions ``q_pos [B, Q]`` where ``ok [B, Q]``,
+    then attend ``q [B, Q, Hq, dh]`` -> (the layer's arrays, ``o [B, Q, Hq,
+    dv]``). ``sink [Hq]``: the layer's scalars in its rows' denominators
+    (``MultiHeadAttention.sink``). ``q`` None:
     the write alone. ``k`` None: nothing is written and ``k_c, v_c`` are
     attended as they are (a layer that attends ANOTHER layer's pages,
     ``kv_from``; a fill's last row, whose layer wrote its window before:
@@ -542,7 +544,8 @@ def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
     if kernels:
         o = paged_attention.paged_grouped_attention(
             q, k_c, v_c, table, q_pos[:, 0], p_hi + 1,
-            n_kv_heads=a.n_kv_heads, window=a.window, ring=bool(a.window))
+            n_kv_heads=a.n_kv_heads, window=a.window, ring=bool(a.window),
+            sink=sink)
         return k_c, v_c, o
     n_cells = table.shape[1] * geo.page_size
     if a.window:
@@ -551,9 +554,9 @@ def _grouped_layer(a, q, k, v, k_c, v_c, *, q_pos, ok, tables, geo, dt,
         k_pos = jnp.broadcast_to(jnp.arange(n_cells)[None], (B, n_cells))
     allowed = tfm.attend_allowed(a, q_pos, k_pos,
                                  (k_pos >= 0) & (k_pos <= p_hi[:, None]))
-    rows = (c[table].reshape(B, n_cells, a.n_kv_heads, a.head_dim)
+    rows = (c[table].reshape(B, n_cells, a.n_kv_heads, -1)
             for c in (k_c, v_c))
-    return k_c, v_c, tfm.grouped_attend(q, *rows, a, allowed, dt)
+    return k_c, v_c, tfm.grouped_attend(q, *rows, a, allowed, dt, sink)
 
 
 def _grouped_work(a, live, itemsize):
@@ -561,28 +564,36 @@ def _grouped_work(a, live, itemsize):
     (``live`` as in :func:`_latent_work`): the K/V rows it reads (each live
     row of a slot once: what the paged kernel has to move; a window layer
     those of its ring, and the rows it would read were it sized like a full
-    one) and the (query, key) pairs it multiplies, full and window layers
-    apart. A layer that attends ANOTHER layer's pages (``kv_from``) counts
+    one), the same rows in BYTES at the kind's own key and value lanes
+    (``kv_full_bytes``, ``kv_window_bytes``: kinds of one model may differ in
+    key/value heads, and a key row need not be as wide as a value row), and
+    the (query, key) pairs it multiplies, full and window layers apart;
+    ``sink_rows``, the (query, layer) pairs whose softmax is normalised over
+    a sink. A layer that attends ANOTHER layer's pages (``kv_from``) counts
     its rows as ``kv_shared_rows`` (K/V rows read by a layer that owns none)
-    and its pairs with the full layers'. A full or sharing layer's rows in
-    bytes (K and V at ``itemsize``) are what ``serve_stats()["state"]`` sets
-    beside the recurrent layers' bytes."""
+    and its pairs with the full layers'. A full or sharing layer's bytes are
+    also what ``serve_stats()["state"]`` sets beside the recurrent layers'
+    bytes (``kv_bytes``)."""
     rows = live.max(axis=1, initial=0)      # a slot's live rows, read once
+    row_bytes = (a.k_width + a.v_width) * itemsize
     found = dict.fromkeys(("kv_full_rows", "kv_window_rows",
                            "kv_window_rows_as_full", "qk_full_pairs",
-                           "qk_window_pairs"), 0)
+                           "qk_window_pairs", "kv_full_bytes",
+                           "kv_window_bytes"), 0)
+    found["sink_rows"] = live.size if a.sink else 0
     if a.window:
+        ring = np.minimum(rows, a.window - 1 + live.shape[1]).sum()
         found.update(
-            kv_window_rows=np.minimum(
-                rows, a.window - 1 + live.shape[1]).sum(),
+            kv_window_rows=ring, kv_window_bytes=ring * row_bytes,
             kv_window_rows_as_full=rows.sum(),
             qk_window_pairs=np.minimum(live, a.window).sum())
         return {"attn": found}
-    found.update(qk_full_pairs=live.sum())
+    found.update(qk_full_pairs=live.sum(),
+                 kv_full_bytes=rows.sum() * row_bytes)
     found["kv_full_rows" if a.kv_from is None
           else "kv_shared_rows"] = rows.sum()
     return {"attn": found,
-            "state": {"kv_bytes": rows.sum() * 2 * a.kv_width * itemsize}}
+            "state": {"kv_bytes": rows.sum() * row_bytes}}
 
 
 def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=None,
@@ -871,7 +882,8 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
                 cv[li], vv = write(cv[li], v)
                 return attend(q, kk, vv)
         elif isinstance(a, tfm.MultiHeadAttention):
-            def write_and_attend(q, k, v, li=li, a=a, written=li == leaves):
+            def write_and_attend(q, k, v, li=li, a=a, written=li == leaves,
+                                 **sink):
                 # Whose pages: the layer's own, or the layer's it names,
                 # which are read and not written (as the layer's own are on
                 # a fill's last row: they were written above).
@@ -880,7 +892,7 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
                 k_c, v_c, o = _grouped_layer(
                     a.attended, q, *((None, None) if written else (k, v)),
                     ck[src], cv[src], **window, geo=geo,
-                    dt=cfg.compute_dtype, kernels=kernels["grouped"])
+                    dt=cfg.compute_dtype, kernels=kernels["grouped"], **sink)
                 if own:
                     ck[li], cv[li] = k_c, v_c
                 return o

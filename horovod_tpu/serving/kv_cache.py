@@ -45,7 +45,13 @@ window - 1 + the longest query window of any program``, so that a program
 may write its whole window first and still find every key its first query
 sees. A window layer of a described MULTI-HEAD kind
 (``TransformerConfig.multihead``) holds its K and its V the same way, both on
-rings from that pool, ``[ring_pages, page, n_kv_heads * head_dim]``. A ring (and not a block table that frees pages as the window slides)
+rings from that pool, ``[ring_pages, page, n_kv_heads * head_dim]``. A kind
+whose VALUE heads have a width of their own (``MultiHeadAttention.v_head_dim``)
+holds K and V arrays of different lanes, ``n_kv_heads * head_dim`` and
+``n_kv_heads * v_head_dim``, as projected: no lane of padding is stored; and
+kinds of one model may differ in key/value heads, so a full layer's pages and
+a window layer's rings need not be equally wide. A ring (and not a block table
+that frees pages as the window slides)
 because its size never changes: nothing is allocated or freed at a token
 boundary, no request can be starved or preempted for window state, and the
 block table a program takes keeps one fixed width, ``max_blocks +
@@ -200,8 +206,8 @@ def layer_shapes(cfg, geo, li):
         raise ValueError("a window layer needs a geometry with rings "
                          "(kv_cache.with_rings)")
     if isinstance(a, MultiHeadAttention):
-        shape = (pages, geo.page_size, a.kv_width)
-        return shape, shape
+        return ((pages, geo.page_size, a.k_width),
+                (pages, geo.page_size, a.v_width))
     return ((pages, geo.page_size, a.row_width),
             (pages, geo.page_size, a.index_dim) if a.index_topk else None)
 

@@ -129,12 +129,12 @@ def test_the_file_describes_its_layers():
         (48, 0), (72, 512), (72, 512), (72, 512)] * 2 + [(48, 0)]
     full, window = kinds[0], kinds[1]
     assert all(isinstance(a, tfm.MultiHeadAttention) for a in kinds)
-    assert (full.n_kv_heads, full.head_dim, full.group, full.kv_width,
+    assert (full.n_kv_heads, full.head_dim, full.group, full.k_width,
             full.rope_dim, full.rope_theta, full.gate) == (
         8, 128, 6, 1024, 64, 500000, True)
     assert (full.yarn.factor, full.yarn.original_max, full.yarn.beta_fast,
             full.yarn.beta_slow) == (128, 8192, 32, 1)
-    assert (window.group, window.kv_width, window.rope_dim, window.yarn,
+    assert (window.group, window.k_width, window.rope_dim, window.yarn,
             window.rope_theta, window.gate) == (9, 1024, 128, None, 10000,
                                                 True)
     assert cfg.head_dim == 64 != full.head_dim     # 3072 / 48: not a layer's

@@ -188,30 +188,37 @@ def _grouped_case(rng, a, *, B, Q, width, lens, page=PAGE):
     :func:`paged_grouped_attention`: ``lens[b] = (pos0, valid queries)``,
     0 valid = an inactive slot. Every cell a query may not see holds NaN
     (a page no slot owns) or large garbage (the positions after a slot's
-    last, stale ring cells): a read of either shows in the output."""
+    last, stale ring cells): a read of either shows in the output. K and V
+    pages at the kind's own lanes; a kind with a sink gets one scalar a query
+    head, large enough to hold a real share of a row's softmax."""
     from horovod_tpu.ops.pallas_paged_attention import paged_grouped_attention
 
     n_pages = 1 + B * width
-    lanes = a.kv_width
     tables = rng.permutation(np.arange(1, n_pages)).reshape(B, width).astype(
         np.int32)
-    pages = np.full((2, n_pages, page, lanes), np.nan, np.float32)
+    pages = [np.full((n_pages, page, lanes), np.nan, np.float32)
+             for lanes in (a.k_width, a.v_width)]
     pos0 = np.asarray([p for p, _ in lens], np.int32)
     kv_len = np.asarray([p + n if n else 0 for p, n in lens], np.int32)
     cells = width * page
     for b, n in enumerate(kv_len):
         # The positions a query of this slot may see, where they lie.
         lo = max(0, int(pos0[b]) - (a.window - 1)) if a.window else 0
-        rows = 1e4 * rng.normal(size=(2, cells, lanes))
-        for t in range(lo, int(n)):
-            rows[:, t % cells if a.window else t] = rng.normal(
-                size=(2, lanes))
-        pages[:, tables[b]] = rows.reshape(2, width, page, lanes)
+        for held in pages:
+            lanes = held.shape[-1]
+            rows = 1e4 * rng.normal(size=(cells, lanes))
+            for t in range(lo, int(n)):
+                rows[t % cells if a.window else t] = rng.normal(size=lanes)
+            held[tables[b]] = rows.reshape(width, page, lanes)
     q = jnp.asarray(rng.normal(size=(B, Q, a.n_heads, a.head_dim)),
                     jnp.bfloat16)
-    k_pages, v_pages = jnp.asarray(pages, jnp.bfloat16)
+    k_pages, v_pages = (jnp.asarray(held, jnp.bfloat16) for held in pages)
+    sink = jnp.asarray(2.0 + rng.normal(size=a.n_heads), jnp.float32) \
+        if a.sink else None
 
     def run(**kw):
+        if a.sink:
+            kw["sink"] = sink
         return paged_grouped_attention(
             q, k_pages, v_pages, jnp.asarray(tables), jnp.asarray(pos0),
             jnp.asarray(kv_len), n_kv_heads=a.n_kv_heads, window=a.window,
@@ -227,26 +234,42 @@ def _grouped_case(rng, a, *, B, Q, width, lens, page=PAGE):
     allowed = tfm.attend_allowed(
         a, jnp.asarray(q_pos), jnp.asarray(k_pos),
         jnp.asarray((k_pos >= 0) & (k_pos <= p_hi[:, None])))
-    clean = jnp.nan_to_num(jnp.asarray(pages), nan=0.0).astype(jnp.bfloat16)
-    k_all, v_all = (c[jnp.asarray(tables)].reshape(
-        B, cells, a.n_kv_heads, a.head_dim) for c in clean)
-    want = tfm.grouped_attend(q, k_all, v_all, a, allowed, jnp.bfloat16)
+    k_all, v_all = (
+        jnp.nan_to_num(jnp.asarray(held), nan=0.0).astype(jnp.bfloat16)[
+            jnp.asarray(tables)].reshape(B, cells, a.n_kv_heads, -1)
+        for held in pages)
+    want = tfm.grouped_attend(q, k_all, v_all, a, allowed, jnp.bfloat16,
+                              sink)
     live = q_pos < kv_len[:, None]                  # the queries that count
     return run, np.asarray(want, np.float32), live
 
 
-@pytest.mark.parametrize("heads, kv_heads, head_dim, window", [
-    (48, 8, 128, 0),      # the published full layer's grouping
-    (72, 8, 128, 512),    # the published window layer's, on a ring
-    (6, 2, 32, 40),       # a window the ring barely holds
-    (4, 4, 64, 0),        # Hq == Hkv: what paged_decode_attention computes
-    (32, 8, 64, 0),       # heads of 64 over 8: two heads a lane tile
-], ids=["full-6x8", "window-9x8", "window-small", "one-each", "narrow-4x8"])
+# Keys of 192 beside values of 128, a sink: (v_head_dim, sink); the kinds
+# before them have neither.
+PLAIN = (None, False)
+
+
+@pytest.mark.parametrize("heads, kv_heads, head_dim, window, wide", [
+    (48, 8, 128, 0, PLAIN),     # the published full layer's grouping
+    (72, 8, 128, 512, PLAIN),   # the published window layer's, on a ring
+    (6, 2, 32, 40, PLAIN),      # a window the ring barely holds
+    (4, 4, 64, 0, PLAIN),       # Hq == Hkv: what paged_decode_attention computes
+    (32, 8, 64, 0, PLAIN),      # heads of 64 over 8: two heads a lane tile
+    (64, 4, 192, 0, (128, False)),    # 16 to a head of 192 / 128, on pages
+    (64, 8, 192, 128, (128, True)),   # 8 to a head, a ring of 128, a sink
+    (8, 2, 192, 0, (128, True)),      # a sink over a whole context
+    (8, 4, 192, 40, (128, False)),    # a window with no sink
+], ids=["full-6x8", "window-9x8", "window-small", "one-each", "narrow-4x8",
+        "full-192-16x4", "window-192-8x8-sink", "full-192-sink",
+        "window-192-no-sink"])
 def test_grouped_kernel_decodes_like_the_gathered_tier(heads, kv_heads,
-                                                       head_dim, window):
+                                                       head_dim, window,
+                                                       wide):
     """One query a slot (the decode step): slots of mixed lengths, one
-    inactive, a window layer's slots past their ring."""
-    a = tfm.MultiHeadAttention(heads, kv_heads, head_dim, window=window)
+    inactive (a slot of length 0: zeros, sink or none), a window layer's
+    slots past their ring."""
+    a = tfm.MultiHeadAttention(heads, kv_heads, head_dim, window=window,
+                               v_head_dim=wide[0], sink=wide[1])
     rng = np.random.default_rng(heads + window)
     width = -(-(window - 1 + 1) // PAGE) if window else 6
     lens = [(0, 1), (window + 3 * width * PAGE + 5 if window else 77, 1),
@@ -258,18 +281,25 @@ def test_grouped_kernel_decodes_like_the_gathered_tier(heads, kv_heads,
     np.testing.assert_allclose(got[live], want[live], atol=2e-2, rtol=2e-2)
 
 
-@pytest.mark.parametrize("heads, kv_heads, head_dim, window, q_block", [
-    (12, 2, 32, 0, 8), (18, 2, 32, 24, 8), (18, 2, 32, 24, 32),
-    (4, 4, 32, 0, 16), (8, 4, 64, 0, 16),
-], ids=["full", "window", "window-one-block", "one-each", "narrow"])
+@pytest.mark.parametrize(
+    "heads, kv_heads, head_dim, window, q_block, wide", [
+        (12, 2, 32, 0, 8, PLAIN), (18, 2, 32, 24, 8, PLAIN),
+        (18, 2, 32, 24, 32, PLAIN), (4, 4, 32, 0, 16, PLAIN),
+        (8, 4, 64, 0, 16, PLAIN),
+        (16, 4, 192, 0, 8, (128, False)), (16, 8, 192, 24, 8, (128, True)),
+        (8, 2, 192, 0, 16, (128, True)), (8, 4, 192, 24, 32, (128, False)),
+    ], ids=["full", "window", "window-one-block", "one-each", "narrow",
+            "full-192", "window-192-sink", "full-192-sink",
+            "window-192-one-block"])
 def test_grouped_kernel_fills_a_chunk_like_the_gathered_tier(
-        heads, kv_heads, head_dim, window, q_block):
+        heads, kv_heads, head_dim, window, q_block, wide):
     """A block of 32 queries a slot (the chunk fill), in query blocks of
     ``q_block``: a first chunk, a chunk deep into the context (a window
     layer's ring wrapped), a short last chunk (20 of 32 valid), an inactive
     slot. Scores exist a tile at a time, so a query that sees nothing of a
     key block another query of its block sees must stay exact."""
-    a = tfm.MultiHeadAttention(heads, kv_heads, head_dim, window=window)
+    a = tfm.MultiHeadAttention(heads, kv_heads, head_dim, window=window,
+                               v_head_dim=wide[0], sink=wide[1])
     rng = np.random.default_rng(heads + window + q_block)
     Q = 32
     width = -(-(window - 1 + Q) // PAGE) if window else 12
@@ -363,3 +393,12 @@ def test_who_takes_the_grouped_kernel():
     assert not grouped_supported(16, 64, bf16, 3)      # an odd head is left
     assert not grouped_supported(16, 96, bf16, 8)
     assert (_heads_packed(64, 8), _heads_packed(32, 8)) == (2, 4)
+    # A value width of its own: whole tiles, under a key of whole tiles or
+    # of whole tiles and a half in pairs.
+    assert grouped_supported(16, 192, bf16, 4, 128)
+    assert grouped_supported(16, 192, bf16, 8, 128)
+    assert grouped_supported(16, 256, bf16, 3, 128)
+    assert not grouped_supported(16, 192, bf16, 3, 128)   # an odd head is left
+    assert not grouped_supported(16, 192, bf16, 4, 64)
+    assert not grouped_supported(16, 192, bf16, 4)        # values of 192
+    assert not grouped_supported(16, 160, bf16, 4, 128)

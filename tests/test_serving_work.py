@@ -38,8 +38,10 @@ KINDS = {
 LIVE = {"chunk": np.arange(8, 16)[None] + 1,
         "decode": np.asarray([20, 3, 0])[:, None] + 1}
 BASE = dict(kv_scored=0, kv_selected=0, kv_window=0, calls=1)
+# A row of the grouped kinds here: K and V of 2 heads of 16, float32: 256 B.
 NO_GROUPED = dict(kv_full_rows=0, kv_window_rows=0, kv_window_rows_as_full=0,
-                  qk_full_pairs=0, qk_window_pairs=0)
+                  qk_full_pairs=0, qk_window_pairs=0, kv_full_bytes=0,
+                  kv_window_bytes=0, sink_rows=0)
 WANT = {
     "latent-select": {
         "chunk": {"attn": dict(BASE, kv_scored=300, kv_selected=192,
@@ -56,16 +58,18 @@ WANT = {
                                 latent_expanded_calls=0, queries=3)}},
     "multihead-full": {
         "chunk": {"attn": dict(BASE, **dict(NO_GROUPED, kv_full_rows=48,
+                                            kv_full_bytes=48 * 256,
                                             qk_full_pairs=300), queries=8)},
         "decode": {"attn": dict(BASE, **dict(NO_GROUPED, kv_full_rows=78,
+                                             kv_full_bytes=78 * 256,
                                              qk_full_pairs=78), queries=3)}},
     "multihead-window": {
         "chunk": {"attn": dict(BASE, **dict(
             NO_GROUPED, kv_window_rows=36, kv_window_rows_as_full=48,
-            qk_window_pairs=120), queries=8)},
+            kv_window_bytes=36 * 256, qk_window_pairs=120), queries=8)},
         "decode": {"attn": dict(BASE, **dict(
             NO_GROUPED, kv_window_rows=30, kv_window_rows_as_full=78,
-            qk_window_pairs=30), queries=3)}},
+            kv_window_bytes=30 * 256, qk_window_pairs=30), queries=3)}},
     "state-space": {
         "chunk": {"state": dict(rows=3, bytes=33792, tokens=24, resets=0,
                                 kv_bytes=0, calls=1)},
@@ -165,7 +169,9 @@ def test_work_of_a_fill_that_leaves_the_stack(ends, tail):
         "attn": dict(BASE, queries=8, fill_rows=8, tail_rows=tail,
                      kv_full_rows=rows, kv_shared_rows=rows,
                      qk_full_pairs=2 * pairs, kv_window_rows=12,
-                     kv_window_rows_as_full=16, qk_window_pairs=40),
+                     kv_window_rows_as_full=16, qk_window_pairs=40,
+                     kv_full_bytes=2 * rows * 256, kv_window_bytes=12 * 256,
+                     sink_rows=0),
         "state": dict(scan_rows=1, scan_bytes=2 * 448, scan_tokens=8,
                       scan_resets=0, calls=1,
                       kv_bytes=2 * rows * 2 * 32 * 4)}

@@ -672,6 +672,88 @@ def test_grouped_cell_programs_fit_one_chip(topo, as_on_the_chip):
             assert str(geo.max_kv) not in m.group(1).split(","), m.group(0)
 
 
+# ---- the serving programs at benchmark/configs/mimo-v2-flash.json's sizes ----
+
+def test_kinds_cell_programs_fit_one_chip(topo, as_on_the_chip):
+    """``mimo-serve-mixed64k-over``'s two programs (the 512-token chunk fill
+    and the decode step) at the cell's geometry: seven layers of two described
+    kinds that differ in KEY/VALUE heads, keys of 192 beside values of 128, a
+    sink on the window layers; 16 slots of a 64k context, the window layers on
+    rings of 40 pages. The chip's compiler takes the grouped paged kernel at
+    the published widths (a key head read as the aligned 256 lanes around it,
+    K and V pages of different lanes, the sink's tile) for one query a slot
+    and for a block of 128, under the instruction names the benchmark's
+    readers match, once a layer of its kind; no program holds scores of
+    ``[queries, max_kv]`` or a gathered copy of a slot's pages; weights +
+    cache + temporaries stay on the chip; the cache is aliased through."""
+    import importlib.util
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2-flash.json")) as f:
+        config = json.load(f)
+    sys.path.insert(0, root)
+    spec = importlib.util.spec_from_file_location(
+        "serve_gqa_kinds", os.path.join(root, "benchmark", "runners",
+                                        "serve_gqa_kinds.py"))
+    runner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(runner)
+    cfg = runner.model_config(config)
+    srv = config["assumed"]["serve"]
+    B = srv["max_batch"]
+    geo = kv_cache.with_rings(
+        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
+        cfg, srv["chunk"], B)
+    assert (geo.max_kv, geo.ring_tokens, geo.ring_pages) == (65536, 640, 641)
+    assert engine.grouped_kernels(cfg, geo, None)
+    params, cache = jax.tree.map(
+        lambda x: _on_chip(topo, x.shape, x.dtype),
+        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
+                                kv_cache.make_cache(cfg, geo))))
+    assert [c.shape[-1] for c in cache["k"]] == [768] + [1536] * 4 + [768,
+                                                                      1536]
+    assert [c.shape[-1] for c in cache["v"]] == [512] + [1024] * 4 + [512,
+                                                                      1024]
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert 12.4e9 < held < 12.6e9          # 73.9 % of the chip's 16.91e9
+
+    def slots(b, *q):
+        return [_on_chip(topo, s, d) for s, d in (
+            ((b, *q), jnp.int32), ((b,), jnp.int32),
+            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
+
+    kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
+    n_window = sum(1 for a in kinds if a.window)
+    for name, fn, args in (
+            ("chunk", engine.make_chunk_step(cfg, geo, q_len=srv["chunk"]),
+             slots(1, srv["chunk"])),
+            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
+             slots(B))):
+        compiled = fn.lower(params, cache, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
+        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
+        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
+        # The file's assumed.serve.why states them: 0.02e9 and 0.011e9.
+        assert memory.temp_size_in_bytes < 0.05e9
+        text = compiled.as_text()
+        for kernel, n in (("paged_full_attention", len(kinds) - n_window),
+                          ("paged_window_attention", n_window),
+                          ("paged_decode_attention", 0)):
+            calls = [line for line in text.splitlines()
+                     if re.match(rf"\s*%{kernel}[.\d]* = ", line)
+                     and "tpu_custom_call" in line]
+            assert len(calls) == n, (name, kernel)
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
+            == 3 * len(cfg.moe_layers)
+        # No float array spans a slot's max_kv positions: neither gathered
+        # pages nor a query block's scores over them.
+        for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", text):
+            assert str(geo.max_kv) not in m.group(1).split(","), m.group(0)
+
+
 # ---- the serving programs at benchmark/configs/sarvam-105b.json ----
 
 def test_full_latent_cell_programs_fit_one_chip(topo, as_on_the_chip):

@@ -22,7 +22,8 @@ module printed without locations.
 ``cpu``: the plain tier, at the tiny configurations the tier-1 tests serve
 (``tests/test_serving.py``, ``test_olmoe.py``, ``test_dots3.py``,
 ``test_laguna.py``, ``test_nemotron_h.py``, ``test_sarvam_mla.py``,
-``test_solar_open2.py``, ``test_phi4_flash.py``, ``test_granite_h.py``; the
+``test_solar_open2.py``, ``test_phi4_flash.py``, ``test_mimo_v2.py``,
+``test_granite_h.py``; the
 helpers of those files build them; ``granite_h`` with no snapshot rows and
 ``granite_h-share`` with the prefix cache that holds state and its two copy
 programs). ``chip``: the kernels' tier, a
@@ -48,7 +49,8 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "tests")]
 # cell's configuration -> its runner (``benchmark/configs/<name>.json``).
 CELLS = ("gpt2-large", "olmoe-1b-7b", "dots3-note-prev", "laguna-s-2.1",
          "nemotron-3-super-120b", "sarvam-105b", "solar-open2-250b",
-         "phi-4-mini-flash-reasoning", "granite-4.0-h-micro")
+         "phi-4-mini-flash-reasoning", "granite-4.0-h-micro",
+         "mimo-v2-flash")
 
 
 def _sha(text):
@@ -150,7 +152,7 @@ def _tiny_loops():
         test = importlib.import_module("test_" + name.partition("-")[0])
         kind = test._cfg(test._config())
         yield name, lambda: test._loop(kind, abstract(kind), **kw)
-    for name in ("nemotron_h", "solar_open2", "phi4_flash"):
+    for name in ("nemotron_h", "solar_open2", "phi4_flash", "mimo_v2"):
         test = importlib.import_module("test_" + name)
         kind = test.runner.model_config(test._config())
         yield name, lambda: test._loop(kind, abstract(kind))
