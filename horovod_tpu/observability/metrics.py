@@ -733,6 +733,11 @@ SERVE_KV_SELECT_SHARE = gauge(
     "hvd_serve_kv_select_share",
     "hvd_serve_kv_selected over hvd_serve_kv_scored, all programs so far: "
     "the share of the scored keys that attention reads")
+SERVE_SELECT_BLOCKS_SHARE = gauge(
+    "hvd_serve_select_blocks_share",
+    "Blocks of 128 keys the key selection's top-k ranked over the blocks "
+    "of max_kv keys a query, all programs so far: the share of a slot's "
+    "context that the kernel's work followed")
 CKPT_SAVES = counter(
     "hvd_ckpt_saves",
     "checkpoint.save() calls entered on this rank")

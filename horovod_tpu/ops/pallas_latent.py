@@ -24,11 +24,13 @@ the product with the rows, as ``latent_attend`` does.
     neither fetched nor multiplied: cost follows the live context.
 ``index_select``   a query's ``k`` best keys, exactly, with no sort: the
     k-th largest score by bisection on the scores' bit patterns (32 counting
-    passes over the row in VMEM), then the kept keys' indices compacted to
-    the front by prefix sums done as matrix products (within blocks of 128
-    keys, over the blocks, and one gather-by-one-hot product), ``-1`` where
-    fewer than ``k`` keys are live. Ties at the k-th score go to the lower
-    key indices, as in a stable top-k.
+    passes over the row in VMEM, eight queries a grid step together), then
+    the kept keys' indices compacted to the front by prefix sums done as
+    matrix products (within blocks of 128 keys, over the blocks, and one
+    gather-by-one-hot product), ``-1`` where fewer than ``k`` keys are
+    live. Ties at the k-th score go to the lower key indices, as in a
+    stable top-k. Told the queries' live keys it works over the head of the
+    row that holds them: cost follows the live context.
 ``sparse_latent_attention``   one query's heads against that query's own
     gathered rows ``[k, row_width]``: absorbed logits, softmax over the live
     ones, times the rows' latent part.
@@ -49,6 +51,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -179,43 +182,59 @@ def index_scores(q_i, w, keys, q_pos, *, interpret=None):
 
 # ---- index_select ---------------------------------------------------------
 
+_SELECT_SLAB = 32       # blocks of 128 keys: the step of the kernel's work
+_SELECT_ROWS = 8        # queries a grid step
+_NO_KEY = -2 ** 31 + 2 ** 23 - 1          # _ordered(-inf)
+
+
 def _ordered(x):
     """float32 -> int32 whose signed order is the floats' order."""
     bits = jax.lax.bitcast_convert_type(x, jnp.int32)
     return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
 
 
-def _count(mask):
-    ones = jnp.where(mask, 1.0, 0.0)
-    return jnp.sum(jnp.sum(ones, axis=1, keepdims=True), axis=0,
-                   keepdims=True)                               # [1, 1]
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
 
 
-def _select_kernel(s_ref, o_ref, *, k):
-    scores = s_ref[0]                                 # [nb, 128] float32
-    nb = scores.shape[0]
-    key = _ordered(scores)
-    live = scores > -jnp.inf
+def _mm(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
 
-    # The k-th largest key: the largest T with count(key >= T) >= k, built
-    # from the sign down (two's complement: setting a bit moves T up).
-    kf = jnp.float32(k)
-    t = jnp.where(_count(key >= 0) >= kf, jnp.int32(0),
-                  jnp.int32(-2 ** 31))                          # [1, 1]
-    for bit in range(30, -1, -1):
-        cand = t | jnp.int32(1 << bit)
-        t = jnp.where(_count(key >= cand) >= kf, cand, t)
 
-    def iota(shape, dim):
-        return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+def _kth(key_ref, rows, k):
+    """The k-th largest key of each of ``rows`` queries, ``key_ref [blocks *
+    rows, 128]`` int32 (block ``j`` of the queries: ``[j * rows:(j + 1) *
+    rows]``, a query a sublane) -> ``[rows, 1]``: the largest T with
+    count(key >= T) >= k, built from the sign down (two's complement: from
+    the least int32, adding a bit moves T up). The queries go through the 32
+    passes together: a pass is one compare a vector register and ONE sum
+    across lanes for all of them, which is what a pass waits for."""
+    def bit(i, t):
+        cand = t + (jnp.int32(1) << (31 - i))
+        ones = [jnp.where(key_ref[j:j + rows] >= cand, 1.0, 0.0)
+                for j in range(0, key_ref.shape[0], rows)]
+        while len(ones) > 1:        # pairwise: no chain as long as the row
+            ones = [a + b for a, b in zip(ones[::2], ones[1::2])] \
+                + ones[len(ones) & ~1:]
+        enough = jnp.sum(ones[0], axis=1, keepdims=True) >= jnp.float32(k)
+        return jnp.where(enough, cand, t)
 
-    def mm(a, b, dims):
-        return jax.lax.dot_general(a, b, (dims, ((), ())),
-                                   preferred_element_type=jnp.float32)
+    return jax.lax.fori_loop(0, 32, bit,
+                             jnp.full((rows, 1), -2 ** 31, jnp.int32))
 
-    upper = jnp.where(iota((_LANES, _LANES), 0) <= iota((_LANES, _LANES), 1),
-                      1.0, 0.0).astype(jnp.bfloat16)
-    before = jnp.where(iota((nb, nb), 1) < iota((nb, nb), 0), 1.0,
+
+def _select(key, k, t):
+    """One query's ``k`` best keys, from the head of its row as ordered keys
+    ``key [nb, 128]`` int32 (``-inf``'s key: not allowed) -> ``[1, k]``
+    int32. ``t [1, 128]``: the k-th largest key (:func:`_kth`), None where
+    the caller knows that no more than ``k`` are allowed: all are kept."""
+    nb = key.shape[0]
+    live = key > _NO_KEY
+    square = (_LANES, _LANES)
+    upper = jnp.where(_iota(square, 0) <= _iota(square, 1), 1.0,
+                      0.0).astype(jnp.bfloat16)
+    before = jnp.where(_iota((nb, nb), 1) < _iota((nb, nb), 0), 1.0,
                        0.0).astype(jnp.bfloat16)
 
     def prefix(mask):
@@ -224,67 +243,161 @@ def _select_kernel(s_ref, o_ref, *, k):
         blocks before it). Prefix sums as products with triangles of ones:
         small whole numbers, exact in bfloat16 operands and float32 sums."""
         m = jnp.where(mask, 1.0, 0.0)                           # [nb, 128]
-        rank = mm(m.astype(jnp.bfloat16), upper, ((1,), (0,))) * m
+        rank = _mm(m.astype(jnp.bfloat16), upper, ((1,), (0,))) * m
         per_block = jnp.sum(m, axis=1, keepdims=True)           # [nb, 1]
-        start = mm(before, jnp.broadcast_to(per_block, (nb, _LANES))
-                   .astype(jnp.bfloat16), ((1,), (0,)))[:, 0:1]
+        start = _mm(before, jnp.broadcast_to(per_block, (nb, _LANES))
+                    .astype(jnp.bfloat16), ((1,), (0,)))[:, 0:1]
         return rank, per_block, start
 
-    # Every key above the k-th score, and of those AT it (ties: several
-    # heads' ReLUs all shut give exactly 0) the first in key order that fill
-    # the k, as a stable top-k does.
-    above = (key > t) & live
-    tie_rank, _, tie_start = prefix((key == t) & live)
-    kept = above | ((tie_rank > 0)
-                    & (tie_rank + tie_start <= kf - _count(above)))
+    if t is None:
+        kept = live
+    else:
+        # Every key above the k-th score, and of those AT it (ties: several
+        # heads' ReLUs all shut give exactly 0) the first in key order that
+        # fill the k, as a stable top-k does.
+        above = (key > t) & live
+        n_above = jnp.sum(jnp.sum(jnp.where(above, 1.0, 0.0), axis=0,
+                                  keepdims=True), axis=1, keepdims=True)
+        tie_rank, _, tie_start = prefix((key == t) & live)
+        kept = above | ((tie_rank > 0)
+                        & (tie_rank + tie_start <= jnp.float32(k) - n_above))
     rank, per_block, start = prefix(kept)
     # Output slot p (on the lanes) takes the (p - start + 1)-th kept key of
     # the block whose [start, start + per_block) holds p.
-    p = iota((1, k), 1).astype(jnp.float32)
+    p = _iota((1, k), 1).astype(jnp.float32)
     mine = (start <= p) & (p < start + per_block)               # [nb, k]
-    block = jnp.sum(jnp.where(mine, iota((nb, k), 0).astype(jnp.float32),
+    block = jnp.sum(jnp.where(mine, _iota((nb, k), 0).astype(jnp.float32),
                               0.0), axis=0, keepdims=True)
     want = p - jnp.sum(jnp.where(mine, start, 0.0), axis=0,
                        keepdims=True) + 1.0                     # [1, k]
-    eye = jnp.where(iota((_LANES, _LANES), 0) == iota((_LANES, _LANES), 1),
-                    1.0, 0.0).astype(jnp.bfloat16)
-    rank_t = mm(eye, rank.astype(jnp.bfloat16), ((1,), (1,)))   # [128, nb]
-    ranks = mm(rank_t.astype(jnp.bfloat16),
-               jnp.where(mine, 1.0, 0.0).astype(jnp.bfloat16),
-               ((1,), (0,)))                                    # [128, k]
+    eye = jnp.where(_iota(square, 0) == _iota(square, 1), 1.0,
+                    0.0).astype(jnp.bfloat16)
+    rank_t = _mm(eye, rank.astype(jnp.bfloat16), ((1,), (1,)))  # [128, nb]
+    ranks = _mm(rank_t.astype(jnp.bfloat16),
+                jnp.where(mine, 1.0, 0.0).astype(jnp.bfloat16),
+                ((1,), (0,)))                                   # [128, k]
     lane = jnp.sum(jnp.where(ranks == want,
-                             iota((_LANES, k), 0).astype(jnp.float32), 0.0),
+                             _iota((_LANES, k), 0).astype(jnp.float32), 0.0),
                    axis=0, keepdims=True)
     n_kept = jnp.sum(per_block, axis=0, keepdims=True)          # [1, 1]
     idx = (block * _LANES + lane).astype(jnp.int32)
-    o_ref[0] = jnp.where(p < n_kept, idx, -1)
+    return jnp.where(p < n_kept, idx, -1)
 
 
-def index_select(scores, k, *, interpret=None):
+def _select_heads(nb, k):
+    """The heads of a row of ``nb`` blocks that :func:`_select_kernel` has a
+    body for -> (the one that holds ``k`` keys: queries with no more keep
+    them all; the ones that queries with more are ranked over: whole slabs
+    of ``_SELECT_SLAB`` blocks, and the whole row)."""
+    few = min(nb, -(-k // (8 * _LANES)) * 8)
+    return few, [e for e in range(_SELECT_SLAB, nb, _SELECT_SLAB)
+                 if e * _LANES > k] + [nb]
+
+
+def select_blocks(live, S, k):
+    """-> (the blocks of 128 keys :func:`index_select` ranks for each of the
+    queries that see ``live`` (any shape, in the call's order) of ``S``
+    keys, as many entries: the head of the tile of queries that the query is
+    in; the blocks of the whole row, which it ranked before it was told).
+    Host arithmetic, numpy; a last block of ``S`` that is not whole counts
+    as one."""
+    whole = -(-S // _LANES)
+    few, heads = _select_heads(whole, k)
+    live = np.minimum(np.asarray(live).reshape(-1), S)
+    most = np.pad(live, (0, -live.size % _SELECT_ROWS)).reshape(
+        -1, _SELECT_ROWS).max(axis=1)
+    ranked = np.asarray(heads)[np.searchsorted(heads, -(-most // _LANES))]
+    return np.repeat(np.where(most <= k, few, ranked),
+                     _SELECT_ROWS)[:live.size], whole
+
+
+def _select_kernel(n_ref, n_rows_ref, s_ref, o_ref, key_ref, t_ref, *, k):
+    rows, S = s_ref.shape
+    first = pl.program_id(0) * rows
+    most = n_ref[first]
+    for r in range(1, rows):
+        most = jnp.maximum(most, n_ref[first + r])
+    blocks = (most + _LANES - 1) // _LANES
+    few, heads = _select_heads(S // _LANES, k)
+
+    def select(head, ranked):
+        # The head's keys in order, a vector register a block of 128 keys
+        # (the tile's queries on its sublanes), and -inf's where a query
+        # sees no key: what the passes of _kth count in. A query's own
+        # blocks are every ``rows``-th sublane of that.
+        for j in range(head):
+            at = j * _LANES + _iota((rows, _LANES), 1)
+            key_ref[j * rows:(j + 1) * rows] = _ordered(jnp.where(
+                at < n_rows_ref[...], s_ref[:, j * _LANES:(j + 1) * _LANES],
+                -jnp.inf))
+        if ranked:
+            t_ref[...] = jnp.broadcast_to(
+                _kth(key_ref.at[:head * rows], rows, k), t_ref.shape)
+
+        def one(r, _):
+            t = t_ref[pl.ds(r, 1), :] if ranked else None
+            o_ref[r] = _select(key_ref[pl.ds(r, head, stride=rows), :], k, t)
+        jax.lax.fori_loop(0, rows, one, None)
+
+    # ONE of these bodies runs: the smallest head that holds the live keys
+    # of every query of the tile. The keys past it are -inf's, below every
+    # T that k live keys reach, so the count needs none of them.
+    @pl.when(most <= k)
+    def _all():
+        select(few, False)
+
+    lo = 0
+    for head in heads:
+        @pl.when((most > k) & (blocks > lo) & (blocks <= head))
+        def _top(head=head):
+            select(head, True)
+        lo = head
+
+
+def index_select(scores, k, live=None, *, interpret=None):
     """``scores [B, Q, S]`` float32 (``-inf`` = not allowed) -> the ``k``
     best keys of every query ``[B, Q, k]`` int32 in key order, ``-1`` behind
-    them where fewer than ``k`` are allowed."""
+    them where fewer than ``k`` are allowed. ``live [B, Q]``: only a query's
+    first ``live`` keys are allowed (None: all ``S``), and the kernel's work
+    follows them: a tile of ``_SELECT_ROWS`` queries is counted, ranked and
+    compacted over the head of its rows that holds their live keys (whole
+    slabs of ``_SELECT_SLAB`` blocks of 128 keys), not over ``S``."""
     B, Q, S = scores.shape
     if S % _LANES or k > S:
         raise ValueError(f"index_select needs {_LANES} | S and k <= S, got "
                          f"S {S}, k {k}")
-    nb = S // _LANES
+    nb, rows = S // _LANES, _SELECT_ROWS
+    live = (jnp.full((B * Q,), S, jnp.int32) if live is None else
+            jnp.clip(live.astype(jnp.int32), 0, S).reshape(-1))
+    scores = scores.reshape(B * Q, S)
+    pad = -(B * Q) % rows
+    if pad:             # whole tiles of queries: the others see no key
+        live = jnp.pad(live, (0, pad))
+        scores = jnp.pad(scores, ((0, pad), (0, 0)))
+    N = B * Q + pad
     out = pl.pallas_call(
         functools.partial(_select_kernel, k=k),
-        grid=(B * Q,),
-        in_specs=[pl.BlockSpec((1, nb, _LANES), lambda n: (n, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1, k), lambda n: (n, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Q, 1, k), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N // rows,),
+            in_specs=[
+                pl.BlockSpec((rows, 1), lambda i, live: (i, 0)),
+                pl.BlockSpec((rows, S), lambda i, live: (i, 0)),
+            ],
+            out_specs=pl.BlockSpec((rows, 1, k), lambda i, live: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((nb * rows, _LANES), jnp.int32),
+                            pltpu.VMEM((rows, _LANES), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((N, 1, k), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
-            flops=B * Q * (2 * _LANES * nb * k + 40 * S),
-            transcendentals=0, bytes_accessed=B * Q * (S + k) * 4),
+            flops=N * (2 * _LANES * nb * k + 40 * S),
+            transcendentals=0, bytes_accessed=N * (S + k) * 4),
         name=SELECT_NAME,
         interpret=_interpret() if interpret is None else interpret,
-    )(scores.reshape(B * Q, nb, _LANES))
-    return out.reshape(B, Q, k)
+    )(live, live[:, None], scores)
+    return out[:B * Q].reshape(B, Q, k)
 
 
 # ---- sparse_latent_attention -------------------------------------------------

@@ -455,7 +455,8 @@ def _latent_layer(a, q, row, index, q_heads, wkv_b, rows_c, keys_c, *,
     if kernels:
         scores = pallas_latent.index_scores(index["q"], index["w"], keys,
                                             q_pos)
-        selected = pallas_latent.index_select(scores, a.index_topk)
+        selected = pallas_latent.index_select(scores, a.index_topk,
+                                              q_pos + 1)
     else:
         scores = tfm.index_scores(index["q"], index["w"], keys,
                                   tfm.attend_allowed(a, q_pos, k_pos))
@@ -493,18 +494,24 @@ def _latent_layer(a, q, row, index, q_heads, wkv_b, rows_c, keys_c, *,
     return rows_c, keys_c, values(o), selected
 
 
-def _latent_work(a, live, kernels):
+def _latent_work(a, live, kernels, max_kv):
     """What ONE latent layer of kind ``a`` does in a call whose queries see
     ``live [slots, queries]`` keys each (their positions + 1; a slot's
     queries are consecutive), for ``serve_stats()["attn"]``: the (query,
-    key) pairs a selecting layer scores and then attends over, those a
-    window layer attends over; for a layer that attends its whole context
-    the rows it has to read (a slot's live rows once), its pairs, and
-    whether its kernel took the expanded form."""
+    key) pairs a selecting layer scores and then attends over, and the
+    blocks of 128 keys its top-k ranks beside those of ``max_kv`` keys a
+    query (``pallas_latent.select_blocks``: what following the live context
+    saves); the pairs a window layer attends over; for a layer that attends
+    its whole context the rows it has to read (a slot's live rows once), its
+    pairs, and whether its kernel took the expanded form."""
     found = {}
     if a.index_topk:
+        ranked, whole = pallas_latent.select_blocks(live, max_kv,
+                                                    a.index_topk)
         found.update(kv_scored=live.sum(),
-                     kv_selected=np.minimum(live, a.index_topk).sum())
+                     kv_selected=np.minimum(live, a.index_topk).sum(),
+                     select_blocks_live=ranked.sum(),
+                     select_blocks_all=live.size * whole)
     if a.window:
         found.update(kv_window=np.minimum(live, a.window).sum())
     if not (a.index_topk or a.window):
@@ -760,7 +767,7 @@ def work(cfg, geo, mesh):
             return _scan_work(a, live, itemsize)
         if isinstance(a, tfm.MultiHeadAttention):
             return _grouped_work(a, live, itemsize)
-        return _latent_work(a, live, latent)
+        return _latent_work(a, live, latent, geo.max_kv)
 
     def count(live, ends=None):
         if not families:

@@ -484,6 +484,9 @@ class ServeLoop:
                 exported[name].labels(program=kind).inc(int(n))
         if family == "attn" and _metrics.enabled():
             _metrics.SERVE_KV_SELECT_SHARE.set(self._kv_select_share())
+            if "select_blocks_all" in found:
+                _metrics.SERVE_SELECT_BLOCKS_SHARE.set(
+                    self._select_blocks_share())
 
     def _count(self, kind, live, ends=None):
         """One program call whose queries see ``live [slots, queries]`` keys
@@ -498,6 +501,12 @@ class ServeLoop:
         scored = sum(attn["kv_scored"].values())
         return (sum(attn["kv_selected"].values()) / scored
                 if scored else 0.0)
+
+    def _select_blocks_share(self):
+        attn = self.tally["attn"]
+        every = sum(attn["select_blocks_all"].values())
+        return (sum(attn["select_blocks_live"].values()) / every
+                if every else 0.0)
 
     def warmup(self):
         """Compile every engine jit outside any measured window. Every
@@ -1121,6 +1130,9 @@ class ServeLoop:
                             for name, by_kind in counters.items()}
         if "attn" in snap:
             snap["attn"]["kv_select_share"] = self._kv_select_share()
+            if "select_blocks_all" in snap["attn"]:
+                snap["attn"]["select_blocks_share"] = \
+                    self._select_blocks_share()
         if "moe" in snap:
             ms, load = self.tally["moe"], self._moe_load
             steps = ms["calls"].get("decode", 0) * len(self.cfg.moe_layers)

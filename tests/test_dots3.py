@@ -198,6 +198,10 @@ def test_preemption_and_refill_keep_the_tokens():
     assert stats["calls"]["chunk"] > 4 and stats["calls"]["decode"] > 0
     assert 0 < stats["kv_select_share"] <= 1
     assert stats["kv_selected"]["decode"] <= stats["kv_scored"]["decode"]
+    for kind in ("chunk", "decode"):
+        assert 0 < stats["select_blocks_live"][kind] \
+            <= stats["select_blocks_all"][kind]
+    assert 0 < stats["select_blocks_share"] <= 1
     assert stats["kv_window"]["decode"] > 0
     assert len(finished) == 2
     for req in finished:

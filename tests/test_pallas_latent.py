@@ -43,26 +43,115 @@ def test_index_scores(q_len, pos0):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("k, live", [(24, [256, 100, 30, 24, 7, 1]),
-                                     (128, [256, 129, 128, 127, 5, 1])])
-def test_index_select_is_the_top_k(k, live):
+def _rows(live, S, seed=3):
+    """A row of scores a query that sees ``live[row]`` keys, ``-inf`` past
+    them; every other row with ties everywhere (scores rounded to whole
+    numbers: a stable top-k keeps the lower key indices at the k-th
+    score)."""
+    scores = np.asarray(_normal(seed, (1, len(live), S))).copy()
+    for row, n in enumerate(live):
+        scores[0, row, n:] = -np.inf
+    scores[0, ::2] = np.round(scores[0, ::2])
+    return scores
+
+
+def _is_the_top_k(got, scores, k, live):
+    """The same SET as ``lax.top_k``, indices in key order, ``-1`` behind
+    the kept ones."""
+    want = np.sort(np.asarray(tfm.select_keys(jnp.asarray(scores), k)), -1)
+    assert np.array_equal(np.sort(got, -1), want)
+    for row, n in enumerate(live):
+        kept = got[0, row, :min(n, k)]
+        assert (np.diff(kept) > 0).all() and (got[0, row, min(n, k):] == -1
+                                              ).all()
+
+
+# The last seven: S 1024 in slabs of 2 blocks, k 256: a tile of queries is
+# ranked over a head of 4, 6 or 8 blocks, or keeps every live key of the 8,
+# with the queries' live keys handed over.
+@pytest.mark.parametrize("k, S, live, hand_over", [
+    pytest.param(24, 256, [256, 100, 30, 24, 7, 1], False, id="24"),
+    pytest.param(128, 256, [256, 129, 128, 127, 5, 1], False, id="128"),
+    pytest.param(256, 1024, [7, 100, 255, 1, 130, 200, 64, 256], True,
+                 id="live-under-k"),
+    pytest.param(256, 1024, [256] * 8, True, id="live-k"),
+    pytest.param(256, 1024, [513, 512, 511, 300, 257, 400, 385, 500], True,
+                 id="live-a-key-past-a-slab's-edge"),
+    pytest.param(256, 1024, [896, 895, 897, 800, 769, 770, 850, 890], True,
+                 id="live-a-block-short-of-S"),
+    pytest.param(256, 1024, [1024] * 8, True, id="live-S"),
+    pytest.param(256, 1024, [1024, 3, 256, 257, 0, 513, 896, 40] * 2, True,
+                 id="live-differs-by-row"),
+    pytest.param(256, 1024, [600, 0, 1024], True, id="live-a-tile-not-whole"),
+])
+def test_index_select_is_the_top_k(k, S, live, hand_over, monkeypatch):
     """The same SET as ``lax.top_k`` for every number of live keys around
-    ``k``, ``-1`` behind the kept ones, indices in key order."""
-    S = 256
+    ``k``, ``-1`` behind the kept ones, indices in key order. With the
+    queries' live keys handed over (rows of ONE call that differ: the decode
+    step's shape) the kernel ranks the head of the row that holds them and
+    gives the same bits as over the whole row: what lies past a query's live
+    keys (finite junk here: the kernel masks it, as ``index_scores`` does)
+    is not a score, and neither is the rest of its tile of queries' head."""
+    monkeypatch.setattr(pk, "_SELECT_SLAB", 2)
     scores = np.asarray(_normal(3, (1, len(live), S))).copy()
     for row, n in enumerate(live):
         scores[0, row, n:] = -np.inf
     # Half the rows with ties everywhere (scores rounded to whole numbers:
     # a stable top-k keeps the lower key indices at the k-th score).
     scores[0, ::2] = np.round(scores[0, ::2])
-    scores = jnp.asarray(scores)
-    want = np.sort(np.asarray(tfm.select_keys(scores, k)), -1)
-    got = np.asarray(pk.index_select(scores, k))
+    want = np.sort(np.asarray(tfm.select_keys(jnp.asarray(scores), k)), -1)
+    got = np.asarray(pk.index_select(jnp.asarray(scores), k))
     assert np.array_equal(np.sort(got, -1), want)
     for row, n in enumerate(live):
         kept = got[0, row, :min(n, k)]
         assert (np.diff(kept) > 0).all() and (got[0, row, min(n, k):] == -1
                                               ).all()
+    if hand_over:
+        junk = np.where(np.isfinite(scores), scores,
+                        1e3 + np.asarray(_normal(4, scores.shape)))
+        n = jnp.asarray(live, jnp.int32)[None]
+        for given in (scores, junk):
+            assert np.array_equal(got, np.asarray(pk.index_select(
+                jnp.asarray(given), k, n)))
+
+
+def test_index_select_ties_across_a_slabs_edge(monkeypatch):
+    """Ties at the k-th score that straddle a slab's edge (keys 256 and 512
+    at slabs of 2 blocks) or end at it: the lower key indices fill the k,
+    as a stable sort does (every head's ReLU shut: a run of zeros; keys
+    below them in front push the run along, keys above them take places)."""
+    monkeypatch.setattr(pk, "_SELECT_SLAB", 2)
+    S, k = 1024, 256
+    rows = [(0, 0, 1024), (1, 0, 600), (1, 10, 700), (130, 3, 520),
+            (200, 0, 1024), (256, 0, 513), (257, 0, 1024), (300, 7, 900)]
+    scores = np.full((1, len(rows), S), -np.inf, np.float32)
+    for row, (below, above, n) in enumerate(rows):
+        scores[0, row, :n] = 0.0
+        scores[0, row, :below] = -1.0
+        scores[0, row, n - above:n] = 1.0
+    want = np.sort(np.argsort(-scores, axis=-1, kind="stable")[..., :k], -1)
+    live = jnp.asarray([n for _, _, n in rows], jnp.int32)[None]
+    for n in (None, live):
+        got = np.asarray(pk.index_select(jnp.asarray(scores), k, n))
+        assert np.array_equal(got, want)
+    last = [below + k - above - 1 for below, above, _ in rows]
+    assert {255, 256, 512} <= set(last) and max(last) > 512
+
+
+def test_select_blocks_counts_the_heads():
+    """``select_blocks``: the blocks the kernel ranks for a query, by the
+    tile of eight queries it is in: the ``k`` keys' blocks where no query of
+    the tile sees more, else whole slabs up to the one that holds the
+    tile's longest context."""
+    S, k = 32768, 2048
+    live = np.r_[np.arange(1, 9), np.full(8, 2048), 2049, np.full(7, 5),
+                 np.full(8, 4096), 4097, np.full(7, 100), np.full(8, S),
+                 np.full(3, 12289)]
+    want = np.r_[np.full(16, 16), np.full(16, 32), np.full(8, 64),
+                 np.full(8, 256), np.full(3, 128)]
+    ranked, whole = pk.select_blocks(live, S, k)
+    assert np.array_equal(ranked, want) and whole == 256
+    assert pk.select_blocks(np.zeros((0, 1)), S, k)[0].size == 0
 
 
 def test_sparse_latent_attention():

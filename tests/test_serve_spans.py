@@ -482,6 +482,16 @@ def test_latent_layers_keep_their_kernels_names(monkeypatch):
         min(t + 1, 17) for n in prompts for t in range(n))
     assert attn["calls"]["chunk"] == sum(-(-n // 112) for n in prompts)
     assert 0 < attn["kv_select_share"] < 1
+    # The blocks of 128 keys the top-k ranked (the head of the row that a
+    # tile of queries' live keys lie in) beside a slot's eight.
+    assert (0 < attn["select_blocks_live"]["chunk"]
+            <= attn["select_blocks_all"]["chunk"]
+            == 8 * attn["queries"]["chunk"])
+    assert 0 < attn["select_blocks_share"] <= 1
+    from horovod_tpu.observability import metrics
+    gauge = metrics.SERVE_SELECT_BLOCKS_SHARE
+    assert gauge in metrics.REGISTRY.metrics() and (gauge.name, gauge.kind) \
+        == ("hvd_serve_select_blocks_share", "gauge")
 
 
 def test_described_multihead_layers_keep_their_kernels_names(monkeypatch):

@@ -45,8 +45,10 @@ NO_GROUPED = dict(kv_full_rows=0, kv_window_rows=0, kv_window_rows_as_full=0,
 WANT = {
     "latent-select": {
         "chunk": {"attn": dict(BASE, kv_scored=300, kv_selected=192,
+                               select_blocks_live=24, select_blocks_all=24,
                                queries=8)},
         "decode": {"attn": dict(BASE, kv_scored=78, kv_selected=39,
+                                select_blocks_live=9, select_blocks_all=9,
                                 queries=3)}},
     "latent-window": {
         "chunk": {"attn": dict(BASE, kv_window=120, queries=8)},

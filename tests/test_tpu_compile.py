@@ -560,6 +560,7 @@ def test_layered_cell_programs_fit_one_chip(topo, as_on_the_chip):
             ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
              slots(B))):
         compiled = fn.lower(params, cache, *args).compile()
+        rows = args[0].shape[0] * (args[0].shape[1] if name == "chunk" else 1)
         memory = compiled.memory_analysis()
         assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
         fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
@@ -572,6 +573,16 @@ def test_layered_cell_programs_fit_one_chip(topo, as_on_the_chip):
                      if re.match(rf"\s*%{kernel}[.\d]* = ", line)
                      and "tpu_custom_call" in line]
             assert len(calls) == n, (name, kernel)
+            if kernel == "index_select":
+                # The top-k is told the queries' live keys: one number a
+                # query for the kernel's scalar unit, ahead of the same as
+                # a column and of the scores as they are (no copy in
+                # blocks of 128).
+                for call in calls:
+                    assert (f"operand_layout_constraints={{s32[{rows}]{{0}}, "
+                            f"s32[{rows},1]{{1,0}}, "
+                            f"f32[{rows},{geo.max_kv}]{{1,0}}}}"
+                            in call), call[:300]
         assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
             == 3 * len(cfg.moe_layers)
         # The held experts' products: a chunk's in blocks whose rows the
@@ -581,7 +592,6 @@ def test_layered_cell_programs_fit_one_chip(topo, as_on_the_chip):
             == {"256" if name == "chunk" else "128"}
         # Nothing float32 of the per-head score block's size, and no sort
         # as long as a row of scores.
-        rows = args[0].shape[0] * (args[0].shape[1] if name == "chunk" else 1)
         block = rows * 64 * geo.max_kv
         for m in re.finditer(r" = f32\[([\d,]+)\]", text):
             assert int(np.prod([int(d) for d in m.group(1).split(",")])) \
