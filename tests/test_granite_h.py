@@ -6,67 +6,121 @@ cold fill gives and what the reference gives; rows and pages are conserved;
 faults planted in the program fail."""
 
 import dataclasses
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.runners import serve_lm, serve_share
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import pallas_ssm
 from horovod_tpu.serving import engine, kv_cache
 from horovod_tpu.serving.loop import ServeLoop, serve_stats
 from horovod_tpu.serving.scheduler import Request
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from . import served
+
+NAME = "granite-4.0-h-micro"
+serve_share = served.runner(NAME)
 PAGE, CHUNK = 8, 16
-
-
-def tiny_config():
-    """The published file with every size shrunk (widths kept in their
-    ratios: d_inner = 2 x hidden, query heads 2 x key/value heads)."""
-    with open(os.path.join(
-            ROOT, "benchmark/configs/granite-4.0-h-micro.json")) as f:
-        config = json.load(f)
-    config.update(
-        hidden_size=64, intermediate_size=96, shared_intermediate_size=96,
-        num_attention_heads=4, num_key_value_heads=2, attention_head_dim=16,
-        attention_multiplier=0.1, vocab_size=128, num_hidden_layers=4,
-        layer_types=["mamba", "mamba", "attention", "mamba"],
-        mamba_n_heads=16, mamba_d_head=8, mamba_d_state=16,
-        mamba_chunk_size=8, max_position_embeddings=4096)
-    config["model"] = dict(config["model"], dtype="float32",
-                           param_dtype="float32")
-    return config
+rel, tokens = served.ENTRIES[NAME].rel, served.ENTRIES[NAME].tokens
 
 
 @pytest.fixture(scope="module")
 def model():
-    config = tiny_config()
-    cfg = serve_share.model_config(config)
-    params = serve_share.make_params(cfg, jax.random.PRNGKey(7))
-    reference = serve_lm.load_reference(config)
+    reference = served.reference(NAME)
+    config, cfg, params = served.tiny(NAME)
     return config, cfg, params, reference, reference.hyper(config)
 
 
-def rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
+class TestContract(served.Contract):
+    name = NAME
 
 
-def tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 128, n).tolist()
+class TestCellPrograms(served.CellPrograms):
+    """``granite-serve-agent-share-over``'s programs at the cell's geometry
+    (33 state rows and the pool of snapshot rows behind them), on ONE period
+    of the model's ten layers (nine Mamba-2 layers of 64 heads in one group,
+    one attention layer of 32 query heads over 8 key/value heads of 64; the
+    cell runs four such periods): the chip's compiler takes both kernels at
+    the new shapes (``ssm_decode_update`` as four packs of 16 heads,
+    ``paged_full_attention`` with two 64-wide heads a lane tile), the decode
+    step passes over a layer's state once and touches no snapshot row's worth
+    of temporaries, the fill's two programs cut the head and run the
+    recurrence as ONE ``ssm_chunk_scan`` a Mamba-2 layer with no
+    ``f32[1,2,64,256,256]`` decay tensor (PR 58), the page-wide tail program
+    stays the blocked form's, and the state copy is in place."""
+    name = NAME
+
+    def also_built(self, found):
+        from jax.sharding import Mesh
+
+        cfg, geo, c = found.cfg, found.geo, found.cell
+        chunk, page = c.chunk, geo.page_size
+        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+        found.gates_by_window = {
+            q: engine._kernels(cfg, geo, None, q) for q in (1, chunk, page)}
+        found.gates_by_window["mesh"] = engine._kernels(cfg, geo, mesh, chunk)
+        found.gate_with_no_state_layer = engine._kernels(
+            served.cell("gpt2-large").cfg, geo, None, chunk)["state"]
+        scalar = found.on_chip((), jnp.int32)
+        copy = engine.make_state_copy(cfg, geo, "state_snapshot").lower(
+            found.cache, scalar, scalar)
+        compiled = copy.compile()
+        found.programs["copy"] = served.Compiled(
+            copy, compiled, compiled.as_text(), compiled.memory_analysis(), [])
+        with pytest.MonkeyPatch.context() as closed:
+            closed.setattr(engine, "state_kernels", lambda *a: False)
+            found.blocked = engine.make_chunk_step(
+                cfg, geo, q_len=chunk, head="none").lower(
+                found.params, found.cache, *served.slots(
+                    geo, 1, chunk, like=found.on_chip)).compile().as_text()
+
+    def also_cell(self, built):
+        srv = built.cell.config["assumed"]["serve"]
+        B, chunk, rows = srv["max_batch"], srv["chunk"], srv["snapshot_rows"]
+        on = {"latent": False, "grouped": True, "state": True, "linear": False}
+        # A window that is no whole block (the page-wide tail), a mesh, a
+        # model with no state-space layer: the blocked form.
+        off = dict(on, state=False)
+        assert built.gates_by_window == {
+            1: on, chunk: on, srv["page_size"]: off,
+            "mesh": dict(off, grouped=False)}
+        assert not built.gate_with_no_state_layer
+        # The whole model and its cache, by shape: what the serve block's why
+        # says.
+        whole, full_geo = built.cell.cfg, built.cell.geo
+        n_params = sum(x.size for x in jax.tree.leaves(jax.eval_shape(
+            lambda: tfm.init_params(jax.random.PRNGKey(0), whole))))
+        assert 3.18e9 < n_params < 3.20e9
+        row = 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
+        assert kv_cache.cache_bytes(whole, full_geo) == (
+            (B + 1 + rows) * row + 4 * 2 * srv["n_pages"] * 16 * 512 * 2)
+        assert 2 * n_params + kv_cache.cache_bytes(whole, full_geo) < 14.2e9
+        # With the gate closed the chunk program is the blocked form's.
+        assert not served.kernel_calls(built.blocked, "ssm_chunk_scan")
+        assert "f32[2,64,256,256]" in built.blocked
+        self.also_program(built, "copy", built.programs["copy"])
+        copy = built.programs["copy"]
+        assert copy.memory.alias_size_in_bytes >= kv_cache.cache_bytes(
+            built.cfg, built.geo)
+        for kernel in ("paged_full_attention", "ssm_decode_update",
+                       "ssm_chunk_scan"):
+            assert not served.kernel_calls(copy.text, kernel)
+
+    def also_program(self, built, program, p):
+        srv = built.cell.config["assumed"]["serve"]
+        layer_state = 4 * (srv["max_batch"] + 1 + srv["snapshot_rows"]) \
+            * 64 * 64 * 128
+        # no copy of a layer's rows, and no float32 logits of 512 positions
+        assert p.memory.temp_size_in_bytes + p.fresh < layer_state, program
+        # the blocked form's decays (its compiled text drops the leading 1)
+        assert "f32[1,2,64,256,256]" not in p.text, program
+        assert "f32[2,64,256,256]" not in p.text, program
 
 
 def test_the_file_describes_the_published_model():
-    with open(os.path.join(
-            ROOT, "benchmark/configs/granite-4.0-h-micro.json")) as f:
-        config = json.load(f)
-    cfg = serve_share.model_config(config)
+    cfg = serve_share.model_config(served.file_config(NAME))
     kinds = [type(cfg.attn_of(li)).__name__ for li in range(cfg.n_layers)]
     assert [i for i, k in enumerate(kinds) if k == "MultiHeadAttention"] \
         == [5, 15, 25, 35]
@@ -78,13 +132,6 @@ def test_the_file_describes_the_published_model():
     a = cfg.attn_of(5)
     assert (a.query_mult, a.rope_dim) == (0.015625 * 8, 0)
     assert (cfg.embed_mult, cfg.residual_mult, cfg.logits_div) == (12, 0.22, 8)
-
-
-def test_forward_gives_the_reference_on_every_position(model):
-    _, cfg, params, reference, hp = model
-    t = np.asarray([tokens(45)], np.int32)
-    want = reference.logits(reference.from_horovod_tpu(params), t, hp)
-    assert rel(tfm.forward(params, t, cfg), want) < 2e-5
 
 
 def four_groups(cfg, params):
@@ -158,11 +205,10 @@ def test_the_references_faults_move_the_logits(model, fault):
     assert rel(same, want) == 0.0
 
 
-def make_loop(model, rows, **kw):
-    _, cfg, params, _, _ = model
-    geo = kv_cache.geometry(kw.pop("n_pages", 96), PAGE, 256)
-    return ServeLoop(params, cfg, geo=geo, max_batch=kw.pop("max_batch", 2),
-                     prefill_chunk=CHUNK, snapshot_rows=rows, **kw)
+def make_loop(rows, **kw):
+    """A loop with a prefix cache that holds state remembers what it served:
+    only one without snapshot rows is shared between cases."""
+    return served.loop(NAME, fresh=rows > 0, snapshot_rows=rows, **kw)
 
 
 def serve(loop, prompt, rid=0, new=4):
@@ -192,7 +238,7 @@ def conserved(loop):
 
 
 def test_chunks_and_decode_through_dirty_rows_give_the_reference(model):
-    loop = make_loop(model, 0, fill_head="last")
+    loop = make_loop(0, fill_head="last")
     assert loop.prefix is None and loop.chunk_end_fn is not None
     serve(loop, tokens(60, 9))                     # leaves slot 0 dirty
     got, req = serve(loop, tokens(53, 1), rid=1)
@@ -203,11 +249,11 @@ def test_chunks_and_decode_through_dirty_rows_give_the_reference(model):
 def test_a_hit_gives_what_cold_gives_and_the_reference(model):
     a = tokens(70, 2)
     b = a[:64] + tokens(30, 3)         # A's whole pages, then a new tail
-    cold = make_loop(model, 0, fill_head="last")
+    cold = make_loop(0, fill_head="last")
     want_a, _ = serve(cold, a)
     want_b, cold_b = serve(cold, b, rid=1)
     assert cold_b.cached_tokens == 0
-    loop = make_loop(model, 4, fill_head="last")
+    loop = make_loop(4, fill_head="last")
     got_a, _ = serve(loop, a)
     conserved(loop)
     got_b, hit_b = serve(loop, b, rid=1)
@@ -220,7 +266,7 @@ def test_a_hit_gives_what_cold_gives_and_the_reference(model):
 
 
 def test_a_session_of_three_turns_and_two_sessions_on_one_prefix(model):
-    loop = make_loop(model, 6, fill_head="last")
+    loop = make_loop(6, fill_head="last")
     prefix = tokens(48, 4)
     said = prefix + tokens(21, 5)
     hits = []
@@ -243,7 +289,7 @@ def test_a_session_of_three_turns_and_two_sessions_on_one_prefix(model):
 
 
 def test_a_match_longer_than_its_deepest_snapshot(model):
-    loop = make_loop(model, 1, fill_head="last")       # ONE row
+    loop = make_loop(1, fill_head="last")       # ONE row
     a = tokens(70, 30)
     serve(loop, a)                                     # row at 64
     serve(loop, tokens(40, 31), rid=1)                 # takes the row
@@ -258,9 +304,8 @@ def test_snapshot_rows_hold_the_float32_state_bit_for_bit(model):
     """What the benchmark's ``state_rel`` cannot see: a state rounded ONCE, at
     the snapshot. The pool's rows are float32 whatever the compute dtype, and
     the two copy programs move a row as it is."""
-    config = tiny_config()
-    config["model"] = dict(config["model"], dtype="bfloat16")
-    low = serve_share.model_config(config)
+    half = dict(served.tiny_config(NAME)[0]["model"], dtype="bfloat16")
+    low = served.tiny_config(NAME, model=half)[1]
     geo = kv_cache.with_rings(kv_cache.geometry(96, PAGE, 256), low, CHUNK,
                               2, snapshot_rows=3)
     for li, (tail, state) in enumerate(zip(*(kv_cache.make_cache(
@@ -268,7 +313,7 @@ def test_snapshot_rows_hold_the_float32_state_bit_for_bit(model):
         if isinstance(low.attn_of(li), tfm.RECURRENT):
             assert state.dtype == jnp.float32 and tail.dtype == jnp.bfloat16
             assert state.shape[0] == tail.shape[0] == 2 + 1 + 3
-    loop = make_loop(model, 3, fill_head="last")
+    loop = make_loop(3, fill_head="last")
     serve(loop, tokens(60, 40))                        # slot 0's rows dirty
     first = loop.geo.state_rows                        # the pool's first row
     cache = loop.snapshot_fn(loop.cache, np.int32(1), np.int32(first + 2))
@@ -284,7 +329,7 @@ def test_snapshot_rows_hold_the_float32_state_bit_for_bit(model):
 
 
 def test_eviction_under_pressure_conserves_rows_and_pages(model):
-    loop = make_loop(model, 3, fill_head="last", n_pages=40, max_batch=2)
+    loop = make_loop(3, fill_head="last", n_pages=40, max_batch=2)
     rng = np.random.default_rng(40)
     prefix = tokens(32, 41)
     reqs = [Request(rid=i, prompt=prefix + tokens(int(rng.integers(20, 90)),
@@ -316,7 +361,7 @@ def test_the_chunk_kernel_gives_what_the_blocked_form_gives(model, monkeypatch,
     against the plain programs themselves."""
     a = tokens(70, 2)
     b = a[:64] + tokens(30, 3)
-    plain = make_loop(model, 4, fill_head="last")
+    plain = make_loop(4, fill_head="last")
     want_a, _ = serve(plain, a)
     want_b, _ = serve(plain, b, rid=1)
     entered = []
@@ -328,7 +373,7 @@ def test_the_chunk_kernel_gives_what_the_blocked_form_gives(model, monkeypatch,
 
     monkeypatch.setattr(engine, "state_kernels", lambda *a: True)
     monkeypatch.setattr(pallas_ssm, "ssm_chunk_scan", counted)
-    loop = make_loop(model, 4, fill_head="last")
+    loop = make_loop(4, fill_head="last")
     got, req = serve(loop, a)
     want = want_a
     if case == "hit":
@@ -383,7 +428,7 @@ def test_faults_planted_in_the_program_fail(model, monkeypatch, fault):
         monkeypatch.setattr(ServeLoop, "_chunk_fill", chunk_fill)
     else:
         monkeypatch.setattr(engine, "make_state_copy", broken)
-    loop = make_loop(model, 4, fill_head="last")
+    loop = make_loop(4, fill_head="last")
     serve(loop, a)
     got, req = serve(loop, b, rid=1)
     assert req.cached_tokens == 64
@@ -394,11 +439,11 @@ def test_snapshot_rows_0_builds_todays_programs(model):
     """No snapshot rows: no prefix for a model with state, the geometry and
     the chunk program's text what they are without the argument."""
     _, cfg, params, _, _ = model
-    loop = make_loop(model, 0)
+    loop = make_loop(0)
     assert loop.prefix is None and not loop.snapshots
     assert loop.geo.snapshot_rows == 0 and loop.chunk_end_fn is None
     geo = kv_cache.with_rings(kv_cache.geometry(96, PAGE, 256), cfg, CHUNK, 2)
     assert geo == loop.geo
-    with_rows = make_loop(model, 3)
+    with_rows = make_loop(3)
     assert with_rows.geo.state_rows == 3 and with_rows.geo.snapshot_rows == 3
     assert with_rows.cache["v"][0].shape[0] == 6

@@ -17,10 +17,8 @@ runs in float32, where program and reference must agree to rounding although
 the one attends through pages and rings and the other over the whole sequence.
 """
 import dataclasses
-import importlib.util
-import json
 import math
-import os
+import re
 
 import numpy as np
 import pytest
@@ -33,89 +31,70 @@ from horovod_tpu.serving import kv_cache
 from horovod_tpu.serving import loop as serve_loop
 from horovod_tpu.serving.scheduler import Request
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from . import served
 
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-reference = _load("benchmark/reference/laguna.py", "laguna_reference")
-layers_runner = _load("benchmark/runners/serve_layers.py",
-                      "serve_layers_runner")
-runner = _load("benchmark/runners/serve_gqa.py", "serve_gqa_runner")
-FILE = json.load(open(os.path.join(ROOT, "benchmark", "configs",
-                                   "laguna-s-2.1.json")))
-PAGE, CHUNK, TOL = 4, 8, 2e-4
-KINDS = ["full_attention"] + ["sliding_attention"] * 3
-
-
-def _config(**overrides):
-    """The configuration file with every size shrunk; YaRN's original
-    length too (16), so that the blend is in play at these positions."""
-    config = dict(FILE)
-    config.update(
-        hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
-        shared_expert_intermediate_size=32, num_attention_heads=4,
-        num_key_value_heads=2, head_dim=16,
-        heads_by_kind={"full_attention": 4, "sliding_attention": 6},
-        num_attention_heads_per_layer=[4, 6, 6, 6] * 12,
-        layer_types=KINDS * 12, num_hidden_layers=5, sliding_window=8,
-        num_experts_published=16, experts_held=[4, 4], num_experts=4,
-        num_experts_per_tok=3, vocab_size=128, max_position_embeddings=256,
-        rope_parameters={
-            "full_attention": dict(
-                FILE["rope_parameters"]["full_attention"], factor=8,
-                original_max_position_embeddings=16, attention_factor=1.2),
-            "sliding_attention": FILE["rope_parameters"][
-                "sliding_attention"]})
-    config.update(overrides)
-    return config
-
-
-def _cfg(config, **overrides):
-    return dataclasses.replace(runner.model_config(config), dtype="float32",
-                               param_dtype="float32", **overrides)
-
-
-def _params(cfg, seed=0):
-    """Seeded weights as the benchmark's runner draws them: norm scales
-    around 1, so that none can be left out unseen."""
-    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
-    rng = np.random.default_rng(seed)
-
-    def jitter(path, x):
-        if getattr(path[-1], "key", None) == "scale":
-            return (1.0 + 0.1 * rng.standard_normal(x.shape)).astype(x.dtype)
-        return x
-
-    return jax.tree_util.tree_map_with_path(jitter, params)
-
-
-def _tokens(n, seed=1):
-    return np.random.default_rng(seed).integers(0, 128, n).tolist()
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / np.abs(want).max())
+NAME = "laguna-s-2.1"
+FILE = served.file_config(NAME)
+runner, reference = served.runner(NAME), served.reference(NAME)
+PAGE, CHUNK = 4, 8
+TOL, _rel, _tokens = (getattr(served.ENTRIES[NAME], k)
+                      for k in ("tol", "rel", "tokens"))
 
 
 def _want(config, params, tokens, last=None, fault=None):
-    hp = reference.hyper(config)
-    return reference.logits(
-        reference.from_horovod_tpu(params), jnp.asarray([tokens], jnp.int32),
-        hp, last=last, with_routes=True, kn=reference.knobs(hp, fault))
+    return served.want(NAME, config, params, tokens, fault=fault, last=last,
+                       with_routes=True)
 
 
-def _loop(cfg, params, max_batch=2, n_pages=64, context=128, **kw):
-    geo = kv_cache.geometry(n_pages, PAGE, context)
-    return serve_loop.ServeLoop(params, cfg, geo=geo, max_batch=max_batch,
-                                prefill_chunk=CHUNK, **kw)
+class TestContract(served.Contract):
+    name = NAME
+
+    def also_served(self, lp, n, rows):
+        assert lp.prefill_fn is None and lp.bprefill_fn is None
+        assert lp.prefix is None                    # rings are not shared
+        assert lp.geo.ring_blocks == 4              # 8 - 1 + 8 positions
+
+    def also_preempted(self, lp, done):
+        """Both emit the REFERENCE's greedy tokens. The counters of the
+        multi-head kinds follow the programs."""
+        config, _, params = served.tiny(NAME)
+        assert lp.batcher.ring_alloc.used_pages() == 0
+        stats = serve_loop.serve_stats()["attn"]
+        assert stats["calls"]["chunk"] > 4 and stats["calls"]["decode"] > 0
+        for kind in ("chunk", "decode"):
+            assert 0 < stats["kv_window_rows"][kind] \
+                <= stats["kv_window_rows_as_full"][kind]
+            # two full layers, three window layers
+            assert stats["kv_window_rows_as_full"][kind] * 2 \
+                == stats["kv_full_rows"][kind] * 3
+            assert stats["qk_full_pairs"][kind] >= stats["kv_full_rows"][kind]
+        assert stats["qk_full_pairs"]["decode"] \
+            == stats["kv_full_rows"]["decode"]
+        for req in done:
+            seq = list(req.prompt) + list(req.generated)
+            theirs = _want(config, params, seq[:-1],
+                           last=len(req.generated))[0]
+            assert np.argmax(theirs[0], -1).tolist() == req.generated
+
+    def also_cache(self, cfg, geo):
+        with pytest.raises(ValueError, match="rings"):
+            kv_cache.layer_shapes(cfg, kv_cache.geometry(64, PAGE, 128), 1)
+
+
+class TestCellPrograms(served.CellPrograms):
+    """``laguna-serve-agent-over``: nine layers of two described multi-head
+    kinds, 32 slots of a 16k context, the window layers' K and V on rings.
+    The chip's compiler takes the grouped paged kernel at the published
+    widths for one query a slot and for a block of 128, and
+    ``paged_decode_attention`` is in neither program (no plain layer)."""
+    name = NAME
+
+    def also_program(self, built, program, p):
+        # The held experts' products: a chunk's in blocks whose rows the
+        # compiler tiles by 256 (``transformer._HELD_BLOCK`` rests on that
+        # rule), a decode step's 320 rows in one product as before.
+        assert set(re.findall(r'ragged_dot_tiling="(\d+),', p.text)) \
+            == {"256" if program == "chunk" else "64"}
 
 
 def test_the_file_describes_its_layers():
@@ -186,114 +165,6 @@ def test_yarn_frequencies_by_hand():
     assert factor == 1.0
 
 
-def test_forward_matches_the_reference():
-    """The trainer's forward pass (no cache): logits and the experts
-    chosen."""
-    config = _config()
-    cfg = _cfg(config)
-    params = _params(cfg)
-    tokens = _tokens(40)
-    want, routes = _want(config, params, tokens)
-    got = tfm.forward(params, jnp.asarray([tokens], jnp.int32), cfg)
-    assert _rel(got, want) < TOL
-    assert routes.shape == (4, 1, 40, 3)
-
-
-@pytest.mark.parametrize("n, why", [
-    (5, "a context shorter than the window and than a chunk"),
-    (37, "a window layer past its ring (16 cells) twice over"),
-    (16, "a prompt of whole chunks, one ring's worth"),
-])
-def test_chunk_fill_and_decode_match_the_reference(n, why):
-    """The loop's own programs through the caches, as the benchmark's check
-    drives them: the prompt in chunks of 8, then four decode steps; every
-    logit row of the last chunk and the steps, and the experts chosen at
-    EVERY position."""
-    config = _config()
-    cfg = _cfg(config)
-    params = _params(cfg)
-    loop = _loop(cfg, params)
-    assert loop.prefill_fn is None and loop.bprefill_fn is None
-    assert loop.prefix is None                      # rings are not shared
-    assert loop.geo.ring_blocks == 4                # 8 - 1 + 8 positions
-    pages = np.arange(1, 2 + (n + layers_runner.N_DECODE) // PAGE)
-    seq, got, tops, _ = layers_runner.served_rows(
-        loop, params, _tokens(n, seed=n), pages, ring_pages=[1, 2, 3, 4])
-    want, want_top = _want(config, params, seq, last=len(got))
-    assert _rel(got, want[0]) < TOL, why
-    assert layers_runner.flips(tops, np.asarray(want_top)[:, 0])[0] == 0
-
-
-def test_preemption_and_refill_keep_the_tokens():
-    """A pool too small for both requests' contexts: the younger is
-    preempted, loses its pages and its ring, and is filled again from its
-    first token; both emit the reference's greedy tokens. The counters of
-    the multi-head kinds follow the programs."""
-    config = _config()
-    cfg = _cfg(config)
-    params = _params(cfg)
-    loop = _loop(cfg, params, n_pages=13, context=48)
-    prompts = [_tokens(14, seed=7), _tokens(11, seed=8)]
-    reqs = [Request(rid=i, prompt=p, max_new_tokens=20, arrival_t=1e-6)
-            for i, p in enumerate(prompts)]
-    _, finished = loop.run(reqs)
-    assert loop.batcher.stats["preemptions"] > 0
-    assert loop.batcher.ring_alloc.used_pages() == 0
-    stats = serve_loop.serve_stats()["attn"]
-    assert stats["calls"]["chunk"] > 4 and stats["calls"]["decode"] > 0
-    for kind in ("chunk", "decode"):
-        assert 0 < stats["kv_window_rows"][kind] \
-            <= stats["kv_window_rows_as_full"][kind]
-        # two full layers, three window layers
-        assert stats["kv_window_rows_as_full"][kind] * 2 \
-            == stats["kv_full_rows"][kind] * 3
-        assert stats["qk_full_pairs"][kind] >= stats["kv_full_rows"][kind]
-    assert stats["qk_full_pairs"]["decode"] == stats["kv_full_rows"]["decode"]
-    assert len(finished) == 2
-    for req in finished:
-        seq = list(req.prompt) + list(req.generated)
-        want = _want(config, params, seq[:-1], last=len(req.generated))[0]
-        assert np.argmax(want[0], -1).tolist() == req.generated
-
-
-def test_the_shares_add_up_to_the_uncut_layer():
-    """Section 4 of the model-configs guide: over a deployment of four
-    chips, each holding 4 of the 16 experts, the routed parts all shares
-    give, with the shared expert counted once, add up to what the uncut
-    layer gives; and the program's expert layer on each share is that
-    share's part."""
-    config = _config()
-    whole = _cfg(_config(experts_held=[0, 16]))
-    params = _params(whole)
-    layer = params["layers"][1]
-    h = jnp.asarray(np.random.default_rng(3).standard_normal((1, 24, 64)),
-                    jnp.float32)
-    p = reference.from_horovod_tpu(params)["layers"][1]["mlp"]
-    hp = reference.hyper(_config(experts_held=[0, 16]))
-    with jax.default_matmul_precision("highest"):
-        shared, routed, _ = reference.moe_parts(h[0], p, hp)
-        total = jnp.zeros_like(routed)
-        for offset in range(0, 16, 4):
-            share_cfg = _cfg(_config(experts_held=[offset, 4]))
-            mine = dict(layer, **{
-                name: layer[name][offset:offset + 4]
-                for name in ("w_in", "w_gate", "w_out")})
-            got, routing = tfm._moe_ffn(h, mine, share_cfg)
-            held = dict(p, experts={name: x[offset:offset + 4]
-                                    for name, x in p["experts"].items()})
-            _, part, _ = reference.moe_parts(
-                h[0], held, dict(hp, experts_held=(offset, 4)))
-            assert _rel(got[0], shared + part) < TOL
-            assert int(routing["counts"].sum()) == int(
-                ((routing["top"] >= offset)
-                 & (routing["top"] < offset + 4)).sum())
-            total = total + part
-    assert _rel(total, routed) < TOL
-    uncut, _ = tfm._moe_ffn(h, layer, whole)
-    assert _rel(uncut[0], shared + routed) < TOL
-    assert config["experts_held"] == [4, 4]
-
-
 def _sabotaged(name, cfg, params):
     """The program with one ASSUMED convention left out or changed; the
     reference keeps it."""
@@ -329,45 +200,12 @@ def _sabotaged(name, cfg, params):
     "YaRN on the window layers too", "weights not divided by their sum",
     "no routed scale", "softmax router"])
 def test_an_assumption_left_out_fails(name):
-    config = _config()
-    cfg = _cfg(config)
-    params = _params(cfg)
+    config, cfg, params = served.tiny(NAME)
     tokens = _tokens(40)
     want = _want(config, params, tokens)[0]
     bad_cfg, bad_params = _sabotaged(name, cfg, params)
     got = tfm.forward(bad_params, jnp.asarray([tokens], jnp.int32), bad_cfg)
     assert _rel(got, want) > 50 * TOL, name
-
-
-@pytest.mark.parametrize("fault", reference.FAULTS)
-def test_a_planted_fault_moves_the_reference(fault):
-    """The benchmark's controls: the reference itself with one thing wrong
-    (``reference.knobs``) is far from the sound reference, here as on the
-    chip; and the grouping fault is the program's ``j % Hkv`` twin."""
-    config = _config()
-    params = _params(_cfg(config))
-    tokens = _tokens(40)
-    want = _want(config, params, tokens)[0]
-    assert _rel(_want(config, params, tokens, fault=fault)[0], want) \
-        > 50 * TOL, fault
-    assert fault in FILE["controls"]["planted_faults"]["reference_faults"]
-
-
-@pytest.mark.parametrize("li, pages, lanes", [
-    (0, "n_pages", 32),     # full: 2 key/value heads of 16, on pages
-    (1, "ring_pages", 32),  # window: the same lanes, on rings, K and V both
-])
-def test_cache_shapes_by_layer_kind(li, pages, lanes):
-    cfg = _cfg(_config())
-    geo = kv_cache.with_rings(kv_cache.geometry(64, PAGE, 128), cfg, CHUNK, 2)
-    assert (geo.ring_blocks, geo.ring_pages) == (4, 9)
-    shape = (getattr(geo, pages), PAGE, lanes)
-    assert kv_cache.layer_shapes(cfg, geo, li) == (shape, shape)
-    # Two full layers on 64 pages, three window layers on 9 ring pages.
-    assert kv_cache.cache_bytes(cfg, geo) == 2 * 4 * PAGE * lanes * (
-        2 * 64 + 3 * 9)
-    with pytest.raises(ValueError, match="rings"):
-        kv_cache.layer_shapes(cfg, kv_cache.geometry(64, PAGE, 128), 1)
 
 
 def test_cache_shapes_of_the_plain_kind_stay():
@@ -407,12 +245,9 @@ BLOCK = 16      # rows of a block here: the small shapes must loop
 def _small(model):
     """(cfg, an expert layer's weights) of a small configuration: 4 of 16
     experts held, 3 (Laguna) or 4 (dots3) a token."""
-    if model == "dots3":
-        from . import test_dots3 as other
-        cfg = other._cfg(other._config())
-        return cfg, other._params(cfg)["layers"][1]
-    cfg = _cfg(_config())
-    return cfg, _params(cfg)["layers"][1]
+    _, cfg, params = served.tiny({"laguna": NAME,
+                                  "dots3": "dots3-note-prev"}[model])
+    return cfg, params["layers"][1]
 
 
 def _whole_tail(x, w, top, layer, cfg):
@@ -516,10 +351,10 @@ def test_without_experts_held_the_program_is_the_parent_s(monkeypatch):
     model) lowers to the text it lowered to before: the same instructions
     in the same order, whatever the block."""
     monkeypatch.setattr(tfm, "_HELD_BLOCK", BLOCK)
-    held = _cfg(_config(experts_held=[0, 16]))
+    _, held, params = served.tiny(NAME, experts_held=[0, 16])
     cfg = dataclasses.replace(held, experts_held=())
     assert cfg.n_held == 16
-    layer = _params(held)["layers"][1]
+    layer = params["layers"][1]
     x, w, top = _routed(held, 24, 24 * cfg.top_k)
     text = _lowered(tfm._moe_grouped, layer, cfg, x, w, top)
     assert text == _lowered(_whole_tail, layer, cfg, x, w, top)
@@ -533,9 +368,8 @@ def test_serve_stats_count_the_rows_the_products_ran_over(monkeypatch):
     row, so its fill is the held share of the live rows; a chunk's blocks
     run over fewer rows than were routed, so its fill is well over that."""
     monkeypatch.setattr(tfm, "_HELD_BLOCK", BLOCK)
-    cfg = _cfg(_config())
-    params = _params(cfg)
-    loop = _loop(cfg, params, max_batch=4)
+    _, cfg, params = served.tiny(NAME)
+    loop = served.loop(NAME, fresh=True, max_batch=4)
     reqs = [Request(rid=i, prompt=_tokens(19 + i, seed=i), max_new_tokens=6,
                     arrival_t=1e-6) for i in range(4)]
     loop.run(reqs)

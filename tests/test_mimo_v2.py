@@ -18,10 +18,6 @@ runs in float32, where program and reference must agree to rounding although
 the one attends through pages and rings and the other over the whole sequence.
 """
 import dataclasses
-import functools
-import importlib.util
-import json
-import os
 
 import numpy as np
 import pytest
@@ -33,93 +29,56 @@ from horovod_tpu.models import transformer as tfm
 from horovod_tpu.serving import kv_cache
 from horovod_tpu.serving import loop as serve_loop
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from . import served
+
+NAME = "mimo-v2-flash"
+FILE = served.file_config(NAME)
+runner, reference = served.runner(NAME), served.reference(NAME)
+TOL, _rel, _tokens = (getattr(served.ENTRIES[NAME], k)
+                      for k in ("tol", "rel", "tokens"))
 
 
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+def _want(config, params, tokens, last=None, fault=None):
+    return served.want(NAME, config, params, tokens, fault=fault, last=last,
+                       with_routes=True)
 
 
-reference = _load("benchmark/reference/mimo_v2.py", "mimo_v2_reference")
-layers_runner = _load("benchmark/runners/serve_layers.py",
-                      "serve_layers_runner")
-runner = _load("benchmark/runners/serve_gqa_kinds.py",
-               "serve_gqa_kinds_runner")
-FILE = json.load(open(os.path.join(ROOT, "benchmark", "configs",
-                                   "mimo-v2-flash.json")))
-PAGE, CHUNK, TOL = 4, 8, 2e-4
+class TestContract(served.Contract):
+    name = NAME
+
+    def also_served(self, lp, n, rows):
+        """Through pages (the full layers, 2 key/value heads) and rings (the
+        window layers, 4)."""
+        assert lp.prefill_fn is None and lp.bprefill_fn is None
+        assert lp.geo.ring_blocks == 4              # 8 - 1 + 8 positions
 
 
-def _config(**overrides):
-    """The configuration file with every size shrunk and the ratios kept."""
-    config = dict(FILE)
-    config.update(
-        hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
-        num_attention_heads=8, swa_num_attention_heads=8,
-        num_key_value_heads=2, swa_num_key_value_heads=4,
-        head_dim=24, swa_head_dim=24, v_head_dim=16, swa_v_head_dim=16,
-        sliding_window=8, sliding_window_size=8, num_hidden_layers=7,
-        n_routed_experts_published=16, experts_held=[4, 4],
-        n_routed_experts=4, num_experts_per_tok=2, vocab_size=128,
-        max_position_embeddings=256, rope_theta=500.0, swa_rope_theta=20.0)
-    config.update(overrides)
-    return config
+class TestCellPrograms(served.CellPrograms):
+    """``mimo-serve-mixed64k-over``: seven layers of two described kinds that
+    differ in KEY/VALUE heads, keys of 192 beside values of 128, a sink on the
+    window layers; 16 slots of a 64k context, the window layers on rings of
+    40 pages. The chip's compiler takes the grouped paged kernel at the
+    published widths (a key head read as the aligned 256 lanes around it, K
+    and V pages of different lanes, the sink's tile) for one query a slot and
+    for a block of 128."""
+    name = NAME
+
+    def also_cell(self, built):
+        assert [c.shape[-1] for c in built.cache["k"]] \
+            == [768] + [1536] * 4 + [768, 1536]
+        assert [c.shape[-1] for c in built.cache["v"]] \
+            == [512] + [1024] * 4 + [512, 1024]
 
 
-def _cfg(config, **overrides):
-    return dataclasses.replace(runner.model_config(config), dtype="float32",
-                               param_dtype="float32", **overrides)
-
-
-@functools.lru_cache(maxsize=None)
-def _params(cfg, seed=0):
-    """Seeded weights as the benchmark's runner draws them: norm scales
-    around 1, a selection bias solved for an even load, sinks that hold a
-    real share of a window row's softmax (here ln 8 - 0.7 .. ln 8 + 0.3), so
-    that none can be left out unseen. (Made once a configuration: the solve
-    runs seven layers over 4,096 tokens.)"""
-    params = runner.make_params(cfg, jax.random.PRNGKey(seed))
+def test_the_sinks_hold_a_real_share_of_a_window_row():
+    """The runner's weights: a selection bias solved for an even load, sinks
+    that hold a real share of a window row's softmax (here ln 8 - 0.7 .. ln 8
+    + 0.3), so that none can be left out unseen."""
+    params = served.tiny(NAME)[2]
     sinks = [layer["sink"] for layer in params["layers"] if "sink" in layer]
     assert len(sinks) == 5 and all(
         np.log(8) - 0.7 <= float(s.min()) <= float(s.max()) <= np.log(8) + 0.3
         for s in sinks)
-    return params
-
-
-def _tokens(n, seed=1):
-    return np.random.default_rng(seed).integers(0, 128, n).tolist()
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / np.abs(want).max())
-
-
-_COMPILED = {}
-
-
-def _want(config, params, tokens, last=None, fault=None):
-    """The reference's logits and routes; the knobs are arguments, so the
-    sound model and every fault share one compiled program a length (as the
-    benchmark's runner has it)."""
-    hp = reference.hyper(config)
-    key = (json.dumps(config, sort_keys=True), len(tokens), last)
-    if key not in _COMPILED:
-        _COMPILED[key] = jax.jit(lambda w, t, kn: reference.logits(
-            w, t, hp, last=last, with_routes=True, kn=kn))
-    return _COMPILED[key](
-        reference.from_horovod_tpu(params), jnp.asarray([tokens], jnp.int32),
-        reference.knobs(hp, fault))
-
-
-def _loop(cfg, params, max_batch=2, n_pages=64, context=128, **kw):
-    geo = kv_cache.geometry(n_pages, PAGE, context)
-    return serve_loop.ServeLoop(params, cfg, geo=geo, max_batch=max_batch,
-                                prefill_chunk=CHUNK, **kw)
 
 
 def test_the_file_describes_its_layers():
@@ -169,54 +128,14 @@ def test_the_file_describes_its_layers():
     assert "3.430 B parameters" in FILE["reduced_why"]
 
 
-def test_forward_matches_the_reference():
-    """The trainer's forward pass (no cache): logits and the experts
-    chosen."""
-    config = _config()
-    cfg = _cfg(config)
-    params = _params(cfg)
-    tokens = _tokens(40)
-    want, routes = _want(config, params, tokens)
-    got = tfm.forward(params, jnp.asarray([tokens], jnp.int32), cfg)
-    assert _rel(got, want) < TOL
-    assert routes.shape == (6, 1, 40, 2)
-
-
-@pytest.mark.parametrize("n, why", [
-    (5, "a context shorter than the window and than a chunk"),
-    (37, "a window layer past its ring (16 cells) twice over"),
-    (16, "a prompt of whole chunks, one ring's worth"),
-])
-def test_chunk_fill_and_decode_match_the_reference(n, why):
-    """The loop's own programs through the caches, as the benchmark's check
-    drives them: the prompt in chunks of 8 through pages (the full layers, 2
-    key/value heads) and rings (the window layers, 4), then four decode
-    steps; every logit row of the last chunk and the steps against the
-    reference's one full pass, and the experts chosen at EVERY position."""
-    config = _config()
-    cfg = _cfg(config)
-    params = _params(cfg)
-    loop = _loop(cfg, params)
-    assert loop.prefill_fn is None and loop.bprefill_fn is None
-    assert loop.geo.ring_blocks == 4                # 8 - 1 + 8 positions
-    pages = np.arange(1, 2 + (n + layers_runner.N_DECODE) // PAGE)
-    seq, got, tops, _ = layers_runner.served_rows(
-        loop, params, _tokens(n, seed=n), pages, ring_pages=[1, 2, 3, 4])
-    want, want_top = _want(config, params, seq, last=len(got))
-    assert _rel(got, want[0]) < TOL, why
-    assert layers_runner.flips(tops, np.asarray(want_top)[:, 0])[0] == 0
-
-
 def test_the_loop_serves_and_counts_each_kind_at_its_own_lanes():
     """Two requests through ``ServeLoop.run`` emit the reference's greedy
     tokens, and ``serve_stats()["attn"]`` prices each kind's rows at its own
     key and value lanes and counts the rows normalised over a sink."""
     from horovod_tpu.serving.scheduler import Request
 
-    config = _config()
-    cfg = _cfg(config)
-    params = _params(cfg)
-    loop = _loop(cfg, params)
+    config, cfg, params = served.tiny(NAME)
+    loop = served.loop(NAME)
     reqs = [Request(rid=i, prompt=_tokens(n, seed=n), max_new_tokens=6,
                     arrival_t=1e-6) for i, n in enumerate((21, 9))]
     _, finished = loop.run(reqs)
@@ -243,18 +162,18 @@ def test_the_shares_add_up_to_the_uncut_layer():
     add up to what the uncut layer gives (there is no shared expert to count
     once); and the program's expert layer on each share is that share's
     part."""
-    whole = _cfg(_config(experts_held=[0, 16]))
-    params = _params(whole)
+    uncut, whole, params = served.tiny(NAME, experts_held=[0, 16])
     layer = params["layers"][1]
     h = jnp.asarray(np.random.default_rng(3).standard_normal((1, 24, 64)),
                     jnp.float32)
     p = reference.from_horovod_tpu(params)["layers"][1]["mlp"]
-    hp = reference.hyper(_config(experts_held=[0, 16]))
+    hp = reference.hyper(uncut)
     with jax.default_matmul_precision("highest"):
         routed, _ = reference.moe_part(h[0], p, hp)
         total = jnp.zeros_like(routed)
         for offset in range(16):
-            share_cfg = _cfg(_config(experts_held=[offset, 1]))
+            share_cfg = served.tiny_config(
+                NAME, experts_held=[offset, 1])[1]
             mine = dict(layer, **{
                 name: layer[name][offset:offset + 1]
                 for name in ("w_in", "w_gate", "w_out")})
@@ -316,9 +235,7 @@ def _sabotaged(name, cfg, params):
     "thetas_swapped", "window_one_short", "a sink on the full layers too",
     "no selection bias"])
 def test_an_assumption_left_out_fails(name):
-    config = _config()
-    cfg = _cfg(config)
-    params = _params(cfg)
+    config, cfg, params = served.tiny(NAME)
     tokens = _tokens(40)
     want = _want(config, params, tokens)[0]
     bad_cfg, bad_params = _sabotaged(name, cfg, params)
@@ -326,51 +243,22 @@ def test_an_assumption_left_out_fails(name):
     assert _rel(got, want) > 50 * TOL, name
 
 
-@pytest.mark.parametrize("fault", reference.FAULTS)
-def test_a_planted_fault_moves_the_reference(fault):
-    """The benchmark's controls: the reference itself with one thing wrong
-    (``reference.knobs``) is far from the sound reference, here as on the
-    chip; and where the program can plant the same fault, the two faulty
+@pytest.mark.parametrize("fault", [f for f in reference.FAULTS
+                                   if f != "kv_heads_of_other_kind"])
+def test_a_planted_fault_is_the_program_s_twin(fault):
+    """Where the program can plant the reference's fault, the two faulty
     models agree with each other: the knob changes what its name says."""
-    config = _config()
-    cfg = _cfg(config)
-    params = _params(cfg)
+    config, cfg, params = served.tiny(NAME)
     tokens = _tokens(40)
-    want = _want(config, params, tokens)[0]
     bad = _want(config, params, tokens, fault=fault)[0]
-    assert _rel(bad, want) > 50 * TOL, fault
-    assert fault in FILE["controls"]["planted_faults"]["reference_faults"]
-    if fault != "kv_heads_of_other_kind":
-        bad_cfg, bad_params = _sabotaged(fault, cfg, params)
-        got = tfm.forward(bad_params, jnp.asarray([tokens], jnp.int32),
-                          bad_cfg)
-        assert _rel(got, bad) < TOL, fault
+    bad_cfg, bad_params = _sabotaged(fault, cfg, params)
+    got = tfm.forward(bad_params, jnp.asarray([tokens], jnp.int32), bad_cfg)
+    assert _rel(got, bad) < TOL, fault
 
 
 def test_the_faults_of_the_file_are_the_reference_s():
     assert tuple(FILE["controls"]["planted_faults"]["reference_faults"]) \
         == reference.FAULTS
-
-
-@pytest.mark.parametrize("li, pages, k_lanes, v_lanes", [
-    (0, "n_pages", 48, 32),     # full: 2 heads, keys of 24, values of 16
-    (1, "ring_pages", 96, 64),  # window: 4 heads, on rings
-])
-def test_cache_shapes_by_layer_kind(li, pages, k_lanes, v_lanes):
-    """A layer's K and V arrays have lanes of their own, and the two kinds
-    differ in key/value heads."""
-    cfg = _cfg(_config())
-    geo = kv_cache.with_rings(kv_cache.geometry(64, PAGE, 128), cfg, CHUNK, 2)
-    assert (geo.ring_blocks, geo.ring_pages) == (4, 9)
-    n = getattr(geo, pages)
-    assert kv_cache.layer_shapes(cfg, geo, li) == ((n, PAGE, k_lanes),
-                                                   (n, PAGE, v_lanes))
-    # Two full layers on 64 pages, five window layers on 9 ring pages.
-    assert kv_cache.cache_bytes(cfg, geo) == 4 * PAGE * (
-        2 * 64 * (48 + 32) + 5 * 9 * (96 + 64))
-    cache = kv_cache.make_cache(cfg, geo)
-    assert cache["k"][li].shape[-1] == k_lanes
-    assert cache["v"][li].shape[-1] == v_lanes
 
 
 def test_the_cell_s_cache_at_the_published_widths():

@@ -17,10 +17,8 @@ through chunk programs and decode steps and the other scans the sequence once.
 """
 import dataclasses
 import hashlib
-import importlib.util
 import json
-import os
-import sys
+import re
 
 import numpy as np
 import pytest
@@ -31,66 +29,112 @@ import jax.numpy as jnp
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import pallas_ssm
 from horovod_tpu.serving import engine, kv_cache
-from horovod_tpu.serving import loop as serve_loop
 from horovod_tpu.serving.scheduler import Request
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from . import served
 
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    sys.path.insert(0, ROOT)
-    try:
-        spec.loader.exec_module(module)
-    finally:
-        sys.path.remove(ROOT)
-    return module
-
-
-reference = _load("benchmark/reference/nemotron_h.py", "nemotron_h_reference")
-runner = _load("benchmark/runners/serve_hybrid.py", "serve_hybrid_runner")
-FILE = json.load(open(os.path.join(ROOT, "benchmark", "configs",
-                                   "nemotron-3-super-120b.json")))
-PAGE, CHUNK, TOL = 4, 8, 2e-5
-
-
-def _config(**overrides):
-    """The configuration file with every size shrunk."""
-    config = json.loads(json.dumps(FILE))
-    config.update(
-        hidden_size=32, expand=2, mamba_num_heads=8, mamba_head_dim=8,
-        n_groups=2, ssm_state_size=16, chunk_size=4, num_attention_heads=4,
-        num_key_value_heads=2, head_dim=16, moe_latent_size=16,
-        moe_intermediate_size=24, intermediate_size=24,
-        moe_shared_expert_intermediate_size=48, n_routed_experts_published=16,
-        n_routed_experts=8, experts_held=[4, 8], num_experts_per_tok=3,
-        vocab_size=96, max_position_embeddings=256,
-        # the period's last five letters, EMEM*: every kind, fewer to compile
-        num_hidden_layers=5, layers_run=[32, 37])
-    config["model"].update(dtype="float32", param_dtype="float32")
-    config["assumed"]["serve"]["chunk"] = CHUNK
-    config.update(overrides)
-    return config
+NAME = "nemotron-3-super-120b"
+FILE = served.file_config(NAME)
+runner, reference = served.runner(NAME), served.reference(NAME)
+PAGE, CHUNK = 4, 8
+TOL, _rel, _tokens = (getattr(served.ENTRIES[NAME], k)
+                      for k in ("tol", "rel", "tokens"))
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    config = _config()
-    cfg = runner.model_config(config)
-    params = runner.make_params(cfg, jax.random.PRNGKey(3))
-    return config, cfg, params
+    return served.tiny(NAME)
 
 
-def _tokens(n, seed=1):
-    return jax.random.randint(jax.random.PRNGKey(seed), (1, n), 0, 96)
+class TestContract(served.Contract):
+    name = NAME
+
+    def also_reused(self, stats, lengths):
+        state = stats["state"]
+        assert state["resets"]["chunk"] == 5 * 2          # requests x layers
+        assert state["resets"].get("decode", 0) == 0
+        assert state["rows"]["decode"] == state["tokens"]["decode"]
+        assert state["bytes"]["decode"] == 2 * state["rows"]["decode"] * (
+            3 * 128 * 4 + 8 * 8 * 16 * 4)
+        assert state["kv_bytes"]["decode"] > 0
+
+    def also_cache(self, cfg, geo):
+        cache = kv_cache.make_cache(cfg, geo)
+        assert cache["k"][1].dtype == jnp.float32               # compute dtype
+        half = dataclasses.replace(cfg, dtype="bfloat16")
+        assert kv_cache.make_cache(half, geo)["k"][1].dtype == jnp.bfloat16
+        assert kv_cache.make_cache(half, geo)["v"][1].dtype == jnp.float32
+        assert kv_cache.cache_bytes(half, geo) == (
+            2 * (4 * 3 * 128 * 2 + 4 * 8 * 8 * 16 * 4) + 2 * 33 * PAGE * 32 * 2)
+        with pytest.raises(ValueError, match="state rows"):
+            kv_cache.layer_shapes(cfg, kv_cache.geometry(33, PAGE, 64), 1)
+
+    def also_over_state(self, lp, cfg, params):
+        req = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=2)
+        lp.batcher.submit(req)
+        lp.batcher.admit()
+        assert lp.batcher.block_table(req, lp.geo.max_blocks)[-1] \
+            == req.slot + 1
 
 
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
+class TestCellPrograms(served.CellPrograms):
+    """``nemotron-serve-reason-over``: eleven layers that are each a mixer or
+    a feed-forward, five state-space layers on slot-owned rows (float32
+    state), one attention layer of 32 query heads over 2 key/value heads on
+    pages, five expert layers with no cache. The chip's compiler takes the
+    grouped paged kernel at a group of 16; the decode step holds no second
+    copy of a layer's state (0.54 GB: a gather of the rows, or the blocked
+    scan at a block of one, made one a layer) and passes over it once, in
+    the kernel ``ssm_decode_update`` (PR 43; XLA made three passes of
+    ``_ssd_step``); the chunk program runs its recurrence as ONE
+    ``ssm_chunk_scan`` a state-space layer (PR 58: eight packs of 16 heads,
+    blocks of 128) and keeps none of the blocked form's per-head ``[128,
+    128]`` decay tensors (``f32[4,8,16,128,128]`` in the compiled text of the
+    blocked form)."""
+    name = NAME
+    DECAYS = "f32[4,8,16,128,128]"
+
+    def also_built(self, found):
+        cfg, geo, chunk = found.cfg, found.geo, found.cell.chunk
+        found.gate_by_window = [engine.state_kernels(cfg, geo, None, q)
+                                for q in (chunk, 16)]
+        with pytest.MonkeyPatch.context() as closed:
+            closed.setattr(engine, "state_kernels", lambda *a: False)
+            found.blocked = engine.make_chunk_step(cfg, geo, q_len=chunk).lower(
+                found.params, found.cache, *served.slots(
+                    geo, 1, chunk, like=found.on_chip)).compile().as_text()
+
+    def also_cell(self, built):
+        cfg, geo = built.cfg, built.geo
+        n_params = sum(x.size for x in jax.tree.leaves(built.params))
+        assert 4.64e9 < n_params < 4.66e9           # the file's reduced_why
+        assert kv_cache.cache_bytes(cfg, geo) == built.held - 2 * n_params
+        assert sum(isinstance(cfg.attn_of(li), tfm.StateSpaceMixer)
+                   and cfg.has_mixer(li) for li in range(cfg.n_layers)) == 5
+        assert [bool(g) for g in built.gate_by_window] == [True, False]
+        # With the gate closed the chunk program is the blocked form's: no
+        # kernel, and the decays in a buffer of their own.
+        assert "ssm_chunk_scan" not in built.blocked
+        assert self.DECAYS in built.blocked
+
+    def also_program(self, built, program, p):
+        assert self.DECAYS not in p.text
+        if program != "decode":
+            return
+        # The kernel takes the layer's whole array and gives it back
+        # (aliased), and nothing else makes an array of a layer's rows.
+        B = built.cell.max_batch
+        rows = ["f32[%d,128,64,128]" % n for n in (B, B + 1)]
+        made = []
+        for line in p.text.splitlines():   # "%name = type op(..": layouts off
+            m = re.match(r"\s*(?:ROOT )?(%[\w.-]+) = (\([^)]*\)|\S+) "
+                         r"([\w-]+)\(", re.sub(r"\{[^}]*\}", "", line))
+            if m and any(r in m.group(2) for r in rows) \
+                    and m.group(3) not in ("parameter", "tuple",
+                                           "get-tuple-element"):
+                made.append(m.group(1))
+        assert len(made) == 5 and all(
+            m.startswith("%ssm_decode_update") for m in made), made
 
 
 # ---- the description ------------------------------------------------------
@@ -260,29 +304,8 @@ def test_dead_positions_leave_tail_and_state_alone(tiny):
     assert _rel(s1[2], whole[2][0]) < 1e-6
 
 
+
 # ---- the model against the reference --------------------------------------
-
-def test_forward_against_the_reference(tiny):
-    config, cfg, params = tiny
-    tokens = _tokens(37)
-    hp = reference.hyper(config)
-    want, routes = reference.logits(reference.from_horovod_tpu(params),
-                                    tokens, hp, with_routes=True)
-    got = tfm.forward(params, tokens, cfg)
-    assert _rel(got, want) < TOL
-    assert routes.shape == (2, 1, 37, 3)
-
-
-@pytest.mark.parametrize("fault", reference.FAULTS)
-def test_a_reference_fault_moves_the_logits(tiny, fault):
-    config, cfg, params = tiny
-    tokens = _tokens(21)
-    hp = reference.hyper(config)
-    w = reference.from_horovod_tpu(params)
-    sound = reference.logits(w, tokens, hp)
-    bad = reference.logits(w, tokens, hp, kn=reference.knobs(hp, fault))
-    assert _rel(bad, sound) > 100 * TOL
-
 
 def test_a_bf16_state_fails_the_comparison(tiny, monkeypatch):
     """Tight enough that a state kept in bfloat16 is refused."""
@@ -350,80 +373,72 @@ def test_the_four_shares_add_up_to_the_whole_layer(tiny):
                 routed) < TOL
 
 
-# ---- the cache and the programs -------------------------------------------
 
-def test_cache_shapes_by_layer_kind(tiny):
-    _, cfg, _ = tiny
-    geo = kv_cache.with_rings(kv_cache.geometry(33, PAGE, 64), cfg, CHUNK, 3)
-    assert (geo.state_rows, geo.ring_blocks, geo.table_width) == (4, 0, 17)
-    assert kv_cache.layer_shapes(cfg, geo, 0) == (None, None)   # experts
-    assert kv_cache.layer_shapes(cfg, geo, 1) == ((4, 3, 128), (4, 8, 8, 16))
-    assert kv_cache.layer_shapes(cfg, geo, 4) == ((33, PAGE, 32),) * 2
-    cache = kv_cache.make_cache(cfg, geo)
-    assert cache["k"][0] is None and cache["v"][0] is None
-    assert cache["k"][1].dtype == jnp.float32                   # compute dtype
-    assert cache["v"][1].dtype == jnp.float32
-    half = dataclasses.replace(cfg, dtype="bfloat16")
-    assert kv_cache.make_cache(half, geo)["k"][1].dtype == jnp.bfloat16
-    assert kv_cache.make_cache(half, geo)["v"][1].dtype == jnp.float32
-    assert kv_cache.cache_bytes(half, geo) == (
-        2 * (4 * 3 * 128 * 2 + 4 * 8 * 8 * 16 * 4) + 2 * 33 * PAGE * 32 * 2)
-    assert kv_cache.cache_bytes(cfg, geo) == sum(
-        x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
-    with pytest.raises(ValueError, match="state rows"):
-        kv_cache.layer_shapes(cfg, kv_cache.geometry(33, PAGE, 64), 1)
+def test_the_memo_keeps_no_loop_traced_under_a_planted_name(tiny, monkeypatch):
+    """``served.loop`` while ``engine._state_layer`` is replaced by a wrapper
+    that records its calls builds a loop of its own; with the patch undone
+    the same model and arguments give a loop whose chunk program never
+    enters the wrapper: the memo is keyed on what decides the program, and
+    neither hands out nor keeps a loop traced under a planted name."""
+    _, cfg, params = tiny
+    entered, sound = [], engine._state_layer
 
+    def recording(*args, **kw):
+        entered.append(1)
+        return sound(*args, **kw)
 
-def _loop(cfg, params, n_pages=65, max_batch=3, **kw):
-    return serve_loop.ServeLoop(
-        params, cfg, geo=kv_cache.geometry(n_pages, PAGE, 64),
-        max_batch=max_batch, prefill_chunk=CHUNK, **kw)
+    def chunk(loop):
+        loop.cache, *_ = loop.chunk_fn(
+            params, loop.cache, *served.slots(loop.geo, 1, CHUNK))
 
-
-def _greedy(params, cfg, req):
-    """Whether ``req.generated`` is what greedy decoding of ``forward``
-    generates after ``req.prompt``: one causal pass over prompt + generated
-    (padded to one length, so one compilation) predicts each of them."""
-    seq = list(req.prompt) + list(req.generated)
-    logits = tfm.forward(params, jnp.asarray([seq + [0] * (64 - len(seq))]),
-                         cfg)[0]
-    n = len(req.prompt)
-    return [int(t) for t in jnp.argmax(logits[n - 1:len(seq) - 1], -1)]
+    kw = dict(n_pages=17)               # arguments no other case asks for
+    with monkeypatch.context() as plant:
+        plant.setattr(engine, "_state_layer", recording)
+        assert served.planted()
+        under = served.loop(NAME, **kw)
+        chunk(under)
+        assert entered
+    assert not served.planted()
+    del entered[:]
+    clean = served.loop(NAME, **kw)
+    assert clean is not under and clean is served.loop(NAME, **kw)
+    chunk(clean)
+    assert not entered
 
 
-@pytest.fixture(scope="module", params=[False, True],
-                ids=["plain", "kernel"])
-def programs(tiny, request):
+# ---- the programs through the kernels --------------------------------------
+
+@pytest.fixture(scope="module")
+def kernel_programs():
     """One loop's compiled programs and cache for the cases below: each
-    starts its prompt in the rows the case before it left. ``kernel``: the
-    state-space layers' recurrence through ``ops/pallas_ssm.py`` in interpret
-    mode, as the engine takes it on a TPU (``ssm_chunk_scan`` in the chunk
-    program, whose 8 positions are two of this model's blocks of 4, and
+    starts its prompt in the rows the case before it left. The state-space
+    layers' recurrence goes through ``ops/pallas_ssm.py`` in interpret mode,
+    as the engine takes it on a TPU (``ssm_chunk_scan`` in the chunk program,
+    whose 8 positions are two of this model's blocks of 4, and
     ``ssm_decode_update`` in the decode step; steered here: the programs are
     traced at their first call, so the steering lasts as long as they do).
     ``loop.entered`` names the kernels a trace went through."""
-    _, cfg, params = tiny
     with pytest.MonkeyPatch.context() as steer:
         entered = set()
-        if request.param:
-            steer.setattr(engine, "state_kernels", lambda *a: True)
-            for name in ("ssm_chunk_scan", "ssm_decode_update"):
-                def counted(*a, name=name, sound=getattr(pallas_ssm, name),
-                            **kw):
-                    entered.add(name)
-                    return sound(*a, **kw)
-                steer.setattr(pallas_ssm, name, counted)
-        loop = _loop(cfg, params)
+        steer.setattr(engine, "state_kernels", lambda *a: True)
+        for name in ("ssm_chunk_scan", "ssm_decode_update"):
+            def counted(*a, name=name, sound=getattr(pallas_ssm, name), **kw):
+                entered.add(name)
+                return sound(*a, **kw)
+            steer.setattr(pallas_ssm, name, counted)
+        loop = served.loop(NAME)        # steered: the memo is not asked
         loop.entered = entered
         yield loop
 
 
-def test_which_recurrence_the_programs_trace(tiny, programs):
+@pytest.mark.parametrize("tier", ["plain", "kernel"])
+def test_which_recurrence_the_programs_trace(tiny, request, tier):
     """A chunk and a decode step with no live slot (nothing moves): the
     kernel tier traces both kernels, once a state-space layer, the plain tier
     neither."""
     _, cfg, params = tiny
-    loop = programs
+    loop = (request.getfixturevalue("kernel_programs") if tier == "kernel"
+            else served.loop(NAME))
     before = jax.tree.map(np.asarray, loop.cache)
     idle = np.zeros((1, loop.geo.table_width), np.int32)
     loop.cache, *_ = loop.chunk_fn(
@@ -432,178 +447,23 @@ def test_which_recurrence_the_programs_trace(tiny, programs):
     loop.cache, *_ = loop.decode_fn(
         params, loop.cache, np.zeros(3, np.int32), np.zeros(3, np.int32),
         np.repeat(idle, 3, 0), np.zeros(3, bool))
-    steered = engine.state_kernels(cfg, loop.geo, None) is True
-    assert loop.entered == ({"ssm_chunk_scan", "ssm_decode_update"}
-                            if steered else set())
+    assert getattr(loop, "entered", set()) == (
+        {"ssm_chunk_scan", "ssm_decode_update"} if tier == "kernel"
+        else set())
     for was, now in zip(jax.tree.leaves(before),
                         jax.tree.leaves(jax.tree.map(np.asarray, loop.cache))):
         assert np.array_equal(was[1:], now[1:])     # row / page 0 is trash
 
 
 @pytest.mark.parametrize("n", [5, 19, 24])
-def test_chunks_then_decode_against_one_forward(tiny, programs, n):
-    """A prompt filled in chunks of 8 (padding -1) and decoded four steps
-    through the engine's programs, in a slot other than 0 and on rows that
-    are dirty from the second case on, against one full ``forward``: every
-    logit row of every chunk and step."""
+def test_chunks_then_decode_through_the_kernels(tiny, kernel_programs, n):
+    """``Contract``'s case on the kernel tier: every logit row of every
+    chunk and step against one full ``forward``."""
     _, cfg, params = tiny
-    loop = programs
-    geo, slot = loop.geo, 2
+    loop = kernel_programs
     prompt = [int(t) for t in _tokens(n, seed=n)[0]]
-    table = np.zeros(geo.table_width, np.int32)
-    table[:8] = np.arange(1, 9)
-    table[-1] = slot + 1
-    rows = []
-    for start in range(0, n, CHUNK):
-        toks = np.full((1, CHUNK), -1, np.int32)
-        toks[0, :len(prompt[start:start + CHUNK])] = prompt[start:start + CHUNK]
-        loop.cache, lg, *_ = loop.chunk_fn(
-            params, loop.cache, toks, np.asarray([start], np.int32),
-            table[None], np.ones(1, bool))
-        rows.append(np.asarray(lg[0, :min(CHUNK, n - start)]))
-    seq = prompt + [int(np.argmax(rows[-1][-1]))]
-    tables = np.zeros((3, geo.table_width), np.int32)
-    tables[slot] = table
-    active = np.arange(3) == slot
-    for _ in range(4):
-        tokens, positions = np.zeros(3, np.int32), np.zeros(3, np.int32)
-        tokens[slot], positions[slot] = seq[-1], len(seq) - 1
-        loop.cache, lg, *_ = loop.decode_fn(params, loop.cache, tokens,
-                                            positions, tables, active)
-        rows.append(np.asarray(lg[slot:slot + 1]))
-        seq.append(int(np.argmax(rows[-1][-1])))
-    want = tfm.forward(params, jnp.asarray([seq[:-1]]), cfg)[0]
-    assert _rel(np.concatenate(rows), want) < TOL
-    # The other slots' rows were never touched.
+    rows, seq = served.fill_then_decode(loop, params, prompt, served.SLOT,
+                                        steps=4)
+    assert _rel(rows, served.logits(cfg, params, seq[:-1], 64)) < TOL
     assert not np.asarray(loop.cache["v"][1][1]).any()
-    assert np.asarray(loop.cache["v"][1][slot + 1]).any()
-
-
-def test_a_reused_slot_gives_the_logits_of_a_fresh_run(tiny):
-    """Five requests through three slots: the later ones start in rows the
-    earlier ones left dirty, and generate what a fresh model generates."""
-    _, cfg, params = tiny
-    loop = _loop(cfg, params)
-    loop.warmup()
-    rng = np.random.default_rng(0)
-    reqs = [Request(rid=i, prompt=rng.integers(0, 96, 9 + 3 * i).tolist(),
-                    max_new_tokens=5, arrival_t=0.001 * (i + 1))
-            for i in range(5)]
-    _, done = loop.run(reqs)
-    assert len(done) == 5
-    for r in done:
-        assert r.generated == _greedy(params, cfg, r), r.rid
-    state = serve_loop.serve_stats()["state"]
-    assert state["resets"]["chunk"] == 5 * 2          # requests x layers
-    assert state["resets"].get("decode", 0) == 0
-    assert state["rows"]["decode"] == state["tokens"]["decode"]
-    assert state["bytes"]["decode"] == 2 * state["rows"]["decode"] * (
-        3 * 128 * 4 + 8 * 8 * 16 * 4)
-    assert state["kv_bytes"]["decode"] > 0
-
-
-def test_a_preempted_request_replays_from_a_zeroed_row(tiny):
-    """Too few pages for three growing requests: the youngest is preempted,
-    its pages freed, and its replay (prompt + generated, from position 0)
-    finds its row zeroed: every request generates a fresh run's tokens."""
-    _, cfg, params = tiny
-    loop = _loop(cfg, params, n_pages=14)
-    rng = np.random.default_rng(1)
-    reqs = [Request(rid=i, prompt=rng.integers(0, 96, 10).tolist(),
-                    max_new_tokens=12, arrival_t=0.001 * (i + 1))
-            for i in range(3)]
-    summary, done = loop.run(reqs)
-    assert summary["preemptions"] > 0
-    for r in done:
-        assert r.generated == _greedy(params, cfg, r), r.rid
-
-
-def _wrapped_state_layer(entered):
-    sound = engine._state_layer
-
-    def wrapper(mix, tail_c, state_c, *, q_pos, ok, tables):
-        entered.append("engine._state_layer")
-        return sound(mix, tail_c, state_c, q_pos=q_pos, ok=ok, tables=tables)
-
-    return engine, "_state_layer", wrapper
-
-
-def _wrapped_state_space_mix(entered):
-    sound = tfm.state_space_mix
-
-    def wrapper(u, layer, a, cfg, tail=None, state=None, live=None):
-        entered.append("tfm.state_space_mix")
-        return sound(u, layer, a, cfg, tail, state, live)
-
-    return tfm, "state_space_mix", wrapper
-
-
-@pytest.mark.parametrize("wrap", [_wrapped_state_layer,
-                                  _wrapped_state_space_mix],
-                         ids=["engine._state_layer", "tfm.state_space_mix"])
-def test_the_seams_the_benchmark_plants_its_faults_in(tiny, monkeypatch, wrap):
-    """``benchmark/tests/test_serve_hybrid_cpu.py`` proves the hybrid cell's
-    ``correct`` by replacing ``engine._state_layer`` and
-    ``tfm.state_space_mix`` with wrappers of EXACTLY these signatures (no
-    ``kernels=``, no ``recur=``: what the plain tier calls them with). Both
-    programs look the two up through their modules when they are traced, so
-    a wrapper planted before the first call is entered by the chunk and by
-    the decode program, and a sound one changes nothing."""
-    _, cfg, params = tiny
-    entered = []
-    # The plain tier, whatever the ``programs`` fixture has steered on.
-    monkeypatch.setattr(engine, "state_kernels", lambda *a: False)
-    monkeypatch.setattr(*wrap(entered))
-    loop = _loop(cfg, params)
-    geo, slot, n = loop.geo, 1, 5
-    prompt = [int(t) for t in _tokens(n, seed=n)[0]]
-    table = np.zeros(geo.table_width, np.int32)
-    table[:8] = np.arange(1, 9)
-    table[-1] = slot + 1
-    toks = np.full((1, CHUNK), -1, np.int32)
-    toks[0, :n] = prompt
-    loop.cache, lg, *_ = loop.chunk_fn(
-        params, loop.cache, toks, np.zeros(1, np.int32), table[None],
-        np.ones(1, bool))
-    in_chunk = len(entered)
-    assert in_chunk > 0
-    rows = [np.asarray(lg[0, :n])]
-    seq = prompt + [int(np.argmax(rows[0][-1]))]
-    tables = np.zeros((3, geo.table_width), np.int32)
-    tables[slot] = table
-    tokens, positions = np.zeros(3, np.int32), np.zeros(3, np.int32)
-    tokens[slot], positions[slot] = seq[-1], n
-    loop.cache, lg, *_ = loop.decode_fn(params, loop.cache, tokens,
-                                        positions, tables,
-                                        np.arange(3) == slot)
-    assert len(entered) > in_chunk
-    rows.append(np.asarray(lg[slot:slot + 1]))
-    want = tfm.forward(params, jnp.asarray([seq]), cfg)[0]
-    assert _rel(np.concatenate(rows), want) < TOL
-
-
-def test_no_speculation_and_no_prefix_cache_over_state(tiny):
-    _, cfg, params = tiny
-    with pytest.raises(ValueError, match="roll the slot's state back"):
-        _loop(cfg, params, spec_tokens=2)
-    loop = _loop(cfg, params, prefix_cache=True)
-    assert loop.prefix is None and loop.prefill_fn is None
-    assert loop.batcher.state_rows
-    req = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=2)
-    loop.batcher.submit(req)
-    loop.batcher.admit()
-    assert loop.batcher.block_table(req, loop.geo.max_blocks)[-1] \
-        == req.slot + 1
-
-
-def test_the_scopes_reach_the_compiled_program(tiny):
-    """``state_space`` and ``expert_latent`` are in the lowered decode
-    program's op names, where the benchmark's readers find them."""
-    _, cfg, params = tiny
-    geo = kv_cache.with_rings(kv_cache.geometry(33, PAGE, 64), cfg, CHUNK, 2)
-    text = engine.make_decode_step(cfg, geo, max_batch=2).lower(
-        params, kv_cache.make_cache(cfg, geo), np.zeros(2, np.int32),
-        np.zeros(2, np.int32), np.zeros((2, geo.table_width), np.int32),
-        np.zeros(2, bool)).as_text(debug_info=True)
-    for scope in ("state_space", "expert_latent", "experts", "attention"):
-        assert f"/{scope}/" in text, scope
+    assert np.asarray(loop.cache["v"][1][served.SLOT + 1]).any()

@@ -9,9 +9,7 @@ and activations) against the tolerance the benchmark's configuration file
 states, and must fail it while the honest program passes.
 """
 import dataclasses
-import importlib.util
-import json
-import os
+import re
 
 import numpy as np
 import pytest
@@ -23,53 +21,55 @@ from horovod_tpu.models import transformer as tfm
 from horovod_tpu.serving import engine, kv_cache
 from horovod_tpu.serving import loop as serve_loop
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from . import served
 
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-olmoe = _load("benchmark/reference/olmoe.py", "olmoe_reference")
-CONFIG = json.load(open(os.path.join(ROOT, "benchmark", "configs",
-                                     "olmoe-1b-7b.json")))
-HP = dict(olmoe.hyper(CONFIG), n_head=4, top_k=2)
+NAME = "olmoe-1b-7b"
+olmoe = served.reference(NAME)
+CONFIG = served.file_config(NAME)
+HP = served.ENTRIES[NAME].hyper(CONFIG)
+_rel, _tokens = served.rel_max, served.ENTRIES[NAME].tokens
 
 
 def _tiny(dtype="float32", **overrides):
-    fields = dict(vocab_size=128, d_model=64, n_heads=4, n_layers=2, d_ff=32,
-                  d_expert=32, max_seq_len=256, n_experts=8, top_k=2,
-                  dtype=dtype, param_dtype=dtype)
-    fields.update(overrides)
-    return tfm.olmoe_1b_7b(**fields)
+    return tfm.olmoe_1b_7b(**{**served.OLMOE_TINY, "dtype": dtype,
+                              "param_dtype": dtype, **overrides})
 
 
-def _params(cfg, seed=0):
-    """Seeded weights with every norm's scale drawn around 1, as the
-    benchmark's runner makes them: a scale of one would hide a norm."""
-    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
-    rng = np.random.default_rng(seed)
-
-    def jitter(path, x):
-        if getattr(path[-1], "key", None) != "scale":
-            return x
-        return (1.0 + 0.1 * rng.standard_normal(x.shape)).astype(x.dtype)
-
-    return jax.tree_util.tree_map_with_path(jitter, params)
+_params = served.ENTRIES[NAME].params
 
 
-def _tokens(shape, seed=1):
-    return jnp.asarray(np.random.default_rng(seed).integers(0, 128, shape),
-                       jnp.int32)
+class TestContract(served.Contract):
+    name = NAME
 
 
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / np.abs(want).max())
+class TestCellPrograms(served.CellPrograms):
+    """``olmoe-serve-chat-over``'s two programs (the 512-token chunk fill and
+    the decode step; a cache of 4096 gets no padded prefill) at the cell's
+    geometry: 12 layers of 64 experts in bf16, every slot of 8 at the full
+    context. The decode step reads the cache through the paged kernel alone
+    (one call a layer; its temporaries were 0.28e9 with the gather)."""
+    name = NAME
+
+    def also_cell(self, built):
+        config, cfg = built.cell.config, built.cfg
+        assert cfg == tfm.olmoe_1b_7b(n_layers=config["num_hidden_layers"])
+        assert (cfg.d_model, cfg.ffn_width, cfg.n_experts, cfg.top_k) == (
+            config["hidden_size"], config["intermediate_size"],
+            config["num_experts"], config["num_experts_per_tok"])
+        assert built.geo.max_kv > 1024          # ServeLoop: chunk fills only
+
+    def also_program(self, built, program, p):
+        """No program makes a float32 copy of an expert tensor or a copy
+        shaped like the cache; nothing of the gathered pages' size."""
+        cfg, geo = built.cfg, built.geo
+        assert served.cache_materialisations(p.text, cfg, geo) == []
+        if program == "decode":
+            assert served.gathered(p.text, cfg, geo,
+                                   built.cell.max_batch) == []
+        expert = cfg.n_experts * cfg.d_model * cfg.ffn_width
+        for m in re.finditer(r" = f32\[([\d,]+)\]", p.text):
+            assert int(np.prod([int(d) for d in m.group(1).split(",")])) \
+                < expert, m.group(0)
 
 
 def test_catalog_widths_are_the_presets():
@@ -88,20 +88,9 @@ def test_catalog_widths_are_the_presets():
         specs, is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
 
 
-def test_forward_matches_reference():
-    cfg = _tiny()
-    params = _params(cfg)
-    tokens = _tokens((2, 24))
-    want, routes = olmoe.logits(olmoe.from_horovod_tpu(params), tokens, HP,
-                                with_routes=True)
-    assert _rel(tfm.forward(params, tokens, cfg), want) < 1e-5
-    assert routes.shape == (2, 2, 24, 2)
-
-
 def test_loss_and_gradients_match_reference():
-    cfg = _tiny()
-    params = _params(cfg)
-    tokens = _tokens((2, 25))
+    _, cfg, params = served.tiny(NAME)
+    tokens = _tokens(25)
     w = olmoe.from_horovod_tpu(params)
     loss, grads = jax.value_and_grad(
         lambda p: tfm.loss_fn(p, {"tokens": tokens}, cfg))(params)
@@ -174,8 +163,7 @@ def test_served_logits_match_reference(route):
     """Prefill (each way the loop can do it) then decode through the paged
     cache, RoPE by each slot's positions, against the reference's one full
     forward pass."""
-    cfg = _tiny()
-    params = _params(cfg)
+    _, cfg, params = served.tiny(NAME)
     geo = kv_cache.geometry(n_pages=9, page_size=8, max_context=64)
     prompt = np.random.default_rng(2).integers(0, 128, 37).tolist()
     rows, seq = _serve_rows(cfg, params, prompt, geo, route)
@@ -191,8 +179,8 @@ def test_routed_product(case):
     projection) against the explicit sum over one-hot routing masks: no
     token dropped, every token ``top_k`` pairs, the same under a
     permutation of the tokens; the dense dispatch a mesh takes agrees."""
-    cfg = _tiny()
-    layer = _params(cfg)["layers"][0]
+    _, cfg, params = served.tiny(NAME)
+    layer = params["layers"][0]
     x = jax.random.normal(jax.random.PRNGKey(5), (3, 10, cfg.d_model))
     w, top = tfm._route(x, layer, cfg)
     got, rows = tfm._moe_grouped(x, w, top, layer, cfg)
@@ -231,7 +219,7 @@ def _bf16_rel(sabotage):
     cfg = _tiny("bfloat16", d_model=128, d_ff=64, d_expert=64, n_layers=8,
                 n_experts=64, top_k=8)
     params = _params(cfg, seed=1)
-    tokens = _tokens((2, 48), seed=8)
+    tokens = _tokens(48, seed=8)
     want = olmoe.logits(olmoe.from_horovod_tpu(params), tokens,
                         dict(HP, top_k=8))
     if sabotage == "int8_weights":
@@ -268,8 +256,7 @@ def test_serve_loop_fills_a_wide_cache_by_chunks(monkeypatch):
     every prompt is chunk-filled, the greedy tokens are the reference's, and
     ``serve_stats()["moe"]`` counts what the programs routed."""
     monkeypatch.setattr(serve_loop, "PADDED_PREFILL_MAX_KV", 64)
-    cfg = _tiny()
-    params = _params(cfg)
+    _, cfg, params = served.tiny(NAME)
     geo = kv_cache.geometry(n_pages=65, page_size=8, max_context=128)
     loop = serve_loop.ServeLoop(params, cfg, geo=geo, max_batch=4,
                                 prefill_chunk=32)
@@ -307,9 +294,8 @@ def test_expert_mesh_takes_the_dense_dispatch_and_agrees():
     the ``expert`` axis: right, never silently something else."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    cfg = _tiny()
-    params = _params(cfg)
-    tokens = _tokens((4, 12))
+    _, cfg, params = served.tiny(NAME)
+    tokens = served.rowed(128, 1, rows=4)(12)
     mesh = Mesh(np.asarray(jax.devices()[:4]), ("expert",))
     specs = tfm.filter_specs(tfm.param_specs(cfg), mesh)
     sharded = jax.device_put(params, jax.tree.map(

@@ -19,11 +19,7 @@ rounding although the one carries rings, pages and state through chunk
 programs and decode steps and the other scans the sequence once.
 """
 import dataclasses
-import hashlib
-import importlib.util
-import json
-import os
-import sys
+import re
 
 import numpy as np
 import pytest
@@ -36,98 +32,134 @@ from horovod_tpu.serving import engine, kv_cache
 from horovod_tpu.serving import loop as serve_loop
 from horovod_tpu.serving.scheduler import Request
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from . import served
 
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    sys.path.insert(0, ROOT)
-    try:
-        spec.loader.exec_module(module)
-    finally:
-        sys.path.remove(ROOT)
-    return module
-
-
-reference = _load("benchmark/reference/phi4_flash.py", "phi4_reference")
-runner = _load("benchmark/runners/serve_sambay.py", "serve_sambay_runner")
-FILE = json.load(open(os.path.join(
-    ROOT, "benchmark", "configs", "phi-4-mini-flash-reasoning.json")))
-PAGE, CHUNK, WINDOW, TOL = 4, 8, 6, 2e-5
-KINDS = ["mamba", "window"] * 3 + ["mamba", "full"] + ["gmu", "cross"] * 2
-MEMORY, SHARED = 6, 7       # the last scan, the full layer
-
-
-def _config(**overrides):
-    """The configuration file with every size shrunk."""
-    config = json.loads(json.dumps(FILE))
-    config.update(hidden_size=32, num_attention_heads=4,
-                  num_key_value_heads=2, head_dim=8, intermediate_size=48,
-                  sliding_window=WINDOW, vocab_size=96,
-                  max_position_embeddings=256, num_hidden_layers=len(KINDS),
-                  layer_kinds=KINDS, memory_from=MEMORY, kv_from=SHARED)
-    config["model"].update(dtype="float32", param_dtype="float32")
-    config["assumed"]["mamba"].update(d_inner=64, d_state=4, dt_rank=2)
-    config["assumed"]["serve"]["chunk"] = CHUNK
-    config.update(overrides)
-    return config
+NAME = "phi-4-mini-flash-reasoning"
+FILE = served.file_config(NAME)
+runner, reference = served.runner(NAME), served.reference(NAME)
+PAGE, CHUNK, WINDOW = 4, 8, served.PHI4_WINDOW
+MEMORY, SHARED = served.PHI4_MEMORY, served.PHI4_SHARED
+TOL, _rel, _tokens = (getattr(served.ENTRIES[NAME], k)
+                      for k in ("tol", "rel", "tokens"))
+_FORWARD = served.forward
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    config = _config()
-    cfg = runner.model_config(config)
-    params = runner.make_params(cfg, jax.random.PRNGKey(0))
-    return config, cfg, params
+    return served.tiny(NAME)
 
 
-@pytest.fixture(scope="module")
-def compiled(tiny):
-    """The program's forward pass and the reference (its knobs an argument:
-    one program reads the sound model and every fault), compiled once."""
-    config, cfg, _ = tiny
-    hp = reference.hyper(config)
-    return (jax.jit(lambda p, t: tfm.forward(p, t, cfg)),
-            jax.jit(lambda p, t, kn: reference.logits(
-                reference.from_horovod_tpu(p), t, hp, kn=kn)),
-            lambda fault=None: reference.knobs(hp, fault))
+class TestContract(served.Contract):
+    name = NAME
+
+    def also_served(self, lp, n, rows):
+        """Through the loop's TWO fill programs: every logit row the server
+        emits (the fill's one row, the steps'). 30 is longer than window +
+        chunk: the ring of 16 cells wraps."""
+        assert rows.shape == (5, 96)
+
+    def also_reused(self, stats, lengths):
+        """The counters are host arithmetic on the calls' positions."""
+        attn, state = stats["attn"], stats["state"]
+        assert set(state) == {"scan_rows", "scan_bytes", "scan_tokens",
+                              "scan_resets", "kv_bytes", "calls"}
+        assert state["scan_resets"]["chunk"] == 5 * 4      # requests x scans
+        assert state["scan_resets"].get("decode", 0) == 0
+        assert state["scan_rows"]["decode"] == state["scan_tokens"]["decode"] \
+            == 4 * 5 * 4             # scans x requests x steps after the first
+        assert state["scan_bytes"]["decode"] == 2 * state["scan_rows"][
+            "decode"] * (3 * 64 * 4 + 4 * 64 * 4)
+        # The fill: every prompt position through the layers below the exit,
+        # one row a prompt through those above; a decode step all of it.
+        assert attn["fill_rows"]["chunk"] == sum(lengths)
+        assert attn["tail_rows"]["chunk"] == 5
+        assert attn["fill_rows"]["decode"] == attn["tail_rows"]["decode"] == 20
+        # Two layers read the rows of the one that owns them.
+        assert attn["kv_shared_rows"]["decode"] == 2 * attn["kv_full_rows"][
+            "decode"]
+        assert attn["kv_full_rows"]["chunk"] == sum(lengths)
+        assert attn["qk_full_pairs"]["chunk"] == 3 * sum(lengths)
+        assert state["kv_bytes"]["decode"] == 3 * attn["kv_full_rows"][
+            "decode"] * 2 * 16 * 4
+
+    def also_cache(self, cfg, geo):
+        """The shared pages are held and counted once."""
+        cache = kv_cache.make_cache(cfg, geo)
+        assert cache["k"][0].dtype == cache["k"][SHARED].dtype == jnp.float32
+
+    def also_over_state(self, lp, cfg, params):
+        """... and it runs a fill's chunks through the two programs the
+        engine made for it; padding of -1."""
+        assert not cfg.state_space and not cfg.delta_rule
+        assert lp.fill_exit == SHARED and lp.chunk_end_fn is not None
+        seen = []
+
+        def watching(name):
+            fn = getattr(lp, name)
+
+            def watched(params, cache, toks, *rest):
+                seen.append((name, np.asarray(toks)[0].tolist()))
+                return fn(params, cache, toks, *rest)
+
+            setattr(lp, name, watched)
+
+        watching("chunk_fn")
+        watching("chunk_end_fn")
+        req = Request(rid=0, prompt=list(range(1, 12)), max_new_tokens=2,
+                      arrival_t=0.0)
+        _, done = lp.run([req])
+        assert done[0].generated == served.greedy(cfg, params, done[0], 64)
+        assert seen == [("chunk_fn", list(range(1, 9))),
+                        ("chunk_end_fn", [9, 10, 11] + [-1] * 5)]
+        plain = serve_loop.ServeLoop(
+            tfm.init_params(jax.random.PRNGKey(0), tfm.tiny()), tfm.tiny(),
+            geo=kv_cache.geometry(33, PAGE, 64), max_batch=2)
+        assert plain.fill_exit is None and plain.chunk_end_fn is None
 
 
-def _tokens(n, seed=0):
-    return jnp.asarray(np.random.default_rng(seed).integers(0, 96, (1, n)),
-                       jnp.int32)
+class TestCellPrograms(served.CellPrograms):
+    """``phi4flash-serve-think-over``'s three programs (the 512-token chunk
+    that ends no prompt, the one that ends one, the decode step of 32 slots)
+    at the cell's geometry, the configuration UNCUT: nine selective-scan
+    layers on slot-owned rows (float32 ``[16, 5120]`` a slot), eight
+    differential window layers on rings, ONE full layer on pages of a
+    32,768-token context that seven more layers read, seven gated memory
+    units, a vocabulary of 200,064. Nothing holds a second copy of the shared
+    layer's pages or of a layer's state; the decode step reads the shared
+    pages through the paged kernel eight times and the rings eight."""
+    name = NAME
 
+    def also_cell(self, built):
+        cfg, geo = built.cfg, built.geo
+        assert (built.cell.max_batch, built.cell.chunk) == (32, 512)
+        assert engine.fill_exit(cfg) == built.cell.config["kv_from"] == 17
+        n_params = sum(x.size for x in jax.tree.leaves(built.params))
+        assert 3.85e9 < n_params < 3.855e9          # the file's reduced_why
+        assert kv_cache.cache_bytes(cfg, geo) == built.held - 2 * n_params
 
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
-
-
-def _loop(cfg, params, n_pages=65, max_batch=3, **kw):
-    return serve_loop.ServeLoop(
-        params, cfg, geo=kv_cache.geometry(n_pages, PAGE, 64),
-        max_batch=max_batch, prefill_chunk=CHUNK, **kw)
-
-
-def _greedy(params, cfg, req):
-    """What one full forward pass over the request's own tokens picks (the
-    sequence padded behind to one length: one compiled program)."""
-    seq = list(req.prompt) + list(req.generated)
-    lg = _FORWARD(cfg)(params, jnp.asarray([seq + [0] * (48 - len(seq))]))[0]
-    return [int(t) for t in
-            jnp.argmax(lg[len(req.prompt) - 1:len(seq) - 1], -1)]
-
-
-_PROGRAMS = {}
-
-
-def _FORWARD(cfg):
-    if cfg not in _PROGRAMS:
-        _PROGRAMS[cfg] = jax.jit(lambda p, t: tfm.forward(p, t, cfg))
-    return _PROGRAMS[cfg]
+    def also_program(self, built, program, p):
+        """The chunk that ends no prompt takes NO parameter above the exit
+        layer and returns no logits, and neither chunk makes ``[512, vocab]``
+        logits or a ``[512, 16, 5120]`` float32 history of the state."""
+        assert not re.search(r"f32\[(\d+,)*512,(\d+,)*(16,5120|5120,16)\]",
+                             p.text), program
+        assert not re.search(r"\[(1,)?512,200064\]", p.text), program
+        n_args = len(jax.tree.leaves(p.lowered.args_info))
+        kept = p.text[p.text.index("\nENTRY "):].count(" parameter(")
+        n_above = len(jax.tree.leaves(built.params["layers"][17 + 1:]))
+        if program == "chunk":
+            # No weight above the exit layer is an argument of the compiled
+            # program (a gated memory unit's first matrix is the one [2560,
+            # 5120] in the model), and there are no logits.
+            assert kept <= n_args - n_above - 2, (kept, n_args, n_above)
+            assert "bf16[2560,5120]" not in p.text
+            assert p.fresh < 4096, program          # a tuple's pointers
+            assert not re.search(r",200064\]", p.text)
+        elif program == "chunk_end":
+            assert kept == n_args and "bf16[2560,5120]" in p.text
+        else:       # a second copy of a layer's slots would be this large
+            state = 4 * 32 * 16 * 5120
+            assert p.memory.temp_size_in_bytes < 8 * state + 2 * 32 * 200064 * 4
 
 
 # ---- the file ---------------------------------------------------------------
@@ -169,28 +201,11 @@ def test_the_file_keeps_the_published_widths_and_counts():
     assert FILE["memory_from"] == 16
 
 
-def test_what_stood_builds_what_it_built():
-    """The newest standing kind (gated delta-rule linear attention at its
-    test's tiny size) makes the tree, the parameters' bits and the logits it
-    made at the commit before this PR; ``tests/test_solar_open2.py`` and the
-    files it names pin the seven before it the same way. None has a fill that
-    leaves the stack."""
-    solar = _load("tests/test_solar_open2.py", "standing_solar")
-    cfg = solar.runner.model_config(solar._config())
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    leaves = jax.tree_util.tree_leaves_with_path(params)
-    shapes = hashlib.sha256(";".join(
-        f"{jax.tree_util.keystr(p)}:{x.shape}:{x.dtype}"
-        for p, x in leaves).encode()).hexdigest()[:16]
-    bits = hashlib.sha256(b"".join(
-        np.asarray(x).tobytes() for _, x in leaves)).hexdigest()[:16]
-    tokens = jnp.asarray(np.random.default_rng(1).integers(
-        0, cfg.vocab_size, (1, 24)), jnp.int32)
-    logits = np.asarray(tfm.forward(params, tokens, cfg), np.float64)
-    assert (shapes, bits) == ("43825c9cd7bf06d0", "a0094d1a7b67ca3f")
-    assert logits.sum() == pytest.approx(56.85422448441386, rel=1e-6)
-    assert np.abs(logits).sum() == pytest.approx(1842.5716400817037, rel=1e-6)
-    assert engine.fill_exit(cfg) is None
+def test_no_standing_kind_leaves_the_stack():
+    """None of the kinds that stood before this one has a fill that leaves
+    the stack (``Contract.test_what_stood_builds_what_it_built`` pins what
+    each builds)."""
+    assert engine.fill_exit(served.tiny_config("solar-open2-250b")[1]) is None
     assert engine.fill_exit(tfm.tiny()) is None
 
 
@@ -343,100 +358,32 @@ def test_not_differential_is_todays_attention(tiny):
     assert q.shape == (1, 11, 4, 8) and v.shape == (1, 11, 2, 8)
 
 
-def test_forward_against_the_reference(tiny, compiled):
-    _, cfg, params = tiny
-    forward, ref, knobs = compiled
+def test_forward_with_remat_against_the_reference(tiny):
+    config, cfg, params = tiny
     tokens = _tokens(40)
-    want = ref(params, tokens, knobs())
-    assert _rel(forward(params, tokens), want) < TOL
     remat = dataclasses.replace(cfg, remat=True)
-    assert _rel(_FORWARD(remat)(params, tokens), want) < TOL
+    assert _rel(_FORWARD(remat)(params, tokens),
+                served.want(NAME, config, params, tokens)) < TOL
 
 
-@pytest.mark.parametrize("fault", reference.FAULTS)
-def test_a_reference_fault_moves_the_logits(tiny, compiled, fault):
-    """Each thing the reference can get wrong (lambda, the pairs' norm, the
-    ``1 - lambda_init`` scale, the window's width, whose keys and values a
-    cross layer reads, whose memory a GMU gates and where it is taken, what
-    is carried, the biases, the skip) moves its logits a thousand times
-    further from the program's than rounding does."""
-    _, cfg, params = tiny
-    forward, ref, knobs = compiled
-    tokens = _tokens(40)
-    assert _rel(forward(params, tokens),
-                ref(params, tokens, knobs(fault))) > 1000 * TOL
-
-
-def test_the_program_names_its_memory_and_its_shared_cache(tiny, compiled):
+def test_the_program_names_its_memory_and_its_shared_cache(tiny):
     """The program with ``memory_from`` the scan before the last, and with the
     cross layers on the last window layer's keys and values, is the
     reference's planted fault of that name: the named layer is what is
     read."""
     config, cfg, params = tiny
-    _, ref, knobs = compiled
     tokens = _tokens(40)
     for key, value, fault in (
             ("memory_from", MEMORY - 2, "memory_from_an_earlier_layer"),
             ("kv_from", SHARED - 2, "kv_from_a_window_layer")):
-        other = runner.model_config(_config(**{key: value}))
-        want = ref(params, tokens, knobs(fault))
+        other = served.tiny_config(NAME, **{key: value})[1]
+        want = served.want(NAME, config, params, tokens, fault=fault)
         assert _rel(_FORWARD(other)(params, tokens), want) < TOL, key
     assert cfg.hands_memory(MEMORY) and not cfg.hands_memory(MEMORY - 2)
     assert cfg.shares_kv(SHARED) and not cfg.shares_kv(SHARED - 2)
 
 
-# ---- the cache --------------------------------------------------------------
-
-def test_cache_shapes_by_layer_kind(tiny):
-    """Rings for the window layers, pages for the ONE full layer, state rows
-    for the scans, and nothing for a layer that attends another's pages or
-    gates another's memory: the shared pages are held and counted once."""
-    _, cfg, _ = tiny
-    geo = kv_cache.with_rings(kv_cache.geometry(65, PAGE, 64), cfg, CHUNK, 3)
-    assert (geo.ring_blocks, geo.ring_pages, geo.state_rows,
-            geo.table_width) == (4, 13, 4, 16 + 4 + 1)
-    shapes = [kv_cache.layer_shapes(cfg, geo, li) for li in range(len(KINDS))]
-    assert shapes[0] == ((4, 3, 64), (4, 4, 64))          # tail, state
-    assert shapes[1] == ((13, PAGE, 16),) * 2             # a ring
-    assert shapes[SHARED] == ((65, PAGE, 16),) * 2        # the pages
-    assert all(s == (None, None) for s in shapes[SHARED + 1:])
-    cache = kv_cache.make_cache(cfg, geo)
-    assert cache["v"][0].dtype == jnp.float32
-    assert cache["k"][0].dtype == cache["k"][SHARED].dtype == jnp.float32
-    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
-    assert kv_cache.cache_bytes(cfg, geo) == held == 4 * (
-        4 * 4 * (3 * 64 + 4 * 64) + 3 * 2 * 13 * PAGE * 16
-        + 2 * 65 * PAGE * 16)
-
-
 # ---- the programs -----------------------------------------------------------
-
-@pytest.fixture(scope="module")
-def programs(tiny):
-    """One loop's compiled programs and cache for the cases below: each
-    starts its prompt in the rows the case before it left."""
-    _, cfg, params = tiny
-    return _loop(cfg, params)
-
-
-@pytest.mark.parametrize("n", [5, 19, 30])
-def test_chunks_then_decode_against_one_forward(tiny, programs, n):
-    """A prompt filled in chunks of 8 (padding -1) through the loop's TWO
-    fill programs and decoded four steps, in a slot other than 0 and on
-    rings and rows that are dirty from the second case on, against one full
-    ``forward``: every logit row the server emits (the fill's one row, the
-    steps'). 30 is longer than window + chunk: the ring of 16 cells wraps."""
-    _, cfg, params = tiny
-    seq, rows = runner.served_rows(
-        programs, params, [int(t) for t in _tokens(n, seed=n)[0]],
-        np.arange(1, 10), 2)
-    want = _FORWARD(cfg)(params, jnp.asarray([seq + [0] * (48 - len(seq))]))[0]
-    assert rows.shape == (5, 96)
-    assert _rel(rows, want[n - 1:len(seq)]) < TOL
-    # The other slots' rows were never touched.
-    assert not np.asarray(programs.cache["v"][0][1]).any()
-    assert np.asarray(programs.cache["v"][0][3]).any()
-
 
 def test_the_fill_leaves_the_stack(tiny):
     """The rows the server emits are the full stack's: the chunk that ends a
@@ -488,118 +435,3 @@ def test_the_fill_leaves_the_stack(tiny):
     with pytest.raises(ValueError, match="runs the whole stack"):
         engine.make_chunk_step(tfm.tiny(), kv_cache.geometry(9, PAGE, 16),
                                q_len=4, ends=False)
-
-
-def test_a_reused_slot_gives_the_logits_of_a_fresh_run(tiny):
-    """Five requests through three slots: the later ones start in rings and
-    rows the earlier ones left dirty, and generate what a fresh model
-    generates; the counters are host arithmetic on the calls' positions."""
-    _, cfg, params = tiny
-    loop = _loop(cfg, params)
-    loop.warmup()
-    rng = np.random.default_rng(0)
-    lengths = [9 + 5 * i for i in range(5)]            # + 5 new: under 48
-    reqs = [Request(rid=i, prompt=rng.integers(0, 96, n).tolist(),
-                    max_new_tokens=5, arrival_t=0.001 * (i + 1))
-            for i, n in enumerate(lengths)]
-    _, done = loop.run(reqs)
-    assert len(done) == 5
-    for r in done:
-        assert r.generated == _greedy(params, cfg, r), r.rid
-    stats = serve_loop.serve_stats()
-    attn, state = stats["attn"], stats["state"]
-    assert set(state) == {"scan_rows", "scan_bytes", "scan_tokens",
-                          "scan_resets", "kv_bytes", "calls"}
-    assert state["scan_resets"]["chunk"] == 5 * 4         # requests x scans
-    assert state["scan_resets"].get("decode", 0) == 0
-    assert state["scan_rows"]["decode"] == state["scan_tokens"]["decode"] \
-        == 4 * 5 * 4             # scans x requests x steps after the first
-    assert state["scan_bytes"]["decode"] == 2 * state["scan_rows"][
-        "decode"] * (3 * 64 * 4 + 4 * 64 * 4)
-    # The fill: every prompt position through the layers below the exit, one
-    # row a prompt through those above; a decode step all of it.
-    assert attn["fill_rows"]["chunk"] == sum(lengths)
-    assert attn["tail_rows"]["chunk"] == 5
-    assert attn["fill_rows"]["decode"] == attn["tail_rows"]["decode"] == 20
-    # Two layers read the rows of the one that owns them.
-    assert attn["kv_shared_rows"]["decode"] == 2 * attn["kv_full_rows"][
-        "decode"]
-    assert attn["kv_full_rows"]["chunk"] == sum(lengths)
-    assert attn["qk_full_pairs"]["chunk"] == 3 * sum(lengths)
-    assert state["kv_bytes"]["decode"] == 3 * attn["kv_full_rows"][
-        "decode"] * 2 * 16 * 4
-
-
-def test_a_preempted_request_replays_from_a_zeroed_row(tiny):
-    """Too few pages for three growing requests: the youngest is preempted,
-    its pages freed, and its replay (prompt + generated, from position 0)
-    finds its row zeroed: every request generates a fresh run's tokens."""
-    _, cfg, params = tiny
-    loop = _loop(cfg, params, n_pages=14)
-    rng = np.random.default_rng(1)
-    reqs = [Request(rid=i, prompt=rng.integers(0, 96, 10).tolist(),
-                    max_new_tokens=12, arrival_t=0.001 * (i + 1))
-            for i in range(3)]
-    summary, done = loop.run(reqs)
-    assert summary["preemptions"] > 0
-    for r in done:
-        assert r.generated == _greedy(params, cfg, r), r.rid
-
-
-def test_no_speculation_no_prefix_cache_and_negative_padding(tiny):
-    """The loop reads "has a layer that carries state" and not the kind: no
-    prefix cache, no speculation, padding of -1; and it runs a fill's chunks
-    through the two programs the engine made for it."""
-    _, cfg, params = tiny
-    assert not cfg.state_space and not cfg.delta_rule and cfg.recurrent
-    with pytest.raises(ValueError, match="roll the slot's state back"):
-        _loop(cfg, params, spec_tokens=2)
-    loop = _loop(cfg, params, prefix_cache=True)
-    assert loop.has_state and loop.prefix is None and loop.spec_fn is None
-    assert loop.fill_exit == SHARED and loop.chunk_end_fn is not None
-    seen = []
-
-    def watching(name):
-        fn = getattr(loop, name)
-
-        def watched(params, cache, toks, *rest):
-            seen.append((name, np.asarray(toks)[0].tolist()))
-            return fn(params, cache, toks, *rest)
-
-        setattr(loop, name, watched)
-
-    watching("chunk_fn")
-    watching("chunk_end_fn")
-    req = Request(rid=0, prompt=list(range(1, 12)), max_new_tokens=2,
-                  arrival_t=0.0)
-    _, done = loop.run([req])
-    assert done[0].generated == _greedy(params, cfg, done[0])
-    assert seen == [("chunk_fn", list(range(1, 9))),
-                    ("chunk_end_fn", [9, 10, 11] + [-1] * 5)]
-    plain = serve_loop.ServeLoop(
-        tfm.init_params(jax.random.PRNGKey(0), tfm.tiny()), tfm.tiny(),
-        geo=kv_cache.geometry(33, PAGE, 64), max_batch=2)
-    assert plain.fill_exit is None and plain.chunk_end_fn is None
-
-
-def test_the_scopes_reach_the_compiled_programs(tiny):
-    """``state_space``, ``attention`` and ``gated_memory`` are in the lowered
-    decode program's op names, where the benchmark's readers find them; the
-    chunk that ends no prompt has no gated memory unit at all."""
-    _, cfg, params = tiny
-    geo = kv_cache.with_rings(kv_cache.geometry(33, PAGE, 64), cfg, CHUNK, 2)
-    cache = kv_cache.make_cache(cfg, geo)
-
-    def slots(b, *q):
-        return (np.zeros((b, *q), np.int32), np.zeros(b, np.int32),
-                np.zeros((b, geo.table_width), np.int32), np.zeros(b, bool))
-
-    for fn, args, scopes in (
-            (engine.make_decode_step(cfg, geo, max_batch=2), slots(2),
-             {"state_space": True, "attention": True, "gated_memory": True}),
-            (engine.make_chunk_step(cfg, geo, q_len=CHUNK, ends=False),
-             slots(1, CHUNK),
-             {"state_space": True, "attention": True, "gated_memory": False})):
-        text = fn.lower(params, cache, *args).as_text(debug_info=True)
-        for scope, there in scopes.items():
-            assert (f"/{scope}/" in text) == there, scope

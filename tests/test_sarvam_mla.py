@@ -17,10 +17,7 @@ expanded form with no cache.
 """
 import dataclasses
 import functools
-import hashlib
-import importlib.util
-import json
-import os
+import re
 
 import numpy as np
 import pytest
@@ -34,84 +31,15 @@ from horovod_tpu.serving import engine, kv_cache
 from horovod_tpu.serving import loop as serve_loop
 from horovod_tpu.serving.scheduler import Request
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from . import served
 
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-reference = _load("benchmark/reference/sarvam_mla.py", "sarvam_reference")
-runner = _load("benchmark/runners/serve_latent.py", "serve_latent_runner")
-FILE = json.load(open(os.path.join(ROOT, "benchmark", "configs",
-                                   "sarvam-105b.json")))
-PAGE, CHUNK, TOL = 4, 8, 2e-4
-
-
-def _config(**overrides):
-    """The configuration file with every size shrunk."""
-    config = dict(FILE)
-    config.update(
-        hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
-        num_attention_heads=4, kv_lora_rank=16, qk_nope_head_dim=16,
-        qk_rope_head_dim=8, q_head_dim=24, head_dim=24, v_head_dim=16,
-        num_experts_published=16, experts_held=[4, 4], num_experts=4,
-        num_experts_per_tok=4, vocab_size=128, max_position_embeddings=256,
-        rope_scaling=dict(FILE["rope_scaling"],
-                          original_max_position_embeddings=16))
-    config.update(overrides)
-    return config
-
-
-def _cfg(config, **overrides):
-    return dataclasses.replace(runner.model_config(config), dtype="float32",
-                               param_dtype="float32", **overrides)
-
-
-def _params(cfg, seed=0):
-    """Seeded weights as the benchmark's runner draws them: norm scales
-    around 1 and the router's selection bias around 0, so that none can be
-    left out unseen."""
-    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
-    rng = np.random.default_rng(seed)
-
-    def jitter(path, x):
-        name = getattr(path[-1], "key", None)
-        if name == "scale":
-            return (1.0 + 0.1 * rng.standard_normal(x.shape)).astype(x.dtype)
-        if name == "router_bias":
-            return (0.1 * rng.standard_normal(x.shape)).astype(x.dtype)
-        return x
-
-    return jax.tree_util.tree_map_with_path(jitter, params)
-
-
-def _tokens(n, seed=1):
-    return np.random.default_rng(seed).integers(0, 128, n).tolist()
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / np.abs(want).max())
-
-
-def _want(config, params, tokens, fault=None, **kw):
-    hp = reference.hyper(config)
-    return reference.logits(
-        reference.from_horovod_tpu(params), jnp.asarray([tokens], jnp.int32),
-        hp, kn=reference.knobs(hp, fault), **kw)
-
-
-def _loop(cfg, params, max_batch=2, n_pages=64, context=128, **kw):
-    geo = kv_cache.geometry(n_pages, PAGE, context)
-    kw.setdefault("prefill_chunk", CHUNK)
-    return serve_loop.ServeLoop(params, cfg, geo=geo, max_batch=max_batch,
-                                **kw)
-
+NAME = "sarvam-105b"
+FILE = served.file_config(NAME)
+runner, reference = served.runner(NAME), served.reference(NAME)
+PAGE, CHUNK = 4, 8
+TOL, _rel, _tokens = (getattr(served.ENTRIES[NAME], k)
+                      for k in ("tol", "rel", "tokens"))
+_want = functools.partial(served.want, NAME)
 
 # A latent wide enough for its head dims (64 over 16 + 16) that a chunk of 32
 # queries is cheaper expanded (``pallas_latent.expands``: from 22 queries on).
@@ -120,9 +48,7 @@ WIDE, WIDE_CHUNK = dict(kv_lora_rank=64), 32
 
 @pytest.fixture(scope="module")
 def tiny():
-    config = _config()
-    cfg = _cfg(config)
-    return config, cfg, _params(cfg)
+    return served.tiny(NAME)
 
 
 @pytest.fixture(params=["plain", "kernel"])
@@ -132,6 +58,43 @@ def tier(request, monkeypatch):
     monkeypatch.setattr(engine, "latent_kernels",
                         lambda *a: request.param == "kernel")
     return request.param
+
+
+class TestContract(served.Contract):
+    name = NAME
+
+    def also_cache(self, cfg, geo):
+        """A full latent layer has no scorer cache, and no ring."""
+        assert all(v is None for v in kv_cache.make_cache(cfg, geo)["v"])
+        assert kv_cache.geometry(64, PAGE, 128) == geo
+        with pytest.raises(ValueError, match="q_rank"):
+            tfm.LatentAttention(4, 0, 16, 16, 8, 16, index_topk=8)
+
+
+class TestCellPrograms(served.CellPrograms):
+    """``sarvam-serve-longdoc-over``: five layers of full-context latent
+    attention, 16 slots of a 32k context. The chip's compiler takes both
+    forms' kernels at the published widths (64 heads over one 640-lane row a
+    token): ``paged_latent_attention`` for one query a slot in the decode
+    step, ``paged_latent_attention_expanded`` for the chunk's 512 queries
+    (inside its VMEM limit), each under a name the benchmark's readers match
+    (``^paged_latent_attention``), once a layer."""
+    name = NAME
+
+    def also_cell(self, built):
+        assert all(v is None for v in built.cache["v"])     # no scorer cache
+        assert kv_cache.cache_bytes(built.cfg, built.geo) \
+            == 5 * 32769 * 16 * 640 * 2
+
+    def also_program(self, built, program, p):
+        """The absorbed form has left nothing in the chunk: no query ``[..,
+        64, 640]``, no output in the latent ``[.., 64, 512]``."""
+        a = built.cfg.attn_of(0)
+        absorbed = {f"{a.n_heads},{a.row_width}", f"{a.n_heads},{a.kv_rank}"}
+        if program == "chunk":
+            for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", p.text):
+                assert ",".join(m.group(1).split(",")[-2:]) not in absorbed, \
+                    m.group(0)
 
 
 # ---- the description ------------------------------------------------------
@@ -176,44 +139,7 @@ def test_a_file_whose_factors_disagree_is_refused():
         runner.model_config(dict(FILE, softmax_scale_mult=1.0))
 
 
-def test_a_full_latent_layer_has_no_scorer_cache(tiny):
-    _, cfg, _ = tiny
-    geo = kv_cache.geometry(64, PAGE, 128)
-    for li in range(cfg.n_layers):
-        rows, keys = kv_cache.layer_shapes(cfg, geo, li)
-        assert rows == (64, PAGE, 128) and keys is None   # 16 + 8 -> 128
-    cache = kv_cache.make_cache(cfg, geo)
-    assert all(v is None for v in cache["v"])
-    assert kv_cache.with_rings(geo, cfg, CHUNK, 2) == geo   # no ring
-    with pytest.raises(ValueError, match="q_rank"):
-        tfm.LatentAttention(4, 0, 16, 16, 8, 16, index_topk=8)
-
-
 # ---- program against reference --------------------------------------------
-
-@pytest.mark.parametrize("n", [5, 37])
-def test_forward_is_the_expanded_reference(tiny, n):
-    """The absorbed layer (``q_rank`` 0, the query norm, YaRN, ``m^2``)
-    through ``transformer.forward`` against the reference's expanded form."""
-    config, cfg, params = tiny
-    tokens = _tokens(n)
-    got = tfm.forward(params, jnp.asarray([tokens]), cfg)
-    want, routes = _want(config, params, tokens, with_routes=True)
-    assert _rel(got, want) < TOL
-    assert routes.shape == (4, 1, n, 4)
-
-
-@pytest.mark.parametrize("fault", ["mscale_left_out", "yarn_not_interpolated",
-                                   "q_norm_left_out", "rope_key_left_out",
-                                   "shared_expert_left_out"])
-def test_a_fault_in_the_reference_moves_the_logits(tiny, fault):
-    """Tight enough that leaving out ``m^2``, the query norm, YaRN's
-    interpolation, the shared rotated key or the shared expert fails."""
-    config, cfg, params = tiny
-    tokens = _tokens(37)
-    got = tfm.forward(params, jnp.asarray([tokens]), cfg)
-    assert _rel(got, _want(config, params, tokens, fault)) > 100 * TOL
-
 
 def test_the_selection_bias_chooses(tiny):
     config, cfg, params = tiny
@@ -236,18 +162,18 @@ def test_chunks_then_decode_is_one_forward(tiny, tier, n, wide, monkeypatch):
     _, cfg, params = tiny
     kw = {}
     if wide:
-        cfg = _cfg(_config(**WIDE))
-        params, kw = _params(cfg), dict(prefill_chunk=WIDE_CHUNK)
+        _, cfg, params = served.tiny(NAME, **WIDE)
+        kw = dict(prefill_chunk=WIDE_CHUNK)
     traced = []
     for name in ("paged_latent_attention", "paged_latent_attention_expanded"):
         def spy(*args, name=name, fn=getattr(pallas_latent, name), **kwargs):
             traced.append((name, args[0].shape[1]))
             return fn(*args, **kwargs)
         monkeypatch.setattr(pallas_latent, name, spy)
-    served = _load("benchmark/runners/serve_layers.py", "serve_layers_runner")
-    lp = _loop(cfg, params, **kw)
-    pages = np.arange(1, 2 + (n + served.N_DECODE) // PAGE)
-    seq, rows, tops, selected = served.served_rows(lp, params, _tokens(n),
+    layers = served.load("benchmark/runners/serve_layers.py")
+    lp = served.loop(NAME, model=(cfg, params), **kw)
+    pages = np.arange(1, 2 + (n + layers.N_DECODE) // PAGE)
+    seq, rows, tops, selected = layers.served_rows(lp, params, _tokens(n),
                                                    pages)
     assert selected is None and tops.shape == (4, len(seq), 4)
     full = tfm.forward(params, jnp.asarray([seq]), cfg)
@@ -386,33 +312,17 @@ def _requests(lengths, new=6, seed=3):
             for i, n in enumerate(lengths)]
 
 
-def _greedy(cfg, params, prompt, new, width=48):
-    """Sequential greedy decoding by one compiled ``forward`` over a padded
-    window (causal: what follows a position does not reach it)."""
-    forward = _greedy.compiled.setdefault(id(params), jax.jit(
-        lambda tokens: tfm.forward(params, tokens, cfg)))
-    seq = list(prompt)
-    for _ in range(new):
-        padded = np.zeros((1, width), np.int32)
-        padded[0, :len(seq)] = seq
-        seq.append(int(jnp.argmax(forward(padded)[0, len(seq) - 1])))
-    return seq[len(prompt):]
-
-
-_greedy.compiled = {}
-
-
 def test_a_slot_reused_after_another_request(tiny, tier):
     """Five requests through two slots: every slot serves several, on pages
     others freed; each chain is sequential greedy decoding."""
     _, cfg, params = tiny
-    lp = _loop(cfg, params, prefix_cache=False)
+    lp = served.loop(NAME, prefix_cache=False)      # steered: its own
     lp.warmup()
     reqs = _requests((21, 9, 30, 13, 26))
     _, finished = lp.run(reqs)
     assert len(finished) == 5
     for r in finished:
-        assert r.generated == _greedy(cfg, params, r.prompt, 6), r.rid
+        assert r.generated == served.greedy(cfg, params, r, 48), r.rid
     attn = serve_loop._LAST_STATS["attn"]
     assert attn["kv_latent_rows"]["chunk"] > 0
     assert attn["qk_latent_pairs"]["decode"] \
@@ -424,7 +334,7 @@ def test_a_prefix_hit_over_latent_pages(tiny, tier):
     second request that shares 16 tokens (four pages) hits them, fills only
     its own suffix, and emits what a fresh run emits."""
     _, cfg, params = tiny
-    lp = _loop(cfg, params)
+    lp = served.loop(NAME)                          # steered: its own
     assert lp.prefix is not None
     lp.warmup()
     shared = _tokens(16, 9)
@@ -436,13 +346,14 @@ def test_a_prefix_hit_over_latent_pages(tiny, tier):
     _, finished = lp.run([second])
     assert lp.batcher.prefix_hit_ratio() > 0
     assert lp.prefix.stats["hit_tokens"] == 16
-    assert finished[0].generated == _greedy(cfg, params, second.prompt, 5)
+    assert finished[0].generated == served.greedy(cfg, params, finished[0],
+                                                  48)
 
 
 def test_counters_are_host_arithmetic(tiny):
     """``kv_latent_rows`` / ``qk_latent_pairs`` of one fill and one step."""
     _, cfg, params = tiny
-    lp = _loop(cfg, params)
+    lp = served.loop(NAME, fresh=True)
     lp._count("chunk", np.arange(8, 16)[None] + 1)
     lp._count("decode", np.asarray([20, 3])[:, None] + 1)
     s = lp.tally["attn"]
@@ -488,10 +399,10 @@ def test_the_form_follows_the_queries_and_the_widths(monkeypatch):
     assert kernels_of(512, True) == ["paged_latent_attention_expanded"]
     assert kernels_of(512, False) == []
     # The loop's counter, on a latent wide enough for a chunk of 32.
-    cfg = _cfg(_config(**WIDE))
+    _, cfg, params = served.tiny(NAME, **WIDE)
     for kernels, chunk in ((True, 2 * cfg.n_layers), (False, 0)):
         monkeypatch.setattr(engine, "latent_kernels", lambda *_: kernels)
-        lp = _loop(cfg, _params(cfg), prefill_chunk=WIDE_CHUNK)
+        lp = served.loop(NAME, model=(cfg, params), prefill_chunk=WIDE_CHUNK)
         for start in (0, 32):
             lp._count("chunk", np.arange(start, start + 32)[None] + 1)
         lp._count("decode", np.asarray([70, 3])[:, None] + 1)
@@ -535,57 +446,23 @@ def test_the_four_shares_add_up_to_the_uncut_layer(tiny):
 
 # ---- what stands ------------------------------------------------------------
 
-def _standing():
-    """Every configuration that stood before this kind was added, at its
-    test's tiny size."""
-    dots3 = _load("tests/test_dots3.py", "standing_dots3")
-    laguna = _load("tests/test_laguna.py", "standing_laguna")
-    nemotron = _load("tests/test_nemotron_h.py", "standing_nemotron")
-    olmoe = _load("tests/test_olmoe.py", "standing_olmoe")
-    return {
-        "gpt2": lambda: dataclasses.replace(tfm.tiny(), dtype="float32"),
-        "gpt2-moe": lambda: dataclasses.replace(tfm.tiny(n_experts=4),
-                                                dtype="float32"),
-        "olmoe": olmoe._tiny,
-        "dots3": lambda: dots3._cfg(dots3._config()),
-        "laguna": lambda: laguna._cfg(laguna._config()),
-        "nemotron": lambda: nemotron.runner.model_config(nemotron._config()),
-    }
-
-
-# Read at the commit before this kind was added (and again after it, where
-# the parameters' and the logits' bits were the same): the tree's names,
-# shapes and dtypes; the parameters' bits; the logits' sum and absolute sum.
+# The two plain kinds (the served kinds' digests are their entries' ``stood``:
+# ``Contract.test_what_stood_builds_what_it_built``). Read at the commit
+# before this kind was added: the tree's names, shapes and dtypes; the
+# parameters' bits; the logits' sum and absolute sum.
 BEFORE = {
-    "gpt2": ("cc4cd16b2fa77829", "cbd6bb0daeef0e3f",
+    "gpt2": (0, "cc4cd16b2fa77829", "cbd6bb0daeef0e3f",
              26.86668354183348, 830.3952171302299),
-    "gpt2-moe": ("f1e5ca5f9601c02d", "1618fc16a96a3bb7",
+    "gpt2-moe": (4, "f1e5ca5f9601c02d", "1618fc16a96a3bb7",
                  -8.571801105956183, 815.3737999860223),
-    "olmoe": ("45ca8ddf957b3c67", "6f28cb44274d41ba",
-              163.5613178020576, 2485.642097896314),
-    "dots3": ("b51e92cddb1ebdc2", "9a4fb4d9808e075e",
-              54.04719592873607, 2490.710302407021),
-    "laguna": ("5d59e605ac0a288f", "c32b8d2342a1026b",
-               -0.39590076345484704, 2489.4818965856684),
-    "nemotron": ("33c483e0d514437d", "8bb489ccee542fdf",
-                 -76.91667951270938, 1802.9259913302958),
 }
 
 
 @pytest.mark.parametrize("name", sorted(BEFORE))
 def test_what_stands_builds_what_it_built(name):
-    cfg = _standing()[name]()
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    leaves = jax.tree_util.tree_leaves_with_path(params)
-    shapes = hashlib.sha256(";".join(
-        f"{jax.tree_util.keystr(p)}:{x.shape}:{x.dtype}"
-        for p, x in leaves).encode()).hexdigest()[:16]
-    bits = hashlib.sha256(b"".join(
-        np.asarray(x).tobytes() for _, x in leaves)).hexdigest()[:16]
-    tokens = jnp.asarray(np.random.default_rng(1).integers(
-        0, cfg.vocab_size, (1, 24)), jnp.int32)
-    logits = np.asarray(tfm.forward(params, tokens, cfg), np.float64)
-    want = BEFORE[name]
-    assert (shapes, bits) == want[:2]
-    assert logits.sum() == pytest.approx(want[2], rel=1e-6, abs=1e-6)
-    assert np.abs(logits).sum() == pytest.approx(want[3], rel=1e-6)
+    n_experts, *want = BEFORE[name]
+    shapes, bits, total, absolute = served.digest(dataclasses.replace(
+        tfm.tiny(n_experts=n_experts), dtype="float32"))
+    assert [shapes, bits] == want[:2]
+    assert total == pytest.approx(want[2], rel=1e-6, abs=1e-6)
+    assert absolute == pytest.approx(want[3], rel=1e-6)

@@ -43,15 +43,14 @@ from horovod_tpu.serving.loop import (ServeLoop,  # noqa: E402
                                       poisson_requests, serve_stats)
 from horovod_tpu.serving.scheduler import Request  # noqa: E402
 
+from .served import GPT2_TINY  # noqa: E402
+
 pytestmark = pytest.mark.serve
 
 
 def _cfg(**kw):
-    """float32 so logits parity is tight (tiny() is bf16)."""
-    base = dict(vocab_size=64, d_model=32, n_heads=4, n_layers=2,
-                d_ff=64, max_seq_len=64, dtype="float32")
-    base.update(kw)
-    return tfm.TransformerConfig(**base)
+    """``gpt2-large``'s tiny stand-in (``served.GPT2_TINY``)."""
+    return tfm.TransformerConfig(**{**GPT2_TINY, **kw})
 
 
 def _ref_logits(params, cfg, seq):

@@ -18,11 +18,7 @@ through chunk programs and decode steps and the other scans the sequence once.
 """
 import dataclasses
 import functools
-import hashlib
-import importlib.util
-import json
-import os
-import sys
+import re
 
 import numpy as np
 import pytest
@@ -35,68 +31,106 @@ from horovod_tpu.serving import engine, kv_cache
 from horovod_tpu.serving import loop as serve_loop
 from horovod_tpu.serving.scheduler import Request
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from . import served
 
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, path))
-    module = importlib.util.module_from_spec(spec)
-    sys.path.insert(0, ROOT)
-    try:
-        spec.loader.exec_module(module)
-    finally:
-        sys.path.remove(ROOT)
-    return module
-
-
-reference = _load("benchmark/reference/solar_open2.py", "solar_reference")
-runner = _load("benchmark/runners/serve_linear.py", "serve_linear_runner")
-FILE = json.load(open(os.path.join(ROOT, "benchmark", "configs",
-                                   "solar-open2-250b.json")))
-PAGE, CHUNK, TOL = 4, 8, 2e-5
-
-
-def _config(**overrides):
-    """The configuration file with every size shrunk."""
-    config = json.loads(json.dumps(FILE))
-    config.update(
-        hidden_size=32, linear_attn_config={
-            "short_conv_kernel_size": 4, "head_dim": 16, "num_heads": 4,
-            "num_kv_heads": None},
-        kda_low_rank=8, num_attention_heads=4, num_key_value_heads=2,
-        head_dim=16, moe_intermediate_size=24, intermediate_size=24,
-        n_routed_experts_published=16, n_routed_experts=8,
-        experts_held=[4, 8], num_experts_per_tok=3, vocab_size=96,
-        max_position_embeddings=256)
-    config["model"].update(dtype="float32", param_dtype="float32")
-    config["assumed"]["serve"]["chunk"] = CHUNK
-    config.update(overrides)
-    return config
+NAME = "solar-open2-250b"
+FILE = served.file_config(NAME)
+runner, reference = served.runner(NAME), served.reference(NAME)
+PAGE, CHUNK = 4, 8
+TOL, _rel, _tokens = (getattr(served.ENTRIES[NAME], k)
+                      for k in ("tol", "rel", "tokens"))
+_want = functools.partial(served.want, NAME)
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    config = _config()
-    cfg = runner.model_config(config)
-    params = runner.make_params(cfg, jax.random.PRNGKey(3))
-    return config, cfg, params
+    return served.tiny(NAME)
 
 
-def _tokens(n, seed=1):
-    return jax.random.randint(jax.random.PRNGKey(seed), (1, n), 0, 96)
+def _greedy(params, cfg, req):
+    return served.greedy(cfg, params, req, 64)
 
 
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
+class TestContract(served.Contract):
+    name = NAME
+
+    def also_reused(self, stats, lengths):
+        """The counters are host arithmetic on the calls' positions."""
+        state = stats["state"]
+        assert set(state) == {"delta_rows", "delta_bytes", "delta_tokens",
+                              "delta_resets", "delta_kernel_calls",
+                              "kv_bytes", "calls"}
+        assert not any(state["delta_kernel_calls"].values())   # a CPU backend
+        assert state["delta_resets"]["chunk"] == 5 * 3     # requests x layers
+        assert state["delta_resets"].get("decode", 0) == 0
+        assert state["delta_rows"]["decode"] \
+            == state["delta_tokens"]["decode"] \
+            == 3 * 5 * 4          # layers x requests x steps after the first
+        assert state["delta_bytes"]["decode"] == 2 * state["delta_rows"][
+            "decode"] * (3 * 192 * 4 + 4 * 16 * 16 * 4)
+        assert state["kv_bytes"]["decode"] > 0
+
+    def also_cache(self, cfg, geo):
+        cache = kv_cache.make_cache(cfg, geo)
+        assert cache["k"][1].dtype == cache["v"][1].dtype == jnp.float32
+        half = dataclasses.replace(cfg, dtype="bfloat16")
+        assert kv_cache.make_cache(half, geo)["k"][1].dtype == jnp.bfloat16
+        assert kv_cache.make_cache(half, geo)["v"][1].dtype == jnp.float32
+        assert kv_cache.cache_bytes(half, geo) == (
+            3 * (4 * 3 * 192 * 2 + 4 * 4 * 16 * 16 * 4) + 2 * 33 * PAGE * 32 * 2)
+        with pytest.raises(ValueError, match="state rows"):
+            kv_cache.layer_shapes(cfg, kv_cache.geometry(33, PAGE, 64), 1)
+        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("model",))
+        with pytest.raises(ValueError, match="under a mesh"):
+            kv_cache.make_cache(cfg, geo, mesh)
+
+    def also_over_state(self, lp, cfg, params):
+        """A model whose ONLY recurrent layers are of the delta-rule kind
+        (no ``state_space`` entry at all); and a chunk's padding is -1."""
+        assert not cfg.state_space
+        seen = []
+        chunk_fn = lp.chunk_fn
+
+        def watching(params, cache, toks, *rest):
+            seen.append(np.asarray(toks))
+            return chunk_fn(params, cache, toks, *rest)
+
+        lp.chunk_fn = watching
+        req = Request(rid=0, prompt=list(range(1, 12)), max_new_tokens=2,
+                      arrival_t=0.0)
+        _, done = lp.run([req])
+        assert done[0].generated == _greedy(params, cfg, done[0])
+        assert [t[0].tolist() for t in seen] == [
+            list(range(1, 9)), [9, 10, 11] + [-1] * 5]
 
 
-def _want(config, params, tokens, fault=None, **kw):
-    hp = reference.hyper(config)
-    return reference.logits(reference.from_horovod_tpu(params), tokens, hp,
-                            kn=reference.knobs(hp, fault), **kw)
+class TestCellPrograms(served.CellPrograms):
+    """``solar2-serve-longctx-over``: three delta-rule layers on slot-owned
+    rows (float32 ``[64, 128, 128]`` a slot) beside one softmax layer of 64
+    query heads over 8 key/value heads on pages of a 65,536-token context, 40
+    held experts a layer; the 2,048-token chunk fill and the decode step of
+    16 slots. The chunk program computes the recurrence in its CHUNKED form in
+    ONE kernel a layer, ``kda_chunk_scan`` (PR 49: not the fallback's two, no
+    chain of XLA's over the 32 blocks' states, no loop over 2,048 positions),
+    and the decode step's window is the one-position update: no kernel;
+    neither holds a second copy of a layer's state; the softmax layer reads
+    its pages through the paged kernel, once a program."""
+    name = NAME
+
+    def also_cell(self, built):
+        cfg, geo = built.cfg, built.geo
+        assert (built.cell.max_batch, built.cell.chunk) == (16, 2048)
+        n_params = sum(x.size for x in jax.tree.leaves(built.params))
+        assert 3.30e9 < n_params < 3.32e9           # the file's reduced_why
+        assert kv_cache.cache_bytes(cfg, geo) == built.held - 2 * n_params
+        assert sum(isinstance(cfg.attn_of(li), tfm.DeltaRuleMixer)
+                   for li in range(cfg.n_layers)) == 3
+
+    def also_program(self, built, program, p):
+        assert not [line for line in p.text.splitlines()
+                    if " while(" in line and "f32[32,1,64,128,128]" in line]
+        if program == "chunk":      # nothing walks the positions one by one
+            assert not re.search(r"f32\[2048,1,64,128(,128)?\]", p.text)
 
 
 # ---- the description ------------------------------------------------------
@@ -115,7 +149,7 @@ def test_the_published_list_names_every_layer(tiny):
     assert params["layers"][1]["w_dr_in"].shape == (32, 3 * 64)
     assert params["layers"][1]["w_dr_low"].shape == (32, 2 * 8 + 4)
     with pytest.raises(SystemExit, match="gqa_layers"):
-        runner.model_config(_config(gqa_layers=[0, 3]))
+        served.tiny_config(NAME, gqa_layers=[0, 3])
 
 
 def test_the_file_keeps_the_published_widths_and_counts():
@@ -151,28 +185,6 @@ def test_the_file_keeps_the_published_widths_and_counts():
              + 12 * (count(softmax) + 320 * 15.7286e6 + 15.7286e6 + 1.3107e6)
              + 2 * 196608 * 4096)
     assert 249.5e9 < whole < 250.5e9
-
-
-def test_what_stood_builds_what_it_built():
-    """The newest standing kind (full-context latent attention at its test's
-    tiny size) makes the tree, the parameters' bits and the logits it made at
-    the commit before this kind was added; ``tests/test_sarvam_mla.py`` and
-    ``tests/test_nemotron_h.py`` pin the six before it the same way."""
-    sarvam = _load("tests/test_sarvam_mla.py", "standing_sarvam")
-    cfg = sarvam._cfg(sarvam._config())
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    leaves = jax.tree_util.tree_leaves_with_path(params)
-    shapes = hashlib.sha256(";".join(
-        f"{jax.tree_util.keystr(p)}:{x.shape}:{x.dtype}"
-        for p, x in leaves).encode()).hexdigest()[:16]
-    bits = hashlib.sha256(b"".join(
-        np.asarray(x).tobytes() for _, x in leaves)).hexdigest()[:16]
-    tokens = jnp.asarray(np.random.default_rng(1).integers(
-        0, cfg.vocab_size, (1, 24)), jnp.int32)
-    logits = np.asarray(tfm.forward(params, tokens, cfg), np.float64)
-    assert (shapes, bits) == ("78cbdcd0269be547", "805376dabd5d2dcb")
-    assert logits.sum() == pytest.approx(39.154423932261125, rel=1e-6)
-    assert np.abs(logits).sum() == pytest.approx(2541.947748722516, rel=1e-6)
 
 
 # ---- the recurrence: blocks against position by position -------------------
@@ -328,25 +340,6 @@ def test_mixer_against_the_reference_layer(tiny):
 
 # ---- the model against the reference ---------------------------------------
 
-def test_forward_against_the_reference(tiny):
-    config, cfg, params = tiny
-    tokens = _tokens(41)
-    assert _rel(tfm.forward(params, tokens, cfg),
-                _want(config, params, tokens)) < TOL
-
-
-@pytest.mark.parametrize("fault", [f for f in reference.FAULTS
-                                   if f != "selection_bias_left_out"])
-def test_a_reference_fault_moves_the_logits(tiny, fault):
-    """Leaving out the subtraction, the doubling of ``beta``, the keys'
-    normalisation, the decay a channel, either gate, the shared expert, or
-    what a slot carries between two chunk programs fails the comparison."""
-    config, cfg, params = tiny
-    tokens = _tokens(29, seed=4)
-    got = tfm.forward(params, tokens, cfg)
-    assert _rel(got, _want(config, params, tokens, fault)) > 50 * TOL
-
-
 def test_the_selection_bias_chooses(tiny):
     config, cfg, params = tiny
     tokens = _tokens(29, seed=4)
@@ -450,148 +443,6 @@ def test_an_expert_sent_every_row_drops_none(tiny):
     assert _rel(routed, want) < 1e-5
 
 
-# ---- the cache and the programs -------------------------------------------
-
-def test_cache_shapes_by_layer_kind(tiny):
-    _, cfg, _ = tiny
-    geo = kv_cache.with_rings(kv_cache.geometry(33, PAGE, 64), cfg, CHUNK, 3)
-    assert (geo.state_rows, geo.ring_blocks, geo.table_width) == (4, 0, 17)
-    assert kv_cache.layer_shapes(cfg, geo, 0) == ((33, PAGE, 32),) * 2
-    assert kv_cache.layer_shapes(cfg, geo, 1) == ((4, 3, 192), (4, 4, 16, 16))
-    cache = kv_cache.make_cache(cfg, geo)
-    assert cache["k"][1].dtype == cache["v"][1].dtype == jnp.float32
-    half = dataclasses.replace(cfg, dtype="bfloat16")
-    assert kv_cache.make_cache(half, geo)["k"][1].dtype == jnp.bfloat16
-    assert kv_cache.make_cache(half, geo)["v"][1].dtype == jnp.float32
-    assert kv_cache.cache_bytes(half, geo) == (
-        3 * (4 * 3 * 192 * 2 + 4 * 4 * 16 * 16 * 4) + 2 * 33 * PAGE * 32 * 2)
-    assert kv_cache.cache_bytes(cfg, geo) == sum(
-        x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
-    with pytest.raises(ValueError, match="state rows"):
-        kv_cache.layer_shapes(cfg, kv_cache.geometry(33, PAGE, 64), 1)
-
-    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("model",))
-    with pytest.raises(ValueError, match="under a mesh"):
-        kv_cache.make_cache(cfg, geo, mesh)
-
-
-def _loop(cfg, params, n_pages=65, max_batch=3, **kw):
-    return serve_loop.ServeLoop(
-        params, cfg, geo=kv_cache.geometry(n_pages, PAGE, 64),
-        max_batch=max_batch, prefill_chunk=CHUNK, **kw)
-
-
-def _greedy(params, cfg, req):
-    """What greedy decoding of ``forward`` generates after ``req.prompt``:
-    one causal pass over prompt + generated (padded to one length, so one
-    compilation) predicts each of them."""
-    seq = list(req.prompt) + list(req.generated)
-    logits = tfm.forward(params, jnp.asarray([seq + [0] * (64 - len(seq))]),
-                         cfg)[0]
-    n = len(req.prompt)
-    return [int(t) for t in jnp.argmax(logits[n - 1:len(seq) - 1], -1)]
-
-
-@pytest.fixture(scope="module")
-def programs(tiny):
-    """One loop's compiled programs and cache for the cases below: each
-    starts its prompt in the rows the case before it left."""
-    _, cfg, params = tiny
-    return _loop(cfg, params)
-
-
-def _fill_then_decode(loop, params, prompt, slot, steps=3):
-    """``prompt`` through ``loop``'s chunk program in chunks of 8 (padding
-    -1), then ``steps`` decode steps, all in ``slot`` -> (every logit row,
-    the prompt and what was generated)."""
-    geo, n = loop.geo, len(prompt)
-    table = np.zeros(geo.table_width, np.int32)
-    table[:8] = np.arange(1, 9)
-    table[-1] = slot + 1
-    rows = []
-    for start in range(0, n, CHUNK):
-        toks = np.full((1, CHUNK), -1, np.int32)
-        toks[0, :len(prompt[start:start + CHUNK])] = prompt[start:start + CHUNK]
-        loop.cache, lg, *_ = loop.chunk_fn(
-            params, loop.cache, toks, np.asarray([start], np.int32),
-            table[None], np.ones(1, bool))
-        rows.append(np.asarray(lg[0, :min(CHUNK, n - start)]))
-    seq = prompt + [int(np.argmax(rows[-1][-1]))]
-    tables = np.zeros((3, geo.table_width), np.int32)
-    tables[slot] = table
-    for _ in range(steps):
-        tokens, positions = np.zeros(3, np.int32), np.zeros(3, np.int32)
-        tokens[slot], positions[slot] = seq[-1], len(seq) - 1
-        loop.cache, lg, *_ = loop.decode_fn(params, loop.cache, tokens,
-                                            positions, tables,
-                                            np.arange(3) == slot)
-        rows.append(np.asarray(lg[slot:slot + 1]))
-        seq.append(int(np.argmax(rows[-1][-1])))
-    return np.concatenate(rows), seq
-
-
-@pytest.mark.parametrize("n", [5, 19, 24])
-def test_chunks_then_decode_against_one_forward(tiny, programs, n):
-    """A prompt filled in chunks of 8 (padding -1) and decoded four steps
-    through the engine's programs, in a slot other than 0 and on rows that
-    are dirty from the second case on, against one full ``forward``: every
-    logit row of every chunk and step."""
-    _, cfg, params = tiny
-    loop, slot = programs, 2
-    prompt = [int(t) for t in _tokens(n, seed=n)[0]]
-    rows, seq = _fill_then_decode(loop, params, prompt, slot, steps=4)
-    want = tfm.forward(params, jnp.asarray([seq[:-1]]), cfg)[0]
-    assert _rel(rows, want) < TOL
-    # The other slots' rows were never touched.
-    assert not np.asarray(loop.cache["v"][1][1]).any()
-    assert np.asarray(loop.cache["v"][1][slot + 1]).any()
-
-
-def test_a_reused_slot_gives_the_logits_of_a_fresh_run(tiny):
-    """Five requests through three slots: the later ones start in rows the
-    earlier ones left dirty, and generate what a fresh model generates; the
-    counters are host arithmetic on the calls' positions."""
-    _, cfg, params = tiny
-    loop = _loop(cfg, params)
-    loop.warmup()
-    rng = np.random.default_rng(0)
-    reqs = [Request(rid=i, prompt=rng.integers(0, 96, 9 + 3 * i).tolist(),
-                    max_new_tokens=5, arrival_t=0.001 * (i + 1))
-            for i in range(5)]
-    _, done = loop.run(reqs)
-    assert len(done) == 5
-    for r in done:
-        assert r.generated == _greedy(params, cfg, r), r.rid
-    state = serve_loop.serve_stats()["state"]
-    assert set(state) == {"delta_rows", "delta_bytes", "delta_tokens",
-                          "delta_resets", "delta_kernel_calls", "kv_bytes",
-                          "calls"}
-    assert not any(state["delta_kernel_calls"].values())      # a CPU backend
-    assert state["delta_resets"]["chunk"] == 5 * 3        # requests x layers
-    assert state["delta_resets"].get("decode", 0) == 0
-    assert state["delta_rows"]["decode"] == state["delta_tokens"]["decode"] \
-        == 3 * 5 * 4                  # layers x requests x steps after the first
-    assert state["delta_bytes"]["decode"] == 2 * state["delta_rows"][
-        "decode"] * (3 * 192 * 4 + 4 * 16 * 16 * 4)
-    assert state["kv_bytes"]["decode"] > 0
-
-
-def test_a_preempted_request_replays_from_a_zeroed_row(tiny):
-    """Too few pages for three growing requests: the youngest is preempted,
-    its pages freed, and its replay (prompt + generated, from position 0)
-    finds its row zeroed: every request generates a fresh run's tokens."""
-    _, cfg, params = tiny
-    loop = _loop(cfg, params, n_pages=14)
-    rng = np.random.default_rng(1)
-    reqs = [Request(rid=i, prompt=rng.integers(0, 96, 10).tolist(),
-                    max_new_tokens=12, arrival_t=0.001 * (i + 1))
-            for i in range(3)]
-    summary, done = loop.run(reqs)
-    assert summary["preemptions"] > 0
-    for r in done:
-        assert r.generated == _greedy(params, cfg, r), r.rid
-
-
 # ---- the chunk program's recurrence through the kernel ---------------------
 
 def test_the_kernel_gives_what_the_plain_programs_give(tiny, monkeypatch):
@@ -603,9 +454,9 @@ def test_the_kernel_gives_what_the_plain_programs_give(tiny, monkeypatch):
     logits; a loop with reused slots and one short of pages generates what a
     fresh model generates, and counts three kernel calls a chunk call."""
     _, cfg, params = tiny
-    plain = _loop(cfg, params)
+    plain = served.loop(NAME)
     monkeypatch.setattr(engine, "linear_kernels", lambda *a: True)
-    forced = _loop(cfg, params)
+    forced = served.loop(NAME)          # steered: the memo is not asked
     linear = [li for li in range(cfg.n_layers)
               if isinstance(cfg.attn_of(li), tfm.DeltaRuleMixer)]
     assert len(linear) == 3
@@ -624,7 +475,7 @@ def test_the_kernel_gives_what_the_plain_programs_give(tiny, monkeypatch):
     assert calls(plain.chunk_fn, 1, CHUNK) == 0
 
     prompt = [int(t) for t in _tokens(19, seed=19)[0]]
-    got, want = (_fill_then_decode(loop, params, prompt, slot=2)[0]
+    got, want = (served.fill_then_decode(loop, params, prompt, slot=2)[0]
                  for loop in (forced, plain))
     assert _rel(got, want) < TOL
     for li in linear:
@@ -634,11 +485,12 @@ def test_the_kernel_gives_what_the_plain_programs_give(tiny, monkeypatch):
             assert not np.asarray(forced.cache[kept][li][1]).any()
     # The same slot again: its rows are dirty, the window begins on zeros.
     prompt = [int(t) for t in _tokens(13, seed=13)[0]]
-    got, seq = _fill_then_decode(forced, params, prompt, slot=2, steps=1)
+    got, seq = served.fill_then_decode(forced, params, prompt, slot=2,
+                                       steps=1)
     assert _rel(got, tfm.forward(params, jnp.asarray([seq[:-1]]), cfg)[0]) < TOL
 
     # Reused slots, and a request preempted for pages and replayed.
-    loop = _loop(cfg, params, n_pages=14)
+    loop = served.loop(NAME, n_pages=14)
     rng = np.random.default_rng(1)
     reqs = [Request(rid=i, prompt=rng.integers(0, 96, 10).tolist(),
                     max_new_tokens=12, arrival_t=0.001 * (i + 1))
@@ -652,50 +504,3 @@ def test_the_kernel_gives_what_the_plain_programs_give(tiny, monkeypatch):
     assert state["delta_kernel_calls"].get("decode", 0) == 0
 
 
-def test_no_speculation_no_prefix_cache_and_negative_padding(tiny):
-    """A model whose ONLY recurrent layers are of the delta-rule kind (no
-    ``state_space`` entry at all): the loop reads "has a layer that carries
-    state" and not the kind."""
-    _, cfg, params = tiny
-    assert not cfg.state_space and cfg.recurrent
-    with pytest.raises(ValueError, match="roll the slot's state back"):
-        _loop(cfg, params, spec_tokens=2)
-    loop = _loop(cfg, params, prefix_cache=True)
-    assert loop.has_state and loop.prefix is None and loop.spec_fn is None
-    seen = []
-    chunk_fn = loop.chunk_fn
-
-    def watching(params, cache, toks, *rest):
-        seen.append(np.asarray(toks))
-        return chunk_fn(params, cache, toks, *rest)
-
-    loop.chunk_fn = watching
-    req = Request(rid=0, prompt=list(range(1, 12)), max_new_tokens=2,
-                  arrival_t=0.0)
-    _, done = loop.run([req])
-    assert done[0].generated == _greedy(params, cfg, done[0])
-    assert [t[0].tolist() for t in seen] == [
-        list(range(1, 9)), [9, 10, 11] + [-1] * 5]
-
-
-def test_the_scope_reaches_both_compiled_programs(tiny):
-    """``linear_attention`` is in the lowered chunk and decode programs' op
-    names, where the benchmark's readers find it, beside ``attention`` and
-    ``experts``; the chunk program computes the recurrence in its chunked
-    form (a triangular inverse's matrix products under the scope) and the
-    decode program holds no product of the state at all."""
-    _, cfg, params = tiny
-    geo = kv_cache.with_rings(kv_cache.geometry(33, PAGE, 64), cfg, CHUNK, 2)
-    cache = kv_cache.make_cache(cfg, geo)
-
-    def slots(b, *q):
-        return (np.zeros((b, *q), np.int32), np.zeros(b, np.int32),
-                np.zeros((b, geo.table_width), np.int32), np.zeros(b, bool))
-
-    for fn, args in ((engine.make_decode_step(cfg, geo, max_batch=2),
-                      slots(2)),
-                     (engine.make_chunk_step(cfg, geo, q_len=CHUNK),
-                      slots(1, CHUNK))):
-        text = fn.lower(params, cache, *args).as_text(debug_info=True)
-        for scope in ("linear_attention", "experts", "attention"):
-            assert f"/{scope}/" in text, scope

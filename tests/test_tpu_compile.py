@@ -6,7 +6,8 @@ what the chip's would refuse — a block that breaks the tiling, a kernel that
 wants more VMEM than it may have — which interpret-mode tests on the CPU
 cannot see. Nothing runs, so a pass says nothing about results or times; the
 numbers side is chip_smoke.py's. Skipped only where the topology cannot be
-described (no libtpu).
+described (no libtpu: ``conftest.topo``). The serve cells' programs are
+compiled the same way in each model's own file (``served.CellPrograms``).
 
 The serving programs are compiled too, at the benchmark's ``gpt2-large``
 geometry, for what their compiled text shows and no CPU test can: that the
@@ -16,16 +17,13 @@ reads it only through the paged-attention kernel, one call a layer, and
 gathers nothing (PR 28).
 """
 import dataclasses
-import os
 import re
-import sys
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
@@ -35,25 +33,7 @@ from horovod_tpu.ops.pallas_attention import (flash_attention,
 from horovod_tpu.parallel import expert_parallel, make_ring_attention
 from horovod_tpu.serving import engine, kv_cache
 
-
-@pytest.fixture(scope="module")
-def topo():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        desc = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — any failure: no TPU compiler
-        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
-    # A compile for a described chip is written to JAX's persistent cache
-    # but cannot be read back without the chip; keep it out of the cache.
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+from . import served
 
 
 def _mosaic_calls(fn, *shapes):
@@ -304,15 +284,6 @@ def _gpt2_large():
                                  dtype="bfloat16")
 
 
-@pytest.fixture
-def as_on_the_chip(monkeypatch):
-    """The serving engine chooses the decode program's attention from the
-    backend it sees, which here is the CPU whatever the compile is for: let
-    it see the TPU the program is compiled for (the guide's "steer in the
-    test"), so that what is compiled is what the chip runs."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-
 def _serve_program(name, cfg, geo, max_batch):
     """(jitted program, shapes of its arguments after params and cache) as
     ServeLoop builds and calls it."""
@@ -338,58 +309,12 @@ def _serve_program(name, cfg, geo, max_batch):
         slots(max_batch)
 
 
-_RESULT = re.compile(
-    r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\((.*)$")
-# What may have a whole layer's cache as its result: the argument itself, a
-# free reinterpretation of it, the scatter that updates it in place (XLA:TPU
-# wraps it in a fusion of kind kCustom), and the memory-space assignment's
-# asynchronous move of a few layers into the chip's fast memory and back
-# (copy-start/-done, and ConcatBitcast over slice-done pieces). Anything
-# else -- copy, slice, a loop fusion -- materialises the cache anew.
-_IN_PLACE = {"parameter", "bitcast", "get-tuple-element", "scatter",
-             "copy-done", "custom-call"}
-
-
-def _gathered(text, cfg, geo, max_batch):
-    """Instructions whose result is as large as every slot's ``max_kv``
-    tokens of one layer: the gathered pages ``[B * max_blocks, page, H*dh]``,
-    their reshape to ``[B, max_kv, H, dh]`` or any copy of either."""
-    size = max_batch * geo.max_kv * cfg.n_heads * cfg.head_dim
-    found = []
-    for line in text.splitlines():
-        m = _RESULT.match(line)
-        if m and m.group(1) and int(np.prod(
-                [int(d) for d in m.group(1).split(",")])) == size:
-            found.append(line.strip()[:160])
-    return found
-
-
 def _paged_kernels(text):
     """The paged-attention kernel's calls, by the name the benchmark's
     ``paged_attn_dev_ms.over`` reads in the chip's trace."""
     return [line for line in text.splitlines()
             if re.match(r"\s*%paged_decode_attention[.\d]* = ", line)
             and "tpu_custom_call" in line]
-
-
-def _cache_materialisations(text, cfg, geo):
-    """Instructions of a compiled program whose result is as large as one
-    layer's cache, has the cache's page dimension, and is not in place."""
-    layer = geo.n_pages * geo.page_size * cfg.n_heads * cfg.head_dim
-    page_dims = {geo.n_pages, geo.n_pages * geo.page_size}
-    found = []
-    for line in text.splitlines():
-        m = _RESULT.match(line)
-        if not m or not m.group(1):
-            continue
-        dims = [int(d) for d in m.group(1).split(",")]
-        if int(np.prod(dims)) < layer or not page_dims & set(dims):
-            continue
-        op, rest = m.group(2), m.group(3)
-        if op in _IN_PLACE or (op == "fusion" and "kind=kCustom" in rest):
-            continue
-        found.append(line.strip()[:160])
-    return found
 
 
 @pytest.mark.parametrize("name, max_batch", [
@@ -420,882 +345,16 @@ def test_serving_program_never_copies_the_cache(topo, as_on_the_chip, name,
     compiled = fn.lower(params, cache,
                         *[_on_chip(topo, *s) for s in shapes]).compile()
     text = compiled.as_text()
-    assert _cache_materialisations(text, cfg, geo) == []
+    assert served.cache_materialisations(text, cfg, geo) == []
     memory = compiled.memory_analysis()
     # Every layer's array is donated and aliased to its output.
     assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
     if name == "decode":
         assert len(_paged_kernels(text)) == cfg.n_layers
-        assert _gathered(text, cfg, geo, max_batch) == []
+        assert served.gathered(text, cfg, geo, max_batch) == []
         assert memory.temp_size_in_bytes < 0.2e9   # the weights' bf16 casts
     else:
         assert _paged_kernels(text) == []
     if name == "decode_gather":
-        assert _gathered(text, cfg, geo, max_batch) != []
+        assert served.gathered(text, cfg, geo, max_batch) != []
         assert memory.temp_size_in_bytes < 1e9
-
-
-# ---- the serving programs at benchmark/configs/olmoe-1b-7b.json's sizes ----
-
-def test_olmoe_cell_programs_fit_one_chip(topo, as_on_the_chip):
-    """``olmoe-serve-chat-over``'s two programs (the 512-token chunk fill and
-    the decode step; a cache of 4096 gets no padded prefill) at the cell's
-    geometry: 12 layers of 64 experts in bf16, every slot of 8 at the full
-    context. Weights + cache + the program's temporaries stay under the
-    chip's 16.91e9 bytes; the experts are one ``ragged-dot`` custom call a
-    projection (the name the benchmark's reader matches); no program makes a
-    float32 copy of an expert tensor or a copy shaped like the cache; the
-    decode step reads the cache through the paged kernel alone (one call a
-    layer, nothing of the gathered pages' size; its temporaries were 0.28e9
-    with the gather)."""
-    import json
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "olmoe-1b-7b.json")) as f:
-        config = json.load(f)
-    srv = config["assumed"]["serve"]
-    cfg = tfm.olmoe_1b_7b(n_layers=config["num_hidden_layers"])
-    assert (cfg.d_model, cfg.ffn_width, cfg.n_experts, cfg.top_k) == (
-        config["hidden_size"], config["intermediate_size"],
-        config["num_experts"], config["num_experts_per_tok"])
-    geo = kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"])
-    assert geo.max_kv > 1024          # ServeLoop: chunk fills only
-    params, cache = jax.tree.map(
-        lambda x: _on_chip(topo, x.shape, x.dtype),
-        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
-                                kv_cache.make_cache(cfg, geo))))
-    held = sum(x.size * x.dtype.itemsize
-               for x in jax.tree.leaves((params, cache)))
-    assert 13.6e9 < held < 13.8e9
-    B = srv["max_batch"]
-
-    def slots(b, *q):
-        return [_on_chip(topo, s, d) for s, d in (
-            ((b, *q), jnp.int32), ((b,), jnp.int32),
-            ((b, geo.max_blocks), jnp.int32), ((b,), jnp.bool_))]
-
-    expert = cfg.n_experts * cfg.d_model * cfg.ffn_width
-    for name, fn, args in (
-            ("chunk", engine.make_chunk_step(cfg, geo, q_len=512),
-             slots(1, 512)),
-            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
-             slots(B))):
-        compiled = fn.lower(params, cache, *args).compile()
-        memory = compiled.memory_analysis()
-        assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
-        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
-        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
-        assert memory.temp_size_in_bytes < 1e9
-        text = compiled.as_text()
-        assert _cache_materialisations(text, cfg, geo) == []
-        if name == "decode":
-            assert len(_paged_kernels(text)) == cfg.n_layers
-            assert _gathered(text, cfg, geo, B) == []
-            assert memory.temp_size_in_bytes < 0.1e9
-        else:
-            assert _paged_kernels(text) == []
-        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
-            == 3 * cfg.n_layers
-        # No instruction's result is an expert tensor's worth of float32.
-        for m in re.finditer(r" = f32\[([\d,]+)\]", text):
-            assert int(np.prod([int(d) for d in m.group(1).split(",")])) \
-                < expert, m.group(0)
-
-
-# ---- the serving programs at benchmark/configs/dots3-note-prev.json's sizes --
-
-def test_layered_cell_programs_fit_one_chip(topo, as_on_the_chip):
-    """``dots3-serve-doc-over``'s two programs (the 512-token chunk fill and
-    the decode step) at the cell's geometry: five layers that differ, 16
-    slots of a 32k context, the window layers on rings. The chip's compiler
-    takes all four latent kernels at the published widths; each is in both
-    programs under the instruction name the benchmark's readers match, once
-    a layer of its kind; the selection costs no ``[512, 64, max_kv]`` float32
-    score block and no top-k sort of the scores (the sorts that remain are
-    the expert dispatch's and the router's, a few thousand elements each);
-    weights + cache + temporaries stay on the chip; the cache is aliased
-    through."""
-    import importlib.util
-    import json
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "dots3-note-prev.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "serve_layers", os.path.join(root, "benchmark", "runners",
-                                     "serve_layers.py"))
-    runner = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(runner)
-    cfg = runner.model_config(config)
-    srv = config["assumed"]["serve"]
-    B = srv["max_batch"]
-    geo = kv_cache.with_rings(
-        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
-        cfg, 512, B)
-    assert (geo.max_kv, geo.ring_tokens, geo.ring_pages) == (32768, 1024,
-                                                             1025)
-    assert engine.latent_kernels(cfg, geo, None)
-    params, cache = jax.tree.map(
-        lambda x: _on_chip(topo, x.shape, x.dtype),
-        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
-                                kv_cache.make_cache(cfg, geo))))
-    held = sum(x.size * x.dtype.itemsize
-               for x in jax.tree.leaves((params, cache)))
-    assert 9.8e9 < held < 10.0e9
-    assert 0.25 * 16.91e9 < held
-
-    def slots(b, *q):
-        return [_on_chip(topo, s, d) for s, d in (
-            ((b, *q), jnp.int32), ((b,), jnp.int32),
-            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
-
-    kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
-    n_full = sum(1 for a in kinds if a.index_topk)
-    n_window = sum(1 for a in kinds if a.window)
-    for name, fn, args in (
-            ("chunk", engine.make_chunk_step(cfg, geo, q_len=512),
-             slots(1, 512)),
-            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
-             slots(B))):
-        compiled = fn.lower(params, cache, *args).compile()
-        rows = args[0].shape[0] * (args[0].shape[1] if name == "chunk" else 1)
-        memory = compiled.memory_analysis()
-        assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
-        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
-        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
-        text = compiled.as_text()
-        for kernel, n in (("index_scores", n_full), ("index_select", n_full),
-                          ("sparse_latent_attention", n_full),
-                          ("window_latent_attention", n_window)):
-            calls = [line for line in text.splitlines()
-                     if re.match(rf"\s*%{kernel}[.\d]* = ", line)
-                     and "tpu_custom_call" in line]
-            assert len(calls) == n, (name, kernel)
-            if kernel == "index_select":
-                # The top-k is told the queries' live keys: one number a
-                # query for the kernel's scalar unit, ahead of the same as
-                # a column and of the scores as they are (no copy in
-                # blocks of 128).
-                for call in calls:
-                    assert (f"operand_layout_constraints={{s32[{rows}]{{0}}, "
-                            f"s32[{rows},1]{{1,0}}, "
-                            f"f32[{rows},{geo.max_kv}]{{1,0}}}}"
-                            in call), call[:300]
-        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
-            == 3 * len(cfg.moe_layers)
-        # The held experts' products: a chunk's in blocks whose rows the
-        # compiler tiles by 256 (``transformer._HELD_BLOCK`` rests on that
-        # rule), a decode step's 128 rows in one product as before.
-        assert set(re.findall(r'ragged_dot_tiling="(\d+),', text)) \
-            == {"256" if name == "chunk" else "128"}
-        # Nothing float32 of the per-head score block's size, and no sort
-        # as long as a row of scores.
-        block = rows * 64 * geo.max_kv
-        for m in re.finditer(r" = f32\[([\d,]+)\]", text):
-            assert int(np.prod([int(d) for d in m.group(1).split(",")])) \
-                < block, m.group(0)
-        for m in re.finditer(r" = \(?\w+\[([\d,]+)\]\S* sort\(", text):
-            assert int(m.group(1).split(",")[-1]) < geo.max_kv, m.group(0)
-
-
-# ---- the serving programs at benchmark/configs/laguna-s-2.1.json's sizes ----
-
-def test_grouped_cell_programs_fit_one_chip(topo, as_on_the_chip):
-    """``laguna-serve-agent-over``'s two programs (the 512-token chunk fill
-    and the decode step) at the cell's geometry: nine layers of two described
-    multi-head kinds, 32 slots of a 16k context, the window layers' K and V on
-    rings. The chip's compiler takes the grouped paged kernel at the
-    published widths for one query a slot and for a block of 128; it is in
-    both programs under the instruction names the benchmark's readers match,
-    once a layer of its kind, and ``paged_decode_attention`` is in neither;
-    no program holds scores of ``[queries, max_kv]`` or a gathered copy of a
-    slot's pages; weights + cache + temporaries stay on the chip; the cache
-    is aliased through."""
-    import importlib.util
-    import json
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "laguna-s-2.1.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "serve_gqa", os.path.join(root, "benchmark", "runners",
-                                  "serve_gqa.py"))
-    runner = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(runner)
-    cfg = runner.model_config(config)
-    srv = config["assumed"]["serve"]
-    B = srv["max_batch"]
-    geo = kv_cache.with_rings(
-        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
-        cfg, 512, B)
-    assert (geo.max_kv, geo.ring_tokens, geo.ring_pages) == (16384, 1024,
-                                                             2049)
-    assert engine.grouped_kernels(cfg, geo, None)
-    assert engine.decode_attn(cfg, geo, None) == "gather"   # no plain layer
-    params, cache = jax.tree.map(
-        lambda x: _on_chip(topo, x.shape, x.dtype),
-        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
-                                kv_cache.make_cache(cfg, geo))))
-    held = sum(x.size * x.dtype.itemsize
-               for x in jax.tree.leaves((params, cache)))
-    assert 13.6e9 < held < 13.7e9          # 81 % of the chip's 16.91e9
-
-    def slots(b, *q):
-        return [_on_chip(topo, s, d) for s, d in (
-            ((b, *q), jnp.int32), ((b,), jnp.int32),
-            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
-
-    kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
-    n_window = sum(1 for a in kinds if a.window)
-    for name, fn, args in (
-            ("chunk", engine.make_chunk_step(cfg, geo, q_len=512),
-             slots(1, 512)),
-            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
-             slots(B))):
-        compiled = fn.lower(params, cache, *args).compile()
-        memory = compiled.memory_analysis()
-        assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
-        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
-        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
-        assert memory.temp_size_in_bytes < 0.2e9
-        text = compiled.as_text()
-        for kernel, n in (("paged_full_attention", len(kinds) - n_window),
-                          ("paged_window_attention", n_window),
-                          ("paged_decode_attention", 0)):
-            calls = [line for line in text.splitlines()
-                     if re.match(rf"\s*%{kernel}[.\d]* = ", line)
-                     and "tpu_custom_call" in line]
-            assert len(calls) == n, (name, kernel)
-        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
-            == 3 * len(cfg.moe_layers)
-        # The held experts' products: a chunk's in blocks whose rows the
-        # compiler tiles by 256 (``transformer._HELD_BLOCK`` rests on that
-        # rule), a decode step's 320 rows in one product as before.
-        assert set(re.findall(r'ragged_dot_tiling="(\d+),', text)) \
-            == {"256" if name == "chunk" else "64"}
-        # No float array spans a slot's max_kv positions: neither gathered
-        # pages nor a query block's scores over them.
-        for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", text):
-            assert str(geo.max_kv) not in m.group(1).split(","), m.group(0)
-
-
-# ---- the serving programs at benchmark/configs/mimo-v2-flash.json's sizes ----
-
-def test_kinds_cell_programs_fit_one_chip(topo, as_on_the_chip):
-    """``mimo-serve-mixed64k-over``'s two programs (the 512-token chunk fill
-    and the decode step) at the cell's geometry: seven layers of two described
-    kinds that differ in KEY/VALUE heads, keys of 192 beside values of 128, a
-    sink on the window layers; 16 slots of a 64k context, the window layers on
-    rings of 40 pages. The chip's compiler takes the grouped paged kernel at
-    the published widths (a key head read as the aligned 256 lanes around it,
-    K and V pages of different lanes, the sink's tile) for one query a slot
-    and for a block of 128, under the instruction names the benchmark's
-    readers match, once a layer of its kind; no program holds scores of
-    ``[queries, max_kv]`` or a gathered copy of a slot's pages; weights +
-    cache + temporaries stay on the chip; the cache is aliased through."""
-    import importlib.util
-    import json
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "mimo-v2-flash.json")) as f:
-        config = json.load(f)
-    sys.path.insert(0, root)
-    spec = importlib.util.spec_from_file_location(
-        "serve_gqa_kinds", os.path.join(root, "benchmark", "runners",
-                                        "serve_gqa_kinds.py"))
-    runner = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(runner)
-    cfg = runner.model_config(config)
-    srv = config["assumed"]["serve"]
-    B = srv["max_batch"]
-    geo = kv_cache.with_rings(
-        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
-        cfg, srv["chunk"], B)
-    assert (geo.max_kv, geo.ring_tokens, geo.ring_pages) == (65536, 640, 641)
-    assert engine.grouped_kernels(cfg, geo, None)
-    params, cache = jax.tree.map(
-        lambda x: _on_chip(topo, x.shape, x.dtype),
-        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
-                                kv_cache.make_cache(cfg, geo))))
-    assert [c.shape[-1] for c in cache["k"]] == [768] + [1536] * 4 + [768,
-                                                                      1536]
-    assert [c.shape[-1] for c in cache["v"]] == [512] + [1024] * 4 + [512,
-                                                                      1024]
-    held = sum(x.size * x.dtype.itemsize
-               for x in jax.tree.leaves((params, cache)))
-    assert 12.4e9 < held < 12.6e9          # 73.9 % of the chip's 16.91e9
-
-    def slots(b, *q):
-        return [_on_chip(topo, s, d) for s, d in (
-            ((b, *q), jnp.int32), ((b,), jnp.int32),
-            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
-
-    kinds = [cfg.attn_of(li) for li in range(cfg.n_layers)]
-    n_window = sum(1 for a in kinds if a.window)
-    for name, fn, args in (
-            ("chunk", engine.make_chunk_step(cfg, geo, q_len=srv["chunk"]),
-             slots(1, srv["chunk"])),
-            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
-             slots(B))):
-        compiled = fn.lower(params, cache, *args).compile()
-        memory = compiled.memory_analysis()
-        assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
-        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
-        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
-        # The file's assumed.serve.why states them: 0.02e9 and 0.011e9.
-        assert memory.temp_size_in_bytes < 0.05e9
-        text = compiled.as_text()
-        for kernel, n in (("paged_full_attention", len(kinds) - n_window),
-                          ("paged_window_attention", n_window),
-                          ("paged_decode_attention", 0)):
-            calls = [line for line in text.splitlines()
-                     if re.match(rf"\s*%{kernel}[.\d]* = ", line)
-                     and "tpu_custom_call" in line]
-            assert len(calls) == n, (name, kernel)
-        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
-            == 3 * len(cfg.moe_layers)
-        # No float array spans a slot's max_kv positions: neither gathered
-        # pages nor a query block's scores over them.
-        for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", text):
-            assert str(geo.max_kv) not in m.group(1).split(","), m.group(0)
-
-
-# ---- the serving programs at benchmark/configs/sarvam-105b.json ----
-
-def test_full_latent_cell_programs_fit_one_chip(topo, as_on_the_chip):
-    """``sarvam-serve-longdoc-over``'s two programs (the 512-token chunk fill
-    and the decode step) at the cell's geometry: five layers of full-context
-    latent attention, 16 slots of a 32k context. The chip's compiler takes
-    both forms' kernels at the published widths (64 heads over one 640-lane
-    row a token): ``paged_latent_attention`` for one query a slot in the
-    decode step, ``paged_latent_attention_expanded`` for the chunk's 512
-    queries (inside its VMEM limit), each under a name the benchmark's readers
-    match (``^paged_latent_attention``), once a layer, and none of the
-    selection's or the window's kernels is; the chunk holds nothing of the
-    absorbed form (no ``[1, 512, 64, 640]`` query, no ``[.., 64, 512]`` output
-    in the latent), the decode step no expanded kernel; no program holds
-    scores of ``[.., max_kv]`` or a gathered copy of a slot's pages; weights
-    + cache + temporaries stay on the chip; the cache is aliased through."""
-    import importlib.util
-    import json
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "sarvam-105b.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "serve_latent", os.path.join(root, "benchmark", "runners",
-                                     "serve_latent.py"))
-    runner = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(runner)
-    cfg = runner.model_config(config)
-    srv = config["assumed"]["serve"]
-    B = srv["max_batch"]
-    geo = kv_cache.with_rings(
-        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
-        cfg, srv["chunk"], B)
-    assert (geo.max_kv, geo.ring_blocks, geo.table_width) == (32768, 0, 2048)
-    assert engine.latent_kernels(cfg, geo, None)
-    params, cache = jax.tree.map(
-        lambda x: _on_chip(topo, x.shape, x.dtype),
-        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
-                                kv_cache.make_cache(cfg, geo))))
-    assert all(v is None for v in cache["v"])       # no scorer cache
-    assert kv_cache.cache_bytes(cfg, geo) == 5 * 32769 * 16 * 640 * 2
-    held = sum(x.size * x.dtype.itemsize
-               for x in jax.tree.leaves((params, cache)))
-    assert 12.4e9 < held < 12.5e9          # 73.5 % of the chip's 16.91e9
-
-    def slots(b, *q):
-        return [_on_chip(topo, s, d) for s, d in (
-            ((b, *q), jnp.int32), ((b,), jnp.int32),
-            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
-
-    for name, fn, args in (
-            ("chunk", engine.make_chunk_step(cfg, geo, q_len=srv["chunk"]),
-             slots(1, srv["chunk"])),
-            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
-             slots(B))):
-        compiled = fn.lower(params, cache, *args).compile()
-        memory = compiled.memory_analysis()
-        assert memory.alias_size_in_bytes == kv_cache.cache_bytes(cfg, geo)
-        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
-        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
-        assert memory.temp_size_in_bytes < 0.6e9, (
-            name, memory.temp_size_in_bytes)
-        text = compiled.as_text()
-        expanded = cfg.n_layers * (name == "chunk")
-        for kernel, n in (("paged_latent_attention_expanded", expanded),
-                          ("paged_latent_attention", cfg.n_layers - expanded),
-                          ("sparse_latent_attention", 0),
-                          ("window_latent_attention", 0),
-                          ("index_scores", 0), ("index_select", 0)):
-            calls = [line for line in text.splitlines()
-                     if re.match(rf"\s*%{kernel}[.\d]* = ", line)
-                     and "tpu_custom_call" in line]
-            assert len(calls) == n, (name, kernel)
-        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
-            == 3 * len(cfg.moe_layers)
-        a = cfg.attn_of(0)
-        absorbed = {f"{a.n_heads},{a.row_width}", f"{a.n_heads},{a.kv_rank}"}
-        for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", text):
-            # No float array spans a slot's max_kv positions: neither
-            # gathered pages nor a query block's scores over them.
-            assert str(geo.max_kv) not in m.group(1).split(","), m.group(0)
-            # The absorbed form has left nothing in the chunk: no query
-            # [.., 64, 640], no output in the latent [.., 64, 512].
-            if name == "chunk":
-                assert ",".join(m.group(1).split(",")[-2:]) not in absorbed, \
-                    m.group(0)
-
-
-# ---- the serving programs at benchmark/configs/nemotron-3-super-120b.json ----
-
-def test_hybrid_cell_programs_fit_one_chip(topo, as_on_the_chip, monkeypatch):
-    """``nemotron-serve-reason-over``'s two programs (the 512-token chunk fill
-    and the decode step) at the cell's geometry: eleven layers that are each
-    a mixer or a feed-forward, five state-space layers on slot-owned rows
-    (float32 state), one attention layer of 32 query heads over 2 key/value
-    heads on pages, five expert layers with no cache. The chip's compiler
-    takes the grouped paged kernel at a group of 16; weights + cache +
-    temporaries stay on the chip; the cache is aliased through, and the
-    decode step holds no second copy of a layer's state (0.54 GB: a gather
-    of the rows, or the blocked scan at a block of one, made one a layer) and
-    passes over it once, in the kernel ``ssm_decode_update``; the chunk
-    program runs its recurrence as ONE ``ssm_chunk_scan`` a state-space layer
-    (eight packs of 16 heads, blocks of 128) and keeps none of the blocked
-    form's per-head ``[128, 128]`` decay tensors (``f32[4,8,16,128,128]``
-    in the compiled text of the blocked form)."""
-    import importlib.util
-    import json
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "nemotron-3-super-120b.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "serve_hybrid", os.path.join(root, "benchmark", "runners",
-                                     "serve_hybrid.py"))
-    runner = importlib.util.module_from_spec(spec)
-    sys.path.insert(0, root)
-    try:
-        spec.loader.exec_module(runner)
-        cfg = runner.model_config(config)
-    finally:
-        sys.path.remove(root)
-    srv = config["assumed"]["serve"]
-    B, chunk = srv["max_batch"], srv["chunk"]
-    geo = kv_cache.with_rings(
-        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
-        cfg, chunk, B)
-    assert (geo.max_kv, geo.state_rows, geo.table_width) == (8192, B + 1, 513)
-    assert engine.grouped_kernels(cfg, geo, None)
-    params, cache = jax.tree.map(
-        lambda x: _on_chip(topo, x.shape, x.dtype),
-        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
-                                kv_cache.make_cache(cfg, geo))))
-    n_params = sum(x.size for x in jax.tree.leaves(params))
-    assert 4.64e9 < n_params < 4.66e9           # the file's reduced_why
-    held = sum(x.size * x.dtype.itemsize
-               for x in jax.tree.leaves((params, cache)))
-    assert kv_cache.cache_bytes(cfg, geo) == held - 2 * n_params
-    assert 13.0e9 < held < 13.2e9          # 78 % of the chip's 16.91e9
-    state = 4 * B * 128 * 64 * 128         # one layer's rows in float32
-    n_state = sum(isinstance(cfg.attn_of(li), tfm.StateSpaceMixer)
-                  and cfg.has_mixer(li) for li in range(cfg.n_layers))
-    assert n_state == 5
-
-    def slots(b, *q):
-        return [_on_chip(topo, s, d) for s, d in (
-            ((b, *q), jnp.int32), ((b,), jnp.int32),
-            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
-
-    for name, fn, args in (
-            ("chunk", engine.make_chunk_step(cfg, geo, q_len=chunk),
-             slots(1, chunk)),
-            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
-             slots(B))):
-        compiled = fn.lower(params, cache, *args).compile()
-        memory = compiled.memory_analysis()
-        assert memory.alias_size_in_bytes >= kv_cache.cache_bytes(cfg, geo)
-        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
-        assert held + memory.temp_size_in_bytes + fresh < 16.91e9
-        assert memory.temp_size_in_bytes < state / 2, name
-        text = compiled.as_text()
-        calls = [line for line in text.splitlines()
-                 if re.match(r"\s*%paged_full_attention[.\d]* = ", line)
-                 and "tpu_custom_call" in line]
-        assert len(calls) == 1, name
-        # Two products an expert layer: no gate matrix.
-        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
-            == 2 * len(cfg.moe_layers)
-        # The decode step passes over a state-space layer's state ONCE: one
-        # kernel call a layer, which takes the layer's whole array and gives
-        # it back (aliased), and nothing else makes an array of a layer's
-        # rows (PR 43; XLA made three passes of `_ssd_step`). The chunk
-        # program's recurrence is one kernel a layer too (PR 58), and the
-        # blocked form's decays of every head against every pair of a
-        # block's positions are in no buffer.
-        for kernel, program in (("ssm_decode_update", "decode"),
-                                ("ssm_chunk_scan", "chunk")):
-            found = [line for line in text.splitlines()
-                     if re.match(rf"\s*%{kernel}[.\d]* = ", line)
-                     and "tpu_custom_call" in line]
-            assert len(found) == (n_state if name == program else 0), name
-        assert engine.state_kernels(cfg, geo, None)
-        assert engine.state_kernels(cfg, geo, None, chunk)
-        assert not engine.state_kernels(cfg, geo, None, 16)
-        assert "f32[4,8,16,128,128]" not in text
-        if name == "decode":
-            rows = ["f32[%d,128,64,128]" % n for n in (B, B + 1)]
-            made = []
-            for line in text.splitlines():     # "%name = type op(..": layouts off
-                m = re.match(r"\s*(?:ROOT )?(%[\w.-]+) = (\([^)]*\)|\S+) "
-                             r"([\w-]+)\(", re.sub(r"\{[^}]*\}", "", line))
-                if m and any(r in m.group(2) for r in rows) \
-                        and m.group(3) not in ("parameter", "tuple",
-                                               "get-tuple-element"):
-                    made.append(m.group(1))
-            assert len(made) == n_state and all(
-                m.startswith("%ssm_decode_update") for m in made), made
-    # With the gate closed the chunk program is the blocked form's: no
-    # kernel, and the decays in a buffer of their own.
-    monkeypatch.setattr(engine, "state_kernels", lambda *a: False)
-    plain = engine.make_chunk_step(cfg, geo, q_len=chunk).lower(
-        params, cache, *slots(1, chunk)).compile().as_text()
-    assert "ssm_chunk_scan" not in plain
-    assert "f32[4,8,16,128,128]" in plain
-
-
-# ---- the serving programs at benchmark/configs/solar-open2-250b.json ----
-
-def test_linear_cell_programs_fit_one_chip(topo, as_on_the_chip):
-    """``solar2-serve-longctx-over``'s two programs (the 2,048-token chunk
-    fill and the decode step of 16 slots) at the cell's geometry: three
-    delta-rule layers on slot-owned rows (float32 ``[64, 128, 128]`` a slot)
-    beside one softmax layer of 64 query heads over 8 key/value heads on
-    pages of a 65,536-token context, 40 held experts a layer. Weights + cache
-    + temporaries stay on the chip; the cache is aliased through; the chunk
-    program computes the recurrence in its CHUNKED form in ONE kernel a
-    layer, ``kda_chunk_scan`` (PR 49: no chain of XLA's over the 32 blocks'
-    states, no loop over 2,048 positions), and the decode step has none;
-    neither program holds a second copy of a layer's state or anything as
-    wide as the context; the softmax layer reads its pages through the paged
-    kernel, once a program."""
-    import importlib.util
-    import json
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "solar-open2-250b.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "serve_linear", os.path.join(root, "benchmark", "runners",
-                                     "serve_linear.py"))
-    runner = importlib.util.module_from_spec(spec)
-    sys.path.insert(0, root)
-    try:
-        spec.loader.exec_module(runner)
-        cfg = runner.model_config(config)
-    finally:
-        sys.path.remove(root)
-    srv = config["assumed"]["serve"]
-    B, chunk = srv["max_batch"], srv["chunk"]
-    geo = kv_cache.with_rings(
-        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
-        cfg, chunk, B)
-    assert (geo.max_kv, geo.state_rows, geo.table_width, B, chunk) == (
-        65536, 17, 4097, 16, 2048)
-    assert engine.grouped_kernels(cfg, geo, None)
-    assert not engine.state_kernels(cfg, geo, None)     # no such layer
-    assert engine.linear_kernels(cfg, geo, None)
-    params, cache = jax.tree.map(
-        lambda x: _on_chip(topo, x.shape, x.dtype),
-        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
-                                kv_cache.make_cache(cfg, geo))))
-    n_params = sum(x.size for x in jax.tree.leaves(params))
-    assert 3.30e9 < n_params < 3.32e9           # the file's reduced_why
-    held = sum(x.size * x.dtype.itemsize
-               for x in jax.tree.leaves((params, cache)))
-    assert kv_cache.cache_bytes(cfg, geo) == held - 2 * n_params
-    assert 11.1e9 < held < 11.2e9          # 66 % of the chip's 16.91e9
-    state = 4 * B * 64 * 128 * 128         # one layer's slots in float32
-    n_linear = sum(isinstance(cfg.attn_of(li), tfm.DeltaRuleMixer)
-                   for li in range(cfg.n_layers))
-    assert n_linear == 3
-
-    def slots(b, *q):
-        return [_on_chip(topo, s, d) for s, d in (
-            ((b, *q), jnp.int32), ((b,), jnp.int32),
-            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
-
-    for name, fn, args in (
-            ("chunk", engine.make_chunk_step(cfg, geo, q_len=chunk),
-             slots(1, chunk)),
-            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
-             slots(B))):
-        compiled = fn.lower(params, cache, *args).compile()
-        memory = compiled.memory_analysis()
-        assert memory.alias_size_in_bytes >= kv_cache.cache_bytes(cfg, geo)
-        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
-        assert held + memory.temp_size_in_bytes + fresh < 0.8 * 16.91e9, name
-        text = compiled.as_text()
-        calls = [line for line in text.splitlines()
-                 if re.match(r"\s*%paged_full_attention[.\d]* = ", line)
-                 and "tpu_custom_call" in line]
-        assert len(calls) == 1, name
-        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) \
-            == 3 * len(cfg.moe_layers)
-        # Nothing as wide as the context: no scores [.., max_kv], no
-        # gathered pages.
-        assert not re.search(r"(f32|bf16)\[[\d,]*65536[\d,]*\]", text), name
-        # The chunked form is ONE kernel a linear layer (not the fallback's
-        # two), and XLA chains nothing over the 32 blocks' states; the
-        # decode step's window is the one-position update: no kernel.
-        scans = [line for line in text.splitlines()
-                 if re.match(r"\s*%kda_chunk_scan[.\d]* = ", line)
-                 and "tpu_custom_call" in line]
-        assert not [line for line in text.splitlines()
-                    if " while(" in line and "f32[32,1,64,128,128]" in line]
-        if name == "decode":
-            # A second copy of a layer's slots would be this large.
-            assert memory.temp_size_in_bytes < state, name
-            assert not scans
-        else:
-            assert memory.temp_size_in_bytes < 1.5e9, name
-            assert len(scans) == n_linear
-            # Nothing walks the 2,048 positions one by one.
-            assert not re.search(r"f32\[2048,1,64,128(,128)?\]", text)
-
-
-def test_sambay_cell_programs_fit_one_chip(topo, as_on_the_chip):
-    """``phi4flash-serve-think-over``'s three programs (the 512-token chunk
-    that ends no prompt, the one that ends one, the decode step of 32 slots)
-    at the cell's geometry, the configuration UNCUT: nine selective-scan
-    layers on slot-owned rows (float32 ``[16, 5120]`` a slot), eight
-    differential window layers on rings, ONE full layer on pages of a
-    32,768-token context that seven more layers read, seven gated memory
-    units, a vocabulary of 200,064. Weights + cache + temporaries stay on the
-    chip; the cache is aliased through and nothing holds a second copy of the
-    shared layer's pages or of a layer's state; the chunk that ends no prompt
-    takes NO parameter above the exit layer and returns no logits, and
-    neither chunk makes ``[512, vocab]`` logits or a ``[512, 16, 5120]``
-    float32 history of the state; nothing is as wide as the context; the
-    decode step reads the shared pages through the paged kernel eight times
-    and the rings eight."""
-    import importlib.util
-    import json
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "phi-4-mini-flash-reasoning.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "serve_sambay", os.path.join(root, "benchmark", "runners",
-                                     "serve_sambay.py"))
-    runner = importlib.util.module_from_spec(spec)
-    sys.path.insert(0, root)
-    try:
-        spec.loader.exec_module(runner)
-        cfg = runner.model_config(config)
-    finally:
-        sys.path.remove(root)
-    srv = config["assumed"]["serve"]
-    B, chunk = srv["max_batch"], srv["chunk"]
-    geo = kv_cache.with_rings(
-        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
-        cfg, chunk, B)
-    assert (geo.max_kv, geo.ring_blocks, geo.ring_pages, geo.state_rows,
-            geo.table_width, B, chunk) == (32768, 64, 2049, 33, 2113, 32, 512)
-    leaves = engine.fill_exit(cfg)
-    assert leaves == config["kv_from"] == 17
-    assert engine.grouped_kernels(cfg, geo, None)
-    params, cache = jax.tree.map(
-        lambda x: _on_chip(topo, x.shape, x.dtype),
-        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
-                                kv_cache.make_cache(cfg, geo))))
-    n_params = sum(x.size for x in jax.tree.leaves(params))
-    assert 3.85e9 < n_params < 3.855e9          # the file's reduced_why
-    held = sum(x.size * x.dtype.itemsize
-               for x in jax.tree.leaves((params, cache)))
-    assert kv_cache.cache_bytes(cfg, geo) == held - 2 * n_params
-    assert 14.4e9 < held < 14.6e9          # 86 % of the chip's 16.91e9
-    pages = 2 * geo.n_pages * geo.page_size * 1280     # the shared K or V
-    state = 4 * B * 16 * 5120              # one layer's slots in float32
-    n_above = len(jax.tree.leaves(params["layers"][leaves + 1:]))
-
-    def slots(b, *q):
-        return [_on_chip(topo, s, d) for s, d in (
-            ((b, *q), jnp.int32), ((b,), jnp.int32),
-            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
-
-    for name, fn, args in (
-            ("chunk", engine.make_chunk_step(cfg, geo, q_len=chunk,
-                                             ends=False), slots(1, chunk)),
-            ("chunk_end", engine.make_chunk_step(cfg, geo, q_len=chunk,
-                                                 ends=True), slots(1, chunk)),
-            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
-             slots(B))):
-        lowered = fn.lower(params, cache, *args)
-        compiled = lowered.compile()
-        memory = compiled.memory_analysis()
-        assert memory.alias_size_in_bytes >= kv_cache.cache_bytes(cfg, geo)
-        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
-        assert held + memory.temp_size_in_bytes + fresh < 0.97 * 16.91e9, (
-            name, memory.temp_size_in_bytes)
-        # No second copy of the shared pages, whoever reads them.
-        assert memory.temp_size_in_bytes < pages, name
-        text = compiled.as_text()
-
-        def kernel_calls(kernel):
-            return len([line for line in text.splitlines()
-                        if re.match(rf"\s*%{kernel}[.\d]* = ", line)
-                        and "tpu_custom_call" in line])
-
-        # Nothing as wide as the context (no scores [.., max_kv], no gathered
-        # pages), no history of the state over the window's positions, no
-        # logits of every position.
-        assert not re.search(r"(f32|bf16)\[[\d,]*32768[\d,]*\]", text), name
-        assert not re.search(r"f32\[(\d+,)*512,(\d+,)*(16,5120|5120,16)\]",
-                             text), name
-        assert not re.search(r"\[(1,)?512,200064\]", text), name
-        n_args = len(jax.tree.leaves(lowered.args_info))
-        kept = text[text.index("\nENTRY "):].count(" parameter(")
-        if name == "chunk":
-            # The fill leaves the stack: no weight above the exit layer is an
-            # argument of the compiled program (a gated memory unit's first
-            # matrix is the one [2560, 5120] in the model), and there are no
-            # logits.
-            assert kept <= n_args - n_above - 2, (kept, n_args, n_above)
-            assert "bf16[2560,5120]" not in text
-            assert fresh < 4096, name          # a tuple's pointers
-            assert kernel_calls("paged_full_attention") == 0
-            assert kernel_calls("paged_window_attention") == 8
-            assert not re.search(r",200064\]", text)
-        elif name == "chunk_end":
-            assert kept == n_args and "bf16[2560,5120]" in text
-            assert kernel_calls("paged_full_attention") == 8
-            assert kernel_calls("paged_window_attention") == 8
-        else:
-            assert kernel_calls("paged_full_attention") == 8
-            assert kernel_calls("paged_window_attention") == 8
-            # A second copy of a layer's slots would be this large.
-            assert memory.temp_size_in_bytes < 8 * state + 2 * B * 200064 * 4
-        print(name, memory.temp_size_in_bytes, fresh, kept, n_args)
-
-
-# ---- the serving programs at benchmark/configs/granite-4.0-h-micro.json ----
-
-def test_share_cell_programs_fit_one_chip(topo, as_on_the_chip, monkeypatch):
-    """``granite-serve-agent-share-over``'s programs at the cell's geometry
-    (33 state rows and the pool of snapshot rows behind them), on ONE period
-    of the model's ten layers (nine Mamba-2 layers of 64 heads in one group,
-    one attention layer of 32 query heads over 8 key/value heads of 64; the
-    cell runs four such periods): the chip's compiler takes both kernels at
-    the new shapes (``ssm_decode_update`` as four packs of 16 heads,
-    ``paged_full_attention`` with two 64-wide heads a lane tile), the cache
-    is aliased through every program, the decode step passes over a layer's
-    state once and touches no snapshot row's worth of temporaries, the fill's
-    two programs cut the head and run the recurrence as ONE
-    ``ssm_chunk_scan`` a Mamba-2 layer with no ``f32[1,2,64,256,256]`` decay
-    tensor (PR 58), the page-wide tail program stays the blocked form's, and
-    the state copy is in place."""
-    import dataclasses
-    import json
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, root)
-    try:
-        from benchmark.runners import serve_share
-    finally:
-        sys.path.remove(root)
-    with open(os.path.join(root, "benchmark", "configs",
-                           "granite-4.0-h-micro.json")) as f:
-        config = json.load(f)
-    whole = serve_share.model_config(config)
-    cfg = dataclasses.replace(whole, n_layers=10,
-                              layer_attn=whole.layer_attn[:10])
-    srv = config["assumed"]["serve"]
-    B, chunk, rows = srv["max_batch"], srv["chunk"], srv["snapshot_rows"]
-    geo = kv_cache.with_rings(
-        kv_cache.geometry(srv["n_pages"], srv["page_size"], srv["context"]),
-        cfg, chunk, B, snapshot_rows=rows)
-    assert (geo.max_kv, geo.state_rows, geo.snapshot_rows,
-            geo.table_width) == (16384, B + 1, rows, 1025)
-    on = {"latent": False, "grouped": True, "state": True, "linear": False}
-    assert engine._kernels(cfg, geo, None, 1) == on
-    assert engine._kernels(cfg, geo, None, chunk) == on
-    # A window that is no whole block (the page-wide tail), a mesh, a model
-    # with no state-space layer: the blocked form.
-    off = dict(on, state=False)
-    assert engine._kernels(cfg, geo, None, srv["page_size"]) == off
-    assert engine._kernels(cfg, geo, Mesh(np.array(topo.devices[:1]),
-                                          ("data",)), chunk) == dict(
-        off, grouped=False)
-    assert not engine._kernels(_gpt2_large(), geo, None, chunk)["state"]
-    # The whole model and its cache, by shape: what the serve block's why says.
-    full_geo = kv_cache.with_rings(geo, whole, chunk, B, snapshot_rows=rows)
-    n_params = sum(x.size for x in jax.tree.leaves(jax.eval_shape(
-        lambda: tfm.init_params(jax.random.PRNGKey(0), whole))))
-    assert 3.18e9 < n_params < 3.20e9
-    row = 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
-    assert kv_cache.cache_bytes(whole, full_geo) == (
-        (B + 1 + rows) * row + 4 * 2 * srv["n_pages"] * 16 * 512 * 2)
-    assert 2 * n_params + kv_cache.cache_bytes(whole, full_geo) < 14.2e9
-
-    params, cache = jax.tree.map(
-        lambda x: _on_chip(topo, x.shape, x.dtype),
-        jax.eval_shape(lambda: (tfm.init_params(jax.random.PRNGKey(0), cfg),
-                                kv_cache.make_cache(cfg, geo))))
-    layer_state = 4 * (B + 1 + rows) * 64 * 64 * 128
-
-    def slots(b, *q):
-        return [_on_chip(topo, s, d) for s, d in (
-            ((b, *q), jnp.int32), ((b,), jnp.int32),
-            ((b, geo.table_width), jnp.int32), ((b,), jnp.bool_))]
-
-    def calls(text, kernel):
-        return len([line for line in text.splitlines()
-                    if re.match(rf"\s*%{kernel}[.\d]* = ", line)
-                    and "tpu_custom_call" in line])
-
-    scalar = _on_chip(topo, (), jnp.int32)
-    page = srv["page_size"]
-    for name, fn, args, ssm, scans in (
-            ("chunk", engine.make_chunk_step(cfg, geo, q_len=chunk,
-                                             head="none"),
-             [params, cache] + slots(1, chunk), 0, 9),
-            ("chunk_end", engine.make_chunk_step(cfg, geo, q_len=chunk,
-                                                 head="last"),
-             [params, cache] + slots(1, chunk), 0, 9),
-            ("chunk_tail", engine.make_chunk_step(cfg, geo, q_len=page,
-                                                  head="last",
-                                                  name="chunk_tail"),
-             [params, cache] + slots(1, page), 0, 0),
-            ("decode", engine.make_decode_step(cfg, geo, max_batch=B),
-             [params, cache] + slots(B), 9, 0),
-            ("copy", engine.make_state_copy(cfg, geo, "state_snapshot"),
-             [cache, scalar, scalar], 0, 0)):
-        compiled = fn.lower(*args).compile()
-        memory = compiled.memory_analysis()
-        assert memory.alias_size_in_bytes >= kv_cache.cache_bytes(cfg, geo)
-        fresh = memory.output_size_in_bytes - memory.alias_size_in_bytes
-        # no copy of a layer's rows, and no float32 logits of 512 positions
-        assert memory.temp_size_in_bytes + fresh < layer_state, name
-        text = compiled.as_text()
-        assert calls(text, "paged_full_attention") == (name != "copy"), name
-        assert calls(text, "ssm_decode_update") == ssm, name
-        assert calls(text, "ssm_chunk_scan") == scans, name
-        # the blocked form's decays (its compiled text drops the leading 1)
-        assert "f32[1,2,64,256,256]" not in text, name
-        assert "f32[2,64,256,256]" not in text, name
-    # With the gate closed the chunk program is the blocked form's.
-    monkeypatch.setattr(engine, "state_kernels", lambda *a: False)
-    plain = engine.make_chunk_step(cfg, geo, q_len=chunk, head="none").lower(
-        params, cache, *slots(1, chunk)).compile().as_text()
-    assert calls(plain, "ssm_chunk_scan") == 0
-    assert "f32[2,64,256,256]" in plain

@@ -19,16 +19,13 @@ module printed without locations.
     python tools/serve_program_hashes.py --tier chip     # on the chip
     python tools/serve_program_hashes.py --tier described
 
-``cpu``: the plain tier, at the tiny configurations the tier-1 tests serve
-(``tests/test_serving.py``, ``test_olmoe.py``, ``test_dots3.py``,
-``test_laguna.py``, ``test_nemotron_h.py``, ``test_sarvam_mla.py``,
-``test_solar_open2.py``, ``test_phi4_flash.py``, ``test_mimo_v2.py``,
-``test_granite_h.py``; the
-helpers of those files build them; ``granite_h`` with no snapshot rows and
+``cpu``: the plain tier, at the tiny configurations the tier-1 tests serve:
+every entry of ``tests/served.py``'s table, with the variants the entry lists
+(``tiny-spec``, ``sarvam_mla-spec``; ``granite_h`` with no snapshot rows and
 ``granite_h-share`` with the prefix cache that holds state and its two copy
-programs). ``chip``: the kernels' tier, a
-``ServeLoop`` built as each serve cell's runner builds it (the cell's
-configuration file, geometry, slots and chunk; parameters by shape only).
+programs). ``chip``: the kernels' tier, a ``ServeLoop`` built as each serve
+cell's runner builds it (``served.cell``: the cell's configuration file,
+geometry, slots and chunk; parameters by shape only).
 ``described``: the same with no chip, lowered for a described ``v5e:2x2``
 with the engine told it sees a TPU (a rehearsal of ``chip``; its text is not
 the chip's). One JSON line: ``{"tier": .., "hashes": {"<model>.<program>":
@@ -37,20 +34,13 @@ the chip's). One JSON line: ``{"tier": .., "hashes": {"<model>.<program>":
 import argparse
 import base64
 import hashlib
-import importlib
 import json
 import os
 import re
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path[:0] = [ROOT, os.path.join(ROOT, "tests")]
-
-# cell's configuration -> its runner (``benchmark/configs/<name>.json``).
-CELLS = ("gpt2-large", "olmoe-1b-7b", "dots3-note-prev", "laguna-s-2.1",
-         "nemotron-3-super-120b", "sarvam-105b", "solar-open2-250b",
-         "phi-4-mini-flash-reasoning", "granite-4.0-h-micro",
-         "mimo-v2-flash")
+sys.path.insert(0, ROOT)
 
 
 def _sha(text):
@@ -126,76 +116,28 @@ def _programs(loop, like):
     return found
 
 
-def _tiny_loops():
+def _tiny_loops(served):
     """(name, -> ServeLoop) of the tiny configurations the tests serve."""
-    import jax
-    from horovod_tpu.models import transformer as tfm
-    from horovod_tpu.serving import kv_cache
-    from horovod_tpu.serving.loop import ServeLoop
-
-    def abstract(cfg):
-        return jax.eval_shape(
-            lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
-
-    serving = importlib.import_module("test_serving")
-    cfg = serving._cfg()
-    geo = kv_cache.geometry(n_pages=32, page_size=8, max_context=64)
-    yield "tiny", lambda: ServeLoop(abstract(cfg), cfg, geo=geo, max_batch=4)
-    yield "tiny-spec", lambda: ServeLoop(abstract(cfg), cfg, geo=geo,
-                                         max_batch=2, spec_tokens=3)
-    moe = importlib.import_module("test_olmoe")._tiny()
-    yield "olmoe", lambda: ServeLoop(
-        abstract(moe), moe, max_batch=4,
-        geo=kv_cache.geometry(n_pages=65, page_size=8, max_context=128))
-    for name, kw in (("dots3", {}), ("laguna", {}), ("sarvam_mla", {}),
-                     ("sarvam_mla-spec", {"spec_tokens": 3})):
-        test = importlib.import_module("test_" + name.partition("-")[0])
-        kind = test._cfg(test._config())
-        yield name, lambda: test._loop(kind, abstract(kind), **kw)
-    for name in ("nemotron_h", "solar_open2", "phi4_flash", "mimo_v2"):
-        test = importlib.import_module("test_" + name)
-        kind = test.runner.model_config(test._config())
-        yield name, lambda: test._loop(kind, abstract(kind))
-    test = importlib.import_module("test_granite_h")
-    shared = test.serve_share.model_config(test.tiny_config())
-    model = (None, shared, abstract(shared), None, None)
-    yield "granite_h", lambda: test.make_loop(model, 0)
-    yield "granite_h-share", lambda: test.make_loop(model, 3,
-                                                    fill_head="last")
+    for name, entry in served.ENTRIES.items():
+        for suffix, kw in entry.hashed:
+            yield entry.short + suffix, lambda name=name, kw=kw: served.loop(
+                name, abstract=True, **kw)
 
 
-def _cell_loops():
+def _cell_loops(served):
     """(name, -> ServeLoop) as each serve cell's runner builds it, parameters by
     shape only."""
     import jax
     from horovod_tpu.models import transformer as tfm
-    from horovod_tpu.serving import kv_cache
     from horovod_tpu.serving.loop import ServeLoop
 
-    for name in CELLS:
-        with open(os.path.join(ROOT, "benchmark", "configs",
-                               name + ".json")) as f:
-            config = json.load(f)
-        srv = config["assumed"]["serve"]
-        if config["runner"] == "serve":
-            cfg = tfm.TransformerConfig(
-                vocab_size=config["vocab_size"], d_model=config["n_embd"],
-                n_heads=config["n_head"], n_layers=config["n_layer"],
-                d_ff=config["n_inner"], max_seq_len=config["n_positions"],
-                dtype=config["assumed"]["compute_dtype"])
-        else:
-            runner = importlib.import_module(
-                "benchmark.runners." + config["runner"])
-            cfg = runner.model_config(config)
+    for name in served.ENTRIES:
+        cell = served.cell(name)
         params = jax.eval_shape(
-            lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
-        geo = kv_cache.geometry(srv["n_pages"], srv["page_size"],
-                                srv["context"])
-        kw = {"prefill_chunk": srv["chunk"]} if "chunk" in srv else {}
-        kw.update({key: srv[key] for key in ("snapshot_rows", "fill_head")
-                   if key in srv})
-        yield name, lambda: ServeLoop(params, cfg, geo=geo,
-                                      max_batch=srv["max_batch"], **kw)
+            lambda: tfm.init_params(jax.random.PRNGKey(0), cell.cfg))
+        yield name, lambda cell=cell, params=params: ServeLoop(
+            params, cell.cfg, geo=cell.plain, max_batch=cell.max_batch,
+            **cell.loop_kw)
 
 
 def main():
@@ -206,13 +148,14 @@ def main():
     args = ap.parse_args()
     import jax
     from horovod_tpu.serving import kv_cache
+    from tests import served
 
     if args.tier == "cpu":
-        loops = _tiny_loops()
+        loops = _tiny_loops(served)
     elif args.tier == "chip":
         if jax.default_backend() != "tpu":
             raise SystemExit("--tier chip needs the chip")
-        loops = _cell_loops()
+        loops = _cell_loops(served)
     else:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
@@ -224,7 +167,7 @@ def main():
         make = kv_cache.make_cache      # no 12 GB of zeros on the host
         kv_cache.make_cache = lambda *a, **kw: jax.eval_shape(
             lambda: make(*a, **kw))
-        loops = _cell_loops()
+        loops = _cell_loops(served)
 
     def like(shape, dtype):
         if args.tier == "described":
