@@ -1150,17 +1150,25 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk",
     slot's last live row, ``[B, 1, vocab]``; ``"none"`` no projection,
     ``(cache, None)``. A fill needs one row of logits a prompt, and float32 logits of
     512 positions of a 100,352-row vocabulary are 205 MB a queued program.
+    ``"none"`` is any model's (``ServeLoop.chunk_pair_fn``: two requests'
+    chunks that end no prompt, ``B = 2``); ``"last"`` finds its row by the
+    negative padding id, which only a model with recurrent layers has. A
+    program with no head runs the last layer only as far as its cache and its
+    report need it (the router still reads the attention's result; the
+    experts' products feed nothing and are reported as zero counts and rows).
     """
     _check_gathers(cfg, geo, mesh)
     if ends is not None and fill_exit(cfg) is None:
         raise ValueError("ends: this model's fill runs the whole stack "
                          "(engine.fill_exit is None)")
     if head not in ("all", "last", "none") or (
-            head != "all" and (ends is not None or not cfg.recurrent)):
+            head != "all" and ends is not None) or (
+            head == "last" and not cfg.recurrent):
         raise ValueError(
-            f"head is 'all', 'last' or 'none', and 'all' with ends or for a "
-            f"model whose padding is no negative id (no recurrent layer: the "
-            f"last live row is then not the program's to find), got {head!r}")
+            f"head is 'all', 'last' or 'none': 'all' with ends, and no 'last' "
+            f"for a model whose padding is no negative id (no recurrent "
+            f"layer: the last live row is then not the program's to find), "
+            f"got {head!r}")
     q_len = geo.page_size if q_len is None else int(q_len)
     if q_len < 1:
         raise ValueError(f"chunk q_len must be >= 1, got {q_len}")
@@ -1173,7 +1181,14 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk",
                                         geo=geo, mesh=mesh, kernels=kernels,
                                         ends=ends)
         if head == "none":
+            # Nobody reads ``x``, so what feeds nothing but ``x`` is not run
+            # (the compiler drops it): the last layer's feed-forward, and the
+            # mixer of a last layer that has none. Experts whose products are
+            # not run are not counted.
             x = None
+            if moe is not None and cfg.is_moe(cfg.n_layers - 1):
+                moe = {name: v.at[-1].set(0) if name in ("counts", "rows")
+                       else v for name, v in moe.items()}
         elif head == "last":         # each slot's last live row
             live = jnp.cumprod((tokens >= 0).astype(jnp.int32), 1)
             at = jnp.maximum(jnp.sum(live, 1) - 1, 0)

@@ -13,7 +13,11 @@ One iteration = one token boundary:
    wider than ``PADDED_PREFILL_MAX_KV`` the padded prefills are not even
    built (their cost and their materialised scores are those of
    ``max_kv``, whatever the prompt) and EVERY prompt fills by chunks, so
-   that its cost follows its length. Completing a
+   that its cost follows its length. A request still advances one chunk
+   per boundary where a model with routed experts carries two requests'
+   chunks that end no prompt in ONE call (``chunk_pair_fn``: the experts'
+   weights are read once for both, and no logits are projected that
+   nobody reads). Completing a
    prefill emits the request's first token — TTFT is arrival → that
    token, queueing and prefill included — and registers the prompt's
    pages in the prefix cache,
@@ -238,6 +242,10 @@ class ServeLoop:
     ends a prompt; and for ``fill_head="last"``, where the two differ in the
     head alone and a third, ``chunk_tail_fn`` (``jit_chunk_tail``), is
     ``chunk_end_fn`` one page wide for the last few tokens of a prompt.
+    ``chunk_pair_fn`` is the chunk program at two rows with no head, for a
+    model whose chunk runs routed experts on one device and whose fill is
+    the one whole-stack ``chunk_fn`` (docs/serving.md, "Two requests' chunks
+    in one call"); None for every other.
     """
 
     @_startup.phase("serve.build")
@@ -356,6 +364,20 @@ class ServeLoop:
         # rows and a snapshot row.
         self.snapshots = (use_prefix and self.has_state
                           and geo.snapshot_rows > 0)
+        # TWO filling requests' chunks as one call of the same chunk program
+        # at ``B = 2`` (``jit_chunk`` in a trace, like the other), for chunks
+        # that end no prompt: nobody reads a logit of those, so it has no
+        # head. A routed expert's product costs by the (expert, row tile)
+        # visits, each reading the expert's matrices, and a chunk gives an
+        # expert a small part of a tile: two requests' rows in one call read
+        # those matrices once. Built where the chunk's experts take the
+        # grouped form (one device) and a fill ends its chunks at a prompt's
+        # end alone (no exit from the stack, no cut head, no snapshot marks);
+        # a dense chunk's products are bound by the MXU already.
+        self.chunk_pair_fn = None
+        if (cfg.moe_layers and mesh is None and self.chunk_fn is not None
+                and self.chunk_end_fn is None and not self.snapshots):
+            self.chunk_pair_fn = chunk_step(None, head="none")
         self.snapshot_fn = self.restore_fn = None
         if self.snapshots:
             self.snapshot_fn = engine.make_state_copy(cfg, geo,
@@ -366,6 +388,7 @@ class ServeLoop:
         self.reset()
         self.loop_stats = {"prefill_single": 0, "prefill_batched": 0,
                            "prefill_batch_calls": 0, "chunk_fills": 0,
+                           "chunk_pair_calls": 0, "chunk_paired": 0,
                            "boundaries": 0,
                            "decode_calls": 0, "decode_paged_calls": 0,
                            "decode_ahead_calls": 0, "decode_ahead_dropped": 0,
@@ -540,6 +563,11 @@ class ServeLoop:
                                        np.ones(B, np.int32), tables, active))
         if self.chunk_fn is not None:
             with _startup.phase("warmup.chunk"):
+                # Unfetched ones first: the fetch behind them takes their
+                # experts' counts along.
+                if self.chunk_pair_fn is not None:
+                    self._call("chunk", self.chunk_pair_fn,
+                               *slots(2, self.prefill_chunk), fetch=False)
                 if self.chunk_end_fn is not None:
                     self._call("chunk", self.chunk_fn,
                                *slots(1, self.prefill_chunk), fetch=False)
@@ -659,6 +687,49 @@ class ServeLoop:
         row = 0 if self.chunk_end_fn is not None else end - 1 - filled
         step.owners = {req.slot: (req, req.admit_seq, (0, row))}
         return step
+
+    def _pairs(self, req):
+        """Whether ``req``'s next chunk may share a call with another
+        request's: the loop has the program, and the chunk ends no prompt."""
+        return (self.chunk_pair_fn is not None
+                and self._filled(req) + self.prefill_chunk
+                < req.prompt_len + len(req.generated))
+
+    def _chunk_pair(self, pair):
+        """Advance the fills of the TWO requests ``pair`` by one chunk each in
+        ONE call (``chunk_pair_fn``): both chunks are whole and end no prompt
+        (:meth:`_pairs`), so nothing is fetched and no token comes of it. A
+        row is a request's own in everything but the experts' products, whose
+        sorted rows are both requests' and whose counts come back summed."""
+        q = self.prefill_chunk
+
+        def window(req, at):
+            # ``q`` tokens of the context from ``at``: the prompt's, running
+            # into what a preempted request had generated where it replays.
+            toks = list(req.prompt[at:at + q])
+            done = max(0, at - req.prompt_len)
+            return toks + list(req.generated[done:done + q - len(toks)])
+
+        with self._span("serve.chunk.pack"):
+            filled = [self._filled(req) for req in pair]
+            toks = np.asarray([window(req, at)
+                               for req, at in zip(pair, filled)], np.int32)
+            filled = np.asarray(filled, np.int32)
+            tables = np.asarray(
+                [self.batcher.block_table(req, self.geo.max_blocks)
+                 for req in pair], np.int32)
+            self._count("chunk", filled[:, None] + np.arange(q) + 1,
+                        ends=False)
+        with self._span("serve.chunk.dispatch", paired=2, rid=pair[0].rid,
+                        rid_b=pair[1].rid, start=int(filled[0]),
+                        start_b=int(filled[1])):
+            self._call("chunk", self.chunk_pair_fn, toks, filled, tables,
+                       np.ones(2, bool), fetch=False)
+        for req, at in zip(pair, filled):
+            self._fills[req.rid] = (req.admit_seq, int(at) + q)
+        self.loop_stats["chunk_fills"] += 2
+        self.loop_stats["chunk_pair_calls"] += 1
+        self.loop_stats["chunk_paired"] += 2
 
     # -- state beside the pages (a prefix cache that holds state) --------
 
@@ -1035,6 +1106,11 @@ class ServeLoop:
                         _first_token(self._prefill(plain[0]))
                     continue
                 progressed = False
+                # A request whose chunk ends no prompt and may share a call
+                # (``chunk_pair_fn``) waits here for the next such one in the
+                # order; it runs alone before any chunk that emits a token
+                # (whose boundary may take its pages), and at the pass's end.
+                lone = None
                 for req in sorted(todo, key=lambda r: r.admit_seq):
                     if req.rid in advanced:
                         continue
@@ -1043,10 +1119,22 @@ class ServeLoop:
                         continue
                     advanced.add(req.rid)
                     progressed = True
+                    if self._pairs(req):
+                        if lone is None:
+                            lone = req
+                        else:
+                            self._chunk_pair((lone, req))
+                            lone = None
+                        continue
+                    if lone is not None:
+                        self._chunk_fill(lone)
+                        lone = None
                     step = self._chunk_fill(req)
                     if step is not None:
                         _first_token(step)
                         break   # boundary may have changed the todo set
+                if lone is not None:
+                    self._chunk_fill(lone)
                 if not progressed:
                     break
             ready = {s: r for s, r in self.batcher.running.items()
@@ -1125,6 +1213,9 @@ class ServeLoop:
         snap["decode_ahead_share"] = (
             self.loop_stats["decode_ahead_calls"]
             / max(1, self.loop_stats["decode_calls"]))
+        snap["chunk_paired_share"] = (
+            self.loop_stats["chunk_paired"]
+            / max(1, self.loop_stats["chunk_fills"]))
         for family, counters in self.tally.items():
             snap[family] = {name: dict(by_kind)
                             for name, by_kind in counters.items()}

@@ -169,6 +169,129 @@ def fill_then_decode(loop, params, prompt, slot, steps=3):
     return np.concatenate(rows), seq
 
 
+def own_rows(cfg, geo, li, table, blocks):
+    """The rows of layer ``li``'s arrays that a request whose block table is
+    ``table`` has written once its context fills ``blocks`` whole pages: those
+    pages, as many of its ring's (a window layer; all, once the context has
+    gone round) or its slot's state row (a recurrent layer)."""
+    a = cfg.attn_of(li)
+    if isinstance(a, tfm.RECURRENT):
+        return table[-1:]
+    if a is not None and a.window:
+        return table[geo.max_blocks:][:min(blocks, geo.ring_blocks)]
+    return table[:blocks]
+
+
+def pair_against_singles(loop, params, filled, seed=0, timed=0):
+    """Two requests' chunks that end no prompt through ``loop.chunk_pair_fn``
+    in ONE call, against the same chunks through ``loop.chunk_fn`` in two.
+
+    Two prompts, each on TWO slots with pages, rings and rows of their own
+    (slots 0 and 1 take the single calls, 2 and 3 the pair), all four filled
+    to ``filled[slot % 2]`` tokens by the same single calls (whole chunks, then
+    a part of one: an offset as a prefix-cache hit leaves it). Then the chunk
+    at that offset, and behind it one more single chunk on every slot, whose
+    logits say what the difference is worth. -> ``{"cache_rel": the largest
+    difference of what the two ways left in a layer's own rows, over that
+    layer's largest value (``"cache_rel_by_layer"``: of each layer that
+    holds a cache; ``"cache_rms"``: the rms of the difference over the rms
+    of the values, which a few positions routed otherwise hardly move);
+    "logits_rel", "logits_rms": the same of the chunk behind;
+    "route_flips": by expert layer, the share of positions whose experts
+    differ (bfloat16 programs of different shapes round differently, and a
+    router's near tie then falls the other way);
+    "counts": (the experts' counts-and-rows of each single call, those of the
+    pair's one)}``, and with ``timed`` calls of each way
+    ``"single_ms"`` / ``"pair_ms"``, the least time of two single calls and of
+    one pair call on the host's clock (a chip's numbers)."""
+    import time
+
+    cfg, geo, q = loop.cfg, loop.geo, loop.prefill_chunk
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(0, cfg.vocab_size, n + 2 * q).tolist()
+               for n in filled]
+    blocks = -(-(max(filled) + 2 * q) // geo.page_size)
+    tables = np.zeros((4, geo.table_width), np.int32)
+    for slot in range(4):
+        tables[slot, :blocks] = 1 + slot * blocks + np.arange(blocks)
+        tables[slot, geo.max_blocks:geo.max_blocks + geo.ring_blocks] = (
+            1 + slot * geo.ring_blocks + np.arange(geo.ring_blocks))
+        if geo.state_rows:
+            tables[slot, -1] = slot + 1
+    assert 4 * blocks < geo.n_pages and loop.max_batch >= 4
+    assert not any(n % geo.page_size for n in (q, *filled))
+
+    def window(slot, start, end):
+        toks = np.full(q, -int(bool(cfg.recurrent)), np.int32)
+        toks[:end - start] = prompts[slot % 2][start:end]
+        return toks
+
+    def single(slot, start, end):
+        loop.cache, lg, *routing = loop.chunk_fn(
+            params, loop.cache, window(slot, start, end)[None],
+            np.asarray([start], np.int32), tables[slot][None],
+            np.ones(1, bool))
+        return lg, routing
+
+    def pair():
+        loop.cache, lg, *routing = loop.chunk_pair_fn(
+            params, loop.cache,
+            np.stack([window(2 + i, filled[i], filled[i] + q)
+                      for i in range(2)]),
+            np.asarray(filled, np.int32), tables[2:], np.ones(2, bool))
+        assert lg is None
+        return routing
+
+    for slot in range(4):
+        for start in range(0, filled[slot % 2], q):
+            single(slot, start, min(start + q, filled[slot % 2]))
+    routes = [single(i, filled[i], filled[i] + q)[1] for i in range(2)]
+    routed = pair()
+    found = {}
+    if routed:
+        found["counts"] = ([np.asarray(r[1]) for r in routes],
+                           np.asarray(routed[1]))
+        tops = [np.sort(np.concatenate([np.asarray(r[0]["top"])
+                                        for r in routes], 1)),
+                np.sort(np.asarray(routed[0]["top"]))]    # [L, 2, q, k]
+        found["route_flips"] = (tops[0] != tops[1]).any(-1).mean((1, 2))
+
+    def rel(rows, other):
+        return rel_max(other, rows), rel_rms(other, rows)
+
+    by_layer = [
+        max(rel(*(c[own_rows(cfg, geo, li, tables[slot],
+                             (filled[i] + q) // geo.page_size)]
+                  for slot in (i, 2 + i)))
+            for c in (loop.cache["k"][li], loop.cache["v"][li])
+            if c is not None for i in range(2))
+        for li in range(cfg.n_layers) if loop.cache["k"][li] is not None]
+    (found["cache_rel"], found["cache_rms"]) = (
+        max(r[i] for r in by_layer) for i in range(2))
+    found["cache_rel_by_layer"] = [r[0] for r in by_layer]
+    behind = [single(slot, filled[slot % 2] + q, filled[slot % 2] + 2 * q)[0]
+              for slot in range(4)]
+    found["logits_rel"], found["logits_rms"] = (
+        max(rel(behind[i], behind[2 + i])[j] for i in range(2))
+        for j in range(2))
+
+    def least_ms(run):
+        best = np.inf
+        for _ in range(timed):
+            jax.block_until_ready(loop.cache)
+            t0 = time.perf_counter()
+            run()
+            jax.block_until_ready(loop.cache)
+            best = min(best, time.perf_counter() - t0)
+        return best * 1e3
+
+    if timed:
+        found["single_ms"] = least_ms(lambda: [
+            single(i, filled[i], filled[i] + q) for i in range(2)])
+        found["pair_ms"] = least_ms(pair)
+    return found
+
+
 SLOT = 2        # the state models' cases fill a slot other than 0
 
 
@@ -249,7 +372,8 @@ class Cell:
     """What the cell's compiled programs must show (:class:`CellPrograms`).
     ``kernels``: ``{program: {kernel's instruction name: calls}}``, for the
     programs ``chunk`` (with ``chunk_kw`` where the fill has two),
-    ``chunk_end``/``chunk_tail`` (where it has) and ``decode``."""
+    ``chunk_end``/``chunk_tail`` (where it has), ``chunk_pair`` (two requests'
+    chunks in one call, where the loop builds it) and ``decode``."""
     geometry: dict                  # attributes of the ringed geometry
     held: tuple                     # bounds on weights + cache, bytes
     gates: dict                     # engine's gates -> what they answer
@@ -294,6 +418,7 @@ class Served:
     seams: tuple = None             # names of SEAMS this model's cell plants
     steer: tuple = ()               # engine gates forced open for ``seams``
     stood: tuple = None             # digest of what this tiny model builds
+    pairs: dict = None              # loop keywords under which two fills pair
     cell: Cell = None
 
 
@@ -439,7 +564,12 @@ _SHORT_OF_RINGS = dict(n_pages=13, context=48, new=20,
 
 
 def _both(**kernels):
-    return {"chunk": kernels, "decode": kernels}
+    return {"chunk": kernels, "chunk_pair": kernels, "decode": kernels}
+
+
+# Slots for two prompts twice (``pair_against_singles``) and for three fills
+# at once; a model with routed experts.
+_PAIRS = dict(max_batch=4)
 
 
 # The four whose layers keep pages and rings (their files name no dtype: cast),
@@ -460,7 +590,7 @@ ENTRIES = {
         rel=rel_max, tol=1e-5, geometry=(32, 8, 64), serve=dict(max_batch=4),
         hashed=(("", {}), ("-spec", dict(max_batch=2, spec_tokens=3)))),
     "olmoe-1b-7b": Served(
-        short="olmoe", shrink=None,
+        short="olmoe", pairs=dict(_PAIRS, context=1032, prefill_chunk=16), shrink=None,
         model=lambda c: tfm.olmoe_1b_7b(**OLMOE_TINY),
         hyper=lambda c: dict(reference("olmoe-1b-7b").hyper(c), n_head=4,
                              top_k=2),
@@ -477,7 +607,7 @@ ENTRIES = {
                      "decode": dict(paged_decode_attention=12)},
             temp={"chunk": 1e9, "decode": 0.1e9}, wide=False)),
     "dots3-note-prev": _paged(
-        short="dots3", shrink=_dots3, params=jittered("bias", "router_bias"),
+        short="dots3", pairs=_PAIRS, shrink=_dots3, params=jittered("bias", "router_bias"),
         forward=((40, 1),), chosen=dict(with_routes=True, with_selected=True),
         served=(5, 37, 16), drive=_through_pages_and_rings,
         preempted=_SHORT_OF_RINGS, shares=(16, 4),
@@ -498,7 +628,7 @@ ENTRIES = {
                           sparse_latent_attention=2,
                           window_latent_attention=3), wide=False)),
     "laguna-s-2.1": _paged(
-        short="laguna", shrink=_laguna, params=jittered(),
+        short="laguna", pairs=_PAIRS, shrink=_laguna, params=jittered(),
         forward=((40, 1),), chosen=_ROUTES, fault=(40, 1, 50),
         served=(5, 37, 16), drive=_through_pages_and_rings,
         preempted=_SHORT_OF_RINGS, shares=(16, 4),
@@ -516,9 +646,9 @@ ENTRIES = {
             gates=dict(grouped_kernels=True, decode_attn="gather"),
             kernels=_both(paged_full_attention=3, paged_window_attention=6,
                           paged_decode_attention=0),
-            temp=dict(chunk=0.2e9, decode=0.2e9))),
+            temp=dict(chunk=0.2e9, chunk_pair=0.3e9, decode=0.2e9))),
     "nemotron-3-super-120b": _stateful(
-        short="nemotron_h", shrink=_nemotron,
+        short="nemotron_h", pairs=_PAIRS, shrink=_nemotron,
         params=by_runner("nemotron-3-super-120b", 3), tokens=keyed(96, 1),
         forward=((37, 1),), chosen=_ROUTES, fault=(21, 1, 100),
         served=(5, 19, 24), drive=_through_state_rows,
@@ -542,6 +672,10 @@ ENTRIES = {
             gates=dict(grouped_kernels=True, state_kernels=True),
             kernels={"chunk": dict(paged_full_attention=1, ssm_chunk_scan=5,
                                    ssm_decode_update=0),
+                     # its last layer is the attention alone, which feeds
+                     # nothing in a program with no head
+                     "chunk_pair": dict(paged_full_attention=0,
+                                        ssm_chunk_scan=5, ssm_decode_update=0),
                      "decode": dict(paged_full_attention=1, ssm_chunk_scan=0,
                                     ssm_decode_update=5)},
             # half of one layer's rows in float32
@@ -549,7 +683,7 @@ ENTRIES = {
                   for program in ("chunk", "decode")},
             aliased=">=", products=2, wide=False)),   # relu2: no gate matrix
     "sarvam-105b": _paged(
-        short="sarvam_mla", shrink=_sarvam, params=jittered("router_bias"),
+        short="sarvam_mla", pairs=_PAIRS, shrink=_sarvam, params=jittered("router_bias"),
         hashed=(("", {}), ("-spec", dict(spec_tokens=3))),
         forward=((5, 1), (37, 1)), chosen=_ROUTES, fault=(37, 1, 100),
         reused=None,            # its own cases, on both tiers
@@ -566,19 +700,20 @@ ENTRIES = {
             held=(12.4e9, 12.5e9),          # 73.5 % of the chip
             gates=dict(latent_kernels=True),
             # none of the selection's or the window's kernels
-            kernels={"chunk": dict(paged_latent_attention_expanded=5,
-                                   paged_latent_attention=0,
-                                   sparse_latent_attention=0,
-                                   window_latent_attention=0,
-                                   index_scores=0, index_select=0),
+            kernels={**{program: dict(paged_latent_attention_expanded=5,
+                                      paged_latent_attention=0,
+                                      sparse_latent_attention=0,
+                                      window_latent_attention=0,
+                                      index_scores=0, index_select=0)
+                        for program in ("chunk", "chunk_pair")},
                      "decode": dict(paged_latent_attention_expanded=0,
                                     paged_latent_attention=5,
                                     sparse_latent_attention=0,
                                     window_latent_attention=0,
                                     index_scores=0, index_select=0)},
-            temp=dict(chunk=0.6e9, decode=0.6e9))),
+            temp=dict(chunk=0.6e9, chunk_pair=0.6e9, decode=0.6e9))),
     "solar-open2-250b": _stateful(
-        short="solar_open2", shrink=_solar,
+        short="solar_open2", pairs=_PAIRS, shrink=_solar,
         params=by_runner("solar-open2-250b", 3), tokens=keyed(96, 1),
         forward=((41, 1),), chosen=_ROUTES, fault=(29, 4, 50),
         served=(5, 19, 24), drive=_through_state_rows,
@@ -601,9 +736,12 @@ ENTRIES = {
             gates=dict(grouped_kernels=True, state_kernels=False,
                        linear_kernels=True),
             kernels={"chunk": dict(paged_full_attention=1, kda_chunk_scan=3),
+                     "chunk_pair": dict(paged_full_attention=1,
+                                        kda_chunk_scan=3),
                      "decode": dict(paged_full_attention=1, kda_chunk_scan=0)},
             # decode: a second copy of a layer's slots would be this large
-            temp={"chunk": 1.5e9, "decode": 4 * 16 * 64 * 128 * 128},
+            temp={"chunk": 1.5e9, "chunk_pair": 2e9,
+                  "decode": 4 * 16 * 64 * 128 * 128},
             budget=0.8, aliased=">=")),
     "phi-4-mini-flash-reasoning": _stateful(
         short="phi4_flash", shrink=_phi4,
@@ -680,7 +818,7 @@ ENTRIES = {
                       "chunk_tail": dict(head="last", name="chunk_tail")},
             aliased=">=", period=10, wide=False)),
     "mimo-v2-flash": _paged(
-        short="mimo_v2", shrink=_mimo, params=by_runner("mimo-v2-flash", 0),
+        short="mimo_v2", pairs=_PAIRS, shrink=_mimo, params=by_runner("mimo-v2-flash", 0),
         hashed=(("", dict(filed_dtype=True)),),
         forward=((40, 1),), chosen=_ROUTES, fault=(40, 1, 50),
         served=(5, 37, 16), drive=_through_pages_and_rings,
@@ -700,7 +838,7 @@ ENTRIES = {
             kernels=_both(paged_full_attention=2, paged_window_attention=5,
                           paged_decode_attention=0),
             # the file's assumed.serve.why states them: 0.02e9 and 0.011e9
-            temp=dict(chunk=0.05e9, decode=0.05e9))),
+            temp=dict(chunk=0.05e9, chunk_pair=0.12e9, decode=0.05e9))),
 }
 
 
@@ -905,6 +1043,8 @@ class Contract:
         "test_no_speculation_and_no_prefix_cache_over_state": "over_state",
         "test_the_names_the_benchmark_plants_faults_in": "seams",
         "test_what_stood_builds_what_it_built": "stood",
+        "test_two_requests_chunks_in_one_call": "pairs",
+        "test_a_boundary_pairs_the_chunks_that_end_no_prompt": "pairs",
     }
 
     def __init_subclass__(cls):
@@ -1159,6 +1299,92 @@ class Contract:
             lp.chunk_fn.lower(lp.params, lp.cache,
                               *slots(lp.geo, 1, lp.prefill_chunk))
 
+    def test_two_requests_chunks_in_one_call(self, monkeypatch):
+        """``chunk_pair_fn`` on two requests at different offsets (one as a
+        prefix hit leaves it, part of a chunk in) leaves in their pages, rings
+        and state rows what two ``chunk_fn`` calls leave, the chunk behind
+        reads the same logits, and the experts' counts are the two calls'
+        summed; the held experts' products run over BOTH requests' sorted rows
+        in blocks (the block shrunk to 16 rows: a ``while`` of several)."""
+        e, (_, cfg, params) = self.entry, tiny(self.name)
+        if cfg.experts_held:
+            monkeypatch.setattr(tfm, "_HELD_BLOCK", 16)
+        lp = loop(self.name, fresh=True, **e.pairs)
+        q = lp.prefill_chunk
+        found = pair_against_singles(lp, params, (q + lp.geo.page_size, 3 * q))
+        assert found["cache_rel"] < e.tol and found["logits_rel"] < e.tol
+        assert not found["route_flips"].any()
+        singles, pair = found["counts"]
+        if cfg.is_moe(cfg.n_layers - 1):
+            # The last layer's products feed nothing in a program with no
+            # head: they are not run, and not counted.
+            assert not pair[-1].any()
+            singles, pair = [c[:-1] for c in singles], pair[:-1]
+        assert np.array_equal(sum(singles)[:, :-1], pair[:, :-1])
+        assert pair[:, :-1].sum() > 0
+        ran, twice = pair[:, -1], sum(singles)[:, -1]
+        assert (ran <= twice).all()
+        if cfg.experts_held:        # whole blocks, fewer than two calls'
+            assert not (ran % 16).any() and ran.sum() < twice.sum()
+
+    def test_a_boundary_pairs_the_chunks_that_end_no_prompt(self):
+        """Three prompts of 2, 4 and 6 whole chunks and a few tokens arrive
+        together: boundaries hold three, two and one filling request. With
+        ``chunk_pair_fn`` the chunks that end no prompt go two to a call in
+        the order of admission (an odd one, and every chunk that ends a
+        prompt, alone); every request still advances one chunk a boundary, so
+        the tokens are those of the loop without the program, and greedy's."""
+        e, (_, cfg, params) = self.entry, tiny(self.name)
+        lp = loop(self.name, **e.pairs)
+        lp.warmup()
+        q, rng = lp.prefill_chunk, np.random.default_rng(5)
+        prompts = [rng.integers(0, cfg.vocab_size, n * q + 3).tolist()
+                   for n in (2, 4, 6)]
+        counted = ("chunk_fills", "chunk_pair_calls", "chunk_paired")
+        runs = []
+        for paired in (True, False):
+            lp.reset()
+            was = {name: lp.loop_stats[name] for name in counted}
+            routed = dict(lp.tally["moe"]["pairs"])
+            with pytest.MonkeyPatch.context() as without:
+                if not paired:
+                    without.setattr(lp, "chunk_pair_fn", None)
+                _, done = lp.run([
+                    Request(rid=i, prompt=list(p), max_new_tokens=3,
+                            arrival_t=0.0) for i, p in enumerate(prompts)])
+            assert len(done) == 3
+            runs.append((
+                {r.rid: r.generated for r in done},
+                [lp.loop_stats[name] - was[name] for name in counted],
+                lp.tally["moe"]["pairs"]["chunk"] - routed.get("chunk", 0)))
+            for r in done:
+                assert r.generated == greedy(
+                    cfg, params, r, e.geometry[2]), r.rid
+        (tokens, found, routed), (plain_tokens, plain, plain_routed) = runs
+        # 3 + 5 + 7 chunks; (a, b) twice, then (b, c) twice, the rest alone
+        assert found == [15, 4, 8] and plain == [15, 0, 0]
+        assert tokens == plain_tokens
+        # ... and the pairs the experts are counted for, but those of the
+        # last layer in a call of two (its products are not run there).
+        assert (routed < plain_routed if cfg.is_moe(cfg.n_layers - 1)
+                else routed == plain_routed)
+        stats = serve_loop.serve_stats()
+        assert stats["chunk_paired"] == lp.loop_stats["chunk_paired"]
+        assert stats["chunk_paired_share"] == pytest.approx(
+            lp.loop_stats["chunk_paired"] / lp.loop_stats["chunk_fills"])
+
+    def test_the_pair_program_is_built_for_routed_experts_alone(self):
+        """``chunk_pair_fn`` follows from what the loop can see: a chunk
+        program that runs routed experts and ends its chunks at a prompt's
+        end alone. A dense model, a fill that leaves the stack and a fill
+        with a cut head build none."""
+        e = self.entry
+        for _, kw in e.hashed:
+            lp = loop(self.name, abstract=True, **kw)
+            assert (lp.chunk_pair_fn is not None) == bool(
+                e.pairs and lp.chunk_fn is not None), kw
+            assert {"chunk_pair_calls", "chunk_paired"} <= set(lp.loop_stats)
+
     def test_what_stood_builds_what_it_built(self):
         """The tiny model makes the tree, the parameters' bits and the logits
         it made when its kind was added: a kind added since, or a refactor of
@@ -1286,6 +1512,9 @@ class CellPrograms:
                         (c.max_batch,))
             # the fill's last tokens go through a program a page wide
             q = geo.page_size if program == "chunk_tail" else c.chunk
+            if program == "chunk_pair":     # two requests' rows, no head
+                return engine.make_chunk_step(cfg, geo, q_len=q,
+                                              head="none"), (2, q)
             return engine.make_chunk_step(
                 cfg, geo, q_len=q, **(want.programs or {}).get(program, {})
             ), (1, q)
@@ -1344,13 +1573,30 @@ class CellPrograms:
                 else p.memory.alias_size_in_bytes >= cached)
         assert built.held + p.memory.temp_size_in_bytes + p.fresh \
             < want.budget * CHIP_BYTES, (program, p.memory.temp_size_in_bytes)
-        if want.temp:
+        if want.temp and program in want.temp:
             assert p.memory.temp_size_in_bytes < want.temp[program], program
         assert {kernel: len(kernel_calls(p.text, kernel))
                 for kernel in want.kernels[program]} == want.kernels[program]
+        layers = len(cfg.moe_layers)
+        if program == "chunk_pair":
+            # No head: no float32 logits of the two chunks' positions, and
+            # the program's outputs beside the cache are the routing's few MB.
+            # What feeds nothing else is not run: the last layer's experts.
+            # The others' products take BOTH requests' sorted rows in blocks
+            # the compiler tiles by 256, one loop a layer.
+            single = built.programs["chunk"]
+            assert not [out for out in jax.tree.leaves(p.lowered.out_info)
+                        if out.shape[-1:] == (cfg.vocab_size,)
+                        and out.dtype == jnp.float32]
+            assert p.fresh < single.fresh / 2
+            layers -= cfg.is_moe(cfg.n_layers - 1)
+            assert set(re.findall(r'ragged_dot_tiling="(\d+),', p.text)) \
+                == {"256"}
+            assert len(re.findall(r"ragged-dot-metadata[.\d]* = ", p.text)) \
+                == layers
         if cfg.n_experts:
             assert len(re.findall(r"%ragged-dot-none[.\d]* = ", p.text)) \
-                == want.products * len(cfg.moe_layers)
+                == want.products * layers
         if want.wide:
             assert not re.search(
                 rf"(f32|bf16)\[(\d+,)*{geo.max_kv}(,\d+)*\]", p.text), program

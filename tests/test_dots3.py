@@ -95,7 +95,7 @@ class TestCellPrograms(served.CellPrograms):
         # compiler tiles by 256 (``transformer._HELD_BLOCK`` rests on that
         # rule), a decode step's 128 rows in one product as before.
         assert set(re.findall(r'ragged_dot_tiling="(\d+),', p.text)) \
-            == {"256" if program == "chunk" else "128"}
+            == {"128" if program == "decode" else "256"}
         block = rows * 64 * geo.max_kv
         for m in re.finditer(r" = f32\[([\d,]+)\]", p.text):
             assert int(np.prod([int(d) for d in m.group(1).split(",")])) \

@@ -94,7 +94,7 @@ class TestCellPrograms(served.CellPrograms):
         # compiler tiles by 256 (``transformer._HELD_BLOCK`` rests on that
         # rule), a decode step's 320 rows in one product as before.
         assert set(re.findall(r'ragged_dot_tiling="(\d+),', p.text)) \
-            == {"256" if program == "chunk" else "64"}
+            == {"64" if program == "decode" else "256"}
 
 
 def test_the_file_describes_its_layers():

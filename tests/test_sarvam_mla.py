@@ -91,7 +91,7 @@ class TestCellPrograms(served.CellPrograms):
         64, 640]``, no output in the latent ``[.., 64, 512]``."""
         a = built.cfg.attn_of(0)
         absorbed = {f"{a.n_heads},{a.row_width}", f"{a.n_heads},{a.kv_rank}"}
-        if program == "chunk":
+        if program != "decode":
             for m in re.finditer(r" = (?:f32|bf16)\[([\d,]+)\]", p.text):
                 assert ",".join(m.group(1).split(",")[-2:]) not in absorbed, \
                     m.group(0)

@@ -712,7 +712,11 @@ def test_warmup_leaves_the_loop_nothing_to_compile(model, monkeypatch):
         assert set(transfers[warm:]) == {1}
         moe = stats["moe"]
         assert moe["calls"]["decode"] == stats["decode_calls"]
-        assert moe["calls"]["chunk"] == stats["chunk_fills"] > 8
+        # a program a chunk, but one for two where two requests' chunks
+        # that end no prompt shared a call
+        assert stats["chunk_fills"] > 8 and stats["chunk_pair_calls"] > 0
+        assert moe["calls"]["chunk"] == (stats["chunk_fills"]
+                                         - stats["chunk_pair_calls"])
 
 
 # ---------------------------------------------------------------------------

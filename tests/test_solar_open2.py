@@ -129,7 +129,7 @@ class TestCellPrograms(served.CellPrograms):
     def also_program(self, built, program, p):
         assert not [line for line in p.text.splitlines()
                     if " while(" in line and "f32[32,1,64,128,128]" in line]
-        if program == "chunk":      # nothing walks the positions one by one
+        if program != "decode":     # nothing walks the positions one by one
             assert not re.search(r"f32\[2048,1,64,128(,128)?\]", p.text)
 
 

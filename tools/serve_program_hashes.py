@@ -3,8 +3,9 @@
 A refactor of ``serving/engine.py`` or ``serving/loop.py`` that is meant to
 change no program's instructions runs this on both trees and compares the
 two outputs: ``jit_fn.lower(...).as_text()`` of ``decode_fn``, ``chunk_fn`` (and
-``chunk_end_fn`` where it is a program of its own), ``spec_fn`` and, where
-built, ``prefill_fn`` and ``bprefill_fn``, with the
+``chunk_end_fn`` where it is a program of its own, ``chunk_pair_fn`` where
+the loop pairs two requests' chunks), ``spec_fn`` and, where built,
+``prefill_fn`` and ``bprefill_fn``, with the
 arguments ``ServeLoop.warmup`` hands them. Nothing is compiled or run.
 Each program gets two hashes: ``text`` of the lowered text, ``cse`` of the
 same module after MLIR's common-subexpression pass, which is what stays equal
@@ -98,6 +99,10 @@ def _programs(loop, like):
         # The fill's last few tokens, one page wide (with a cut head only).
         "chunk_tail": (getattr(loop, "chunk_tail_fn", None),
                        slots(1, geo.page_size)),
+        # Two requests' chunks that end no prompt in one call (a model with
+        # routed experts; an attribute only since the loop pairs them).
+        "chunk_pair": (getattr(loop, "chunk_pair_fn", None),
+                       slots(2, loop.prefill_chunk)),
         "spec": (loop.spec_fn, slots(B, loop.spec_tokens + 1)),
         "prefill": (loop.prefill_fn, [like((geo.max_kv,), np.int32),
                                       like((), np.int32),
