@@ -49,6 +49,8 @@ def full_attention(cfg, counts):
 
 
 def window_attention(cfg, counts):
+    if not _layers(cfg, "sliding_attention"):
+        return 0, 0     # a model of full layers alone: no call, no counter
     return _attention(cfg, "sliding_attention", counts["qk_window_pairs"],
                       counts["kv_window_rows"], counts["queries"])
 

@@ -32,9 +32,19 @@ def expert_product_bytes(cfg, pairs, expert_reads):
     return weights + rows
 
 
-def least_seconds(cfg, pairs, expert_reads, peak):
+def products_least_seconds(cfg, pairs, expert_reads, peak):
     """The roofline's floor: the larger of operations over the chip's bf16
     peak and bytes over its memory bandwidth (``peaks.json`` entry)."""
     return max(expert_product_flops(cfg, pairs) / (peak["bf16_tflops"] * 1e12),
                expert_product_bytes(cfg, pairs, expert_reads)
                / (peak["hbm_gbps"] * 1e9))
+
+
+def least_seconds(cfg, part, counts, peak):
+    """:func:`products_least_seconds` under the signature every ``flops_*.py``
+    gives ``readers/trace_roofline.py``: one program kind's counters
+    (``pairs``, ``expert_reads``), the one part ``expert_products``."""
+    if part != "expert_products":
+        raise KeyError(part)
+    return products_least_seconds(cfg, counts["pairs"],
+                                  counts["expert_reads"], peak)

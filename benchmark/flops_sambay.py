@@ -118,6 +118,11 @@ PARTS = {"scan_update": (scan_update,), "scan_window": (scan_window,),
          "shared_attention": (shared_attention,),
          "window_attention": (window_attention,),
          "chunk_attention": (shared_attention, window_attention)}
+# The names the shared entries of ``BENCHMARK.json`` ask every model for: a
+# decode step's state layers, a chunk's, the full layer's kernel.
+PARTS.update(state_update=PARTS["scan_update"],
+             state_scan=PARTS["scan_window"],
+             full_attention=PARTS["shared_attention"])
 
 
 def least_seconds(cfg, part, counts, peak):

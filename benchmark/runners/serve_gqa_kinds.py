@@ -13,11 +13,13 @@ Driven by data alone, with these differences from ``serve_gqa``:
   ``dense_layers`` are checked against the published ``hybrid_layer_pattern``
   (0 = ``full_attention``, 1 = ``sliding_attention``) and ``moe_layer_freq``
   (its leading zeros); there is no ``num_attention_heads_per_layer``;
-- weights: ``serve_lm``'s (norm scales N(1, 0.1)), every sink uniform over
+- weights: ``serve_lm``'s (norm scales N(1, 0.1)) with the embedding
+  ``EMBED_SCALE`` times as large (a token leads its own row of the stream, and
+  not the running mean of its prompt's values), every sink uniform over
   (ln window - 0.7, ln window + 0.3), and the router's selection bias solved
   for an even load as ``serve_linear`` solves it (:func:`make_params`): none
-  can be left out unseen, and a seed's tilt does not decide how many of the
-  held experts a step reads;
+  can be left out unseen, and a seed's tilt does not decide how many rows
+  the held experts get;
 - ``assumed.serve.chunk`` is handed to the loop as ``prefill_chunk``;
 - a tree whose ``MultiHeadAttention`` has no ``v_head_dim`` ends this runner
   AT IMPORT, in ``run.py``'s own process, before any worker or device is
@@ -40,6 +42,7 @@ ATTN_COUNTERS = ("kv_full_rows", "kv_window_rows", "kv_window_rows_as_full",
                  "qk_full_pairs", "qk_window_pairs", "kv_full_bytes",
                  "kv_window_bytes", "sink_rows", "queries", "calls")
 KIND_NAMES = ("full_attention", "sliding_attention")
+EMBED_SCALE = 10.0      # the embedding N(0, 0.2), not init_params' N(0, 0.02)
 
 
 def _kinds_take_a_value_width():
@@ -86,7 +89,16 @@ def model_config(config):
 
 
 def make_params(cfg, key):
-    """``serve_lm``'s weights (norm scales N(1, 0.1)); every sink drawn
+    """``serve_lm``'s weights (norm scales N(1, 0.1)) with the embedding
+    ``EMBED_SCALE`` times ``init_params``' N(0, 0.02): this model's full
+    layers sharpen no softmax, so over seeded keys layer 0 returns the
+    running MEAN of its prompt's values (0.04 rms at 512 keys), which led a
+    row of 0.02 rms: every token of a request then scored the experts alike,
+    a request's share of the held experts read 0.6-1.5 of even a layer, the
+    bias solved on eight sequences did not carry to the next, and the SEED
+    decided the rows the held experts ran, 2.1-3.7 a chunk token for an even
+    share's 3, and with them ``serve_tok_s`` (613-654; PERF.md, PR 64). At
+    0.2 rms the token leads its own row. Every sink drawn
     uniform over (ln w - 0.7, ln w + 0.3), ``w`` the kind's window: under
     seeded weights a score is N(0, 1), a full window's ``sum exp(s)`` about
     ``w e^0.5``, and such a sink holds 0.23 to 0.45 of the row's softmax
@@ -107,7 +119,12 @@ def make_params(cfg, key):
 
     from benchmark.runners import serve_linear, serve_lm
 
+    if cfg.tie_embeddings:
+        raise SystemExit("runner serve_gqa_kinds scales the embedding and "
+                         "not the head: the two have to be apart")
     params = serve_lm.make_params(cfg, key)
+    params = dict(params, embed=params["embed"] * jnp.asarray(
+        EMBED_SCALE, params["embed"].dtype))
     layers = list(params["layers"])
     for li, layer in enumerate(layers):
         if "sink" not in layer:

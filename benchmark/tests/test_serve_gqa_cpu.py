@@ -89,13 +89,13 @@ def test_grouped_cell_rehearsal(monkeypatch, capsys, trace):
     # No device plane on a CPU: the trace readers find nothing and say so;
     # the counters' metrics are there.
     assert f["trace_attn"]["qk_window_pairs"]["chunk"] > 0
-    for name in ("kv_ring_share.agent", "route_flip_share",
+    for name in ("kv_ring_share", "route_flip_share",
                  "experts_touched_mean.over", "batch_fill_mean.over",
                  "runtime_init_s"):
         assert name in line["metrics"], name
-    for name in ("chunk_step_dev_ms.agent", "full_attn_dev_ms.agent",
-                 "window_attn_roofline.agent", "chunk_attn_roofline.agent",
-                 "expert_mm_roofline.agent", "decode_step_dev_ms"):
+    for name in ("chunk_step_dev_ms", "full_attn_dev_ms",
+                 "window_attn_roofline", "chunk_attn_roofline",
+                 "expert_mm_roofline", "decode_step_dev_ms"):
         assert name not in line["metrics"], name
 
 

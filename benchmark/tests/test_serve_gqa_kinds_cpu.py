@@ -86,17 +86,16 @@ def test_kinds_cell_rehearsal(monkeypatch, capsys, trace):
     # No device plane on a CPU: the trace readers find nothing and say so;
     # the counters' metrics are there.
     assert f["trace_attn"]["qk_window_pairs"]["chunk"] > 0
-    # (the cell's counters and device times ride the ``.agent`` metrics: the
-    # benchmark's list of per-layer metrics is full at 128, PERF.md PR 59)
-    for name in ("kv_ring_share.agent", "route_flip_share",
+    # (a reading has ONE entry for every cell that takes it: this cell is in
+    # the lists Laguna's is in, priced by its own ``flops_gqa_kinds``)
+    for name in ("kv_ring_share", "route_flip_share",
                  "experts_touched_mean.over", "batch_fill_mean.over",
                  "runtime_init_s"):
         assert name in line["metrics"], name
-    for name in ("chunk_step_dev_ms.agent", "full_attn_dev_ms.agent",
-                 "full_attn_roofline.mixed", "window_attn_roofline.mixed",
-                 "chunk_attn_roofline.mixed", "expert_mm_roofline.agent",
-                 "decode_step_dev_ms", "full_attn_roofline.agent",
-                 "chunk_attn_roofline.agent"):
+    for name in ("chunk_step_dev_ms", "full_attn_dev_ms",
+                 "full_attn_roofline", "window_attn_roofline",
+                 "chunk_attn_roofline", "expert_mm_roofline",
+                 "decode_step_dev_ms"):
         assert name not in line["metrics"], name
 
 
@@ -150,3 +149,48 @@ def test_a_tree_without_the_value_width_ends_the_runner_at_import(
     monkeypatch.undo()
     assert serve_gqa_kinds._kinds_take_a_value_width()
     importlib.reload(serve_gqa_kinds)
+
+
+def _tiny_cfg(**model):
+    import dataclasses
+
+    config = dict(bench_run.load_json(
+        bench_run.CHECKOUT, "benchmark", "configs", "mimo-v2-flash.json"),
+        **TINY)
+    return dataclasses.replace(serve_gqa_kinds.model_config(config),
+                               dtype="float32", param_dtype="float32",
+                               **model)
+
+
+def test_the_embedding_is_ten_times_init_params_and_the_head_is_not():
+    """The runner's repair of the seeded weights (PERF.md, PR 64): a token
+    leads its own row of the stream, so that a request's tokens do not all
+    choose the same experts and the seed does not decide the held experts'
+    rows. The head keeps ``init_params``' draw (but for the mean that
+    ``balance_routers`` takes out of its rows)."""
+    import jax
+    import numpy as np
+
+    from benchmark.runners import serve_lm
+
+    cfg, key = _tiny_cfg(), jax.random.PRNGKey(7)
+    plain = serve_lm.make_params(cfg, key)
+    made = serve_gqa_kinds.make_params(cfg, key)
+    assert serve_gqa_kinds.EMBED_SCALE == 10.0
+    np.testing.assert_allclose(np.asarray(made["embed"]),
+                               10.0 * np.asarray(plain["embed"]), rtol=1e-6)
+    assert abs(float(np.asarray(made["embed"]).std()) - 0.2) < 0.01
+    head = np.asarray(made["head"])
+    assert abs(float(head.std()) - float(np.asarray(plain["head"]).std())) \
+        < 0.1 * float(head.std())
+    for got, was in zip(made["layers"], plain["layers"]):
+        np.testing.assert_array_equal(np.asarray(got["wq"]),
+                                      np.asarray(was["wq"]))
+
+
+def test_tied_embeddings_end_the_runner():
+    import jax
+
+    with pytest.raises(SystemExit, match="scales the embedding"):
+        serve_gqa_kinds.make_params(_tiny_cfg(tie_embeddings=True),
+                                    jax.random.PRNGKey(0))

@@ -83,13 +83,13 @@ def test_hybrid_cell_rehearsal(monkeypatch, capsys, trace):
     # No device plane on a CPU: the trace readers find nothing and say so;
     # the counters' metrics are there.
     assert f["trace_state"]["bytes"]["decode"] > 0
-    for name in ("state_bytes_share.reason", "route_flip_share",
+    for name in ("state_bytes_share", "route_flip_share",
                  "experts_touched_mean.over", "batch_fill_mean.over",
                  "runtime_init_s"):
         assert name in line["metrics"], name
-    for name in ("ssm_decode_dev_ms.reason", "ssm_decode_roofline.reason",
-                 "ssm_scan_dev_ms.reason", "ssm_scan_roofline.reason",
-                 "expert_mm_roofline.reason", "chunk_step_dev_ms.reason",
+    for name in ("ssm_decode_dev_ms", "ssm_decode_roofline",
+                 "ssm_scan_dev_ms", "ssm_scan_roofline",
+                 "expert_mm_roofline", "chunk_step_dev_ms",
                  "decode_step_dev_ms"):
         assert name not in line["metrics"], name
 

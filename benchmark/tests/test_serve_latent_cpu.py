@@ -89,10 +89,10 @@ def test_full_latent_cell_rehearsal(monkeypatch, capsys, trace):
     for name in ("route_flip_share", "experts_touched_mean.over",
                  "batch_fill_mean.over", "runtime_init_s"):
         assert name in line["metrics"], name
-    for name in ("chunk_step_dev_ms.longdoc", "latent_attn_dev_ms.longdoc",
-                 "latent_attn_roofline.longdoc",
-                 "chunk_latent_attn_roofline.longdoc",
-                 "expert_mm_roofline.longdoc", "decode_step_dev_ms"):
+    for name in ("chunk_step_dev_ms", "latent_attn_dev_ms",
+                 "latent_attn_roofline",
+                 "chunk_latent_attn_roofline",
+                 "expert_mm_roofline", "decode_step_dev_ms"):
         assert name not in line["metrics"], name
 
 

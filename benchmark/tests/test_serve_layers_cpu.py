@@ -81,13 +81,13 @@ def test_layered_cell_rehearsal(monkeypatch, capsys, trace):
     # No device plane on a CPU: the trace readers find nothing and say so;
     # the counters' metrics are there.
     assert f["trace_attn"]["kv_scored"]["chunk"] > 0
-    for name in ("kv_select_share.doc", "select_flip_share",
+    for name in ("kv_select_share", "select_flip_share",
                  "route_flip_share", "experts_touched_mean.over",
                  "batch_fill_mean.over", "runtime_init_s"):
         assert name in line["metrics"], name
-    for name in ("chunk_step_dev_ms.doc", "index_select_dev_ms.doc",
-                 "sparse_attn_roofline.doc", "window_attn_roofline.doc",
-                 "index_roofline.doc", "expert_mm_roofline.doc",
+    for name in ("chunk_step_dev_ms", "index_select_dev_ms",
+                 "sparse_attn_roofline", "window_latent_attn_roofline",
+                 "index_roofline", "expert_mm_roofline",
                  "decode_step_dev_ms"):
         assert name not in line["metrics"], name
 

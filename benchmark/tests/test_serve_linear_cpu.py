@@ -88,15 +88,15 @@ def test_linear_cell_rehearsal(monkeypatch, capsys, trace):
     # No device plane on a CPU: the trace readers find nothing and say so;
     # the counters' metrics are there.
     assert f["trace_state"]["delta_bytes"]["decode"] > 0
-    for name in ("state_bytes_share.longctx", "route_flip_share",
+    for name in ("state_bytes_share", "route_flip_share",
                  "experts_touched_mean.over", "batch_fill_mean.over",
                  "runtime_init_s"):
         assert name in line["metrics"], name
-    for name in ("kda_decode_dev_ms.longctx", "kda_decode_roofline.longctx",
-                 "kda_scan_dev_ms.longctx", "kda_scan_roofline.longctx",
-                 "expert_mm_roofline.longctx", "chunk_step_dev_ms.longctx",
-                 "chunk_attn_dev_ms.longctx", "chunk_attn_roofline.longctx",
-                 "full_attn_dev_ms.longctx", "full_attn_roofline.longctx",
+    for name in ("kda_decode_dev_ms", "kda_decode_roofline",
+                 "kda_scan_dev_ms", "kda_scan_roofline",
+                 "expert_mm_roofline", "chunk_step_dev_ms",
+                 "chunk_attn_dev_ms", "chunk_attn_roofline",
+                 "full_attn_dev_ms", "full_attn_roofline",
                  "decode_step_dev_ms"):
         assert name not in line["metrics"], name
 

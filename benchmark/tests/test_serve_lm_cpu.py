@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from benchmark import harness, run as bench_run
-from benchmark.readers import trace_expert_products
+from benchmark.readers import trace_expert_products, trace_roofline
 from benchmark.runners import serve_lm, train
 
 from conftest import CHECKOUT
@@ -72,7 +72,7 @@ def test_olmoe_cell_rehearsal(monkeypatch, capsys, trace):
     for name in ("experts_touched_mean.over", "route_flip_share",
                  "expert_load_max_over_mean.over", "batch_fill_mean.over"):
         assert name in line["metrics"]
-    for name in ("moe_dev_ms.over", "expert_mm_roofline.over",
+    for name in ("moe_dev_ms.over", "expert_mm_roofline",
                  "decode_step_dev_ms"):
         assert name not in line["metrics"]
 
@@ -127,7 +127,8 @@ def test_expert_products_reader_on_a_handmade_trace():
     modules = [(3, 0, 1_000_000), (3, 2_000_000, 1_000_000),
                (4, 4_000_000, 1_000_000)]
     config = {"hidden_size": 2048, "intermediate_size": 1024,
-              "model": {"param_dtype": "bfloat16"}}
+              "model": {"param_dtype": "bfloat16"},
+              "flops": {"expert_products": "flops_moe"}}
     counts = {"pairs": {"decode": 2 * 64 * 12, "chunk": 4096 * 12},
               "expert_reads": {"decode": 2 * 40 * 12, "chunk": 64 * 12},
               "calls": {"decode": 2, "chunk": 1}}
@@ -139,9 +140,12 @@ def test_expert_products_reader_on_a_handmade_trace():
         "what": "ms_per_run", "pattern": "^ragged-dot",
         "program": r"^jit_decode\b"})
     assert per_run == pytest.approx(0.3)
-    share = trace_expert_products.read(ctx, {
-        "what": "roofline", "pattern": "^ragged-dot-none",
-        "counts_field": "trace_moe"})
+    # the file of ``expert_mm_roofline``, priced by this configuration's
+    # ``flops_moe``: the formula of the reader it took the place of
+    roofline = bench_run.load_json(CHECKOUT, "benchmark", "layer_metrics",
+                                   "expert_mm_roofline.json")
+    assert roofline["reader"] == "trace_roofline"
+    share = trace_roofline.read(ctx, roofline["params"])
     mat = 3 * 2048 * 1024 * 2
     row = (2 * (2048 + 1024) + 1024 + 2048) * 2
     least = sum(max(6 * 2048 * 1024 * p / 197e12, (mat * r + row * p) / 819e9)
@@ -149,9 +153,7 @@ def test_expert_products_reader_on_a_handmade_trace():
     assert share == pytest.approx(100 * least / 0.8e-3)
     # A program without such operations, or a run without counters: nothing.
     ctx["fields"] = {}
-    assert trace_expert_products.read(ctx, {
-        "what": "roofline", "pattern": "^ragged-dot-none",
-        "counts_field": "trace_moe"}) is None
+    assert trace_roofline.read(ctx, roofline["params"]) is None
     assert trace_expert_products.read(ctx, {
         "what": "ms_per_run", "pattern": "^no-such-op",
         "program": r"^jit_decode\b"}) is None

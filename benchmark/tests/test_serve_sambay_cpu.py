@@ -89,16 +89,16 @@ def test_sambay_cell_rehearsal(monkeypatch, capsys, trace):
     # No device plane on a CPU: the trace readers find nothing and say so;
     # the counters' metrics are there.
     assert f["trace_state"]["scan_bytes"]["decode"] > 0
-    for name in ("state_bytes_share.think", "kv_ring_share.think",
-                 "tail_rows_share.think", "batch_fill_mean.over",
+    for name in ("state_bytes_share", "kv_ring_share",
+                 "tail_rows_share", "batch_fill_mean.over",
                  "runtime_init_s"):
         assert name in line["metrics"], name
-    for name in ("ssm_decode_dev_ms.think", "ssm_decode_roofline.think",
-                 "ssm_scan_dev_ms.think", "ssm_scan_roofline.think",
-                 "gmu_dev_ms.think", "shared_attn_dev_ms.think",
-                 "shared_attn_roofline.think", "window_attn_dev_ms.think",
-                 "window_attn_roofline.think", "chunk_attn_dev_ms.think",
-                 "chunk_attn_roofline.think", "chunk_step_dev_ms.think",
+    for name in ("ssm_decode_dev_ms", "ssm_decode_roofline",
+                 "ssm_scan_dev_ms", "ssm_scan_roofline",
+                 "gmu_dev_ms", "full_attn_dev_ms",
+                 "full_attn_roofline", "window_attn_dev_ms",
+                 "window_attn_roofline", "chunk_attn_dev_ms",
+                 "chunk_attn_roofline", "chunk_step_dev_ms",
                  "decode_step_dev_ms"):
         assert name not in line["metrics"], name
 
@@ -126,8 +126,8 @@ def _ring_one_page_short(monkeypatch):
     from horovod_tpu.serving import kv_cache
     sound = kv_cache.with_rings
 
-    def short(geo, cfg, q_len, max_batch):
-        geo = sound(geo, cfg, q_len, max_batch)
+    def short(geo, cfg, q_len, max_batch, **rows):
+        geo = sound(geo, cfg, q_len, max_batch, **rows)
         return dataclasses.replace(
             geo, ring_blocks=geo.ring_blocks - 1,
             ring_pages=max_batch * (geo.ring_blocks - 1) + 1)

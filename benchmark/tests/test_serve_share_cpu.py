@@ -102,18 +102,18 @@ def test_share_cell_rehearsal(monkeypatch, capsys, trace):
     if not trace:
         assert set(line["metrics"]) == {"serve_tok_s", "setup_s"}
         return
-    for name in ("prefix_hit_token_share.share",
-                 "snapshot_rows_used_mean.share", "snapshot_evictions.share",
-                 "state_bytes_share.share", "batch_fill_mean.over",
+    for name in ("prefix_hit_token_share",
+                 "snapshot_rows_used_mean", "snapshot_evictions",
+                 "state_bytes_share", "batch_fill_mean.over",
                  "runtime_init_s"):
         assert name in line["metrics"], name
     # No device plane on a CPU: the trace readers find nothing and say so.
-    for name in ("ssm_decode_dev_ms.share", "ssm_decode_roofline.share",
-                 "ssm_scan_dev_ms.share", "ssm_scan_roofline.share",
-                 "full_attn_dev_ms.share", "full_attn_roofline.share",
-                 "chunk_attn_dev_ms.share", "chunk_attn_roofline.share",
-                 "chunk_step_dev_ms.share", "state_snapshot_dev_ms.share",
-                 "state_restore_dev_ms.share", "decode_step_dev_ms"):
+    for name in ("ssm_decode_dev_ms", "ssm_decode_roofline",
+                 "ssm_scan_dev_ms", "ssm_scan_roofline",
+                 "full_attn_dev_ms", "full_attn_roofline",
+                 "chunk_attn_dev_ms", "chunk_attn_roofline",
+                 "chunk_step_dev_ms", "state_snapshot_dev_ms",
+                 "state_restore_dev_ms", "decode_step_dev_ms"):
         assert name not in line["metrics"], name
 
 

@@ -103,6 +103,12 @@ def generate(traffic, seconds, seed, vocab):
     order = np.concatenate([rng.permutation(groups[b])
                             for b in rng.permutation(len(groups))])
     burst = int(traffic.get("burst_at_start", 0))
+    if burst >= n:
+        # the span would be divided among the requests BEHIND the burst:
+        # none, or fewer than none, and every arrival dated minutes late
+        raise ValueError(
+            f"burst_at_start {burst} is not under the {n} requests that "
+            f"rate_rps {traffic['rate_rps']} offers in {seconds} s")
     slot = float(seconds) / (n - burst)
     due = (np.arange(-burst, n - burst) + rng.uniform(size=n)) * slot
     due = np.maximum(due, 1e-6)         # the burst, and no time exactly 0
