@@ -198,7 +198,27 @@ class MultiHeadAttention:
     one learned scalar ``b_j`` a query head a layer joins the softmax's
     DENOMINATOR and nothing else, ``a_k = exp(s_k) / (exp(b_j) + sum_k'
     exp(s_k'))``: a column appended to the scores and dropped after the
-    softmax, never a stored key."""
+    softmax, never a stored key.
+
+    ``qk_head_norm``: an RMSNorm over each head's query and each head's key
+    (one scale of ``head_dim`` a layer for the queries' heads, one for the
+    keys'), before the rotation.
+
+    ``select_topk`` > 0: a learned selection of key/value BLOCKS. Positions
+    lie in blocks of ``select_block``; each key/value head's group of query
+    heads chooses for itself, a query at a time. An indexer of
+    ``index_heads`` heads of ``index_dim`` a group (projections of the
+    layer's normed input, no rotation) keeps ONE pooled row a block, the
+    elementwise maximum of the block's indexer keys, and scores a whole
+    block ``n`` for the query at ``t`` as ``I = sum_j w_j relu(qI_j .
+    pooled_n)``. A query in block ``bt`` always attends the first
+    ``select_first`` blocks and the ``select_local`` last ones (``bt -
+    select_local + 1 .. bt``); the candidates are the whole blocks between,
+    and the ``select_topk`` of highest ``I`` join them (ties to the lower
+    index; all of them while no more are candidates, so a short context is
+    attended whole). Softmax over the visible positions of the chosen
+    blocks (:func:`block_scores`, :func:`select_blocks`,
+    :func:`blocks_allowed`)."""
     n_heads: int
     n_kv_heads: int
     head_dim: int
@@ -214,6 +234,13 @@ class MultiHeadAttention:
     v_head_dim: Optional[int] = None
     value_scale: float = 1.0
     sink: bool = False
+    qk_head_norm: bool = False
+    select_block: int = 0
+    select_topk: int = 0
+    select_first: int = 1
+    select_local: int = 2
+    index_heads: int = 0
+    index_dim: int = 0
 
     def __post_init__(self):
         if isinstance(self.yarn, dict):
@@ -247,6 +274,16 @@ class MultiHeadAttention:
             raise ValueError("a sink is not written for differential "
                              "attention or a layer that attends another's "
                              "keys and values")
+        if self.select_topk and (
+                self.window or self.differential or self.sink or self.bias
+                or self.kv_from is not None or self.select_block < 1
+                or self.index_heads < 1 or self.index_dim < 1
+                or self.select_first < 1 or self.select_local < 1):
+            raise ValueError("a block selection is written for plain grouped "
+                             "heads over their whole context (no window, "
+                             "pairs, sink, bias or shared keys and values) "
+                             "and needs select_block, index_heads, index_dim "
+                             "and at least one first and one local block")
 
     @property
     def query_mult(self):
@@ -292,6 +329,11 @@ class MultiHeadAttention:
         return dataclasses.replace(
             self, n_kv_heads=self.n_kv_heads // 2,
             head_dim=2 * self.head_dim, differential=False)
+
+    @property
+    def pool_width(self):
+        """Lanes of a block's pooled row: the groups' indexer keys, fused."""
+        return self.n_kv_heads * self.index_dim
 
     @property
     def split_kv(self):
@@ -534,6 +576,15 @@ class TransformerConfig:
     qk_norm: bool = False       # RMSNorm over the whole projected Q and K
     ffn: str = "gelu"           # | "swiglu": silu(x Wg) * (x Wu), then Wd
     #                             | "relu2": relu(x W1)^2, then W2
+    # A clamped gated feed-forward (``swigluoai``), dense, shared and routed
+    # alike: with ``g = min(x Wg, limit)`` and ``u = clip(x Wu, -limit,
+    # limit)`` the hidden activation is ``g sigmoid(alpha g) (u + 1)``.
+    # ``swiglu_limit`` 0 = the plain ``silu(x Wg) (x Wu)``.
+    swiglu_limit: float = 0.0
+    swiglu_alpha: float = 1.0
+    # RMSNorm in the form ``x / rms(x) (1 + w)`` (every norm of the block,
+    # the final one and a kind's per-head Q/K norm); ``w`` starts at zero.
+    norm_plus_one: bool = False
     tie_embeddings: bool = True  # False: a separate output head "head"
     # Three scalars a source may state (Granite's ``embedding_multiplier``,
     # ``residual_multiplier``, ``logits_scaling``): the token embeddings times
@@ -613,6 +664,12 @@ class TransformerConfig:
             if getattr(self, field) not in allowed:
                 raise ValueError(f"{field} must be one of {allowed}, got "
                                  f"{getattr(self, field)!r}")
+        if (self.swiglu_limit or self.swiglu_alpha != 1.0) and (
+                self.ffn != "swiglu" or self.swiglu_limit <= 0):
+            raise ValueError("swiglu_limit > 0 (and swiglu_alpha with it) is "
+                             "the clamped form of ffn='swiglu'")
+        if self.norm_plus_one and self.norm != "rmsnorm":
+            raise ValueError("norm_plus_one is RMSNorm's (1 + w) form")
         if self.n_experts and not 1 <= self.top_k <= self.n_experts:
             raise ValueError(f"top_k {self.top_k} must lie in 1.."
                              f"n_experts {self.n_experts}")
@@ -695,6 +752,12 @@ class TransformerConfig:
         return [li for li in range(self.n_layers) if self.is_moe(li)]
 
     @property
+    def selects_blocks(self):
+        """Whether any layer's kind selects key/value blocks: such a model's
+        cache holds pooled rows beside its pages (``serving/kv_cache``)."""
+        return any(a.select_topk for _, a in self.multihead)
+
+    @property
     def n_held(self):
         """Experts whose weights this device holds."""
         return self.experts_held[1] if self.experts_held else self.n_experts
@@ -741,7 +804,7 @@ def _norm_params(cfg, shape):
     ``param_dtype`` float32 as they always were; a bf16 model holds them
     in bf16 like its published weights."""
     pdt = jnp.dtype(cfg.param_dtype)
-    p = {"scale": jnp.ones(shape, pdt)}
+    p = {"scale": (jnp.zeros if cfg.norm_plus_one else jnp.ones)(shape, pdt)}
     if cfg.norm == "layernorm":
         p["bias"] = jnp.zeros(shape, pdt)
     return p
@@ -812,7 +875,9 @@ def _multihead_params(key, cfg, a: MultiHeadAttention):
     ``wv``, where a value head has a width of its own), the output
     projection, the head gate; with ``bias`` a bias on each projection; with
     ``differential`` the four ``lambda`` vectors (N(0, 0.1)) and the scale of
-    the pairs' norm; with ``sink`` one scalar a query head, zeros."""
+    the pairs' norm; with ``sink`` one scalar a query head, zeros; with
+    ``qk_head_norm`` the two per-head norms' scales; with a block selection
+    the indexer's three projections, a key/value group each."""
     D, pdt = cfg.d_model, jnp.dtype(cfg.param_dtype)
     k = jax.random.split(key, 5)
     p = {"wq": _dense_init(k[0], (D, a.n_heads, a.head_dim), D, pdt),
@@ -826,6 +891,16 @@ def _multihead_params(key, cfg, a: MultiHeadAttention):
         p["wkv"] = _dense_init(k[1], (D, 2, a.n_kv_heads, a.head_dim), D, pdt)
     if a.sink:
         p["sink"] = jnp.zeros((a.n_heads,), pdt)
+    if a.qk_head_norm:
+        start = jnp.zeros if cfg.norm_plus_one else jnp.ones
+        for name in ("q_head_norm", "k_head_norm"):
+            p[name] = {"scale": start((a.head_dim,), pdt)}
+    if a.select_topk:
+        ki = jax.random.split(jax.random.fold_in(key, 7), 3)
+        G, J, d = a.n_kv_heads, a.index_heads, a.index_dim
+        p["wi_q"] = _dense_init(ki[0], (D, G, J, d), D, pdt)
+        p["wi_k"] = _dense_init(ki[1], (D, G, d), D, pdt)
+        p["wi_w"] = _dense_init(ki[2], (D, G, J), D, pdt)
     if a.bias:
         p["bq"] = jnp.zeros((a.n_heads, a.head_dim), pdt)
         p["bo"] = jnp.zeros((D,), pdt)
@@ -1114,17 +1189,19 @@ def _layer_norm(x, p, eps=1e-5):
         return y * p["scale"].astype(x.dtype) + p["bias"].astype(x.dtype)
 
 
-def _rms_norm(x, p, eps, axes=(-1,)):
-    """``x * rsqrt(mean(x^2) + eps) * scale`` over ``axes``, in float32."""
+def _rms_norm(x, p, eps, axes=(-1,), plus_one=False):
+    """``x * rsqrt(mean(x^2) + eps) * scale`` over ``axes``, in float32;
+    ``plus_one``: times ``1 + scale``."""
     with jax.named_scope(scopes.RMS_NORM):
         xf = x.astype(jnp.float32)
         y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axes, keepdims=True) + eps)
-        return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
+        scale = p["scale"].astype(jnp.float32)
+        return (y * (1.0 + scale if plus_one else scale)).astype(x.dtype)
 
 
 def _norm(x, p, cfg):
     if cfg.norm == "rmsnorm":
-        return _rms_norm(x, p, cfg.norm_eps)
+        return _rms_norm(x, p, cfg.norm_eps, plus_one=cfg.norm_plus_one)
     return _layer_norm(x, p, cfg.norm_eps)
 
 
@@ -1224,6 +1301,9 @@ def _qkv_kind(h, layer, cfg, a: MultiHeadAttention, positions=None):
              else q * a.query_mult).astype(dt)
     else:
         q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
+    if a.qk_head_norm:
+        q = _rms_norm(q, layer["q_head_norm"], cfg.norm_eps,
+                      plus_one=cfg.norm_plus_one)
     kv = _kv_kind(h, layer, cfg, a)
     if positions is None:
         positions = jnp.arange(h.shape[1])[None]
@@ -1255,6 +1335,9 @@ def _kv_kind(h, layer, cfg, a: MultiHeadAttention):
     kv = jnp.einsum("bsd,dchk->cbshk", h, layer["wkv"].astype(dt))
     if a.bias:
         kv = kv + layer["bkv"].astype(dt)[:, None, None]
+    if a.qk_head_norm:
+        return (_rms_norm(kv[0], layer["k_head_norm"], cfg.norm_eps,
+                          plus_one=cfg.norm_plus_one), kv[1])
     return kv
 
 
@@ -1299,7 +1382,8 @@ def differential_combine(o, layer, a: MultiHeadAttention, li, eps, dt):
 def grouped_attend(q, k, v, a: MultiHeadAttention, allowed, dt, sink=None):
     """Grouped-query attention with materialised scores: ``q [B, S, Hq,
     dh]`` against ``k [B, T, Hkv, dh]``, ``v [B, T, Hkv, dv]`` under ``allowed
-    [B, S, T]`` -> ``[B, S, Hq, dv]`` (zeros for a query that is allowed
+    [B, S, T]`` (or ``[B, Hkv, S, T]``, a key/value head's group its own: a
+    block selection) -> ``[B, S, Hq, dv]`` (zeros for a query that is allowed
     nothing). ``sink [Hq]``: a head's scalar joins its rows' denominators (a
     column appended to the scores, dropped after the softmax). The plain
     tier: the trainer's forward pass, a CPU, a mesh, and what the paged
@@ -1309,7 +1393,7 @@ def grouped_attend(q, k, v, a: MultiHeadAttention, allowed, dt, sink=None):
     logits = jnp.einsum("bsgjk,btgk->bgjst", qg, k,
                         preferred_element_type=jnp.float32) \
         / math.sqrt(dh)
-    ok = allowed[:, None, None]
+    ok = allowed[:, None, None] if allowed.ndim == 3 else allowed[:, :, None]
     logits = jnp.where(ok, logits, -1e30)
     if sink is None:
         probs = jax.nn.softmax(logits, -1)
@@ -1340,12 +1424,86 @@ def attend_allowed(a, q_pos, k_pos, live=None):
 def _attend_kind(a, dt):
     """``attend(q, k, v)`` of a described multi-head layer over its own
     window (no cache): the forward pass of the trainer and of the tests."""
-    def attend(q, k, v, sink=None):
+    def attend(q, k, v, sink=None, index=None):
         pos = jnp.broadcast_to(jnp.arange(k.shape[1])[None], k.shape[:2])
-        return grouped_attend(q, k, v, a, attend_allowed(a, pos, pos), dt,
-                              sink)
+        allowed = attend_allowed(a, pos, pos)
+        if index is None:
+            return grouped_attend(q, k, v, a, allowed, dt, sink)
+        with jax.named_scope(scopes.BLOCK_INDEX):
+            scores = block_scores(index["q"], index["w"],
+                                  pool_blocks(index["k"], a.select_block))
+        with jax.named_scope(scopes.BLOCK_SELECT):
+            chosen = select_blocks(scores, pos, a)
+            allowed = allowed[:, None] & blocks_allowed(chosen, pos, pos, a)
+        with jax.named_scope(scopes.BLOCK_ATTENTION):
+            return grouped_attend(q, k, v, a, allowed, dt), chosen
 
     return attend
+
+
+def block_index(h, layer, cfg, a: MultiHeadAttention):
+    """A selecting layer's indexer operands from the normed input ``h [B, S,
+    D]``: ``{"q": [B, S, G, J, d] indexer queries, "k": [B, S, G, d] this
+    position's indexer key (what its block's pooled row takes the maximum
+    of), "w": [B, S, G, J] float32 head weights, times (J d)^-1/2}``, a
+    key/value group ``G`` each; nothing is rotated."""
+    dt = cfg.compute_dtype
+    w = jnp.einsum("bsd,dgj->bsgj", h, layer["wi_w"].astype(dt)).astype(
+        jnp.float32) / math.sqrt(a.index_heads * a.index_dim)
+    return {"q": jnp.einsum("bsd,dgjk->bsgjk", h, layer["wi_q"].astype(dt)),
+            "k": jnp.einsum("bsd,dgk->bsgk", h, layer["wi_k"].astype(dt)),
+            "w": w}
+
+
+def pool_blocks(k_i, block):
+    """The pooled rows of a window that starts its sequence: ``k_i [B, S, G,
+    d]`` -> ``[B, ceil(S / block), G, d]``, the elementwise maximum over each
+    block's positions (a last block that is not whole over those it has)."""
+    B, S = k_i.shape[:2]
+    pad = -S % block
+    low = jnp.asarray(-jnp.inf, k_i.dtype)
+    k_i = jnp.pad(k_i, ((0, 0), (0, pad), (0, 0), (0, 0)),
+                  constant_values=low)
+    return jnp.max(k_i.reshape(B, -1, block, *k_i.shape[2:]), axis=2)
+
+
+def block_scores(q_i, w, pooled):
+    """The block selection's scores ``I [B, S, G, N] = sum_j w_j relu(q_j .
+    pooled_n)`` (float32) of indexer queries ``q_i [B, S, G, J, d]``, head
+    weights ``w [B, S, G, J]`` and pooled rows ``pooled [B, N, G, d]``; a row
+    nobody wrote scores whatever it holds, and :func:`select_blocks` never
+    reads it."""
+    per_head = jnp.einsum("bsgjd,bngd->bsgjn", q_i, pooled,
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum("bsgjn,bsgj->bsgn", jax.nn.relu(per_head), w)
+
+
+def select_blocks(scores, q_pos, a: MultiHeadAttention):
+    """The learned choice: ``scores [B, S, G, N]``, ``q_pos [B, S]`` -> ``[B,
+    S, G, select_topk]`` int32, the blocks of highest score among a query's
+    candidates (the WHOLE blocks behind the first ones and before the local
+    ones, ``select_first <= n <= bt - select_local``), ties to the lower
+    index, ``-1`` where fewer are candidates."""
+    n = jnp.arange(scores.shape[-1])
+    last = q_pos // a.select_block - a.select_local                  # [B, S]
+    candidate = (n >= a.select_first) & (n <= last[..., None, None])
+    val, idx = jax.lax.top_k(jnp.where(candidate, scores, -jnp.inf),
+                             min(a.select_topk, scores.shape[-1]))
+    return jnp.where(val > -jnp.inf, idx, -1).astype(jnp.int32)
+
+
+def blocks_allowed(chosen, q_pos, k_pos, a: MultiHeadAttention):
+    """Which keys a selecting layer's queries may see by their BLOCK:
+    ``chosen [B, S, G, k]`` (:func:`select_blocks`), ``q_pos [B, S]``,
+    ``k_pos [B, T]`` -> ``[B, G, S, T]``: the first blocks, the local ones
+    and the chosen ones (causality and liveness are :func:`attend_allowed`'s).
+    """
+    kb = (k_pos // a.select_block)[:, None, None, :]              # [B,1,1,T]
+    bt = (q_pos // a.select_block)[:, None, :, None]              # [B,1,S,1]
+    fixed = (kb < a.select_first) | (kb > bt - a.select_local)
+    picked = jnp.any(chosen.transpose(0, 2, 1, 3)[..., None, :]
+                     == kb[..., None], -1)                        # [B,G,S,T]
+    return fixed | picked
 
 
 def _rope_head(x, positions, theta, width):
@@ -1596,12 +1754,26 @@ def _attend_gather(q, k, v, cfg, full_spec=None):
                          _constrain(v, full_spec), cfg)
 
 
+def _gated(gate, h, cfg):
+    """The gated hidden activation from the gate and up projections: ``silu
+    (gate) h``, or under ``swiglu_limit`` the clamped form ``g sigmoid(alpha
+    g) (u + 1)`` with ``g = min(gate, limit)``, ``u = clip(h, -limit,
+    limit)``."""
+    if not cfg.swiglu_limit:
+        return jax.nn.silu(gate) * h
+    limit = cfg.swiglu_limit
+    gate = jnp.minimum(gate, limit)
+    return (gate * jax.nn.sigmoid(cfg.swiglu_alpha * gate)
+            * (jnp.clip(h, -limit, limit) + 1))
+
+
 def _activation(h, layer, x, cfg, eq):
     """The feed-forward's hidden activation from the up projection ``h``:
-    GELU of it, its ReLU squared, or SiLU of the gate projection times it."""
+    GELU of it, its ReLU squared, or the gated form of the gate projection
+    and it (:func:`_gated`)."""
     if cfg.ffn == "swiglu":
         gate = jnp.einsum(eq, x, layer["w_gate"].astype(cfg.compute_dtype))
-        return jax.nn.silu(gate) * h
+        return _gated(gate, h, cfg)
     if cfg.ffn == "relu2":
         return jnp.square(jax.nn.relu(h))
     return jax.nn.gelu(h)
@@ -1693,7 +1865,7 @@ def _expert_products(rows, sizes, layer, cfg):
     h = jax.lax.ragged_dot(rows, layer["w_in"].astype(dt), sizes)
     if cfg.ffn == "swiglu":
         gate = jax.lax.ragged_dot(rows, layer["w_gate"].astype(dt), sizes)
-        h = jax.nn.silu(gate) * h
+        h = _gated(gate, h, cfg)
     elif cfg.ffn == "relu2":
         h = jnp.square(jax.nn.relu(h))
     else:
@@ -2409,6 +2581,17 @@ def _head_gate(h, layer, dt):
     return gate[..., None].astype(dt)
 
 
+def _block_keys(chosen):
+    """``chosen [B, S, G, k]`` blocks (``-1`` = none) as ONE list a query,
+    ``[B, S, G * k]``: group ``g``'s block ``n`` is key ``n * G + g``, which
+    is how a selecting layer reports its choice in its routing
+    (``{"selected": ..}``, beside a latent layer's selected keys)."""
+    G = chosen.shape[2]
+    g = jnp.arange(G, dtype=chosen.dtype)[:, None]
+    return jnp.where(chosen >= 0, chosen * G + g, -1).reshape(
+        *chosen.shape[:2], -1)
+
+
 def block(layer, x, cfg: TransformerConfig, attend, positions=None,
           mesh=None, out_spec=None, valid=None, li=0):
     """THE transformer block, written once: ``x + Wo attend(q, k, v)`` of the
@@ -2432,8 +2615,10 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
     of a described kind (:class:`MultiHeadAttention`) hands it ``attend(q
     [B, S, Hq, dh], k [B, S, Hkv, dh], v [B, S, Hkv, dv]) -> [B, S, Hq, dv]``
     (:func:`_qkv_kind`; ``sink=`` the layer's ``[Hq]`` scalars where the kind
-    has them) and gates the result a head or a channel where the kind
-    says. A RECURRENT layer (:class:`StateSpaceMixer`,
+    has them; ``index=`` the indexer's operands where the kind selects
+    blocks (:func:`block_index`), and ``attend`` then returns ``(o, chosen
+    blocks [B, S, G, k])``, reported as the routing's ``selected``) and gates
+    the result a head or a channel where the kind says. A RECURRENT layer (:class:`StateSpaceMixer`,
     :class:`DeltaRuleMixer`) hands it the mixer itself, ``attend(mix) -> out
     [B, S, D]`` with ``mix(tail, state, live) -> (out, tail, state)``
     (:func:`state_space_mix` or :func:`delta_rule_mix` on this layer's normed
@@ -2488,8 +2673,14 @@ def block(layer, x, cfg: TransformerConfig, attend, positions=None,
                 out = jnp.einsum("bshk,hkd->bsd", attend(q, k, v),
                                  layer["wo"].astype(dt))
             elif isinstance(a, MultiHeadAttention):
-                o = attend(*_qkv_kind(h, layer, cfg, a, positions),
-                           **({"sink": layer["sink"]} if a.sink else {}))
+                extra = {"sink": layer["sink"]} if a.sink else {}
+                if a.select_topk:
+                    with jax.named_scope(scopes.BLOCK_INDEX):
+                        extra["index"] = block_index(h, layer, cfg, a)
+                o = attend(*_qkv_kind(h, layer, cfg, a, positions), **extra)
+                if a.select_topk:
+                    o, selected = o
+                    selected = _block_keys(selected)
                 if a.differential:
                     o = differential_combine(o, layer, a, li, cfg.norm_eps,
                                              dt)
@@ -2550,12 +2741,12 @@ def _block_fn(cfg, mesh, impl, seq_spec, full_spec):
         elif isinstance(a, MultiHeadAttention):
             kind = _attend_kind(a.attended, cfg.compute_dtype)
 
-            def mine(q, k, v, **sink):
+            def mine(q, k, v, **extra):
                 if a.kv_from is not None:
                     k, v = carried[f"kv{a.kv_from}"]
                 elif cfg.shares_kv(li):
                     carried[f"kv{li}"] = (k, v)
-                return kind(q, k, v, **sink)
+                return kind(q, k, v, **extra)
         else:
             mine = attend if a is None else _attend_latent(
                 a, cfg.compute_dtype)

@@ -21,7 +21,12 @@ an attention layer's, and ``linear_attention`` a delta-rule layer's
 the two projections around experts that work in a latent, inside ``mlp``
 beside ``experts``. A selective-scan layer's whole mixer is under
 ``state_space`` too (one kind or the other a model), and ``gated_memory``
-holds a gated memory unit's two products and its gate.
+holds a gated memory unit's two products and its gate. Inside ``attention`` a
+layer that selects key/value blocks opens ``block_index`` (the indexer's
+three projections, the pooled rows, the blocks' scores), ``block_select``
+(the top-k and the lists the kernel walks) and ``block_attention`` (the
+attention over the chosen blocks); its Q/K/V projections, norms, rotation and
+cache writes stay ``attention``'s own.
 
 No JAX here: the benchmark's jax-free parent imports this module.
 """
@@ -31,10 +36,11 @@ import re
 PHASES = GRAD, GRAD_REDUCE, OPTIMIZER = ("grad", "grad_reduce", "optimizer")
 SCOPES = (EMBED, LAYER_NORM, RMS_NORM, ATTENTION, MLP, EXPERTS, LOSS,
           HEAD, STATE_SPACE, EXPERT_LATENT, LINEAR_ATTENTION,
-          GATED_MEMORY) = (
+          GATED_MEMORY, BLOCK_INDEX, BLOCK_SELECT, BLOCK_ATTENTION) = (
               "embed", "layer_norm", "rms_norm", "attention", "mlp",
               "experts", "loss", "head", "state_space", "expert_latent",
-              "linear_attention", "gated_memory")
+              "linear_attention", "gated_memory", "block_index",
+              "block_select", "block_attention")
 
 # What a transform writes around a component of the path it differentiates,
 # transposes or batches: ``transpose(jvp(attention))``. Components that are

@@ -6,8 +6,8 @@ attention over a slot's live pages where they lie, absorbed and expanded.
 ``serving/engine.py`` (``_latent_layer``) runs them inside ``jit_chunk`` and
 ``jit_decode`` on a TPU backend with no mesh; each ``pallas_call`` carries a
 ``name`` that is the instruction's name in the compiled program and in a
-device trace (``docs/observability.md``; the benchmark's ``*_dev_ms.doc`` and
-``*_roofline.doc`` metrics match them). The same mathematics in plain
+device trace (``docs/observability.md``; the benchmark's ``*_dev_ms`` and
+``*_roofline`` entries match them). The same mathematics in plain
 ``jax.numpy`` is ``models/transformer.py``'s ``index_scores``,
 ``select_keys`` and ``latent_attend``, which the engine runs everywhere else
 and the tests compare these with (``interpret=True`` on the CPU).
@@ -58,7 +58,9 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG = -1e30
 _LANES = 128
 
-# The instructions' names (benchmark/layer_metrics/*.doc.json match them).
+# The instructions' names (the benchmark's entries match them by pattern:
+# benchmark/layer_metrics/index_select_dev_ms.json, index_roofline.json,
+# sparse_attn_dev_ms.json, latent_attn_dev_ms.json, ...).
 SCORES_NAME = "index_scores"
 SELECT_NAME = "index_select"
 SPARSE_NAME = "sparse_latent_attention"

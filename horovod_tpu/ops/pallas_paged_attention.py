@@ -3,7 +3,9 @@ step's kernel for the multi-head attention of ``n_heads``
 (:func:`paged_decode_attention`, the rest of this text), and the kernel of
 the multi-head kinds a configuration describes (:func:`paged_grouped_attention`
 below: fewer key/value heads than query heads, a window, a ring, a query
-block longer than one).
+block longer than one), and the same walk over a LIST of chosen pages a
+key/value head (:func:`paged_block_attention`, last: a learned selection of
+key/value blocks).
 
 One query token a slot against that slot's cached context. The cache is
 taken as :mod:`horovod_tpu.serving.kv_cache` holds it — one layer's K and
@@ -55,7 +57,8 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 # The instruction name in a compiled program and in the chip's trace
-# (benchmark/layer_metrics/paged_attn_dev_ms.over.json matches it).
+# (benchmark/layer_metrics/paged_attn_dev_ms.over.json matches it by
+# pattern).
 KERNEL_NAME = "paged_decode_attention"
 
 # Tokens a block aims for: two buffers each of K and V at this many rows
@@ -216,11 +219,14 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
 # ---- grouped queries, windows, rings, query blocks -------------------------
 
-# The instruction names of the described kinds' layers (full and window), in
-# the decode step and in the chunk fill alike
-# (benchmark/layer_metrics/*_attn_dev_ms.agent.json match them).
+# The instruction names of the described kinds' layers (full, window, and a
+# layer that selects its blocks), in the decode step and in the chunk fill
+# alike (benchmark/layer_metrics/full_attn_dev_ms.json, window_attn_dev_ms,
+# chunk_attn_dev_ms, block_attn_dev_ms, ... match them by pattern and tell
+# the programs apart by ``program``).
 FULL_NAME = "paged_full_attention"
 WINDOW_NAME = "paged_window_attention"
+BLOCK_NAME = "paged_block_attention"
 
 # Queries a grid step takes (a power of two: a row's query is ``row %
 # q_block``), and the key tokens a block aims for, (full, window) layers, for
@@ -534,3 +540,260 @@ def paged_grouped_attention(q, k_pages, v_pages, tables, pos0, kv_len, *,
         out = jnp.sum(jnp.where(mine[..., None],
                                 out.reshape(B, Q, Hq, pack, narrow), 0), 3)
     return out
+
+
+# ---- a selection of blocks a key/value head ---------------------------------
+
+# Lanes of a query's own list of chosen blocks as the kernel takes it (the
+# first, the chosen and the local ones, ``-1`` behind them), and the pages a
+# grid step copies at once: (one query a slot, a block of queries).
+_SEL_LANES = 128
+_BLOCK_PAGES = (4, 4)
+
+
+def block_supported(page_size, head_dim, v_dim, dtype):
+    """Whether :func:`paged_block_attention` tiles on a TPU: a page is whole
+    sublane tiles of ``dtype``, a key head and a value head whole lane tiles
+    (a head's lanes are copied out of the fused row by themselves)."""
+    sublanes = 8 * (4 // jnp.dtype(dtype).itemsize)
+    return (page_size % sublanes == 0 and head_dim % 128 == 0
+            and v_dim % 128 == 0)
+
+
+def _block_kernel(pos0_ref, len_ref, tables_ref, lists_ref, counts_ref,
+                  q_ref, *refs, page, ppb, width, n_tiles, n_kv, list_len,
+                  head_dim, v_dim, qb, group, sm_scale):
+    # ``refs``: the queries' own lists where a tile holds several queries,
+    # the layer's arrays, the output, scratch.
+    own_ref = refs[0] if qb > 1 else None
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_s, l_s, acc_s = refs[qb > 1:]
+    b, qi, g = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    kv_len = len_ref[b]
+    q_first = pos0_ref[b] + qi * qb
+    tile = (b * n_tiles + qi) * n_kv + g
+    count = counts_ref[tile]
+    n_steps = (count + ppb - 1) // ppb
+    rows = acc_s.shape[0]
+    k_lanes = pl.ds(pl.multiple_of(g * head_dim, 128), head_dim)
+    v_lanes = pl.ds(pl.multiple_of(g * v_dim, 128), v_dim)
+
+    def entry(n):
+        """The ``n``-th block of the tile's list; past its end the last one
+        again (never a page the slot does not own)."""
+        return lists_ref[tile * list_len
+                         + jnp.maximum(jnp.minimum(n, count - 1), 0)]
+
+    def copies(i, slot):
+        out = []
+        for j in range(ppb):
+            pid = tables_ref[b * width + entry(i * ppb + j)]
+            at = pl.ds(j * page, page)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[pid, pl.ds(0, page), k_lanes], k_buf.at[slot, at],
+                sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[pid, pl.ds(0, page), v_lanes], v_buf.at[slot, at],
+                sems.at[1, slot]))
+        return out
+
+    m_s[...] = jnp.full_like(m_s, _NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+    # Row r of the tile is query r % qb of the block, of one of the group's
+    # heads (rows past group * qb are padding).
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    q_pos = q_first + row % qb
+
+    @pl.when(n_steps > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def step(n, _):
+        slot = n % 2
+
+        @pl.when(n + 1 < n_steps)
+        def _next():
+            for c in copies(n + 1, 1 - slot):
+                c.start()
+
+        for c in copies(n, slot):
+            c.wait()
+        k = k_buf[slot]                                     # [ppb * page, dh]
+        v = v_buf[slot]
+        seen = []
+        for j in range(ppb):
+            at = n * ppb + j
+            blk = entry(at)
+            k_pos = blk * page + jax.lax.broadcasted_iota(
+                jnp.int32, (1, page), 1)
+            # Past the list's end the last block came again: not twice.
+            ok = (k_pos <= q_pos) & (k_pos < kv_len) & (at < count)
+            if qb > 1:
+                # A query sees the block if its OWN list holds it.
+                mine = jnp.max(jnp.where(own_ref[0, 0, 0] == blk, 1.0, 0.0),
+                               axis=1, keepdims=True)               # [qb, 1]
+                mine = jnp.concatenate([mine] * group, axis=0)
+                if rows > group * qb:
+                    mine = jnp.concatenate(
+                        [mine, jnp.zeros((rows - group * qb, 1), mine.dtype)],
+                        axis=0)
+                ok &= mine > 0.5
+            seen.append(ok)
+        ok = seen[0] if ppb == 1 else jnp.concatenate(seen, axis=1)
+        s = jax.lax.dot_general(
+            q_ref[0, 0, 0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        # A key a row does not see scores -inf, under the finite state a row
+        # starts at: its exp is 0 whatever the row has seen, unmasked.
+        s = jnp.where(ok, s, -jnp.inf)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        m_s[...] = m_new
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, -1, keepdims=True)
+        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    jax.lax.fori_loop(0, n_steps, step, None)
+    l = l_s[...]
+    o_ref[0, 0, 0] = (acc_s[...] * (1.0 / jnp.where(l > 0, l, 1.0))
+                      ).astype(o_ref.dtype)
+
+
+def block_lists(chosen, q_pos, live, *, page, first, local):
+    """What a query of a selecting layer attends, from a call's learned
+    choices ``chosen [B, Q, G, k]`` (``-1`` = none) at the positions ``q_pos
+    [B, Q]`` (``live [B, Q]``: the queries that count): -> ``own [B, Q, G,
+    first + k + local]``, every query's whole list: the first blocks it
+    sees, the chosen ones, the local ones that are no first block, ``-1`` =
+    none; no block twice)."""
+    B, Q, G, K = chosen.shape
+    bt = (q_pos // page)[..., None, None]                         # [B,Q,1,1]
+    lead = jnp.broadcast_to(jnp.arange(first), (B, Q, G, first))
+    lead = jnp.where(lead <= bt, lead, -1)
+    tail = bt - jnp.arange(local)
+    tail = jnp.broadcast_to(jnp.where(tail >= first, tail, -1),
+                            (B, Q, G, local))
+    own = jnp.concatenate([lead, chosen, tail], -1).astype(jnp.int32)
+    return jnp.where(live[..., None, None], own, -1)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_kv_heads", "first", "local", "q_block", "pages_per_block",
+    "interpret"))
+def paged_block_attention(q, k_pages, v_pages, tables, pos0, kv_len, chosen,
+                          *, n_kv_heads, first, local, q_block=None,
+                          pages_per_block=None, interpret=False):
+    """:func:`paged_grouped_attention` over a SELECTION of blocks a key/value
+    head: ``q [B, Q, Hq, dh]`` at the consecutive positions ``pos0 [B] ..``
+    against ``k_pages [n_pages, page, Hkv * dh]``, ``v_pages [n_pages, page,
+    Hkv * dv]`` through ``tables [B, W]`` -> ``[B, Q, Hq, dv]``. A page IS a
+    block of the selection. ``chosen [B, Q, Hkv, k]``: the blocks each query
+    chose for each key/value head's group of query heads (``-1`` = none); it
+    also sees the ``first`` leading blocks and the ``local`` last ones of its
+    own position, and of all of them the positions ``<=`` its own and ``<
+    kv_len [B]``.
+
+    Structure: grid over slots, blocks of ``q_block`` queries and key/value
+    heads. A grid step walks the list of the blocks that ANY query of its
+    tile chose for this head, each once, ascending (made here, scalar
+    prefetched beside the block table), ``pages_per_block`` pages at a time
+    through two VMEM buffers; a page is copied as the head's own lanes of
+    the fused rows. Inside, a query is masked to the blocks of its OWN list
+    (compared with the walked block's id), so queries of one tile may choose
+    differently: what a tile reads is the union, what a query attends its
+    own 19. One query a slot (the decode step): the list is the query's own
+    and nothing is compared. Online softmax in float32 scratch, as the
+    grouped kernel's."""
+    B, Q, Hq, dh = q.shape
+    n_pages, page, hd = k_pages.shape
+    G = int(n_kv_heads)
+    dv = v_pages.shape[2] // G
+    if (hd != G * dh or v_pages.shape[:2] != k_pages.shape[:2]
+            or v_pages.shape[2] != G * dv or Hq % G
+            or chosen.shape[:3] != (B, Q, G)):
+        raise ValueError(f"cache {k_pages.shape} / {v_pages.shape} and "
+                         f"choices {chosen.shape} do not hold {G} heads of "
+                         f"{dh} under {Hq} query heads")
+    group, width = Hq // G, tables.shape[1]
+    qb = int(q_block or _Q_BLOCK)
+    if qb & (qb - 1):
+        raise ValueError(f"q_block {qb} is not a power of two")
+    qb = math.gcd(Q, qb)
+    nq = Q // qb
+    q_pos = pos0[:, None] + jnp.arange(Q)[None]
+    own = block_lists(chosen, q_pos, q_pos < kv_len[:, None], page=page,
+                      first=first, local=local)
+    most = own.shape[-1]
+    if most > _SEL_LANES:
+        raise ValueError(f"a query's {most} blocks pass {_SEL_LANES}")
+    ppb = int(pages_per_block or _BLOCK_PAGES[Q > 1])
+    # The tile's list: the blocks any of its queries holds, each once.
+    L = -(-min(width, qb * most) // ppb) * ppb
+    tiles = own.reshape(B, nq, qb, G, most).transpose(0, 1, 3, 2, 4)
+    held = jnp.zeros((B, nq, G, width + 1), bool).at[
+        jnp.arange(B)[:, None, None, None],
+        jnp.arange(nq)[None, :, None, None],
+        jnp.arange(G)[None, None, :, None],
+        jnp.where(tiles >= 0, tiles, width).reshape(B, nq, G, -1)
+    ].set(True)[..., :width]
+    lists = jnp.argsort(~held, axis=-1, stable=True)
+    lists = jnp.pad(lists, ((0, 0),) * 3 + ((0, max(L - width, 0)),))[..., :L]
+    counts = jnp.sum(held, -1)
+    rows = -(-group * qb // 16) * 16
+    qt = q.reshape(B, nq, qb, G, group, dh).transpose(0, 1, 3, 4, 2, 5)
+    qt = qt.reshape(B, nq, G, group * qb, dh)
+    qt = jnp.pad(qt, ((0, 0),) * 3 + ((0, rows - group * qb), (0, 0)))
+
+    def tile(r, lanes):
+        return pl.BlockSpec((1, 1, 1, r, lanes),
+                            lambda b, qi, g, *_: (b, qi, g, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    operands, in_specs = [qt], [tile(rows, dh)]
+    if qb > 1:
+        operands.append(jnp.pad(
+            tiles, ((0, 0),) * 4 + ((0, _SEL_LANES - most),),
+            constant_values=-1))
+        in_specs.append(tile(qb, _SEL_LANES))
+    kernel = functools.partial(
+        _block_kernel, page=page, ppb=ppb, width=width, n_tiles=nq, n_kv=G,
+        list_len=L, head_dim=dh, v_dim=dv, qb=qb, group=group,
+        sm_scale=1.0 / math.sqrt(dh))
+    bt = ppb * page
+    walked = B * nq * G * L * page                    # an upper bound
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(B, nq, G),
+            in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY),
+                                 pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=tile(rows, dv),
+            scratch_shapes=[
+                pltpu.VMEM((2, bt, dh), k_pages.dtype),
+                pltpu.VMEM((2, bt, dv), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, dv), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, nq, G, rows, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * rows * (dh + dv) * walked,
+            transcendentals=rows * walked,
+            bytes_accessed=walked * (dh + dv) * k_pages.dtype.itemsize),
+        name=BLOCK_NAME,
+        interpret=interpret,
+    )(pos0.astype(jnp.int32), kv_len.astype(jnp.int32),
+      tables.reshape(-1).astype(jnp.int32),
+      lists.reshape(-1).astype(jnp.int32),
+      counts.reshape(-1).astype(jnp.int32), *operands, k_pages, v_pages)
+    out = out[:, :, :, :group * qb].reshape(B, nq, G, group, qb, dv)
+    return out.transpose(0, 1, 4, 2, 3, 5).reshape(B, Q, Hq, dv)

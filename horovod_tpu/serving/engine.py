@@ -106,6 +106,22 @@ the slot's pages (or ring) are gathered and attended with materialised scores
 (``transformer.grouped_attend``). A model whose layers are described by kind
 is filled by chunks only: the padded prefills are not built for it.
 
+A multi-head layer whose kind SELECTS key/value blocks
+(``MultiHeadAttention.select_topk``; a page is a block) runs the same way
+once more (:func:`_block_layer`): beside the window's K and V, the window's
+indexer keys join their pages' **pooled rows** by elementwise maximum
+(:func:`_pool_write`; ``kv_cache`` holds a row a page), the slot's pooled rows
+are scored a key/value group at a time (``index_scores``, the latent
+selection's kernel, a group a batch row) and each query keeps its
+``select_topk`` best whole blocks (``transformer.select_blocks``), and the
+queries attend the
+first, the chosen and the local blocks through
+:func:`~horovod_tpu.ops.pallas_paged_attention.paged_block_attention`
+(``paged_block_attention`` in a trace), which walks a LIST of pages a (slot,
+query tile, key/value head). Elsewhere the same mathematics runs over
+gathered pages with materialised scores and a mask a group
+(``transformer.block_scores``, ``select_blocks``, ``blocks_allowed``).
+
 A STATE-SPACE layer (``TransformerConfig.state_space``) keeps no K/V: its
 slot's row of the layer's tail and state arrays (``kv_cache``: the row's index
 rides in the block table's last column) is read, zeroed if the window starts
@@ -162,12 +178,13 @@ kind):
   is asked once a program build (:func:`_kernels`);
 - how its window is written and attended: a program hands :func:`_layers`
   the window (``q_pos``, ``ok``, ``tables``) and the kernel flags once, and
-  ``_layers`` calls :func:`_latent_layer`, :func:`_grouped_layer` and
-  :func:`_state_layer` itself, each looked up through this module when the
+  ``_layers`` calls :func:`_latent_layer`, :func:`_grouped_layer`,
+  :func:`_block_layer` and :func:`_state_layer` itself, each looked up through this module when the
   program is traced (tests and the benchmark's planted faults replace them
   there);
 - what work that is: :func:`_latent_work`, :func:`_grouped_work`,
-  :func:`_state_work` and :func:`_linear_work` beside them give the counters
+  :func:`_block_work`, :func:`_state_work` and :func:`_linear_work` beside
+  them give the counters
   of one layer for one call from the call's positions, and :func:`work` sums
   them over the model's layers for ``ServeLoop``, which tallies them by
   program kind as
@@ -252,11 +269,17 @@ def grouped_kernels(cfg, geo, mesh):
     through :func:`paged_attention.paged_grouped_attention`, in the chunk
     and the decode program alike: where kernels may run, and shapes the
     kernel tiles. Else they gather their pages."""
+    def tiles(a):
+        if a.select_topk:    # the block kernel, and the selection's scorer
+            return (paged_attention.block_supported(
+                geo.page_size, a.head_dim, a.v_dim, cfg.compute_dtype)
+                and a.index_dim % 128 == 0)
+        return paged_attention.grouped_supported(
+            geo.page_size, a.attended.head_dim, cfg.compute_dtype,
+            a.attended.n_kv_heads, a.attended.v_dim)
+
     return (bool(cfg.multihead) and _kernels_may_run(cfg, mesh)
-            and all(paged_attention.grouped_supported(
-                geo.page_size, a.attended.head_dim, cfg.compute_dtype,
-                a.attended.n_kv_heads, a.attended.v_dim)
-                for _, a in cfg.multihead))
+            and all(tiles(a) for _, a in cfg.multihead))
 
 
 def state_kernels(cfg, geo, mesh, q_len=1):
@@ -329,6 +352,16 @@ def fill_exit(cfg):
     return None
 
 
+def marks_padding(cfg):
+    """Whether a chunk's padding has to be told from its tokens: a NEGATIVE
+    token id then marks a padding position, and that position and every one
+    behind it is dead (``ServeLoop`` pads so). A recurrent layer's state must
+    not advance over padding, and a selecting layer's pooled row must not
+    take its maximum over it (a key or a value of a padding position is
+    overwritten before anyone reads it; a maximum is not)."""
+    return bool(cfg.recurrent or cfg.selects_blocks)
+
+
 def _check_positions(cfg, n, what):
     """A learned position table has ``max_seq_len`` rows and no more; RoPE
     has no table to run out of."""
@@ -367,14 +400,18 @@ def _masked(cfg, mask):
     return lambda q, k, v: tfm.causal_attend(q, k, v, cfg, mask=mask)
 
 
-def _cache_out(ck, cv, mesh, cfg):
-    """The per-layer lists back in the cache's form, each array held to
+def _cache_out(lists, mesh, cfg):
+    """The per-layer lists (``{"k": [..], "v": [..]}``, and ``"pool"`` for a
+    model that selects blocks) back in the cache's form, each array held to
     its shard of the mesh."""
     kv_spec = kv_cache.spec(cfg)
-    def out(c):
-        return None if c is None else _constrain(c, mesh, kv_spec)
 
-    return {"k": tuple(map(out, ck)), "v": tuple(map(out, cv))}
+    def out(name):
+        spec = P(None, cfg.model_axis) if name == "pool" else kv_spec
+        return tuple(None if c is None else _constrain(c, mesh, spec)
+                     for c in lists[name])
+
+    return {name: out(name) for name in lists}
 
 
 def _window_cells(a, q_pos, ok, tables, geo):
@@ -603,6 +640,128 @@ def _grouped_work(a, live, itemsize):
             "state": {"kv_bytes": rows.sum() * row_bytes}}
 
 
+def _pool_write(pool_c, k_i, q_pos, ok, table, page):
+    """The window's indexer keys ``k_i [B, Q, G, d]`` at the consecutive
+    positions ``q_pos [B, Q]`` (live where ``ok``) into the pooled rows
+    ``pool_c [n_pages, G * d]`` of the pages they lie in: a page whose FIRST
+    position the window writes starts its row anew with the maximum over the
+    window's positions in it, any other page's row is carried by maximum.
+    (Whoever held the page before, a preempted request's replay, a chunk
+    boundary inside a block: the row is what its positions so far give.)"""
+    B, Q = q_pos.shape
+    k_i = k_i.reshape(B, Q, -1)
+    touched = (Q + page - 2) // page + 1        # pages a window can lie in
+    blocks = (q_pos[:, :1] // page) + jnp.arange(touched)[None]     # [B, n]
+    inside = ((q_pos // page)[:, None] == blocks[..., None]) & ok[:, None]
+    top = jnp.max(jnp.where(inside[..., None], k_i[:, None],
+                            jnp.asarray(-jnp.inf, k_i.dtype)), axis=2)
+    pages = jnp.where(
+        jnp.any(inside, 2), jnp.take_along_axis(
+            table, jnp.minimum(blocks, table.shape[1] - 1), axis=1), 0)
+    fresh = blocks * page >= q_pos[:, :1]
+    top = jnp.where(fresh[..., None], top, jnp.maximum(pool_c[pages], top))
+    return pool_c.at[pages].set(top)
+
+
+def _block_layer(a, q, k, v, index, k_c, v_c, pool_c, *, q_pos, ok, tables,
+                 geo, dt, kernels):
+    """One multi-head layer that SELECTS its key/value blocks, in a chunk or
+    decode program: write the window's ``k``, ``v`` (as
+    :func:`_grouped_layer`) and its indexer keys' pooled rows
+    (:func:`_pool_write`), score the slot's pooled rows with ``index``
+    (``transformer.block_index``'s operands), keep each (query, key/value
+    group)'s best whole blocks, and attend the first, the chosen and the local
+    ones -> (the layer's three arrays, ``o [B, Q, Hq, dv]``, the chosen
+    blocks ``[B, Q, G, k]``, ``-1`` = none). With ``kernels`` the scores are
+    the latent selection's ``index_scores``, a key/value group a batch row and
+    a block a key, and the attention ``paged_block_attention``; else plain
+    ``jax.numpy`` over the gathered pages. The top-k is XLA's on both tiers
+    (``transformer.select_blocks``: 16 of 512 scores a row; the latent
+    selection's ``index_select`` ranks thousands of keys and took 4 ms a
+    layer for a chunk's 4,096 rows of 512, PERF.md PR 65)."""
+    B, Q = q_pos.shape
+    page, N = geo.page_size, geo.max_blocks
+    G, J, d = index["q"].shape[2:]
+    table, page_ids, slot = _window_cells(a, q_pos, ok, tables, geo)
+    k_c = k_c.at[page_ids, slot].set(_fused(k))
+    v_c = v_c.at[page_ids, slot].set(_fused(v))
+    p_hi = jnp.max(jnp.where(ok, q_pos, -1), axis=1)             # [B]
+    with jax.named_scope(tfm.scopes.BLOCK_INDEX):
+        pool_c = _pool_write(pool_c, index["k"], q_pos, ok, table, page)
+        pooled = pool_c[table].reshape(B, N, G, d)
+        if kernels:
+            # A key/value group a batch row, a block a key; a query's last
+            # candidate is the last key the kernel scores for it.
+            last = jnp.repeat(q_pos // page - a.select_local, G, axis=0)
+            scores = pallas_latent.index_scores(
+                index["q"].transpose(0, 2, 1, 3, 4).reshape(B * G, Q, J, d),
+                index["w"].transpose(0, 2, 1, 3).reshape(B * G, Q, J),
+                pooled.transpose(0, 2, 1, 3).reshape(B * G, N, d), last)
+            scores = scores.reshape(B, G, Q, N).transpose(0, 2, 1, 3)
+        else:
+            scores = tfm.block_scores(index["q"], index["w"], pooled)
+    with jax.named_scope(tfm.scopes.BLOCK_SELECT):
+        chosen = tfm.select_blocks(scores, q_pos, a)
+    with jax.named_scope(tfm.scopes.BLOCK_ATTENTION):
+        if kernels:
+            o = paged_attention.paged_block_attention(
+                q, k_c, v_c, table, q_pos[:, 0], p_hi + 1, chosen,
+                n_kv_heads=G, first=a.select_first, local=a.select_local,
+                interpret=jax.default_backend() != "tpu")
+        else:
+            k_pos = jnp.broadcast_to(jnp.arange(geo.max_kv)[None],
+                                     (B, geo.max_kv))
+            allowed = (tfm.attend_allowed(a, q_pos, k_pos,
+                                          k_pos <= p_hi[:, None])[:, None]
+                       & tfm.blocks_allowed(chosen, q_pos, k_pos, a))
+            rows = (c[table].reshape(B, geo.max_kv, G, -1)
+                    for c in (k_c, v_c))
+            o = tfm.grouped_attend(q, *rows, a, allowed, dt)
+    return k_c, v_c, pool_c, o, chosen
+
+
+def _block_work(a, live):
+    """What ONE layer that selects its blocks does in a call (``live`` as in
+    :func:`_latent_work`), a key/value group's count each: the pooled rows it
+    scores (a query's candidates: the whole blocks between its first and its
+    local ones) and the blocks it then chooses; the (query, key) pairs it
+    multiplies (``qk_block_pairs``: the visible keys of a query's first,
+    chosen and local blocks; also ``kv_selected``, beside ``kv_scored`` =
+    every key the query sees, so that ``kv_select_share`` is the share of the
+    live keys that the selection attends); the rows of chosen blocks a tile of
+    queries must read, a block once a tile (``kv_block_rows``: a tile is the
+    queries of one block of positions, and of the blocks its queries choose
+    the count takes the LAST query's, the least any kernel reads: which
+    others the tile's queries add is the data's), beside the rows a tile
+    that read its whole context would (``kv_live_rows``)."""
+    page, G = a.select_block, a.n_kv_heads
+
+    def counted(live):
+        """-> (a query's candidates, the blocks it chooses of them, the keys
+        it sees in its first, chosen and local blocks)."""
+        bt = np.maximum(live - 1, 0) // page
+        candidates = np.maximum(bt - a.select_local - a.select_first + 1, 0)
+        chosen = np.minimum(candidates, a.select_topk)
+        lead = np.minimum(a.select_first, bt + 1)
+        tail = np.minimum(a.select_local, np.maximum(bt + 1 - a.select_first,
+                                                     0))
+        return candidates, chosen, np.where(
+            live > 0, (lead + chosen + tail) * page - (bt + 1) * page + live,
+            0)
+
+    candidates, chosen, seen = counted(live)
+    bt = np.maximum(live - 1, 0) // page
+    ends = np.ones(live.shape, bool)        # a tile's last query
+    ends[:, :-1] = bt[:, 1:] != bt[:, :-1]
+    found = {
+        "block_rows_scored": candidates.sum(),
+        "blocks_chosen": chosen.sum(), "qk_block_pairs": seen.sum(),
+        "kv_selected": seen.sum(), "kv_scored": live.sum(),
+        "kv_block_rows": counted(live[ends])[2].sum(),
+        "kv_live_rows": live[ends].sum()}
+    return {"attn": {name: G * n for name, n in found.items()}}
+
+
 def _state_layer(mix, tail_c, state_c, *, q_pos, ok, tables, kernels=None,
                  snapshots=0):
     """One state-space layer of a chunk or decode program: the slots' rows of
@@ -766,7 +925,8 @@ def work(cfg, geo, mesh):
         if isinstance(a, tfm.SelectiveScanMixer):
             return _scan_work(a, live, itemsize)
         if isinstance(a, tfm.MultiHeadAttention):
-            return _grouped_work(a, live, itemsize)
+            return (_block_work(a, live) if a.select_topk
+                    else _grouped_work(a, live, itemsize))
         return _latent_work(a, live, latent, geo.max_kv)
 
     def count(live, ends=None):
@@ -834,10 +994,12 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
     ``x`` None (no layer above is touched: the program has none of their
     weights), True runs everything from that layer's queries up on each slot's
     LAST live row, so ``x`` comes out ``[B, 1, D]``. ->
-    (ck, cv, x after the final norm, what the layers report or None:
-    ``counts``, ``rows`` and ``top`` of the expert layers, ``selected`` of the
-    selecting ones, each stacked over those layers)."""
-    ck, cv = list(cache["k"]), list(cache["v"])
+    (the cache's per-layer lists by name, x after the final norm, what the
+    layers report or None: ``counts``, ``rows`` and ``top`` of the expert
+    layers, ``selected`` of the selecting ones, each stacked over those
+    layers)."""
+    lists = {name: list(arrays) for name, arrays in cache.items()}
+    ck, cv, cp = lists["k"], lists["v"], lists.get("pool")
     reports, memory = [], {}
     leaves = None if ends is None else fill_exit(cfg)
     for li, layer in enumerate(params["layers"]):
@@ -854,7 +1016,7 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
                 a.attended, None, k, v, ck[li], cv[li], **window, geo=geo,
                 dt=cfg.compute_dtype, kernels=kernels["grouped"])
             if not ends:
-                return ck, cv, None, None
+                return lists, None, None
             at = jnp.maximum(jnp.sum(window["ok"], 1) - 1, 0)[:, None]
 
             def last(v, at=at):
@@ -890,7 +1052,13 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
                 return attend(q, kk, vv)
         elif isinstance(a, tfm.MultiHeadAttention):
             def write_and_attend(q, k, v, li=li, a=a, written=li == leaves,
-                                 **sink):
+                                 index=None, **sink):
+                if index is not None:    # a layer that selects its blocks
+                    ck[li], cv[li], cp[li], o, chosen = _block_layer(
+                        a, q, k, v, index, ck[li], cv[li], cp[li], **window,
+                        geo=geo, dt=cfg.compute_dtype,
+                        kernels=kernels["grouped"])
+                    return o, chosen
                 # Whose pages: the layer's own, or the layer's it names,
                 # which are read and not written (as the layer's own are on
                 # a fill's last row: they were written above).
@@ -917,10 +1085,10 @@ def _layers(params, cache, x, positions, write, attend, valid, *, cfg, mesh,
     names = {name for r in reports for name in r}
     aux = {name: jnp.stack([r[name] for r in reports if name in r])
            for name in sorted(names)} or None
-    return ck, cv, tfm._norm(x, params["final_ln"], cfg), aux
+    return lists, tfm._norm(x, params["final_ln"], cfg), aux
 
 
-def _result(ck, cv, logits, moe, mesh, cfg):
+def _result(lists, logits, moe, mesh, cfg):
     """What every program returns: the cache and float32 logits; for a
     model with experts also its routing, ``{"counts": [expert layers, E]
     pairs each expert held here received from the live rows, "top": [expert
@@ -931,7 +1099,7 @@ def _result(ck, cv, logits, moe, mesh, cfg):
     ``"selected": [selecting layers, B, S, k]``. Every entry of the routing
     but ``counts`` is ``[layers, slot, position, ..]``: the benchmark's check
     indexes them so, which is why the rows travel beside the dict."""
-    out = (_cache_out(ck, cv, mesh, cfg),
+    out = (_cache_out(lists, mesh, cfg),
            None if logits is None else logits.astype(jnp.float32))
     if moe is None:
         return out
@@ -987,12 +1155,12 @@ def make_prefill(cfg, geo, mesh=None, prefill_pad=None):
             return (layer_cache.at[block_table[:n_blocks]].set(pages), kv)
 
         valid = (jnp.arange(pad) < length)[None]
-        ck, cv, x, moe = _layers(params, cache, x, None, write,
-                                 _masked(cfg, mask), valid, cfg=cfg,
-                                 mesh=mesh)
+        lists, x, moe = _layers(params, cache, x, None, write,
+                                _masked(cfg, mask), valid, cfg=cfg,
+                                mesh=mesh)
         last = jnp.take(x[0], length - 1, axis=0)
         logits = tfm.head_logits(last, params, cfg, "d,vd->v")
-        return _result(ck, cv, logits, moe, mesh, cfg)
+        return _result(lists, logits, moe, mesh, cfg)
 
     return jax.jit(prefill, donate_argnums=(1,))
 
@@ -1051,11 +1219,11 @@ def make_decode_step(cfg, geo, mesh=None, max_batch=8):
 
             attend = _masked(cfg, kv_mask)
 
-        ck, cv, x, moe = _layers(params, cache, x, positions[:, None], write,
-                                 attend, active[:, None], cfg=cfg, mesh=mesh,
-                                 window=window, geo=geo, kernels=kernels)
+        lists, x, moe = _layers(params, cache, x, positions[:, None], write,
+                                attend, active[:, None], cfg=cfg, mesh=mesh,
+                                window=window, geo=geo, kernels=kernels)
         logits = tfm.head_logits(x, params, cfg)[:, 0]
-        return _result(ck, cv, logits, moe, mesh, cfg)
+        return _result(lists, logits, moe, mesh, cfg)
 
     return jax.jit(decode, donate_argnums=(1,))
 
@@ -1068,20 +1236,21 @@ def _chunk_forward(params, cache, tokens, positions, block_tables,
     a ``kv_pos <= position`` mask. Within-window causality falls out of
     the same mask because the window's own K/V is written BEFORE the
     gather — position p sees cached history plus window positions
-    <= p. Returns (ck, cv, x[B, Q, D] after the final norm, routing)."""
+    <= p. Returns (the cache's lists, x[B, Q, D] after the final norm,
+    routing)."""
     q_len = tokens.shape[1]
     max_kv = geo.max_kv
     tables, block_tables = block_tables, _context_tables(block_tables, geo)
     pos = positions[:, None] + jnp.arange(q_len)[None, :]    # [B, Q]
     pe = jnp.clip(pos, 0, cfg.max_seq_len - 1)
-    if cfg.recurrent:         # a negative id: padding, from there on
+    if marks_padding(cfg):    # a negative id: padding, from there on
         padding = jnp.cumsum(tokens < 0, axis=1) > 0
         tokens = jnp.maximum(tokens, 0)
     x = tfm.add_positions(tfm.embed_tokens(params, tokens, cfg),
                           params, cfg, pe)                   # [B, Q, D]
     blk = jnp.minimum(pos // geo.page_size, geo.max_blocks - 1)
     valid = (pos < max_kv) & active[:, None]
-    if cfg.recurrent:
+    if marks_padding(cfg):
         valid &= ~padding
     page_ids = jnp.take_along_axis(block_tables, blk, axis=1)
     page_ids = jnp.where(valid, page_ids, 0)                 # trash route
@@ -1133,8 +1302,8 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk",
 
     Writes for positions past ``max_kv`` or on inactive slots route to
     trash page 0, so padded draft lanes and short final chunks are
-    branch-free. For a model whose layers carry a state a negative token id
-    marks padding: that position and every one behind it is dead (its K/V go
+    branch-free. For a model whose layers carry a state or pool their blocks
+    (:func:`marks_padding`) a negative token id marks padding: that position and every one behind it is dead (its K/V go
     to the trash page and it advances no state).
 
     ``ends`` is for a model whose fill leaves the stack (:func:`fill_exit`;
@@ -1176,10 +1345,10 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk",
     _check_positions(cfg, geo.max_kv, "cache width")
 
     def chunk(params, cache, tokens, positions, block_tables, active):
-        ck, cv, x, moe = _chunk_forward(params, cache, tokens, positions,
-                                        block_tables, active, cfg=cfg,
-                                        geo=geo, mesh=mesh, kernels=kernels,
-                                        ends=ends)
+        lists, x, moe = _chunk_forward(params, cache, tokens, positions,
+                                       block_tables, active, cfg=cfg,
+                                       geo=geo, mesh=mesh, kernels=kernels,
+                                       ends=ends)
         if head == "none":
             # Nobody reads ``x``, so what feeds nothing but ``x`` is not run
             # (the compiler drops it): the last layer's feed-forward, and the
@@ -1194,7 +1363,7 @@ def make_chunk_step(cfg, geo, mesh=None, q_len=None, name="chunk",
             at = jnp.maximum(jnp.sum(live, 1) - 1, 0)
             x = jnp.take_along_axis(x, at[:, None, None], axis=1)
         logits = None if x is None else tfm.head_logits(x, params, cfg)
-        return _result(ck, cv, logits, moe, mesh, cfg)
+        return _result(lists, logits, moe, mesh, cfg)
 
     chunk.__name__ = chunk.__qualname__ = name
     return jax.jit(chunk, donate_argnums=(1,))
@@ -1261,13 +1430,13 @@ def make_batched_prefill(cfg, geo, mesh=None, prefill_pad=None):
 
     def bprefill(params, cache, tokens, lengths, block_tables, active):
         positions = jnp.zeros(tokens.shape[:1], jnp.int32)
-        ck, cv, x, moe = _chunk_forward(params, cache, tokens, positions,
-                                        block_tables, active,
-                                        cfg=cfg, geo=geo, mesh=mesh)
+        lists, x, moe = _chunk_forward(params, cache, tokens, positions,
+                                       block_tables, active,
+                                       cfg=cfg, geo=geo, mesh=mesh)
         last = jnp.take_along_axis(
             x, jnp.clip(lengths - 1, 0, pad - 1)[:, None, None], axis=1)
         logits = tfm.head_logits(last, params, cfg)
-        return _result(ck, cv, logits[:, 0], moe, mesh, cfg)
+        return _result(lists, logits[:, 0], moe, mesh, cfg)
 
     return jax.jit(bprefill, donate_argnums=(1,))
 

@@ -59,6 +59,19 @@ ring_blocks`` (the ring's page ids ride behind the context's). Window
 layers sized like full ones would hold ``max_kv`` positions a slot for state
 that is never read again.
 
+A layer that SELECTS key/value blocks (``MultiHeadAttention.select_topk``)
+holds a third array beside its K and V pages, its indexer's **pooled rows**,
+``cache["pool"][li] [n_pages, n_kv_heads * index_dim]``: a page IS a block of
+the selection (``select_block == page_size``), so a block's pooled row lives
+at its page's id and is owned, shared by prefix, freed, preempted and replayed
+with the page, by nobody's doing. A row is the elementwise maximum of the
+indexer keys of the positions its page holds so far: a program that writes a
+page's first position starts the row anew, any other carries it by maximum
+(``engine._pool_write``), and it is only read once its page is whole (a
+query's candidates end before its local blocks). A maximum cannot be rolled
+back, so no speculation (``ServeLoop`` refuses it). Only a model with such a
+layer has the ``"pool"`` entry at all.
+
 A STATE-SPACE layer (``TransformerConfig.state_space``) holds no K/V at all:
 its ``"k"`` array is the convolution **tail**, ``[state_rows, conv_kernel - 1,
 conv_dim]`` in the compute dtype (the last inputs of the depthwise
@@ -212,6 +225,19 @@ def layer_shapes(cfg, geo, li):
             (pages, geo.page_size, a.index_dim) if a.index_topk else None)
 
 
+def pool_shape(cfg, geo, li):
+    """Shape of layer ``li``'s pooled rows (one a page: a block of a
+    selecting kind's selection); None = the layer selects nothing."""
+    a = cfg.attn_of(li)
+    if not getattr(a, "select_topk", 0):
+        return None
+    if a.select_block != geo.page_size:
+        raise ValueError(f"a page IS a block of the selection: select_block "
+                         f"{a.select_block} needs pages of as many positions, "
+                         f"not {geo.page_size}")
+    return geo.n_pages, a.pool_width
+
+
 def _layer_dtypes(cfg, li):
     """Dtypes of layer ``li``'s ``("k", "v")`` arrays: the compute dtype,
     but float32 for a recurrent layer's state."""
@@ -221,7 +247,8 @@ def _layer_dtypes(cfg, li):
 
 
 def make_cache(cfg, geo, mesh=None):
-    """Allocate the zeroed cache: {"k": (...), "v": (...)}, each a tuple of
+    """Allocate the zeroed cache: {"k": (...), "v": (...)} (and ``"pool"``,
+    the pooled rows, for a model that selects blocks), each a tuple of
     n_layers arrays in the model's compute dtype (a recurrent layer's
     state in float32), each of its layer's own shape (:func:`layer_shapes`:
     pages, ring pages or state rows, and the lanes of the layer's kind; None
@@ -235,16 +262,28 @@ def make_cache(cfg, geo, mesh=None):
                          "rule) under a mesh are not written")
     layers = [(layer_shapes(cfg, geo, li), _layer_dtypes(cfg, li))
               for li in range(cfg.n_layers)]
-    return {name: tuple(
+    cache = {name: tuple(
         None if shapes[i] is None
         else jnp.zeros(shapes[i], dtypes[i], device=sharding)
         for shapes, dtypes in layers) for i, name in enumerate(("k", "v"))}
+    if cfg.selects_blocks:
+        rows = None if sharding is None else NamedSharding(
+            mesh, P(None, cfg.model_axis))
+        cache["pool"] = tuple(
+            None if shape is None
+            else jnp.zeros(shape, cfg.compute_dtype, device=rows)
+            for shape in (pool_shape(cfg, geo, li)
+                          for li in range(cfg.n_layers)))
+    return cache
 
 
 def cache_bytes(cfg, geo):
     """Total cache footprint in bytes (every layer's arrays)."""
+    pooled = (pool_shape(cfg, geo, li) for li in range(cfg.n_layers))
     return sum(dtype.itemsize * math.prod(shape)
                for li in range(cfg.n_layers)
                for shape, dtype in zip(layer_shapes(cfg, geo, li),
                                        _layer_dtypes(cfg, li))
-               if shape is not None)
+               if shape is not None) + sum(
+        cfg.compute_dtype.itemsize * math.prod(shape)
+        for shape in pooled if shape is not None)

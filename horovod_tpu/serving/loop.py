@@ -282,6 +282,11 @@ class ServeLoop:
                 "spec_tokens > 0 with layers that carry a state: a rejected "
                 "draft would have to roll the slot's state back, which is "
                 "not written")
+        if cfg.selects_blocks and self.spec_tokens > 0:
+            raise ValueError(
+                "spec_tokens > 0 with layers that select key/value blocks: "
+                "a rejected draft's position has already joined its page's "
+                "pooled row, and a maximum cannot be rolled back")
         padded = geo.max_kv <= PADDED_PREFILL_MAX_KV and not cfg.described
         if prefill_chunk is None:
             prefill_chunk = (2 * geo.page_size if padded
@@ -658,11 +663,11 @@ class ServeLoop:
                     and end - filled <= self.geo.page_size)
             fn = (self.chunk_tail_fn if tail
                   else last and self.chunk_end_fn or self.chunk_fn)
-            # Padding: 0, or for a model whose layers carry a state -1, which
-            # the program reads as a position that advances no state.
+            # Padding: 0, or for a model whose layers carry a state or pool
+            # their blocks -1, which the program reads as a dead position.
             toks = np.full(
                 (1, self.geo.page_size if tail else self.prefill_chunk),
-                -int(self.has_state), np.int32)
+                -int(engine.marks_padding(self.cfg)), np.int32)
             toks[0, :end - filled] = ctx[filled:end]
             bt = np.asarray(
                 self.batcher.block_table(req, self.geo.max_blocks),

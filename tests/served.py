@@ -1,4 +1,4 @@
-"""The served configurations, once: a table of the ten the benchmark serves
+"""The served configurations, once: a table of the eleven the benchmark serves
 (``benchmark/configs/<name>.json``), how each is shrunk for the CPU, and the
 two sets of cases every one of them owes, written as base classes that a
 model's own test file subclasses with the entry's name:
@@ -481,6 +481,20 @@ def _mimo(c):
         max_position_embeddings=256, rope_theta=500.0, swa_rope_theta=20.0)
 
 
+def _minimax(c):
+    """Every published RATIO kept: blocks of 8 positions, 2 chosen of 6 and
+    more candidates beside one first and two local blocks, 4 key/value groups
+    under 16 query heads with 4 indexer heads each, half of a head rotated."""
+    c.update(
+        hidden_size=64, intermediate_size=32, dense_intermediate_size=96,
+        shared_intermediate_size=32, num_attention_heads=16,
+        num_key_value_heads=4, head_dim=16, rotary_dim=8,
+        num_hidden_layers=5, num_local_experts_published=16,
+        experts_held=[4, 4], num_local_experts=4, num_experts_per_tok=2,
+        vocab_size=128, max_position_embeddings=256, rope_theta=500.0)
+    c["assumed"]["selection"].update(block=8, topk=2, index_dim=8)
+
+
 def _in_float32(c, chunk):
     c["model"].update(dtype="float32", param_dtype="float32")
     c["assumed"]["serve"]["chunk"] = chunk
@@ -817,6 +831,35 @@ ENTRIES = {
                       "chunk_end": dict(head="last"),
                       "chunk_tail": dict(head="last", name="chunk_tail")},
             aliased=">=", period=10, wide=False)),
+    "minimax-m3": _paged(
+        short="minimax_m3", pairs=dict(_PAIRS, prefill_chunk=16),
+        shrink=_minimax,
+        params=by_runner("minimax-m3", 0), geometry=(64, 8, 128),
+        # chunks of 12 over blocks of 8: a chunk boundary inside a block
+        serve=dict(max_batch=2, prefill_chunk=12),
+        forward=((100, 1),),
+        chosen=dict(with_routes=True, with_selected=True),
+        fault=(100, 1, 50), served=(5, 37, 100),
+        drive=_through_pages_and_rings, reused=(30, 45, 60, 75, 90),
+        preempted=dict(n_pages=12, new=24, prompts=tuple(
+            (30, None, 0.001 * (i + 1)) for i in range(3))),
+        shares=(16, 4),
+        cache=dict(geo=dict(ring_blocks=0, max_blocks=16),
+                   shapes={0: ((64, 8, 64), (64, 8, 64))},
+                   # K, V and a pooled row a page (4 groups x 8)
+                   bytes=5 * 4 * (2 * 64 * 8 * 64 + 64 * 32)),
+        scopes={program: dict(experts=True, attention=True, block_index=True,
+                              block_select=True, block_attention=True)
+                for program in ("decode", "chunk")},
+        cell=Cell(
+            geometry=dict(max_kv=65536, max_blocks=512, table_width=512,
+                          ring_blocks=0),
+            held=(14.0e9, 14.1e9),          # 83 % of the chip
+            gates=dict(grouped_kernels=True),
+            kernels=_both(paged_block_attention=5, index_scores=5,
+                          index_select=0, paged_full_attention=0),
+            temp=dict(chunk=0.6e9, chunk_pair=1.2e9, decode=0.1e9),
+            aliased=">=")),
     "mimo-v2-flash": _paged(
         short="mimo_v2", pairs=_PAIRS, shrink=_mimo, params=by_runner("mimo-v2-flash", 0),
         hashed=(("", dict(filed_dtype=True)),),

@@ -85,9 +85,14 @@ def test_names_are_spelled_once():
     assert scopes.SCOPES == ("embed", "layer_norm", "rms_norm", "attention",
                              "mlp", "experts", "loss", "head", "state_space",
                              "expert_latent", "linear_attention",
-                             "gated_memory")
+                             "gated_memory", "block_index", "block_select",
+                             "block_attention")
     assert scopes.parse("jit(decode)/gated_memory/dot_general") == (
         None, "gated_memory", False)
+    # a selecting layer's scopes lie INSIDE attention: the innermost is read
+    assert scopes.parse(
+        "jit(chunk)/attention/block_index/bsd,dgjk->bsgjk/dot_general") == (
+            None, "block_index", False)
     assert not set(scopes.PHASES) & set(scopes.SCOPES)
 
 
