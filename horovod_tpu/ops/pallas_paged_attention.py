@@ -544,11 +544,15 @@ def paged_grouped_attention(q, k_pages, v_pages, tables, pos0, kv_len, *,
 
 # ---- a selection of blocks a key/value head ---------------------------------
 
-# Lanes of a query's own list of chosen blocks as the kernel takes it (the
-# first, the chosen and the local ones, ``-1`` behind them), and the pages a
-# grid step copies at once: (one query a slot, a block of queries).
-_SEL_LANES = 128
-_BLOCK_PAGES = (4, 4)
+# The pages a grid step of the one-query walk copies at once; the queries a
+# walked block meets at once in a chunk's walk (a power of two: a block's
+# segment of the pair list is whole steps of it. On a v5e at the cell's
+# shapes a call takes 4.2 / 2.6 / 2.4 ms at 8 / 16 / 32 where every query
+# holds the same blocks and 3.5 / 3.7 at 16 / 32 at a 48k context where each
+# chooses for itself and a step's padding is a larger share: PERF.md, PR 66).
+_BLOCK_PAGES = 4
+_PAIR_QUERIES = 32
+_SMEM_TILE = 1024       # int32 a tile of scalar memory: a block is whole ones
 
 
 def block_supported(page_size, head_dim, v_dim, dtype):
@@ -560,49 +564,51 @@ def block_supported(page_size, head_dim, v_dim, dtype):
             and v_dim % 128 == 0)
 
 
-def _block_kernel(pos0_ref, len_ref, tables_ref, lists_ref, counts_ref,
-                  q_ref, *refs, page, ppb, width, n_tiles, n_kv, list_len,
-                  head_dim, v_dim, qb, group, sm_scale):
-    # ``refs``: the queries' own lists where a tile holds several queries,
-    # the layer's arrays, the output, scratch.
-    own_ref = refs[0] if qb > 1 else None
-    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_s, l_s, acc_s = refs[qb > 1:]
-    b, qi, g = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    kv_len = len_ref[b]
-    q_first = pos0_ref[b] + qi * qb
-    tile = (b * n_tiles + qi) * n_kv + g
-    count = counts_ref[tile]
-    n_steps = (count + ppb - 1) // ppb
-    rows = acc_s.shape[0]
+def _head_copies(k_hbm, v_hbm, k_buf, v_buf, sems, pid, slot, at, g, *,
+                 page, head_dim, v_dim):
+    """Page ``pid``'s copies into rows ``at`` of buffer ``slot``: key/value
+    head ``g``'s own lanes of the fused rows."""
     k_lanes = pl.ds(pl.multiple_of(g * head_dim, 128), head_dim)
     v_lanes = pl.ds(pl.multiple_of(g * v_dim, 128), v_dim)
+    return [
+        pltpu.make_async_copy(k_hbm.at[pid, pl.ds(0, page), k_lanes],
+                              k_buf.at[slot, at], sems.at[0, slot]),
+        pltpu.make_async_copy(v_hbm.at[pid, pl.ds(0, page), v_lanes],
+                              v_buf.at[slot, at], sems.at[1, slot])]
+
+
+def _block_kernel(pos0_ref, len_ref, tables_ref, lists_ref, counts_ref,
+                  q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_s, l_s,
+                  acc_s, *, page, ppb, width, n_kv, list_len, head_dim, v_dim,
+                  sm_scale):
+    """One query a slot: a grid step is a (slot, key/value head) and walks
+    the query's own list."""
+    b, g = pl.program_id(0), pl.program_id(1)
+    kv_len = len_ref[b]
+    q_pos = pos0_ref[b]
+    tile = b * n_kv + g
+    count = counts_ref[tile]
+    n_steps = (count + ppb - 1) // ppb
 
     def entry(n):
-        """The ``n``-th block of the tile's list; past its end the last one
-        again (never a page the slot does not own)."""
+        """The ``n``-th block of the list; past its end the last one again
+        (never a page the slot does not own)."""
         return lists_ref[tile * list_len
                          + jnp.maximum(jnp.minimum(n, count - 1), 0)]
 
     def copies(i, slot):
         out = []
         for j in range(ppb):
-            pid = tables_ref[b * width + entry(i * ppb + j)]
-            at = pl.ds(j * page, page)
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[pid, pl.ds(0, page), k_lanes], k_buf.at[slot, at],
-                sems.at[0, slot]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[pid, pl.ds(0, page), v_lanes], v_buf.at[slot, at],
-                sems.at[1, slot]))
+            out += _head_copies(
+                k_hbm, v_hbm, k_buf, v_buf, sems,
+                tables_ref[b * width + entry(i * ppb + j)], slot,
+                pl.ds(j * page, page), g, page=page, head_dim=head_dim,
+                v_dim=v_dim)
         return out
 
     m_s[...] = jnp.full_like(m_s, _NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
     acc_s[...] = jnp.zeros_like(acc_s)
-    # Row r of the tile is query r % qb of the block, of one of the group's
-    # heads (rows past group * qb are padding).
-    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    q_pos = q_first + row % qb
 
     @pl.when(n_steps > 0)
     def _first():
@@ -624,28 +630,16 @@ def _block_kernel(pos0_ref, len_ref, tables_ref, lists_ref, counts_ref,
         seen = []
         for j in range(ppb):
             at = n * ppb + j
-            blk = entry(at)
-            k_pos = blk * page + jax.lax.broadcasted_iota(
+            k_pos = entry(at) * page + jax.lax.broadcasted_iota(
                 jnp.int32, (1, page), 1)
             # Past the list's end the last block came again: not twice.
-            ok = (k_pos <= q_pos) & (k_pos < kv_len) & (at < count)
-            if qb > 1:
-                # A query sees the block if its OWN list holds it.
-                mine = jnp.max(jnp.where(own_ref[0, 0, 0] == blk, 1.0, 0.0),
-                               axis=1, keepdims=True)               # [qb, 1]
-                mine = jnp.concatenate([mine] * group, axis=0)
-                if rows > group * qb:
-                    mine = jnp.concatenate(
-                        [mine, jnp.zeros((rows - group * qb, 1), mine.dtype)],
-                        axis=0)
-                ok &= mine > 0.5
-            seen.append(ok)
+            seen.append((k_pos <= q_pos) & (k_pos < kv_len) & (at < count))
         ok = seen[0] if ppb == 1 else jnp.concatenate(seen, axis=1)
         s = jax.lax.dot_general(
-            q_ref[0, 0, 0], k, (((1,), (1,)), ((), ())),
+            q_ref[0], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        # A key a row does not see scores -inf, under the finite state a row
-        # starts at: its exp is 0 whatever the row has seen, unmasked.
+        # A key the query does not see scores -inf, under the finite state a
+        # row starts at: its exp is 0 whatever the row has seen, unmasked.
         s = jnp.where(ok, s, -jnp.inf)
         m_prev = m_s[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -659,8 +653,146 @@ def _block_kernel(pos0_ref, len_ref, tables_ref, lists_ref, counts_ref,
 
     jax.lax.fori_loop(0, n_steps, step, None)
     l = l_s[...]
-    o_ref[0, 0, 0] = (acc_s[...] * (1.0 / jnp.where(l > 0, l, 1.0))
-                      ).astype(o_ref.dtype)
+    o_ref[0] = (acc_s[...] * (1.0 / jnp.where(l > 0, l, 1.0))
+                ).astype(o_ref.dtype)
+
+
+def _pair_kernel(pos0_ref, len_ref, tables_ref, blocks_ref, starts_ref,
+                 walked_ref, order_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                 v_buf, sems, m_s, l_s, acc_s, s_buf, p_buf, a_buf, *, page,
+                 width, n_kv, head_dim, v_dim, step, sm_scale):
+    """A chunk of queries a slot: a grid step is a (slot, key/value head),
+    holds the head's rows of the WHOLE chunk, a query's heads one slab
+    ``[slab, dh]``, and walks the blocks any query holds, each once. A walked
+    block meets the queries of its segment of the pair list (``order``),
+    ``step`` at a time: their slabs gathered, one product with the page's
+    keys, the online-softmax update on the gathered slabs of the running
+    state, one product with the values, and the slabs written back. Slab
+    ``Q`` of the state is nobody's: the padding pairs' updates land there.
+
+    A step is one dependent chain (gather, product, row maximum, ``exp``,
+    product, scatter) and a loop body one basic block, so a body holds three
+    steps' independent thirds, handed on through ``s_buf``, ``p_buf`` and
+    ``a_buf``: the values' product and the accumulator of the step before,
+    the softmax of this one, the keys' product of the next. A block's queries
+    are distinct, so neighbouring steps touch different slabs; the walk
+    drains at a block's end, where a query may come again."""
+    b, g = pl.program_id(0), pl.program_id(1)
+    Q, slab = q_ref.shape[:2]
+    kv_len, q_first = len_ref[b], pos0_ref[b]
+    head = b * n_kv + g
+    n_walked = walked_ref[head]
+
+    def copies(n, slot):
+        blk = blocks_ref[head * width + n]
+        return _head_copies(k_hbm, v_hbm, k_buf, v_buf, sems,
+                            tables_ref[b * width + blk], slot,
+                            pl.ds(0, page), g, page=page, head_dim=head_dim,
+                            v_dim=v_dim)
+
+    span = math.gcd(Q, 32)      # slabs a step of the state's first and last
+
+    def fresh(i, _):
+        at = pl.ds(i * span, span)
+        m_s[at] = jnp.full((span,) + m_s.shape[1:], _NEG_INF, m_s.dtype)
+        l_s[at] = jnp.zeros((span,) + l_s.shape[1:], l_s.dtype)
+        acc_s[at] = jnp.zeros((span,) + acc_s.shape[1:], acc_s.dtype)
+
+    jax.lax.fori_loop(0, Q // span, fresh, None)
+    m_s[Q] = jnp.full(m_s.shape[1:], _NEG_INF, m_s.dtype)
+    l_s[Q] = jnp.zeros(l_s.shape[1:], l_s.dtype)
+    acc_s[Q] = jnp.zeros(acc_s.shape[1:], acc_s.dtype)
+
+    @pl.when(n_walked > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def gathered(ref, ids):
+        return jnp.concatenate([ref[i] for i in ids], axis=0)
+
+    def scatter(ref, ids, rows):
+        for j, i in enumerate(ids):
+            ref[i] = rows[j * slab:(j + 1) * slab]
+
+    def visit(n, _):
+        slot = n % 2
+
+        @pl.when(n + 1 < n_walked)
+        def _next():
+            for c in copies(n + 1, 1 - slot):
+                c.start()
+
+        for c in copies(n, slot):
+            c.wait()
+        k = k_buf[slot]                                     # [page, dh]
+        v = v_buf[slot]
+        k_pos = blocks_ref[head * width + n] * page \
+            + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        live = k_pos < kv_len
+        lo = starts_ref[head * (width + 1) + n]
+        n_steps = (starts_ref[head * (width + 1) + n + 1] - lo) // step
+
+        def ids_of(t):
+            return [order_ref[lo + t * step + j] for j in range(step)]
+
+        def keys(t, at):
+            """Step ``t``'s scores, unscaled, into ``s_buf[at]``."""
+            # The padding's query is past the chunk: any query's rows do.
+            qs = jnp.concatenate(
+                [q_ref[jnp.minimum(i, Q - 1)] for i in ids_of(t)], axis=0)
+            s_buf[at] = jax.lax.dot_general(
+                qs, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        def softmax(t, at):
+            """``s_buf[at]`` and the running maximum and sum of step ``t``'s
+            slabs -> the slabs' new ones, ``p_buf[at]``, ``a_buf[at]``."""
+            ids = ids_of(t)
+            q_pos = jnp.concatenate(
+                [jnp.full((slab, 1), q_first + i, jnp.int32) for i in ids],
+                axis=0)
+            # As the one-query walk: -inf under a finite starting state.
+            s = jnp.where((k_pos <= q_pos) & live, s_buf[at] * sm_scale,
+                          -jnp.inf)
+            m_prev = gathered(m_s, ids)
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            p_buf[at] = p.astype(p_buf.dtype)
+            a_buf[at] = alpha
+            scatter(l_s, ids, gathered(l_s, ids) * alpha
+                    + jnp.sum(p, -1, keepdims=True))
+            scatter(m_s, ids, m_new)
+
+        def values(ids, at):
+            scatter(acc_s, ids, gathered(acc_s, ids) * a_buf[at]
+                    + jax.lax.dot_general(
+                        p_buf[at], v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+
+        keys(0, 0)
+
+        def body(t, _):
+            at = t % 2
+            # Before the first step nobody's slab takes what the buffers hold.
+            values([jnp.where(t > 0, i, Q)
+                    for i in ids_of(jnp.maximum(t - 1, 0))], 1 - at)
+            softmax(t, at)
+            keys(jnp.minimum(t + 1, n_steps - 1), 1 - at)
+
+        jax.lax.fori_loop(0, n_steps, body, None)
+        values(ids_of(n_steps - 1), (n_steps - 1) % 2)
+
+    jax.lax.fori_loop(0, n_walked, visit, None)
+
+    def last(i, _):
+        at = pl.ds(i * span, span)
+        l = l_s[at]
+        o_ref[at] = (acc_s[at] * (1.0 / jnp.where(l > 0, l, 1.0))
+                     ).astype(o_ref.dtype)
+
+    jax.lax.fori_loop(0, Q // span, last, None)
 
 
 def block_lists(chosen, q_pos, live, *, page, first, local):
@@ -681,33 +813,94 @@ def block_lists(chosen, q_pos, live, *, page, first, local):
     return jnp.where(live[..., None, None], own, -1)
 
 
+def _walked(own, width):
+    """The blocks of a table ``width`` wide that the queries' own lists ``own
+    [B, Q, G, n]`` hold, a (slot, key/value head) each -> (the queries that
+    hold each block ``[B, G, width]``, the held blocks ascending and the
+    others behind them ``[B, G, width]``, how many are held ``[B, G]``)."""
+    counts = jnp.sum(own[..., None] == jnp.arange(width), axis=(1, 3))
+    return (counts, jnp.argsort(counts == 0, axis=-1, stable=True),
+            jnp.sum(counts > 0, -1))
+
+
+def block_pairs(own, width, step):
+    """The (query, block) PAIRS a chunk's walk multiplies, block-major: from
+    every query's own list ``own [B, Q, G, n]`` (:func:`block_lists`) over a
+    table of ``width`` blocks ->
+
+    - ``order [B, G, P]``: the pairs' queries sorted by block, ascending
+      inside a block; a block's segment is padded to whole steps of ``step``
+      queries with the query ``Q``, which is nobody's; behind the last
+      segment ``Q`` too (``P`` is the most there can be, in whole tiles of
+      scalar memory);
+    - ``blocks [B, G, width]``: the blocks some query holds, ascending,
+      ``walked [B, G]`` of them;
+    - ``starts [B, G, width + 1]``: where the ``n``-th walked block's segment
+      starts in ``order``; flat behind the last, so ``starts[..., -1]`` is the
+      pairs the kernel multiplies, padding and all: at most the pairs there
+      are and ``step - 1`` more a walked block.
+
+    ONE sort of the keys ``block * span + query``, padding among them."""
+    B, Q, G, most = own.shape
+    span = 1 << (Q + step - 1).bit_length()
+    if step & (step - 1) or (width + 1) * span >= 2 ** 31:
+        raise ValueError(f"steps of {step} queries over {width} blocks of "
+                         f"{Q} do not make a key")
+    nobody = width * span + Q
+    counts, blocks, walked = _walked(own, width)
+    short = -counts & (step - 1)                  # [B, G, width]
+    pairs = jnp.where(own >= 0,
+                      own * span + jnp.arange(Q)[None, :, None, None], nobody)
+    fill = jnp.arange(step - 1)
+    pads = jnp.where(fill < short[..., None],
+                     jnp.arange(width)[:, None] * span + Q + fill, nobody)
+    P = Q * most + width * (step - 1)
+    keys = jnp.sort(jnp.concatenate(
+        [pairs.transpose(0, 2, 1, 3).reshape(B, G, -1),
+         pads.reshape(B, G, -1),
+         jnp.full((B, G, -P % _SMEM_TILE), nobody)], -1), axis=-1)
+    starts = jnp.cumsum(jnp.take_along_axis(counts + short, blocks, -1), -1)
+    return (jnp.minimum(keys % span, Q).astype(jnp.int32),
+            blocks.astype(jnp.int32),
+            jnp.pad(starts, ((0, 0), (0, 0), (1, 0))).astype(jnp.int32),
+            walked.astype(jnp.int32))
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "n_kv_heads", "first", "local", "q_block", "pages_per_block",
-    "interpret"))
+    "n_kv_heads", "first", "local", "pages_per_block", "interpret"))
 def paged_block_attention(q, k_pages, v_pages, tables, pos0, kv_len, chosen,
-                          *, n_kv_heads, first, local, q_block=None,
-                          pages_per_block=None, interpret=False):
+                          *, n_kv_heads, first, local, pages_per_block=None,
+                          interpret=False):
     """:func:`paged_grouped_attention` over a SELECTION of blocks a key/value
     head: ``q [B, Q, Hq, dh]`` at the consecutive positions ``pos0 [B] ..``
     against ``k_pages [n_pages, page, Hkv * dh]``, ``v_pages [n_pages, page,
     Hkv * dv]`` through ``tables [B, W]`` -> ``[B, Q, Hq, dv]``. A page IS a
     block of the selection. ``chosen [B, Q, Hkv, k]``: the blocks each query
-    chose for each key/value head's group of query heads (``-1`` = none); it
-    also sees the ``first`` leading blocks and the ``local`` last ones of its
-    own position, and of all of them the positions ``<=`` its own and ``<
-    kv_len [B]``.
+    chose for each key/value head's group of query heads (``-1`` = none, no
+    block twice); it also sees the ``first`` leading blocks and the ``local``
+    last ones of its own position, and of all of them the positions ``<=``
+    its own and ``< kv_len [B]``. Online softmax in float32 scratch, as the
+    grouped kernel's; a page is copied as the head's own lanes of the fused
+    rows through two VMEM buffers.
 
-    Structure: grid over slots, blocks of ``q_block`` queries and key/value
-    heads. A grid step walks the list of the blocks that ANY query of its
-    tile chose for this head, each once, ascending (made here, scalar
-    prefetched beside the block table), ``pages_per_block`` pages at a time
-    through two VMEM buffers; a page is copied as the head's own lanes of
-    the fused rows. Inside, a query is masked to the blocks of its OWN list
-    (compared with the walked block's id), so queries of one tile may choose
-    differently: what a tile reads is the union, what a query attends its
-    own 19. One query a slot (the decode step): the list is the query's own
-    and nothing is compared. Online softmax in float32 scratch, as the
-    grouped kernel's."""
+    One query a slot (the decode step): grid over slots and key/value heads;
+    a grid step walks the query's own list (scalar prefetched beside the
+    block table), ``pages_per_block`` pages at a time, the group's heads one
+    tile of rows. Bound by the pages' bytes.
+
+    A chunk of queries a slot: the work is the (query, block) PAIRS, not the
+    queries times the blocks any of them chose. The pairs are sorted by block
+    here (:func:`block_pairs`); a grid step, again a (slot, key/value head),
+    holds the head's rows of the whole chunk in VMEM, a query's ``group``
+    heads one slab of whole sublane tiles, and walks the blocks some query
+    holds, each once a chunk. A walked block's page meets the queries that
+    hold it, ``_PAIR_QUERIES`` slabs at a time, gathered by their index in the
+    sorted list and written back after the update: what a block costs is
+    proportional to the queries that chose it, whether the chunk's queries
+    choose alike or each for itself, and nothing is compared with a query's
+    list inside. Bound by the products: a pair is ``group`` rows through two
+    of them, each with ONE weight tile (the page's keys, its values), which
+    Mosaic runs on one matrix unit (PERF.md, PR 66)."""
     B, Q, Hq, dh = q.shape
     n_pages, page, hd = k_pages.shape
     G = int(n_kv_heads)
@@ -719,81 +912,78 @@ def paged_block_attention(q, k_pages, v_pages, tables, pos0, kv_len, chosen,
                          f"choices {chosen.shape} do not hold {G} heads of "
                          f"{dh} under {Hq} query heads")
     group, width = Hq // G, tables.shape[1]
-    qb = int(q_block or _Q_BLOCK)
-    if qb & (qb - 1):
-        raise ValueError(f"q_block {qb} is not a power of two")
-    qb = math.gcd(Q, qb)
-    nq = Q // qb
     q_pos = pos0[:, None] + jnp.arange(Q)[None]
     own = block_lists(chosen, q_pos, q_pos < kv_len[:, None], page=page,
                       first=first, local=local)
     most = own.shape[-1]
-    if most > _SEL_LANES:
-        raise ValueError(f"a query's {most} blocks pass {_SEL_LANES}")
-    ppb = int(pages_per_block or _BLOCK_PAGES[Q > 1])
-    # The tile's list: the blocks any of its queries holds, each once.
-    L = -(-min(width, qb * most) // ppb) * ppb
-    tiles = own.reshape(B, nq, qb, G, most).transpose(0, 1, 3, 2, 4)
-    held = jnp.zeros((B, nq, G, width + 1), bool).at[
-        jnp.arange(B)[:, None, None, None],
-        jnp.arange(nq)[None, :, None, None],
-        jnp.arange(G)[None, None, :, None],
-        jnp.where(tiles >= 0, tiles, width).reshape(B, nq, G, -1)
-    ].set(True)[..., :width]
-    lists = jnp.argsort(~held, axis=-1, stable=True)
-    lists = jnp.pad(lists, ((0, 0),) * 3 + ((0, max(L - width, 0)),))[..., :L]
-    counts = jnp.sum(held, -1)
-    rows = -(-group * qb // 16) * 16
-    qt = q.reshape(B, nq, qb, G, group, dh).transpose(0, 1, 3, 4, 2, 5)
-    qt = qt.reshape(B, nq, G, group * qb, dh)
-    qt = jnp.pad(qt, ((0, 0),) * 3 + ((0, rows - group * qb), (0, 0)))
+    sublanes = 8 * (4 // q.dtype.itemsize)
+    slab = -(-group // sublanes) * sublanes
+    qt = q.reshape(B, Q, G, group, dh)
+    if slab > group:
+        qt = jnp.pad(qt, ((0, 0),) * 3 + ((0, slab - group), (0, 0)))
+    scalars = [pos0.astype(jnp.int32), kv_len.astype(jnp.int32),
+               tables.reshape(-1).astype(jnp.int32)]
+    params = dict(page=page, width=width, n_kv=G, head_dim=dh, v_dim=dv,
+                  sm_scale=1.0 / math.sqrt(dh))
 
-    def tile(r, lanes):
-        return pl.BlockSpec((1, 1, 1, r, lanes),
-                            lambda b, qi, g, *_: (b, qi, g, 0, 0),
+    def rows(lanes):
+        """The ``Q`` slabs of a (slot, key/value head), out of the array as
+        the program holds it: ``[B, Q, G, slab, lanes]``."""
+        return pl.BlockSpec((None, Q, None, slab, lanes),
+                            lambda b, g, *_: (b, 0, g, 0, 0),
                             memory_space=pltpu.VMEM)
 
-    operands, in_specs = [qt], [tile(rows, dh)]
-    if qb > 1:
-        operands.append(jnp.pad(
-            tiles, ((0, 0),) * 4 + ((0, _SEL_LANES - most),),
-            constant_values=-1))
-        in_specs.append(tile(qb, _SEL_LANES))
-    kernel = functools.partial(
-        _block_kernel, page=page, ppb=ppb, width=width, n_tiles=nq, n_kv=G,
-        list_len=L, head_dim=dh, v_dim=dv, qb=qb, group=group,
-        sm_scale=1.0 / math.sqrt(dh))
-    bt = ppb * page
-    walked = B * nq * G * L * page                    # an upper bound
+    if Q == 1:
+        ppb = int(pages_per_block or _BLOCK_PAGES)
+        L = -(-min(width, most) // ppb) * ppb
+        _, lists, count = _walked(own, width)
+        lists = jnp.pad(lists, ((0, 0),) * 2 + ((0, max(L - width, 0)),))
+        scalars += [lists[..., :L].reshape(-1).astype(jnp.int32),
+                    count.reshape(-1).astype(jnp.int32)]
+        kernel = functools.partial(_block_kernel, ppb=ppb, list_len=L,
+                                   **params)
+        operands, in_specs = [qt], [rows(dh)]
+        walked, bt = B * G * L * page, ppb * page       # an upper bound
+        met, state, handed = slab * walked, (slab,), []
+    else:
+        step = _PAIR_QUERIES
+        order, blocks, starts, n_walked = block_pairs(own, width, step)
+        scalars += [blocks.reshape(-1), starts.reshape(-1),
+                    n_walked.reshape(-1)]
+        kernel = functools.partial(_pair_kernel, step=step, **params)
+        P = order.shape[-1]
+        operands = [order.reshape(-1), qt]
+        in_specs = [pl.BlockSpec((P,), lambda b, g, *_: (b * G + g,),
+                                 memory_space=pltpu.SMEM), rows(dh)]
+        walked, bt = B * G * width * page, page         # upper bounds
+        met, state = slab * page * B * G * P, (Q + 1, slab)
+        handed = [pltpu.VMEM((2, step * slab, page), jnp.float32),
+                  pltpu.VMEM((2, step * slab, page), v_pages.dtype),
+                  pltpu.VMEM((2, step * slab, 1), jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(B, nq, G),
+            num_scalar_prefetch=len(scalars),
+            grid=(B, G),
             in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY),
                                  pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=tile(rows, dv),
+            out_specs=rows(dv),
             scratch_shapes=[
                 pltpu.VMEM((2, bt, dh), k_pages.dtype),
                 pltpu.VMEM((2, bt, dv), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, dv), jnp.float32),
-            ]),
-        out_shape=jax.ShapeDtypeStruct((B, nq, G, rows, dv), q.dtype),
+                pltpu.VMEM(state + (1,), jnp.float32),
+                pltpu.VMEM(state + (1,), jnp.float32),
+                pltpu.VMEM(state + (dv,), jnp.float32),
+            ] + handed),
+        out_shape=jax.ShapeDtypeStruct((B, Q, G, slab, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",) * 3,
+            dimension_semantics=("arbitrary",) * 2,
             vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
-            flops=2 * rows * (dh + dv) * walked,
-            transcendentals=rows * walked,
+            flops=2 * (dh + dv) * met, transcendentals=met,
             bytes_accessed=walked * (dh + dv) * k_pages.dtype.itemsize),
         name=BLOCK_NAME,
         interpret=interpret,
-    )(pos0.astype(jnp.int32), kv_len.astype(jnp.int32),
-      tables.reshape(-1).astype(jnp.int32),
-      lists.reshape(-1).astype(jnp.int32),
-      counts.reshape(-1).astype(jnp.int32), *operands, k_pages, v_pages)
-    out = out[:, :, :, :group * qb].reshape(B, nq, G, group, qb, dv)
-    return out.transpose(0, 1, 4, 2, 3, 5).reshape(B, Q, Hq, dv)
+    )(*scalars, *operands, k_pages, v_pages)
+    return out[:, :, :, :group].reshape(B, Q, Hq, dv)

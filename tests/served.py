@@ -858,7 +858,7 @@ ENTRIES = {
             gates=dict(grouped_kernels=True),
             kernels=_both(paged_block_attention=5, index_scores=5,
                           index_select=0, paged_full_attention=0),
-            temp=dict(chunk=0.6e9, chunk_pair=1.2e9, decode=0.1e9),
+            temp=dict(chunk=0.15e9, chunk_pair=0.5e9, decode=0.1e9),
             aliased=">=")),
     "mimo-v2-flash": _paged(
         short="mimo_v2", pairs=_PAIRS, shrink=_mimo, params=by_runner("mimo-v2-flash", 0),

@@ -159,26 +159,66 @@ def test_first_and_local_always_and_never_a_part_written_block():
             chosen[0, i, 0][chosen[0, i, 0] >= 0].tolist())
 
 
-@pytest.mark.parametrize("queries", [1, 16], ids=["decode", "chunk"])
-def test_the_block_kernel_against_the_plain_tier(queries):
-    """``paged_block_attention`` in interpret mode: a list of pages a (slot,
-    tile, group), a tile's queries choosing differently, a list that ends
-    inside a grid step's pages, an inactive slot."""
+def _choices(how, rng, q_pos, G, N, a):
+    """``chosen [B, Q, G, k]`` of the queries at ``q_pos [B, Q]`` over ``N``
+    blocks: ``apart`` every query by scores of its own, ``alike`` all by the
+    same scores, ``one_and_all`` the lowest candidates for everybody and
+    block 9 besides for the LAST query of slot 0 alone."""
+    B, Q = q_pos.shape
+    scores = rng.standard_normal((B, Q, G, N))
+    if how == "alike":
+        scores = np.broadcast_to(scores[:, :1], scores.shape)
+    if how == "one_and_all":
+        scores = np.broadcast_to(-np.arange(N, dtype=float), scores.shape)
+        scores = scores.copy()
+        scores[0, -1, :, 9] = 5.0
+    return tfm.select_blocks(jnp.asarray(scores, jnp.float32), q_pos, a)
+
+
+# (queries a slot, the slots' first positions, how they choose). Slots of
+# unequal length, the last one inactive (``kv_len`` 0), in every case.
+_BLOCK_CASES = {
+    "decode": (1, (100, 37, 0), "apart"),
+    "chunk": (16, (100, 37, 0), "apart"),
+    # a chunk of five blocks and a part, its first position inside a block
+    "long": (44, (100, 37, 0), "apart"),
+    "alike": (24, (100, 37, 0), "alike"),
+    "one_and_all": (24, (100, 37, 0), "one_and_all"),
+    # fewer blocks than a query may hold: every block is attended
+    "short": (16, (10, 3, 0), "apart"),
+}
+
+
+@pytest.mark.parametrize("case", list(_BLOCK_CASES))
+def test_the_block_kernel_against_the_plain_tier(case):
+    """``paged_block_attention`` in interpret mode: one query a slot walks
+    its own list of pages, a list that ends inside a grid step's pages; a
+    chunk walks its (query, block) pairs sorted by block, the queries
+    choosing each for itself, all alike, one block held by one query alone
+    and others by all, segments that are no whole number of steps, a context
+    attended whole; an inactive slot."""
+    queries, first, how = _BLOCK_CASES[case]
     rng = np.random.default_rng(queries)
     B, Hq, G, dh, page, N = 3, 8, 2, 128, 8, 32
     a = _kind()
     k_pages, v_pages = (jnp.asarray(rng.standard_normal(
         (B * N + 1, page, G * dh)), jnp.float32) for _ in range(2))
     tables = jnp.asarray(1 + np.arange(B * N).reshape(B, N), jnp.int32)
-    pos0 = jnp.asarray([100, 37, 0], jnp.int32)
-    kv_len = jnp.asarray([100 + queries, 37 + queries, 0], jnp.int32)
+    pos0 = jnp.asarray(first, jnp.int32)
+    kv_len = jnp.where(jnp.arange(B) < 2, pos0 + queries, 0)
     q = jnp.asarray(rng.standard_normal((B, queries, Hq, dh)), jnp.float32)
     q_pos = pos0[:, None] + jnp.arange(queries)[None]
-    chosen = tfm.select_blocks(jnp.asarray(rng.standard_normal(
-        (B, queries, G, N)), jnp.float32), q_pos, a)
+    chosen = _choices(how, rng, q_pos, G, N, a)
+    held = np.asarray(paged.block_lists(
+        chosen, q_pos, q_pos < kv_len[:, None], page=page, first=1, local=2))
+    if how == "one_and_all":
+        assert (held[0] == 9).sum() == G and (held[0] == 2).any(-1).all()
+    if queries > 1 and how != "short":
+        # some block's queries are no whole number of the kernel's steps
+        assert (np.bincount(held[held >= 0]) % paged._PAIR_QUERIES).any()
     got = paged.paged_block_attention(
         q, k_pages, v_pages, tables, pos0, kv_len, chosen, n_kv_heads=G,
-        first=1, local=2, q_block=8, interpret=True)
+        first=1, local=2, interpret=True)
     k_pos = jnp.broadcast_to(jnp.arange(N * page)[None], (B, N * page))
     allowed = tfm.attend_allowed(
         a, q_pos, k_pos, k_pos < kv_len[:, None])[:, None] \
@@ -187,6 +227,45 @@ def test_the_block_kernel_against_the_plain_tier(queries):
     want = tfm.grouped_attend(q, *rows, a, allowed, jnp.float32)
     assert _rel(got[:2], want[:2]) < 1e-5
     assert not np.asarray(got[2]).any()
+
+
+@pytest.mark.parametrize("how", ["apart", "alike"])
+@pytest.mark.parametrize("step", [4, 16])
+def test_the_pairs_multiplied_are_the_pairs_chosen(how, step):
+    """``block_pairs``, the chunk walk's counter: every (query, block) pair
+    of the queries' own lists once, under its block, queries ascending; a
+    block's segment whole steps, padded with nobody's query; so the pairs
+    multiplied pass the pairs there are by less than a step a walked block,
+    whether the queries choose alike or each for itself."""
+    rng = np.random.default_rng(step)
+    B, Q, G, N, page = 2, 40, 2, 32, 8
+    a = _kind()
+    q_pos = jnp.asarray([[100], [37]]) + jnp.arange(Q)[None]
+    live = jnp.asarray(np.arange(Q)[None] < np.asarray([[Q], [Q - 7]]))
+    own = paged.block_lists(_choices(how, rng, q_pos, G, N, a), q_pos, live,
+                            page=page, first=1, local=2)
+    order, blocks, starts, walked = (np.asarray(x) for x in
+                                     paged.block_pairs(own, N, step))
+    own = np.asarray(own)
+    for b in range(B):
+        for g in range(G):
+            want = sorted((int(n), i) for i in range(Q)
+                          for n in own[b, i, g] if n >= 0)
+            got, n_walked = [], int(walked[b, g])
+            assert n_walked == len({n for n, _ in want})
+            for at in range(n_walked):
+                lo, hi = starts[b, g, at], starts[b, g, at + 1]
+                assert (hi - lo) % step == 0 and hi > lo
+                segment = order[b, g, lo:hi]
+                got += [(int(blocks[b, g, at]), int(i))
+                        for i in segment if i < Q]
+                assert (segment[np.argmax(segment == Q):] == Q).all() \
+                    or Q not in segment
+            assert got == want
+            multiplied = int(starts[b, g, -1])
+            assert multiplied == starts[b, g, n_walked]
+            assert len(want) <= multiplied <= len(want) + n_walked * step
+            assert (order[b, g, multiplied:] == Q).all()
 
 
 def test_the_kernels_give_what_the_plain_programs_give(monkeypatch):
